@@ -1,7 +1,7 @@
 """p2p_llm_chat_tpu — a TPU-native P2P chat framework with an in-tree LLM co-pilot.
 
 A from-scratch build with the capabilities of NajyFannoun/P2P-LLM-Chat-Go
-(see /root/repo/SURVEY.md): per-user P2P chat nodes with encrypted peer
+(see SURVEY.md): per-user P2P chat nodes with encrypted peer
 streams and a local HTTP API, a username->peer directory service, an
 optional circuit relay, a chat web UI with an AI reply co-pilot — plus,
 replacing the reference's external Ollama dependency, a native JAX/XLA

@@ -7,7 +7,7 @@ serving-evaluation convention bench.py's mixed phase follows), and a
 pass/fail verdict against the scenario targets from scenarios.py.
 
 Rows are durable by the same convention as the bench: the first free
-``E2E_r0N.json`` slot in the repo root (``BENCH_r0N.json``'s sibling),
+``E2E_r0N.json`` slot in the repo root (beside the driver's bench rows),
 and a failed run writes an *error row* rather than nothing — a crashed
 64-peer run that silently prints to a lost stdout is an hour of chip
 time unrecorded.
@@ -347,7 +347,8 @@ def build_ledger(records: list, registry: dict, duration_s: float,
 
 
 def next_row_path(directory: str, prefix: str = "E2E") -> str:
-    """First free ``<prefix>_r0N.json`` slot — the BENCH_r0N convention."""
+    """First free ``<prefix>_r0N.json`` slot — the driver's bench-row
+    naming."""
     for i in range(1, 100):
         p = os.path.join(directory, f"{prefix}_r{i:02d}.json")
         if not os.path.exists(p):

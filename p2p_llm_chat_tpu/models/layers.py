@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils.device import on_tpu
 from .configs import ModelConfig, RopeScaling
 from .quant import mm
 
@@ -213,16 +214,6 @@ def flash_attend_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
 _FLASH_SCORE_ELEMS = 2 ** 25
 
 
-_ON_TPU: Optional[bool] = None
-
-
-def _tpu_backend() -> bool:
-    global _ON_TPU
-    if _ON_TPU is None:
-        _ON_TPU = jax.default_backend() == "tpu"
-    return _ON_TPU
-
-
 def attend_gqa_causal0(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """Causal-from-position-0 attention via the canonical Pallas TPU
     flash kernel (jax.experimental.pallas.ops) — probabilities never
@@ -267,7 +258,7 @@ def attend_gqa_auto(q: jax.Array, k: jax.Array, v: jax.Array,
     Skv = k.shape[1]
     big = B * Hq * Sq * Skv > _FLASH_SCORE_ELEMS
     if (big and causal0_len is not None and causal0_len == Sq
-            and _tpu_backend() and Sq % 512 == 0 and D % 128 == 0):
+            and on_tpu() and Sq % 512 == 0 and D % 128 == 0):
         return attend_gqa_causal0(q, k[:, :Sq], v[:, :Sq])
     if big and Sq >= 256 and Skv >= 1024 and Skv % 512 == 0:
         # Sq >= 256 keeps DECODE-side shapes (speculative verify: a few

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..parallel.sharding import LogicalRules, DEFAULT_RULES, constrain
+from ..utils.device import pallas_interpret
 from .configs import ModelConfig
 from .quant import LayerSlice, QTensor, QTensor4, mm
 from .layers import (
@@ -199,8 +200,8 @@ def fuse_params(params: dict, tp: int = 1, mesh: Optional[Mesh] = None,
     Why: decode is HBM-bandwidth-bound, and on a v5e chip the measured
     per-matmul-call fixed cost (kernel entry + tile pipeline fill) is what
     keeps the weight stream below the bandwidth bound — fusing the
-    column-parallel pairs cut the measured matmul floor of a bench-1b
-    step by ~20% (see BASELINE.md round-3 notes). The math is identical:
+    column-parallel pairs cut the matmul floor of a bench-1b step by
+    ~20% (builder-reported, before PR 1). The math is identical:
     the fused weight's output columns are a permutation of the originals',
     and int8 per-output-channel scales permute with them
     (models/quant.QTensor stores s per output column).
@@ -210,7 +211,7 @@ def fuse_params(params: dict, tp: int = 1, mesh: Optional[Mesh] = None,
     and the fused leaves are device_put with the fused column axis
     sharded over tp — each device's shard is exactly its own q/k/v (or
     gate/up) columns, so TP serving keeps the fused-matmul win instead
-    of giving it up (VERDICT r3 weak #3).
+    of giving it up.
 
     Works on bf16 arrays and QTensors alike; no-op if already fused.
 
@@ -681,14 +682,12 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
                  num_steps: int, sample_fn, sample_state, stop_ids,
                  kv_window: Optional[int] = None,
                  pages: Optional[int] = None,
-                 interpret: Optional[bool] = None,
                  step_fn=None):
     """``num_steps`` autoregressive steps in ONE dispatch: a ``lax.scan``
     over :func:`decode_step` (dense) / :func:`decode_step_paged`
     (``pages`` set) carrying the cache, the sampled next-token feed, the
     active mask, and the caller's sampling state — so K decode steps cost
-    one host dispatch/readback instead of K (the host-side per-dispatch
-    overhead was ~a third of every decode tick at B=32; BENCH_r05).
+    one host dispatch/readback instead of K.
 
     Each scan step IS the plain step — the same ``decode_step[_paged]``
     call, then ``sample_fn(logits [B,V], state, emit_pos [B], active
@@ -727,8 +726,7 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
                                     rules, active=act, kv_window=kv_window)
         else:
             logits, cache = step_fn(params, config, tokens, cache, mesh,
-                                    rules, active=act, pages=pages,
-                                    interpret=interpret)
+                                    rules, active=act, pages=pages)
         toks, state = sample_fn(logits[:, 0, :], state, emit_pos, act)
         # Parked rows keep their previous input token (the plain
         # program's exact next-token rule).
@@ -868,7 +866,7 @@ def _constrain_pool(cache, mesh: Optional[Mesh],
 def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                       cache, mesh: Optional[Mesh] = None,
                       rules: LogicalRules = DEFAULT_RULES,
-                      *, pages: int, interpret: Optional[bool] = None,
+                      *, pages: int,
                       mlp_fn=None, last_idx: Optional[jax.Array] = None):
     """Speculative verify over the paged pool: :func:`verify_step`'s
     contract (S candidate positions, lengths unchanged; caller advances
@@ -901,8 +899,7 @@ def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     from ..ops.paged_kv import (write_decode_multi,
                                 write_decode_multi_all_layers)
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = pallas_interpret()
     cache = _constrain_pool(cache, mesh, rules)
     B, S = tokens.shape
     positions = cache.lengths[:, None] + jnp.arange(S)[None, :]    # [B,S]
@@ -1015,8 +1012,7 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                       cache, mesh: Optional[Mesh] = None,
                       rules: LogicalRules = DEFAULT_RULES,
                       active: Optional[jax.Array] = None,
-                      *, pages: int, interpret: Optional[bool] = None,
-                      mlp_fn=None):
+                      *, pages: int, mlp_fn=None):
     """One autoregressive step over the paged KV pool (ops/paged_kv.py).
 
     Same contract as :func:`decode_step` — including the parked-row
@@ -1052,8 +1048,7 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     from ..ops.paged_kv import PagedKVCache, write_decode, write_decode_burst
     from ..ops.paged_attention import _DEFAULT_IMPL, paged_attention_append
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = pallas_interpret()
     cache = _constrain_pool(cache, mesh, rules)
     B = tokens.shape[0]
     positions = cache.lengths[:, None]                 # [B,1]
@@ -1077,7 +1072,8 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                                 rules)
             attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
                                           cache.lengths, layer, pages=pages,
-                                          interpret=interpret)
+                                          interpret=interpret,
+                                          sharded=mesh is not None)
             h = _post_attn(h, attn[:, None], lp, config, mesh, rules,
                            mlp_fn)
             return h, (k[:, 0], v[:, 0])

@@ -208,8 +208,8 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     of llama.fuse_params' dense ``wgu``): when given, gate and up run as
     ONE batched einsum and w_gate/w_up are ignored (may be None). Decode
     is bandwidth-bound with a per-matmul fixed cost, so halving the
-    expert projection dispatches pays exactly like the dense fusion did
-    (BASELINE.md round-3 notes); per-output-channel int8 scales
+    expert projection dispatches pays exactly like the dense fusion
+    did; per-output-channel int8 scales
     concatenate with their columns, so the math is identical.
     """
     B, S, H = x.shape
@@ -358,8 +358,7 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
                  active: Optional[jax.Array] = None, *,
                  num_steps: int, sample_fn, sample_state, stop_ids,
                  kv_window: Optional[int] = None,
-                 pages: Optional[int] = None,
-                 interpret: Optional[bool] = None):
+                 pages: Optional[int] = None):
     """llama.decode_fused over the MoE step functions (same contract:
     K steps, one dispatch, in-scan EOS parking, bit-identical to K
     sequential plain ticks)."""
@@ -369,7 +368,7 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
                               sample_fn=sample_fn,
                               sample_state=sample_state, stop_ids=stop_ids,
                               kv_window=kv_window, pages=pages,
-                              interpret=interpret, step_fn=step_fn)
+                              step_fn=step_fn)
 
 
 def verify_step(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -404,7 +403,7 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                       cache, mesh: Optional[Mesh] = None,
                       rules: LogicalRules = DEFAULT_RULES,
                       active: Optional[jax.Array] = None,
-                      *, pages: int, interpret: Optional[bool] = None):
+                      *, pages: int):
     """llama.decode_step_paged with the MoE MLP (same contract; decode's
     token count is tiny, so the expert bucket stays exact). Attention
     impl selection — including the round-8 multi-chunk flash-append
@@ -413,18 +412,17 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     mlp_fn seam, so MoE long-window decode takes the same kernel."""
     return llama.decode_step_paged(params, config, tokens, cache, mesh,
                                    rules, active, pages=pages,
-                                   interpret=interpret,
                                    mlp_fn=_mlp_fn(config, None))
 
 
 def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                       cache, mesh: Optional[Mesh] = None,
                       rules: LogicalRules = DEFAULT_RULES,
-                      *, pages: int, interpret: Optional[bool] = None,
+                      *, pages: int,
                       last_idx: Optional[jax.Array] = None):
     """llama.verify_step_paged with the MoE MLP."""
     return llama.verify_step_paged(params, config, tokens, cache, mesh,
-                                   rules, pages=pages, interpret=interpret,
+                                   rules, pages=pages,
                                    mlp_fn=_mlp_fn(config, None),
                                    last_idx=last_idx)
 

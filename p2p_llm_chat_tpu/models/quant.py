@@ -50,6 +50,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..utils.device import on_tpu
+
 
 class QTensor(NamedTuple):
     """int8 weight + f32 per-output-channel scale (contraction axis kept
@@ -196,7 +198,6 @@ def dequantize4(w: QTensor4, dtype=jnp.bfloat16) -> jax.Array:
 # below it; prefill chunks far above (where XLA's matmul is the right
 # tool and the convert cost is amortised).
 _KERNEL_MAX_ROWS = 512
-_BACKEND_IS_TPU: bool | None = None
 _FORCE_XLA = False
 
 
@@ -212,13 +213,11 @@ def set_mm_impl(impl: str) -> None:
     _FORCE_XLA = impl == "xla"
 
 
-def _kernel_wanted() -> bool:
-    global _BACKEND_IS_TPU
-    if _FORCE_XLA:
-        return False
-    if _BACKEND_IS_TPU is None:
-        _BACKEND_IS_TPU = jax.devices()[0].platform == "tpu"
-    return _BACKEND_IS_TPU
+def kernel_wanted() -> bool:
+    """Whether decode-shaped quantized matmuls dispatch the Pallas
+    kernels: on the TPU (utils/device.py, the one platform probe) unless
+    a mesh forced the XLA path (:func:`set_mm_impl`)."""
+    return on_tpu() and not _FORCE_XLA
 
 
 def _deq_once(q: jax.Array, s: jax.Array, dtype) -> jax.Array:
@@ -258,7 +257,7 @@ def mm(x: jax.Array, w) -> jax.Array:
         inner, layer = w.w, w.layer
         if isinstance(inner, QTensor):
             if (inner.q.ndim == 3 and rows <= _KERNEL_MAX_ROWS
-                    and _kernel_wanted()):
+                    and kernel_wanted()):
                 from ..ops.quant_mm import pick_block, quant_matmul_stacked
                 if pick_block(H) and pick_block(inner.q.shape[2]):
                     y = quant_matmul_stacked(x.reshape(rows, H), inner.q,
@@ -270,7 +269,7 @@ def mm(x: jax.Array, w) -> jax.Array:
             return mm(x, inner)
         if isinstance(inner, QTensor4):
             if (inner.q.ndim == 3 and rows <= _KERNEL_MAX_ROWS
-                    and _kernel_wanted()):
+                    and kernel_wanted()):
                 from ..ops.quant_mm import (pick_int4_bo,
                                             quant_matmul_stacked4)
                 if pick_int4_bo(rows, H, inner.q.shape[-1],
@@ -290,7 +289,7 @@ def mm(x: jax.Array, w) -> jax.Array:
         for d in lead:
             rows *= d
         O = w.q.shape[-1]
-        if w.q.ndim == 2 and rows <= _KERNEL_MAX_ROWS and _kernel_wanted():
+        if w.q.ndim == 2 and rows <= _KERNEL_MAX_ROWS and kernel_wanted():
             from ..ops.quant_mm import pick_int4_bo, quant_matmul4
             if pick_int4_bo(rows, H, O, w.s.shape[-2], x.dtype.itemsize):
                 y = quant_matmul4(x.reshape(rows, H), w.q, w.s)
@@ -306,7 +305,7 @@ def mm(x: jax.Array, w) -> jax.Array:
         rows = 1
         for d in lead:
             rows *= d
-        if w.q.ndim == 2 and rows <= _KERNEL_MAX_ROWS and _kernel_wanted():
+        if w.q.ndim == 2 and rows <= _KERNEL_MAX_ROWS and kernel_wanted():
             from ..ops.quant_mm import pick_block, quant_matmul
             if pick_block(H) and pick_block(w.q.shape[1]):
                 y = quant_matmul(x.reshape(rows, H), w.q, w.s)
@@ -342,7 +341,7 @@ def q_einsum(spec: str, x: jax.Array, w) -> jax.Array:
         if not isinstance(inner, (QTensor, QTensor4)):
             raise TypeError("LayerSlice wraps stacked QTensors only")
         if (inner.q.ndim == 4 and x.ndim == 3 and spec in _EXPERT_MM_SPECS
-                and x.shape[1] <= _KERNEL_MAX_ROWS and _kernel_wanted()):
+                and x.shape[1] <= _KERNEL_MAX_ROWS and kernel_wanted()):
             C, H = x.shape[1], x.shape[2]
             O = inner.q.shape[-1]
             if isinstance(inner, QTensor):

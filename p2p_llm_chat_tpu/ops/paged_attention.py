@@ -101,6 +101,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.device import on_tpu
 from ..utils.env import env_int, env_or
 
 NEG_INF = -1e30
@@ -408,7 +409,8 @@ def _append_kernel_wanted() -> bool:
 
 
 def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
-                           *, pages: int, interpret: bool = False):
+                           *, pages: int, interpret: bool = False,
+                           sharded: bool = False):
     """Decode attention where this step's k/v is NOT yet in the pool:
     attend over the pool window (positions < ``lengths``) and merge the
     current token's own (k_cur, v_cur) contribution with one exact
@@ -431,7 +433,9 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     q/k_cur/v_cur: [B, Hq|Hkv, D] (one token per row); cache: the
     PagedKVCache (bf16 or int8 pools); lengths: positions already in
     the pool per row (NOT including the current token). Returns
-    [B, Hq, D] in q.dtype.
+    [B, Hq, D] in q.dtype. ``sharded``: the pool is sharded over a mesh
+    (TP serving) — the Pallas kernels cannot consume it, so every
+    window stays on the XLA path.
 
     The XLA gather+merge below is the DEFAULT at short windows and
     everywhere on CPU (it measured fastest at short serving windows —
@@ -450,14 +454,15 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     B, Hq, D = q.shape
     Hkv = k_cur.shape[1]
     rep = Hq // Hkv
-    if _append_kernel_wanted():
+    if _append_kernel_wanted() and not sharded:
         return _paged_append_kernel_call(
             q, k_cur, v_cur, cache.k, cache.v, cache.k_scale,
             cache.v_scale, cache.page_table, lengths, layer, pages=pages,
             quantized=cache.k_scale is not None, interpret=interpret)
     W = pages * cache.k.shape[2]
     if not interpret and _flash_append_wanted(
-            W, cache.k.shape[3] * cache.k.shape[4]):
+            W, cache.k.shape[3] * cache.k.shape[4], sharded,
+            cache.k.shape[4]):
         # Long-window default (round-8): the (B, chunk)-grid flash
         # kernel reads each page exactly once per (layer, step) and
         # holds only bounded tiles in VMEM, so there is no multi-chunk
@@ -755,10 +760,8 @@ def _flash_append_policy(window: int, append_impl: str, min_w: int,
     once plus a per-chunk fixed cost that the hd-aware chunk budget
     AMORTISES OVER MORE TOKENS as hd shrinks (same VMEM bytes per
     chunk). Narrow-KV geometries therefore cross over earlier in
-    tokens: at bench-moe's hd=512 the boundary halves to W >= 1024 —
-    squarely inside the windows where BASELINE.md round-5 recorded the
-    ~1.3 ms MoE paged-walk gap the gather path was paying. The floor
-    keeps sub-2-chunk windows on gather everywhere.
+    tokens: at bench-moe's hd=512 the boundary halves to W >= 1024.
+    The floor keeps sub-2-chunk windows on gather everywhere.
     """
     if append_impl == "flash":
         return True
@@ -770,22 +773,49 @@ def _flash_append_policy(window: int, append_impl: str, min_w: int,
                          min_w * hd // _FLASH_HD_REF)
 
 
-def _flash_append_wanted(window: int, hd: int = _FLASH_HD_REF) -> bool:
-    if jax.devices()[0].platform != "tpu":
-        return False            # non-interpret pallas_call needs the TPU
+def flash_append_blocked(sharded: bool = False,
+                         head_dim: int = 128) -> str | None:
+    """Why the compiled flash-append kernel cannot run in this process
+    for this pool, or None when it can — the guard around
+    :func:`_flash_append_policy`, worded for the boot log:
+
+    - the compiled kernel needs the TPU (utils/device.py, the one
+      platform probe);
+    - ``pallas_call`` cannot consume a pool whose kv-head axis is
+      sharded over a mesh (``sharded`` — same policy as the prefill and
+      matmul kernels; the XLA gather path shards fine);
+    - Mosaic (libtpu 0.0.34) refuses the kernel's ``[Hkv, D] -> [Hkv*D]``
+      tile collapse unless ``head_dim`` fills whole 128-lane rows
+      ("infer-vector-layout: unsupported shape cast", seen on a v5e at
+      the ``tiny`` config's D=32, where the geometry-scaled boundary
+      engages the kernel from W=256)."""
+    if not on_tpu():
+        return "not on a TPU"
+    if sharded:
+        return "the pool is sharded over a mesh"
+    if head_dim % 128:
+        return f"head_dim {head_dim} is not a multiple of 128 lanes"
+    return None
+
+
+def _flash_append_wanted(window: int, hd: int = _FLASH_HD_REF,
+                         sharded: bool = False, head_dim: int = 128) -> bool:
+    if flash_append_blocked(sharded, head_dim):
+        return False
     return _flash_append_policy(window, _APPEND_IMPL,
                                 _flash_append_min_w(), hd)
 
 
-def effective_flash_min_w(hd: int = _FLASH_HD_REF) -> int:
+def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
+                          head_dim: int = 128) -> int:
     """The flash-append engagement boundary as ONE number, for gauges
     and logs (serve/scheduler.py's ``paged_flash_min_w``): 0 = the
-    kernel cannot engage in this process (non-TPU platform, disabled,
-    or the block-kernel override), 1 = the flash override (every
-    window), else the geometry-scaled min-W threshold for ``hd =
+    kernel cannot engage in this process (:func:`flash_append_blocked`,
+    disabled, or the block-kernel override), 1 = the flash override
+    (every window), else the geometry-scaled min-W threshold for ``hd =
     Hkv * head_dim`` (the scheduler passes its model's). Kept next to
     _flash_append_policy so the dispatch rule has exactly one home."""
-    if jax.devices()[0].platform != "tpu":
+    if flash_append_blocked(sharded, head_dim):
         return 0
     if _APPEND_IMPL == "flash":
         return 1

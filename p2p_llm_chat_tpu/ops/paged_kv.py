@@ -132,8 +132,8 @@ def shard_cache(cache: PagedKVCache, mesh,
                 tp_axis: str = "tp") -> PagedKVCache:
     """Shard the pool over kv heads (tp) — the memory-fit half of the
     tensor-parallel serving story: without it every chip holds the FULL
-    pool and TP cannot serve contexts one chip's HBM can't (VERDICT r3
-    weak #3). k/v shard dim 3 (Hkv of [L, N, ps, Hkv, D]); the head-major
+    pool and TP cannot serve contexts one chip's HBM can't. k/v shard
+    dim 3 (Hkv of [L, N, ps, Hkv, D]); the head-major
     scale arrays shard dim 2; page_table/lengths replicate (host-written
     per tick). Falls back to replication when Hkv doesn't divide tp
     (tiny test configs — same policy as parallel/sharding.constrain)."""

@@ -103,8 +103,7 @@ _X_VMEM_BUDGET_BYTES = 6 * 1024 * 1024
 # here so the decision survives budget retunes (grid depth 16 at
 # O=4096). Both derive from the same grid-depth arithmetic the
 # hidden=1024 probe measured; tools/check_quant_kernel.py carries the
-# expert-shape matrix for the on-chip confirmation (BASELINE.md
-# round-18 deferral).
+# expert-shape matrix for the on-chip confirmation.
 _TILE_TABLE = {1024: 256, 2816: 128, 11520: 256}
 
 

@@ -40,52 +40,6 @@ from .mesh import AXES, MeshConfig, make_mesh
 log = get_logger("parallel.distributed")
 
 
-def _enable_cpu_collectives() -> None:
-    """Multi-process on the CPU platform needs a cross-process collectives
-    backend. jax's ``jax_cpu_collectives_implementation`` defaults to
-    ``"none"`` (and reads no environment variable — it is settable only
-    via ``jax.config.update`` before the CPU client exists), under which
-    EVERY cross-process computation — including the one-int psum inside
-    ``multihost_utils.broadcast_one_to_all`` — dies with "Multiprocess
-    computations aren't implemented on the CPU backend". That was the
-    root cause of the test_multihost_serve / test_distributed failures
-    noted since round 8: the multihost serve front answered 500 at the
-    first POST because the leader's command broadcast could never run.
-    Flip the flag to gloo here, before ``jax.distributed.initialize``
-    touches any backend — and only when this process is explicitly
-    pinned to CPU (``jax_platforms``/``JAX_PLATFORMS``); accelerator
-    runs keep jax's default. Best-effort on purpose: a jax build
-    without the flag (or without gloo compiled in) just keeps its
-    default."""
-    plats = (getattr(jax.config, "jax_platforms", None)
-             or os.environ.get("JAX_PLATFORMS", "") or "")
-    if plats.split(",")[0].strip().lower() != "cpu":
-        return
-    # The flag holder is update()-able but NOT readable as a jax.config
-    # attribute (it is a Flag, not a State) — read the current value off
-    # the xla_bridge holder so an operator's explicit choice (e.g. mpi
-    # via absl flags) is never overridden. The read is best-effort in
-    # its OWN try: xla_bridge is private and has churned before; a
-    # moved/renamed holder must degrade to "assume unset" and still
-    # attempt the update below, not silently disable the whole fix
-    # (which would resurrect the exact "Multiprocess computations
-    # aren't implemented" failure this function root-caused).
-    cur = None
-    try:
-        from jax._src import xla_bridge as _xb
-        cur = _xb.CPU_COLLECTIVES_IMPLEMENTATION.value
-    except Exception:   # noqa: BLE001 — private module; treat as unset
-        pass
-    if cur not in (None, "none"):
-        return                      # operator chose an implementation
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        log.info("CPU platform multi-process: enabled gloo collectives")
-    except Exception:   # noqa: BLE001 — flag absent on older/newer jax
-        log.warning("no gloo CPU collectives in this jax build; "
-                    "multi-process CPU computations may be unsupported")
-
-
 def init_distributed(coordinator: Optional[str] = None,
                      num_processes: Optional[int] = None,
                      process_id: Optional[int] = None) -> bool:
@@ -99,7 +53,8 @@ def init_distributed(coordinator: Optional[str] = None,
         os.environ.get("JAX_PROCESS_ID", "-1"))
     if coordinator is None and n == 0:
         return False
-    _enable_cpu_collectives()
+    # CPU-pinned processes (the two-process tests) need no set-up: jax
+    # 0.9.0's jax_cpu_collectives_implementation already defaults to gloo.
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=n or None,

@@ -65,11 +65,11 @@ def make_mesh(cfg: MeshConfig, devices: list | None = None) -> Mesh:
     if cfg.size > len(devs):
         raise ValueError(f"mesh needs {cfg.size} devices, have {len(devs)}")
     if devices is None and cfg.size == len(devs):
-        try:
-            from jax.experimental import mesh_utils
-            return Mesh(mesh_utils.create_device_mesh(shape), AXES)
-        except Exception:   # noqa: BLE001 — topology helper is best-effort
-            pass
+        # A topology the helper cannot map raises: falling back to flat
+        # enumeration order would be a silent placement decision on
+        # real chips.
+        from jax.experimental import mesh_utils
+        return Mesh(mesh_utils.create_device_mesh(shape), AXES)
     return Mesh(np.array(devs[: cfg.size]).reshape(shape), AXES)
 
 

@@ -42,7 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..models.configs import ModelConfig
 from ..models.layers import (attend_gqa, causal_mask, length_mask, rms_norm,
@@ -155,7 +155,7 @@ def pp_prefill(params: dict, config: ModelConfig, tokens: jax.Array,
         device_fn, mesh=mesh,
         in_specs=(_stage_specs(params), P()),
         out_specs=(P(), P("pp"), P("pp")),
-        check_rep=False,
+        check_vma=False,
     )
     logits, ck, cv = mapped(params, tokens)
     return logits, KVCache(k=ck, v=cv, lengths=prompt_lens.astype(jnp.int32))
@@ -230,7 +230,7 @@ def pp_decode_step(params: dict, config: ModelConfig, tokens: jax.Array,
         device_fn, mesh=mesh,
         in_specs=(_stage_specs(params), P(), P("pp"), P("pp"), P()),
         out_specs=(P(), P("pp"), P("pp")),
-        check_rep=False,
+        check_vma=False,
     )
     logits, ck, cv = mapped(params, tokens, cache.k, cache.v, cache.lengths)
     inc = (jnp.ones_like(cache.lengths) if active is None

@@ -51,7 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..models.configs import ModelConfig
 from ..models.layers import NEG_INF, apply_rope, rms_norm, rope_frequencies
@@ -309,7 +309,7 @@ def ring_prefill(params: dict, config: ModelConfig, tokens: jax.Array,
         out_specs=(P(None, "sp", None),
                    P(None, None, "sp", "tp" if tp > 1 else None, None),
                    P(None, None, "sp", "tp" if tp > 1 else None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     logits, ck, cv = mapped(params, tokens)
     return logits, KVCache(k=ck, v=cv,
@@ -400,7 +400,7 @@ def sp_decode_step(params: dict, config: ModelConfig, tokens: jax.Array,
         in_specs=(ring_param_specs(_axes_for(config)), P(), kv_spec,
                   kv_spec, P()),
         out_specs=(P(), kv_spec, kv_spec),
-        check_rep=False,
+        check_vma=False,
     )
     logits, ck, cv = mapped(params, tokens, cache.k, cache.v, cache.lengths)
     inc = (jnp.ones_like(cache.lengths) if active is None

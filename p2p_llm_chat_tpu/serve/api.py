@@ -20,7 +20,7 @@ SURVEY.md §1 L4 note):
   checks.
 - ``GET  /metrics``       Prometheus-style counters: request counts, TTFT
   and total-latency summaries, tokens generated, in-flight gauge (the
-  benchmark metrics of BASELINE.md, in-tree per SURVEY.md §5).
+  benchmark metrics of PERF.md, in-tree per SURVEY.md §5).
 """
 
 from __future__ import annotations
@@ -81,8 +81,7 @@ class OllamaServer:
                              f"{self.replica_class!r}")
         # Eager FAIL_POINTS parse: a malformed chaos config must fail
         # HERE, at boot, not as a ValueError at some arbitrary deep
-        # failpoint() mid-serving (where it would surface as one buried
-        # warmup-failure log line and a permanently-warming /readyz).
+        # failpoint() mid-serving.
         _failpoints.load_env()
         # 11434 is Ollama's default port; SERVE_ADDR overrides.
         self.addr_cfg = addr if addr is not None else env_or("SERVE_ADDR", "127.0.0.1:11434")
@@ -183,6 +182,12 @@ class OllamaServer:
         Draining (the replica-router retire path) is not-ready with its
         own status so an operator can tell it from warming."""
         cls = self.replica_class
+        err = self._backend_failed()
+        if err:
+            # Terminal (a warmup the compiler refused): 500, no
+            # Retry-After — polling will not help; serve_forever exits.
+            return Response(500, {"status": "failed", "error": err,
+                                  "class": cls})
         if self._draining.is_set():
             return Response(503, {"status": "draining", "class": cls},
                             headers={"Retry-After": "5"})
@@ -196,6 +201,10 @@ class OllamaServer:
             return Response(200, {"status": "ready", "class": cls})
         return Response(503, {"status": "warming", "class": cls},
                         headers={"Retry-After": "2"})
+
+    def _backend_failed(self) -> Optional[str]:
+        fn = getattr(self.backend, "failed", None)
+        return fn() if callable(fn) else None
 
     def _drain(self, req: Request) -> Response:
         """POST /admin/drain: stop taking new sessions (503 + Retry-After
@@ -949,8 +958,18 @@ class OllamaServer:
         return self._server.url
 
     def serve_forever(self) -> None:
+        """Serve until killed — or until the backend reports a terminal
+        failure (a failed warmup), which ends the PROCESS non-zero: a
+        launcher waiting on /readyz sees a dead child at once instead of
+        polling a server that can never go ready."""
         self.start()
-        threading.Event().wait()
+        while True:
+            time.sleep(1.0)
+            err = self._backend_failed()
+            if err:
+                log.error("backend failed (%s); exiting", err)
+                self.stop()
+                raise SystemExit(1)
 
     def stop(self) -> None:
         if self._server:
@@ -958,8 +977,9 @@ class OllamaServer:
 
 
 def main() -> None:
-    """Entry point: serve FakeLLM (real engine wiring arrives with
-    serve.engine; SERVE_BACKEND=fake|tpu selects).
+    """Entry point: ``SERVE_BACKEND=fake`` (default) serves FakeLLM,
+    ``tpu`` the real engine (serve/engine.py — on the TPU, or boot
+    fails; ``JAX_PLATFORMS=cpu`` pins the CPU on purpose).
 
     Multi-host mode switch (docs/serving.md Round-10): setting
     ``SERVE_ROUTER_UPSTREAMS`` starts the replica router

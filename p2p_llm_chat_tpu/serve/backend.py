@@ -93,8 +93,9 @@ class GenerateRequest:
 
 @dataclass
 class RequestStats:
-    """Per-request timing — the north-star metric is p50 TTFT (BASELINE.md),
-    so timing is in-tree from day one (SURVEY.md §5 tracing)."""
+    """Per-request timing — the north-star metric is p50 TTFT
+    (BASELINE.json), so timing is in-tree from day one (SURVEY.md §5
+    tracing)."""
 
     ttft_s: Optional[float] = None        # arrival -> first token
     total_s: Optional[float] = None

@@ -315,7 +315,7 @@ def build_class_autoscaler() -> ClassAutoscaler:
     class on disjoint port ranges (prefill at
     ``SERVE_ROUTER_AUTOSCALE_PORT_BASE``, decode just above its
     ceiling), each child tagged via ``SERVE_REPLICA_CLASS``."""
-    from .router import ProcessReplicaSpawner
+    from .router import ProcessReplicaSpawner, chip_pool_from_env
     base = env_int("SERVE_ROUTER_AUTOSCALE_PORT_BASE", 11500)
     mx = env_int("SERVE_ROUTER_AUTOSCALE_MAX", 4)
     # Each class gets a HARD-BOUNDED range of 4x its replica ceiling
@@ -324,12 +324,13 @@ def build_class_autoscaler() -> ClassAutoscaler:
     # makes cross-range walks impossible by construction; start_all.py
     # reserves the same 8x span against node/UI collisions.
     width = 4 * mx
+    chips = chip_pool_from_env()     # one pool: the classes share a host
     spawners = {
         "prefill": ProcessReplicaSpawner(
-            port_base=base, max_ports=width,
+            port_base=base, max_ports=width, chips=chips,
             env_extra={"SERVE_REPLICA_CLASS": "prefill"}),
         "decode": ProcessReplicaSpawner(
-            port_base=base + width, max_ports=width,
+            port_base=base + width, max_ports=width, chips=chips,
             env_extra={"SERVE_REPLICA_CLASS": "decode"}),
     }
 

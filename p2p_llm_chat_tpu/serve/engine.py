@@ -15,9 +15,14 @@ Two provisioning paths (build_engine_from_env):
   (models/weights.py, tokenizer.py) — the production path for real llama3 /
   Mixtral weights.
 - no checkpoint: randomly-initialised weights for ``MODEL_CONFIG`` (default
-  ``tiny``) + the byte tokenizer, so the full serving stack runs anywhere —
-  the same graceful no-artifacts posture as FakeLLM, but exercising every
-  real device code path.
+  ``tiny``) + the byte tokenizer, so the full serving stack runs with no
+  artifacts — the same posture as FakeLLM, but exercising every real
+  device code path.
+
+The engine serves on the TPU. It boots on the CPU only when the
+operator pins ``JAX_PLATFORMS=cpu`` (the test suite does); any other
+non-TPU platform fails the boot (utils/device.require_tpu) instead of
+serving from wherever JAX fell back to.
 
 Env surface (reference-style env-first config, utils/env.py):
 ``SERVE_BACKEND=tpu``, ``CKPT_DIR``, ``MODEL_CONFIG``, ``SERVE_SLOTS``,
@@ -66,6 +71,7 @@ from ..models.configs import get_config
 from ..models import family_for
 from ..models.weights import load_checkpoint
 from ..tokenizer import ByteTokenizer, load_tokenizer
+from ..utils.device import bytes_in_use, device_info, require_tpu
 from ..utils.env import env_bool, env_float, env_int, env_or
 from ..utils.log import get_logger
 from .backend import Backend, GenerateRequest, RequestStats
@@ -258,8 +264,9 @@ class TPUEngine:
             try:
                 self.scheduler.warmup(prompt_buckets=buckets,
                                       prefix_texts=self.prefix_texts)
-            except Exception:   # noqa: BLE001 — warmup is best-effort
-                log.exception("warmup failed")
+            except Exception:   # noqa: BLE001 — recorded, see failed()
+                log.exception("warmup failed — this engine will never "
+                              "report ready")
 
         if background:
             # Not-ready from THIS call, not from when the thread gets
@@ -280,10 +287,27 @@ class TPUEngine:
         requests' TTFT)."""
         return self.scheduler.ready
 
+    def failed(self) -> Optional[str]:
+        """Terminal failure for /readyz and the process entry point: the
+        warmup raised (a program the compiler refused, a dead device),
+        so this engine can never go ready. serve/api.py answers 500 on
+        /readyz and exits non-zero — a launcher must see a dead child,
+        not poll a 503-warming server until its deadline."""
+        return self.scheduler.warmup_error
+
     def metrics_snapshot(self) -> dict[str, float]:
         """Serving-plane gauges (batch occupancy, queue depth, KV pool)
-        merged into the API front's /metrics (serve/api.py)."""
-        return self.scheduler.metrics_snapshot()
+        merged into the API front's /metrics (serve/api.py), plus the
+        device this process computes on — the only place an operator
+        (or chip_smoke.py) can read which platform actually serves."""
+        out = self.scheduler.metrics_snapshot()
+        info = device_info()
+        out[f'serve_device_info{{platform="{info["platform"]}",'
+            f'device_kind="{info["device_kind"]}",'
+            f'count="{info["count"]}"}}'] = 1
+        for i, n in enumerate(bytes_in_use()):
+            out[f'serve_device_bytes{{device="{i}"}}'] = n
+        return out
 
     # -- grafttrace (obs/, round 15) -----------------------------------------
 
@@ -363,9 +387,18 @@ class TPUEngine:
         self.scheduler.stop()
 
 
+def log_device(after: str) -> None:
+    """The boot line that names the device: platform, kind, count and
+    allocated bytes per device (weights + KV pool after a load)."""
+    info = device_info()
+    log.info("device: platform=%s kind=%r count=%d bytes_in_use=%s (%s)",
+             info["platform"], info["device_kind"], info["count"],
+             bytes_in_use(), after)
+
+
 def build_engine_from_env() -> Backend:
-    """Engine from env vars; falls back to a random tiny model + byte
-    tokenizer when no checkpoint is configured (runs anywhere).
+    """Engine from env vars; a random tiny model + byte tokenizer when
+    no checkpoint is configured.
 
     ``SERVE_COORDINATOR`` (or the JAX_COORDINATOR/... trio) switches to
     the multi-host SPMD engine: every process joins the distributed
@@ -373,11 +406,12 @@ def build_engine_from_env() -> Backend:
     process 0 serves HTTP, the rest mirror its programs
     (serve/multihost.py — api.main() dispatches follower_loop)."""
     from ..utils.jax_cache import enable_persistent_cache
-    enable_persistent_cache()   # 8B warmup: ~18 min cold -> cache reads
+    enable_persistent_cache()
     coord = env_or("SERVE_COORDINATOR", "") or None
     if coord or env_or("JAX_COORDINATOR", ""):
         from .multihost import build_multihost_engine
         return build_multihost_engine(coord)
+    require_tpu("SERVE_BACKEND=tpu")
     ckpt_dir = env_or("CKPT_DIR", "")
     num_slots = env_int("SERVE_SLOTS", 8)
     max_seq = env_int("SERVE_MAX_SEQ", 1024)
@@ -443,9 +477,17 @@ def build_engine_from_env() -> Backend:
         log.info("jax.profiler server on :%d", prof_port)
 
     mesh = None
+    n_dev = len(jax.devices())
     if tp > 1:
         from ..parallel.mesh import local_mesh
         mesh = local_mesh(tp=tp)
+        if n_dev > tp:
+            log.warning("SERVE_TP=%d on %d devices: the mesh adds a dp axis "
+                        "of %d over the rest (weights replicated across "
+                        "it)", tp, n_dev, n_dev // tp)
+    elif n_dev > 1:
+        log.warning("%d devices are visible and SERVE_TP is unset: the "
+                    "model and its KV pool live on device 0 alone", n_dev)
 
     quant = env_or("SERVE_QUANT", "")
     if quant not in ("", "int8", "int4"):
@@ -470,10 +512,21 @@ def build_engine_from_env() -> Backend:
             return family.init_params_quantized(config,
                                                 jax.random.PRNGKey(seed),
                                                 quant=quant)
-        params = family.init_params(config, jax.random.PRNGKey(seed))
         if mesh is not None:
-            from ..parallel.sharding import shard_params
-            params = shard_params(params, family.param_axes(config), mesh)
+            # Generate every leaf straight into its shards: the eager
+            # init materialises whole f32 leaves on device 0 (7.5 GB for
+            # one 8B MLP stack) before anything is sharded.
+            from jax.sharding import NamedSharding, PartitionSpec
+            from ..parallel.sharding import tree_specs
+            shardings = jax.tree.map(
+                lambda spec: NamedSharding(mesh, spec),
+                tree_specs(family.param_axes(config)),
+                is_leaf=lambda x: isinstance(x, PartitionSpec))
+            params = jax.jit(lambda k: family.init_params(config, k),
+                             out_shardings=shardings)(
+                                 jax.random.PRNGKey(seed))
+        else:
+            params = family.init_params(config, jax.random.PRNGKey(seed))
         if quant:
             from ..models.quant import quantize_params
             params = quantize_params(params, mesh=mesh, mode=quant)
@@ -664,6 +717,7 @@ def build_engine_from_env() -> Backend:
                                             config, tokenizer, name=tag)
         multi = MultiBackend(backends, default=specs[0][0])
         log.info("multi-model serving: %s", ", ".join(multi.models()))
+        log_device("weights and KV pools loaded")
         buckets = warmup_buckets()
         if buckets:
             multi.warmup(buckets, background=True)
@@ -683,6 +737,7 @@ def build_engine_from_env() -> Backend:
         tokenizer = ByteTokenizer(vocab_size=config.vocab_size)
         engine = make_engine(params, config, tokenizer,
                              name=env_or("LLM_MODEL", config.name))
+    log_device("weights and KV pool loaded")
     buckets = warmup_buckets()
     if buckets:
         engine.warmup(buckets, background=True)

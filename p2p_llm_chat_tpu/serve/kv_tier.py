@@ -3,8 +3,8 @@
 The north-star workload is millions of chat sessions that are idle
 between turns, but a session's KV historically lived in HBM for the
 request's lifetime and evaporated at finish — a follow-up turn re-paid
-the whole history's prefill. At measured KV economics (16 KB/token int8
-on bench-moe, BASELINE.md) HBM bounds *open* sessions long before it
+the whole history's prefill. At KV economics of 16 KB/token (int8,
+bench-moe) HBM bounds *open* sessions long before it
 bounds *decoding* sessions; pinned host RAM is ~50x larger per chip.
 This module adds the vLLM-style memory hierarchy on top of the paged
 pool (ops/paged_kv.py):

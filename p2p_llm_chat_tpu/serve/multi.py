@@ -87,6 +87,16 @@ class MultiBackend:
                 return False
         return True
 
+    def failed(self) -> Optional[str]:
+        """First engine's terminal failure (engine.TPUEngine.failed), or
+        None: the front serves every tag or exits."""
+        for tag, b in self.backends.items():
+            fn = getattr(b, "failed", None)
+            err = fn() if callable(fn) else None
+            if err:
+                return f"{tag}: {err}"
+        return None
+
     def warmup(self, *args, **kwargs) -> None:
         for b in self.backends.values():
             fn = getattr(b, "warmup", None)

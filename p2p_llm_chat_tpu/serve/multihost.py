@@ -659,6 +659,10 @@ def build_multihost_engine(coordinator: Optional[str]) -> MultihostEngine:
     if not init_distributed(coordinator=coordinator):
         raise SystemExit("SERVE_COORDINATOR set but distributed init "
                          "failed (need JAX_NUM_PROCESSES/JAX_PROCESS_ID)")
+    # After the handshake: probing devices earlier would initialise the
+    # backend before jax.distributed.initialize.
+    from ..utils.device import require_tpu
+    require_tpu("SERVE_BACKEND=tpu (multihost)")
     tp = env_int("SERVE_TP", 1)
     n_dev = len(jax.devices())
     if n_dev % tp:

@@ -121,6 +121,7 @@ from typing import Iterator, Optional
 
 from ..obs import trace as _trace
 from ..utils import backoff as _backoff
+from ..utils.chips import ChipPool, chip_env, cpu_pinned
 from ..utils.env import env_bool, env_float, env_int, env_or
 from ..utils.failpoints import failpoint
 from ..utils.http import HttpServer, Request, Response, Router
@@ -1733,6 +1734,13 @@ class Autoscaler:
             fn()
 
 
+def chip_pool_from_env() -> ChipPool:
+    """``SERVE_ROUTER_AUTOSCALE_CHIPS`` (comma-separated chip indices)
+    as a pool; unset = no chips, so TPU spawns are refused."""
+    raw = env_or("SERVE_ROUTER_AUTOSCALE_CHIPS", "")
+    return ChipPool([int(c) for c in raw.split(",") if c.strip()])
+
+
 class ProcessReplicaSpawner:
     """The env-path spawner (``SERVE_ROUTER_AUTOSCALE=1``): replicas as
     ``python -m p2p_llm_chat_tpu.serve.api`` subprocesses on successive
@@ -1740,11 +1748,18 @@ class ProcessReplicaSpawner:
     router's environment (minus the mode flags a replica must never
     see) — so SERVE_BACKEND/CKPT_DIR/SERVE_KV* flow through and a
     spawned replica is a full-stack engine. Retirement only applies to
-    replicas this spawner created; boot upstreams are the operator's."""
+    replicas this spawner created; boot upstreams are the operator's.
+
+    TPU replicas (``SERVE_BACKEND=tpu``, JAX not pinned to the CPU) get
+    one chip each from ``chips`` — by default the indices listed in
+    ``SERVE_ROUTER_AUTOSCALE_CHIPS`` (start_all.py passes the chips its
+    fixed replicas left over) — and with no free chip the spawn is
+    REFUSED: a replica that shares a chip comes up on the CPU."""
 
     def __init__(self, port_base: Optional[int] = None,
                  env_extra: Optional[dict] = None,
-                 max_ports: int = 0) -> None:
+                 max_ports: int = 0,
+                 chips: Optional[ChipPool] = None) -> None:
         self.port_base = (port_base if port_base is not None else
                           env_int("SERVE_ROUTER_AUTOSCALE_PORT_BASE",
                                   11500))
@@ -1768,11 +1783,22 @@ class ProcessReplicaSpawner:
         # range start_all.py's collision check reserved — a monotonic
         # walk would leave it after max_replicas lifetime spawns.
         self._free_ports: list[int] = []      # guarded-by: _mu
+        self.chips = chips if chips is not None else chip_pool_from_env()
+        self._chip_of: dict[str, int] = {}    # guarded-by: _mu (url -> chip)
 
     def __call__(self) -> Optional[str]:
         import os
         import subprocess
         import sys
+        chip = None
+        if env_or("SERVE_BACKEND", "fake") == "tpu" and not cpu_pinned():
+            chip = self.chips.take()
+            if chip is None:
+                log.warning("no free TPU chip for another replica (one "
+                            "process per chip; SERVE_ROUTER_AUTOSCALE_CHIPS "
+                            "lists the chips this spawner may use); "
+                            "refusing this spawn")
+                return None
         with self._mu:
             if self._free_ports:
                 self._free_ports.sort()
@@ -1787,22 +1813,29 @@ class ProcessReplicaSpawner:
                         "killed spawns leak their slot until reaped); "
                         "skipping this spawn", self.port_base,
                         self.port_base + self.max_ports)
+            if chip is not None:
+                self.chips.give(chip)
             return None
         url = f"http://127.0.0.1:{port}"
         env = {**os.environ,
                "SERVE_ADDR": f"127.0.0.1:{port}",
                "SERVE_ROUTER_UPSTREAMS": "",
                "SERVE_COORDINATOR": "",
-               **self.env_extra}
+               **self.env_extra,
+               **(chip_env(chip) if chip is not None else {})}
         try:
             proc = subprocess.Popen(
                 [sys.executable, "-m", "p2p_llm_chat_tpu.serve.api"],
                 env=env)
         except Exception:   # noqa: BLE001 — a failed spawn skips the pass
             log.exception("autoscale replica spawn failed")
+            if chip is not None:
+                self.chips.give(chip)
             return None
         with self._mu:
             self._procs[url] = proc
+            if chip is not None:
+                self._chip_of[url] = chip
         return url
 
     def can_retire(self, url: str) -> bool:
@@ -1835,6 +1868,10 @@ class ProcessReplicaSpawner:
                 log.warning("retired replica %s ignored SIGKILL; "
                             "abandoning (port not reused)", url)
                 return
+        with self._mu:
+            chip = self._chip_of.pop(url, None)
+        if chip is not None:
+            self.chips.give(chip)     # the exit was observed: chip is free
         try:
             port = int(url.rsplit(":", 1)[1])
         except ValueError:

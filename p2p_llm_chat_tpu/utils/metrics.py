@@ -1,7 +1,7 @@
 """In-tree metrics: counters, gauges, and latency histograms.
 
 The reference has no observability beyond stdout logs (SURVEY.md §5); the
-serving benchmarks (tokens/sec/chip, p50 TTFT — BASELINE.md) *are* metrics,
+serving benchmarks (tokens/sec/chip, p50 TTFT — PERF.md) *are* metrics,
 so they are first-class here. Prometheus-style text rendering on /metrics;
 percentiles computed from a bounded reservoir.
 """

@@ -31,6 +31,7 @@ import tempfile
 import time
 import urllib.request
 
+from p2p_llm_chat_tpu.utils.chips import chip_env, count_chips, cpu_pinned
 from p2p_llm_chat_tpu.utils.env import env_float, env_int, env_or
 
 REPO_ROOT = os.path.dirname(os.path.abspath(__file__))
@@ -203,6 +204,23 @@ def main() -> int:
     check_port_ranges(len(users), args.node_port_base, args.ui_port_base,
                       args.dir_port, args.serve_port,
                       fixed_replicas + scale_room)
+    # One chip per TPU replica: a second process on a taken chip would
+    # come up on the CPU (and then refuse to boot — utils/device.py), so
+    # replica i is confined to chip i and a fleet wider than the host's
+    # chips is refused here, before anything starts. The launcher itself
+    # never touches JAX. (JAX_PLATFORMS=cpu — tests, demos — pins the
+    # replicas to the CPU on purpose; a single engine keeps every chip
+    # for SERVE_TP.)
+    chip_fleet = (args.backend == "tpu" and fixed_replicas >= 2
+                  and not cpu_pinned())
+    if chip_fleet:
+        chips = count_chips()
+        if fixed_replicas > chips:
+            raise SystemExit(
+                f"--backend tpu: {fixed_replicas} replicas need "
+                f"{fixed_replicas} chips, this host has {chips} — one "
+                "process per chip (export JAX_PLATFORMS=cpu to run the "
+                "fleet on the CPU on purpose)")
     procs: list[tuple[str, subprocess.Popen]] = []
 
     def shutdown(*_, exit_code: int = 0):
@@ -255,7 +273,8 @@ def main() -> int:
                        # inherit router/lockstep mode flags.
                        "SERVE_REPLICA_CLASS": role,
                        "SERVE_ROUTER_UPSTREAMS": "",
-                       "SERVE_COORDINATOR": ""}, procs)
+                       "SERVE_COORDINATOR": "",
+                       **(chip_env(i) if chip_fleet else {})}, procs)
             router_env = {"SERVE_ADDR": f"127.0.0.1:{args.serve_port}",
                           "SERVE_ROUTER_UPSTREAMS": ",".join(upstreams),
                           "SERVE_REPLICA_CLASS": ""}
@@ -274,6 +293,11 @@ def main() -> int:
                     "SERVE_BACKEND": args.backend,
                     "SERVE_PREFILL_REPLICAS": str(max(0, args.prefill)),
                     "SERVE_DECODE_REPLICAS": str(max(0, args.decode)),
+                    # The chips the fixed replicas left over are all
+                    # the autoscaler may hand out (none: it refuses).
+                    "SERVE_ROUTER_AUTOSCALE_CHIPS": ",".join(
+                        str(c) for c in range(fixed_replicas, chips))
+                    if chip_fleet else "",
                 })
             spawn("serve-router", "p2p_llm_chat_tpu.serve.router",
                   router_env, procs)

@@ -270,6 +270,42 @@ def test_readyz_gates_on_backend_probe():
         srv.stop()
 
 
+def test_failed_backend_is_terminal_500_and_ends_serve_forever():
+    """A backend reporting a terminal failure (a failed warmup) answers
+    /readyz 500 "failed" — not a 503 a launcher would keep polling —
+    and serve_forever ends the process non-zero."""
+    class Failing(FakeLLM):
+        err = None
+
+        def failed(self):
+            return self.err
+
+    backend = Failing()
+    srv = OllamaServer(backend, addr="127.0.0.1:0")
+    done = {}
+
+    def serve():
+        try:
+            srv.serve_forever()
+        except SystemExit as e:
+            done["code"] = e.code
+
+    t = threading.Thread(target=serve, daemon=True)
+    t.start()
+    deadline = time.monotonic() + 5
+    while srv._server is None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    status, body = http_json("GET", f"{srv.url}/readyz")
+    assert status == 200
+    backend.err = "XlaRuntimeError: the compiler refused a kernel"
+    status, body = http_json("GET", f"{srv.url}/readyz",
+                             raise_for_status=False)
+    assert status == 500 and body["status"] == "failed"
+    assert "compiler refused" in body["error"]
+    t.join(timeout=5)
+    assert not t.is_alive() and done["code"] == 1
+
+
 def test_readyz_default_ready_without_probe(server):
     status, body = http_json("GET", f"{server.url}/readyz")
     assert status == 200 and body["status"] == "ready"
@@ -428,6 +464,30 @@ def test_engine_readiness_semantics(engine):
         assert engine.ready() is True
     finally:
         sched._warmup_started, sched._warmup_done_at = False, 0.0
+
+
+@pytest.mark.model
+def test_warmup_that_raises_marks_the_engine_failed(engine):
+    """A warmup job that raises (here the admit failpoint; on the chip,
+    a program the compiler refuses) is terminal: the engine reports the
+    failure, never goes ready, and the jobs queued behind the failed
+    one are skipped instead of compiled."""
+    sched = engine.scheduler
+    hits = fp.snapshot().get("serve.scheduler.admit", 0)
+    fp.arm("serve.scheduler.admit", "raise")
+    try:
+        engine.warmup((32,), background=False)
+        assert "FailpointError" in engine.failed()
+        assert engine.ready() is False
+        # Only the first job ran: the rest of the ladder was void.
+        assert fp.snapshot()["serve.scheduler.admit"] == hits + 1
+    finally:
+        fp.disarm_all()
+        sched.warmup_error = None
+        sched._warmup_started, sched._warmup_done_at = False, 0.0
+    assert engine.failed() is None and engine.ready() is True
+    text, _ = run(engine, "after the failed warmup", max_tokens=4)
+    assert text == oracle("after the failed warmup", 4)
 
 
 @pytest.mark.model

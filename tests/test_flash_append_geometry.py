@@ -201,13 +201,32 @@ def test_dispatch_policy_table(monkeypatch):
     assert pa._flash_append_min_w() == 4096
     monkeypatch.setenv("PAGED_APPEND_FLASH_MIN_W", "")
     assert pa._flash_append_min_w() == 2048      # empty = unset
-    # CPU CI: the platform guard must hold regardless of the policy,
-    # and the gauge helper (serve/scheduler.py `paged_flash_min_w`)
-    # must report "cannot engage" = 0.
-    if jax.devices()[0].platform != "tpu":
-        monkeypatch.delenv("PAGED_APPEND_FLASH_MIN_W", raising=False)
+    monkeypatch.delenv("PAGED_APPEND_FLASH_MIN_W", raising=False)
+    if not pa.on_tpu():
+        # CPU CI: the platform guard must hold regardless of the policy,
+        # and the gauge helper (serve/scheduler.py `paged_flash_min_w`)
+        # must report "cannot engage" = 0.
         assert not pa._flash_append_wanted(1 << 20)
         assert pa.effective_flash_min_w() == 0
+        monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    # On the TPU — the probe patched open above, or for real on the chip
+    # (`python -m pytest --noconftest tests/test_flash_append_geometry.py
+    # -k dispatch_policy`; conftest would pin the CPU) — the guard opens
+    # at the geometry-scaled boundary, and shuts again for a pool
+    # sharded over a mesh (pallas_call cannot consume one).
+    assert pa._flash_append_wanted(2048) and not pa._flash_append_wanted(1024)
+    assert pa._flash_append_wanted(1024, 512)
+    assert pa.effective_flash_min_w() == 2048
+    assert pa.effective_flash_min_w(512) == 1024
+    assert not pa._flash_append_wanted(1 << 20, sharded=True)
+    assert pa.effective_flash_min_w(sharded=True) == 0
+    # ... and for a head_dim that does not fill 128-lane rows: Mosaic
+    # refuses the kernel there (seen on the chip at tiny's D=32, where
+    # the scaled boundary would engage it from W=256).
+    assert not pa._flash_append_wanted(1 << 20, 64, head_dim=32)
+    assert pa.effective_flash_min_w(64, head_dim=32) == 0
+    assert "128 lanes" in pa.flash_append_blocked(head_dim=32)
+    assert pa.flash_append_blocked(head_dim=128) is None
 
 
 # -- long-window matrix (ci.sh full mode) -------------------------------------

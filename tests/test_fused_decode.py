@@ -80,8 +80,7 @@ def _plain_step_dense(tokens, cache, active, keys, ring):
 def _plain_step_paged(tokens, cache, active, keys, ring):
     emit_pos = cache.lengths + 1
     logits, cache = llama.decode_step_paged(
-        PARAMS, CFG, tokens, cache, active=active, pages=MAX_SEQ // 16,
-        interpret=True)
+        PARAMS, CFG, tokens, cache, active=active, pages=MAX_SEQ // 16)
     toks, keys, ring = sample_step_batched(
         logits[:, 0, :], keys, TEMPS, TOP_KS, TOP_PS, ring=ring, rp=RPS,
         emit_pos=emit_pos, active=active)
@@ -144,7 +143,7 @@ def _run_both(first, cache, keys, ring, stop, *, paged, pages=None):
     kwargs = dict(num_steps=K, sample_fn=_sample_fn,
                   sample_state=(keys, ring), stop_ids=stop, active=active)
     if paged:
-        kwargs.update(pages=pages, interpret=True)
+        kwargs.update(pages=pages)
     else:
         kwargs.update(kv_window=MAX_SEQ)
     fused = jax.jit(

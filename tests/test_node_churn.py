@@ -192,8 +192,10 @@ def test_send_joins_parked_backlog_preserving_order():
         # Pin the backlog: the worker can't re-resolve while this is
         # armed, but /send's direct path (dir.lookup) still can — the
         # exact shape of the bug: recipient reachable, backlog parked.
-        fp.disarm("p2p.node.deliver")
+        # (Armed BEFORE the deliver fault lifts: in between, the
+        # worker's first backoff tick could drain the backlog.)
         fp.arm("p2p.node.resolve", "raise")
+        fp.disarm("p2p.node.deliver")
         _, resp = http_json("POST", f"{a.http_url}/send",
                             {"to_username": "cannan", "content": "second"},
                             timeout=20.0)

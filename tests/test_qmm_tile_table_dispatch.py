@@ -162,10 +162,9 @@ def _spy(monkeypatch, name):
 
 @pytest.fixture
 def on_tpu(monkeypatch):
-    """Make _kernel_wanted() answer True on the CPU test host (the
-    backend probe is cached; the decision logic under test is
-    backend-independent)."""
-    monkeypatch.setattr(quant, "_BACKEND_IS_TPU", True)
+    """Make kernel_wanted() answer True on the CPU test host (the
+    decision logic under test is backend-independent)."""
+    monkeypatch.setattr(quant, "on_tpu", lambda: True)
     monkeypatch.setattr(quant, "_FORCE_XLA", False)
 
 

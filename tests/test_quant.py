@@ -235,7 +235,7 @@ def test_init_params_quantized_streams_to_fused_int8():
     """Streaming random init (models/llama.init_params_quantized) yields
     an already-fused int8 tree: fuse_params is a no-op, decode runs, and
     the quantisation error bound holds per leaf (the path that lets the
-    8B config fit one 16 GB chip — VERDICT r3 #1)."""
+    8B config fit one 16 GB chip)."""
     import jax
     import jax.numpy as jnp
     from p2p_llm_chat_tpu.models import llama

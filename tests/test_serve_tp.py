@@ -123,7 +123,7 @@ def test_tp_engine_with_prefix_and_spec():
 
 
 def test_tp_pool_and_fused_weights_are_sharded():
-    """VERDICT r3 weak #3: TP serving must actually PLACE the paged pool
+    """TP serving must actually PLACE the paged pool
     and the fused projections across the mesh — correctness alone
     (above) can hide silent replication, which breaks the memory-fit
     story that motivates TP. tiny-tp's 4 kv heads divide tp=2, so the

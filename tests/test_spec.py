@@ -443,7 +443,7 @@ def test_repeat_penalty_across_full_window(spec_k):
 def test_quote_params_greedy_follows_printable_cycle():
     """models/synth.quote_params: greedy decode follows the printable
     successor cycles (the property that makes prompt-lookup drafts land
-    and suggestion streams decode as text — BASELINE.md round 4)."""
+    and suggestion streams decode as text)."""
     import numpy as np
     import jax
     import jax.numpy as jnp

@@ -1,8 +1,7 @@
 """Coverage for the production checkpoint path: weights.load_checkpoint.
 
-VERDICT round 1 flagged that only the in-memory ``convert_hf_state_dict``
-oracle was tested while the safetensors-directory path serving actually
-uses had zero coverage. These tests write tiny HF-layout checkpoints
+The in-memory ``convert_hf_state_dict`` oracle alone would leave the
+safetensors-directory path serving actually uses uncovered. These tests write tiny HF-layout checkpoints
 (config.json + sharded ``*.safetensors``) to disk with
 ``safetensors.numpy.save_file`` and require ``load_checkpoint`` to
 reproduce the convert-path tree exactly — dense and MoE, unsharded and

@@ -1,11 +1,16 @@
-"""TPU parity check: Pallas append-attention kernels vs XLA gather path.
+"""TPU parity check: the Pallas attention kernels vs their XLA paths.
 
 Runs both Pallas implementations of
 ops/paged_attention.paged_attention_append — the round-4 gathered-window
 block kernel and the round-8 multi-chunk flash-append kernel — on the
-real chip over random pools (bf16 and int8) and asserts closeness to
-the gather path. CPU tests can't cover the Mosaic lowering; this is the
-hardware check.
+real chip over random pools (bf16 and int8) and checks closeness to
+the gather path; then the two write-then-attend decode kernels behind
+``PAGED_ATTN_IMPL=kernel|flash``, and the prefill flash kernel
+(models/layers.attend_gqa_causal0). CPU tests can't cover the Mosaic
+lowering; this is the hardware check. Shapes have llama3.1-8b's
+attention geometry (32 query / 8 kv heads x 128, page size 64, B=32).
+Every kernel runs to the end and gets one ``VERDICT <kernel>:
+PASS|FAIL`` line; the exit code is non-zero when any FAILs.
 """
 
 from __future__ import annotations
@@ -22,6 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import importlib  # noqa: E402
 
 from p2p_llm_chat_tpu.models.configs import get_config  # noqa: E402
+from tools.kernel_verdicts import require_tpu, run_cases  # noqa: E402
 
 # The ops package __init__ rebinds `paged_attention` to the function;
 # importlib reaches the module.
@@ -55,8 +61,11 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
     the cross-chunk scratch merge, slot parity through row boundaries,
     and the clamped partial chunk all execute on real Mosaic, not just
     in interpret mode)."""
-    cfg = get_config("bench-1b")
+    # Two layers are all the check reads (first and last), and what
+    # keeps the bf16 pool at W=3072 x B=32 inside a 16 GB chip.
+    cfg = get_config("llama3.1-8b").with_(num_layers=2)
     rng = np.random.default_rng(seed)
+    key = jax.random.PRNGKey(seed)
     mppr = pages
     num_pages = B * mppr + 1
     cache = PagedKVCache.create(cfg, B, num_pages, ps,
@@ -67,10 +76,14 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
         n = int(rng.integers(1, pages * ps - 1))
         lengths.append(n)
         table = jnp.asarray(1 + b * mppr + np.arange(mppr), jnp.int32)
-        rk = jnp.asarray(rng.normal(size=(cfg.num_layers, pages * ps,
-                                          cfg.num_kv_heads, cfg.head_dim)),
-                         jnp.bfloat16)
-        rv = jnp.asarray(rng.normal(size=rk.shape), jnp.bfloat16)
+        # Pool contents come from the device's own generator: 32 rows
+        # of host normals cost minutes of chip time.
+        rk = jax.random.normal(
+            jax.random.fold_in(key, 2 * b),
+            (cfg.num_layers, pages * ps, cfg.num_kv_heads, cfg.head_dim),
+            jnp.bfloat16)
+        rv = jax.random.normal(jax.random.fold_in(key, 2 * b + 1),
+                               rk.shape, jnp.bfloat16)
         cache = write_prefill_row(cache, rk, rv, jnp.asarray(b),
                                   jnp.asarray(n), table)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -116,9 +129,69 @@ def run_flash(quantized: bool, B=32, pages=48, ps=64) -> None:
         seed=1)
 
 
+def _close(got, ref, what: str) -> None:
+    gn, rn = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    rel = np.max(np.abs(gn - rn)) / (np.max(np.abs(rn)) or 1.0)
+    print(f"{what}: rel {rel:.5f}")
+    assert rel < 2e-2, f"{what} diverges from the XLA path"
+
+
+def run_decode_impl(impl: str, B=32, pages=3, ps=64) -> None:
+    """``PAGED_ATTN_IMPL=kernel|flash`` (write-then-attend decode over a
+    bf16 pool) vs the gather impl."""
+    cfg = get_config("llama3.1-8b").with_(num_layers=2)
+    key = jax.random.PRNGKey(2)
+    shape = (cfg.num_layers, B * pages + 1, ps, cfg.num_kv_heads,
+             cfg.head_dim)
+    k_pages = jax.random.normal(key, shape, jnp.bfloat16)
+    v_pages = jax.random.normal(jax.random.fold_in(key, 1), shape,
+                                jnp.bfloat16)
+    table = jnp.asarray(1 + np.arange(B * pages).reshape(B, pages),
+                        jnp.int32)
+    lens = jnp.asarray(np.random.default_rng(2).integers(
+        1, pages * ps, size=B), jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 2),
+                          (B, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+    for layer in (0, cfg.num_layers - 1):
+        args = (q, k_pages, v_pages, table, lens, jnp.asarray(layer))
+        _close(pa.paged_attention(*args, pages=pages, impl=impl),
+               pa.paged_attention(*args, pages=pages, impl="gather"),
+               f"decode impl={impl} layer={layer}")
+
+
+def run_prefill_flash(B=1, S=2048) -> None:
+    """The prefill flash kernel at the shape that reaches it under the
+    default chunked admission: a 2048-token prefix build."""
+    from p2p_llm_chat_tpu.models.layers import (attend_gqa,
+                                                attend_gqa_causal0,
+                                                causal_mask)
+    cfg = get_config("llama3.1-8b")
+    key = jax.random.PRNGKey(3)
+    q = jax.random.normal(key, (B, S, cfg.num_heads, cfg.head_dim),
+                          jnp.bfloat16)
+    k = jax.random.normal(jax.random.fold_in(key, 1),
+                          (B, S, cfg.num_kv_heads, cfg.head_dim),
+                          jnp.bfloat16)
+    v = jax.random.normal(jax.random.fold_in(key, 2), k.shape, jnp.bfloat16)
+    _close(jax.jit(attend_gqa_causal0)(q, k, v),
+           jax.jit(attend_gqa)(q, k, v, causal_mask(S, S, 0)),
+           f"prefill flash B={B} S={S}")
+
+
+def main() -> int:
+    require_tpu()
+    cases = (("block int8", lambda: run(quantized=True)),
+             ("block bf16", lambda: run(quantized=False)),
+             ("flash-append int8", lambda: run_flash(quantized=True)),
+             ("flash-append bf16", lambda: run_flash(quantized=False)),
+             ("decode impl=kernel bf16", lambda: run_decode_impl("kernel")),
+             ("decode impl=flash bf16", lambda: run_decode_impl("flash")),
+             ("prefill flash", run_prefill_flash))
+    failed, _ = run_cases(cases)
+    print(f"attention kernels: {len(cases) - failed}/{len(cases)} compile "
+          "and match their XLA paths")
+    return 1 if failed else 0
+
+
 if __name__ == "__main__":
-    run(quantized=True)
-    run(quantized=False)
-    run_flash(quantized=True)
-    run_flash(quantized=False)
-    print("append kernel parity OK")
+    sys.exit(main())

@@ -1,21 +1,28 @@
 """TPU parity + timing check: Pallas quantized matmuls vs forced XLA.
 
 Runs the w8a16 and w4a16 kernels (ops/quant_mm.py — stacked and
-unstacked) on the real chip over random weights and asserts closeness
+unstacked) on the real chip over random weights and checks closeness
 to the explicit-dequant XLA path, then times both at decode rows. CPU
 tests cover the math in interpret mode; this is the Mosaic-lowering
 check, and the measurement behind the per-hidden-size tile autotune
-table (_TILE_TABLE — the hidden=1024 retune where the stacked w8a16
-kernel lost ~5% to forced XLA before the bo cap): the timing rows must
-show no shape regime where the in-tree kernel loses to XLA.
+table (_TILE_TABLE).
+
+Every shape runs to the end and gets one verdict line: ``PASS``
+(compiled, matches XLA), ``FAIL`` (the compiler refused it, it
+diverged, or it ran out of memory — with the reason), and beside a
+pass whether the kernel ``loses to XLA`` on time (the
+kernel-never-loses bar ROADMAP S5 settles; printed, not part of the
+exit code). The exit code is non-zero when any shape FAILs.
 
 The shape matrix covers the serving configs' decode projections:
-hidden 1024 (draft-400m — the retuned row), 2048 (bench-1b), and 4096
-(llama3.1-8b), each at the model's wider fused output dims.
+hidden 1024 (draft-400m), 2048 (bench-1b), and every llama3.1-8b
+projection — K=4096 -> 6144 (fused qkv) / 4096 / 28672 (fused
+gate|up), K=14336 -> 4096, and the 4096 x 128256 head.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import time
@@ -29,6 +36,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from p2p_llm_chat_tpu.models.quant import (QTensor, QTensor4,  # noqa: E402
                                            _int4_group, dequantize,
                                            dequantize4, quantize, quantize4)
+from tools.kernel_verdicts import (SlowerThanXLA, require_tpu,  # noqa: E402
+                                   run_cases)
 from p2p_llm_chat_tpu.ops.quant_mm import (_pick_1d_bo,  # noqa: E402
                                            pick_expert_bo, pick_int4_bo,
                                            quant_matmul, quant_matmul4,
@@ -52,15 +61,26 @@ def _time_ms(fn) -> float:
     return (time.monotonic() - t) / STEPS * 1e3
 
 
+def _quantized(seed: int, shape: tuple, quantize_fn):
+    """Random f32 weights ``[L, ...]`` made and quantized ON the device
+    (host generation of the 6 GB expert stacks costs minutes of chip
+    time), one layer at a time: a whole expert stack in f32 plus the
+    quantizer's temporaries does not fit a 16 GB chip."""
+    qfn = jax.jit(quantize_fn)
+    layers = [qfn(jax.random.normal(
+        jax.random.fold_in(jax.random.PRNGKey(seed), layer), shape[1:],
+        jnp.float32)) for layer in range(shape[0])]
+    return type(layers[0])(q=jnp.stack([t.q for t in layers]),
+                           s=jnp.stack([t.s for t in layers]))
+
+
 def run8(H: int, O: int, L: int = 2) -> None:
     """w8a16: stacked + unstacked kernel vs forced-XLA dequant — parity
     (roundoff-only: both sides see the same int8 weights) and timing."""
     rng = np.random.default_rng(H + O)
     x = jnp.asarray(rng.standard_normal((ROWS, H), np.float32),
                     jnp.bfloat16)
-    # f32 host gen on purpose: f64 at the 8B fused-MLP shape is ~2 GB.
-    w = jnp.asarray(rng.standard_normal((L, H, O), np.float32))
-    qt = quantize(w)
+    qt = _quantized(H + O, (L, H, O), quantize)
 
     xla = jax.jit(lambda x, q, s: x @ dequantize(QTensor(q=q, s=s),
                                                  x.dtype))
@@ -82,9 +102,8 @@ def run8(H: int, O: int, L: int = 2) -> None:
     bo = _pick_1d_bo(ROWS, H, O, 2)
     print(f"int8 H={H} O={O} (1d bo={bo}): kernel {k_ms:.4f} ms vs XLA "
           f"{x_ms:.4f} ms ({x_ms / k_ms:.2f}x)")
-    assert k_ms <= x_ms * 1.02, \
-        f"w8a16 kernel loses to forced XLA at H={H} O={O} — retune " \
-        f"_TILE_TABLE (ops/quant_mm.py)"
+    if k_ms > x_ms * 1.02:
+        raise SlowerThanXLA(f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms")
 
 
 def run4(H: int, O: int, L: int = 2) -> None:
@@ -92,8 +111,7 @@ def run4(H: int, O: int, L: int = 2) -> None:
     rng = np.random.default_rng(H + O + 1)
     x = jnp.asarray(rng.standard_normal((ROWS, H), np.float32),
                     jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((L, H, O), np.float32))
-    qt = quantize4(w)
+    qt = _quantized(H + O + 1, (L, H, O), quantize4)
     ng = qt.s.shape[-2]
     bo = pick_int4_bo(ROWS, H, O, ng, 2)
     assert bo is not None, f"w4a16 kernel must cover H={H} O={O} ng={ng}"
@@ -117,9 +135,8 @@ def run4(H: int, O: int, L: int = 2) -> None:
     x_ms = _time_ms(lambda: xla(x, qt.q[1], qt.s[1]))
     print(f"int4 H={H} O={O} (1d bo={bo}, ng={ng}): kernel {k_ms:.4f} ms "
           f"vs XLA {x_ms:.4f} ms ({x_ms / k_ms:.2f}x)")
-    assert k_ms <= x_ms * 1.02, \
-        f"w4a16 kernel loses to forced XLA at H={H} O={O} — retune " \
-        f"_TILE_TABLE (ops/quant_mm.py)"
+    if k_ms > x_ms * 1.02:
+        raise SlowerThanXLA(f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms")
 
 
 def run_experts8(H: int, O: int, NE: int = 8, L: int = 2) -> None:
@@ -128,9 +145,7 @@ def run_experts8(H: int, O: int, NE: int = 8, L: int = 2) -> None:
     rng = np.random.default_rng(H + O + 2)
     x = jnp.asarray(rng.standard_normal((NE, EXPERT_ROWS, H), np.float32),
                     jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((L, NE, H, O), np.float32))
-    qt = quantize(w)
-    del w
+    qt = _quantized(H + O + 2, (L, NE, H, O), quantize)
     assert pick_expert_bo(EXPERT_ROWS, H, O, 2) is not None, \
         f"expert kernel must cover H={H} O={O}"
 
@@ -151,9 +166,8 @@ def run_experts8(H: int, O: int, NE: int = 8, L: int = 2) -> None:
     bo = pick_expert_bo(EXPERT_ROWS, H, O, 2)
     print(f"int8 experts H={H} O={O} NE={NE} (bo={bo}): kernel "
           f"{k_ms:.4f} ms vs XLA {x_ms:.4f} ms ({x_ms / k_ms:.2f}x)")
-    assert k_ms <= x_ms * 1.02, \
-        f"w8a16 expert kernel loses to forced XLA at H={H} O={O} — " \
-        f"retune _TILE_TABLE (ops/quant_mm.py)"
+    if k_ms > x_ms * 1.02:
+        raise SlowerThanXLA(f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms")
 
 
 def run_experts4(H: int, O: int, NE: int = 8, L: int = 2) -> None:
@@ -166,9 +180,8 @@ def run_experts4(H: int, O: int, NE: int = 8, L: int = 2) -> None:
     rng = np.random.default_rng(H + O + 3)
     x = jnp.asarray(rng.standard_normal((NE, EXPERT_ROWS, H), np.float32),
                     jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((L, NE, H, O), np.float32))
-    qt = quantize4(w, group=group)
-    del w
+    qt = _quantized(H + O + 3, (L, NE, H, O),
+                    functools.partial(quantize4, group=group))
     ng = qt.s.shape[-2]
     bo = pick_int4_bo(EXPERT_ROWS, H, O, ng, 2)
     assert bo is not None, \
@@ -191,26 +204,37 @@ def run_experts4(H: int, O: int, NE: int = 8, L: int = 2) -> None:
     print(f"int4 experts H={H} O={O} NE={NE} (bo={bo}, ng={ng}"
           f"{', odd walk' if ng % 2 else ''}): kernel {k_ms:.4f} ms vs "
           f"XLA {x_ms:.4f} ms ({x_ms / k_ms:.2f}x)")
-    assert k_ms <= x_ms * 1.02, \
-        f"w4a16 expert kernel loses to forced XLA at H={H} O={O} — " \
-        f"retune _TILE_TABLE (ops/quant_mm.py)"
+    if k_ms > x_ms * 1.02:
+        raise SlowerThanXLA(f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms")
+
+
+def main() -> int:
+    require_tpu()
+    # (H, O) per serving config's decode projections: draft-400m's
+    # H=1024 trunk (the _TILE_TABLE retune rows), bench-1b's H=2048,
+    # then every llama3.1-8b projection — fused qkv, wo, fused gate|up,
+    # w_down and the vocabulary head.
+    cases = []
+    for H, O in ((1024, 2048), (1024, 4096), (2048, 2048), (2048, 11264),
+                 (4096, 6144), (4096, 4096), (4096, 28672), (14336, 4096),
+                 (4096, 128256)):
+        cases += [(f"int8 H={H} O={O}", functools.partial(run8, H, O)),
+                  (f"int4 H={H} O={O}", functools.partial(run4, H, O))]
+    # MoE expert pools: bench-moe's fused wgu_e [H=1024, O=2F=5632] and
+    # w_down [2816, 1024], then mixtral-large's expert scale — wgu_e
+    # [4096, 23040] and w_down [11520, 4096], the int4 odd-group-count
+    # walk (group 256 => ng=45).
+    for H, O in ((1024, 5632), (2816, 1024), (4096, 23040),
+                 (11520, 4096)):
+        cases += [(f"int8 experts H={H} O={O}",
+                   functools.partial(run_experts8, H, O)),
+                  (f"int4 experts H={H} O={O}",
+                   functools.partial(run_experts4, H, O))]
+    failed, slower = run_cases(cases)
+    print(f"quant kernels: {len(cases) - failed}/{len(cases)} compile and "
+          f"match XLA, {slower} lose to XLA on time")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    # (H, O) per serving config's decode projections: draft-400m's
-    # H=1024 trunk (wqkv-fused 2048 and the 4096 MLP — the _TILE_TABLE
-    # retune rows), bench-1b's H=2048, llama3.1-8b's H=4096 with the
-    # fused gate|up width.
-    for H, O in ((1024, 2048), (1024, 4096), (2048, 2048), (2048, 11264),
-                 (4096, 4096), (4096, 28672)):
-        run8(H, O)
-        run4(H, O)
-    # MoE expert pools (round 18): bench-moe's fused wgu_e [H=1024,
-    # O=2F=5632] and w_down [2816, 1024], then mixtral-large's real
-    # expert scale — wgu_e [4096, 23040] and w_down [11520, 4096], the
-    # int4 odd-group-count walk (group 256 => ng=45).
-    for H, O in ((1024, 5632), (2816, 1024), (4096, 23040),
-                 (11520, 4096)):
-        run_experts8(H, O)
-        run_experts4(H, O)
-    print("quant kernel parity + timing OK")
+    sys.exit(main())

@@ -10,7 +10,7 @@
 # new stall/TBT gauges publish. Bit-identity of chunked vs single-shot
 # output is pinned by tests/test_chunked_prefill.py (ci.sh), not here.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v
 
 fail() { echo "FAIL: $1"; exit 1; }

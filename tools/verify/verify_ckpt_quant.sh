@@ -3,7 +3,7 @@
 # build a tiny NATIVE checkpoint, serve it with SERVE_QUANT=int8 (takes
 # weights.load_checkpoint_quantized), and generate through the front.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v
 
 fail() { echo "FAIL: $1"; exit 1; }

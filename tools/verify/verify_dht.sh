@@ -1,7 +1,7 @@
 #!/bin/bash
 # Verify the DHT lookup rung with real OS processes.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v  # scratch for logs/pids
 rm -f /tmp/v/*.log /tmp/v/*.pid
 

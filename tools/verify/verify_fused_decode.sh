@@ -3,7 +3,7 @@
 # Ollama front, streamed + non-streamed generates, /metrics assertions.
 set -u
 mkdir -p /tmp/vf
-cd /root/repo
+cd "$(dirname "$0")/../.."
 PORT=18433
 SERVE_BACKEND=tpu MODEL_CONFIG=tiny SERVE_KV=paged SERVE_KV_QUANT=int8 \
   SERVE_QUANT=int8 SERVE_FUSE=4 SERVE_SLOTS=4 SERVE_MAX_SEQ=256 \

@@ -4,7 +4,7 @@
 # same invocation ci.sh runs (acceptance criterion: ci.sh fails when an
 # unguarded write to a `# guarded-by:` attribute is introduced).
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v
 
 fail() { echo "FAIL: $1"; exit 1; }

@@ -4,7 +4,7 @@
 # (2) a native MoE checkpoint through the streamed int8 loader
 # ("quantized+fused (streaming, single-chip)" log line). PASS/FAIL.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v5
 PORT=$((21000 + RANDOM % 5000))
 

@@ -5,7 +5,7 @@
 # request per lockstep round and the seeded completion must reproduce.
 # Prints PASS/FAIL.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v5
 COORD_PORT=$((20000 + RANDOM % 8000))
 SERVE_PORT=$((COORD_PORT + 1))
@@ -13,17 +13,13 @@ COORD=127.0.0.1:$COORD_PORT
 
 spawn() {
   local pid=$1
-  REPO=/root/repo PYTHONPATH=/root/repo \
+  REPO="$PWD" PYTHONPATH="$PWD" \
   XLA_FLAGS=--xla_force_host_platform_device_count=1 \
   JAX_PLATFORMS=cpu JAX_COORDINATOR=$COORD JAX_NUM_PROCESSES=2 \
   JAX_PROCESS_ID=$pid SERVE_BACKEND=tpu SERVE_COORDINATOR=$COORD \
   MODEL_CONFIG=tiny SERVE_MAX_SEQ=128 SERVE_MH_WINDOW_MS=300 \
   SERVE_ADDR=127.0.0.1:$SERVE_PORT \
-  python -c "
-import jax
-jax.config.update('jax_platforms', 'cpu')
-from p2p_llm_chat_tpu.serve.api import main
-main()" > /tmp/v5/mh_$pid.log 2>&1 &
+  python -m p2p_llm_chat_tpu.serve.api > /tmp/v5/mh_$pid.log 2>&1 &
   echo $! > /tmp/v5/mh_$pid.pid
 }
 

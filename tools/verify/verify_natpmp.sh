@@ -1,7 +1,7 @@
 #!/bin/bash
 # Verify NAT-PMP end-to-end: real node process + fake gateway process.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v  # scratch for logs/pids
 
 fail() { echo "FAIL: $1"; exit 1; }

@@ -1,7 +1,7 @@
 #!/bin/bash
 # Replica-router live drive: launcher fleet (2 replicas + router), Ollama
 # contract through the router, aggregation, drain semantics.
-cd /root/repo
+cd "$(dirname "$0")/../.."
 P=19434
 python start_all.py --replicas 2 --users "" --serve-port $P \
   --dir-port 19080 --node-port-base 19081 --ui-port-base 19501 \

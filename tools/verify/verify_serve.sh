@@ -2,7 +2,7 @@
 # Serve-plane verify: full feature stack (paged + int8 + spec + prefix)
 # through the Ollama-compatible front, per the project verify skill.
 set -u
-cd /root/repo
+cd "$(dirname "$0")/../.."
 mkdir -p /tmp/v  # scratch for logs/pids
 
 fail() { echo "FAIL: $1"; exit 1; }
