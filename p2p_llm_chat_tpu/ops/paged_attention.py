@@ -188,6 +188,7 @@ def _paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, layer,
     )
     return pl.pallas_call(
         functools.partial(_kernel, page_size=page_size, rep=rep, scale=scale),
+        name="paged_attention_block",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
@@ -383,6 +384,7 @@ def _paged_append_kernel_call(q, k_cur, v_cur, k_pages, v_pages, k_scale,
         functools.partial(_append_kernel, page_size=page_size, pages=pages,
                           rep=rep, rows=rows, scale=scale,
                           quantized=quantized),
+        name="paged_attention_append",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
@@ -1106,6 +1108,7 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
     return pl.pallas_call(
         _flash_append_kernel_body(quantized, page_size, pages, chunk_pages,
                                   num_chunks, rep, scale, compute_dtype),
+        name="paged_attention_flash_append",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
@@ -1141,6 +1144,7 @@ def _paged_attention_flash(q, k_pages, v_pages, page_table, lengths, layer,
     return pl.pallas_call(
         functools.partial(_flash_kernel, page_size=page_size, pages=pages,
                           chunk_pages=chunk_pages, rep=rep, scale=scale),
+        name="paged_attention_flash",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,

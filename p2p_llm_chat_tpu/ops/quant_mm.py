@@ -272,6 +272,7 @@ def quant_matmul_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
         )
         out = pl.pallas_call(
             _qmm_kernel_1d_stacked,
+            name="qmm_int8_stacked_1d",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rp, O), x.dtype),
             interpret=interpret,
@@ -291,6 +292,7 @@ def quant_matmul_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
     )
     out = pl.pallas_call(
         _qmm_kernel_2d_stacked,
+        name="qmm_int8_stacked_2d",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rp, O), x.dtype),
         interpret=interpret,
@@ -405,6 +407,7 @@ def quant_matmul(x: jax.Array, q: jax.Array, s: jax.Array,
     if bo_1d is not None:
         out = pl.pallas_call(
             _qmm_kernel_1d,
+            name="qmm_int8_1d",
             grid=(O // bo_1d,),
             in_specs=[
                 pl.BlockSpec((rp, H), lambda i: (0, 0)),
@@ -419,6 +422,7 @@ def quant_matmul(x: jax.Array, q: jax.Array, s: jax.Array,
 
     out = pl.pallas_call(
         _qmm_kernel,
+        name="qmm_int8_2d",
         grid=(O // bo, H // bh),
         in_specs=[
             pl.BlockSpec((rp, bh), lambda i, j: (0, j)),
@@ -457,6 +461,7 @@ def quant_matmul4(x: jax.Array, q: jax.Array, s: jax.Array,
     rp = rows + pad
     out = pl.pallas_call(
         _qmm4_kernel_1d,
+        name="qmm_int4_1d",
         grid=(O // bo,),
         in_specs=[
             pl.BlockSpec((rp, H), lambda i: (0, 0)),
@@ -506,6 +511,7 @@ def quant_matmul_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
     )
     out = pl.pallas_call(
         _qmm4_kernel_1d_stacked,
+        name="qmm_int4_stacked_1d",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rp, O), x.dtype),
         interpret=interpret,
@@ -552,6 +558,7 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
     )
     out = pl.pallas_call(
         _qmm_kernel_experts_stacked,
+        name="qmm_int8_experts_stacked",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NE, cp, O), x.dtype),
         interpret=interpret,
@@ -600,6 +607,7 @@ def quant_matmul_experts_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
     )
     out = pl.pallas_call(
         _qmm4_kernel_experts_stacked,
+        name="qmm_int4_experts_stacked",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NE, cp, O), x.dtype),
         interpret=interpret,

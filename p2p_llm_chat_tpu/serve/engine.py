@@ -405,7 +405,11 @@ def build_engine_from_env() -> Backend:
     runtime and shards the model over the hybrid dp-over-DCN mesh;
     process 0 serves HTTP, the rest mirror its programs
     (serve/multihost.py — api.main() dispatches follower_loop)."""
+    from ..obs.phase import compile_clock
     from ..utils.jax_cache import enable_persistent_cache
+    # Before the first compile: the streamed init's programs count
+    # towards serve_boot_compile_seconds too.
+    compile_clock()
     enable_persistent_cache()
     coord = env_or("SERVE_COORDINATOR", "") or None
     if coord or env_or("JAX_COORDINATOR", ""):

@@ -57,6 +57,7 @@ from ..models.layers import causal_mask
 from ..models.llama import KVCache
 from ..models.sampling import sample_batched, sample_step_batched
 from ..obs.flight import FlightRecorder
+from ..obs.phase import LoopPhases, compile_clock, process_age_s
 from ..tokenizer import Tokenizer
 from ..utils.env import env_float
 from ..utils.failpoints import failpoint
@@ -501,6 +502,20 @@ class BatchScheduler:
         self._last_fuse_k = 0         # owned-by: _loop
         self._flight = FlightRecorder()
         self._trace = None
+        # Loop-phase timer (obs/phase.py): where this thread's wall
+        # goes, as self times by phase, on the profiler's clock and as
+        # window counters (serve_loop_*_seconds_total).
+        self._phase = LoopPhases()    # owned-by: _loop
+        self._loop_s = 0.0            # owned-by: _loop — wall of all iterations
+        self._warm_iter = 0           # owned-by: _loop — last iteration that ran a warm-up job
+        # Boot gauges (serve_boot_*): set once each, at the end of this
+        # constructor and when a warm-up finishes. The compile clock is
+        # the process's own (started here if the entry point has not).
+        compile_clock()
+        self._boot_load_s = 0.0
+        self._boot_warmup_s = 0.0
+        self._boot_compile_s = 0.0
+        self._n_warmup_jobs = 0       # owned-by: _loop
         # Heartbeat: start time of the CURRENT loop iteration (written
         # by _loop each pass, read by metrics_snapshot) — lets the gauge
         # expose an in-flight stall a wedged iteration would otherwise
@@ -576,6 +591,20 @@ class BatchScheduler:
         self._n_decode_ticks = 0      # owned-by: _loop
         self._n_expired = 0           # owned-by: _loop
         self._n_spec_accepted = 0     # owned-by: _loop — draft tokens accepted by verify
+        # Counts at the dispatch sites (owned-by: _loop), so that ratios
+        # are measured where the work happens: admission dispatches
+        # started, prompt positions that had to be computed against the
+        # positions the padded programs computed, live rows x steps per
+        # decode dispatch, and the decode dispatch intervals no
+        # admission work cut into (_note_clean_interval).
+        self._n_admit_batches = 0
+        self._n_prefill_tokens = 0
+        self._n_prefill_padded = 0
+        self._n_decode_row_steps = 0
+        self._clean_s = 0.0
+        self._clean_steps = 0
+        self._clean_hold = 0          # dispatches still to skip after admission work
+        self._prev_k = 0              # K of the dispatch before _last_dispatch (0: none)
         # Shared-prefix KV cache (serve/prefix.py): prompt-head matches
         # skip recomputing the prefix at admission. Ladder grains that
         # could never pass the admission budget guard (P + smallest
@@ -783,10 +812,15 @@ class BatchScheduler:
 
         # Jitted programs. decode is compiled once; admit once per
         # (chunk-rows, prompt-bucket) shape pair — both power-of-two
-        # bucketed, so the compile cache stays small.
+        # bucketed, so the compile cache stays small. A program takes
+        # the name of the function it jits, and that name is a contract:
+        # it begins with the program's kind (prefill_, decode_, spec_,
+        # kv_), which is what a reader of a device trace sums by
+        # (`XLA Modules` events read jit_prefill_..., jit_decode_...;
+        # tests/test_loop_phases.py holds every jit site to it).
         def _make_decode(kv_window: int):
-            def _decode(params, tokens, cache, active, temps, top_ks, top_ps,
-                        keys, ring, rps):
+            def decode_step(params, tokens, cache, active, temps, top_ks,
+                            top_ps, keys, ring, rps):
                 # The emitted token's context position is lengths+1 (the
                 # INPUT token occupies lengths) — writing at lengths would
                 # clobber the previous tick's emission in the ring.
@@ -812,7 +846,7 @@ class BatchScheduler:
                 # garbage sample.
                 next_tokens = jnp.where(active[:, None], toks[:, None], tokens)
                 return toks, next_tokens, cache, keys, ring
-            return jax.jit(_decode, donate_argnums=(1, 2, 7, 8))
+            return jax.jit(decode_step, donate_argnums=(1, 2, 7, 8))
 
         self._make_decode = _make_decode
         self._decode_programs: dict[int, object] = {}
@@ -828,8 +862,8 @@ class BatchScheduler:
             # graftcheck: sync-ok host-side constant, not a device readback
             stop_ids = np.asarray(sorted(self._stop_ids), np.int32)
 
-            def _decode_fused(params, tokens, cache, active, temps, top_ks,
-                              top_ps, keys, ring, rps):
+            def decode_fused_steps(params, tokens, cache, active, temps,
+                                   top_ks, top_ps, keys, ring, rps):
                 def sample_fn(logits, state, emit_pos, act):
                     keys, ring = state
                     toks, keys, ring = sample_step_batched(
@@ -848,7 +882,7 @@ class BatchScheduler:
                  (keys, ring)) = model.decode_fused(params, config, tokens,
                                                     cache, mesh, **kwargs)
                 return toks_all, next_tokens, cache, keys, ring
-            return jax.jit(_decode_fused, donate_argnums=(1, 2, 7, 8))
+            return jax.jit(decode_fused_steps, donate_argnums=(1, 2, 7, 8))
 
         self._make_decode_fused = _make_decode_fused
         self._decode_fused_programs: dict[tuple[int, int], object] = {}
@@ -859,8 +893,8 @@ class BatchScheduler:
             fused. Host reads back 2×B int32 (accepted, correction)."""
             from ..models.sampling import spec_verify_batched
 
-            def _spec(params, tokens, drafts, max_acc, cache, active,
-                      temps, top_ks, top_ps, keys, ring, rps):
+            def spec_verify(params, tokens, drafts, max_acc, cache, active,
+                            temps, top_ks, top_ps, keys, ring, rps):
                 K = tokens.shape[1] - 1
                 lengths_pre = cache.lengths
                 if self.kv_mode == "paged":
@@ -899,7 +933,7 @@ class BatchScheduler:
                 next_tokens = jnp.where(active[:, None],
                                         correction[:, None], tokens[:, :1])
                 return accepted, correction, next_tokens, cache, keys, ring
-            return jax.jit(_spec, donate_argnums=(4, 9, 10))
+            return jax.jit(spec_verify, donate_argnums=(4, 9, 10))
 
         self._make_spec = _make_spec
         self._spec_programs: dict[int, object] = {}
@@ -913,9 +947,9 @@ class BatchScheduler:
             from ..models.sampling import spec_verify_tree
             from ..ops.paged_kv import copy_slot
 
-            def _spec_tree(params, tokens, depths, anc, drafts, sib_tok,
-                           sib_node, max_acc, cache, active, temps,
-                           top_ks, top_ps, keys, ring, rps):
+            def spec_tree_verify(params, tokens, depths, anc, drafts,
+                                 sib_tok, sib_node, max_acc, cache, active,
+                                 temps, top_ks, top_ps, keys, ring, rps):
                 B, N = tokens.shape
                 K = drafts.shape[1]
                 lengths_pre = cache.lengths
@@ -985,7 +1019,7 @@ class BatchScheduler:
                                         tokens[:, :1])
                 return (accepted, used_sib, correction, next_tokens,
                         cache, keys, ring)
-            return jax.jit(_spec_tree, donate_argnums=(8, 13, 14))
+            return jax.jit(spec_tree_verify, donate_argnums=(8, 13, 14))
 
         self._make_spec_tree = _make_spec_tree
         self._spec_tree_programs: dict[int, object] = {}
@@ -1013,7 +1047,7 @@ class BatchScheduler:
             prompt-tail penalty windows; paged mode adds tables
             [B,mppr] (each waking row's FULL page map: the session's
             kept pages plus freshly-allocated growth pages)."""
-            def _wake(params, tokens, ints, floats, rings, *args):
+            def kv_wake(params, tokens, ints, floats, rings, *args):
                 if self.kv_mode == "paged":
                     tables = args[0]
                     rest = args[1:]
@@ -1065,7 +1099,7 @@ class BatchScheduler:
                 return (toks, cache, keys, next_tokens, temps, top_ks,
                         top_ps, ring, rps)
             first = 6 if self.kv_mode == "paged" else 5
-            return jax.jit(_wake,
+            return jax.jit(kv_wake,
                            donate_argnums=tuple(range(first, first + 8)))
 
         self._make_wake = _make_wake
@@ -1119,8 +1153,8 @@ class BatchScheduler:
             rps = rps.at[rows].set(floats[2], mode="drop")
             return keys, next_tokens, temps, top_ks, top_ps, ring, rps
 
-        def _admit_batch(params, tokens, ints, floats, rings, cache, keys,
-                         next_tokens, temps, top_ks, top_ps, ring, rps):
+        def prefill_admit(params, tokens, ints, floats, rings, cache, keys,
+                          next_tokens, temps, top_ks, top_ps, ring, rps):
             """Prefill R prompts together, splice each row's kv into the big
             cache, and sample each row's first token. R comes from a
             two-size ladder (short chunks carry padding entries whose row
@@ -1143,11 +1177,11 @@ class BatchScheduler:
             return (toks, cache, keys, next_tokens, temps, top_ks, top_ps,
                     ring, rps)
 
-        def _admit_batch_paged(params, tokens, ints, floats, rings, tables,
-                               cache, keys, next_tokens, temps, top_ks,
-                               top_ps, ring, rps):
+        def prefill_admit_paged(params, tokens, ints, floats, rings, tables,
+                                cache, keys, next_tokens, temps, top_ks,
+                                top_ps, ring, rps):
             """Paged-mode admission: same fused prefill/sample as
-            _admit_batch, but the chunk's kv splices into the page pool
+            prefill_admit, but the chunk's kv splices into the page pool
             through the rows' page maps in ONE scatter
             (ops/paged_kv.write_prefill_batch — the R-sequential-scatters
             version made paged TTFT ~8x dense). Padding entries carry an
@@ -1201,10 +1235,10 @@ class BatchScheduler:
             rings = rings.at[jnp.arange(R), total_lens % _RING].set(toks)
             return small, toks, row_keys, rings
 
-        def _admit_batch_prefix(params, pk, pv, tokens, ints, floats, rings,
-                                cache, keys, next_tokens, temps, top_ks,
-                                top_ps, ring, rps):
-            """_admit_batch for a chunk sharing one cached prefix: splice
+        def prefill_admit_prefix(params, pk, pv, tokens, ints, floats,
+                                 rings, cache, keys, next_tokens, temps,
+                                 top_ks, top_ps, ring, rps):
+            """prefill_admit for a chunk sharing one cached prefix: splice
             [prefix KV + suffix KV] (the small cache, P+S wide) into the
             big cache and install lengths = total (prefix + suffix)."""
             S = tokens.shape[1]
@@ -1224,10 +1258,10 @@ class BatchScheduler:
             return (toks, cache, keys, next_tokens, temps, top_ks, top_ps,
                     ring, rps)
 
-        def _admit_batch_paged_prefix(params, pk, pv, tokens, ints, floats,
-                                      rings, tables, cache, keys,
-                                      next_tokens, temps, top_ks, top_ps,
-                                      ring, rps):
+        def prefill_admit_paged_prefix(params, pk, pv, tokens, ints, floats,
+                                       rings, tables, cache, keys,
+                                       next_tokens, temps, top_ks, top_ps,
+                                       ring, rps):
             """Paged-mode prefix admission: the combined [prefix + suffix]
             KV splices into each row's own pages through the one-scatter
             batch path (copy-based sharing — rows own their prefix copy,
@@ -1246,15 +1280,15 @@ class BatchScheduler:
                     ring, rps)
 
         if self.kv_mode == "paged":
-            self._admit_j = jax.jit(_admit_batch_paged,
+            self._admit_j = jax.jit(prefill_admit_paged,
                                     donate_argnums=(6, 7, 8, 9, 10, 11, 12,
                                                     13))
             self._admit_prefix_j = jax.jit(
-                _admit_batch_paged_prefix,
+                prefill_admit_paged_prefix,
                 donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
             from ..ops.paged_kv import set_row_table
 
-            def _zero_row(cache, row):
+            def kv_zero_row(cache, row):
                 return set_row_table(
                     cache, row,
                     jnp.zeros((cache.page_table.shape[1],), jnp.int32))
@@ -1262,13 +1296,13 @@ class BatchScheduler:
             # Row release: zero the table (writes re-route to the garbage
             # page) BEFORE its pages return to the allocator — a stale
             # parked row must never scatter into a re-allocated page.
-            self._zero_row_j = jax.jit(_zero_row, donate_argnums=(0,))
+            self._zero_row_j = jax.jit(kv_zero_row, donate_argnums=(0,))
         else:
-            self._admit_j = jax.jit(_admit_batch,
+            self._admit_j = jax.jit(prefill_admit,
                                     donate_argnums=(5, 6, 7, 8, 9, 10, 11,
                                                     12))
             self._admit_prefix_j = jax.jit(
-                _admit_batch_prefix,
+                prefill_admit_prefix,
                 donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
 
         # Multi-tier KV copy programs: the park gather and wake scatter
@@ -1279,8 +1313,17 @@ class BatchScheduler:
         # use per-width slice/set programs (_extract_row_for).
         if self.kv_mode == "paged":
             from ..ops.paged_kv import gather_pages, scatter_pages
-            self._gather_pages_j = jax.jit(gather_pages)
-            self._scatter_pages_j = jax.jit(scatter_pages,
+
+            # Wrapped only to carry the kind in the program's name.
+            def kv_gather_pages(cache, pages):
+                return gather_pages(cache, pages)
+
+            def kv_scatter_pages(cache, pages, *payload):
+                return scatter_pages(cache, pages, *payload)
+
+            # graftcheck: nodonate park gather READS the live pool; the resident buffer must outlive the copy
+            self._gather_pages_j = jax.jit(kv_gather_pages)
+            self._scatter_pages_j = jax.jit(kv_scatter_pages,
                                             donate_argnums=(0,))
         self._row_copy_programs: dict[tuple, object] = {}
 
@@ -1367,7 +1410,7 @@ class BatchScheduler:
                 return KVCache(k, v, lengths)
 
             if first:
-                def _chunk_first(params, *args):
+                def prefill_chunk_first(params, *args):
                     if P0:
                         pk, pv, tokens, ints = args[:4]
                         rest = args[4:]
@@ -1394,10 +1437,12 @@ class BatchScheduler:
                     return carry, logits_c, cache
                 # donate the big cache (always the last argument)
                 n_args = 1 + (2 if P0 else 0) + 2 + (1 if paged else 0) + 1
-                return jax.jit(_chunk_first, donate_argnums=(n_args - 1,))
+                return jax.jit(prefill_chunk_first,
+                               donate_argnums=(n_args - 1,))
 
             if not final:
-                def _chunk_mid(params, tokens, ints, carry, logits_c, *rest):
+                def prefill_chunk_mid(params, tokens, ints, carry, logits_c,
+                                      *rest):
                     tables = rest[0] if paged else None
                     cache = rest[-1]
                     carry, logits_c = _fwd(params, tokens, ints, carry,
@@ -1405,10 +1450,10 @@ class BatchScheduler:
                     cache = _splice(cache, carry, ints, tables)
                     return carry, logits_c, cache
                 last = 5 + (1 if paged else 0)
-                return jax.jit(_chunk_mid, donate_argnums=(3, 4, last))
+                return jax.jit(prefill_chunk_mid, donate_argnums=(3, 4, last))
 
-            def _chunk_final(params, tokens, ints, floats, rings, carry,
-                             logits_c, *rest):
+            def prefill_chunk_final(params, tokens, ints, floats, rings,
+                                    carry, logits_c, *rest):
                 tables = rest[0] if paged else None
                 (cache, keys, next_tokens, temps, top_ks, top_ps, ring,
                  rps) = rest[-8:]
@@ -1434,7 +1479,7 @@ class BatchScheduler:
             # to alias into — donating them only trips XLA's unusable-
             # donation warning, so they are freed by refcount instead.
             off0 = 7 + (1 if paged else 0)
-            return jax.jit(_chunk_final,
+            return jax.jit(prefill_chunk_final,
                            donate_argnums=tuple(range(off0, off0 + 8)))
 
         self._make_prefill_chunk_program = _make_prefill_chunk_program
@@ -1443,7 +1488,7 @@ class BatchScheduler:
         # wrappers above compile per batch width R on first call).
         self._chunk_shapes_run: set[tuple] = set()  # owned-by: _loop
 
-        def _build_prefix(params, toks):
+        def prefill_build_prefix(params, toks):
             """Prefill one prefix ([1,P]) and strip the batch axis —
             the register_prefix / promotion builder."""
             P = toks.shape[1]
@@ -1453,8 +1498,11 @@ class BatchScheduler:
                                      mesh)
             return cache.k[:, 0], cache.v[:, 0]
 
-        self._build_prefix_j = jax.jit(_build_prefix)
+        self._build_prefix_j = jax.jit(prefill_build_prefix)
 
+        # Process start (as the OS records it) until this scheduler is
+        # built: interpreter, imports, weights, pool.
+        self._boot_load_s = process_age_s() or 0.0
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="batch-scheduler")
         self._thread.start()
@@ -1595,10 +1643,10 @@ class BatchScheduler:
         key = ("extract", W)
         p = self._row_copy_programs.get(key)
         if p is None:
-            def _ex(cache, row):
+            def kv_extract_row(cache, row):
                 return cache.k[:, row, :W], cache.v[:, row, :W]
             # graftcheck: nodonate park gather READS the live cache; the resident buffer must outlive the copy
-            p = jax.jit(_ex)
+            p = jax.jit(kv_extract_row)
             self._row_copy_programs[key] = p
         return p
 
@@ -1608,10 +1656,10 @@ class BatchScheduler:
         key = ("inject", W)
         p = self._row_copy_programs.get(key)
         if p is None:
-            def _in(cache, row, k, v):
+            def kv_inject_row(cache, row, k, v):
                 return cache._replace(k=cache.k.at[:, row, :W].set(k),
                                       v=cache.v.at[:, row, :W].set(v))
-            p = jax.jit(_in, donate_argnums=(0,))
+            p = jax.jit(kv_inject_row, donate_argnums=(0,))
             self._row_copy_programs[key] = p
         return p
 
@@ -1924,8 +1972,11 @@ class BatchScheduler:
             # compiles even with the persistent cache) start their
             # deadline clock here, not at arrival (see _expired).
             self._warmup_done_at = time.monotonic()
-            log.info("warmup finished: %d jobs in %.1f s", len(steps),
-                     self._warmup_done_at - t_warm)
+            self._boot_warmup_s = self._warmup_done_at - t_warm
+            self._boot_compile_s = compile_clock().seconds
+            log.info("warmup finished: %d jobs in %.1f s (process: %.1f s "
+                     "of compilation and cache retrieval so far)",
+                     len(steps), self._boot_warmup_s, self._boot_compile_s)
         self._warmup_done_at = None
         t_warm = time.monotonic()
         steps.append(_warmup_finished)
@@ -2581,6 +2632,9 @@ class BatchScheduler:
             it_start = time.monotonic()
             self._loop_beat = it_start
             self._loop_iter += 1
+            phase = self._phase
+            phase.mark_iteration()
+            warm0 = phase.inclusive("warmup")
             try:
                 self._drain_stall_reset()
                 self._drain_park_all()
@@ -2588,9 +2642,10 @@ class BatchScheduler:
                 # unexpected admission-path error must fail requests and
                 # reset, never kill the scheduler thread (which would leave
                 # every future submit() hanging on a dead queue).
-                self._admit_pending(block=not self._any_active()
-                                    and pending is None
-                                    and self._prefill_carry is None)
+                with phase("admit"):
+                    self._admit_pending(block=not self._any_active()
+                                        and pending is None
+                                        and self._prefill_carry is None)
                 if self._closed.is_set():
                     return
                 if self._prefix is not None:
@@ -2633,7 +2688,11 @@ class BatchScheduler:
                         pending = None
                     if not self._any_active():
                         continue
-                    if self._spec_tick(spec_allowed):
+                    with phase("decode_dispatch"):
+                        # Its reads and per-row work mark their own
+                        # phases inside; what is left is the dispatch.
+                        spec_done = self._spec_tick(spec_allowed)
+                    if spec_done:
                         continue
                 # Fused K-step ticks ride the same one-tick-deep pipeline
                 # as plain ones: tick t+1 (up to K steps) is enqueued
@@ -2641,9 +2700,10 @@ class BatchScheduler:
                 # readback/stream work overlaps device compute. K=1 while
                 # speculation is live this iteration (a fused tick would
                 # emit K tokens with no draft chance).
-                new = self._dispatch_tick(
-                    allow_fuse=not spec_now,
-                    inflight=pending[2] if pending is not None else 0)
+                with phase("decode_dispatch"):
+                    new = self._dispatch_tick(
+                        allow_fuse=not spec_now,
+                        inflight=pending[2] if pending is not None else 0)
                 if pending is not None:
                     self._process_tick(*pending)
                 pending = new
@@ -2659,21 +2719,31 @@ class BatchScheduler:
                 pending = None
                 self._fail_all_and_reset()
             finally:
-                self._watchdog(it_start)
+                self._loop_s += time.monotonic() - it_start
+                self._watchdog(it_start,
+                               phase.inclusive("warmup") - warm0)
 
     # graftcheck: runs-on _loop
-    def _watchdog(self, it_start: float) -> None:
+    def _watchdog(self, it_start: float, warm_s: float = 0.0) -> None:
         """Loop-iteration watchdog: an iteration past the budget (a
         mid-serving compile, a wedged device call, a host stall) updates
         the ``loop_stall_ms`` max gauge and logs ONCE per stall episode
         — enter and recover each log one line, never one per iteration
         (a minutes-long warmup would otherwise spam hundreds). Blocked-
         idle iterations cap at the admission poll timeout (~0.2 s), so
-        idleness never reads as a stall."""
+        idleness never reads as a stall. Nor does warm-up: until the
+        scheduler is ready, the ``warm_s`` seconds this iteration spent
+        inside warm-up jobs (every cold compile is one) are not the
+        loop's, and an iteration that is over budget only because of
+        them enters no episode, dumps nothing and leaves the gauges at
+        0 = never stalled. Once ready, a job's time counts like any
+        other (a background warm-up then stalls live streams)."""
         budget = self.loop_budget_ms
         if not budget:
             return
         dur_ms = (time.monotonic() - it_start) * 1e3
+        if warm_s and self._warmup_done_at is None:
+            dur_ms -= warm_s * 1e3
         if dur_ms > budget:
             if dur_ms > self._loop_stall_ms:
                 self._loop_stall_ms = dur_ms
@@ -2692,7 +2762,8 @@ class BatchScheduler:
                 # caused it, which is the whole diagnosis.
                 self._flight.note("stall_enter", self._loop_iter,
                                   over_ms=round(dur_ms, 1),
-                                  budget_ms=self.loop_budget_ms)
+                                  budget_ms=self.loop_budget_ms,
+                                  phase=self._phase.slowest)
                 try:
                     path = self._flight.dump("watchdog_stall")
                     log.warning("flight recorder dumped to %s", path)
@@ -2715,7 +2786,9 @@ class BatchScheduler:
         beat, budget = self._loop_beat, self.loop_budget_ms
         # A cleanly stopped scheduler's stale beat is not a stall; a
         # DEAD loop thread on a live scheduler very much is.
-        if beat is not None and budget and not self._closed.is_set():
+        if (beat is not None and budget and not self._closed.is_set()
+                and not (self._warm_iter == self._loop_iter
+                         and self._warmup_done_at is None)):
             cur = (time.monotonic() - beat) * 1e3
             if cur > budget:
                 stall = max(stall, cur)
@@ -2737,9 +2810,15 @@ class BatchScheduler:
                 # short arrival gap (3 ms): a concurrent burst lands in ONE
                 # big-chunk admission instead of fragmenting into serial
                 # small chunks; a lone request pays at most the gap.
-                timeout = 0.2 if (block and not out) else (0.003 if out else None)
-                slot = self._admit_q.get(block=timeout is not None,
-                                         timeout=timeout)
+                if block and not out:
+                    # Nothing live and nothing pending: the one place
+                    # the loop waits for work.
+                    with self._phase("idle"):
+                        slot = self._admit_q.get(timeout=0.2)
+                else:
+                    timeout = 0.003 if out else None
+                    slot = self._admit_q.get(block=timeout is not None,
+                                             timeout=timeout)
             except queue.Empty:
                 break
             if isinstance(slot, _WarmupJob):
@@ -2747,7 +2826,10 @@ class BatchScheduler:
                 # job per compiled program precisely so decode ticks and
                 # admissions run in between — draining them all here
                 # would stall every live stream for the whole ladder.
-                slot.run()
+                self._warm_iter = self._loop_iter
+                self._n_warmup_jobs += 1
+                with self._phase("warmup"):
+                    slot.run()
                 break
             if slot is None or self._closed.is_set():
                 if slot is not None:
@@ -3114,6 +3196,7 @@ class BatchScheduler:
         """Serving-plane gauges/counters for the /metrics endpoint (read
         from any thread; values are monotonically-written ints and
         len()s, so torn reads are harmless)."""
+        ph = self._phase
         out = {
             "serve_batch_occupancy": sum(s is not None for s in self._slots),
             "serve_batch_slots": self.num_slots,
@@ -3181,6 +3264,44 @@ class BatchScheduler:
                 self._tbt_hist.percentile(50) or 0.0, 4),
             "inter_token_p95_ms": round(
                 self._tbt_hist.percentile(95) or 0.0, 4),
+            # Loop phases (obs/phase.py): the loop thread's wall by
+            # phase, as self times, so the seven add up to no more than
+            # serve_loop_seconds_total; the rest is the loop's own
+            # bookkeeping between marks. Window differences of these
+            # are the benchmark's host_ms_per_step and
+            # device_wait_share.
+            "serve_loop_idle_seconds_total": ph.seconds("idle"),
+            "serve_loop_admit_seconds_total": ph.seconds("admit"),
+            "serve_loop_prefill_chunk_seconds_total":
+                ph.seconds("prefill_chunk"),
+            "serve_loop_decode_dispatch_seconds_total":
+                ph.seconds("decode_dispatch"),
+            "serve_loop_readback_seconds_total": ph.seconds("readback"),
+            "serve_loop_stream_seconds_total": ph.seconds("stream"),
+            "serve_loop_warmup_seconds_total": ph.seconds("warmup"),
+            "serve_loop_seconds_total": self._loop_s,
+            "serve_loop_iterations_total": self._loop_iter,
+            # Counts at the dispatch sites: admissions started (with
+            # serve_admitted_total: requests per admission), prompt
+            # positions that had to be computed against the positions
+            # the padded programs computed, live rows x steps over the
+            # decode dispatches, and the decode dispatch intervals that
+            # no admission work cut into with the steps they held
+            # (_note_clean_interval).
+            "serve_admit_batches_total": self._n_admit_batches,
+            "serve_prefill_tokens_total": self._n_prefill_tokens,
+            "serve_prefill_tokens_padded_total": self._n_prefill_padded,
+            "serve_decode_row_steps_total": self._n_decode_row_steps,
+            "serve_decode_clean_seconds_total": self._clean_s,
+            "serve_decode_clean_steps_total": self._clean_steps,
+            # Boot, set once: process start (the OS's record) until
+            # this scheduler was built; warmup() entry to its last job;
+            # the seconds of compilation and cache retrieval JAX
+            # reported in this process until then; warm-up jobs run.
+            "serve_boot_load_seconds": round(self._boot_load_s, 3),
+            "serve_boot_warmup_seconds": round(self._boot_warmup_s, 3),
+            "serve_boot_compile_seconds": round(self._boot_compile_s, 3),
+            "serve_boot_programs_total": self._n_warmup_jobs,
         }
         if self.spec_k:
             out["serve_spec_accepted_total"] = self._n_spec_accepted
@@ -3669,6 +3790,11 @@ class BatchScheduler:
         tokens, ints, floats, rings, tables = self._admit_host_arrays(
             chunk, rows, S, R, prefix)
         self._admit_since_tick = True
+        if chunk:       # warm-up's all-padding dispatches do not count
+            self._n_admit_batches += 1
+            self._n_prefill_tokens += sum(len(s.prompt_ids) - P
+                                          for s in chunk)
+            self._n_prefill_padded += R * S
 
         if prefix is not None:
             self._n_prefix_admits += len(chunk)
@@ -3776,60 +3902,61 @@ class BatchScheduler:
         """Admission epilogue shared by the single-shot program and the
         final prefill chunk: read the first tokens back, install the
         slots, stream/stop-check each first token."""
-        # graftcheck: sync-ok intentional: R int32 first tokens, TTFT depends on it
-        first_toks = np.asarray(toks_dev)
+        with self._phase("readback", rows=len(chunk)):
+            # graftcheck: sync-ok intentional: R int32 first tokens, TTFT depends on it
+            first_toks = np.asarray(toks_dev)
+        with self._phase("stream"):
+            # Draft-source admission BEFORE the install loop (a row that
+            # finishes on its very first token releases inside the loop, and
+            # release must never precede its own admit): n-gram builds its
+            # prompt index per row; the model drafter prefills every row's
+            # prompt in one batched dispatch — async, no readback, so it
+            # overlaps the first-token streaming below and whatever target
+            # work the loop does next (the PR 3 chunk ladder included).
+            # Gated on the runtime-togglable spec_k (bench A/B phases flip
+            # it): with speculation off, no drafter dispatches may run —
+            # sources late-bind at the next draft_batch instead (the model
+            # drafter's catch-up feed covers rows admitted while off).
+            if self.spec_k and self._sources and chunk:
+                ctxs = {row: slot.prompt_ids
+                        for slot, row in zip(chunk, rows)}
+                rws = [row for _, row in zip(chunk, rows)]
+                for s in self._sources:
+                    pf = getattr(s, "prefill", None)
+                    if pf is not None:
+                        pf(rws, ctxs)
+                    else:
+                        for r in rws:
+                            s.admit(r, ctxs[r])
 
-        # Draft-source admission BEFORE the install loop (a row that
-        # finishes on its very first token releases inside the loop, and
-        # release must never precede its own admit): n-gram builds its
-        # prompt index per row; the model drafter prefills every row's
-        # prompt in one batched dispatch — async, no readback, so it
-        # overlaps the first-token streaming below and whatever target
-        # work the loop does next (the PR 3 chunk ladder included).
-        # Gated on the runtime-togglable spec_k (bench A/B phases flip
-        # it): with speculation off, no drafter dispatches may run —
-        # sources late-bind at the next draft_batch instead (the model
-        # drafter's catch-up feed covers rows admitted while off).
-        if self.spec_k and self._sources and chunk:
-            ctxs = {row: slot.prompt_ids
-                    for slot, row in zip(chunk, rows)}
-            rws = [row for _, row in zip(chunk, rows)]
-            for s in self._sources:
-                pf = getattr(s, "prefill", None)
-                if pf is not None:
-                    pf(rws, ctxs)
-                else:
-                    for r in rws:
-                        s.admit(r, ctxs[r])
-
-        now = time.monotonic()
-        self._n_admitted += len(chunk)
-        if chunk:
-            self._flight.note("admit", self._loop_iter, n=len(chunk))
-        tr = self._trace
-        for i, (slot, row) in enumerate(zip(chunk, rows)):
-            slot.depart()                # reached a batch row: not queued
-            if slot.stats is not None:
-                slot.stats.ttft_s = now - slot.req.arrival_time
-            if tr is not None and slot.req.trace_sampled:
-                # Pre-first-token wall, split at the admission dispatch:
-                # queue wait (arrival -> dispatch) vs prefill compute
-                # (dispatch -> install, chunk readback included).
-                t_admit = slot.admit_t or now
-                tr.add(slot.req.trace_id, "sched.queue_wait",
-                       slot.req.arrival_time,
-                       t_admit - slot.req.arrival_time)
-                tr.add(slot.req.trace_id, "sched.prefill", t_admit,
-                       now - t_admit, tokens=len(slot.prompt_ids),
-                       row=row)
-            slot.ctx_len = len(slot.prompt_ids)
-            # last_emit_t stays 0 until _append_token below sets it: the
-            # first token's latency is TTFT, not an inter-token gap — a
-            # pre-set stamp would log a fake ~0 ms TBT sample per request.
-            self._slots[row] = slot
-            if not self._append_token(slot, row, int(first_toks[pad + i])):
-                # finished on the very first token (eos / limits)
-                self._release(row)
+            now = time.monotonic()
+            self._n_admitted += len(chunk)
+            if chunk:
+                self._flight.note("admit", self._loop_iter, n=len(chunk))
+            tr = self._trace
+            for i, (slot, row) in enumerate(zip(chunk, rows)):
+                slot.depart()                # reached a batch row: not queued
+                if slot.stats is not None:
+                    slot.stats.ttft_s = now - slot.req.arrival_time
+                if tr is not None and slot.req.trace_sampled:
+                    # Pre-first-token wall, split at the admission dispatch:
+                    # queue wait (arrival -> dispatch) vs prefill compute
+                    # (dispatch -> install, chunk readback included).
+                    t_admit = slot.admit_t or now
+                    tr.add(slot.req.trace_id, "sched.queue_wait",
+                           slot.req.arrival_time,
+                           t_admit - slot.req.arrival_time)
+                    tr.add(slot.req.trace_id, "sched.prefill", t_admit,
+                           now - t_admit, tokens=len(slot.prompt_ids),
+                           row=row)
+                slot.ctx_len = len(slot.prompt_ids)
+                # last_emit_t stays 0 until _append_token below sets it: the
+                # first token's latency is TTFT, not an inter-token gap — a
+                # pre-set stamp would log a fake ~0 ms TBT sample per request.
+                self._slots[row] = slot
+                if not self._append_token(slot, row, int(first_toks[pad + i])):
+                    # finished on the very first token (eos / limits)
+                    self._release(row)
 
     def _start_prefill_carry(self, chunk: list[_Slot], rows: list[int],
                              S: int, R: int, C: int) -> None:
@@ -3847,6 +3974,10 @@ class BatchScheduler:
             s.admit_t = t_admit
         tokens, ints, floats, rings, tables = self._admit_host_arrays(
             chunk, rows, S, R, prefix)
+        # The padded positions are counted chunk by chunk (_prefill_step).
+        P = prefix.length if prefix is not None else 0
+        self._n_admit_batches += 1
+        self._n_prefill_tokens += sum(len(s.prompt_ids) - P for s in chunk)
         self._prefill_carry = _PrefillCarry(
             chunk=chunk, rows=rows, S=S, off=0, C=C,
             prefix=prefix, kv=None,
@@ -3864,22 +3995,25 @@ class BatchScheduler:
         C = pc.C    # the carry's own width — see _PrefillCarry.C
         P0 = pc.prefix.length if pc.prefix is not None else 0
         off = pc.off
+        R = pc.tokens.shape[0]
         self._n_prefill_chunks += 1
+        self._n_prefill_padded += R * C
         self._admit_since_tick = True
         self._flight.note("prefill_chunk", self._loop_iter,
                           off=off, C=C, S=pc.S, n=len(pc.chunk))
-        kv, logits, toks_dev = self._dispatch_prefill_chunk(
-            P0, pc.S, off, C, pc.tokens[:, off: off + C], pc.ints,
-            pc.floats, pc.rings, pc.tables, pc.kv, pc.logits, pc.prefix)
-        if toks_dev is None:
-            pc.kv, pc.logits, pc.off = kv, logits, off + C
-            return
-        self._prefill_carry = None
-        if pc.prefix is not None:
-            self._n_prefix_admits += len(pc.chunk)
-            self._n_prefix_tokens += P0 * len(pc.chunk)
-        self._install_admitted(pc.chunk, pc.rows,
-                               pc.tokens.shape[0] - len(pc.chunk), toks_dev)
+        with self._phase("prefill_chunk", R=R, S=pc.S, C=C, off=off):
+            kv, logits, toks_dev = self._dispatch_prefill_chunk(
+                P0, pc.S, off, C, pc.tokens[:, off: off + C], pc.ints,
+                pc.floats, pc.rings, pc.tables, pc.kv, pc.logits, pc.prefix)
+            if toks_dev is None:
+                pc.kv, pc.logits, pc.off = kv, logits, off + C
+                return
+            self._prefill_carry = None
+            if pc.prefix is not None:
+                self._n_prefix_admits += len(pc.chunk)
+                self._n_prefix_tokens += P0 * len(pc.chunk)
+            self._install_admitted(pc.chunk, pc.rows, R - len(pc.chunk),
+                                   toks_dev)
 
     def _dispatch_prefill_chunk(self, P0: int, S: int, off: int, C: int,
                                 tokens, ints, floats, rings, tables, kv,
@@ -3949,6 +4083,29 @@ class BatchScheduler:
         self._last_decode_t = now
         self._admit_since_tick = False
 
+    # graftcheck: runs-on _loop
+    def _note_clean_interval(self, now: float, admitted: bool) -> None:
+        """_wall_hist's sample as a pair of window counters, without
+        the intervals admission work cut into. Under the one-tick-deep
+        pipeline the loop dispatches tick m as soon as tick m-2 has been
+        read back, so the interval that ends at dispatch m is the device
+        time of tick m-2 plus whatever else was queued before it, and
+        its steps are tick m-2's K. An admission dispatched in iteration
+        j therefore lands in the interval ending at dispatch j+2 (a
+        chunk, which nothing waits for), or shortens the one ending at
+        j+1 (a single-shot admission's first-token read drains the
+        pipeline): the two intervals after a flagged one are skipped
+        too."""
+        last = self._last_dispatch      # (time, K) of tick m-1, or None
+        if admitted:
+            self._clean_hold = 2
+        elif self._clean_hold:
+            self._clean_hold -= 1
+        elif last is not None and self._prev_k and now - last[0] < 0.25:
+            self._clean_s += now - last[0]
+            self._clean_steps += self._prev_k
+        self._prev_k = last[1] if last is not None else 0
+
     def _dispatch_tick(self, allow_fuse: bool = True,
                        inflight: int = 0) -> tuple:
         """Dispatch one batched decode tick (async — returns without a
@@ -3982,6 +4139,7 @@ class BatchScheduler:
             self._n_fused_ticks += 1
             self._n_fused_steps += K
         now = time.monotonic()
+        admitted = self._admit_since_tick    # cleared by the next call
         self._note_admission_gap(now)
         if (self._last_dispatch is not None
                 and now - self._last_dispatch[0] < 0.25):
@@ -3991,8 +4149,10 @@ class BatchScheduler:
             # gaps (> 250 ms) are load valleys, not decode wall.
             self._wall_hist.observe(
                 (now - self._last_dispatch[0]) * 1e3 / self._last_dispatch[1])
+        self._note_clean_interval(now, admitted)
         self._last_dispatch = (now, K)
         active = tuple(s is not None for s in self._slots)
+        self._n_decode_row_steps += sum(active) * K
         if active != self._active_host:
             # Re-upload the mask only when the active set changed (it only
             # moves on admission/finish — not per tick).
@@ -4030,26 +4190,29 @@ class BatchScheduler:
         # Failpoint: the engine's token readback (device -> host). A
         # fault here (a device reset) hits the same loop
         # recovery envelope as a dispatch fault.
-        failpoint("serve.engine.readback")
-        # graftcheck: sync-ok intentional: [B] or [K,B] int32, the tick's readback
-        toks = np.asarray(toks_dev)
+        with self._phase("readback", K=K):
+            failpoint("serve.engine.readback")
+            # graftcheck: sync-ok intentional: [B] or [K,B] int32, the tick's readback
+            toks = np.asarray(toks_dev)
         if toks.ndim == 1:
             toks = toks[None]
-        for row, slot in enumerate(snapshot):
-            # Identity check, not just done/None: the row may have been
-            # released AND re-admitted since dispatch — acting on it now
-            # (e.g. the cancelled branch's release) would evict the NEW
-            # occupant.
-            if slot is None or slot.done or self._slots[row] is not slot:
-                continue
-            if slot.cancelled.is_set():
-                self._release(row)
-                continue
-            for k in range(toks.shape[0]):
-                slot.ctx_len += 1      # decode wrote this row's next kv slot
-                if not self._append_token(slot, row, int(toks[k, row])):
+        with self._phase("stream"):
+            for row, slot in enumerate(snapshot):
+                # Identity check, not just done/None: the row may have
+                # been released AND re-admitted since dispatch — acting
+                # on it now (e.g. the cancelled branch's release) would
+                # evict the NEW occupant.
+                if (slot is None or slot.done
+                        or self._slots[row] is not slot):
+                    continue
+                if slot.cancelled.is_set():
                     self._release(row)
-                    break
+                    continue
+                for k in range(toks.shape[0]):
+                    slot.ctx_len += 1  # decode wrote this row's next kv slot
+                    if not self._append_token(slot, row, int(toks[k, row])):
+                        self._release(row)
+                        break
 
     def _ensure_sources(self) -> None:
         """Build the draft-source list (and per-source throttle/counter
@@ -4236,6 +4399,10 @@ class BatchScheduler:
 
         self._n_decode_ticks += 1
         self._n_spec_ticks += 1
+        # One step for every live row, as Observations.decode_steps
+        # counts a speculative dispatch.
+        self._n_decode_row_steps += sum(
+            s is not None for s in self._slots)
         self._last_dispatch = None    # spec wall is not decode-step wall
         # A spec tick emits tokens like a decode tick: book any pending
         # admission gap against it (the chunk's compute delayed THIS
@@ -4260,7 +4427,8 @@ class BatchScheduler:
                 jnp.asarray(max_acc), self._cache, self._active_dev,
                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
                 self._keys, self._ring_dev, self._rps_dev)
-            used = np.asarray(used_sib)  # graftcheck: sync-ok 3xB int32 verify readback
+            with self._phase("readback"):
+                used = np.asarray(used_sib)  # graftcheck: sync-ok 3xB int32 verify readback
         else:
             spec_j = self._spec_for(self._window(extra=K))
             (accepted, correction, self._next_dev, self._cache,
@@ -4270,65 +4438,67 @@ class BatchScheduler:
                 self._temps_dev, self._top_ks_dev, self._top_ps_dev, self._keys,
                 self._ring_dev, self._rps_dev)
             used = np.zeros((B,), np.int32)
-        acc = np.asarray(accepted)  # graftcheck: sync-ok 2xB int32 verify readback
-        corr = np.asarray(correction)  # graftcheck: sync-ok same dispatch, already synced
-        # Per-source EMA update over the rows THAT source drafted this
-        # tick (a source is judged on its own proposals only — the old
-        # all-active-rows denominator let undrafted rows dilute the
-        # signal). Zero-acceptance ticks decay fast (_SPEC_EMA_ZERO_
-        # ALPHA) so a never-accepting workload stops paying verify
-        # forwards within a few ticks. Sources also roll back their
-        # state to the last accepted position here (the model drafter's
-        # KV rewind — observe()).
-        for s in self._sources:
-            rows_s = src_rows.get(s.name) or []
-            if not rows_s:
-                continue
-            n_acc = sum(int(acc[r]) for r in rows_s)
-            self._n_spec_accepted_src[s.name] += n_acc
-            tick_acc = n_acc / len(rows_s)
-            alpha = (_SPEC_EMA_ZERO_ALPHA if n_acc == 0
-                     else _SPEC_EMA_ALPHA)
-            ema = (1 - alpha) * self._spec_ema[s.name] + alpha * tick_acc
-            if tick_acc >= _SPEC_EMA_FLOOR:
-                # Probe recovery: a deeply-decayed EMA (long dry spell)
-                # would need several good probes x _SPEC_PROBE_EVERY
-                # ticks to climb back over the floor — one probe whose
-                # acceptance already clears it is the recovery signal,
-                # so re-enable immediately.
-                ema = max(ema, _SPEC_EMA_SEED)
-            self._spec_ema[s.name] = ema
-            for r in rows_s:
-                # MAIN-CHAIN accepted prefix only: a used sibling's
-                # token diverges from what this source fed itself, so
-                # the drafter must rewind to just before it (the EMA
-                # above still credits the full acceptance).
-                s.observe(r, int(acc[r]) - int(used[r]))
-        for row, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            if slot.cancelled.is_set():
-                self._release(row)
-                continue
-            a = int(acc[row])
-            self._n_spec_accepted += a
-            if tree and row in proposals:
-                self._n_spec_tree_accepted += a
-            if int(used[row]):
-                # Position a-1 accepted the SIBLING token, not the main
-                # draft; the correction then comes from the sibling
-                # node's own logits.
-                a0 = a - 1
-                emitted = ([int(t) for t in drafts[row, :a0]]
-                           + [int(sib_tok[row, a0])] + [int(corr[row])])
-            else:
-                emitted = ([int(t) for t in drafts[row, :a]]
-                           + [int(corr[row])])
-            for t in emitted:
-                slot.ctx_len += 1    # per token, mirroring the plain tick
-                if not self._append_token(slot, row, t):
+        with self._phase("readback"):
+            acc = np.asarray(accepted)  # graftcheck: sync-ok 2xB int32 verify readback
+            corr = np.asarray(correction)  # graftcheck: sync-ok same dispatch, already synced
+        with self._phase("stream"):
+            # Per-source EMA update over the rows THAT source drafted this
+            # tick (a source is judged on its own proposals only — the old
+            # all-active-rows denominator let undrafted rows dilute the
+            # signal). Zero-acceptance ticks decay fast (_SPEC_EMA_ZERO_
+            # ALPHA) so a never-accepting workload stops paying verify
+            # forwards within a few ticks. Sources also roll back their
+            # state to the last accepted position here (the model drafter's
+            # KV rewind — observe()).
+            for s in self._sources:
+                rows_s = src_rows.get(s.name) or []
+                if not rows_s:
+                    continue
+                n_acc = sum(int(acc[r]) for r in rows_s)
+                self._n_spec_accepted_src[s.name] += n_acc
+                tick_acc = n_acc / len(rows_s)
+                alpha = (_SPEC_EMA_ZERO_ALPHA if n_acc == 0
+                         else _SPEC_EMA_ALPHA)
+                ema = (1 - alpha) * self._spec_ema[s.name] + alpha * tick_acc
+                if tick_acc >= _SPEC_EMA_FLOOR:
+                    # Probe recovery: a deeply-decayed EMA (long dry spell)
+                    # would need several good probes x _SPEC_PROBE_EVERY
+                    # ticks to climb back over the floor — one probe whose
+                    # acceptance already clears it is the recovery signal,
+                    # so re-enable immediately.
+                    ema = max(ema, _SPEC_EMA_SEED)
+                self._spec_ema[s.name] = ema
+                for r in rows_s:
+                    # MAIN-CHAIN accepted prefix only: a used sibling's
+                    # token diverges from what this source fed itself, so
+                    # the drafter must rewind to just before it (the EMA
+                    # above still credits the full acceptance).
+                    s.observe(r, int(acc[r]) - int(used[r]))
+            for row, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                if slot.cancelled.is_set():
                     self._release(row)
-                    break
+                    continue
+                a = int(acc[row])
+                self._n_spec_accepted += a
+                if tree and row in proposals:
+                    self._n_spec_tree_accepted += a
+                if int(used[row]):
+                    # Position a-1 accepted the SIBLING token, not the main
+                    # draft; the correction then comes from the sibling
+                    # node's own logits.
+                    a0 = a - 1
+                    emitted = ([int(t) for t in drafts[row, :a0]]
+                               + [int(sib_tok[row, a0])] + [int(corr[row])])
+                else:
+                    emitted = ([int(t) for t in drafts[row, :a]]
+                               + [int(corr[row])])
+                for t in emitted:
+                    slot.ctx_len += 1    # per token, mirroring the plain tick
+                    if not self._append_token(slot, row, t):
+                        self._release(row)
+                        break
         return True
 
     def _append_token(self, slot: _Slot, row: int, tok: int) -> bool:
@@ -4540,8 +4710,9 @@ class BatchScheduler:
         W = _bucket(slot.ctx_len, self.max_seq)
         k, v = self._extract_row_for(W)(self._cache,
                                         jnp.asarray(row, jnp.int32))
-        # graftcheck: sync-ok the park IS the host copy — one readback per finished session
-        payload = (np.asarray(k), np.asarray(v))
+        with self._phase("readback"):
+            # graftcheck: sync-ok the park IS the host copy — one readback per finished session
+            payload = (np.asarray(k), np.asarray(v))
         old = self._tier.take(key)
         if old is not None:
             self._recycle_session(old)
@@ -4574,8 +4745,10 @@ class BatchScheduler:
         padded = pages + [0] * (P2 - n)
         out = self._gather_pages_j(self._cache,
                                    jnp.asarray(padded, jnp.int32))
-        # graftcheck: sync-ok the park IS the host copy — one readback per parked session
-        payload = tuple(None if a is None else np.asarray(a) for a in out)
+        with self._phase("readback"):
+            # graftcheck: sync-ok the park IS the host copy — one readback per parked session
+            payload = tuple(None if a is None else np.asarray(a)
+                            for a in out)
         self._alloc.free(pages)
         from .kv_tier import SessionKV
         self._tier.insert(SessionKV(
@@ -4808,6 +4981,11 @@ class BatchScheduler:
         if not live:
             return demoted, unused
         self._admit_since_tick = True
+        # A wake is an admission whose program runs every row at the
+        # suffix bucket: B x S positions for the waking rows' suffixes.
+        self._n_admit_batches += 1
+        self._n_prefill_tokens += sum(int(ints[0, row]) for _, row in live)
+        self._n_prefill_padded += B * S
         prog = self._wake_for(w, S)
         args = [self._params, jnp.asarray(tokens), jnp.asarray(ints),
                 jnp.asarray(floats), jnp.asarray(rings)]
@@ -4820,43 +4998,45 @@ class BatchScheduler:
          self._temps_dev, self._top_ks_dev, self._top_ps_dev,
          self._ring_dev, self._rps_dev) = prog(*args)
         self._wake_shapes_run.add((w, S))
-        # graftcheck: sync-ok B int32 first tokens — wake TTFT depends on it
-        first_toks = np.asarray(toks_dev)
-        # Draft-source admission before the install loop (same ordering
-        # contract as _install_admitted: release never precedes admit).
-        if self.spec_k and self._sources:
-            ctxs = {row: slot.prompt_ids for slot, row in live}
-            rws = [row for _, row in live]
-            for s in self._sources:
-                pf = getattr(s, "prefill", None)
-                if pf is not None:
-                    pf(rws, ctxs)
-                else:
-                    for r in rws:
-                        s.admit(r, ctxs[r])
-        now = time.monotonic()
-        wake_ms = (now - t0) * 1e3
-        self._n_admitted += len(live)
-        # Prompt tokens whose prefill the wake skipped (everything but
-        # the new turn's suffix) — the compute-saved counter.
-        self._tier.note_waked(
-            len(live),
-            tokens_saved=sum(int(ints[1, row]) for _, row in live))
-        tr = self._trace
-        for slot, row in live:
-            self._wake_hist.observe(wake_ms)
-            slot.depart()
-            if slot.stats is not None:
-                slot.stats.ttft_s = now - slot.req.arrival_time
-            if tr is not None and slot.req.trace_sampled:
-                tr.add(slot.req.trace_id, "sched.queue_wait",
-                       slot.req.arrival_time, t0 - slot.req.arrival_time)
-                tr.add(slot.req.trace_id, "sched.wake", t0, now - t0,
-                       tokens_saved=int(ints[1, row]), row=row)
-            slot.ctx_len = len(slot.prompt_ids)
-            self._slots[row] = slot
-            if not self._append_token(slot, row, int(first_toks[row])):
-                self._release(row)
+        with self._phase("readback", rows=len(live)):
+            # graftcheck: sync-ok B int32 first tokens — wake TTFT depends on it
+            first_toks = np.asarray(toks_dev)
+        with self._phase("stream"):
+            # Draft-source admission before the install loop (same ordering
+            # contract as _install_admitted: release never precedes admit).
+            if self.spec_k and self._sources:
+                ctxs = {row: slot.prompt_ids for slot, row in live}
+                rws = [row for _, row in live]
+                for s in self._sources:
+                    pf = getattr(s, "prefill", None)
+                    if pf is not None:
+                        pf(rws, ctxs)
+                    else:
+                        for r in rws:
+                            s.admit(r, ctxs[r])
+            now = time.monotonic()
+            wake_ms = (now - t0) * 1e3
+            self._n_admitted += len(live)
+            # Prompt tokens whose prefill the wake skipped (everything but
+            # the new turn's suffix) — the compute-saved counter.
+            self._tier.note_waked(
+                len(live),
+                tokens_saved=sum(int(ints[1, row]) for _, row in live))
+            tr = self._trace
+            for slot, row in live:
+                self._wake_hist.observe(wake_ms)
+                slot.depart()
+                if slot.stats is not None:
+                    slot.stats.ttft_s = now - slot.req.arrival_time
+                if tr is not None and slot.req.trace_sampled:
+                    tr.add(slot.req.trace_id, "sched.queue_wait",
+                           slot.req.arrival_time, t0 - slot.req.arrival_time)
+                    tr.add(slot.req.trace_id, "sched.wake", t0, now - t0,
+                           tokens_saved=int(ints[1, row]), row=row)
+                slot.ctx_len = len(slot.prompt_ids)
+                self._slots[row] = slot
+                if not self._append_token(slot, row, int(first_toks[row])):
+                    self._release(row)
         return demoted, unused
 
     def _release(self, row: int) -> None:
