@@ -69,11 +69,20 @@ from .prefix import PrefixEntry, PrefixStore
 log = get_logger("serve.scheduler")
 
 _MIN_BUCKET = 16
+# Admission programs come in two widths a bucket: 1 row, for the request
+# that arrives alone or takes the one row a finished request freed, and
+# one wide program for bursts — the widest power of two, at most
+# _MAX_ADMIT_CHUNK rows (and no wider than the batch), whose
+# R x (P + S) tokens stay inside _ADMIT_WIDE_TOKENS. At about 2,048
+# tokens a prefill's matmuls are compute-bound, so a wider program buys
+# a burst no device time over several of these, and decode ticks run
+# between them (_admit_widths).
 _MAX_ADMIT_CHUNK = 8
-# Cap on one admission chunk's R x S footprint: the fused prefill
-# materialises a [L, R, S(+P), Hkv, D] small cache, so full-width chunks
-# at long prompt buckets would transiently eat gigabytes of HBM (32 x
-# 2048 at a 1B config is ~6 GB). Long prompts admit in narrower chunks.
+_ADMIT_WIDE_TOKENS = 2048
+# Cap on the R x S footprint of an operator-fixed width (admit_chunk):
+# the fused prefill materialises a [L, R, S(+P), Hkv, D] small cache, so
+# wide chunks at long prompt buckets would transiently eat gigabytes of
+# HBM (32 x 2048 at a 1B config is ~6 GB).
 _ADMIT_TOKEN_BUDGET = 16384
 # Repeat-penalty recent-token window (Ollama repeat_last_n default).
 _RING = 64
@@ -109,6 +118,11 @@ def _bucket(n: int, max_seq: int) -> int:
     while b < n:
         b *= 2
     return min(b, max_seq)
+
+
+def _pow2_floor(n: int) -> int:
+    """Largest power of two <= n; 1 for n < 1."""
+    return 1 << (max(n, 1).bit_length() - 1)
 
 
 @dataclass
@@ -303,12 +317,15 @@ class BatchScheduler:
                  kv_idle_s: float = 30.0,
                  spec_tree_nodes: int = 0,
                  spec_tree_gap: float = 4.0) -> None:
-        """``admit_chunk``: burst-admission width. None (default) admits a
-        backlog burst through one full-width prefill (minimal dispatches —
-        best p95/throughput); a fixed power-of-two (e.g. 8) staggers the
-        burst through smaller prefills so early chunks' first tokens land
-        before the whole burst's prefill compute finishes (better p50
-        TTFT, one extra dispatch + readback per chunk).
+        """``admit_chunk``: a fixed admission width an operator may set.
+        None (default): a dispatch is as wide as what it carries — the
+        1-row program for a request admitted alone, the bucket's wide
+        program (at most 8 rows, at most about 2,048 tokens:
+        _admit_widths) for requests collected together, a larger burst
+        through several of those with decode ticks in between. A fixed
+        power of two makes that the ONLY width, for every admission
+        (narrowed only where the bucket would pass the HBM budget):
+        fewer programs to warm, and every lone request padded to it.
 
         ``queue_timeout_s``: server-side admission deadline. A request
         that has not reached a batch row this long after arrival fails
@@ -593,11 +610,13 @@ class BatchScheduler:
         self._n_spec_accepted = 0     # owned-by: _loop — draft tokens accepted by verify
         # Counts at the dispatch sites (owned-by: _loop), so that ratios
         # are measured where the work happens: admission dispatches
-        # started, prompt positions that had to be computed against the
-        # positions the padded programs computed, live rows x steps per
-        # decode dispatch, and the decode dispatch intervals no
-        # admission work cut into (_note_clean_interval).
+        # started and the rows their programs were wide (R, dummy
+        # entries included), prompt positions that had to be computed
+        # against the positions the padded programs computed, live rows
+        # x steps per decode dispatch, and the decode dispatch intervals
+        # no admission work cut into (_note_clean_interval).
         self._n_admit_batches = 0
+        self._n_admit_rows_padded = 0
         self._n_prefill_tokens = 0
         self._n_prefill_padded = 0
         self._n_decode_row_steps = 0
@@ -660,13 +679,6 @@ class BatchScheduler:
         self._admit_prefix_aot: dict[tuple, object] = {}   # owned-by: _loop — (P,S,R) -> Compiled
         self._prefill_chunk_aot: dict[tuple, object] = {}  # owned-by: _loop — (P0,S,off,C,R) -> Compiled
         self._params_struct = None    # lazy jax.ShapeDtypeStruct tree of params
-        # Chunk widths promotions compile against before a warmup
-        # records the real set (mirrors warmup()'s chunk_sizes default).
-        if self.admit_chunk:
-            self._warmed_chunks: tuple[int, ...] = (self.admit_chunk,)
-        else:
-            self._warmed_chunks = tuple(sorted({
-                _MAX_ADMIT_CHUNK, max(self.num_slots, _MAX_ADMIT_CHUNK)}))
         # Fused multi-step decode state (tentpole of the wall/device-gap
         # work): the ramp remembers the last dispatched K, the counters
         # feed /metrics (decode_fused_* — realized K is steps/dispatches),
@@ -1760,13 +1772,31 @@ class BatchScheduler:
         return all((P0, S, off, C, R) in self._chunk_shapes_run
                    for off in range(0, S, C))
 
-    def _chunk_cap(self, S: int) -> int:
-        """Widest admission chunk (power of two) whose R x S footprint
-        stays inside _ADMIT_TOKEN_BUDGET; at least 1."""
-        cap, p = max(1, _ADMIT_TOKEN_BUDGET // S), 1
-        while p * 2 <= cap:
-            p *= 2
-        return p
+    def _admit_widths(self, footprint: int) -> tuple[int, ...]:
+        """Widths (rows R, ascending) of the admission programs for a
+        per-row token footprint: the suffix bucket plus any broadcast
+        prefix (the small cache is [L, R, P+S, ...], so both count).
+        Warm-up compiles exactly these, the promotion worker builds
+        exactly these, and _admit_width picks among them, so an
+        admission never meets a width nobody compiled.
+
+        Default: 1, and one wide program for bursts (see
+        _ADMIT_WIDE_TOKENS). A fixed ``admit_chunk`` is the ONLY width,
+        narrowed where the bucket would pass the HBM budget."""
+        if self.admit_chunk:
+            return (min(self.admit_chunk,
+                        _pow2_floor(_ADMIT_TOKEN_BUDGET // footprint)),)
+        wide = min(_MAX_ADMIT_CHUNK,
+                   1 << max(0, self.num_slots - 1).bit_length(),
+                   _pow2_floor(_ADMIT_WIDE_TOKENS // footprint))
+        return (1, wide) if wide > 1 else (1,)
+
+    def _admit_width(self, n: int, footprint: int) -> int:
+        """Width of the dispatch for ``n`` requests collected together:
+        the narrowest program that holds them all, else the widest (the
+        rest of the group follows in further dispatches)."""
+        widths = self._admit_widths(footprint)
+        return next((w for w in widths if w >= n), widths[-1])
 
     def _window(self, extra: int = 0) -> int:
         """Smallest power-of-two (>= 128, <= max_seq) attention window
@@ -1779,14 +1809,16 @@ class BatchScheduler:
         return min(w, self.max_seq)
 
     def warmup(self, prompt_buckets: tuple[int, ...] = (128, 256),
-               chunk_sizes: Optional[tuple[int, ...]] = None,
                windows: Optional[tuple[int, ...]] = None,
                prefix_texts: tuple[str, ...] = (),
                timeout_s: float = 1800.0) -> None:
         """Pre-compile the serving programs (first compile is tens of
-        seconds on TPU — it must not land on real requests' TTFT): one
-        admit program per (chunk size, prompt bucket), one decode (and
-        spec) program per attention window.
+        seconds on TPU — it must not land on real requests' TTFT): the
+        admit programs of every prompt bucket at each width admission
+        can choose (_admit_widths: 1 row and one wide program a bucket,
+        with their chunk ladders and prefix splices —
+        _admission_shapes), one decode (and spec) program per attention
+        window.
 
         Warmup dispatches the REAL programs on the LIVE device state with
         all-padding inputs — a no-op by the same invariants serving rests
@@ -1810,13 +1842,6 @@ class BatchScheduler:
         # tens-of-seconds TTFT on TPU — a load balancer must not route
         # here yet). A scheduler that never warms is ready immediately.
         self.note_warmup_pending()
-        if chunk_sizes is None:
-            if self.admit_chunk:
-                # A fixed admit width is the ONLY program admission uses.
-                chunk_sizes = (self.admit_chunk,)
-            else:
-                chunk_sizes = tuple(sorted({
-                    _MAX_ADMIT_CHUNK, max(self.num_slots, _MAX_ADMIT_CHUNK)}))
         buckets = sorted({_bucket(b, self.max_seq) for b in prompt_buckets})
         if windows is None:
             # The whole ladder up to max_seq: any window left uncompiled
@@ -1872,43 +1897,21 @@ class BatchScheduler:
                 n_chunk_jobs += len(jobs)
             steps.extend(jobs)
 
-        for S in buckets:
-            for R in self._chunks_for(S, chunk_sizes):
-                _extend_admit(_admit_steps(S, R))
         # Shared-prefix programs: register the known templates (builds
-        # their KV — one prefill compile per distinct P), then compile the
-        # prefix-admission program for every (chunk, suffix bucket, P)
-        # combination so a template hit never compiles mid-serving.
+        # their KV — one prefill compile per distinct P) before the
+        # splice programs that look their entries up at run time. The P
+        # set is known before the register jobs run: already-cached
+        # lengths plus the exact token length of each template.
+        plens: set[int] = set()
+        if self._prefix is not None:
+            plens.update(self._prefix.lengths())
         for text in prefix_texts:
             steps.append(lambda t=text: self.register_prefix(t))
-        if self._prefix is not None:
-            # One queued job per (P, S, R) program. The P set is known
-            # before the register jobs run: already-cached lengths plus
-            # the exact token length of each template being registered.
-            plens = set(self._prefix.lengths())
-            for text in prefix_texts:
-                n = self._registered_prefix_len(text, quiet=True)
-                if n > 0:
-                    plens.add(n)
-            for P in sorted(plens):
-                for S in buckets:
-                    if P + S > self.max_seq:
-                        continue
-                    for R in self._chunks_for(P + S, chunk_sizes):
-                        _extend_admit(_admit_steps(S, R, P0=P))
-            # Grain pre-warm: auto-promoted prefixes always land on the
-            # grain ladder, so compiling each grain's splice program for
-            # the SMALLEST suffix bucket now (synthetic zero entries —
-            # only shapes matter to the compile cache) means a hot
-            # template promoted mid-traffic admits through a warm
-            # program. Bounded: grains x 1 bucket x chunk widths.
-            smallest = buckets[0] if buckets else 0
-            for P in (self._prefix.grain_ladder if buckets else ()):
-                if P in plens or P + smallest > self.max_seq:
-                    continue
-                for R in self._chunks_for(P + smallest, chunk_sizes):
-                    _extend_admit(_admit_steps(smallest, R, P0=P,
-                                               synthetic=True))
+            n = self._registered_prefix_len(text, quiet=True)
+            if self._prefix is not None and n > 0:
+                plens.add(n)
+        for P, S, R, synthetic in self._admission_shapes(buckets, plens):
+            _extend_admit(_admit_steps(S, R, P0=P, synthetic=synthetic))
         for w in windows:
             steps.append(lambda w=w: self._warm_window(w))
         if self._tier is not None:
@@ -1937,10 +1940,6 @@ class BatchScheduler:
         # (_serving_bucket) — recorded only after every program compiled.
         def _record():
             self._warmed_buckets = buckets
-            # Promotion AOT builds mirror the warmed admission surface:
-            # the worker compiles one splice program per (warmed bucket,
-            # chunk-width) combo for the freshly promoted prefix length.
-            self._warmed_chunks = chunk_sizes
             # Long-window kernel ladder: name which warmed windows baked
             # in the multi-chunk flash-append kernel (W >= min_w on TPU
             # — ops/paged_attention._flash_append_policy). The windows
@@ -1955,10 +1954,11 @@ class BatchScheduler:
                 if kernel_ws:
                     flash_note = (f", flash-append kernel at windows "
                                   f"{kernel_ws} (min_w {min_w})")
-            log.info("warmup compiled: admit %s x buckets %s, decode "
+            log.info("warmup compiled: admit widths by bucket %s, decode "
                      "windows %s, prefill chunk %d (%d continuation "
-                     "programs)%s", chunk_sizes, buckets, windows,
-                     self.prefill_chunk, n_chunk_jobs, flash_note)
+                     "programs)%s",
+                     {S: self._admit_widths(S) for S in buckets},
+                     windows, self.prefill_chunk, n_chunk_jobs, flash_note)
         steps.append(_record)
         # Drain the dispatch queue at the end: warmup executions are
         # async — without a readback the first real request queues
@@ -2006,6 +2006,31 @@ class BatchScheduler:
             self.warmup_error = f"{type(e).__name__}: {e}"
             raise
 
+    def _admission_shapes(self, buckets: list[int],
+                          plens: set[int]) -> list[tuple]:
+        """The admission surface warm-up compiles, as (P, S, R,
+        synthetic): every suffix bucket S at each width _admit_widths
+        allows, without a prefix (P = 0) and behind each cached or
+        registered prefix length in ``plens`` — so neither a lone
+        request nor a burst, template hit or miss, compiles
+        mid-serving. Then the grain pre-warm: auto-promoted prefixes
+        always land on the grain ladder, so compiling each grain's
+        splice program for the SMALLEST suffix bucket now (synthetic
+        zero entries — only shapes matter to the compile cache) means
+        a hot template promoted mid-traffic admits through a warm
+        program. Bounded: grains x 1 bucket x widths."""
+        shapes = [(P, S, R, False)
+                  for P in [0] + sorted(plens) for S in buckets
+                  if P + S <= self.max_seq
+                  for R in self._admit_widths(P + S)]
+        if self._prefix is not None and buckets:
+            S = buckets[0]
+            shapes += [(P, S, R, True)
+                       for P in self._prefix.grain_ladder
+                       if P not in plens and P + S <= self.max_seq
+                       for R in self._admit_widths(P + S)]
+        return shapes
+
     def _build_promotion(self) -> None:
         """Hand one queued prefix promotion to the build worker
         (scheduler thread only). The worker computes the prefix KV AND
@@ -2029,7 +2054,7 @@ class BatchScheduler:
     def _promotion_combos(self, P: int) -> list[tuple]:
         """Admission shapes a fresh prefix of length ``P`` can serve
         through, mirroring warmup()'s prefix sub-ladder: one
-        (S, R, C, offs) per (warmed suffix bucket, chunk width) — offs
+        (S, R, C, offs) per (warmed suffix bucket, admit width) — offs
         is the continuation-chunk offset ladder for chunked buckets,
         None for single-shot. Shapes already compiled (a prior
         promotion at the same grain, or the warmup grain pre-warm's
@@ -2039,7 +2064,7 @@ class BatchScheduler:
         for S in (getattr(self, "_warmed_buckets", None) or ()):
             if P + S > self.max_seq:
                 continue
-            for R in self._chunks_for(P + S, self._warmed_chunks):
+            for R in self._admit_widths(P + S):
                 if C and S > C and S % C == 0:
                     offs = tuple(
                         off for off in range(0, S, C)
@@ -2180,14 +2205,6 @@ class BatchScheduler:
                 head, k, v,
                 note=(f", promoted off-thread, "
                       f"{len(aot_admit) + len(aot_chunks)} AOT programs"))
-
-    def _chunks_for(self, footprint: int,
-                    chunk_sizes: tuple[int, ...]) -> list[int]:
-        """Chunk widths for a per-row token footprint (the suffix bucket
-        plus any broadcast prefix — the small cache is [L, R, P+S, ...],
-        so the budget must count both)."""
-        cap = self._chunk_cap(footprint)
-        return sorted({min(R, cap) for R in chunk_sizes})
 
     def _warm_prefix_combo(self, P: int, S: int, R: int,
                            synthetic: bool = False) -> None:
@@ -3282,13 +3299,16 @@ class BatchScheduler:
             "serve_loop_seconds_total": self._loop_s,
             "serve_loop_iterations_total": self._loop_iter,
             # Counts at the dispatch sites: admissions started (with
-            # serve_admitted_total: requests per admission), prompt
-            # positions that had to be computed against the positions
-            # the padded programs computed, live rows x steps over the
-            # decode dispatches, and the decode dispatch intervals that
-            # no admission work cut into with the steps they held
+            # serve_admitted_total: requests per admission) and the
+            # rows of their programs (1 - admitted / rows: the share
+            # of rows that were dummy entries), prompt positions that
+            # had to be computed against the positions the padded
+            # programs computed, live rows x steps over the decode
+            # dispatches, and the decode dispatch intervals that no
+            # admission work cut into with the steps they held
             # (_note_clean_interval).
             "serve_admit_batches_total": self._n_admit_batches,
+            "serve_admit_rows_padded_total": self._n_admit_rows_padded,
             "serve_prefill_tokens_total": self._n_prefill_tokens,
             "serve_prefill_tokens_padded_total": self._n_prefill_padded,
             "serve_decode_row_steps_total": self._n_decode_row_steps,
@@ -3473,9 +3493,10 @@ class BatchScheduler:
 
     def _admit_pending(self, block: bool) -> None:
         """Admit pending requests into free rows: group by prompt bucket,
-        prefill each group in power-of-two chunks (one fused dispatch per
-        chunk). Paged mode first retries page-starved waiters (FIFO), then
-        pulls fresh requests while pages and rows last.
+        prefill each group in chunks as wide as the requests they carry
+        (one fused dispatch per chunk, _admit_width). Paged mode first
+        retries page-starved waiters (FIFO), then pulls fresh requests
+        while pages and rows last.
 
         While decode is active, at most ONE chunk is admitted per call
         (the rest carries to the next loop iteration), so a multi-chunk
@@ -3673,20 +3694,12 @@ class BatchScheduler:
         groups = sorted(by_bucket.items())
         for gi, ((pkey, S), group) in enumerate(groups):
             while group:
-                # A backlog burst is admitted through the full-width program
-                # (one prefill for up to num_slots requests) instead of
-                # queueing behind _MAX_ADMIT_CHUNK-sized dispatches — unless
-                # a fixed admit_chunk asks for staggered-TTFT chunking.
-                if self.admit_chunk:
-                    R = self.admit_chunk
-                else:
-                    R = (max(self.num_slots, _MAX_ADMIT_CHUNK)
-                         if len(group) > _MAX_ADMIT_CHUNK else _MAX_ADMIT_CHUNK)
-                # Long buckets admit in narrower chunks: the fused
-                # prefill's [L, R, P+S, ..] small cache must stay inside
-                # the admission HBM budget (matches the warmed widths;
-                # prefix-cached groups count their broadcast prefix too).
-                R = min(R, self._chunk_cap(S + len(pkey)))
+                # The dispatch is as wide as what it carries: a lone
+                # request runs the 1-row program, a burst the bucket's
+                # wide one, several times over if the group is larger
+                # (both warmed: _admit_widths). A prefix-cached group
+                # counts its broadcast prefix in the footprint too.
+                R = self._admit_width(len(group), S + len(pkey))
                 chunk = group[:R]
                 group = group[R:]
                 rows = [free.pop(0) for _ in range(len(chunk))]
@@ -3755,16 +3768,19 @@ class BatchScheduler:
                     self._recover_cache()
 
     def _admit_chunk(self, chunk: list[_Slot], rows: list[int], S: int,
-                     R: int = _MAX_ADMIT_CHUNK,
+                     R: int,
                      warm_prefix: Optional[PrefixEntry] = None) -> None:
         """One fused dispatch: batched prefill of ``chunk`` + kv splice into
         ``rows`` + first-token sample per row.
 
-        The program shape is (R, S) with R from a two-size ladder: short
-        chunks are padded with dummy entries whose row index is the
-        out-of-range sentinel ``num_slots`` — every install of theirs is
-        scatter-dropped — so only two programs per prompt bucket are ever
-        compiled.
+        The program shape is (R, S) with R the narrowest of the bucket's
+        widths that holds the chunk (_admit_widths: 1 row, and one wide
+        program for bursts — two programs per prompt bucket). A chunk
+        shorter than R is padded with dummy entries whose row index is
+        the out-of-range sentinel ``num_slots`` — every install of
+        theirs is scatter-dropped. ``serve_admit_rows_padded_total``
+        sums R over the dispatches; ``serve_admitted_total`` over it is
+        the share of rows that carried a request.
 
         A prefix-cached chunk (every slot carries the same
         ``slot.prefix``; _admit_pending groups by entry) uploads only the
@@ -3786,12 +3802,12 @@ class BatchScheduler:
             s.admit_t = t_admit
         prefix = chunk[0].prefix if chunk else warm_prefix
         P = prefix.length if prefix is not None else 0
-        pad = R - len(chunk)
         tokens, ints, floats, rings, tables = self._admit_host_arrays(
             chunk, rows, S, R, prefix)
         self._admit_since_tick = True
         if chunk:       # warm-up's all-padding dispatches do not count
             self._n_admit_batches += 1
+            self._n_admit_rows_padded += R
             self._n_prefill_tokens += sum(len(s.prompt_ids) - P
                                           for s in chunk)
             self._n_prefill_padded += R * S
@@ -3852,7 +3868,7 @@ class BatchScheduler:
                     self._keys, self._next_dev, self._temps_dev,
                     self._top_ks_dev, self._top_ps_dev, self._ring_dev,
                     self._rps_dev)
-        self._install_admitted(chunk, rows, pad, toks_dev)
+        self._install_admitted(chunk, rows, toks_dev)
 
     def _admit_host_arrays(self, chunk: list[_Slot], rows: list[int],
                            S: int, R: int,
@@ -3862,9 +3878,15 @@ class BatchScheduler:
         two admission paths cannot drift. Returns (tokens [R,S], ints
         [5,R] = lens/rows/seeds/top_k/total-lens, floats [3,R], rings
         [R,_RING], tables [R,mppr] or None); the non-prefix single-shot
-        programs consume ``ints[:4]``."""
+        programs consume ``ints[:4]``.
+
+        The requests take the FIRST entries and the dummy entries
+        follow: a routed MLP's capacity buckets fill in entry order
+        (models/mixtral.moe_mlp), and the dummy entries, all token 0,
+        agree on their two experts in every layer — ahead of the
+        requests they filled those buckets and a quarter of the real
+        tokens' assignments were dropped (PERF.md §6, PR 24)."""
         P = prefix.length if prefix is not None else 0
-        pad = R - len(chunk)
         tokens = np.zeros((R, S), np.int32)
         ints = np.zeros((5, R), np.int32)
         floats = np.zeros((3, R), np.float32)       # temp/top_p/repeat_pen
@@ -3874,8 +3896,7 @@ class BatchScheduler:
         ints[4] = P + 1
         floats[1] = 1.0
         floats[2] = 1.0
-        for i, (slot, row) in enumerate(zip(chunk, rows)):
-            r = pad + i
+        for r, (slot, row) in enumerate(zip(chunk, rows)):
             suffix = slot.prompt_ids[P:]
             tokens[r, : len(suffix)] = suffix
             o = slot.req.options
@@ -3893,12 +3914,12 @@ class BatchScheduler:
         tables = None
         if self.kv_mode == "paged":
             tables = np.zeros((R, self._cache.max_pages_per_row), np.int32)
-            for i, slot in enumerate(chunk):
-                tables[pad + i, : len(slot.pages)] = slot.pages
+            for r, slot in enumerate(chunk):
+                tables[r, : len(slot.pages)] = slot.pages
         return tokens, ints, floats, rings, tables
 
     def _install_admitted(self, chunk: list[_Slot], rows: list[int],
-                          pad: int, toks_dev) -> None:
+                          toks_dev) -> None:
         """Admission epilogue shared by the single-shot program and the
         final prefill chunk: read the first tokens back, install the
         slots, stream/stop-check each first token."""
@@ -3954,7 +3975,7 @@ class BatchScheduler:
                 # first token's latency is TTFT, not an inter-token gap — a
                 # pre-set stamp would log a fake ~0 ms TBT sample per request.
                 self._slots[row] = slot
-                if not self._append_token(slot, row, int(first_toks[pad + i])):
+                if not self._append_token(slot, row, int(first_toks[i])):
                     # finished on the very first token (eos / limits)
                     self._release(row)
 
@@ -3977,6 +3998,7 @@ class BatchScheduler:
         # The padded positions are counted chunk by chunk (_prefill_step).
         P = prefix.length if prefix is not None else 0
         self._n_admit_batches += 1
+        self._n_admit_rows_padded += R
         self._n_prefill_tokens += sum(len(s.prompt_ids) - P for s in chunk)
         self._prefill_carry = _PrefillCarry(
             chunk=chunk, rows=rows, S=S, off=0, C=C,
@@ -4012,8 +4034,7 @@ class BatchScheduler:
             if pc.prefix is not None:
                 self._n_prefix_admits += len(pc.chunk)
                 self._n_prefix_tokens += P0 * len(pc.chunk)
-            self._install_admitted(pc.chunk, pc.rows, R - len(pc.chunk),
-                                   toks_dev)
+            self._install_admitted(pc.chunk, pc.rows, toks_dev)
 
     def _dispatch_prefill_chunk(self, P0: int, S: int, off: int, C: int,
                                 tokens, ints, floats, rings, tables, kv,
@@ -4984,6 +5005,7 @@ class BatchScheduler:
         # A wake is an admission whose program runs every row at the
         # suffix bucket: B x S positions for the waking rows' suffixes.
         self._n_admit_batches += 1
+        self._n_admit_rows_padded += B
         self._n_prefill_tokens += sum(int(ints[0, row]) for _, row in live)
         self._n_prefill_padded += B * S
         prog = self._wake_for(w, S)

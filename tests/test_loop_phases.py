@@ -44,7 +44,8 @@ CFG = get_config("tiny")
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
 PHASE_SERIES = [f"serve_loop_{n}_seconds_total" for n in PHASES]
-SITE_COUNTERS = ("serve_admit_batches_total", "serve_prefill_tokens_total",
+SITE_COUNTERS = ("serve_admit_batches_total", "serve_admit_rows_padded_total",
+                 "serve_prefill_tokens_total",
                  "serve_prefill_tokens_padded_total",
                  "serve_decode_row_steps_total")
 
@@ -212,8 +213,9 @@ def test_site_counters_are_exact(served):
     assert m["serve_admit_batches_total"] == 3
     assert m["prefill_chunks_total"] == 2
     assert m["serve_prefill_tokens_total"] == 11 + 41 + 21
-    # Every program is 8 rows wide: 8 x 16, 2 x 8 x 32, 8 x 32.
-    assert m["serve_prefill_tokens_padded_total"] == 128 + 512 + 256
+    # Each arrived alone, so every program is 1 row wide: 16, 2 x 32, 32.
+    assert m["serve_admit_rows_padded_total"] == 3
+    assert m["serve_prefill_tokens_padded_total"] == 16 + 64 + 32
     # One live row, K = 1: a request of n tokens takes its first from
     # the prefill and n dispatches (n - 1 steps and the one the
     # pipeline had already sent when the last token was read).
