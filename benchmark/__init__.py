@@ -6,9 +6,11 @@ gain cannot edit it: traffic generation (traffic.py), the load generator
 device trace to metrics (metrics.py, layer_metrics/, trace_reduce.py),
 the table of peaks and the bytes and operations of a step (peaks.json,
 roofline.py), the plain reference and the comparison that decides
-``correct`` (reference.py). From the program it takes only the system
-under test, its /metrics counters, its /admin/trace spans and the names
-the profiler prints.
+``correct`` (reference.py for the mathematics and the tolerances; one
+file per model family under architectures/ for the field mapping, the
+reader of the engine's tree and the verdict). From the program it takes
+only the system under test, its /metrics counters, its /admin/trace
+spans and the names the profiler prints.
 
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 

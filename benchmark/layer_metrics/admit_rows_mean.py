@@ -2,7 +2,7 @@
 differences of ``serve_admitted_total`` / ``serve_admit_batches_total``
 (one batch per admission dispatch started: a single-shot program, a
 chunk ladder or a session wake; warm-up's all-padding dispatches are
-not counted). The programs come 8 or 32 rows wide."""
+not counted). The programs come 1 row and at most 8 rows wide."""
 
 
 def read(obs):

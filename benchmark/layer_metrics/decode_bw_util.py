@@ -1,8 +1,9 @@
 """Model programs: bytes the decode steps of the window had to read
 (roofline.decode_step_bytes at the mean live rows and mean context) x
-steps / (window seconds x the device's peak bytes/s), %. An end-to-end
-utilisation of the memory system by decode alone; not a kernel's
-roofline share."""
+steps / (window seconds x the cell's chips x one chip's peak bytes/s),
+%: a model sharded over four chips is read by four memory systems. An
+end-to-end utilisation of the memory system by decode alone; not a
+kernel's roofline share."""
 from benchmark import roofline
 
 
@@ -19,4 +20,4 @@ def read(obs):
     ctx = sum(r.prompt_bytes + 1 + r.tokens / 2 for r in ok) / len(ok)
     per_step = roofline.decode_step_bytes(obs.cell.config, rows=rows,
                                           context=ctx)
-    return 100.0 * per_step * steps / (obs.window_s * bw)
+    return 100.0 * per_step * steps / (obs.window_s * obs.cell.chips * bw)

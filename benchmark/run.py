@@ -14,9 +14,10 @@ goes on earlier lines and into ``chiprun_out/``.
 
 ``--trace 0`` reports the cell's end-to-end metrics, with tracing off.
 ``--trace 1`` reports its per-layer metrics: the child records a
-profiler trace of a 4 s stretch in the middle of the window and reduces
-it itself, requests carry a trace header and their spans are fetched
-from /admin/trace afterwards, and /metrics is sampled at 2 Hz.
+profiler trace of a stretch in the middle of the window (4 s on one
+chip, 1 s on four) and reduces it itself, requests carry a trace header
+and their spans are fetched from /admin/trace afterwards, and /metrics
+is sampled at 2 Hz.
 """
 
 from __future__ import annotations
@@ -150,8 +151,7 @@ def start_child(cell, port: int, ctl_port: int, out_dir: str, traced: bool
         proc = subprocess.Popen(
             [sys.executable, os.path.join(ROOT, "benchmark", "serve_cell.py"),
              "--config-file", cell.config_file, "--control-port",
-             str(ctl_port),
-             "--out-dir", out_dir],
+             str(ctl_port), "--out-dir", out_dir, "--root", cell.root],
             cwd=ROOT, env=child_env(cell, port, traced), stdout=log,
             stderr=subprocess.STDOUT, start_new_session=True)
     return proc, log_path
@@ -251,10 +251,15 @@ class Monitor(threading.Thread):
     window's two ends; in a traced run also at 2 Hz, and the trace of a
     stretch in the middle of the window."""
 
-    def __init__(self, url: str, ctl: str, run: loadgen.Run, traced: bool
-                 ) -> None:
+    def __init__(self, url: str, ctl: str, run: loadgen.Run, traced: bool,
+                 chips: int = 1) -> None:
         super().__init__(daemon=True, name="bench-monitor")
         self.url, self.ctl, self.run_, self.traced = url, ctl, run, traced
+        # The trace holds every chip's events, and what stop_trace takes
+        # to write grows with them (11-32 s for 4 s of one chip; over
+        # the 120 s allowed for 4 s of four: PERF.md, PR 25). The stretch
+        # is TRACE_STRETCH_S chip-seconds.
+        self.trace_stretch_s = TRACE_STRETCH_S / chips
         self.start_c, self.end_c = {}, {}
         self.samples: list = []
         self.stretch = ({}, {})
@@ -278,7 +283,8 @@ class Monitor(threading.Thread):
             self.start_c = scrape(self.url)[0]
             if self.traced:
                 mid = r.ramp_s + r.window_s / 2
-                t_a, t_b = mid - TRACE_STRETCH_S / 2, mid + TRACE_STRETCH_S / 2
+                t_a = mid - self.trace_stretch_s / 2
+                t_b = mid + self.trace_stretch_s / 2
                 t, a, b = r.ramp_s, None, None
                 while t < r.stop_t - 1.0 / SAMPLE_HZ:
                     t += 1.0 / SAMPLE_HZ
@@ -375,7 +381,7 @@ def run_cell(args, t_start: float, data_root: str = ROOT,
 
         run = loadgen.Run("127.0.0.1", port, cell.traffic, args.seed,
                           RAMP_S, float(args.seconds), traced=traced)
-        mon = Monitor(url, ctl, run, traced)
+        mon = Monitor(url, ctl, run, traced, cell.chips)
         mon.start()
         records = run.play()
         mon.join(timeout=180)

@@ -2,7 +2,9 @@
 """The one child of a run: the process that holds the chip.
 
 It builds the cell's ``ModelConfig`` from the configuration file's
-published fields, registers it under the configuration's name, installs
+published fields (through the family's architecture file, which also
+holds the plain reference and the reader of the engine's tree:
+manifest.py), registers it under the configuration's name, installs
 the benchmark tokenizer, and calls ``p2p_llm_chat_tpu.serve.api.main()``:
 the normal entry point, scheduler, cache and HTTP front. The serving
 stack's settings arrive as ``SERVE_*`` variables from the parent
@@ -30,7 +32,8 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_ROOT = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH_ROOT)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
@@ -67,34 +70,37 @@ def make_tokenizer_class():
     return BenchTokenizer
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` from the published field names."""
+def architecture(cfg: dict, root: str = BENCH_ROOT):
+    """The configuration's architecture file, loaded: everything
+    particular to its model family (manifest.py has the contract)."""
+    from benchmark import manifest
+    return manifest.load_architecture(
+        root, cfg.get("architecture", manifest.DEFAULT_ARCHITECTURE))
+
+
+def model_config(cfg: dict, root: str = BENCH_ROOT):
+    """The program's ``ModelConfig``, from the configuration file by way
+    of its family's architecture file."""
+    import dataclasses
+    from benchmark import manifest
     from p2p_llm_chat_tpu.models.configs import ModelConfig
-    heads = cfg["num_attention_heads"]
-    return ModelConfig(
-        name=cfg["name"], vocab_size=cfg["vocab_size"],
-        hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_layers=cfg["num_hidden_layers"], num_heads=heads,
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
-        max_seq_len=cfg["max_position_embeddings"],
-        rope_theta=float(cfg["rope_theta"]), rope_scaling=None,
-        rms_norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        num_experts=cfg.get("num_local_experts", 0),
-        num_experts_per_tok=cfg.get("num_experts_per_tok", 0),
-        moe_capacity_factor=cfg.get("moe_capacity_factor"),
-        bos_token_id=cfg.get("bos_token_id", 1),
-        eos_token_ids=())       # ignore_eos: see the configuration file
+    arch = architecture(cfg, root)
+    kwargs = arch.model_config(cfg)
+    unknown = sorted(set(kwargs)
+                     - {f.name for f in dataclasses.fields(ModelConfig)})
+    if unknown:
+        raise manifest.ManifestError(
+            f"{arch.__file__}: model_config() returns {unknown}, which "
+            f"the program's ModelConfig does not have")
+    return ModelConfig(**kwargs)
 
 
-def install(cfg: dict, captured: dict) -> None:
+def install(cfg: dict, captured: dict, root: str = BENCH_ROOT) -> None:
     """Register the configuration, install the tokenizer, and wrap the
     engine builder so that the control port can reach the engine."""
     from p2p_llm_chat_tpu.models import configs
     from p2p_llm_chat_tpu.serve import engine
-    configs.CONFIGS[cfg["name"]] = model_config(cfg)
+    configs.CONFIGS[cfg["name"]] = model_config(cfg, root)
     engine.ByteTokenizer = make_tokenizer_class()
     build = engine.build_engine_from_env
 
@@ -134,19 +140,21 @@ class CompileLog:
 
 # -- the reference check ------------------------------------------------------
 
-def system_logits(sched, tokens):
+def system_logits(sched, tokens, n_prefill: int):
     """The system's logits for ``tokens`` [B, P+D]: prefill of the first
-    P, spliced into a paged int8 cache as admission does, then D decode
-    steps through it, with the model functions and on the parameter tree
-    the scheduler serves from. Returns [B, P+D, V] float32."""
+    P = ``n_prefill``, spliced into a paged int8 cache as admission
+    does, then D decode steps through it, with the model functions, on
+    the parameter tree and under the mesh the scheduler serves with
+    (``None`` on one chip). Returns [B, P+D, V] float32."""
     import jax
     import jax.numpy as jnp
     from p2p_llm_chat_tpu.models.llama import KVCache
     from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,
                                                write_prefill_batch)
     model, params, config = sched._model, sched._params, sched.config
+    mesh = sched.mesh
     B, T = tokens.shape
-    P = REF_PREFILL
+    P = n_prefill
     ps = sched.page_size
     per_row = -(-(T + 1) // ps)
     window_pages = 1
@@ -161,16 +169,16 @@ def system_logits(sched, tokens):
     def prefill(params, toks):
         small = KVCache.create(config, B, P, dtype=sched._dtype)
         logits, small = model.prefill(params, config, toks, lens, small,
-                                      None, last_only=False)
+                                      mesh, last_only=False)
         cache = PagedKVCache.create(config, B, 1 + B * per_row, ps,
                                     max_pages_per_row=per_row,
-                                    quantized=sched.kv_quant)
+                                    quantized=sched.kv_quant, mesh=mesh)
         return logits, write_prefill_batch(cache, small.k, small.v, rows,
                                            lens, tables)
 
     @jax.jit
     def decode(params, tok, cache):
-        return model.decode_step_paged(params, config, tok, cache,
+        return model.decode_step_paged(params, config, tok, cache, mesh,
                                        pages=window_pages)
 
     logits, cache = prefill(params, tokens[:, :P])
@@ -181,91 +189,33 @@ def system_logits(sched, tokens):
     return jnp.concatenate(out, axis=1)
 
 
-def _deq(q, s):
-    import jax.numpy as jnp
-    return q.astype(jnp.float32) * s.astype(jnp.float32)
-
-
-def reference_check(sched, cfg: dict, seed: int) -> dict:
-    """Compare the system with benchmark/reference.py on REF_SEQS seeded
-    sequences: REF_PREFILL tokens of prefill, then REF_DECODE decode
-    steps through the paged int8 cache. The reference gets the engine's
-    own weights, dequantised one layer (one expert) at a time."""
-    import jax
+def reference_check(sched, cfg: dict, seed: int,
+                    root: str = BENCH_ROOT) -> dict:
+    """Compare the system with its family's plain reference on REF_SEQS
+    seeded sequences: REF_PREFILL tokens of prefill, then REF_DECODE
+    decode steps. Everything particular to the family (how the engine's
+    tree is read, the reference, the verdict) is its architecture
+    file's."""
     import jax.numpy as jnp
     import numpy as np
-    from benchmark import reference
 
     t0 = time.monotonic()
-    if sched.kv_mode != "paged":
-        return {"ok": False, "error": "the check drives the paged cache; "
-                                      f"SERVE_KV is {sched.kv_mode!r}"}
-    params, config = sched._params, sched.config
-    layers = params["layers"]
+    arch = architecture(cfg, root)
+    drive = getattr(arch, "system_logits", None)
+    if drive is None:
+        if sched.kv_mode != "paged":
+            return {"ok": False, "error": "the check drives the paged "
+                                          f"cache; SERVE_KV is "
+                                          f"{sched.kv_mode!r}"}
+        drive = system_logits
     rng = np.random.default_rng(seed)
     tokens = jnp.asarray(rng.integers(
-        0, config.vocab_size, size=(REF_SEQS, REF_PREFILL + REF_DECODE)),
-        jnp.int32)
-    system = system_logits(sched, tokens)
-    Q, KV = config.q_dim, config.kv_dim
-    f32 = jnp.float32
-
-    # The tree is an argument, never a closure: a jitted closure would
-    # bake 8 GB of weights into the program as constants.
-    @jax.jit
-    def _layer_weights(layers, layer):
-        take = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False)
-        wqkv = _deq(take(layers["wqkv"].q), take(layers["wqkv"].s))
-        w = {"attn_norm": take(layers["attn_norm"]).astype(f32),
-             "mlp_norm": take(layers["mlp_norm"]).astype(f32),
-             "wq": wqkv[:, :Q], "wk": wqkv[:, Q:Q + KV],
-             "wv": wqkv[:, Q + KV:],
-             "wo": _deq(take(layers["wo"].q), take(layers["wo"].s))}
-        if config.is_moe:
-            w["router"] = take(layers["router"]).astype(f32)
-        else:
-            E = config.intermediate_size
-            wgu = _deq(take(layers["wgu"].q), take(layers["wgu"].s))
-            w.update(w_gate=wgu[:, :E], w_up=wgu[:, E:],
-                     w_down=_deq(take(layers["w_down"].q),
-                                 take(layers["w_down"].s)))
-        return w
-
-    @jax.jit
-    def _expert_weights(wgu_e, w_down, layer, e):
-        E = config.intermediate_size
-        wgu = _deq(wgu_e.q[layer, e], wgu_e.s[layer, e])
-        return (wgu[:, :E], wgu[:, E:],
-                _deq(w_down.q[layer, e], w_down.s[layer, e]))
-
-    def layer_weights(layer):
-        return _layer_weights(layers, layer)
-
-    def expert_weights(layer, e):
-        return _expert_weights(layers["wgu_e"], layers["w_down"], layer, e)
-
-    head = params["lm_head"]
-    lm_head = (_deq(head.q, head.s) if hasattr(head, "q")
-               else head.astype(f32))
-    ref, facts = reference.forward(
-        cfg, tokens, params["embed"], layer_weights,
-        params["final_norm"].astype(f32), lm_head,
-        expert_weights=expert_weights if config.is_moe else None)
-    out = reference.compare(system, ref, routed=config.is_moe)
-    if config.is_moe:
-        # What the prefill's capacity buckets dropped, by the
-        # reference's own routing of the prefill tokens.
-        n_prefill = REF_SEQS * REF_PREFILL
-        cap = max(1, int((config.moe_capacity_factor or 0) * n_prefill
-                         * config.num_experts_per_tok / config.num_experts))
-        keep = jnp.tile(jnp.arange(REF_PREFILL + REF_DECODE) < REF_PREFILL,
-                        REF_SEQS)
-        out["capacity"] = cap
-        out["overflow_pairs"] = (
-            sum(reference.expert_overflow(w[keep], cap)
-                for w in facts["routing"])
-            if config.moe_capacity_factor else 0)
-        out["near_ties"] = int(jnp.sum(facts["min_margin"] < 0.02))
+        0, sched.config.vocab_size,
+        size=(REF_SEQS, REF_PREFILL + REF_DECODE)), jnp.int32)
+    system = drive(sched, tokens, REF_PREFILL)
+    ref, facts = arch.forward(cfg, tokens, arch.engine_weights(sched))
+    out = arch.compare(system, ref, {**facts, "n_prefill": REF_PREFILL},
+                       cfg)
     out["seconds"] = time.monotonic() - t0
     return out
 
@@ -274,8 +224,8 @@ def reference_check(sched, cfg: dict, seed: int) -> dict:
 
 class Control:
     def __init__(self, cfg: dict, out_dir: str, captured: dict,
-                 compiles: CompileLog) -> None:
-        self.cfg, self.out_dir = cfg, out_dir
+                 compiles: CompileLog, root: str = BENCH_ROOT) -> None:
+        self.cfg, self.out_dir, self.root = cfg, out_dir, root
         self.captured, self.compiles = captured, compiles
         self.window_t0 = None
         self.trace_dir = os.path.join(out_dir, "trace")
@@ -305,7 +255,7 @@ class Control:
             return self.device()
         if path == "/refcheck":
             return reference_check(self._sched(), self.cfg,
-                                   int(query.get("seed", 0)))
+                                   int(query.get("seed", 0)), self.root)
         if path == "/window_start":
             self.window_t0 = time.monotonic()
             return {"compiles_before": len(self.compiles.times)}
@@ -384,6 +334,10 @@ def main() -> None:
     ap.add_argument("--config-file", required=True)
     ap.add_argument("--control-port", type=int, required=True)
     ap.add_argument("--out-dir", required=True)
+    ap.add_argument("--root", default=BENCH_ROOT,
+                    help="the directory of the cell's data files (the "
+                         "first of BENCHMARK.json's paths): its "
+                         "architectures/ holds the configuration's")
     args = ap.parse_args()
     with open(args.config_file) as f:
         cfg = json.load(f)
@@ -391,8 +345,8 @@ def main() -> None:
     captured: dict = {}
     compiles = CompileLog()
     compiles.listen()
-    install(cfg, captured)
-    serve_control(Control(cfg, args.out_dir, captured, compiles),
+    install(cfg, captured, args.root)
+    serve_control(Control(cfg, args.out_dir, captured, compiles, args.root),
                   args.control_port)
     from p2p_llm_chat_tpu.serve import api
     api.main()

@@ -157,6 +157,9 @@ def attribute_gaps(gaps: list, host: tuple) -> dict:
 
 
 def reduce_planes(planes, top: int = 10) -> dict:
+    # ProfileData.planes can be walked once (jaxlib 0.9.0), and the host
+    # events are read in a second walk.
+    planes = list(planes)
     lo, hi = None, None
     per_chip = []
     modules: dict = {}

@@ -1,118 +1,29 @@
 """A rehearsal of a whole run on the CPU at a tiny size, and the proof that
-a new cell is data: a configuration, a traffic mix, a cell and a
-per-layer metric are loaded from a temporary directory.
-
-The test, not run.py, pins the child onto the CPU (there is no flag for
-it): it replaces ``run.child_env`` and ``run.check_device``. A number
-from this run is not a device metric and is compared with nothing.
+a new cell is data: a configuration, a traffic mix, a cell, a per-layer
+metric and the family's architecture file are loaded from a temporary
+directory (rehearsal_files.py makes it and pins the child onto the CPU).
 """
 
-import argparse
 import json
 import os
-import shutil
-import sys
 import time
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+from rehearsal_files import (on_cpu, run_args as _args,  # noqa: F401
+                             tiny, write_benchmark)
 
-from benchmark import manifest, roofline, run  # noqa: E402
-
-HEAD = ("You are a helpful assistant. Draft a concise, friendly reply to "
-        "the following message:\n\n")
-
-
-def _tiny(name: str, experts: int = 0) -> dict:
-    cfg = {"name": name, "source": "tests", "hidden_size": 128,
-           "intermediate_size": 256, "num_hidden_layers": 2,
-           "num_attention_heads": 4, "num_key_value_heads": 2,
-           "head_dim": 32, "vocab_size": 512,
-           "max_position_embeddings": 256, "rope_theta": 10000.0,
-           "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
-           "stack": {"SERVE_QUANT": "int8", "SERVE_KV": "paged",
-                     "SERVE_KV_QUANT": "int8", "SERVE_SLOTS": "4",
-                     "SERVE_MAX_SEQ": "256", "SERVE_FUSE": "4",
-                     "SERVE_PREFILL_CHUNK": "256"}}
-    if experts:
-        cfg.update(num_local_experts=experts, num_experts_per_tok=2,
-                   moe_capacity_factor=2.0)
-    return cfg
+from benchmark import manifest, run  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def data_root(tmp_path_factory):
-    """A benchmark of one tiny cell, made of new files only; the readers
-    of the real per-layer metrics are copied beside one new one."""
-    root = tmp_path_factory.mktemp("bench")
-    b = root / "benchmark"
-    shutil.copytree(os.path.join(ROOT, "benchmark", "layer_metrics"),
-                    b / "layer_metrics")
-    for d in ("configs", "traffic", "cells"):
-        (b / d).mkdir()
-    (b / "layer_metrics" / "requests_ok.py").write_text(
-        '"""A metric a later PR might add: requests that ended well."""\n'
-        "def read(obs):\n    return float(len(obs.counted_ok()))\n")
-    (b / "configs" / "tiny-dense.json").write_text(json.dumps(
-        _tiny("tiny-dense")))
-    (b / "configs" / "tiny-routed.json").write_text(json.dumps(
-        _tiny("tiny-routed", experts=4)))
-    (b / "traffic" / "tiny-open.json").write_text(json.dumps({
-        "loop": "open", "rate_rps": None,
-        "prompt": {"head": HEAD, "tail": "\n\nReply:", "body_tokens": {
-            "dist": "lognormal", "median": 30, "sigma": 0.5, "min": 8,
-            "max": 90}},
-        "output_tokens": {"dist": "uniform", "min": 4, "max": 12},
-        "options": {"temperature": 0}, "warmup_buckets": [128, 256]}))
-    (b / "cells" / "tiny-dense.tiny-open.json").write_text(json.dumps(
-        {"traffic": {"rate_rps": 6.0}}))
-    (b / "cells" / "tiny-routed.tiny-open.json").write_text(json.dumps(
-        {"traffic": {"rate_rps": 6.0}}))
-    real = manifest.load_manifest(ROOT)
-    man = dict(real)
-    man["paths"] = ["benchmark"]
-    man["configs"] = [
-        {"name": n, "source": "tests", "file": f"benchmark/configs/{n}.json",
-         "reduced": [], "why": "tiny"}
-        for n in ("tiny-dense", "tiny-routed")]
-    man["workloads"] = [
-        {"name": f"{n}.tiny-open", "config": n, "traffic": "tiny-open",
-         "chips": 1, "why": "rehearsal"}
-        for n in ("tiny-dense", "tiny-routed")]
-    strip = lambda ms: [{k: v for k, v in m.items() if k != "workloads"}
-                        for m in ms]
-    man["end_to_end"] = strip(real["end_to_end"])
-    man["per_layer"] = strip(real["per_layer"]) + [
-        {"name": "requests_ok", "unit": "requests", "better": "higher",
-         "source": "host_clock", "layer": "load generator (benchmark)",
-         "moves": "tpot_p50_ms"}]
-    (root / "BENCHMARK.json").write_text(json.dumps(man))
-    return str(root)
-
-
-@pytest.fixture()
-def on_cpu(monkeypatch):
-    real_env = run.child_env
-
-    def env(cell, port, traced):
-        e = real_env(cell, port, traced)
-        e["JAX_PLATFORMS"] = "cpu"
-        return e
-
-    monkeypatch.setattr(run, "child_env", env)
-    monkeypatch.setattr(run, "check_device", lambda device, labels, cell:
-                        roofline.peaks_for("TPU v5 lite"))
-    monkeypatch.setattr(run, "RAMP_S", 1.0)
-    monkeypatch.setattr(run, "TRACE_STRETCH_S", 1.0)
-
-
-def _args(cell: str, trace: int) -> argparse.Namespace:
-    return argparse.Namespace(workload=cell, seed=7, seconds=4.0,
-                              trace=trace, sample=False)
+    """Two tiny cells, made of new files only; the readers of the real
+    per-layer metrics (beside one new one) and the real architecture
+    files are copied."""
+    return write_benchmark(tmp_path_factory.mktemp("bench"),
+                           [tiny("tiny-dense"),
+                            tiny("tiny-routed", experts=4)])
 
 
 def test_new_cell_is_data_only(data_root):
@@ -122,6 +33,9 @@ def test_new_cell_is_data_only(data_root):
     assert cell.traffic["loop"] == "open"
     assert "requests_ok" in [m["name"] for m in cell.per_layer]
     assert callable(manifest.load_reader(cell.root, "requests_ok"))
+    arch = manifest.load_architecture(cell.root)
+    assert arch.__file__.startswith(data_root)
+    assert arch.model_config(cell.config)["num_experts"] == 4
     with pytest.raises(manifest.ManifestError):
         manifest.load_cell("no-such-cell", data_root)
 

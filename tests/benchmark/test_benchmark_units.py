@@ -169,6 +169,32 @@ def test_counter_metrics():
     assert read("kv_pages_peak") == pytest.approx(80.0)
 
 
+def test_decode_bw_util_counts_every_chips_memory_system():
+    """A model sharded over four chips is read by four memory systems:
+    the same steps are a quarter of the peak; on one chip the value is
+    the old expression's, bit for bit."""
+    from types import SimpleNamespace
+    start = {"serve_decode_ticks_total": 0, "decode_fused_ticks_total": 0,
+             "decode_fused_steps_total": 0}
+    end = {"serve_decode_ticks_total": 500, "decode_fused_ticks_total": 500,
+           "decode_fused_steps_total": 2000}
+    recs = [_rec(5.0, 5.0, [(5.5, 30000), (6.0, 30000)], 6.1)]
+    read = manifest.load_reader(os.path.join(ROOT, "benchmark"),
+                                "decode_bw_util")
+    cfg = _cfg("mixtral-8x7b-v0.1-l6")
+    peaks = roofline.peaks_for("TPU v5 lite")
+
+    def at(chips):
+        return read(metrics.Observations(
+            recs, 5.0, 51.0, counters_start=start, counters_end=end,
+            cell=SimpleNamespace(config=cfg, chips=chips), peaks=peaks))
+
+    was = (100.0 * roofline.decode_step_bytes(cfg, rows=30.0, context=30101.0)
+           * 2000 / (51.0 * peaks["hbm_bytes_per_s"]))
+    assert at(1) == was and 0 < was < 100
+    assert at(4) == pytest.approx(was / 4)
+
+
 # -- the manifest and its files ----------------------------------------------
 
 def test_manifest_shape():
@@ -347,6 +373,26 @@ def test_trace_reduction_by_hand():
     ])) == {}
 
 
+def test_trace_reduction_walks_planes_that_can_be_walked_once():
+    """``ProfileData.planes`` is an iterator (jaxlib 0.9.0): handed one,
+    the reduction still finds the host's events, and the idle gaps carry
+    their names (every ledger row before PR 25 read "(no host event)")."""
+    ev = lambda n, s, d: {"name": n, "start_ns": s, "dur_ns": d, "stats": {}}
+    planes = _planes([
+        {"plane": "/device:TPU:0", "line": "XLA Ops", "events": [
+            ev("fusion.1", 1000, 2000), ev("fusion.1", 7000, 1000)]},
+        {"plane": "/host:CPU", "line": "sched/77", "events": [
+            ev("sched.admit", 2900, 4200), ev("sched.readback", 0, 1000)]},
+    ])
+    want = trace_reduce.reduce_planes(planes)
+    once = trace_reduce.reduce_planes(iter(planes))
+    assert once == want
+    gaps = dict(once["idle_gaps"])
+    assert gaps["sched:sched.admit"] == pytest.approx(4e-6)     # [3,7)
+    assert gaps["sched:sched.readback"] == pytest.approx(1e-6)  # [0,1)
+    assert "(no host event)" not in gaps
+
+
 def test_trace_reduction_on_a_recorded_trace():
     """The first events of every line of a real v5e trace (PR 22, kept by
     run.py --sample): the planes and lines are where the reduction looks
@@ -399,6 +445,17 @@ def test_tokenizer_hook_is_where_serve_cell_expects_it(monkeypatch):
     x = serve_cell.model_config(_cfg("mixtral-8x7b-v0.1-l6"))
     assert (x.num_experts, x.num_experts_per_tok, x.num_layers,
             x.moe_capacity_factor, x.is_moe) == (8, 2, 6, 2.0, True)
+
+
+def test_traced_stretch_is_counted_in_chip_seconds():
+    """The trace holds every chip's events and ``stop_trace`` writes them
+    all while the window runs on: four chips are traced for a quarter of
+    the time, one chip for what it always was."""
+    from benchmark import run
+    mon = lambda chips: run.Monitor("http://x", "http://y", None, True,
+                                    chips)
+    assert mon(1).trace_stretch_s == run.TRACE_STRETCH_S == 4.0
+    assert mon(4).trace_stretch_s == 1.0
 
 
 def test_last_line_contract():
