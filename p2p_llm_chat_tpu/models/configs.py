@@ -44,6 +44,14 @@ class ModelConfig:
     # bucket C = factor * tokens * k / num_experts. None = exact/dropless
     # (models/mixtral.py moe_mlp; decode is always exact).
     moe_capacity_factor: Optional[float] = None
+    # Renormalise the kept top-k router weights to sum to 1 (Mixtral).
+    # False keeps the softmax's own weights (OLMoE: norm_topk_prob false).
+    moe_renormalize: bool = True
+    # RMSNorm with learned weights over the WHOLE q and k projections
+    # (all heads together), between the projection and the split into
+    # heads, before RoPE (OLMoE). Leaves q_norm [L, q_dim], k_norm
+    # [L, kv_dim].
+    qk_norm_whole: bool = False
     # token ids (llama3 defaults; byte tokenizer overrides)
     bos_token_id: int = 128000
     eos_token_ids: tuple[int, ...] = (128001, 128008, 128009)
@@ -113,6 +121,24 @@ _register(ModelConfig(
     bos_token_id=1, eos_token_ids=(2,), max_seq_len=32768,
 ))
 
+# OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json): 64 thin
+# experts, top-8 with the softmax's own weights, whole-projection
+# QK-norm, MHA. 6.92 G parameters, 7.0 GB int8: whole on one 16 GB chip.
+# ``intermediate_size`` is ONE expert's width, as published. No
+# ``moe_capacity_factor``: dropless, as published (a prefill bucket holds
+# every token of its chunk, 8 / 64 of it used on average). A 64-way
+# router is too uneven for a bounded bucket: under the benchmark's random
+# weights factor 2.0 dropped 25% of the prefill's routed pairs and 4.0
+# still 8% (PERF.md section 6, PR 26; Mixtral's 8 experts at 2.0: 0.05%).
+_register(ModelConfig(
+    name="olmoe-1b-7b", vocab_size=50304, hidden_size=2048,
+    intermediate_size=1024, num_layers=16, num_heads=16, num_kv_heads=16,
+    head_dim=128, rope_theta=10000.0, rms_norm_eps=1e-5,
+    num_experts=64, num_experts_per_tok=8,
+    moe_renormalize=False, qk_norm_whole=True,
+    bos_token_id=1, eos_token_ids=(50279,), max_seq_len=4096,
+))
+
 # ~7.3B-total MoE config for single-chip benching at REAL expert scale:
 # each expert is 3*4096*11520 ≈ 141.6M params — 16.4x bench-moe's 8.65M,
 # Mixtral-8x7B-class expert width at Mixtral's 8-expert top-2 routing —
@@ -147,6 +173,16 @@ _register(ModelConfig(
     num_layers=2, num_heads=4, num_kv_heads=2, head_dim=32, max_seq_len=256,
     rope_theta=10000.0, num_experts=4, num_experts_per_tok=2,
     bos_token_id=1, eos_token_ids=(2,),
+))
+
+# OLMoE's block at test size: MHA, QK-norm, top-4 of 8 without
+# renormalisation.
+_register(ModelConfig(
+    name="tiny-olmoe", vocab_size=512, hidden_size=128,
+    intermediate_size=64, num_layers=2, num_heads=4, num_kv_heads=4,
+    head_dim=32, max_seq_len=256, rope_theta=10000.0,
+    num_experts=8, num_experts_per_tok=4, moe_renormalize=False,
+    qk_norm_whole=True, bos_token_id=1, eos_token_ids=(2,),
 ))
 
 # Loadgen CPU profile: ``tiny`` dims with a real context window, so the

@@ -19,7 +19,7 @@ TPU-first choices:
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,29 @@ class KVCache(NamedTuple):
 
 # -- parameters ---------------------------------------------------------------
 
+def qk_norm_leaves(config: ModelConfig, key: jax.Array, dtype) -> dict:
+    """The whole-projection QK-norm weights (``config.qk_norm_whole``:
+    OLMoE): ``q_norm`` [L, q_dim], ``k_norm`` [L, kv_dim]. Empty for a
+    configuration without it, so every initialiser of both families can
+    ``update`` its layer tree with it. Drawn from [0.5, 1.5), not ones:
+    under the scaled-normal init a projection already has unit RMS, so
+    a norm of ones is nearly the identity and a model that left it out
+    would pass every comparison with a reference."""
+    if not config.qk_norm_whole:
+        return {}
+    kq, kk = jax.random.split(key)
+    L = config.num_layers
+
+    def draw(k, n):
+        return (0.5 + jax.random.uniform(k, (L, n), jnp.float32)
+                ).astype(dtype)
+    return {"q_norm": draw(kq, config.q_dim),
+            "k_norm": draw(kk, config.kv_dim)}
+
+
+QK_NORM_AXES = {"q_norm": (None, "heads"), "k_norm": (None, "kv_heads")}
+
+
 def init_params(config: ModelConfig, key: jax.Array,
                 dtype=DEFAULT_COMPUTE_DTYPE) -> dict:
     """Random init (scaled normal). Real weights come from
@@ -86,6 +109,7 @@ def init_params(config: ModelConfig, key: jax.Array,
         },
         "final_norm": jnp.ones((H,), dtype),
     }
+    params["layers"].update(qk_norm_leaves(config, ks[9], dtype))
     if not config.tie_embeddings:
         params["lm_head"] = normal(ks[8], (H, config.vocab_size))
     return params
@@ -136,6 +160,8 @@ def init_params_quantized(config: ModelConfig, key: jax.Array,
     layers: dict = {
         "attn_norm": jnp.ones((L, H), dtype),
         "mlp_norm": jnp.ones((L, H), dtype),
+        # A key of its own, so that no other leaf's draw moves.
+        **qk_norm_leaves(config, jax.random.fold_in(k_head, 1), dtype),
     }
     for name, (din, dout) in dims.items():
         layers[name] = stream_bufs(L, (din, dout), quant)
@@ -306,6 +332,8 @@ def param_axes(config: ModelConfig) -> dict:
         },
         "final_norm": ("embed",),
     }
+    if config.qk_norm_whole:
+        axes["layers"].update(QK_NORM_AXES)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -404,6 +432,16 @@ def _attn_qkv(h: jax.Array, lp: dict, config: ModelConfig,
                                     config.head_dim)
         v = mm(x, lp["wv"]).reshape(B, S, config.num_kv_heads,
                                     config.head_dim)
+    if config.qk_norm_whole:
+        # RMSNorm over the WHOLE projection, all heads together, before
+        # RoPE. q and k are in natural head order here under either
+        # fused layout, so the weights are too. Under tensor parallelism
+        # the mean runs over a sharded axis: a reduction across devices,
+        # which the partitioner inserts.
+        q = rms_norm(q.reshape(B, S, config.q_dim), lp["q_norm"],
+                     config.rms_norm_eps).reshape(q.shape)
+        k = rms_norm(k.reshape(B, S, config.kv_dim), lp["k_norm"],
+                     config.rms_norm_eps).reshape(k.shape)
     q = constrain(q, mesh, ("batch", None, "act_heads", None), rules)
     k = constrain(k, mesh, ("batch", None, "act_heads", None), rules)
     q = apply_rope(q, positions, inv_freq)
@@ -476,17 +514,20 @@ def _block(h: jax.Array, lp: dict, config: ModelConfig, inv_freq: jax.Array,
         cache_k, cache_v
 
 
-def hidden_states(params: dict, config: ModelConfig, tokens: jax.Array,
-                  positions: jax.Array, cache: KVCache, mask: jax.Array,
-                  mesh: Optional[Mesh] = None,
-                  rules: LogicalRules = DEFAULT_RULES,
-                  kv_window: Optional[int] = None,
-                  mlp_fn=None, causal0: bool = False,
-                  write_pos: Optional[jax.Array] = None
-                  ) -> tuple[jax.Array, KVCache]:
-    """embed -> scan(blocks) -> final norm. Returns (h [B,S,H], cache) —
-    the shared trunk of :func:`forward`; also the embedding feature
-    extractor (:func:`embed_pooled` / the serve /api/embed path).
+def hidden_states_aux(params: dict, config: ModelConfig, tokens: jax.Array,
+                      positions: jax.Array, cache: KVCache, mask: jax.Array,
+                      mlp_fn, mlp_aux,
+                      mesh: Optional[Mesh] = None,
+                      rules: LogicalRules = DEFAULT_RULES,
+                      kv_window: Optional[int] = None,
+                      causal0: bool = False,
+                      write_pos: Optional[jax.Array] = None
+                      ) -> tuple[jax.Array, KVCache, Any]:
+    """embed -> scan(blocks) -> final norm, for an MLP that accumulates
+    something over the layers (models/mixtral.py counts what its
+    capacity buckets drop). ``mlp_fn(x, lp, mesh, rules, aux) ->
+    (out, aux)``; ``mlp_aux`` (a pytree) is the value the first layer is
+    handed, and the scan carries it. Returns (h [B,S,H], cache, aux).
 
     ``write_pos`` ([B,S], default = ``positions``): cache slots this
     step's k/v land in, decoupled from the RoPE positions — tree
@@ -500,17 +541,60 @@ def hidden_states(params: dict, config: ModelConfig, tokens: jax.Array,
     wp = positions if write_pos is None else write_pos
 
     def body(carry, layer):
-        h, ck, cv = carry
+        h, ck, cv, aux = carry
         lp = _layer_view(params["layers"], layer)
+
+        # The block hands the MLP's output on and knows no second
+        # result: the running value goes in and comes out beside it,
+        # inside this one trace of the body.
+        def fn(x, lp, mesh, rules):
+            nonlocal aux
+            out, aux = mlp_fn(x, lp, mesh, rules, aux)
+            return out
         h, ck, cv = _block(h, lp, config, inv_freq, positions, ck, cv,
                            layer, wp, mask, mesh, rules, kv_window,
-                           mlp_fn, causal0)
-        return (h, ck, cv), None
+                           fn, causal0)
+        return (h, ck, cv, aux), None
 
-    (h, new_k, new_v), _ = jax.lax.scan(
-        body, (h, cache.k, cache.v), jnp.arange(config.num_layers))
+    (h, new_k, new_v, aux), _ = jax.lax.scan(
+        body, (h, cache.k, cache.v, mlp_aux), jnp.arange(config.num_layers))
     h = rms_norm(h, params["final_norm"], config.rms_norm_eps)
-    return h, KVCache(new_k, new_v, cache.lengths)
+    return h, KVCache(new_k, new_v, cache.lengths), aux
+
+
+def hidden_states(params: dict, config: ModelConfig, tokens: jax.Array,
+                  positions: jax.Array, cache: KVCache, mask: jax.Array,
+                  mesh: Optional[Mesh] = None,
+                  rules: LogicalRules = DEFAULT_RULES,
+                  kv_window: Optional[int] = None,
+                  mlp_fn=None, causal0: bool = False,
+                  write_pos: Optional[jax.Array] = None
+                  ) -> tuple[jax.Array, KVCache]:
+    """Returns (h [B,S,H], cache) — the shared trunk of :func:`forward`;
+    also the embedding feature extractor (:func:`embed_pooled` / the
+    serve /api/embed path). :func:`hidden_states_aux` with nothing to
+    accumulate; ``mlp_fn(x, lp, mesh, rules)`` or the dense default."""
+    def fn(x, lp, mesh, rules, aux):
+        return (mlp_fn(x, lp, mesh, rules) if mlp_fn is not None
+                else _default_mlp(x, lp, mesh, rules, config)), aux
+    h, cache, _ = hidden_states_aux(params, config, tokens, positions, cache,
+                                    mask, fn, (), mesh, rules, kv_window,
+                                    causal0, write_pos)
+    return h, cache
+
+
+def _logits(params: dict, config: ModelConfig, h: jax.Array,
+            last_idx: Optional[jax.Array], mesh: Optional[Mesh],
+            rules: LogicalRules) -> jax.Array:
+    """The lm_head over ``h`` [B,S,H], or over each row's ``last_idx``
+    position only ([B,1,vocab]): see :func:`forward`."""
+    if last_idx is not None:
+        h = jnp.take_along_axis(h, last_idx[:, None, None].astype(jnp.int32),
+                                axis=1)                     # [B,1,H]
+    lm_head = (params["embed"].T if config.tie_embeddings
+               else params["lm_head"])
+    logits = mm(h, lm_head).astype(jnp.float32)
+    return constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
 
 
 def forward(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -540,14 +624,23 @@ def forward(params: dict, config: ModelConfig, tokens: jax.Array,
     h, cache = hidden_states(params, config, tokens, positions, cache, mask,
                              mesh, rules, kv_window, mlp_fn, causal0,
                              write_pos=write_pos)
-    if last_idx is not None:
-        h = jnp.take_along_axis(h, last_idx[:, None, None].astype(jnp.int32),
-                                axis=1)                     # [B,1,H]
-    lm_head = (params["embed"].T if config.tie_embeddings
-               else params["lm_head"])
-    logits = mm(h, lm_head).astype(jnp.float32)
-    logits = constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
-    return logits, cache
+    return _logits(params, config, h, last_idx, mesh, rules), cache
+
+
+def forward_aux(params: dict, config: ModelConfig, tokens: jax.Array,
+                positions: jax.Array, cache: KVCache, mask: jax.Array,
+                mlp_fn, mlp_aux,
+                mesh: Optional[Mesh] = None,
+                rules: LogicalRules = DEFAULT_RULES,
+                causal0: bool = False,
+                last_idx: Optional[jax.Array] = None
+                ) -> tuple[jax.Array, KVCache, Any]:
+    """:func:`forward` over :func:`hidden_states_aux` (prefill shapes:
+    the whole cache width is read): (logits, cache, aux)."""
+    h, cache, aux = hidden_states_aux(params, config, tokens, positions,
+                                      cache, mask, mlp_fn, mlp_aux, mesh,
+                                      rules, causal0=causal0)
+    return _logits(params, config, h, last_idx, mesh, rules), cache, aux
 
 
 def embed_pooled(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -636,11 +729,28 @@ def prefill_chunk(params: dict, config: ModelConfig, tokens: jax.Array,
 
     Returns (logits [B,1,vocab] (or [B,C,vocab] without last_idx),
     cache with the chunk's slots written, lengths untouched)."""
-    B, C = tokens.shape
-    positions = jnp.broadcast_to(offset + jnp.arange(C)[None, :], (B, C))
-    mask = causal_mask(C, cache.k.shape[2], offset)
+    positions, mask = _chunk_geometry(tokens, cache, offset)
     return forward(params, config, tokens, positions, cache, mask, mesh,
                    rules, mlp_fn=mlp_fn, last_idx=last_idx)
+
+
+def _chunk_geometry(tokens: jax.Array, cache: KVCache, offset: int) -> tuple:
+    B, C = tokens.shape
+    positions = jnp.broadcast_to(offset + jnp.arange(C)[None, :], (B, C))
+    return positions, causal_mask(C, cache.k.shape[2], offset)
+
+
+def prefill_chunk_aux(params: dict, config: ModelConfig, tokens: jax.Array,
+                      cache: KVCache, offset: int, mlp_fn, mlp_aux,
+                      mesh: Optional[Mesh] = None,
+                      rules: LogicalRules = DEFAULT_RULES,
+                      last_idx: Optional[jax.Array] = None
+                      ) -> tuple[jax.Array, KVCache, Any]:
+    """:func:`prefill_chunk` over :func:`forward_aux`: (logits, cache,
+    aux)."""
+    positions, mask = _chunk_geometry(tokens, cache, offset)
+    return forward_aux(params, config, tokens, positions, cache, mask,
+                       mlp_fn, mlp_aux, mesh, rules, last_idx=last_idx)
 
 
 def decode_step(params: dict, config: ModelConfig, tokens: jax.Array,

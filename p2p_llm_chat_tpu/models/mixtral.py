@@ -83,6 +83,7 @@ def init_params(config: ModelConfig, key: jax.Array,
         },
         "final_norm": jnp.ones((H,), dtype),
     }
+    params["layers"].update(llama.qk_norm_leaves(config, ks[10], dtype))
     if not config.tie_embeddings:
         params["lm_head"] = normal(ks[9], (H, config.vocab_size))
     return params
@@ -129,6 +130,8 @@ def init_params_quantized(config: ModelConfig, key: jax.Array,
     layers: dict = {
         "attn_norm": jnp.ones((L, H), dtype),
         "mlp_norm": jnp.ones((L, H), dtype),
+        # A key of its own, so that no other leaf's draw moves.
+        **llama.qk_norm_leaves(config, jax.random.fold_in(k_head, 1), dtype),
     }
     bufs = {name: stream_bufs(L, shape, quant)
             for name, shape in dims.items()}
@@ -184,6 +187,8 @@ def param_axes(config: ModelConfig) -> dict:
         },
         "final_norm": ("embed",),
     }
+    if config.qk_norm_whole:
+        axes["layers"].update(llama.QK_NORM_AXES)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -196,7 +201,8 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
             mesh: Optional[Mesh] = None,
             rules: LogicalRules = DEFAULT_RULES,
             capacity: Optional[int] = None,
-            w_gu: Optional[jax.Array] = None) -> jax.Array:
+            w_gu: Optional[jax.Array] = None,
+            renormalize: bool = True) -> jax.Array:
     """Sparse-MoE SwiGLU via scatter/gather dispatch into capacity buckets.
 
     x: [B,S,H]; router: [H,NE]; w_gate/w_up: [NE,H,F]; w_down: [NE,F,H].
@@ -211,7 +217,41 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     expert projection dispatches pays exactly like the dense fusion
     did; per-output-channel int8 scales
     concatenate with their columns, so the math is identical.
+
+    ``renormalize`` (static; ``ModelConfig.moe_renormalize``): divide the
+    kept weights by their sum (Mixtral). False keeps the softmax's own
+    weights (OLMoE, ``norm_topk_prob: false``).
     """
+    return _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok,
+                    mesh, rules, capacity, w_gu, renormalize)[0]
+
+
+def moe_mlp_counted(x: jax.Array, router: jax.Array, w_gate: jax.Array,
+                    w_up: jax.Array, w_down: jax.Array,
+                    num_experts_per_tok: int, valid: jax.Array,
+                    mesh: Optional[Mesh] = None,
+                    rules: LogicalRules = DEFAULT_RULES,
+                    capacity: Optional[int] = None,
+                    w_gu: Optional[jax.Array] = None,
+                    renormalize: bool = True) -> tuple[jax.Array, jax.Array]:
+    """:func:`moe_mlp`, and what its buckets dropped: ``(out, stats)``
+    with ``stats`` int32 [2] = the routed (token, expert) pairs of the
+    ``valid`` positions ([B,S] bool: the real prompt positions), and
+    those of them that found their bucket full. Padding positions still
+    take slots in (token, slot) order; what they displace is counted,
+    what they lose is not."""
+    out, full = _moe_mlp(x, router, w_gate, w_up, w_down,
+                         num_experts_per_tok, mesh, rules, capacity, w_gu,
+                         renormalize)
+    real = jnp.repeat(valid.reshape(-1), num_experts_per_tok)      # [T*k]
+    stats = jnp.stack([jnp.sum(real), jnp.sum(real & full)])
+    return out, stats.astype(jnp.int32)
+
+
+def _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok, mesh,
+             rules, capacity, w_gu, renormalize) -> tuple:
+    """(out [B,S,H], full [T*k] bool: the t-major (token, selection)
+    pairs whose bucket had no slot left)."""
     B, S, H = x.shape
     NE = router.shape[-1]
     k = num_experts_per_tok
@@ -220,11 +260,12 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     xt = x.reshape(T, H)
 
     # Routing in f32 (HF parity: softmax over ALL experts, then top-k,
-    # then renormalise the selected weights).
+    # then, for Mixtral, renormalise the selected weights).
     logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)   # [T,NE]
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_i = jax.lax.top_k(probs, k)                         # [T,k]
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if renormalize:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
 
     # Position-in-expert with (token, selection-slot) priority: cumsum of
     # the selection one-hot over the t-major flattened [T*k] selections.
@@ -256,7 +297,7 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
                         mode="fill", fill_value=0)                 # [T*k,H]
     out = jnp.sum(gathered.reshape(T, k, H).astype(jnp.float32)
                   * top_w[..., None], axis=1)
-    return out.astype(x.dtype).reshape(B, S, H)
+    return out.astype(x.dtype).reshape(B, S, H), slot >= C
 
 
 # -- forward ------------------------------------------------------------------
@@ -278,8 +319,29 @@ def _mlp_fn(config: ModelConfig, capacity: Optional[int]):
     def fn(x, lp, mesh, rules):
         return moe_mlp(x, lp["router"], lp.get("w_gate"), lp.get("w_up"),
                        lp["w_down"], config.num_experts_per_tok, mesh,
-                       rules, capacity, w_gu=lp.get("wgu_e"))
+                       rules, capacity, w_gu=lp.get("wgu_e"),
+                       renormalize=config.moe_renormalize)
     return fn
+
+
+def _mlp_fn_counted(config: ModelConfig, capacity: Optional[int],
+                    valid: jax.Array):
+    """The expert MLP in llama.hidden_states_aux's form: the running
+    drop count goes in and comes out beside the output."""
+    def fn(x, lp, mesh, rules, stats):
+        out, more = moe_mlp_counted(
+            x, lp["router"], lp.get("w_gate"), lp.get("w_up"),
+            lp["w_down"], config.num_experts_per_tok, valid, mesh, rules,
+            capacity, w_gu=lp.get("wgu_e"),
+            renormalize=config.moe_renormalize)
+        return out, stats + more
+    return fn
+
+
+def no_stats() -> jax.Array:
+    """A drop count's start: int32 [2] = (routed pairs of real prompt
+    positions, those dropped), summed over the layers."""
+    return jnp.zeros((2,), jnp.int32)
 
 
 def forward(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -298,6 +360,31 @@ def forward(params: dict, config: ModelConfig, tokens: jax.Array,
                          last_idx=last_idx)
 
 
+def forward_counted(params: dict, config: ModelConfig, tokens: jax.Array,
+                    positions: jax.Array, cache: KVCache, mask: jax.Array,
+                    valid: jax.Array,
+                    mesh: Optional[Mesh] = None,
+                    rules: LogicalRules = DEFAULT_RULES,
+                    capacity=_AUTO, causal0: bool = False,
+                    last_idx: Optional[jax.Array] = None
+                    ) -> tuple[jax.Array, KVCache, jax.Array]:
+    """:func:`forward`, and third what the capacity buckets dropped of
+    the ``valid`` positions ([B,S] bool, the real prompt positions):
+    :func:`moe_mlp_counted`'s ``stats`` summed over the layers. The
+    scheduler's prefill programs run these ``_counted`` forms."""
+    cap = _capacity_for(config, int(tokens.shape[0] * tokens.shape[1]),
+                        capacity)
+    return llama.forward_aux(params, config, tokens, positions, cache, mask,
+                             _mlp_fn_counted(config, cap, valid), no_stats(),
+                             mesh, rules, causal0=causal0, last_idx=last_idx)
+
+
+def _prefill_geometry(tokens: jax.Array, cache: KVCache) -> tuple:
+    B, S = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    return positions, causal_mask(S, cache.k.shape[2], 0)
+
+
 def prefill(params: dict, config: ModelConfig, tokens: jax.Array,
             prompt_lens: jax.Array, cache: KVCache,
             mesh: Optional[Mesh] = None,
@@ -305,13 +392,28 @@ def prefill(params: dict, config: ModelConfig, tokens: jax.Array,
             capacity=_AUTO, last_only: bool = False) -> tuple[jax.Array, KVCache]:
     """Same contract as llama.prefill (right-padded prompts from pos 0),
     incl. ``last_only`` (admission's one-position logits)."""
-    B, S = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    mask = causal_mask(S, cache.k.shape[2], 0)
+    positions, mask = _prefill_geometry(tokens, cache)
     logits, cache = forward(params, config, tokens, positions, cache, mask,
                             mesh, rules, capacity=capacity, causal0=True,
                             last_idx=prompt_lens - 1 if last_only else None)
     return logits, cache._replace(lengths=prompt_lens.astype(jnp.int32))
+
+
+def prefill_counted(params: dict, config: ModelConfig, tokens: jax.Array,
+                    prompt_lens: jax.Array, cache: KVCache, valid: jax.Array,
+                    mesh: Optional[Mesh] = None,
+                    rules: LogicalRules = DEFAULT_RULES,
+                    capacity=_AUTO, last_only: bool = False
+                    ) -> tuple[jax.Array, KVCache, jax.Array]:
+    """:func:`prefill` over :func:`forward_counted`: (logits, cache,
+    stats)."""
+    positions, mask = _prefill_geometry(tokens, cache)
+    logits, cache, stats = forward_counted(
+        params, config, tokens, positions, cache, mask, valid, mesh, rules,
+        capacity=capacity, causal0=True,
+        last_idx=prompt_lens - 1 if last_only else None)
+    return (logits, cache._replace(lengths=prompt_lens.astype(jnp.int32)),
+            stats)
 
 
 def prefill_chunk(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -333,6 +435,24 @@ def prefill_chunk(params: dict, config: ModelConfig, tokens: jax.Array,
     return llama.prefill_chunk(params, config, tokens, cache, offset, mesh,
                                rules, last_idx=last_idx,
                                mlp_fn=_mlp_fn(config, cap))
+
+
+def prefill_chunk_counted(params: dict, config: ModelConfig,
+                          tokens: jax.Array, cache: KVCache, offset: int,
+                          valid: jax.Array,
+                          mesh: Optional[Mesh] = None,
+                          rules: LogicalRules = DEFAULT_RULES,
+                          last_idx: Optional[jax.Array] = None,
+                          capacity=_AUTO
+                          ) -> tuple[jax.Array, KVCache, jax.Array]:
+    """:func:`prefill_chunk`, and third the chunk's drop count
+    (:func:`forward_counted`; ``valid`` [B,C] bool)."""
+    cap = _capacity_for(config, int(tokens.shape[0] * tokens.shape[1]),
+                        capacity)
+    return llama.prefill_chunk_aux(
+        params, config, tokens, cache, offset,
+        _mlp_fn_counted(config, cap, valid), no_stats(), mesh, rules,
+        last_idx=last_idx)
 
 
 def decode_step(params: dict, config: ModelConfig, tokens: jax.Array,

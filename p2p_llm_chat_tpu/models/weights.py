@@ -73,6 +73,34 @@ def _moe_layer_map(i: int, num_experts: int) -> dict[str, Any]:
     return m
 
 
+def _olmoe_layer_map(i: int, num_experts: int) -> dict[str, Any]:
+    """OLMoE layout: the dense names for attention and norms, the two
+    whole-projection QK-norm vectors, ``mlp.gate`` for the router and
+    ``mlp.experts.N.{gate,up,down}_proj`` for the experts."""
+    p = f"model.layers.{i}"
+    m: dict[str, Any] = {k: v for k, v in _dense_layer_map(i).items()
+                         if k not in ("w_gate", "w_up", "w_down")}
+    m["q_norm"] = (f"{p}.self_attn.q_norm.weight", False)
+    m["k_norm"] = (f"{p}.self_attn.k_norm.weight", False)
+    m["router"] = (f"{p}.mlp.gate.weight", True)
+    for key, proj in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                      ("w_down", "down_proj")):
+        m[key] = [(f"{p}.mlp.experts.{e}.{proj}.weight", True)
+                  for e in range(num_experts)]
+    return m
+
+
+def _layer_map(config: ModelConfig, i: int) -> dict[str, Any]:
+    """Layer i's name map for the configuration's family. The two routed
+    layouts are told apart by QK-norm: Mixtral (``block_sparse_moe``,
+    w1/w2/w3) has none, OLMoE (``mlp.experts``, *_proj) has it."""
+    if not config.is_moe:
+        return _dense_layer_map(i)
+    if config.qk_norm_whole:
+        return _olmoe_layer_map(i, config.num_experts)
+    return _moe_layer_map(i, config.num_experts)
+
+
 def convert_hf_state_dict(state: dict[str, np.ndarray], config: ModelConfig,
                           dtype=jnp.bfloat16) -> dict:
     """Convert a flat HF state dict (numpy arrays) into our stacked tree.
@@ -84,8 +112,7 @@ def convert_hf_state_dict(state: dict[str, np.ndarray], config: ModelConfig,
 
     L = config.num_layers
     layers: dict[str, Any] = {}
-    maps = [( _moe_layer_map(i, config.num_experts) if config.is_moe
-              else _dense_layer_map(i)) for i in range(L)]
+    maps = [_layer_map(config, i) for i in range(L)]
     for key in maps[0]:
         per_layer = []
         for i in range(L):
@@ -110,7 +137,11 @@ def convert_hf_state_dict(state: dict[str, np.ndarray], config: ModelConfig,
 # -- safetensors checkpoint directory loading --------------------------------
 
 def config_from_hf_json(path: str) -> ModelConfig:
-    """Derive a ModelConfig from an HF config.json (llama/mixtral families)."""
+    """Derive a ModelConfig from an HF config.json (llama, mixtral and
+    olmoe families). Mixtral publishes ``num_local_experts`` and always
+    renormalises the kept router weights; OLMoE publishes ``num_experts``
+    and ``norm_topk_prob``, and its ``model_type`` says that q and k
+    carry a whole-projection RMSNorm."""
     with open(path) as f:
         hf = json.load(f)
     arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
@@ -141,9 +172,12 @@ def config_from_hf_json(path: str) -> ModelConfig:
         rope_scaling=rope_scaling,
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
         tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
-        num_experts=int(hf.get("num_local_experts", 0)),
+        num_experts=int(hf.get("num_local_experts")
+                        or hf.get("num_experts") or 0),
         num_experts_per_tok=int(hf.get("num_experts_per_tok", 0)),
-        bos_token_id=int(hf.get("bos_token_id", 1)),
+        moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+        qk_norm_whole=hf.get("model_type") == "olmoe",
+        bos_token_id=int(hf.get("bos_token_id") or 1),
         eos_token_ids=eos_ids,
     )
 
@@ -160,9 +194,7 @@ def _reverse_name_map(config: ModelConfig) -> dict[str, tuple]:
     if not config.tie_embeddings:
         out["lm_head.weight"] = (("lm_head",), None, None, True)
     for i in range(config.num_layers):
-        m = (_moe_layer_map(i, config.num_experts) if config.is_moe
-             else _dense_layer_map(i))
-        for key, spec in m.items():
+        for key, spec in _layer_map(config, i).items():
             if isinstance(spec, list):
                 for e, (name, tr) in enumerate(spec):
                     out[name] = (("layers", key), i, e, tr)
@@ -348,7 +380,7 @@ class UnsupportedForQuantizedLoad(ValueError):
 _CONFIG_IDENTITY_FIELDS = (
     "vocab_size", "hidden_size", "intermediate_size", "num_layers",
     "num_heads", "num_kv_heads", "head_dim", "tie_embeddings",
-    "num_experts", "num_experts_per_tok",
+    "num_experts", "num_experts_per_tok", "moe_renormalize", "qk_norm_whole",
 )
 
 
@@ -443,10 +475,11 @@ def load_checkpoint_quantized(ckpt_dir: str,
             "load_checkpoint_quantized covers the llama and mixtral "
             f"families; {config.name} keeps the standard load paths")
     moe = config.is_moe
-    layer_keys = (("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
-                   "router", "w_gate", "w_up", "w_down") if moe else
-                  ("attn_norm", "wq", "wk", "wv", "wo",
-                   "mlp_norm", "w_gate", "w_up", "w_down"))
+    # Norm vectors stay in the compute dtype, one row a layer.
+    norm_keys = ("attn_norm", "mlp_norm") + (
+        ("q_norm", "k_norm") if config.qk_norm_whole else ())
+    layer_keys = norm_keys + ("wq", "wk", "wv", "wo", "w_gate", "w_up",
+                              "w_down") + (("router",) if moe else ())
 
     # -- per-layer host-tensor iterator -------------------------------------
     if native:
@@ -582,13 +615,12 @@ def load_checkpoint_quantized(ckpt_dir: str,
                                          s=bufs[name].s.at[layer].set(s))
         return out
 
-    attn_norms = np.zeros((L, H), np.float32)
-    mlp_norms = np.zeros((L, H), np.float32)
+    norms: dict = {k: [] for k in norm_keys}
     routers = np.zeros((L, H, NE), np.float32) if moe else None
     for li in range(L):
         lt = layer_host(li)
-        attn_norms[li] = lt["attn_norm"].astype(np.float32)
-        mlp_norms[li] = lt["mlp_norm"].astype(np.float32)
+        for k in norm_keys:
+            norms[k].append(lt[k].astype(np.float32))
         fused = {
             "wqkv": np.concatenate(
                 [lt["wq"], lt["wk"], lt["wv"]], axis=1),
@@ -613,8 +645,7 @@ def load_checkpoint_quantized(ckpt_dir: str,
 
     top = top_host()
     layers: dict = {
-        "attn_norm": jnp.asarray(attn_norms, dtype),
-        "mlp_norm": jnp.asarray(mlp_norms, dtype),
+        **{k: jnp.asarray(np.stack(v), dtype) for k, v in norms.items()},
         **bufs,
     }
     if moe:

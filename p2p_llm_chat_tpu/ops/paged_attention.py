@@ -729,6 +729,20 @@ _FLASH_HD_REF = 1024
 # gather path's XLA fusion wins on every geometry measured.
 _FLASH_MIN_W_FLOOR = 256
 
+# The boundary for KV geometries WIDER than the calibration (hd >
+# _FLASH_HD_REF; OLMoE's MHA: 16 kv heads x 128 = 2048), as a share of
+# the knob like the scaled rule below: (numerator, denominator). Measured,
+# not extrapolated: on a v5e at hd=2048, int8 pool, 32 rows, the gather
+# path costs 0.62 / 1.42 / 3.74 ms a layer-step at W = 512 / 1024 / 2048
+# and the flash kernel 0.18 / 0.30 / 0.57 ms (3.5x / 4.8x / 6.6x; PERF.md
+# section 6, PR 26; tools/check_append_kernel.py time). The gather path's
+# dequantised window grows with hd and stops fitting what XLA fuses, so
+# the narrow geometries' ratio hd / 1024 does not continue upward (it
+# would say 4096 here, every window of a 2048-token deployment on the
+# gather path). A quarter of the default 2048 is 512, the smallest window
+# measured.
+_FLASH_WIDE_RATIO = (1, 4)
+
 
 def _flash_append_min_w() -> int:
     """Engage the flash append kernel at windows >= this many tokens
@@ -753,7 +767,10 @@ def _flash_append_policy(window: int, append_impl: str, min_w: int,
       owns the dispatch upstream);
     - otherwise flash iff ``min_w > 0`` and the window reaches the
       GEOMETRY-SCALED boundary ``max(256, min_w * hd / 1024)`` where
-      ``hd = Hkv * head_dim``.
+      ``hd = Hkv * head_dim``, for geometries up to the calibration's
+      (hd <= 1024); for wider ones ``max(256, min_w / 4)``
+      (``_FLASH_WIDE_RATIO``: the ratio is measured per geometry, the
+      knob scales every one).
 
     Why the scaling (round-18): the round-8 boundary (2048) was
     measured at hd=1024. Per window token, the gather path pays hd
@@ -771,8 +788,16 @@ def _flash_append_policy(window: int, append_impl: str, min_w: int,
         return False
     if min_w <= 0:
         return False
-    return window >= max(_FLASH_MIN_W_FLOOR,
-                         min_w * hd // _FLASH_HD_REF)
+    return window >= _flash_boundary(min_w, hd)
+
+
+def _flash_boundary(min_w: int, hd: int) -> int:
+    """The window from which the flash kernel serves geometry ``hd`` when
+    ``PAGED_APPEND_FLASH_MIN_W`` is ``min_w`` > 0 (shared by the policy
+    and its one-number export)."""
+    num, den = ((hd, _FLASH_HD_REF) if hd <= _FLASH_HD_REF
+                else _FLASH_WIDE_RATIO)
+    return max(_FLASH_MIN_W_FLOOR, min_w * num // den)
 
 
 def flash_append_blocked(sharded: bool = False,
@@ -826,7 +851,7 @@ def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
     min_w = _flash_append_min_w()
     if min_w <= 0:
         return 0
-    return max(_FLASH_MIN_W_FLOOR, min_w * hd // _FLASH_HD_REF)
+    return _flash_boundary(min_w, hd)
 
 
 def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,

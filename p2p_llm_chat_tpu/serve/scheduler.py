@@ -41,6 +41,7 @@ Design, shaped by XLA's compilation model (SURVEY.md §7 "hard parts"):
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -587,6 +588,17 @@ class BatchScheduler:
         self._quant_mode = quant_mode(params)
         log.info("model weights: %.3f GB (%s)",
                  self._weight_bytes / 1e9, self._quant_mode or "bf16")
+        if config.is_moe:
+            log.info("routed model %s: %d experts, top-%d %s; prefill "
+                     "capacity factor %s; QK-norm %s; what the buckets "
+                     "drop is counted: serve_moe_assignments_total, "
+                     "serve_moe_dropped_total", config.name,
+                     config.num_experts, config.num_experts_per_tok,
+                     "renormalised" if config.moe_renormalize
+                     else "with the softmax's own weights",
+                     config.moe_capacity_factor or "none (dropless)",
+                     "over the whole projection" if config.qk_norm_whole
+                     else "none")
         self._log_kernels()
 
         self._slots: list[Optional[_Slot]] = [None] * num_slots  # owned-by: _loop
@@ -619,6 +631,13 @@ class BatchScheduler:
         self._n_admit_rows_padded = 0
         self._n_prefill_tokens = 0
         self._n_prefill_padded = 0
+        # A routed model's prefill programs (admission at every width,
+        # the chunk ladder, prefix builds): routed (token, expert) pairs
+        # of real prompt positions, and those that found their capacity
+        # bucket full. Decode and wake buckets are exact and add nothing.
+        self._n_moe_assigned = 0
+        self._n_moe_dropped = 0
+        self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
         self._clean_steps = 0
@@ -1123,6 +1142,24 @@ class BatchScheduler:
         # discipline).
         self._wake_shapes_run: set[tuple] = set()  # owned-by: _loop
 
+        routed = config.is_moe
+
+        def _moe_valid(ints, off: int, width: int):
+            """What a routed model's ``_counted`` prefill counts over
+            (models/mixtral.py ``valid``): the real prompt positions of
+            the ``width`` positions from suffix offset ``off`` — below
+            the entry's (suffix) length, in an entry that carries a
+            request (a dummy entry's row is the sentinel ``num_slots``)."""
+            pos = off + jnp.arange(width)[None, :]
+            real = (ints[1] < self.num_slots)[:, None]
+            return (pos < ints[0][:, None]) & real
+
+        def _with_moe(toks, moe):
+            """The first tokens with a routed model's drop count behind
+            them (``moe`` is None for a dense one), so that the one
+            array the host already reads carries both: [R], or [R + 2]."""
+            return toks if moe is None else jnp.concatenate([toks, moe])
+
         def _prefill_first_token(params, tokens, ints, floats, rings):
             """Shared admission prologue (dense and paged): batched prefill
             of R prompts + each row's first sampled token.
@@ -1139,8 +1176,14 @@ class BatchScheduler:
             # last_only: the full [R,S,V] logits would materialise an
             # R*S x vocab f32 temp (3.9 GB at 8B dims, 64x128 chunk) and
             # pay S x the lm_head FLOPs for positions nobody samples.
-            logits, small = model.prefill(params, config, tokens, lens,
-                                          small, mesh, last_only=True)
+            moe = None
+            if routed:
+                logits, small, moe = model.prefill_counted(
+                    params, config, tokens, lens, small,
+                    _moe_valid(ints, 0, S), mesh, last_only=True)
+            else:
+                logits, small = model.prefill(params, config, tokens, lens,
+                                              small, mesh, last_only=True)
             last = logits[:, 0, :]                                    # [R,V]
             row_keys = jax.vmap(jax.random.PRNGKey)(seeds)
             toks, row_keys = sample_batched(last, row_keys, chunk_temps,
@@ -1149,7 +1192,7 @@ class BatchScheduler:
             # The first token joins each row's penalty window at its
             # context position.
             rings = rings.at[jnp.arange(R), lens % _RING].set(toks)
-            return small, toks, row_keys, rings
+            return small, toks, row_keys, rings, moe
 
         def _install_rows(rows, row_keys, toks, ints, floats, rings, keys,
                           next_tokens, temps, top_ks, top_ps, ring, rps):
@@ -1175,7 +1218,7 @@ class BatchScheduler:
             bucket. One vector scatter installs the whole chunk."""
             S = tokens.shape[1]
             lens, rows = ints[0], ints[1]
-            small, toks, row_keys, rings = _prefill_first_token(
+            small, toks, row_keys, rings, moe = _prefill_first_token(
                 params, tokens, ints, floats, rings)
             k = cache.k.at[:, rows, :S].set(small.k, mode="drop")
             v = cache.v.at[:, rows, :S].set(small.v, mode="drop")
@@ -1186,8 +1229,8 @@ class BatchScheduler:
              rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
                                   keys, next_tokens, temps, top_ks, top_ps,
                                   ring, rps)
-            return (toks, cache, keys, next_tokens, temps, top_ks, top_ps,
-                    ring, rps)
+            return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
+                    top_ks, top_ps, ring, rps)
 
         def prefill_admit_paged(params, tokens, ints, floats, rings, tables,
                                 cache, keys, next_tokens, temps, top_ks,
@@ -1200,7 +1243,7 @@ class BatchScheduler:
             all-zero table (writes land in garbage page 0) and the
             out-of-range row sentinel (installs dropped)."""
             lens, rows = ints[0], ints[1]
-            small, toks, row_keys, rings = _prefill_first_token(
+            small, toks, row_keys, rings, moe = _prefill_first_token(
                 params, tokens, ints, floats, rings)
             from ..ops.paged_kv import write_prefill_batch
             cache = write_prefill_batch(cache, small.k, small.v, rows, lens,
@@ -1209,8 +1252,8 @@ class BatchScheduler:
              rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
                                   keys, next_tokens, temps, top_ks, top_ps,
                                   ring, rps)
-            return (toks, cache, keys, next_tokens, temps, top_ks, top_ps,
-                    ring, rps)
+            return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
+                    top_ks, top_ps, ring, rps)
 
         def _prefill_first_token_prefix(params, pk, pv, tokens, ints, floats,
                                         rings):
@@ -1236,16 +1279,22 @@ class BatchScheduler:
                                    v=small.v.at[:, :, :P].set(v0))
             positions = jnp.broadcast_to(P + jnp.arange(S)[None, :], (R, S))
             mask = causal_mask(S, P + S, P)
-            logits, small = model.forward(params, config, tokens, positions,
-                                          small, mask, mesh,
-                                          last_idx=suf_lens - 1)
+            moe = None
+            if routed:
+                logits, small, moe = model.forward_counted(
+                    params, config, tokens, positions, small, mask,
+                    _moe_valid(ints, 0, S), mesh, last_idx=suf_lens - 1)
+            else:
+                logits, small = model.forward(params, config, tokens,
+                                              positions, small, mask, mesh,
+                                              last_idx=suf_lens - 1)
             last = logits[:, 0, :]
             row_keys = jax.vmap(jax.random.PRNGKey)(seeds)
             toks, row_keys = sample_batched(last, row_keys, floats[0],
                                             ints[3], floats[1],
                                             ring=rings, rp=floats[2])
             rings = rings.at[jnp.arange(R), total_lens % _RING].set(toks)
-            return small, toks, row_keys, rings
+            return small, toks, row_keys, rings, moe
 
         def prefill_admit_prefix(params, pk, pv, tokens, ints, floats,
                                  rings, cache, keys, next_tokens, temps,
@@ -1256,7 +1305,7 @@ class BatchScheduler:
             S = tokens.shape[1]
             P = pk.shape[1]
             rows, total_lens = ints[1], ints[4]
-            small, toks, row_keys, rings = _prefill_first_token_prefix(
+            small, toks, row_keys, rings, moe = _prefill_first_token_prefix(
                 params, pk, pv, tokens, ints, floats, rings)
             k = cache.k.at[:, rows, : P + S].set(small.k, mode="drop")
             v = cache.v.at[:, rows, : P + S].set(small.v, mode="drop")
@@ -1267,8 +1316,8 @@ class BatchScheduler:
              rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
                                   keys, next_tokens, temps, top_ks, top_ps,
                                   ring, rps)
-            return (toks, cache, keys, next_tokens, temps, top_ks, top_ps,
-                    ring, rps)
+            return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
+                    top_ks, top_ps, ring, rps)
 
         def prefill_admit_paged_prefix(params, pk, pv, tokens, ints, floats,
                                        rings, tables, cache, keys,
@@ -1279,7 +1328,7 @@ class BatchScheduler:
             batch path (copy-based sharing — rows own their prefix copy,
             so release/containment invariants are untouched)."""
             rows, total_lens = ints[1], ints[4]
-            small, toks, row_keys, rings = _prefill_first_token_prefix(
+            small, toks, row_keys, rings, moe = _prefill_first_token_prefix(
                 params, pk, pv, tokens, ints, floats, rings)
             from ..ops.paged_kv import write_prefill_batch
             cache = write_prefill_batch(cache, small.k, small.v, rows,
@@ -1288,8 +1337,8 @@ class BatchScheduler:
              rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
                                   keys, next_tokens, temps, top_ks, top_ps,
                                   ring, rps)
-            return (toks, cache, keys, next_tokens, temps, top_ks, top_ps,
-                    ring, rps)
+            return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
+                    top_ks, top_ps, ring, rps)
 
         if self.kv_mode == "paged":
             self._admit_j = jax.jit(prefill_admit_paged,
@@ -1380,14 +1429,25 @@ class BatchScheduler:
             paged = self.kv_mode == "paged"
 
             def _fwd(params, tokens, ints, carry, logits_c):
+                # A routed model's carried logits travel with its drop
+                # count so far: (logits [R,V], stats [2]).
                 suf_lens = ints[0]
                 local_last = suf_lens - 1 - OFF
-                logits, carry = model.prefill_chunk(
-                    params, config, tokens, carry, base, mesh,
-                    last_idx=jnp.clip(local_last, 0, C - 1))
+                last_idx = jnp.clip(local_last, 0, C - 1)
+                if routed:
+                    logits_c, moe_c = logits_c
+                    logits, carry, moe = model.prefill_chunk_counted(
+                        params, config, tokens, carry, base,
+                        _moe_valid(ints, OFF, C), mesh, last_idx=last_idx)
+                else:
+                    logits, carry = model.prefill_chunk(
+                        params, config, tokens, carry, base, mesh,
+                        last_idx=last_idx)
                 keep = (local_last >= 0) & (local_last < C)
                 logits_c = jnp.where(keep[:, None], logits[:, 0, :],
                                      logits_c)
+                if routed:
+                    logits_c = (logits_c, moe_c + moe)
                 return carry, logits_c
 
             def _splice(cache, carry, ints, tables):
@@ -1442,9 +1502,8 @@ class BatchScheduler:
                         carry = carry._replace(
                             k=carry.k.at[:, :, :P0].set(k0),
                             v=carry.v.at[:, :, :P0].set(v0))
-                    logits0 = jnp.zeros((R, config.vocab_size), jnp.float32)
                     carry, logits_c = _fwd(params, tokens, ints, carry,
-                                           logits0)
+                                           self._chunk_logits0(R))
                     cache = _splice(cache, carry, ints, tables)
                     return carry, logits_c, cache
                 # donate the big cache (always the last argument)
@@ -1471,6 +1530,9 @@ class BatchScheduler:
                  rps) = rest[-8:]
                 carry, logits_c = _fwd(params, tokens, ints, carry,
                                        logits_c)
+                moe = None
+                if routed:
+                    logits_c, moe = logits_c
                 R = tokens.shape[0]
                 seeds, total_lens = ints[2], ints[4]
                 row_keys = jax.vmap(jax.random.PRNGKey)(seeds)
@@ -1485,8 +1547,8 @@ class BatchScheduler:
                  rps) = _install_rows(ints[1], row_keys, toks, ints,
                                       floats, rings, keys, next_tokens,
                                       temps, top_ks, top_ps, ring, rps)
-                return (toks, cache, keys, next_tokens, temps, top_ks,
-                        top_ps, ring, rps)
+                return (_with_moe(toks, moe), cache, keys, next_tokens,
+                        temps, top_ks, top_ps, ring, rps)
             # The carry kv/logits die here but have no same-shaped output
             # to alias into — donating them only trips XLA's unusable-
             # donation warning, so they are freed by refcount instead.
@@ -1505,9 +1567,13 @@ class BatchScheduler:
             the register_prefix / promotion builder."""
             P = toks.shape[1]
             cache = KVCache.create(config, 1, P, dtype=self._dtype)
-            _, cache = model.prefill(params, config, toks,
-                                     jnp.full((1,), P, jnp.int32), cache,
-                                     mesh)
+            lens = jnp.full((1,), P, jnp.int32)
+            if routed:
+                _, cache, moe = model.prefill_counted(
+                    params, config, toks, lens, cache,
+                    jnp.ones((1, P), bool), mesh)
+                return cache.k[:, 0], cache.v[:, 0], moe
+            _, cache = model.prefill(params, config, toks, lens, cache, mesh)
             return cache.k[:, 0], cache.v[:, 0]
 
         self._build_prefix_j = jax.jit(prefill_build_prefix)
@@ -1574,13 +1640,31 @@ class BatchScheduler:
         ids = self.tokenizer.encode(text, add_bos=True)
         return self._register_prefix_ids(ids[:P])
 
+    def _chunk_logits0(self, R: int):
+        """What a chunk ladder carries from chunk to chunk beside the
+        KV, at its start: the rows' last-position logits, and for a
+        routed model the drop count so far."""
+        logits = jnp.zeros((R, self.config.vocab_size), jnp.float32)
+        if self.config.is_moe:
+            return logits, jnp.zeros((2,), jnp.int32)
+        return logits
+
+    def _count_moe(self, stats) -> None:
+        self._n_moe_assigned += int(stats[0])
+        self._n_moe_dropped += int(stats[1])
+
     def _build_prefix_kv(self, ids) -> tuple:
         """Prefix KV for ``ids`` — reads only immutable state (params +
         the jitted builder), so it is safe on the promotion worker
         thread too."""
-        return self._build_prefix_j(
+        built = self._build_prefix_j(
             self._params,  # graftcheck: sync-ok host token ids, upload not readback
             jnp.asarray(np.asarray(ids, np.int32)[None, :]))
+        if self.config.is_moe:
+            # A build has no first token to ride on: its drop count
+            # waits, on the device, for the next admission's readback.
+            self._moe_unread.append(built[2])
+        return built[0], built[1]
 
     def _install_prefix(self, ids, k, v, note: str = "") -> None:
         """Store insert + log (scheduler thread only — single writer)."""
@@ -2136,8 +2220,7 @@ class BatchScheduler:
             carry_s = jax.eval_shape(
                 lambda R=R, W=P + S: KVCache.create(self.config, R, W,
                                                     dtype=self._dtype))
-            logits_s = jax.ShapeDtypeStruct((R, self.config.vocab_size),
-                                            jnp.float32)
+            logits_s = jax.eval_shape(lambda R=R: self._chunk_logits0(R))
             for off in offs:
                 prog = self._make_prefill_chunk_program(P, S, off, C)
                 if off == 0:
@@ -2275,7 +2358,7 @@ class BatchScheduler:
         else:
             kv = KVCache.create(self.config, R, prefix_len + S,
                                 dtype=self._dtype)
-            logits = jnp.zeros((R, self.config.vocab_size), jnp.float32)
+            logits = self._chunk_logits0(R)
         self._dispatch_prefill_chunk(prefix_len, S, off, C, tokens, ints,
                                      floats, rings, tables, kv, logits,
                                      entry)
@@ -3311,6 +3394,11 @@ class BatchScheduler:
             "serve_admit_rows_padded_total": self._n_admit_rows_padded,
             "serve_prefill_tokens_total": self._n_prefill_tokens,
             "serve_prefill_tokens_padded_total": self._n_prefill_padded,
+            # Routed models: (token, expert) pairs the prefill programs
+            # routed for real prompt positions, and those their
+            # capacity buckets dropped (0 and 0 for a dense model).
+            "serve_moe_assignments_total": self._n_moe_assigned,
+            "serve_moe_dropped_total": self._n_moe_dropped,
             "serve_decode_row_steps_total": self._n_decode_row_steps,
             "serve_decode_clean_seconds_total": self._clean_s,
             "serve_decode_clean_steps_total": self._clean_steps,
@@ -3926,6 +4014,13 @@ class BatchScheduler:
         with self._phase("readback", rows=len(chunk)):
             # graftcheck: sync-ok intentional: R int32 first tokens, TTFT depends on it
             first_toks = np.asarray(toks_dev)
+            if self.config.is_moe:
+                # The prefill's drop count rides behind the first tokens
+                # (_with_moe); prefix builds left theirs waiting.
+                self._count_moe(first_toks[-2:])
+                while self._moe_unread:
+                    # graftcheck: sync-ok 2 int32 of a build that ended before this admission was dispatched
+                    self._count_moe(np.asarray(self._moe_unread.popleft()))
         with self._phase("stream"):
             # Draft-source admission BEFORE the install loop (a row that
             # finishes on its very first token releases inside the loop, and

@@ -372,13 +372,19 @@ def test_queue_timeout_guards_capacity_not_boot():
         eng.stop()
 
 
-def test_moe_family_serves_through_same_scheduler():
-    """tiny-moe through the continuous-batching loop must match a solo
-    mixtral prefill+decode oracle — the scheduler dispatches the model
-    family from the config (models.family_for), not a hardcoded llama."""
+MOE_CONFIGS = ["tiny-moe", "tiny-olmoe"]
+
+
+@pytest.mark.parametrize("moe_config", MOE_CONFIGS)
+def test_moe_family_serves_through_same_scheduler(moe_config):
+    """A routed model through the continuous-batching loop must match a
+    solo mixtral prefill+decode oracle — the scheduler dispatches the
+    model family from the config (models.family_for), not a hardcoded
+    llama. tiny-olmoe (QK-norm, unrenormalised top-4 of 8) goes through
+    the same module."""
     from p2p_llm_chat_tpu.models import mixtral
 
-    mcfg = get_config("tiny-moe")
+    mcfg = get_config(moe_config)
     mparams = mixtral.init_params(mcfg, jax.random.PRNGKey(1),
                                   dtype=jnp.float32)
     stop_ids = set(mcfg.eos_token_ids) | {TOK.eos_id}

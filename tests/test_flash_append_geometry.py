@@ -190,9 +190,17 @@ def test_dispatch_policy_table(monkeypatch):
     # rule (sub-2-chunk grids cannot pipeline).
     assert not pa._flash_append_policy(255, "gather", 2048, hd=32)
     assert pa._flash_append_policy(256, "gather", 2048, hd=32)
-    # Wider-than-calibration KV raises the bar symmetrically.
-    assert not pa._flash_append_policy(2048, "gather", 2048, hd=2048)
-    assert pa._flash_append_policy(4096, "gather", 2048, hd=2048)
+    # Wider-than-calibration KV (OLMoE's MHA, hd=2048): the ratio to the
+    # knob is measured, not extrapolated: the kernel won 3.5-6.6x at W = 512..2048 on a v5e (PERF.md
+    # section 6, PR 26), where the scaled rule said gather below 4096.
+    assert pa._flash_append_policy(512, "gather", 2048, hd=2048)
+    assert pa._flash_append_policy(2048, "gather", 2048, hd=2048)
+    assert not pa._flash_append_policy(256, "gather", 2048, hd=2048)
+    assert not pa._flash_append_policy(2048, "gather", 0, hd=2048)
+    assert pa.effective_flash_min_w(hd=2048) in (0, 512)   # 0: not a TPU
+    # The knob scales that geometry too: a quarter of it.
+    assert pa._flash_append_policy(1024, "gather", 4096, hd=2048)
+    assert not pa._flash_append_policy(512, "gather", 4096, hd=2048)
     # Overrides ignore geometry.
     assert pa._flash_append_policy(64, "flash", 2048, hd=2048)
     assert not pa._flash_append_policy(1 << 20, "kernel", 2048, hd=256)

@@ -23,18 +23,23 @@ CFG = get_config("tiny")          # Hkv=2, Hq=4, D=32, L=2
 PS = 8                            # page size (slots)
 
 
-def make_cache(batch=3, num_pages=16, max_rows_pages=4):
+# Rep 1 (OLMoE's MHA): 4 query heads on 4 kv heads, one-row slices of
+# the kernels' scratch.
+MHA = get_config("tiny-olmoe")
+
+
+def make_cache(batch=3, num_pages=16, max_rows_pages=4, CFG=CFG):
     return PagedKVCache.create(CFG, batch, num_pages, PS,
                                max_pages_per_row=max_rows_pages,
                                dtype=jnp.float32)
 
 
-def random_filled_cache(rng, lengths, num_pages=16):
+def random_filled_cache(rng, lengths, num_pages=16, CFG=CFG):
     """Cache where each row's first ``lengths[b]`` slots hold random kv,
     installed through the real write ops (prefill splice)."""
     B = len(lengths)
     alloc = PageAllocator(num_pages, PS)
-    cache = make_cache(batch=B, num_pages=num_pages)
+    cache = make_cache(batch=B, num_pages=num_pages, CFG=CFG)
     S = int(max(lengths))
     L = CFG.num_layers
     dense_k = rng.normal(size=(L, B, S, CFG.num_kv_heads,
@@ -185,15 +190,16 @@ def test_parked_row_with_zero_table_writes_garbage_only():
     assert np.any(np.asarray(cache2.k[0, 0]) == 99.0)
 
 
+@pytest.mark.parametrize("CFG", [CFG, MHA], ids=["gqa", "mha"])
 @pytest.mark.parametrize("impl", ["gather", "kernel", "flash"])
 @pytest.mark.parametrize("lengths", [[1, 9, 16], [8, 8, 8], [3, 27, 1]])
-def test_kernel_matches_reference_and_dense(lengths, impl):
+def test_kernel_matches_reference_and_dense(lengths, impl, CFG):
     """Both production implementations (gather default + Pallas kernel in
     interpret mode) against the index-naive reference AND an independent
-    dense oracle."""
+    dense oracle, at rep 2 and at rep 1."""
     rng = np.random.default_rng(7)
     cache, dense_k, dense_v, _, _ = random_filled_cache(
-        rng, lengths, num_pages=32)
+        rng, lengths, num_pages=32, CFG=CFG)
     B = len(lengths)
     q = jnp.asarray(rng.normal(size=(B, CFG.num_heads, CFG.head_dim)),
                     jnp.float32)

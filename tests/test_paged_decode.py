@@ -52,8 +52,9 @@ def setup_caches(model, cfg, params, prompts_lens, max_seq=64, num_pages=32):
     return dense, paged, logits
 
 
-@pytest.mark.parametrize("model,cfg_name", [(llama, "tiny"),
-                                            (mixtral, "tiny-moe")])
+@pytest.mark.parametrize("model,cfg_name", [
+    (llama, "tiny"), (mixtral, "tiny-moe"),
+    (mixtral, "tiny-olmoe")])       # rep 1: 4 query heads on 4 kv heads
 def test_paged_decode_matches_dense(model, cfg_name):
     cfg = get_config(cfg_name)
     params = model.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)

@@ -112,9 +112,48 @@ def test_tile_table_pinned_entries():
     (8, 1024, 5632, 256),      # bench-moe wgu_e (cap via H=1024)
     (8, 2816, 1024, 128),      # bench-moe w_down (cap avoids bo=O)
     (2048, 11520, 4096, None),  # prefill-class rows blow the x budget
+    # mixtral-8x7b (the benchmark's 6-layer cell): wgu_e [4096 -> 2 x
+    # 14336] and w_down, at a part-full, a full and a prefill bucket.
+    (2, 4096, 28672, 1024),
+    (32, 4096, 28672, 1024),
+    (512, 4096, 28672, 1024),
+    (32, 14336, 4096, 256),     # the 4 MiB stripe budget shrinks it
+    # olmoe-1b-7b's thin experts, NE 64 (PERF.md section 6, PR 26: the
+    # chip's sweep of the caps at C = 8..512): wgu_e [2048 -> 2 x 1024]
+    # and w_down [1024 -> 2048].
+    (8, 2048, 2048, 1024),
+    (32, 2048, 2048, 1024),
+    (64, 2048, 2048, 1024),
+    (512, 2048, 2048, 1024),
+    (8, 1024, 2048, 256),
+    (32, 1024, 2048, 256),
+    (64, 1024, 2048, 256),
+    (512, 1024, 2048, 256),
 ])
 def test_pick_expert_bo_matrix(rows, H, O, bo):
     assert qmm.pick_expert_bo(rows, H, O, 2) == bo
+
+
+@pytest.mark.parametrize("rows,H,O,bo", [
+    # mistral-7b-v0.3's decode projections at a full batch: fused qkv,
+    # wo, fused gate|up, w_down, the 32768-wide head.
+    (32, 4096, 6144, 1024),
+    (32, 4096, 4096, 1024),
+    (32, 4096, 28672, 1024),
+    (32, 14336, 4096, 256),
+    (32, 4096, 32768, 1024),
+    (32, 4096, 32000, 256),     # mixtral's head: 32000 = 125 x 256
+    # olmoe-1b-7b's dense projections: fused qkv (MHA: 3 x 2048), wo, and
+    # the 50304-wide head (393 x 128).
+    (32, 2048, 6144, 1024),
+    (32, 2048, 2048, 1024),
+    (32, 2048, 50304, 128),
+])
+def test_pick_1d_bo_at_the_benchmarks_dense_shapes(rows, H, O, bo):
+    """The dense stripe kernel's tile at every decode projection the
+    benchmark's three configurations run: a retune of `_TILE_TABLE` or
+    of a budget for one of them shows here if it moves another's."""
+    assert qmm._pick_1d_bo(rows, H, O, 2) == bo
 
 
 @pytest.mark.parametrize("rows,H,O,ng,bo", [

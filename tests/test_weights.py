@@ -353,3 +353,104 @@ def test_load_checkpoint_quantized_moe_native_matches(tmp_path):
     _assert_trees_equal(got, want)
 
 
+
+
+# -- OLMoE: its own tensor names, num_experts / norm_topk_prob, QK-norm -------
+
+def _tiny_olmoe():
+    """A seeded ``transformers`` OLMoE at a toy size (MHA, 8 experts
+    top-4, ``norm_topk_prob: false``), its q_norm / k_norm weights made
+    random so that a norm the loader dropped could not pass."""
+    from p2p_llm_chat_tpu.models.configs import ModelConfig
+    hf_cfg = transformers.OlmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=4, norm_topk_prob=False,
+        max_position_embeddings=256, rope_theta=10000.0, rms_norm_eps=1e-5,
+        attention_dropout=0.0, pad_token_id=1, eos_token_id=2)
+    torch.manual_seed(0)
+    model = transformers.OlmoeForCausalLM(hf_cfg).eval()
+    with torch.no_grad():
+        for name, p in model.named_parameters():
+            if name.endswith(("q_norm.weight", "k_norm.weight")):
+                p.copy_(0.5 + torch.rand_like(p))
+    ours = ModelConfig(
+        name="tiny-olmoe-parity", vocab_size=128, hidden_size=64,
+        intermediate_size=32, num_layers=2, num_heads=4, num_kv_heads=4,
+        head_dim=16, max_seq_len=256, rope_theta=10000.0,
+        num_experts=8, num_experts_per_tok=4, moe_renormalize=False,
+        qk_norm_whole=True, bos_token_id=1, eos_token_ids=(2,))
+    return model, ours
+
+
+def test_olmoe_checkpoint_names_config_and_logits(tmp_path):
+    """config.json's ``num_experts``, ``norm_topk_prob`` and ``model_type:
+    olmoe`` reach the ModelConfig; ``self_attn.q_norm / k_norm``,
+    ``mlp.gate`` and ``mlp.experts.N.{gate,up,down}_proj`` reach their
+    leaves; and the loaded tree, through models/mixtral.py, gives the
+    ``transformers`` model's own logits."""
+    from p2p_llm_chat_tpu.models import family_for, mixtral
+    from p2p_llm_chat_tpu.models.llama import KVCache
+    model, cfg = _tiny_olmoe()
+    ckpt = _write_ckpt(tmp_path, model, n_shards=3)
+    with open(os.path.join(ckpt, "config.json")) as f:
+        published = json.load(f)
+    assert published["model_type"] == "olmoe"
+    assert "num_local_experts" not in published
+    loaded_cfg = config_from_hf_json(os.path.join(ckpt, "config.json"))
+    assert (loaded_cfg.num_experts, loaded_cfg.num_experts_per_tok) == (8, 4)
+    assert loaded_cfg.qk_norm_whole and not loaded_cfg.moe_renormalize
+    assert family_for(loaded_cfg) is mixtral
+
+    params, _ = load_checkpoint(ckpt, dtype=jnp.float32)
+    want = convert_hf_state_dict(_np_state(model), cfg, dtype=jnp.float32)
+    _assert_trees_equal(params, want)
+    state = _np_state(model)
+    L = params["layers"]
+    assert L["q_norm"].shape == (2, 64) and L["k_norm"].shape == (2, 64)
+    np.testing.assert_array_equal(
+        np.asarray(L["k_norm"][1]),
+        state["model.layers.1.self_attn.k_norm.weight"])
+    np.testing.assert_array_equal(
+        np.asarray(L["router"][0]), state["model.layers.0.mlp.gate.weight"].T)
+    np.testing.assert_array_equal(
+        np.asarray(L["w_down"][1, 5]),
+        state["model.layers.1.mlp.experts.5.down_proj.weight"].T)
+
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(2, 12)).astype(np.int32)
+    with torch.no_grad():
+        ref = model(torch.from_numpy(tokens).long()).logits.float().numpy()
+    cache = KVCache.create(cfg, batch=2, max_seq=32, dtype=jnp.float32)
+    ours, _ = mixtral.prefill(params, loaded_cfg, jnp.asarray(tokens),
+                              jnp.array([12, 12]), cache)
+    np.testing.assert_allclose(np.asarray(ours), ref, atol=5e-3, rtol=2e-2)
+    # A Mixtral-style reading of the same weights is a different model.
+    wrong, _ = mixtral.prefill(
+        params, loaded_cfg.with_(moe_renormalize=True), jnp.asarray(tokens),
+        jnp.array([12, 12]),
+        KVCache.create(cfg, batch=2, max_seq=32, dtype=jnp.float32))
+    assert np.abs(np.asarray(wrong) - ref).max() > 10 * np.abs(
+        np.asarray(ours) - ref).max()
+
+
+def test_olmoe_streamed_int8_load_matches_quantize_then_fuse(tmp_path):
+    """The streamed int8 loader carries q_norm and k_norm beside the
+    other norm vectors, and the identity check compares the two fields a
+    checkpoint's arithmetic depends on."""
+    from p2p_llm_chat_tpu.models import mixtral
+    from p2p_llm_chat_tpu.models.quant import quantize_params
+    from p2p_llm_chat_tpu.models.weights import load_checkpoint_quantized
+
+    model, cfg = _tiny_olmoe()
+    ckpt = _write_ckpt(tmp_path, model, n_shards=2)
+    got, got_cfg = load_checkpoint_quantized(ckpt)
+    assert got_cfg.qk_norm_whole and not got_cfg.moe_renormalize
+    base, _ = load_checkpoint(ckpt)
+    want = mixtral.fuse_params(quantize_params(base))
+    assert {"q_norm", "k_norm", "wgu_e"} <= set(want["layers"])
+    _assert_trees_equal(got, want)
+    for field in ("moe_renormalize", "qk_norm_whole"):
+        with pytest.raises(ValueError, match=field):
+            load_checkpoint_quantized(
+                ckpt, config=cfg.with_(**{field: not getattr(cfg, field)}))

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +51,19 @@ def _flash_kernel(q, k_cur, v_cur, cache, lens, layer, *, pages,
         cache.page_table, lens, layer, pages=pages, quantized=quantized)
 
 
+# (query heads, key-value heads): llama3.1-8b's GQA (rep 4) and OLMoE's
+# MHA (rep 1, 16 heads — one-row scratch slices and [1, D] x [D, page]
+# dots, sixteen times over).
+GQA, MHA16 = (32, 8), (16, 16)
+
+
+def _cfg(heads: tuple, layers=2):
+    return get_config("llama3.1-8b").with_(
+        num_layers=layers, num_heads=heads[0], num_kv_heads=heads[1])
+
+
 def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
-        label="block", seed=0) -> None:
+        label="block", seed=0, heads=GQA) -> None:
     """Shared harness: random bf16/int8 pool filled through the real
     splice op, ``kernel`` vs the gather append path at first/last layer.
 
@@ -63,7 +75,7 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
     in interpret mode)."""
     # Two layers are all the check reads (first and last), and what
     # keeps the bf16 pool at W=3072 x B=32 inside a 16 GB chip.
-    cfg = get_config("llama3.1-8b").with_(num_layers=2)
+    cfg = _cfg(heads)
     rng = np.random.default_rng(seed)
     key = jax.random.PRNGKey(seed)
     mppr = pages
@@ -122,11 +134,11 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
         assert err / denom < 2e-2, f"{label} kernel diverges from gather path"
 
 
-def run_flash(quantized: bool, B=32, pages=48, ps=64) -> None:
+def run_flash(quantized: bool, B=32, pages=48, ps=64, heads=GQA) -> None:
     """The multi-chunk flash-append kernel at a long (multi-chunk)
     window — see run()'s docstring for what that exercises."""
     run(quantized, B, pages, ps, kernel=_flash_kernel, label="flash",
-        seed=1)
+        seed=1, heads=heads)
 
 
 def _close(got, ref, what: str) -> None:
@@ -136,10 +148,10 @@ def _close(got, ref, what: str) -> None:
     assert rel < 2e-2, f"{what} diverges from the XLA path"
 
 
-def run_decode_impl(impl: str, B=32, pages=3, ps=64) -> None:
+def run_decode_impl(impl: str, B=32, pages=3, ps=64, heads=GQA) -> None:
     """``PAGED_ATTN_IMPL=kernel|flash`` (write-then-attend decode over a
     bf16 pool) vs the gather impl."""
-    cfg = get_config("llama3.1-8b").with_(num_layers=2)
+    cfg = _cfg(heads)
     key = jax.random.PRNGKey(2)
     shape = (cfg.num_layers, B * pages + 1, ps, cfg.num_kv_heads,
              cfg.head_dim)
@@ -159,13 +171,13 @@ def run_decode_impl(impl: str, B=32, pages=3, ps=64) -> None:
                f"decode impl={impl} layer={layer}")
 
 
-def run_prefill_flash(B=1, S=2048) -> None:
+def run_prefill_flash(B=1, S=2048, heads=GQA) -> None:
     """The prefill flash kernel at the shape that reaches it under the
     default chunked admission: a 2048-token prefix build."""
     from p2p_llm_chat_tpu.models.layers import (attend_gqa,
                                                 attend_gqa_causal0,
                                                 causal_mask)
-    cfg = get_config("llama3.1-8b")
+    cfg = _cfg(heads)
     key = jax.random.PRNGKey(3)
     q = jax.random.normal(key, (B, S, cfg.num_heads, cfg.head_dim),
                           jnp.bfloat16)
@@ -178,15 +190,109 @@ def run_prefill_flash(B=1, S=2048) -> None:
            f"prefill flash B={B} S={S}")
 
 
+def time_append(heads, W: int, B=32, ps=64, repeat=16, steps=10) -> None:
+    """Milliseconds a layer-step of the int8 pool's append attention, the
+    XLA gather path against the flash-append kernel, at window ``W``:
+    the measurement behind the flash-append boundary
+    (ops/paged_attention._flash_append_policy). Contexts as a full
+    backlog batch holds them: 128 to 900 tokens, and one row that needs
+    the window. One dispatch runs ``repeat`` layer-steps (a lone call
+    measures the host)."""
+    cfg = _cfg(heads)
+    pages = W // ps
+    rng = np.random.default_rng(W)
+    key = jax.random.PRNGKey(W)
+    cache = PagedKVCache.create(cfg, B, B * pages + 1, ps,
+                                max_pages_per_row=pages, dtype=jnp.bfloat16,
+                                quantized=True)
+    lengths = [int(n) for n in rng.integers(128, min(900, W - 1), size=B)]
+    lengths[0] = W - 2
+    for b, n in enumerate(lengths):
+        table = jnp.asarray(1 + b * pages + np.arange(pages), jnp.int32)
+        rk = jax.random.normal(
+            jax.random.fold_in(key, 2 * b),
+            (cfg.num_layers, W, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+        rv = jax.random.normal(jax.random.fold_in(key, 2 * b + 1), rk.shape,
+                               jnp.bfloat16)
+        cache = write_prefill_row(cache, rk, rv, jnp.asarray(b),
+                                  jnp.asarray(n), table)
+    lens = jnp.asarray(lengths, jnp.int32)
+    q = jax.random.normal(key, (B, cfg.num_heads, cfg.head_dim),
+                          jnp.bfloat16)
+    k_cur = jax.random.normal(jax.random.fold_in(key, 99),
+                              (B, cfg.num_kv_heads, cfg.head_dim),
+                              jnp.bfloat16)
+
+    def timed(one) -> float:
+        @jax.jit
+        def run(q, cache):
+            def body(i, acc):
+                return acc + one(q, k_cur, k_cur, cache, lens,
+                                 i % cfg.num_layers)
+            return jax.lax.fori_loop(0, repeat, body, jnp.zeros_like(q))
+        np.asarray(run(q, cache)).ravel()[:1]
+        t = time.monotonic()
+        for _ in range(steps):
+            out = run(q, cache)
+        np.asarray(out).ravel()[:1]
+        return (time.monotonic() - t) / steps / repeat * 1e3
+
+    saved = (pa._APPEND_IMPL, os.environ.get("PAGED_APPEND_FLASH_MIN_W"))
+    pa._APPEND_IMPL = "gather"
+    os.environ["PAGED_APPEND_FLASH_MIN_W"] = "0"
+    try:
+        gather = timed(lambda *a: pa.paged_attention_append(*a, pages=pages))
+    finally:
+        pa._APPEND_IMPL = saved[0]
+        if saved[1] is None:
+            os.environ.pop("PAGED_APPEND_FLASH_MIN_W", None)
+        else:
+            os.environ["PAGED_APPEND_FLASH_MIN_W"] = saved[1]
+    flash = timed(lambda *a: _flash_kernel(*a, pages=pages, quantized=True))
+    hd = cfg.num_kv_heads * cfg.head_dim
+    print(f"append int8 heads={heads} hd={hd} W={W} B={B}: gather "
+          f"{gather:.4f} ms, flash {flash:.4f} ms a layer-step "
+          f"({gather / flash:.2f}x); the rule says "
+          f"{'flash' if pa._flash_append_policy(W, 'auto', pa._flash_append_min_w(), hd) else 'gather'}",
+          flush=True)
+
+
 def main() -> int:
     require_tpu()
+    # ``python tools/check_append_kernel.py time``: the timing behind the
+    # flash-append boundary, not the verdicts.
+    if len(sys.argv) > 1 and sys.argv[1] == "time":
+        for heads in (MHA16, GQA):
+            for W in (512, 1024, 2048):
+                time_append(heads, W)
+        return 0
     cases = (("block int8", lambda: run(quantized=True)),
              ("block bf16", lambda: run(quantized=False)),
              ("flash-append int8", lambda: run_flash(quantized=True)),
              ("flash-append bf16", lambda: run_flash(quantized=False)),
              ("decode impl=kernel bf16", lambda: run_decode_impl("kernel")),
              ("decode impl=flash bf16", lambda: run_decode_impl("flash")),
-             ("prefill flash", run_prefill_flash))
+             ("prefill flash", run_prefill_flash),
+             # OLMoE's geometry: rep 1, 16 heads. The int8 pool is what
+             # the benchmark's stack serves from.
+             ("mha16 block int8",
+              lambda: run(quantized=True, heads=MHA16)),
+             ("mha16 block bf16",
+              lambda: run(quantized=False, heads=MHA16)),
+             ("mha16 flash-append int8",
+              lambda: run_flash(quantized=True, heads=MHA16)),
+             ("mha16 flash-append bf16",
+              lambda: run_flash(quantized=False, heads=MHA16)),
+             ("mha16 decode impl=kernel bf16",
+              lambda: run_decode_impl("kernel", heads=MHA16)),
+             ("mha16 decode impl=flash bf16",
+              lambda: run_decode_impl("flash", heads=MHA16)),
+             ("mha16 prefill flash",
+              lambda: run_prefill_flash(heads=MHA16)))
+    # ``python tools/check_append_kernel.py mha16``: only the cases whose
+    # label holds the word.
+    if len(sys.argv) > 1:
+        cases = tuple(c for c in cases if sys.argv[1] in c[0])
     failed, _ = run_cases(cases)
     print(f"attention kernels: {len(cases) - failed}/{len(cases)} compile "
           "and match their XLA paths")

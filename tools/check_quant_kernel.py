@@ -139,14 +139,15 @@ def run4(H: int, O: int, L: int = 2) -> None:
         raise SlowerThanXLA(f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms")
 
 
-def run_experts8(H: int, O: int, NE: int = 8, L: int = 2) -> None:
+def run_experts8(H: int, O: int, NE: int = 8, L: int = 2,
+                 rows: int = EXPERT_ROWS) -> None:
     """w8a16 grouped expert dispatch (round 18): the per-expert stripe
     walk vs the forced-XLA dequant einsum at decode-class capacity."""
     rng = np.random.default_rng(H + O + 2)
-    x = jnp.asarray(rng.standard_normal((NE, EXPERT_ROWS, H), np.float32),
+    x = jnp.asarray(rng.standard_normal((NE, rows, H), np.float32),
                     jnp.bfloat16)
     qt = _quantized(H + O + 2, (L, NE, H, O), quantize)
-    assert pick_expert_bo(EXPERT_ROWS, H, O, 2) is not None, \
+    assert pick_expert_bo(rows, H, O, 2) is not None, \
         f"expert kernel must cover H={H} O={O}"
 
     xla = jax.jit(lambda x, q, s: jnp.einsum(
@@ -163,11 +164,73 @@ def run_experts8(H: int, O: int, NE: int = 8, L: int = 2) -> None:
 
     k_ms = _time_ms(lambda: quant_matmul_experts_stacked(x, qt.q, qt.s, 1))
     x_ms = _time_ms(lambda: xla(x, qt.q[1], qt.s[1]))
-    bo = pick_expert_bo(EXPERT_ROWS, H, O, 2)
-    print(f"int8 experts H={H} O={O} NE={NE} (bo={bo}): kernel "
-          f"{k_ms:.4f} ms vs XLA {x_ms:.4f} ms ({x_ms / k_ms:.2f}x)")
+    bo = pick_expert_bo(rows, H, O, 2)
+    print(f"int8 experts H={H} O={O} NE={NE} C={rows} (bo={bo}): "
+          f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms ({x_ms / k_ms:.2f}x)")
     if k_ms > x_ms * 1.02:
         raise SlowerThanXLA(f"kernel {k_ms:.4f} ms vs XLA {x_ms:.4f} ms")
+
+
+HBM_GBPS = 819.0   # TPU v5e
+
+
+def sweep_experts8(H: int, O: int, NE: int, rows: tuple,
+                   caps: tuple) -> None:
+    """The measurement behind a `_TILE_TABLE` entry for an expert
+    stripe: the kernel's time at every bucket size ``rows`` under every
+    output-tile cap ``caps`` (None = the budget's own choice), beside
+    the XLA dequant einsum and the time the bytes take at the chip's
+    bandwidth. One dispatch runs the matmul ``REPEAT`` times over
+    alternating layers, as a model's layer scan does: a lone call of
+    half a millisecond measures the host's dispatch, not the kernel.
+    The cap is set for the duration of one timing and the table is left
+    as it was."""
+    from p2p_llm_chat_tpu.ops import quant_mm as qmm
+    L, REPEAT = 2, 16
+    qt = _quantized(H + O + 5, (L, NE, H, O), quantize)
+
+    def repeated(one):
+        @jax.jit
+        def run(x, q, s):
+            def body(i, acc):
+                return acc + one(x, q, s, i % L)
+            return jax.lax.fori_loop(
+                0, REPEAT, body, jnp.zeros((NE, x.shape[1], O), x.dtype))
+        return run
+
+    xla = repeated(lambda x, q, s, layer: jnp.einsum(
+        "ech,ehf->ecf", x, q[layer].astype(x.dtype)) * s[layer].astype(
+            x.dtype))
+    saved = dict(qmm._TILE_TABLE)
+    try:
+        for C in rows:
+            x = jax.random.normal(jax.random.PRNGKey(C), (NE, C, H),
+                                  jnp.bfloat16)
+            nbytes = NE * (H * O + 4 * O + 2 * C * (H + O))
+            floor_ms = nbytes / (HBM_GBPS * 1e9) * 1e3
+            x_ms = _time_ms(lambda: xla(x, qt.q, qt.s)) / REPEAT
+            print(f"sweep H={H} O={O} NE={NE} C={C}: bytes {nbytes} "
+                  f"floor {floor_ms:.4f} ms, XLA {x_ms:.4f} ms")
+            for cap in caps:
+                qmm._TILE_TABLE.clear()
+                qmm._TILE_TABLE.update(saved)
+                qmm._TILE_TABLE.pop(H, None)
+                if cap is not None:
+                    qmm._TILE_TABLE[H] = cap
+                jax.clear_caches()
+                bo = pick_expert_bo(C, H, O, 2)
+                if bo is None:
+                    print(f"  cap={cap}: kernel does not cover C={C}")
+                    continue
+                kern = repeated(quant_matmul_experts_stacked)
+                k_ms = _time_ms(lambda: kern(x, qt.q, qt.s)) / REPEAT
+                print(f"  cap={cap} bo={bo} grid={NE}x{O // bo}: "
+                      f"{k_ms:.4f} ms = {100 * floor_ms / k_ms:.1f}% of "
+                      f"roofline, {x_ms / k_ms:.2f}x XLA", flush=True)
+    finally:
+        qmm._TILE_TABLE.clear()
+        qmm._TILE_TABLE.update(saved)
+        jax.clear_caches()
 
 
 def run_experts4(H: int, O: int, NE: int = 8, L: int = 2) -> None:
@@ -230,6 +293,24 @@ def main() -> int:
                    functools.partial(run_experts8, H, O)),
                   (f"int4 experts H={H} O={O}",
                    functools.partial(run_experts4, H, O))]
+    # OLMoE-1B-7B's thin experts (NE 64, F 1024): fused gate|up
+    # [2048 -> 2048] and w_down [1024 -> 2048], at the decode bucket
+    # (C = 32 rows), a part-full one (8) and the prefill buckets of 256-
+    # and 2048-token admissions under capacity factor 2 (64, 512).
+    for H, O in ((2048, 2048), (1024, 2048)):
+        for C in (8, 32, 64, 512):
+            cases.append((f"int8 experts ne64 H={H} O={O} C={C}",
+                          functools.partial(run_experts8, H, O, 64, 2, C)))
+    # ``python tools/check_quant_kernel.py ne64``: only the cases whose
+    # label holds the word; ``sweep-ne64``: the tile sweep behind the
+    # thin experts' _TILE_TABLE entries instead of the verdicts.
+    if len(sys.argv) > 1 and sys.argv[1] == "sweep-ne64":
+        rows = (8, 32, 64, 128, 256, 512)
+        sweep_experts8(2048, 2048, 64, rows, (None, 512, 256))
+        sweep_experts8(1024, 2048, 64, rows, (None, 512, 256, 128))
+        return 0
+    if len(sys.argv) > 1:
+        cases = [c for c in cases if sys.argv[1] in c[0]]
     failed, slower = run_cases(cases)
     print(f"quant kernels: {len(cases) - failed}/{len(cases)} compile and "
           f"match XLA, {slower} lose to XLA on time")
