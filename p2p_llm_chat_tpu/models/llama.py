@@ -514,6 +514,28 @@ def _block(h: jax.Array, lp: dict, config: ModelConfig, inv_freq: jax.Array,
         cache_k, cache_v
 
 
+def _mlp_carrying(mlp_fn, aux):
+    """An aux-form MLP (``mlp_fn(x, lp, mesh, rules, aux) -> (out,
+    aux)``) in the block's form, for one trace of a layer body: the block
+    hands the MLP's output on and knows no second result, so the running
+    value goes in here and is read back, after the block, from the second
+    function returned."""
+    def fn(x, lp, mesh, rules):
+        nonlocal aux
+        out, aux = mlp_fn(x, lp, mesh, rules, aux)
+        return out
+    return fn, lambda: aux
+
+
+def _mlp_without_aux(mlp_fn, config: ModelConfig):
+    """``mlp_fn(x, lp, mesh, rules)`` (None = the dense SwiGLU) in the aux
+    form, with nothing to accumulate."""
+    def fn(x, lp, mesh, rules, aux):
+        return (mlp_fn(x, lp, mesh, rules) if mlp_fn is not None
+                else _default_mlp(x, lp, mesh, rules, config)), aux
+    return fn
+
+
 def hidden_states_aux(params: dict, config: ModelConfig, tokens: jax.Array,
                       positions: jax.Array, cache: KVCache, mask: jax.Array,
                       mlp_fn, mlp_aux,
@@ -543,18 +565,11 @@ def hidden_states_aux(params: dict, config: ModelConfig, tokens: jax.Array,
     def body(carry, layer):
         h, ck, cv, aux = carry
         lp = _layer_view(params["layers"], layer)
-
-        # The block hands the MLP's output on and knows no second
-        # result: the running value goes in and comes out beside it,
-        # inside this one trace of the body.
-        def fn(x, lp, mesh, rules):
-            nonlocal aux
-            out, aux = mlp_fn(x, lp, mesh, rules, aux)
-            return out
+        fn, aux_after = _mlp_carrying(mlp_fn, aux)
         h, ck, cv = _block(h, lp, config, inv_freq, positions, ck, cv,
                            layer, wp, mask, mesh, rules, kv_window,
                            fn, causal0)
-        return (h, ck, cv, aux), None
+        return (h, ck, cv, aux_after()), None
 
     (h, new_k, new_v, aux), _ = jax.lax.scan(
         body, (h, cache.k, cache.v, mlp_aux), jnp.arange(config.num_layers))
@@ -574,12 +589,10 @@ def hidden_states(params: dict, config: ModelConfig, tokens: jax.Array,
     also the embedding feature extractor (:func:`embed_pooled` / the
     serve /api/embed path). :func:`hidden_states_aux` with nothing to
     accumulate; ``mlp_fn(x, lp, mesh, rules)`` or the dense default."""
-    def fn(x, lp, mesh, rules, aux):
-        return (mlp_fn(x, lp, mesh, rules) if mlp_fn is not None
-                else _default_mlp(x, lp, mesh, rules, config)), aux
     h, cache, _ = hidden_states_aux(params, config, tokens, positions, cache,
-                                    mask, fn, (), mesh, rules, kv_window,
-                                    causal0, write_pos)
+                                    mask, _mlp_without_aux(mlp_fn, config),
+                                    (), mesh, rules, kv_window, causal0,
+                                    write_pos)
     return h, cache
 
 
@@ -633,13 +646,14 @@ def forward_aux(params: dict, config: ModelConfig, tokens: jax.Array,
                 mesh: Optional[Mesh] = None,
                 rules: LogicalRules = DEFAULT_RULES,
                 causal0: bool = False,
-                last_idx: Optional[jax.Array] = None
+                last_idx: Optional[jax.Array] = None,
+                kv_window: Optional[int] = None
                 ) -> tuple[jax.Array, KVCache, Any]:
-    """:func:`forward` over :func:`hidden_states_aux` (prefill shapes:
-    the whole cache width is read): (logits, cache, aux)."""
+    """:func:`forward` over :func:`hidden_states_aux`: (logits, cache,
+    aux)."""
     h, cache, aux = hidden_states_aux(params, config, tokens, positions,
                                       cache, mask, mlp_fn, mlp_aux, mesh,
-                                      rules, causal0=causal0)
+                                      rules, kv_window, causal0)
     return _logits(params, config, h, last_idx, mesh, rules), cache, aux
 
 
@@ -785,14 +799,14 @@ def decode_step(params: dict, config: ModelConfig, tokens: jax.Array,
     return logits, cache._replace(lengths=cache.lengths + inc)
 
 
-def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
-                 cache, mesh: Optional[Mesh] = None,
-                 rules: LogicalRules = DEFAULT_RULES,
-                 active: Optional[jax.Array] = None, *,
-                 num_steps: int, sample_fn, sample_state, stop_ids,
-                 kv_window: Optional[int] = None,
-                 pages: Optional[int] = None,
-                 step_fn=None):
+def decode_fused_aux(params: dict, config: ModelConfig, tokens: jax.Array,
+                     cache, step_fn, step_aux,
+                     mesh: Optional[Mesh] = None,
+                     rules: LogicalRules = DEFAULT_RULES,
+                     active: Optional[jax.Array] = None, *,
+                     num_steps: int, sample_fn, sample_state, stop_ids,
+                     kv_window: Optional[int] = None,
+                     pages: Optional[int] = None):
     """``num_steps`` autoregressive steps in ONE dispatch: a ``lax.scan``
     over :func:`decode_step` (dense) / :func:`decode_step_paged`
     (``pages`` set) carrying the cache, the sampled next-token feed, the
@@ -817,26 +831,29 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
     absorb ``num_steps`` tokens of KV budget (the scheduler's adaptive-K
     guard); EOS is the only mid-scan retirement.
 
+    ``step_fn(params, config, tokens, cache, mesh, rules, aux,
+    active=..., kv_window=... | pages=...) -> (logits, cache, aux)`` is
+    the step in the form that accumulates something over its layers
+    (models/mixtral.py counts the experts a step touched); ``step_aux``
+    (a pytree) is what the first step is handed, and the scan carries it.
+
     Returns (tokens [num_steps, B] int32, emitted [num_steps, B] bool —
     whether the row was live when that step sampled, next_tokens [B,1],
-    cache, active [B], sample_state).
+    cache, active [B], sample_state, aux).
     """
-    if step_fn is None:
-        step_fn = decode_step if pages is None else decode_step_paged
     B = tokens.shape[0]
     if active is None:
         active = jnp.ones((B,), bool)
     stop = jnp.asarray(stop_ids, jnp.int32).reshape(-1)
 
+    window = ({"kv_window": kv_window} if pages is None
+              else {"pages": pages})
+
     def step(carry, _):
-        tokens, cache, act, state = carry
+        tokens, cache, act, state, aux = carry
         emit_pos = cache.lengths + 1       # emitted token's context slot
-        if pages is None:
-            logits, cache = step_fn(params, config, tokens, cache, mesh,
-                                    rules, active=act, kv_window=kv_window)
-        else:
-            logits, cache = step_fn(params, config, tokens, cache, mesh,
-                                    rules, active=act, pages=pages)
+        logits, cache, aux = step_fn(params, config, tokens, cache, mesh,
+                                     rules, aux, active=act, **window)
         toks, state = sample_fn(logits[:, 0, :], state, emit_pos, act)
         # Parked rows keep their previous input token (the plain
         # program's exact next-token rule).
@@ -844,12 +861,34 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
         emitted = act
         if stop.shape[0]:
             act = act & jnp.all(toks[:, None] != stop[None, :], axis=1)
-        return (next_tokens, cache, act, state), (toks, emitted)
+        return (next_tokens, cache, act, state, aux), (toks, emitted)
 
-    (tokens, cache, active, sample_state), (toks_all, emitted) = \
-        jax.lax.scan(step, (tokens, cache, active, sample_state), None,
-                     length=num_steps)
-    return toks_all, emitted, tokens, cache, active, sample_state
+    (tokens, cache, active, sample_state, aux), (toks_all, emitted) = \
+        jax.lax.scan(step, (tokens, cache, active, sample_state, step_aux),
+                     None, length=num_steps)
+    return toks_all, emitted, tokens, cache, active, sample_state, aux
+
+
+def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
+                 cache, mesh: Optional[Mesh] = None,
+                 rules: LogicalRules = DEFAULT_RULES,
+                 active: Optional[jax.Array] = None, *,
+                 num_steps: int, sample_fn, sample_state, stop_ids,
+                 kv_window: Optional[int] = None,
+                 pages: Optional[int] = None):
+    """:func:`decode_fused_aux` with nothing to accumulate, over
+    :func:`decode_step` / :func:`decode_step_paged` (``pages`` set): the
+    same returns without the aux."""
+    step_fn = decode_step if pages is None else decode_step_paged
+
+    def fn(params, config, tokens, cache, mesh, rules, aux, **kw):
+        return (*step_fn(params, config, tokens, cache, mesh, rules, **kw),
+                aux)
+    return decode_fused_aux(params, config, tokens, cache, fn, (), mesh,
+                            rules, active, num_steps=num_steps,
+                            sample_fn=sample_fn, sample_state=sample_state,
+                            stop_ids=stop_ids, kv_window=kv_window,
+                            pages=pages)[:-1]
 
 
 def verify_step(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -1118,12 +1157,17 @@ def verify_tree_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                      rules), cache
 
 
-def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
-                      cache, mesh: Optional[Mesh] = None,
-                      rules: LogicalRules = DEFAULT_RULES,
-                      active: Optional[jax.Array] = None,
-                      *, pages: int, mlp_fn=None):
-    """One autoregressive step over the paged KV pool (ops/paged_kv.py).
+def decode_step_paged_aux(params: dict, config: ModelConfig,
+                          tokens: jax.Array, cache, mlp_fn, mlp_aux,
+                          mesh: Optional[Mesh] = None,
+                          rules: LogicalRules = DEFAULT_RULES,
+                          active: Optional[jax.Array] = None,
+                          *, pages: int):
+    """One autoregressive step over the paged KV pool (ops/paged_kv.py),
+    for an MLP that accumulates something over the layers, in
+    :func:`hidden_states_aux`'s form: ``mlp_fn(x, lp, mesh, rules, aux)
+    -> (out, aux)``, ``mlp_aux`` what the first layer is handed.
+    :func:`decode_step_paged` is this with nothing to accumulate.
 
     Same contract as :func:`decode_step` — including the parked-row
     invariant, which paging strengthens: a released row's zeroed page
@@ -1134,7 +1178,7 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     ``pages = ceil(window / page_size)``).
 
     cache: ops.paged_kv.PagedKVCache. Returns (logits [B,1,vocab], cache
-    with lengths advanced where active).
+    with lengths advanced where active, aux).
 
     Structure note: the default (gather-impl) path attends BEFORE the
     pool write — the current token's k/v folds into attention via one
@@ -1176,7 +1220,9 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
         return constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
 
     if _DEFAULT_IMPL == "gather":
-        def body(h, layer):
+        def body(carry, layer):
+            h, aux = carry
+            fn, aux_after = _mlp_carrying(mlp_fn, aux)
             lp = _layer_view(params["layers"], layer)
             q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh,
                                 rules)
@@ -1184,16 +1230,16 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                                           cache.lengths, layer, pages=pages,
                                           interpret=interpret,
                                           sharded=mesh is not None)
-            h = _post_attn(h, attn[:, None], lp, config, mesh, rules,
-                           mlp_fn)
-            return h, (k[:, 0], v[:, 0])
+            h = _post_attn(h, attn[:, None], lp, config, mesh, rules, fn)
+            return (h, aux_after()), (k[:, 0], v[:, 0])
 
-        h, (k_all, v_all) = jax.lax.scan(
-            body, h, jnp.arange(config.num_layers))
-        return finish(h), write_decode_burst(cache, k_all, v_all, inc)
+        (h, aux), (k_all, v_all) = jax.lax.scan(
+            body, (h, mlp_aux), jnp.arange(config.num_layers))
+        return finish(h), write_decode_burst(cache, k_all, v_all, inc), aux
 
     def body(carry, layer):
-        h, pk, pv, sk, sv = carry
+        h, pk, pv, sk, sv, aux = carry
+        fn, aux_after = _mlp_carrying(mlp_fn, aux)
         lp = _layer_view(params["layers"], layer)
         q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh, rules)
         step_cache = cache._replace(k=pk, v=pv, k_scale=sk, v_scale=sv)
@@ -1203,13 +1249,26 @@ def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                                pages=pages, interpret=interpret,
                                k_scale=step_cache.k_scale,
                                v_scale=step_cache.v_scale)
-        h = _post_attn(h, attn[:, None], lp, config, mesh, rules, mlp_fn)
+        h = _post_attn(h, attn[:, None], lp, config, mesh, rules, fn)
         return (h, step_cache.k, step_cache.v, step_cache.k_scale,
-                step_cache.v_scale), None
+                step_cache.v_scale, aux_after()), None
 
-    (h, new_k, new_v, new_sk, new_sv), _ = jax.lax.scan(
-        body, (h, cache.k, cache.v, cache.k_scale, cache.v_scale),
+    (h, new_k, new_v, new_sk, new_sv, aux), _ = jax.lax.scan(
+        body, (h, cache.k, cache.v, cache.k_scale, cache.v_scale, mlp_aux),
         jnp.arange(config.num_layers))
     return finish(h), cache._replace(k=new_k, v=new_v, k_scale=new_sk,
                                      v_scale=new_sv,
-                                     lengths=cache.lengths + inc)
+                                     lengths=cache.lengths + inc), aux
+
+
+def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
+                      cache, mesh: Optional[Mesh] = None,
+                      rules: LogicalRules = DEFAULT_RULES,
+                      active: Optional[jax.Array] = None,
+                      *, pages: int, mlp_fn=None):
+    """:func:`decode_step_paged_aux` with nothing to accumulate: (logits
+    [B,1,vocab], cache with lengths advanced where active).
+    ``mlp_fn(x, lp, mesh, rules)`` or the dense default."""
+    return decode_step_paged_aux(params, config, tokens, cache,
+                                 _mlp_without_aux(mlp_fn, config), (), mesh,
+                                 rules, active, pages=pages)[:2]

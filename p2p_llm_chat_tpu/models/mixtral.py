@@ -202,7 +202,8 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
             rules: LogicalRules = DEFAULT_RULES,
             capacity: Optional[int] = None,
             w_gu: Optional[jax.Array] = None,
-            renormalize: bool = True) -> jax.Array:
+            renormalize: bool = True,
+            live: Optional[jax.Array] = None) -> jax.Array:
     """Sparse-MoE SwiGLU via scatter/gather dispatch into capacity buckets.
 
     x: [B,S,H]; router: [H,NE]; w_gate/w_up: [NE,H,F]; w_down: [NE,F,H].
@@ -221,9 +222,17 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     ``renormalize`` (static; ``ModelConfig.moe_renormalize``): divide the
     kept weights by their sum (Mixtral). False keeps the softmax's own
     weights (OLMoE, ``norm_topk_prob: false``).
+
+    ``live`` ([B] bool, None = every row): a decode step's ``active``
+    mask. A row that is not live takes no slot and its output is 0, so a
+    bucket is empty exactly when no live row chose its expert, and the
+    expert-stripe kernels, handed each bucket's count, leave an empty
+    expert's weights unread (ops/quant_mm.py). A live row's output does
+    not depend on the mask: with an exact bucket it only moves to
+    another slot of the same matmul.
     """
     return _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok,
-                    mesh, rules, capacity, w_gu, renormalize)[0]
+                    mesh, rules, capacity, w_gu, renormalize, live)[0]
 
 
 def moe_mlp_counted(x: jax.Array, router: jax.Array, w_gate: jax.Array,
@@ -240,18 +249,19 @@ def moe_mlp_counted(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     those of them that found their bucket full. Padding positions still
     take slots in (token, slot) order; what they displace is counted,
     what they lose is not."""
-    out, full = _moe_mlp(x, router, w_gate, w_up, w_down,
-                         num_experts_per_tok, mesh, rules, capacity, w_gu,
-                         renormalize)
+    out, full, _ = _moe_mlp(x, router, w_gate, w_up, w_down,
+                            num_experts_per_tok, mesh, rules, capacity, w_gu,
+                            renormalize, None)
     real = jnp.repeat(valid.reshape(-1), num_experts_per_tok)      # [T*k]
     stats = jnp.stack([jnp.sum(real), jnp.sum(real & full)])
     return out, stats.astype(jnp.int32)
 
 
 def _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok, mesh,
-             rules, capacity, w_gu, renormalize) -> tuple:
+             rules, capacity, w_gu, renormalize, live) -> tuple:
     """(out [B,S,H], full [T*k] bool: the t-major (token, selection)
-    pairs whose bucket had no slot left)."""
+    pairs whose bucket had no slot left, count [NE] int32: the filled
+    slots of each expert's bucket)."""
     B, S, H = x.shape
     NE = router.shape[-1]
     k = num_experts_per_tok
@@ -271,33 +281,41 @@ def _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok, mesh,
     # the selection one-hot over the t-major flattened [T*k] selections.
     sel = jax.nn.one_hot(top_i, NE, dtype=jnp.int32)               # [T,k,NE]
     flat = sel.reshape(T * k, NE)
+    if live is not None:
+        # A parked row selects nothing: it stands in no expert's queue.
+        takes = jnp.repeat(jnp.broadcast_to(live[:, None], (B, S)).reshape(T),
+                           k)                                      # [T*k]
+        flat = flat * takes[:, None].astype(flat.dtype)
     pos = jnp.cumsum(flat, axis=0) - flat
     slot = jnp.sum(flat * pos, axis=-1)                            # [T*k]
     expert = top_i.reshape(T * k)
-    # Overflow (slot >= C) is aimed one past the buckets; scatter drops it
-    # and the fill-gather below returns 0 for it.
-    idx = jnp.where(slot < C, expert * C + slot, NE * C)           # [T*k]
+    count = jnp.minimum(jnp.sum(flat, axis=0), C)                  # [NE]
+    # Overflow (slot >= C) and a parked row's selections are aimed one
+    # past the buckets; scatter drops them and the fill-gather below
+    # returns 0 for them.
+    placed = slot < C if live is None else (slot < C) & takes
+    idx = jnp.where(placed, expert * C + slot, NE * C)             # [T*k]
 
     x_rep = jnp.repeat(xt, k, axis=0)                              # [T*k,H]
     xin = jnp.zeros((NE * C, H), xt.dtype).at[idx].set(x_rep, mode="drop")
     xin = constrain(xin.reshape(NE, C, H), mesh,
                     ("experts", None, "act_embed"), rules)
     if w_gu is not None:
-        gu = q_einsum("ech,ehf->ecf", xin, w_gu)                   # [NE,C,2F]
+        gu = q_einsum("ech,ehf->ecf", xin, w_gu, count)            # [NE,C,2F]
         F = gu.shape[-1] // 2
         g = jax.nn.silu(gu[..., :F])
         u = gu[..., F:]
     else:
-        g = jax.nn.silu(q_einsum("ech,ehf->ecf", xin, w_gate))
-        u = q_einsum("ech,ehf->ecf", xin, w_up)
-    y = q_einsum("ecf,efh->ech", g * u, w_down)                    # [NE,C,H]
+        g = jax.nn.silu(q_einsum("ech,ehf->ecf", xin, w_gate, count))
+        u = q_einsum("ech,ehf->ecf", xin, w_up, count)
+    y = q_einsum("ecf,efh->ech", g * u, w_down, count)             # [NE,C,H]
     y = constrain(y, mesh, ("experts", None, "act_embed"), rules)
 
     gathered = jnp.take(y.reshape(NE * C, H), idx, axis=0,
                         mode="fill", fill_value=0)                 # [T*k,H]
     out = jnp.sum(gathered.reshape(T, k, H).astype(jnp.float32)
                   * top_w[..., None], axis=1)
-    return out.astype(x.dtype).reshape(B, S, H), slot >= C
+    return out.astype(x.dtype).reshape(B, S, H), slot >= C, count
 
 
 # -- forward ------------------------------------------------------------------
@@ -335,6 +353,20 @@ def _mlp_fn_counted(config: ModelConfig, capacity: Optional[int],
             capacity, w_gu=lp.get("wgu_e"),
             renormalize=config.moe_renormalize)
         return out, stats + more
+    return fn
+
+
+def _mlp_fn_touched(config: ModelConfig, live: Optional[jax.Array]):
+    """A decode step's expert MLP (exact bucket, ``live`` rows) in the
+    aux form: the running count of experts touched goes in and comes out
+    beside the output."""
+    def fn(x, lp, mesh, rules, stats):
+        out, _, count = _moe_mlp(
+            x, lp["router"], lp.get("w_gate"), lp.get("w_up"), lp["w_down"],
+            config.num_experts_per_tok, mesh, rules, None, lp.get("wgu_e"),
+            config.moe_renormalize, live)
+        more = jnp.stack([jnp.sum(count > 0), count.shape[0]])
+        return out, stats + more.astype(jnp.int32)
     return fn
 
 
@@ -455,21 +487,72 @@ def prefill_chunk_counted(params: dict, config: ModelConfig,
         last_idx=last_idx)
 
 
+def no_touched() -> jax.Array:
+    """A decode dispatch's expert count at its start: int32 [2] = (the
+    experts some live row reached, the experts there were), summed over
+    the layers and, in a fused program, over the steps."""
+    return jnp.zeros((2,), jnp.int32)
+
+
+def decode_step_touched(params: dict, config: ModelConfig,
+                        tokens: jax.Array, cache: KVCache,
+                        mesh: Optional[Mesh] = None,
+                        rules: LogicalRules = DEFAULT_RULES,
+                        touched: Optional[jax.Array] = None,
+                        active: Optional[jax.Array] = None,
+                        kv_window: Optional[int] = None
+                        ) -> tuple[jax.Array, KVCache, jax.Array]:
+    """Same contract as llama.decode_step, including the parked-row
+    (active=False) overwrite-before-trust invariant. Decode's token count
+    T = B is small, so the MoE bucket is always exact (capacity=None), and
+    a parked row stays out of it (:func:`moe_mlp`'s ``live``): its MLP
+    output is 0 where it used to be garbage, and nobody reads either.
+    Third, ``touched`` (None = :func:`no_touched`) plus this step's
+    experts reached and experts there were, over the layers: the
+    scheduler's decode programs run these ``_touched`` forms."""
+    positions = cache.lengths[:, None]
+    window = kv_window if kv_window is not None else cache.k.shape[2]
+    mask = length_mask(window, cache.lengths + 1)
+    logits, cache, touched = llama.forward_aux(
+        params, config, tokens, positions, cache, mask,
+        _mlp_fn_touched(config, active),
+        no_touched() if touched is None else touched, mesh, rules,
+        kv_window=kv_window)
+    inc = jnp.ones_like(cache.lengths) if active is None else active.astype(jnp.int32)
+    return logits, cache._replace(lengths=cache.lengths + inc), touched
+
+
 def decode_step(params: dict, config: ModelConfig, tokens: jax.Array,
                 cache: KVCache, mesh: Optional[Mesh] = None,
                 rules: LogicalRules = DEFAULT_RULES,
                 active: Optional[jax.Array] = None,
                 kv_window: Optional[int] = None) -> tuple[jax.Array, KVCache]:
-    """Same contract as llama.decode_step, including the parked-row
-    (active=False) overwrite-before-trust invariant. Decode's token count
-    T = B is small, so the MoE bucket is always exact (capacity=None)."""
-    positions = cache.lengths[:, None]
-    window = kv_window if kv_window is not None else cache.k.shape[2]
-    mask = length_mask(window, cache.lengths + 1)
-    logits, cache = forward(params, config, tokens, positions, cache, mask,
-                            mesh, rules, kv_window=kv_window, capacity=None)
-    inc = jnp.ones_like(cache.lengths) if active is None else active.astype(jnp.int32)
-    return logits, cache._replace(lengths=cache.lengths + inc)
+    """:func:`decode_step_touched` without the count."""
+    return decode_step_touched(params, config, tokens, cache, mesh, rules,
+                               None, active, kv_window)[:2]
+
+
+def decode_fused_touched(params: dict, config: ModelConfig,
+                         tokens: jax.Array, cache,
+                         mesh: Optional[Mesh] = None,
+                         rules: LogicalRules = DEFAULT_RULES,
+                         active: Optional[jax.Array] = None, *,
+                         num_steps: int, sample_fn, sample_state, stop_ids,
+                         kv_window: Optional[int] = None,
+                         pages: Optional[int] = None):
+    """llama.decode_fused over the MoE step functions (same contract:
+    K steps, one dispatch, in-scan EOS parking, bit-identical to K
+    sequential plain ticks; each step is handed the rows still live at
+    it, so a row that parks mid-scan leaves the buckets there), and
+    last the dispatch's expert count (:func:`no_touched`)."""
+    step_fn = (decode_step_touched if pages is None
+               else decode_step_paged_touched)
+    return llama.decode_fused_aux(params, config, tokens, cache, step_fn,
+                                  no_touched(), mesh, rules, active,
+                                  num_steps=num_steps, sample_fn=sample_fn,
+                                  sample_state=sample_state,
+                                  stop_ids=stop_ids, kv_window=kv_window,
+                                  pages=pages)
 
 
 def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -479,16 +562,12 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
                  num_steps: int, sample_fn, sample_state, stop_ids,
                  kv_window: Optional[int] = None,
                  pages: Optional[int] = None):
-    """llama.decode_fused over the MoE step functions (same contract:
-    K steps, one dispatch, in-scan EOS parking, bit-identical to K
-    sequential plain ticks)."""
-    step_fn = decode_step if pages is None else decode_step_paged
-    return llama.decode_fused(params, config, tokens, cache, mesh, rules,
-                              active, num_steps=num_steps,
-                              sample_fn=sample_fn,
-                              sample_state=sample_state, stop_ids=stop_ids,
-                              kv_window=kv_window, pages=pages,
-                              step_fn=step_fn)
+    """:func:`decode_fused_touched` without the count."""
+    return decode_fused_touched(params, config, tokens, cache, mesh, rules,
+                                active, num_steps=num_steps,
+                                sample_fn=sample_fn,
+                                sample_state=sample_state, stop_ids=stop_ids,
+                                kv_window=kv_window, pages=pages)[:-1]
 
 
 def verify_step(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -519,20 +598,35 @@ def verify_tree(params: dict, config: ModelConfig, tokens: jax.Array,
                              mlp_fn=_mlp_fn(config, None))
 
 
+def decode_step_paged_touched(params: dict, config: ModelConfig,
+                              tokens: jax.Array, cache,
+                              mesh: Optional[Mesh] = None,
+                              rules: LogicalRules = DEFAULT_RULES,
+                              touched: Optional[jax.Array] = None,
+                              active: Optional[jax.Array] = None,
+                              *, pages: int):
+    """llama.decode_step_paged with the MoE MLP (same contract; decode's
+    token count is tiny, so the expert bucket stays exact, and a parked
+    row stays out of it as in :func:`decode_step_touched`, whose third
+    result this returns too). Attention
+    impl selection — including the round-8 multi-chunk flash-append
+    default at W >= 2048 on TPU — rides along unchanged: the dispatch
+    lives in ops/paged_attention.paged_attention_append, below the
+    mlp_fn seam, so MoE long-window decode takes the same kernel."""
+    return llama.decode_step_paged_aux(
+        params, config, tokens, cache, _mlp_fn_touched(config, active),
+        no_touched() if touched is None else touched, mesh, rules, active,
+        pages=pages)
+
+
 def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                       cache, mesh: Optional[Mesh] = None,
                       rules: LogicalRules = DEFAULT_RULES,
                       active: Optional[jax.Array] = None,
                       *, pages: int):
-    """llama.decode_step_paged with the MoE MLP (same contract; decode's
-    token count is tiny, so the expert bucket stays exact). Attention
-    impl selection — including the round-8 multi-chunk flash-append
-    default at W >= 2048 on TPU — rides along unchanged: the dispatch
-    lives in ops/paged_attention.paged_attention_append, below the
-    mlp_fn seam, so MoE long-window decode takes the same kernel."""
-    return llama.decode_step_paged(params, config, tokens, cache, mesh,
-                                   rules, active, pages=pages,
-                                   mlp_fn=_mlp_fn(config, None))
+    """:func:`decode_step_paged_touched` without the count."""
+    return decode_step_paged_touched(params, config, tokens, cache, mesh,
+                                     rules, None, active, pages=pages)[:2]
 
 
 def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,

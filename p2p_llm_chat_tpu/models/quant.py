@@ -323,7 +323,7 @@ def mm(x: jax.Array, w) -> jax.Array:
 _EXPERT_MM_SPECS = frozenset({"ech,ehf->ecf", "ecf,efh->ech"})
 
 
-def q_einsum(spec: str, x: jax.Array, w) -> jax.Array:
+def q_einsum(spec: str, x: jax.Array, w, count=None) -> jax.Array:
     """``einsum(spec, x, w)`` for plain or quantized ``w``. The spec's
     contraction over ``w`` must be its -2 axis (the quantize() axis) and
     the output must end with ``w``'s out axis — true for every expert
@@ -335,7 +335,12 @@ def q_einsum(spec: str, x: jax.Array, w) -> jax.Array:
     Pallas kernels (ops/quant_mm.quant_matmul_experts_stacked[4]) so the
     expert trunk streams quantized bytes from the scan-invariant pool —
     the eager fallback slices the layer out and recurses, which is
-    bit-identical to what _layer_view did before the kernels existed."""
+    bit-identical to what _layer_view did before the kernels existed.
+
+    ``count`` ([NE] int32, the filled slots of each expert's bucket
+    ``x[e]``; None = unknown): handed to the expert-stripe kernels, which
+    read no weights for an expert whose count is 0. Every XLA path
+    ignores it: an empty bucket's zeros give zeros there at full cost."""
     if isinstance(w, LayerSlice):
         inner, layer = w.w, w.layer
         if not isinstance(inner, (QTensor, QTensor4)):
@@ -349,14 +354,15 @@ def q_einsum(spec: str, x: jax.Array, w) -> jax.Array:
                                             quant_matmul_experts_stacked)
                 if pick_expert_bo(C, H, O, x.dtype.itemsize):
                     return quant_matmul_experts_stacked(x, inner.q, inner.s,
-                                                        layer)
+                                                        layer, count)
             else:
                 from ..ops.quant_mm import (pick_int4_bo,
                                             quant_matmul_experts_stacked4)
                 if pick_int4_bo(C, H, O, inner.s.shape[-2],
                                 x.dtype.itemsize):
                     return quant_matmul_experts_stacked4(x, inner.q,
-                                                         inner.s, layer)
+                                                         inner.s, layer,
+                                                         count)
         inner = type(inner)(
             q=jax.lax.dynamic_index_in_dim(inner.q, layer, 0, False),
             s=jax.lax.dynamic_index_in_dim(inner.s, layer, 0, False))

@@ -46,6 +46,9 @@ MoE expert pools [L, NE, H, O]: grid (NE, O/bo) per layer, each program
 DMAing one expert's whole-contraction stripe, so the top-k gathered
 expert matmuls of models/mixtral.moe_mlp ride the quantized stream
 instead of falling back to an XLA dequant of the full expert stack.
+They are handed each bucket's count of filled slots and read nothing for
+an empty one (:func:`_expert_weight_map`): decode at a part-full batch
+is bound by the experts' bytes, and most experts hold no live token then.
 """
 
 from __future__ import annotations
@@ -216,24 +219,47 @@ def _qmm4_kernel_1d_stacked(layer_ref, x_ref, q_ref, s_ref, o_ref):
     o_ref[...] = _qmm4_body(x_ref[...], q_ref[0], s_ref[0], o_ref.dtype)
 
 
-def _qmm_kernel_experts_stacked(layer_ref, x_ref, q_ref, s_ref, o_ref):
+def _touched_or_zero(route_ref, o_ref, compute) -> None:
+    """Run ``compute`` (which writes this program's output block) for an
+    expert whose bucket holds a token; an empty one's block is written
+    as zeros, which is what its all-zero bucket would have given, so
+    nothing uninitialised reaches a later fusion."""
+    touched = route_ref[pl.program_id(0)] > 0
+    pl.when(touched)(compute)
+
+    @pl.when(jnp.logical_not(touched))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _qmm_kernel_experts_stacked(layer_ref, route_ref, x_ref, q_ref, s_ref,
+                                o_ref):
     """w8a16 expert stripe: one program = one expert's whole-contraction
     [H, bo] tile from the [L, NE, H, O] pool at the scalar-prefetched
     layer — the batched-expert twin of _qmm_kernel_1d_stacked. The
     expert axis is the OUTER grid dim, so the per-expert x block
-    [C, H] is fetched once and the O/bo stripe walk streams under it."""
-    x = x_ref[0]                                   # [Cp, H] bf16
-    q = q_ref[0, 0].astype(x.dtype)                # [H, bo] int8 -> bf16
-    acc = jax.lax.dot(x, q, preferred_element_type=jnp.float32)
-    s = s_ref[0, 0, 0].astype(jnp.float32)         # [bo]
-    o_ref[0] = (acc * s[None, :]).astype(o_ref.dtype)
+    [C, H] is fetched once and the O/bo stripe walk streams under it.
+    ``route_ref`` (:func:`_expert_route`): an empty expert's programs
+    were handed no new stripe and compute nothing."""
+    def compute():
+        x = x_ref[0]                               # [Cp, H] bf16
+        q = q_ref[0, 0].astype(x.dtype)            # [H, bo] int8 -> bf16
+        acc = jax.lax.dot(x, q, preferred_element_type=jnp.float32)
+        s = s_ref[0, 0, 0].astype(jnp.float32)     # [bo]
+        o_ref[0] = (acc * s[None, :]).astype(o_ref.dtype)
+    _touched_or_zero(route_ref, o_ref, compute)
 
 
-def _qmm4_kernel_experts_stacked(layer_ref, x_ref, q_ref, s_ref, o_ref):
+def _qmm4_kernel_experts_stacked(layer_ref, route_ref, x_ref, q_ref, s_ref,
+                                 o_ref):
     """w4a16 expert stripe over the [L, NE, K/2, O] packed pool — the
     batched-expert twin of _qmm4_kernel_1d_stacked, sharing the
-    segment-walk body (and its odd-group support)."""
-    o_ref[0] = _qmm4_body(x_ref[0], q_ref[0, 0], s_ref[0, 0], o_ref.dtype)
+    segment-walk body (and its odd-group support) and the int8 twin's
+    skip of an empty expert."""
+    def compute():
+        o_ref[0] = _qmm4_body(x_ref[0], q_ref[0, 0], s_ref[0, 0],
+                              o_ref.dtype)
+    _touched_or_zero(route_ref, o_ref, compute)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -519,9 +545,41 @@ def quant_matmul_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
     return out[:rows] if pad else out
 
 
+def _expert_route(count: jax.Array | None, NE: int) -> jax.Array:
+    """The expert kernels' second scalar-prefetch operand, int32 [2*NE]:
+    ``count`` (filled slots of each expert's bucket; None = every expert
+    is touched) and behind it, for every expert, the expert whose weight
+    block its programs name: its own if it is touched, else the nearest
+    touched expert before it, else the first touched one (the last expert
+    when no bucket holds a token: one stripe is then fetched, unused)."""
+    if count is None:
+        count = jnp.ones((NE,), jnp.int32)
+    count = count.astype(jnp.int32)
+    ids = jnp.arange(NE, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(count > 0, ids, -1))
+    first = jnp.where(jnp.any(count > 0), jnp.argmax(count > 0),
+                      NE - 1).astype(jnp.int32)
+    return jnp.concatenate([count, jnp.where(before >= 0, before, first)])
+
+
+def _expert_weight_map(NE: int, stripes: int):
+    """Index map of an expert pool's weight (and scale) block on the grid
+    ``(NE, stripes)``. A touched expert walks its own stripes. An empty
+    one names the block the pipeline already holds, the last stripe of
+    the touched expert before it, or the first stripe of the first
+    touched expert when none precedes it (:func:`_expert_route`), so no
+    DMA is issued for its programs."""
+    def index(e, i, ly, route):
+        src = route[NE + e]
+        held = jnp.where(src < e, stripes - 1, 0)
+        return ly[0], src, 0, jnp.where(route[e] > 0, i, held)
+    return index
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
-                                 layer: jax.Array, *,
+                                 layer: jax.Array,
+                                 count: jax.Array | None = None, *,
                                  interpret: bool = False) -> jax.Array:
     """Batched per-expert ``x[e] @ dequant(q[layer, e], s[layer, e])``
     reading the 4-D expert pool directly — the MoE twin of
@@ -533,6 +591,13 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
     x: [NE, C, H] expert buckets; q: [L, NE, H, O] int8;
     s: [L, NE, 1, O] f32; layer: scalar int32. Returns [NE, C, O].
     Caller guarantees ``pick_expert_bo`` accepts the shape.
+
+    ``count`` ([NE] int32, None = all touched): the filled slots of each
+    bucket, which the dispatch knows on its way to them. An expert whose
+    count is 0 has an all-zero bucket by the caller's word: its weights
+    are not read (:func:`_expert_weight_map`) and its output is zeros.
+    Decode at a part-full batch is where that pays: the step's time is
+    the experts' bytes, and 2 live rows x top-2 reach at most 4 of 8.
     """
     NE, C, H = x.shape
     O = q.shape[-1]
@@ -546,15 +611,17 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     cp = C + pad
     ly = jnp.asarray(layer, jnp.int32).reshape(1)
+    weight_map = _expert_weight_map(NE, O // bo)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(NE, O // bo),
         in_specs=[
-            pl.BlockSpec((1, cp, H), lambda e, i, ly: (e, 0, 0)),
-            pl.BlockSpec((1, 1, H, bo), lambda e, i, ly: (ly[0], e, 0, i)),
-            pl.BlockSpec((1, 1, 1, bo), lambda e, i, ly: (ly[0], e, 0, i)),
+            pl.BlockSpec((1, cp, H), lambda e, i, ly, route: (e, 0, 0)),
+            pl.BlockSpec((1, 1, H, bo), weight_map),
+            pl.BlockSpec((1, 1, 1, bo), weight_map),
         ],
-        out_specs=pl.BlockSpec((1, cp, bo), lambda e, i, ly: (e, 0, i)),
+        out_specs=pl.BlockSpec((1, cp, bo),
+                               lambda e, i, ly, route: (e, 0, i)),
     )
     out = pl.pallas_call(
         _qmm_kernel_experts_stacked,
@@ -562,13 +629,14 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NE, cp, O), x.dtype),
         interpret=interpret,
-    )(ly, x, q, s)
+    )(ly, _expert_route(count, NE), x, q, s)
     return out[:, :C] if pad else out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quant_matmul_experts_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
-                                  layer: jax.Array, *,
+                                  layer: jax.Array,
+                                  count: jax.Array | None = None, *,
                                   interpret: bool = False) -> jax.Array:
     """int4 twin of :func:`quant_matmul_experts_stacked`: the packed
     [L, NE, H/2, O] expert pool streams at int4-packed bytes, unpacked
@@ -576,9 +644,9 @@ def quant_matmul_experts_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
     included — mixtral-large's w_down groups at 256 into ng=45).
 
     x: [NE, C, H]; q: [L, NE, H/2, O] int8 packed nibbles;
-    s: [L, NE, ng, O] f32 group scales; layer: scalar int32. Returns
-    [NE, C, O]. Caller guarantees :func:`pick_int4_bo` accepts the
-    per-expert shape.
+    s: [L, NE, ng, O] f32 group scales; layer: scalar int32; ``count``
+    as the int8 twin's (an empty expert is skipped). Returns [NE, C, O].
+    Caller guarantees :func:`pick_int4_bo` accepts the per-expert shape.
     """
     NE, C, H = x.shape
     O = q.shape[-1]
@@ -594,16 +662,17 @@ def quant_matmul_experts_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     cp = C + pad
     ly = jnp.asarray(layer, jnp.int32).reshape(1)
+    weight_map = _expert_weight_map(NE, O // bo)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(NE, O // bo),
         in_specs=[
-            pl.BlockSpec((1, cp, H), lambda e, i, ly: (e, 0, 0)),
-            pl.BlockSpec((1, 1, H // 2, bo),
-                         lambda e, i, ly: (ly[0], e, 0, i)),
-            pl.BlockSpec((1, 1, ng, bo), lambda e, i, ly: (ly[0], e, 0, i)),
+            pl.BlockSpec((1, cp, H), lambda e, i, ly, route: (e, 0, 0)),
+            pl.BlockSpec((1, 1, H // 2, bo), weight_map),
+            pl.BlockSpec((1, 1, ng, bo), weight_map),
         ],
-        out_specs=pl.BlockSpec((1, cp, bo), lambda e, i, ly: (e, 0, i)),
+        out_specs=pl.BlockSpec((1, cp, bo),
+                               lambda e, i, ly, route: (e, 0, i)),
     )
     out = pl.pallas_call(
         _qmm4_kernel_experts_stacked,
@@ -611,5 +680,5 @@ def quant_matmul_experts_stacked4(x: jax.Array, q: jax.Array, s: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NE, cp, O), x.dtype),
         interpret=interpret,
-    )(ly, x, q, s)
+    )(ly, _expert_route(count, NE), x, q, s)
     return out[:, :C] if pad else out

@@ -637,6 +637,11 @@ class BatchScheduler:
         # bucket full. Decode and wake buckets are exact and add nothing.
         self._n_moe_assigned = 0
         self._n_moe_dropped = 0
+        # Of the experts a decode step could have streamed (layers x
+        # experts, each step of each dispatch), those a live row reached:
+        # the others' weights were not read (ops/quant_mm.py).
+        self._n_moe_decode_touched = 0   # owned-by: _loop
+        self._n_moe_decode_slots = 0     # owned-by: _loop
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -849,6 +854,19 @@ class BatchScheduler:
         # kv_), which is what a reader of a device trace sums by
         # (`XLA Modules` events read jit_prefill_..., jit_decode_...;
         # tests/test_loop_phases.py holds every jit site to it).
+        routed = config.is_moe
+
+        def _with_moe(toks, moe):
+            """A dispatch's tokens with a routed model's count behind
+            them (``moe`` int32 [2], None for a dense model), so that
+            the one array the host already reads carries both: the
+            prefill's drop count behind an admission's first tokens
+            ([R] -> [R + 2]), the experts a decode dispatch touched
+            behind its tokens ([B] or [K, B] -> [K * B + 2])."""
+            if moe is None:
+                return toks
+            return jnp.concatenate([toks.reshape(-1), moe])
+
         def _make_decode(kv_window: int):
             def decode_step(params, tokens, cache, active, temps, top_ks,
                             top_ps, keys, ring, rps):
@@ -856,15 +874,22 @@ class BatchScheduler:
                 # INPUT token occupies lengths) — writing at lengths would
                 # clobber the previous tick's emission in the ring.
                 emit_pos = cache.lengths + 1
-                if self.kv_mode == "paged":
-                    pages = -(-kv_window // self.page_size)
-                    logits, cache = model.decode_step_paged(
-                        params, config, tokens, cache, mesh, active=active,
-                        pages=pages)
+                paged = self.kv_mode == "paged"
+                window = ({"pages": -(-kv_window // self.page_size)} if paged
+                          else {"kv_window": kv_window})
+                moe = None
+                if routed:
+                    # A routed model's step also counts the experts its
+                    # live rows reached (models/mixtral.py).
+                    step = (model.decode_step_paged_touched if paged
+                            else model.decode_step_touched)
+                    logits, cache, moe = step(params, config, tokens, cache,
+                                              mesh, active=active, **window)
                 else:
-                    logits, cache = model.decode_step(
-                        params, config, tokens, cache, mesh, active=active,
-                        kv_window=kv_window)
+                    step = (model.decode_step_paged if paged
+                            else model.decode_step)
+                    logits, cache = step(params, config, tokens, cache, mesh,
+                                         active=active, **window)
                 # Shared sample + penalty-ring step (parked rows' ring
                 # writes drop) — the ONE implementation the fused path's
                 # scan body also runs, so fused-K output stays
@@ -876,7 +901,7 @@ class BatchScheduler:
                 # (ignored) next step stays stable regardless of their
                 # garbage sample.
                 next_tokens = jnp.where(active[:, None], toks[:, None], tokens)
-                return toks, next_tokens, cache, keys, ring
+                return _with_moe(toks, moe), next_tokens, cache, keys, ring
             return jax.jit(decode_step, donate_argnums=(1, 2, 7, 8))
 
         self._make_decode = _make_decode
@@ -909,10 +934,17 @@ class BatchScheduler:
                     kwargs["pages"] = -(-kv_window // self.page_size)
                 else:
                     kwargs["kv_window"] = kv_window
-                (toks_all, _, next_tokens, cache, _,
-                 (keys, ring)) = model.decode_fused(params, config, tokens,
-                                                    cache, mesh, **kwargs)
-                return toks_all, next_tokens, cache, keys, ring
+                moe = None
+                if routed:
+                    (toks_all, _, next_tokens, cache, _, (keys, ring),
+                     moe) = model.decode_fused_touched(
+                        params, config, tokens, cache, mesh, **kwargs)
+                else:
+                    (toks_all, _, next_tokens, cache, _,
+                     (keys, ring)) = model.decode_fused(
+                        params, config, tokens, cache, mesh, **kwargs)
+                return (_with_moe(toks_all, moe), next_tokens, cache, keys,
+                        ring)
             return jax.jit(decode_fused_steps, donate_argnums=(1, 2, 7, 8))
 
         self._make_decode_fused = _make_decode_fused
@@ -1142,8 +1174,6 @@ class BatchScheduler:
         # discipline).
         self._wake_shapes_run: set[tuple] = set()  # owned-by: _loop
 
-        routed = config.is_moe
-
         def _moe_valid(ints, off: int, width: int):
             """What a routed model's ``_counted`` prefill counts over
             (models/mixtral.py ``valid``): the real prompt positions of
@@ -1153,12 +1183,6 @@ class BatchScheduler:
             pos = off + jnp.arange(width)[None, :]
             real = (ints[1] < self.num_slots)[:, None]
             return (pos < ints[0][:, None]) & real
-
-        def _with_moe(toks, moe):
-            """The first tokens with a routed model's drop count behind
-            them (``moe`` is None for a dense one), so that the one
-            array the host already reads carries both: [R], or [R + 2]."""
-            return toks if moe is None else jnp.concatenate([toks, moe])
 
         def _prefill_first_token(params, tokens, ints, floats, rings):
             """Shared admission prologue (dense and paged): batched prefill
@@ -3411,6 +3435,14 @@ class BatchScheduler:
             "serve_boot_compile_seconds": round(self._boot_compile_s, 3),
             "serve_boot_programs_total": self._n_warmup_jobs,
         }
+        if self.config.is_moe:
+            # Routed models only: of the layers x experts each decode
+            # step could have streamed (slots), those a live row reached
+            # (touched); the others' weights were not read.
+            out["serve_moe_decode_experts_touched_total"] = \
+                self._n_moe_decode_touched
+            out["serve_moe_decode_expert_slots_total"] = \
+                self._n_moe_decode_slots
         if self.spec_k:
             out["serve_spec_accepted_total"] = self._n_spec_accepted
             # Back-compat aggregate: the most optimistic source (the
@@ -4230,8 +4262,9 @@ class BatchScheduler:
         where speculation could run — a fused tick would emit K tokens
         with no draft opportunity). ``inflight``: steps of the still-
         unprocessed pipelined tick, counted against every budget.
-        Returns (toks_dev [B] or [K,B], snapshot of the rows it decoded
-        for, K); _process_tick consumes it, one tick later under
+        Returns (toks_dev [B] or [K,B], flat with the expert count
+        behind it for a routed model (_with_moe), snapshot of the rows it
+        decoded for, K); _process_tick consumes it, one tick later under
         pipelining."""
         # Flight event BEFORE the failpoint/device dispatch: if this
         # very dispatch wedges (the armed-delay stall test), the ring's
@@ -4310,8 +4343,13 @@ class BatchScheduler:
             failpoint("serve.engine.readback")
             # graftcheck: sync-ok intentional: [B] or [K,B] int32, the tick's readback
             toks = np.asarray(toks_dev)
-        if toks.ndim == 1:
-            toks = toks[None]
+        if self.config.is_moe:
+            # The experts the dispatch touched ride behind its tokens
+            # (_with_moe).
+            self._n_moe_decode_touched += int(toks[-2])
+            self._n_moe_decode_slots += int(toks[-1])
+            toks = toks[:-2]
+        toks = toks.reshape(K, -1)
         with self._phase("stream"):
             for row, slot in enumerate(snapshot):
                 # Identity check, not just done/None: the row may have

@@ -190,9 +190,9 @@ def _spy(monkeypatch, name):
     the module on every call, so the monkeypatch is what they fetch)."""
     calls = []
 
-    def fake(x, q, s, layer, **kw):
+    def fake(x, q, s, layer, count=None, **kw):
         calls.append((x.shape, q.shape, int(layer) if np.ndim(layer) == 0
-                      else layer))
+                      else layer, count))
         return jnp.zeros(x.shape[:2] + (q.shape[-1],), x.dtype)
 
     monkeypatch.setattr(qmm, name, fake)
@@ -213,16 +213,21 @@ def test_expert_dispatch_int8_pool_hits_kernel(on_tpu, monkeypatch):
     x = jnp.ones((2, 8, 256), jnp.float32)
     y = q_einsum("ech,ehf->ecf", x, LayerSlice(w, 1))
     assert y.shape == (2, 8, 512)
-    assert len(calls) == 1 and calls[0][2] == 1
+    assert len(calls) == 1 and calls[0][2] == 1 and calls[0][3] is None
+    # The buckets' count of filled slots goes to the kernel as it came.
+    count = jnp.asarray([3, 0], jnp.int32)
+    q_einsum("ech,ehf->ecf", x, LayerSlice(w, 1), count)
+    assert calls[1][3] is count
 
 
 def test_expert_dispatch_int4_pool_hits_kernel(on_tpu, monkeypatch):
     calls = _spy(monkeypatch, "quant_matmul_experts_stacked4")
     w = _expert_pool_int4()              # H=512, ng=1 -> odd walk, seg 256
     x = jnp.ones((2, 8, 512), jnp.float32)
-    y = q_einsum("ech,ehf->ecf", x, LayerSlice(w, 0))
+    count = jnp.asarray([0, 8], jnp.int32)
+    y = q_einsum("ech,ehf->ecf", x, LayerSlice(w, 0), count)
     assert y.shape == (2, 8, 512)
-    assert len(calls) == 1 and calls[0][2] == 0
+    assert len(calls) == 1 and calls[0][2] == 0 and calls[0][3] is count
 
 
 @pytest.mark.parametrize("reason,spec,xshape", [

@@ -175,7 +175,7 @@ HBM_GBPS = 819.0   # TPU v5e
 
 
 def sweep_experts8(H: int, O: int, NE: int, rows: tuple,
-                   caps: tuple) -> None:
+                   caps: tuple, touched: tuple = ()) -> None:
     """The measurement behind a `_TILE_TABLE` entry for an expert
     stripe: the kernel's time at every bucket size ``rows`` under every
     output-tile cap ``caps`` (None = the budget's own choice), beside
@@ -184,7 +184,13 @@ def sweep_experts8(H: int, O: int, NE: int, rows: tuple,
     alternating layers, as a model's layer scan does: a lone call of
     half a millisecond measures the host's dispatch, not the kernel.
     The cap is set for the duration of one timing and the table is left
-    as it was."""
+    as it was.
+
+    ``touched``: for each ``t`` of it, the kernel again with only ``t``
+    of the ``NE`` buckets holding rows (spread evenly over the experts,
+    the rest zero, the count handed to the kernel as the dispatch hands
+    it): ms, and the GB/s of the bytes that then had to be read, the
+    ``t`` experts' weights and every bucket's rows in and out."""
     from p2p_llm_chat_tpu.ops import quant_mm as qmm
     L, REPEAT = 2, 16
     qt = _quantized(H + O + 5, (L, NE, H, O), quantize)
@@ -227,6 +233,21 @@ def sweep_experts8(H: int, O: int, NE: int, rows: tuple,
                 print(f"  cap={cap} bo={bo} grid={NE}x{O // bo}: "
                       f"{k_ms:.4f} ms = {100 * floor_ms / k_ms:.1f}% of "
                       f"roofline, {x_ms / k_ms:.2f}x XLA", flush=True)
+                for t in touched:
+                    held = np.zeros((NE,), np.int32)
+                    held[[j * NE // t for j in range(t)]] = C
+                    count = jnp.asarray(held)
+                    xt = x * (count > 0)[:, None, None].astype(x.dtype)
+                    part = repeated(
+                        lambda x, q, s, layer: quant_matmul_experts_stacked(
+                            x, q, s, layer, count))
+                    t_ms = _time_ms(lambda: part(xt, qt.q, qt.s)) / REPEAT
+                    read = t * (H * O + 4 * O) + NE * 2 * C * (H + O)
+                    print(f"    touched {t}/{NE}: {t_ms:.4f} ms = "
+                          f"{t_ms / k_ms:.3f} of all touched without a "
+                          f"count ({t / NE:.3f} of the experts), "
+                          f"{read / t_ms / 1e6:.1f} GB/s of {read} bytes",
+                          flush=True)
     finally:
         qmm._TILE_TABLE.clear()
         qmm._TILE_TABLE.update(saved)
@@ -308,6 +329,17 @@ def main() -> int:
         rows = (8, 32, 64, 128, 256, 512)
         sweep_experts8(2048, 2048, 64, rows, (None, 512, 256))
         sweep_experts8(1024, 2048, 64, rows, (None, 512, 256, 128))
+        return 0
+    # ``sweep-touched``: the decode bucket (C = 32) with part of the
+    # experts empty, at Mixtral-8x7B's two expert shapes and OLMoE's,
+    # under the tiles the program picks (the table as it stands).
+    if len(sys.argv) > 1 and sys.argv[1] == "sweep-touched":
+        from p2p_llm_chat_tpu.ops.quant_mm import _TILE_TABLE
+        for H, O, NE, touched in ((4096, 28672, 8, (2, 4, 8)),
+                                  (14336, 4096, 8, (2, 4, 8)),
+                                  (2048, 2048, 64, (16, 48, 64)),
+                                  (1024, 2048, 64, (16, 48, 64))):
+            sweep_experts8(H, O, NE, (32,), (_TILE_TABLE.get(H),), touched)
         return 0
     if len(sys.argv) > 1:
         cases = [c for c in cases if sys.argv[1] in c[0]]
