@@ -19,7 +19,7 @@ rc=0
 # compile. Any new finding fails the gate — suppress only with a
 # reasoned annotation (docs/static-analysis.md).
 echo "== graftcheck static analysis (all analyzers)"
-python -m tools.graftcheck p2p_llm_chat_tpu bench.py start_all.py tests \
+python -m tools.graftcheck p2p_llm_chat_tpu start_all.py tests \
   || exit 1
 
 echo "== native sanitizer build (ASan + UBSan)"
@@ -87,7 +87,7 @@ if [ "${1:-}" = "full" ]; then
   JAX_PLATFORMS=cpu python -m pytest tests/test_router.py -q || rc=1
 
   # Multi-tier KV: the WHOLE park/wake file including the slow-marked
-  # matrix (dense x bf16-pool x prefix composition, eviction under a
+  # matrix (bf16-pool x prefix composition, eviction under a
   # sub-session host budget, pool-pressure parking). Excluded from the
   # sweep below so each case executes exactly once.
   echo "== multi-tier KV: park/wake matrix (CPU)"
@@ -238,7 +238,7 @@ else
 
   # Multi-tier KV (tier-1 legs): park/wake policy units, the raw-bits
   # gather/scatter round-trip, and the paged-int8 resident-vs-parked
-  # byte-identity oracle. The dense / bf16 / prefix-composition /
+  # byte-identity oracle. The bf16 / prefix-composition /
   # eviction-pressure matrix is slow-marked into full mode. Excluded
   # from the sweep below so each case executes exactly once.
   echo "== multi-tier KV: park/wake bit-identity (CPU)"
@@ -294,10 +294,10 @@ else
 
   # Tree speculation (round 17, tier-1 legs): tree-mask ancestry units,
   # the single-tree verify-vs-sequential-replay logits + rejected-
-  # branch KV-containment oracle, dense greedy bit-identity tree-on vs
+  # branch KV-containment oracle, greedy bit-identity tree-on vs
   # off, the NGram linear-degrade contract, one-drafter-dispatch-per-
   # tick pin, and the equal-budget accepted-per-dispatch A/B. The
-  # paged / paged+int8 legs are slow-marked into full mode. Excluded
+  # int8-pool leg is slow-marked into full mode. Excluded
   # from the sweep below so each case executes exactly once.
   echo "== tree speculation: bit-identity + dispatch-budget pins (CPU)"
   JAX_PLATFORMS=cpu python -m pytest tests/test_spec_tree.py -q -x \

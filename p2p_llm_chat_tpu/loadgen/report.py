@@ -1,14 +1,12 @@
-"""The SLO ledger: trace records in, one BENCH-style JSON row out.
+"""The SLO ledger: trace records in, one JSON row out.
 
 Per scenario and in aggregate: TTFT p50/p95 (queue lag included — the
 open-loop driver's stall signal), inter-token p95, the shed/error
-taxonomy, goodput (completions *meeting their SLO* per second — the
-serving-evaluation convention bench.py's mixed phase follows), and a
+taxonomy, goodput (completions *meeting their SLO* per second), and a
 pass/fail verdict against the scenario targets from scenarios.py.
 
-Rows are durable by the same convention as the bench: the first free
-``E2E_r0N.json`` slot in the repo root (beside the driver's bench rows),
-and a failed run writes an *error row* rather than nothing — a crashed
+Rows are durable: the first free ``E2E_r0N.json`` slot in the repo
+root, and a failed run writes an *error row* rather than nothing — a crashed
 64-peer run that silently prints to a lost stdout is an hour of chip
 time unrecorded.
 """
@@ -40,7 +38,7 @@ MIN_FRACTION_N = 8
 
 
 def percentile(xs: list, p: float) -> Optional[float]:
-    """Nearest-rank on the sorted sample (bench.py's _pct convention)."""
+    """Nearest-rank on the sorted sample."""
     if not xs:
         return None
     xs = sorted(xs)

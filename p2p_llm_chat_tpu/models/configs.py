@@ -208,13 +208,12 @@ _register(ModelConfig(
     rope_theta=10000.0, bos_token_id=1, eos_token_ids=(2,),
 ))
 
-# ~1B-class dense config used by bench.py on a single v5e chip (fits HBM in
-# bf16 with room for KV cache; same architecture family as the 8B).
-# max_seq_len 16384: the long-context bench rows (BENCH_CTX 4k-12k,
-# round-5) need headroom past the old 2048 cap; rope_theta 500000 (the
-# llama3 base) is stable at these lengths, and actual KV allocation is
-# sized per run (BENCH_MAX_SEQ / the scheduler's right-sized pool), so
-# the cap costs nothing when unused.
+# ~1B-class dense config for a single v5e chip (fits HBM in bf16 with
+# room for KV cache; same architecture family as the 8B).
+# max_seq_len 16384: long contexts (4k-12k) need headroom past 2048;
+# rope_theta 500000 (the llama3 base) is stable at these lengths, and
+# actual KV allocation is sized per run (SERVE_MAX_SEQ / the scheduler's
+# right-sized pool), so the cap costs nothing when unused.
 _register(ModelConfig(
     name="bench-1b", vocab_size=32768, hidden_size=2048,
     intermediate_size=5632, num_layers=22, num_heads=16, num_kv_heads=8,
@@ -230,10 +229,10 @@ _register(ModelConfig(
 # MUST share its target's vocabulary (draft ids feed the target's verify
 # forward directly); pair it with a different-vocab target by cloning
 # the config at the target's vocab (`get_config("draft-400m").with_(
-# vocab_size=target.vocab_size)` — bench.py's freeform spec phase does
-# this for bench-1b). Embeddings are untied so the synthetic quote/
+# vocab_size=target.vocab_size)` — serve/engine.py's SERVE_DRAFT path
+# does this). Embeddings are untied so the synthetic quote/
 # freeform workloads (models/synth.py) can install their successor-map
-# lm_head for CPU tests and benches without real checkpoints.
+# lm_head for CPU tests without real checkpoints.
 _register(ModelConfig(
     name="draft-400m", vocab_size=128256, hidden_size=1024,
     intermediate_size=4096, num_layers=16, num_heads=8, num_kv_heads=4,

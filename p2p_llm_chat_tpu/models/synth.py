@@ -19,8 +19,7 @@ follows the cycle), so decode/prefill cost is identical to a real
 checkpoint of the same config — but greedy/sampled output settles into a
 repeating printable phrase: prompt-lookup drafts land (the speculation
 benchmark) and the detokenizer streams byte-per-token (the UI-boundary
-TTFT benchmark). bench.py (BENCH_WORKLOAD=quote) and tools/e2e_bench.py
-share this construction.
+TTFT benchmark). tools/e2e_bench.py uses this construction.
 """
 
 from __future__ import annotations

@@ -27,7 +27,7 @@ serving from wherever JAX fell back to.
 Env surface (reference-style env-first config, utils/env.py):
 ``SERVE_BACKEND=tpu``, ``CKPT_DIR``, ``MODEL_CONFIG``, ``SERVE_SLOTS``,
 ``SERVE_MAX_SEQ``, ``SERVE_TP``, ``LLM_MODEL`` (served model tag),
-``SERVE_KV`` (dense|paged), ``SERVE_PAGE_SIZE``, ``SERVE_PAGES``,
+``SERVE_PAGE_SIZE``, ``SERVE_PAGES``,
 ``SERVE_ADMIT_CHUNK``, ``SERVE_QUEUE_TIMEOUT`` (seconds, 0 disables),
 ``SERVE_QUEUE_MAX`` (admission-queue depth bound for overload shedding:
 unset = 8 x SERVE_SLOTS, 0 = unbounded; at the bound, submits fast-fail
@@ -92,8 +92,7 @@ class TPUEngine:
 
     def __init__(self, params: dict, config, tokenizer, *,
                  num_slots: int = 8, max_seq: int = 1024, mesh=None,
-                 name: Optional[str] = None, kv_mode: str = "dense",
-                 page_size: int = 64,
+                 name: Optional[str] = None, page_size: int = 64,
                  num_pages: Optional[int] = None,
                  admit_chunk: Optional[int] = None,
                  queue_timeout_s: Optional[float] = 60.0,
@@ -160,8 +159,7 @@ class TPUEngine:
                     drafter.max_seq, spec_k, config.name)
         self.scheduler = BatchScheduler(params, config, tokenizer,
                                         num_slots=num_slots, max_seq=max_seq,
-                                        mesh=mesh, kv_mode=kv_mode,
-                                        page_size=page_size,
+                                        mesh=mesh, page_size=page_size,
                                         num_pages=num_pages,
                                         admit_chunk=admit_chunk,
                                         queue_timeout_s=queue_timeout_s,
@@ -420,7 +418,14 @@ def build_engine_from_env() -> Backend:
     num_slots = env_int("SERVE_SLOTS", 8)
     max_seq = env_int("SERVE_MAX_SEQ", 1024)
     tp = env_int("SERVE_TP", 1)
-    kv_mode = env_or("SERVE_KV", "dense")
+    # Input validation only: the paged pool is the one KV layout, and
+    # deployments still export SERVE_KV=paged.
+    serve_kv = env_or("SERVE_KV", "paged")
+    if serve_kv != "paged":
+        raise SystemExit(
+            f"SERVE_KV={serve_kv!r}: dense serving was removed in PR 28; "
+            "the paged pool is the only KV layout (unset SERVE_KV, or set "
+            "it to paged)")
     page_size = env_int("SERVE_PAGE_SIZE", 64)
     num_pages = env_int("SERVE_PAGES", 0) or None
     admit_chunk = env_int("SERVE_ADMIT_CHUNK", 0) or None
@@ -436,7 +441,7 @@ def build_engine_from_env() -> Backend:
     queue_max = None if qm < 0 else qm
     spec_k = env_int("SERVE_SPEC", 0)
     # Draft-model speculative decoding (serve/draft_model.py): a config
-    # name (random-init / synthetic path — CPU tests, benches) or a
+    # name (random-init / synthetic path — CPU tests) or a
     # checkpoint dir (the production path: e.g. a llama3.2-1b instruct
     # checkpoint drafting for llama3.1-8b) of a SMALL model resident
     # alongside the target. Requires SERVE_SPEC > 0; drafts fill in
@@ -473,8 +478,7 @@ def build_engine_from_env() -> Backend:
         t for t in env_or("SERVE_PREFIX_TEXTS", "").split("||") if t)
     # SERVE_PROFILE_PORT=N starts jax.profiler's collection server:
     # attach TensorBoard/xprof to capture live device traces of the
-    # serving loop (SURVEY.md §5 tracing plan; BENCH_PROFILE covers the
-    # offline bench path).
+    # serving loop (SURVEY.md §5 tracing plan).
     prof_port = env_int("SERVE_PROFILE_PORT", 0)
     if prof_port:
         jax.profiler.start_server(prof_port)
@@ -502,8 +506,6 @@ def build_engine_from_env() -> Backend:
     if kv_quant and kv_quant != "int8":
         raise SystemExit(
             f"SERVE_KV_QUANT must be int8 or empty, got {kv_quant!r}")
-    if kv_quant and kv_mode != "paged":
-        raise SystemExit("SERVE_KV_QUANT=int8 requires SERVE_KV=paged")
 
     def random_init_params(config, seed: int):
         """Shared per-model build: random init -> shard -> quantize.
@@ -584,8 +586,8 @@ def build_engine_from_env() -> Backend:
 
     def make_engine(params, config, tokenizer, name: str) -> TPUEngine:
         return TPUEngine(params, config, tokenizer, num_slots=num_slots,
-                         max_seq=max_seq, mesh=mesh, kv_mode=kv_mode,
-                         page_size=page_size, num_pages=num_pages,
+                         max_seq=max_seq, mesh=mesh, page_size=page_size,
+                         num_pages=num_pages,
                          admit_chunk=admit_chunk,
                          queue_timeout_s=queue_timeout_s, spec_k=spec_k,
                          prefix_cache=prefix_cache,

@@ -9,13 +9,13 @@ bounds *decoding* sessions; pinned host RAM is ~50x larger per chip.
 This module adds the vLLM-style memory hierarchy on top of the paged
 pool (ops/paged_kv.py):
 
-- **resident** (paged mode): a finished request whose client named a
+- **resident**: a finished request whose client named a
   session keeps its physical pages in the pool — the row is released
   and its table zeroed, but the pages stay out of the allocator. A
   follow-up whose prompt extends the session's tokens wakes for free:
   the pages re-enter a fresh row's table and only the new turn's suffix
   runs a forward (serve/scheduler.py `_admit_wake`).
-- **parked** (both modes): under idle timeout or page-pool pressure the
+- **parked**: under idle timeout or page-pool pressure the
   session's raw KV words (int8 + scales included — bit-exact, never a
   requantize) are gathered in one dispatch and copied to host arrays;
   the pages go back to the allocator. Wake re-uploads the payload
@@ -62,6 +62,9 @@ log = get_logger("serve.kv_tier")
 # (bumped on any incompatible layout change; importers reject unknown
 # versions rather than guess — the serve/prefix.py convention).
 _WIRE_VERSION = 1
+# The one pool family a payload may come from; an importer refuses any
+# other ``kind`` (a peer's payload is outside input).
+_WIRE_KIND = "paged"
 
 # Token-head index grain: sessions of at least this many tokens are
 # findable by the hash of their first HEAD_GRAIN token ids (a follow-up
@@ -119,8 +122,8 @@ class SessionKV:
     the last — the cache never holds the final emitted token's KV);
     ``length`` == len(tokens). Exactly one of ``pages`` (resident) /
     ``host`` (parked) is set; ``host`` is the raw-bits payload tuple
-    ((k, v, k_scale, v_scale), n_pages) for paged pools or
-    ((k, v), width) for dense rows."""
+    ((k, v, k_scale, v_scale), n_pages), the scales None for a float
+    pool."""
 
     key: str
     tokens: tuple
@@ -144,13 +147,12 @@ def serialize_session(sess: SessionKV) -> bytes:
     (int8 payload and head-major scales included — never a requantize),
     so an import followed by the destination's verify-shaped wake
     resumes the conversation byte-identically to never having moved.
-    ``kind`` records the pool family the payload came from ("paged":
-    span = page count; "dense": span = the row's bucket width) — the
-    importer validates it against its own geometry before adopting."""
+    ``kind`` names the pool family the payload came from (``_WIRE_KIND``;
+    span = page count) — the importer validates the payload against its
+    own geometry before adopting."""
     import numpy as np
     assert sess.parked, "only parked sessions serialize (park first)"
     arrays, span = sess.host
-    kind = "paged" if len(arrays) == 4 else "dense"
     present = [a is not None for a in arrays]
     # Arrays ship as RAW BYTES + explicit dtype/shape sidecars, not as
     # native npz arrays: npz round-trips extension dtypes (the bf16
@@ -169,7 +171,7 @@ def serialize_session(sess: SessionKV) -> bytes:
     np.savez_compressed(
         buf, version=np.int64(_WIRE_VERSION),
         key=np.bytes_(sess.key.encode()),
-        kind=np.bytes_(kind.encode()),
+        kind=np.bytes_(_WIRE_KIND.encode()),
         tokens=np.asarray(sess.tokens, np.int64),
         length=np.int64(sess.length), span=np.int64(span),
         present=np.asarray(present, bool), **payload)
@@ -216,11 +218,9 @@ def deserialize_session(data: bytes) -> Optional[SessionKV]:
             arrays = tuple(arrays)
     except Exception:   # noqa: BLE001 — peer payloads are untrusted
         return None
-    if (not key or kind not in ("paged", "dense") or span <= 0
+    if (not key or kind != _WIRE_KIND or span <= 0
             or not (0 < length <= len(tokens))
-            or not arrays or arrays[0] is None
-            or (kind == "paged" and len(arrays) != 4)
-            or (kind == "dense" and len(arrays) != 2)):
+            or len(arrays) != 4 or arrays[0] is None):
         return None
     nbytes = sum(a.nbytes for a in arrays if a is not None)
     return SessionKV(key=key, tokens=tokens, length=length,

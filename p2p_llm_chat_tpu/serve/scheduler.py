@@ -59,6 +59,9 @@ from ..models.llama import KVCache
 from ..models.sampling import sample_batched, sample_step_batched
 from ..obs.flight import FlightRecorder
 from ..obs.phase import LoopPhases, compile_clock, process_age_s
+from ..ops.paged_kv import (PageAllocator, PagedKVCache, copy_slot,
+                            gather_pages, scatter_pages, set_row_table,
+                            write_prefill_batch, write_prefill_chunk)
 from ..tokenizer import Tokenizer
 from ..utils.env import env_float
 from ..utils.failpoints import failpoint
@@ -143,7 +146,7 @@ class _Slot:
     max_new: int = 0
     ctx_len: int = 0                                   # host mirror of lengths[row]
     ctx_budget: int = 0                                # max ctx this slot may hold
-    pages: Optional[list[int]] = None                  # paged mode: physical pages
+    pages: Optional[list[int]] = None                  # physical pages
     cancelled: threading.Event = field(default_factory=threading.Event)
     error: Optional[str] = None                        # surfaced by submit()
     prefix: Optional[PrefixEntry] = None               # cached-prefix admission
@@ -221,7 +224,7 @@ class _PrefillCarry:
     S: int                         # suffix bucket (the chunk ladder's span)
     off: int                       # suffix tokens already prefilled
     C: int                         # chunk width, snapshotted at admission —
-    # a runtime toggle of scheduler.prefill_chunk (bench phases) must not
+    # a runtime toggle of scheduler.prefill_chunk must not
     # reshape or never-finish an in-flight carry
     prefix: Optional[PrefixEntry]  # shared broadcast prefix (or None)
     kv: Optional[object]           # device carry cache [L,R,P0+S,Hkv,D]
@@ -230,7 +233,7 @@ class _PrefillCarry:
     ints: "np.ndarray"             # [5,R] lens/rows/seeds/top_k/total-lens
     floats: "np.ndarray"           # [3,R] temp/top_p/repeat_penalty
     rings: "np.ndarray"            # [R,_RING] prompt-tail penalty windows
-    tables: Optional["np.ndarray"]  # [R,mppr] page maps (paged mode)
+    tables: "np.ndarray"            # [R,mppr] page maps
 
 
 class _SlotStream:
@@ -298,10 +301,12 @@ class BatchScheduler:
     """Owns the device state (params, KV cache, per-row sampling state)
     and the decode loop."""
 
+    # Read by benchmark/serve_cell.py's reference check.
+    kv_mode = "paged"
+
     def __init__(self, params: dict, config: ModelConfig,
                  tokenizer: Tokenizer, num_slots: int = 8,
-                 max_seq: int = 1024, mesh=None, kv_mode: str = "dense",
-                 page_size: int = 64,
+                 max_seq: int = 1024, mesh=None, page_size: int = 64,
                  num_pages: Optional[int] = None,
                  admit_chunk: Optional[int] = None,
                  queue_timeout_s: Optional[float] = 60.0,
@@ -447,11 +452,6 @@ class BatchScheduler:
         O(full prompt) to O(suffix). Register known templates via
         :meth:`register_prefix` / warmup ``prefix_texts``; repeated
         heads auto-promote after ``prefix_promote_after`` sightings."""
-        if kv_mode not in ("dense", "paged"):
-            raise ValueError(f"kv_mode must be dense|paged, got {kv_mode!r}")
-        if kv_quant and kv_mode != "paged":
-            raise ValueError("kv_quant=True needs kv_mode='paged' (the "
-                             "int8 pool lives in ops/paged_kv.py)")
         if kv_quant:
             # ops/__init__ rebinds the `paged_attention` attribute to the
             # FUNCTION, so module access must go through importlib.
@@ -468,10 +468,9 @@ class BatchScheduler:
         self.kv_quant = kv_quant
         # The gather->flash-append boundary this process's programs will
         # bake in at trace time. Snapshotted again when warmup records
-        # its ladder (the env toggle is runtime-flippable by design —
-        # bench sweeps do — but the LIVE programs keep whatever they
-        # traced, so the gauge must report the compiled-in value, not
-        # the current env).
+        # its ladder (the env toggle is runtime-flippable by design,
+        # but the LIVE programs keep whatever they traced, so the gauge
+        # must report the compiled-in value, not the current env).
         self._paged_flash_min_w = self._flash_min_w(config, mesh)
         if admit_chunk is not None and admit_chunk < 1:
             raise ValueError(f"admit_chunk must be >= 1, got {admit_chunk}")
@@ -551,12 +550,11 @@ class BatchScheduler:
         self.num_slots = num_slots
         self.max_seq = min(max_seq, config.max_seq_len)
         self.mesh = mesh
-        self.kv_mode = kv_mode
         self.page_size = page_size
-        # Default pool: the dense footprint (num_slots x max_seq) plus the
-        # garbage page — paging then wins by admitting each request at its
-        # *actual* budget, so a smaller pool (or more slots) fits the same
-        # HBM; override via num_pages / SERVE_PAGES.
+        # Default pool: every slot at max_seq (num_slots x max_seq) plus
+        # the garbage page. A request is admitted at its *actual* budget,
+        # so a smaller pool (or more slots) serves the same traffic in
+        # less HBM; override via num_pages / SERVE_PAGES.
         self.num_pages = (num_pages if num_pages is not None else
                           num_slots * -(-self.max_seq // page_size) + 1)
         self._dtype = params["embed"].dtype
@@ -602,7 +600,7 @@ class BatchScheduler:
         self._log_kernels()
 
         self._slots: list[Optional[_Slot]] = [None] * num_slots  # owned-by: _loop
-        self._waiting: list[_Slot] = []  # owned-by: _loop — paged: admitted later, no pages yet
+        self._waiting: list[_Slot] = []  # owned-by: _loop — admitted later, no pages yet
         self._stop_ids = set(config.eos_token_ids)
         eos = getattr(tokenizer, "eos_id", None)
         if eos is not None and 0 <= eos < config.vocab_size:
@@ -841,7 +839,7 @@ class BatchScheduler:
         self._spec_ema: dict[str, float] = {}  # owned-by: _loop
         self._spec_cooldown: dict[str, int] = {}   # owned-by: _loop
         # Per-source proposed/accepted draft-token counters (/metrics
-        # spec_draft_source observability; bench freeform phase).
+        # spec_draft_source observability).
         self._n_spec_proposed_src: dict[str, int] = {}  # owned-by: _loop
         self._n_spec_accepted_src: dict[str, int] = {}  # owned-by: _loop
         self._ensure_sources()
@@ -874,22 +872,18 @@ class BatchScheduler:
                 # INPUT token occupies lengths) — writing at lengths would
                 # clobber the previous tick's emission in the ring.
                 emit_pos = cache.lengths + 1
-                paged = self.kv_mode == "paged"
-                window = ({"pages": -(-kv_window // self.page_size)} if paged
-                          else {"kv_window": kv_window})
+                pages = -(-kv_window // self.page_size)
                 moe = None
                 if routed:
                     # A routed model's step also counts the experts its
                     # live rows reached (models/mixtral.py).
-                    step = (model.decode_step_paged_touched if paged
-                            else model.decode_step_touched)
-                    logits, cache, moe = step(params, config, tokens, cache,
-                                              mesh, active=active, **window)
+                    logits, cache, moe = model.decode_step_paged_touched(
+                        params, config, tokens, cache, mesh, active=active,
+                        pages=pages)
                 else:
-                    step = (model.decode_step_paged if paged
-                            else model.decode_step)
-                    logits, cache = step(params, config, tokens, cache, mesh,
-                                         active=active, **window)
+                    logits, cache = model.decode_step_paged(
+                        params, config, tokens, cache, mesh, active=active,
+                        pages=pages)
                 # Shared sample + penalty-ring step (parked rows' ring
                 # writes drop) — the ONE implementation the fused path's
                 # scan body also runs, so fused-K output stays
@@ -929,11 +923,8 @@ class BatchScheduler:
 
                 kwargs: dict = dict(num_steps=K, sample_fn=sample_fn,
                                     sample_state=(keys, ring),
-                                    stop_ids=stop_ids, active=active)
-                if self.kv_mode == "paged":
-                    kwargs["pages"] = -(-kv_window // self.page_size)
-                else:
-                    kwargs["kv_window"] = kv_window
+                                    stop_ids=stop_ids, active=active,
+                                    pages=-(-kv_window // self.page_size))
                 moe = None
                 if routed:
                     (toks_all, _, next_tokens, cache, _, (keys, ring),
@@ -960,16 +951,11 @@ class BatchScheduler:
                             temps, top_ks, top_ps, keys, ring, rps):
                 K = tokens.shape[1] - 1
                 lengths_pre = cache.lengths
-                if self.kv_mode == "paged":
-                    S = tokens.shape[1]
-                    pages = min(-(-(kv_window + S) // self.page_size),
-                                cache.max_pages_per_row)
-                    logits, cache = model.verify_step_paged(
-                        params, config, tokens, cache, mesh, pages=pages)
-                else:
-                    logits, cache = model.verify_step(
-                        params, config, tokens, cache, mesh,
-                        kv_window=kv_window)
+                S = tokens.shape[1]
+                pages = min(-(-(kv_window + S) // self.page_size),
+                            cache.max_pages_per_row)
+                logits, cache = model.verify_step_paged(
+                    params, config, tokens, cache, mesh, pages=pages)
                 accepted, correction, keys = spec_verify_batched(
                     logits.astype(jnp.float32), drafts, keys, temps,
                     top_ks, top_ps, max_acc, ring=ring, rp=rps,
@@ -1008,7 +994,6 @@ class BatchScheduler:
             advance, all fused. Host reads back 3×B int32 (accepted,
             used_sib, correction)."""
             from ..models.sampling import spec_verify_tree
-            from ..ops.paged_kv import copy_slot
 
             def spec_tree_verify(params, tokens, depths, anc, drafts,
                                  sib_tok, sib_node, max_acc, cache, active,
@@ -1016,16 +1001,11 @@ class BatchScheduler:
                 B, N = tokens.shape
                 K = drafts.shape[1]
                 lengths_pre = cache.lengths
-                if self.kv_mode == "paged":
-                    pages = min(-(-(kv_window + N) // self.page_size),
-                                cache.max_pages_per_row)
-                    logits, cache = model.verify_tree_paged(
-                        params, config, tokens, depths, anc, cache, mesh,
-                        pages=pages)
-                else:
-                    logits, cache = model.verify_tree(
-                        params, config, tokens, depths, anc, cache, mesh,
-                        kv_window=kv_window)
+                pages = min(-(-(kv_window + N) // self.page_size),
+                            cache.max_pages_per_row)
+                logits, cache = model.verify_tree_paged(
+                    params, config, tokens, depths, anc, cache, mesh,
+                    pages=pages)
                 accepted, used_sib, correction, keys = spec_verify_tree(
                     logits.astype(jnp.float32), drafts, sib_tok,
                     sib_node, keys, temps, top_ks, top_ps, max_acc,
@@ -1044,16 +1024,7 @@ class BatchScheduler:
                 move = active & (used_sib > 0)
                 dst = lengths_pre + accepted
                 src = jnp.where(move, lengths_pre + sn, dst)
-                if self.kv_mode == "paged":
-                    cache = copy_slot(cache, src, dst)
-                else:
-                    b_ix = jnp.arange(B)
-                    src_c = jnp.minimum(src, cache.k.shape[2] - 1)
-                    cache = cache._replace(
-                        k=cache.k.at[:, b_ix, dst].set(
-                            cache.k[:, b_ix, src_c], mode="drop"),
-                        v=cache.v.at[:, b_ix, dst].set(
-                            cache.v[:, b_ix, src_c], mode="drop"))
+                cache = copy_slot(cache, src, dst)
                 inc = jnp.where(active, accepted + 1, 0)
                 cache = cache._replace(
                     lengths=cache.lengths
@@ -1090,7 +1061,7 @@ class BatchScheduler:
         def _make_wake(kv_window: int, S: int):
             """Session-wake admission program (multi-tier KV): ONE fused
             dispatch re-opens waking sessions — install each waking
-            row's page table (paged) and length ATOMICALLY (the chunked-
+            row's page table and length ATOMICALLY (the chunked-
             admission splice discipline: a half-woken row never looks
             live), run the suffix tokens through a verify-shaped
             multi-position forward that attends the session's existing
@@ -1107,39 +1078,23 @@ class BatchScheduler:
             tokens [B,S] right-padded suffixes; ints [4,B] = suffix
             lens (0 = not waking) / session lengths / seeds / top_k;
             floats [3,B] = temp/top_p/repeat_penalty; rings [B,_RING]
-            prompt-tail penalty windows; paged mode adds tables
-            [B,mppr] (each waking row's FULL page map: the session's
-            kept pages plus freshly-allocated growth pages)."""
-            def kv_wake(params, tokens, ints, floats, rings, *args):
-                if self.kv_mode == "paged":
-                    tables = args[0]
-                    rest = args[1:]
-                else:
-                    tables = None
-                    rest = args
-                (cache, keys, next_tokens, temps, top_ks, top_ps,
-                 ring, rps) = rest
+            prompt-tail penalty windows; tables [B,mppr] = each waking
+            row's FULL page map (the session's kept pages plus
+            freshly-allocated growth pages)."""
+            def kv_wake(params, tokens, ints, floats, rings, tables, cache,
+                        keys, next_tokens, temps, top_ks, top_ps, ring, rps):
                 suf, start = ints[0], ints[1]
                 mask = suf > 0
                 lengths = jnp.where(mask, start, cache.lengths).astype(
                     cache.lengths.dtype)
-                if tables is not None:
-                    table = jnp.where(mask[:, None],
-                                      tables.astype(jnp.int32),
-                                      cache.page_table)
-                    cache = cache._replace(page_table=table,
-                                           lengths=lengths)
-                    pages = min(-(-(kv_window + S) // self.page_size),
-                                cache.max_pages_per_row)
-                    logits, cache = model.verify_step_paged(
-                        params, config, tokens, cache, mesh, pages=pages,
-                        last_idx=jnp.clip(suf - 1, 0, S - 1))
-                else:
-                    cache = cache._replace(lengths=lengths)
-                    logits, cache = model.verify_step(
-                        params, config, tokens, cache, mesh,
-                        kv_window=kv_window,
-                        last_idx=jnp.clip(suf - 1, 0, S - 1))
+                table = jnp.where(mask[:, None], tables.astype(jnp.int32),
+                                  cache.page_table)
+                cache = cache._replace(page_table=table, lengths=lengths)
+                pages = min(-(-(kv_window + S) // self.page_size),
+                            cache.max_pages_per_row)
+                logits, cache = model.verify_step_paged(
+                    params, config, tokens, cache, mesh, pages=pages,
+                    last_idx=jnp.clip(suf - 1, 0, S - 1))
                 inc = jnp.where(mask, suf, 0)
                 cache = cache._replace(
                     lengths=cache.lengths + inc.astype(cache.lengths.dtype))
@@ -1161,9 +1116,8 @@ class BatchScheduler:
                 rps = jnp.where(mask, floats[2], rps)
                 return (toks, cache, keys, next_tokens, temps, top_ks,
                         top_ps, ring, rps)
-            first = 6 if self.kv_mode == "paged" else 5
             return jax.jit(kv_wake,
-                           donate_argnums=tuple(range(first, first + 8)))
+                           donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 
         self._make_wake = _make_wake
         self._wake_programs: dict[tuple[int, int], object] = {}
@@ -1185,8 +1139,8 @@ class BatchScheduler:
             return (pos < ints[0][:, None]) & real
 
         def _prefill_first_token(params, tokens, ints, floats, rings):
-            """Shared admission prologue (dense and paged): batched prefill
-            of R prompts + each row's first sampled token.
+            """Shared admission prologue: batched prefill of R prompts +
+            each row's first sampled token.
 
             Host scalars arrive packed (``ints`` [4,R] = lens/rows/seeds/
             top_k, ``floats`` [3,R] = temperature/top_p/repeat_penalty,
@@ -1232,44 +1186,21 @@ class BatchScheduler:
             rps = rps.at[rows].set(floats[2], mode="drop")
             return keys, next_tokens, temps, top_ks, top_ps, ring, rps
 
-        def prefill_admit(params, tokens, ints, floats, rings, cache, keys,
-                          next_tokens, temps, top_ks, top_ps, ring, rps):
-            """Prefill R prompts together, splice each row's kv into the big
-            cache, and sample each row's first token. R comes from a
-            two-size ladder (short chunks carry padding entries whose row
-            index is the out-of-range sentinel, so every install of theirs
-            is dropped); S is the prompt bucket — two compiled programs per
-            bucket. One vector scatter installs the whole chunk."""
-            S = tokens.shape[1]
-            lens, rows = ints[0], ints[1]
-            small, toks, row_keys, rings, moe = _prefill_first_token(
-                params, tokens, ints, floats, rings)
-            k = cache.k.at[:, rows, :S].set(small.k, mode="drop")
-            v = cache.v.at[:, rows, :S].set(small.v, mode="drop")
-            lengths = cache.lengths.at[rows].set(
-                lens.astype(cache.lengths.dtype), mode="drop")
-            cache = KVCache(k, v, lengths)
-            (keys, next_tokens, temps, top_ks, top_ps, ring,
-             rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
-                                  keys, next_tokens, temps, top_ks, top_ps,
-                                  ring, rps)
-            return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
-                    top_ks, top_ps, ring, rps)
-
         def prefill_admit_paged(params, tokens, ints, floats, rings, tables,
                                 cache, keys, next_tokens, temps, top_ks,
                                 top_ps, ring, rps):
-            """Paged-mode admission: same fused prefill/sample as
-            prefill_admit, but the chunk's kv splices into the page pool
-            through the rows' page maps in ONE scatter
-            (ops/paged_kv.write_prefill_batch — the R-sequential-scatters
-            version made paged TTFT ~8x dense). Padding entries carry an
-            all-zero table (writes land in garbage page 0) and the
-            out-of-range row sentinel (installs dropped)."""
+            """Prefill R prompts together, splice each row's kv into the
+            page pool, and sample each row's first token. R comes from a
+            two-size ladder and S is the prompt bucket — two compiled
+            programs per bucket. The chunk's kv goes through the rows'
+            page maps in ONE scatter (ops/paged_kv.write_prefill_batch;
+            R sequential scatters cost ~8x the TTFT). Padding entries
+            carry an all-zero table (writes land in garbage page 0) and
+            the out-of-range row sentinel ``num_slots`` (installs
+            dropped)."""
             lens, rows = ints[0], ints[1]
             small, toks, row_keys, rings, moe = _prefill_first_token(
                 params, tokens, ints, floats, rings)
-            from ..ops.paged_kv import write_prefill_batch
             cache = write_prefill_batch(cache, small.k, small.v, rows, lens,
                                         tables)
             (keys, next_tokens, temps, top_ks, top_ps, ring,
@@ -1320,41 +1251,19 @@ class BatchScheduler:
             rings = rings.at[jnp.arange(R), total_lens % _RING].set(toks)
             return small, toks, row_keys, rings, moe
 
-        def prefill_admit_prefix(params, pk, pv, tokens, ints, floats,
-                                 rings, cache, keys, next_tokens, temps,
-                                 top_ks, top_ps, ring, rps):
-            """prefill_admit for a chunk sharing one cached prefix: splice
-            [prefix KV + suffix KV] (the small cache, P+S wide) into the
-            big cache and install lengths = total (prefix + suffix)."""
-            S = tokens.shape[1]
-            P = pk.shape[1]
-            rows, total_lens = ints[1], ints[4]
-            small, toks, row_keys, rings, moe = _prefill_first_token_prefix(
-                params, pk, pv, tokens, ints, floats, rings)
-            k = cache.k.at[:, rows, : P + S].set(small.k, mode="drop")
-            v = cache.v.at[:, rows, : P + S].set(small.v, mode="drop")
-            lengths = cache.lengths.at[rows].set(
-                total_lens.astype(cache.lengths.dtype), mode="drop")
-            cache = KVCache(k, v, lengths)
-            (keys, next_tokens, temps, top_ks, top_ps, ring,
-             rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
-                                  keys, next_tokens, temps, top_ks, top_ps,
-                                  ring, rps)
-            return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
-                    top_ks, top_ps, ring, rps)
-
         def prefill_admit_paged_prefix(params, pk, pv, tokens, ints, floats,
                                        rings, tables, cache, keys,
                                        next_tokens, temps, top_ks, top_ps,
                                        ring, rps):
-            """Paged-mode prefix admission: the combined [prefix + suffix]
-            KV splices into each row's own pages through the one-scatter
-            batch path (copy-based sharing — rows own their prefix copy,
-            so release/containment invariants are untouched)."""
+            """prefill_admit_paged for a chunk sharing one cached prefix:
+            the combined [prefix + suffix] KV (the small cache, P+S wide)
+            splices into each row's own pages through the one-scatter
+            batch path, and lengths = total (copy-based sharing — rows
+            own their prefix copy, so release/containment invariants are
+            untouched)."""
             rows, total_lens = ints[1], ints[4]
             small, toks, row_keys, rings, moe = _prefill_first_token_prefix(
                 params, pk, pv, tokens, ints, floats, rings)
-            from ..ops.paged_kv import write_prefill_batch
             cache = write_prefill_batch(cache, small.k, small.v, rows,
                                         total_lens, tables)
             (keys, next_tokens, temps, top_ks, top_ps, ring,
@@ -1364,53 +1273,38 @@ class BatchScheduler:
             return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
                     top_ks, top_ps, ring, rps)
 
-        if self.kv_mode == "paged":
-            self._admit_j = jax.jit(prefill_admit_paged,
-                                    donate_argnums=(6, 7, 8, 9, 10, 11, 12,
-                                                    13))
-            self._admit_prefix_j = jax.jit(
-                prefill_admit_paged_prefix,
-                donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
-            from ..ops.paged_kv import set_row_table
+        self._admit_j = jax.jit(prefill_admit_paged,
+                                donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+        self._admit_prefix_j = jax.jit(
+            prefill_admit_paged_prefix,
+            donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
 
-            def kv_zero_row(cache, row):
-                return set_row_table(
-                    cache, row,
-                    jnp.zeros((cache.page_table.shape[1],), jnp.int32))
+        def kv_zero_row(cache, row):
+            return set_row_table(
+                cache, row,
+                jnp.zeros((cache.page_table.shape[1],), jnp.int32))
 
-            # Row release: zero the table (writes re-route to the garbage
-            # page) BEFORE its pages return to the allocator — a stale
-            # parked row must never scatter into a re-allocated page.
-            self._zero_row_j = jax.jit(kv_zero_row, donate_argnums=(0,))
-        else:
-            self._admit_j = jax.jit(prefill_admit,
-                                    donate_argnums=(5, 6, 7, 8, 9, 10, 11,
-                                                    12))
-            self._admit_prefix_j = jax.jit(
-                prefill_admit_prefix,
-                donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
+        # Row release: zero the table (writes re-route to the garbage
+        # page) BEFORE its pages return to the allocator — a stale
+        # parked row must never scatter into a re-allocated page.
+        self._zero_row_j = jax.jit(kv_zero_row, donate_argnums=(0,))
 
         # Multi-tier KV copy programs: the park gather and wake scatter
         # move a session's raw pool words (int8 + head-major scales
         # included) in ONE dispatch each; jit re-specializes per padded
         # page-count bucket automatically (callers pad the page list to
-        # a power of two so the compile cache stays small). Dense rows
-        # use per-width slice/set programs (_extract_row_for).
-        if self.kv_mode == "paged":
-            from ..ops.paged_kv import gather_pages, scatter_pages
+        # a power of two so the compile cache stays small).
+        # Wrapped only to carry the kind in the program's name.
+        def kv_gather_pages(cache, pages):
+            return gather_pages(cache, pages)
 
-            # Wrapped only to carry the kind in the program's name.
-            def kv_gather_pages(cache, pages):
-                return gather_pages(cache, pages)
+        def kv_scatter_pages(cache, pages, *payload):
+            return scatter_pages(cache, pages, *payload)
 
-            def kv_scatter_pages(cache, pages, *payload):
-                return scatter_pages(cache, pages, *payload)
-
-            # graftcheck: nodonate park gather READS the live pool; the resident buffer must outlive the copy
-            self._gather_pages_j = jax.jit(kv_gather_pages)
-            self._scatter_pages_j = jax.jit(kv_scatter_pages,
-                                            donate_argnums=(0,))
-        self._row_copy_programs: dict[tuple, object] = {}
+        # graftcheck: nodonate park gather READS the live pool; the resident buffer must outlive the copy
+        self._gather_pages_j = jax.jit(kv_gather_pages)
+        self._scatter_pages_j = jax.jit(kv_scatter_pages,
+                                        donate_argnums=(0,))
 
         def _make_prefill_chunk_program(P0: int, S: int, OFF: int, C: int):
             """ONE continuation-prefill chunk program of the chunked
@@ -1435,14 +1329,9 @@ class BatchScheduler:
               the carried logits (the exact _prefill_first_token tail)
               and installs lengths/tables/sampling state atomically.
 
-            Dense rows additionally park their decode-write position at
-            max_seq on the first chunk: a stale length from the row's
-            previous tenant could sit inside the region later chunks
-            write, and every decode tick scatters a parked row's
-            garbage k/v at that slot — out-of-range writes drop
-            instead. (Paged rows need nothing: their live page_table
-            row is zeroed from release, so garbage writes keep landing
-            in page 0 until the final install.)"""
+            A row's live page_table row stays zeroed from release until
+            the final install, so a parked row's garbage decode writes
+            keep landing in page 0 meanwhile."""
             if S % C or not 0 <= OFF < S:
                 raise ValueError(
                     f"chunk ladder must divide the bucket: S={S} C={C} "
@@ -1450,7 +1339,6 @@ class BatchScheduler:
             first, final = OFF == 0, OFF + C == S
             W = P0 + S
             base = P0 + OFF
-            paged = self.kv_mode == "paged"
 
             def _fwd(params, tokens, ints, carry, logits_c):
                 # A routed model's carried logits travel with its drop
@@ -1477,45 +1365,25 @@ class BatchScheduler:
             def _splice(cache, carry, ints, tables):
                 rows = ints[1]
                 lo = 0 if first else base   # first chunk carries the prefix
-                if paged:
-                    from ..ops.paged_kv import write_prefill_chunk
-                    cache = write_prefill_chunk(
-                        cache, carry.k[:, :, lo: base + C],
-                        carry.v[:, :, lo: base + C], tables, lo)
-                    if final:
-                        table = cache.page_table.at[rows].set(
-                            tables.astype(jnp.int32), mode="drop")
-                        lengths = cache.lengths.at[rows].set(
-                            ints[4].astype(cache.lengths.dtype),
-                            mode="drop")
-                        cache = cache._replace(page_table=table,
-                                               lengths=lengths)
-                    return cache
-                k = cache.k.at[:, rows, lo: base + C].set(
-                    carry.k[:, :, lo: base + C], mode="drop")
-                v = cache.v.at[:, rows, lo: base + C].set(
-                    carry.v[:, :, lo: base + C], mode="drop")
+                cache = write_prefill_chunk(
+                    cache, carry.k[:, :, lo: base + C],
+                    carry.v[:, :, lo: base + C], tables, lo)
                 if final:
+                    table = cache.page_table.at[rows].set(
+                        tables.astype(jnp.int32), mode="drop")
                     lengths = cache.lengths.at[rows].set(
                         ints[4].astype(cache.lengths.dtype), mode="drop")
-                elif first:
-                    lengths = cache.lengths.at[rows].set(
-                        jnp.int32(self.max_seq), mode="drop")
-                else:
-                    lengths = cache.lengths
-                return KVCache(k, v, lengths)
+                    cache = cache._replace(page_table=table,
+                                           lengths=lengths)
+                return cache
 
             if first:
                 def prefill_chunk_first(params, *args):
                     if P0:
-                        pk, pv, tokens, ints = args[:4]
-                        rest = args[4:]
+                        pk, pv, tokens, ints, tables, cache = args
                     else:
                         pk = pv = None
-                        tokens, ints = args[:2]
-                        rest = args[2:]
-                    tables = rest[0] if paged else None
-                    cache = rest[-1]
+                        tokens, ints, tables, cache = args
                     R = tokens.shape[0]
                     carry = KVCache.create(config, R, W, dtype=self._dtype)
                     if P0:
@@ -1531,27 +1399,22 @@ class BatchScheduler:
                     cache = _splice(cache, carry, ints, tables)
                     return carry, logits_c, cache
                 # donate the big cache (always the last argument)
-                n_args = 1 + (2 if P0 else 0) + 2 + (1 if paged else 0) + 1
                 return jax.jit(prefill_chunk_first,
-                               donate_argnums=(n_args - 1,))
+                               donate_argnums=(6 if P0 else 4,))
 
             if not final:
                 def prefill_chunk_mid(params, tokens, ints, carry, logits_c,
-                                      *rest):
-                    tables = rest[0] if paged else None
-                    cache = rest[-1]
+                                      tables, cache):
                     carry, logits_c = _fwd(params, tokens, ints, carry,
                                            logits_c)
                     cache = _splice(cache, carry, ints, tables)
                     return carry, logits_c, cache
-                last = 5 + (1 if paged else 0)
-                return jax.jit(prefill_chunk_mid, donate_argnums=(3, 4, last))
+                return jax.jit(prefill_chunk_mid, donate_argnums=(3, 4, 6))
 
             def prefill_chunk_final(params, tokens, ints, floats, rings,
-                                    carry, logits_c, *rest):
-                tables = rest[0] if paged else None
-                (cache, keys, next_tokens, temps, top_ks, top_ps, ring,
-                 rps) = rest[-8:]
+                                    carry, logits_c, tables, cache, keys,
+                                    next_tokens, temps, top_ks, top_ps, ring,
+                                    rps):
                 carry, logits_c = _fwd(params, tokens, ints, carry,
                                        logits_c)
                 moe = None
@@ -1576,9 +1439,8 @@ class BatchScheduler:
             # The carry kv/logits die here but have no same-shaped output
             # to alias into — donating them only trips XLA's unusable-
             # donation warning, so they are freed by refcount instead.
-            off0 = 7 + (1 if paged else 0)
             return jax.jit(prefill_chunk_final,
-                           donate_argnums=tuple(range(off0, off0 + 8)))
+                           donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
 
         self._make_prefill_chunk_program = _make_prefill_chunk_program
         self._prefill_chunk_programs: dict[tuple[int, int, int], object] = {}
@@ -1757,40 +1619,14 @@ class BatchScheduler:
             self._wake_programs[(window, S)] = p
         return p
 
-    def _extract_row_for(self, W: int):
-        """Dense-row park gather: one [L,W,Hkv,D] slice pair per
-        session (W = the session's power-of-two width bucket)."""
-        key = ("extract", W)
-        p = self._row_copy_programs.get(key)
-        if p is None:
-            def kv_extract_row(cache, row):
-                return cache.k[:, row, :W], cache.v[:, row, :W]
-            # graftcheck: nodonate park gather READS the live cache; the resident buffer must outlive the copy
-            p = jax.jit(kv_extract_row)
-            self._row_copy_programs[key] = p
-        return p
-
-    def _inject_row_for(self, W: int):
-        """Dense-row wake scatter: the inverse copy, donated so the
-        upload lands in place."""
-        key = ("inject", W)
-        p = self._row_copy_programs.get(key)
-        if p is None:
-            def kv_inject_row(cache, row, k, v):
-                return cache._replace(k=cache.k.at[:, row, :W].set(k),
-                                      v=cache.v.at[:, row, :W].set(v))
-            p = jax.jit(kv_inject_row, donate_argnums=(0,))
-            self._row_copy_programs[key] = p
-        return p
-
     def _prefill_chunk_for(self, P0: int, S: int, off: int, C: int):
         """Jitted continuation-prefill chunk program (compiled once per
         (prefix length, suffix bucket, offset, chunk width) — warmup
         walks the whole ladder so none compiles mid-serving). ``C`` is
         the caller's chunk width, NOT self.prefill_chunk: an in-flight
         carry snapshots its width at admission, so a runtime toggle of
-        prefill_chunk (bench.py phases do this) can never mismatch a
-        half-prefilled admission against a differently-shaped program."""
+        prefill_chunk can never mismatch a half-prefilled admission
+        against a differently-shaped program."""
         key = (P0, S, off, C)
         p = self._prefill_chunk_programs.get(key)
         if p is None:
@@ -1843,7 +1679,7 @@ class BatchScheduler:
         kmax = self.decode_fuse_max
         if kmax <= 1:
             return 1
-        C = self.prefill_chunk   # one read: bench toggles it at runtime
+        C = self.prefill_chunk   # one read: togglable at runtime
         if ((not C or self.max_seq % C)
                 and (self._admit_carry or self._waiting
                      or not self._admit_q.empty())):
@@ -2039,8 +1875,7 @@ class BatchScheduler:
             # per-program queue, so a mid-traffic warmup interleaves
             # drafter compiles with live ticks too.
             steps.extend(self._draft_model.warm(buckets, windows))
-        if self.kv_mode == "paged":
-            steps.append(self._warm_zero_row)
+        steps.append(self._warm_zero_row)
         # One-shot device-step measurement for the wall/device gauges —
         # after the windows compiled, before traffic.
         steps.append(self._probe_device_step)
@@ -2055,13 +1890,12 @@ class BatchScheduler:
             # batch promoting from a gather window into a kernel window
             # mid-serving never compiles over active streams.
             flash_note = ""
-            if self.kv_mode == "paged":
-                min_w = self._paged_flash_min_w = self._flash_min_w(
-                    self.config, self.mesh)
-                kernel_ws = [w for w in windows if min_w and w >= min_w]
-                if kernel_ws:
-                    flash_note = (f", flash-append kernel at windows "
-                                  f"{kernel_ws} (min_w {min_w})")
+            min_w = self._paged_flash_min_w = self._flash_min_w(
+                self.config, self.mesh)
+            kernel_ws = [w for w in windows if min_w and w >= min_w]
+            if kernel_ws:
+                flash_note = (f", flash-append kernel at windows "
+                              f"{kernel_ws} (min_w {min_w})")
             log.info("warmup compiled: admit widths by bucket %s, decode "
                      "windows %s, prefill chunk %d (%d continuation "
                      "programs)%s",
@@ -2204,8 +2038,7 @@ class BatchScheduler:
                 self._keys, self._next_dev, self._temps_dev,
                 self._top_ks_dev, self._top_ps_dev, self._ring_dev,
                 self._rps_dev)),
-            "mppr": (self._cache.max_pages_per_row
-                     if self.kv_mode == "paged" else 0),
+            "mppr": self._cache.max_pages_per_row,
         }
 
     def _compile_promotion_aot(self, P: int, k, v, combos: list[tuple],
@@ -2221,22 +2054,17 @@ class BatchScheduler:
                                        structs["sample"])
         ks = jax.ShapeDtypeStruct(k.shape, k.dtype)
         vs = jax.ShapeDtypeStruct(v.shape, v.dtype)
-        paged = self.kv_mode == "paged"
         aot_admit: dict[tuple, object] = {}
         aot_chunks: dict[tuple, object] = {}
         for S, R, C, offs in combos:
             ints5 = jax.ShapeDtypeStruct((5, R), jnp.int32)
             floats3 = jax.ShapeDtypeStruct((3, R), jnp.float32)
             rings = jax.ShapeDtypeStruct((R, _RING), jnp.int32)
-            tables = (jax.ShapeDtypeStruct((R, structs["mppr"]), jnp.int32)
-                      if paged else None)
+            tables = jax.ShapeDtypeStruct((R, structs["mppr"]), jnp.int32)
             if offs is None:
                 args = [params_s, ks, vs,
                         jax.ShapeDtypeStruct((R, S), jnp.int32), ints5,
-                        floats3, rings]
-                if paged:
-                    args.append(tables)
-                args += [cache_s, *sample_s]
+                        floats3, rings, tables, cache_s, *sample_s]
                 aot_admit[(P, S, R)] = (
                     self._admit_prefix_j.lower(*args).compile())
                 continue
@@ -2248,21 +2076,13 @@ class BatchScheduler:
             for off in offs:
                 prog = self._make_prefill_chunk_program(P, S, off, C)
                 if off == 0:
-                    args = [params_s, ks, vs, toks, ints5]
-                    if paged:
-                        args.append(tables)
-                    args.append(cache_s)
+                    args = [params_s, ks, vs, toks, ints5, tables, cache_s]
                 elif off + C < S:
-                    args = [params_s, toks, ints5, carry_s, logits_s]
-                    if paged:
-                        args.append(tables)
-                    args.append(cache_s)
+                    args = [params_s, toks, ints5, carry_s, logits_s, tables,
+                            cache_s]
                 else:
                     args = [params_s, toks, ints5, floats3, rings, carry_s,
-                            logits_s]
-                    if paged:
-                        args.append(tables)
-                    args += [cache_s, *sample_s]
+                            logits_s, tables, cache_s, *sample_s]
                 aot_chunks[(P, S, off, C, R)] = (
                     prog.lower(*args).compile())
         return aot_admit, aot_chunks
@@ -2375,8 +2195,7 @@ class BatchScheduler:
         floats[1] = 1.0
         floats[2] = 1.0
         rings = np.full((R, _RING), self.config.vocab_size, np.int32)
-        tables = (np.zeros((R, self._cache.max_pages_per_row), np.int32)
-                  if self.kv_mode == "paged" else None)
+        tables = np.zeros((R, self._cache.max_pages_per_row), np.int32)
         if off == 0:
             kv = logits = None
         else:
@@ -2395,7 +2214,7 @@ class BatchScheduler:
         a mid-traffic warmup must not perturb seeded requests' outputs.
 
         Each window's program bakes in its attention impl at trace time
-        (paged mode: gather below PAGED_APPEND_FLASH_MIN_W, the
+        (gather below PAGED_APPEND_FLASH_MIN_W, the
         multi-chunk flash-append kernel at and above it on TPU), so
         running this across the default whole ladder up to max_seq
         warms the kernel's Mosaic compiles at every long-window bucket
@@ -2476,17 +2295,15 @@ class BatchScheduler:
         floats[1] = 1.0
         floats[2] = 1.0
         rings = np.full((B, _RING), self.config.vocab_size, np.int32)
-        args = [self._params, jnp.asarray(tokens), jnp.asarray(ints),
-                jnp.asarray(floats), jnp.asarray(rings)]
-        if self.kv_mode == "paged":
-            args.append(jnp.asarray(
-                np.zeros((B, self._cache.max_pages_per_row), np.int32)))
-        args += [self._cache, self._keys, self._next_dev,
-                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                 self._ring_dev, self._rps_dev]
+        tables = np.zeros((B, self._cache.max_pages_per_row), np.int32)
         (_, self._cache, self._keys, self._next_dev, self._temps_dev,
          self._top_ks_dev, self._top_ps_dev, self._ring_dev,
-         self._rps_dev) = self._wake_for(w, S)(*args)
+         self._rps_dev) = self._wake_for(w, S)(
+            self._params, jnp.asarray(tokens), jnp.asarray(ints),
+            jnp.asarray(floats), jnp.asarray(rings), jnp.asarray(tables),
+            self._cache, self._keys, self._next_dev, self._temps_dev,
+            self._top_ks_dev, self._top_ps_dev, self._ring_dev,
+            self._rps_dev)
         self._wake_shapes_run.add((w, S))
 
     # graftcheck: runs-on _loop
@@ -2546,17 +2363,11 @@ class BatchScheduler:
 
     def _reset_device_state(self) -> None:
         B = self.num_slots
-        if self.kv_mode == "paged":
-            from ..ops.paged_kv import PageAllocator, PagedKVCache
-            self._alloc = PageAllocator(self.num_pages, self.page_size)
-            self._cache = PagedKVCache.create(
-                self.config, B, self.num_pages, self.page_size,
-                max_pages_per_row=-(-self.max_seq // self.page_size),
-                dtype=self._dtype, quantized=self.kv_quant,
-                mesh=self.mesh)
-        else:
-            self._cache = KVCache.create(self.config, B, self.max_seq,
-                                         self._dtype)
+        self._alloc = PageAllocator(self.num_pages, self.page_size)
+        self._cache = PagedKVCache.create(
+            self.config, B, self.num_pages, self.page_size,
+            max_pages_per_row=-(-self.max_seq // self.page_size),
+            dtype=self._dtype, quantized=self.kv_quant, mesh=self.mesh)
         self._next_dev = jnp.zeros((B, 1), jnp.int32)
         self._keys = jnp.zeros((B, 2), jnp.uint32)
         # Per-row sampling options live on device; admission scatters them
@@ -3043,9 +2854,8 @@ class BatchScheduler:
 
     def reset_decode_stall(self, timeout_s: float = 30.0) -> None:
         """Zero the decode_stall_ms max gauge (and its timestamp), so a
-        phased workload (bench.py's mixed-load chunked vs single-shot
-        halves) can attribute the max decode-tick gap to its OWN phase
-        instead of reading a lifetime max. The gauge is _loop-owned, so
+        phased workload can attribute the max decode-tick gap to its OWN
+        phase instead of reading a lifetime max. The gauge is _loop-owned, so
         the reset executes ON the scheduler thread — via an event the
         loop services at the top of EVERY iteration, not a queued
         admission job: the admit queue only drains when admission can
@@ -3081,9 +2891,8 @@ class BatchScheduler:
         Resident pages are device state only the scheduler loop may
         gather, so this is the same event handshake as
         :meth:`reset_decode_stall`: the loop services it at the top of
-        every iteration, even mid-backlog. No-op without a tier, or in
-        dense mode (dense sessions park at finish — nothing is ever
-        resident). Returns once the loop has ack'd."""
+        every iteration, even mid-backlog. No-op without a tier. Returns
+        once the loop has ack'd."""
         if self._tier is None:
             return
         if self._closed.is_set():
@@ -3106,7 +2915,7 @@ class BatchScheduler:
         self._park_all_req.clear()
         key = self._park_all_key
         try:
-            if self._tier is not None and self.kv_mode == "paged":
+            if self._tier is not None:
                 for sess in self._tier.park_candidates(force=True):
                     if key is None or sess.key == key:
                         self._park_session(sess)
@@ -3261,32 +3070,22 @@ class BatchScheduler:
         try:
             arrays, span = sess.host
             k = arrays[0]
-            kind = "paged" if len(arrays) == 4 else "dense"
-            if kind != self.kv_mode or sess.length > self.max_seq:
+            if len(arrays) != 4 or sess.length > self.max_seq:
                 return False
             if any(t < 0 or t >= self.config.vocab_size
                    for t in sess.tokens):
                 return False
             cache_k = self._cache.k
-            if self.kv_mode == "paged":
-                if (k.shape[0] != cache_k.shape[0]
-                        or k.shape[2:] != cache_k.shape[2:]
-                        or str(k.dtype) != str(cache_k.dtype)):
-                    return False
-                if (arrays[2] is not None) != bool(self.kv_quant):
-                    return False
-                if span > k.shape[1] or span > self._cache.max_pages_per_row:
-                    return False
-                if -(-sess.length // self.page_size) > span:
-                    return False
-            else:
-                # Dense row: [L, W, Hkv, D] against cache [L, B, S, Hkv, D].
-                if (k.shape[0] != cache_k.shape[0] or k.shape[1] != span
-                        or span > self.max_seq
-                        or k.shape[2:] != cache_k.shape[3:]
-                        or str(k.dtype) != str(cache_k.dtype)
-                        or sess.length > span):
-                    return False
+            if (k.shape[0] != cache_k.shape[0]
+                    or k.shape[2:] != cache_k.shape[2:]
+                    or str(k.dtype) != str(cache_k.dtype)):
+                return False
+            if (arrays[2] is not None) != bool(self.kv_quant):
+                return False
+            if span > k.shape[1] or span > self._cache.max_pages_per_row:
+                return False
+            if -(-sess.length // self.page_size) > span:
+                return False
             return True
         except Exception:   # noqa: BLE001 — incompatible payloads reject
             return False
@@ -3520,17 +3319,16 @@ class BatchScheduler:
                 self._wake_hist.percentile(50) or 0.0, 3)
             out["kv_wake_p95_ms"] = round(
                 self._wake_hist.percentile(95) or 0.0, 3)
-        if self.kv_mode == "paged":
-            out["serve_kv_free_pages"] = self._alloc.free_pages
-            out["serve_kv_total_pages"] = self.num_pages - 1
-            # The gather->flash-append promotion boundary (0 = kernel
-            # cannot engage: CPU / disabled / block-kernel override;
-            # 1 = the flash override, every window): operators
-            # correlating a step-time knee at a window boundary read the
-            # value the compiled ladder baked in — snapshotted at
-            # construction and at warmup, NOT the live env (the toggle
-            # is runtime-flippable; traced programs are not).
-            out["paged_flash_min_w"] = self._paged_flash_min_w
+        out["serve_kv_free_pages"] = self._alloc.free_pages
+        out["serve_kv_total_pages"] = self.num_pages - 1
+        # The gather->flash-append promotion boundary (0 = kernel
+        # cannot engage: CPU / disabled / block-kernel override;
+        # 1 = the flash override, every window): operators
+        # correlating a step-time knee at a window boundary read the
+        # value the compiled ladder baked in — snapshotted at
+        # construction and at warmup, NOT the live env (the toggle
+        # is runtime-flippable; traced programs are not).
+        out["paged_flash_min_w"] = self._paged_flash_min_w
         return out
 
     def _log_kernels(self) -> None:
@@ -3550,11 +3348,10 @@ class BatchScheduler:
         if self._quant_mode:
             off[f"qmm-{self._quant_mode}"] = (
                 None if kernel_wanted() else why_off or "forced to XLA")
-        if self.kv_mode == "paged":
-            off["flash-append"] = (
-                None if self._paged_flash_min_w > 0 else
-                flash_append_blocked(sharded, self.config.head_dim)
-                or "disabled by PAGED_APPEND_FLASH_MIN_W/PAGED_APPEND_IMPL")
+        off["flash-append"] = (
+            None if self._paged_flash_min_w > 0 else
+            flash_append_blocked(sharded, self.config.head_dim)
+            or "disabled by PAGED_APPEND_FLASH_MIN_W/PAGED_APPEND_IMPL")
         log.info("kernels on %s: %s; XLA instead of: %s; flash-append "
                  "min_w %d; pallas interpret %s", platform(),
                  ", ".join(k for k, why in off.items() if not why) or "none",
@@ -3581,7 +3378,7 @@ class BatchScheduler:
                                      config.head_dim)
 
     def _try_reserve(self, slot: _Slot) -> bool:
-        """Paged mode: claim the slot's page budget (prompt + generation
+        """Claim the slot's page budget (prompt + generation
         room + the next-write slot). All-or-nothing; False = pool pressure,
         the request waits."""
         need = self._alloc.pages_for(len(slot.prompt_ids) + slot.max_new + 1)
@@ -3666,7 +3463,7 @@ class BatchScheduler:
                 continue
             if _classify(s):
                 continue
-            if self.kv_mode == "paged" and s.pages is None:
+            if s.pages is None:
                 # A carried wake remnant whose session vanished since
                 # last round: it needs a cold reservation like any
                 # fresh request (same FIFO discipline vs waiters).
@@ -3677,7 +3474,7 @@ class BatchScheduler:
             else:
                 pending.append(s)
         self._admit_carry = []
-        if self.kv_mode == "paged" and self._waiting:
+        if self._waiting:
             still: list[_Slot] = []
             for s in self._waiting:
                 if s.cancelled.is_set():
@@ -3702,19 +3499,14 @@ class BatchScheduler:
             for s in fresh:
                 if _classify(s):
                     continue
-                if self.kv_mode == "paged":
-                    # Strict FIFO vs page-starved waiters: once anything is
-                    # waiting for pages, fresh requests queue *behind* it —
-                    # a stream of small requests must not bypass (and so
-                    # indefinitely starve) a large waiter. _wait_or_fail
-                    # still fail-fasts never-fits requests, which must not
-                    # become permanent head-of-line blockers.
-                    if self._waiting:
-                        self._wait_or_fail(s)
-                    elif self._try_reserve(s):
-                        pending.append(s)
-                    else:
-                        self._wait_or_fail(s)
+                # Strict FIFO vs page-starved waiters: once anything is
+                # waiting for pages, fresh requests queue *behind* it —
+                # a stream of small requests must not bypass (and so
+                # indefinitely starve) a large waiter. _wait_or_fail
+                # still fail-fasts never-fits requests, which must not
+                # become permanent head-of-line blockers.
+                if self._waiting or not self._try_reserve(s):
+                    self._wait_or_fail(s)
                 else:
                     pending.append(s)
         if not pending and not wakes:
@@ -3752,31 +3544,22 @@ class BatchScheduler:
             except Exception:   # noqa: BLE001
                 log.exception("wake admission failed for %d request(s)",
                               len(batch))
-                for s in batch:
+                # Same wholesale-abort rationale as the chunk path:
+                # tables/pages may be half-installed.
+                for s in (batch + carry_tail
+                          + [x for S2 in wake_keys[wi + 1:]
+                             for x in wakes[S2]] + pending):
                     s.fail("internal error: admission failed")
-                if self.kv_mode == "paged":
-                    # Same wholesale-abort rationale as the chunk path:
-                    # tables/pages may be half-installed.
-                    for s in (carry_tail
-                              + [x for S2 in wake_keys[wi + 1:]
-                                 for x in wakes[S2]] + pending):
-                        s.fail("internal error: admission failed")
-                    self._fail_all_and_reset()
-                    return
-                free.extend(rows)
-                self._recover_cache()
-                continue
+                self._fail_all_and_reset()
+                return
             one_wake = True
             free.extend(unused)
             for s in demoted:
                 # Session vanished between match and claim (replaced /
                 # evicted / taken by an earlier duplicate) or its page
                 # reservation failed: cold-admit this same round.
-                if self.kv_mode == "paged":
-                    if self._waiting or not self._try_reserve(s):
-                        self._wait_or_fail(s)
-                    else:
-                        pending.append(s)
+                if self._waiting or not self._try_reserve(s):
+                    self._wait_or_fail(s)
                 else:
                     pending.append(s)
         if carry_tail or (had_active and one_wake):
@@ -3868,24 +3651,17 @@ class BatchScheduler:
                     log.exception("admission failed for %d request(s)",
                                   len(chunk))
                     self._prefill_carry = None
-                    for s in chunk:
+                    # The chunk's pages may already be installed in row
+                    # tables (the failure can postdate the device call),
+                    # and every not-yet-admitted slot holds pages from
+                    # the allocator about to be reset — abort the whole
+                    # round wholesale rather than risk freeing pages a
+                    # live table still points at / double-allocating.
+                    for s in chunk + group + [x for _, g in groups[gi + 1:]
+                                              for x in g]:
                         s.fail("internal error: admission failed")
-                    if self.kv_mode == "paged":
-                        # The chunk's pages may already be installed in row
-                        # tables (the failure can postdate the device call),
-                        # and every not-yet-admitted slot holds pages from
-                        # the allocator about to be reset — abort the whole
-                        # round wholesale rather than risk freeing pages a
-                        # live table still points at / double-allocating.
-                        for s in group + [x for _, g in groups[gi + 1:]
-                                          for x in g]:
-                            s.fail("internal error: admission failed")
-                        self._fail_all_and_reset()
-                        return
-                    for r in rows:
-                        self._slots[r] = None
-                        free.append(r)
-                    self._recover_cache()
+                    self._fail_all_and_reset()
+                    return
 
     def _admit_chunk(self, chunk: list[_Slot], rows: list[int], S: int,
                      R: int,
@@ -3940,29 +3716,17 @@ class BatchScheduler:
             # compiled on the worker thread instead of here.
             prog = self._admit_prefix_aot.get((P, S, R),
                                               self._admit_prefix_j)
-            if self.kv_mode == "paged":
-                (toks_dev, self._cache, self._keys, self._next_dev,
-                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                 self._ring_dev, self._rps_dev) = \
-                    prog(
-                        self._params, prefix.k, prefix.v,
-                        jnp.asarray(tokens), jnp.asarray(ints),
-                        jnp.asarray(floats), jnp.asarray(rings),
-                        jnp.asarray(tables), self._cache, self._keys,
-                        self._next_dev, self._temps_dev, self._top_ks_dev,
-                        self._top_ps_dev, self._ring_dev, self._rps_dev)
-            else:
-                (toks_dev, self._cache, self._keys, self._next_dev,
-                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                 self._ring_dev, self._rps_dev) = \
-                    prog(
-                        self._params, prefix.k, prefix.v,
-                        jnp.asarray(tokens), jnp.asarray(ints),
-                        jnp.asarray(floats), jnp.asarray(rings),
-                        self._cache, self._keys, self._next_dev,
-                        self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                        self._ring_dev, self._rps_dev)
-        elif self.kv_mode == "paged":
+            (toks_dev, self._cache, self._keys, self._next_dev,
+             self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+             self._ring_dev, self._rps_dev) = \
+                prog(
+                    self._params, prefix.k, prefix.v,
+                    jnp.asarray(tokens), jnp.asarray(ints),
+                    jnp.asarray(floats), jnp.asarray(rings),
+                    jnp.asarray(tables), self._cache, self._keys,
+                    self._next_dev, self._temps_dev, self._top_ks_dev,
+                    self._top_ps_dev, self._ring_dev, self._rps_dev)
+        else:
             # Padding entries keep an all-zero table: their prefill writes
             # land in garbage page 0 (their table/length installs are
             # dropped via the row sentinel).
@@ -3977,17 +3741,6 @@ class BatchScheduler:
                     self._keys, self._next_dev, self._temps_dev,
                     self._top_ks_dev, self._top_ps_dev, self._ring_dev,
                     self._rps_dev)
-        else:
-            (toks_dev, self._cache, self._keys, self._next_dev,
-             self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-             self._ring_dev, self._rps_dev) = \
-                self._admit_j(
-                    self._params, jnp.asarray(tokens),
-                    jnp.asarray(ints[:4]),
-                    jnp.asarray(floats), jnp.asarray(rings), self._cache,
-                    self._keys, self._next_dev, self._temps_dev,
-                    self._top_ks_dev, self._top_ps_dev, self._ring_dev,
-                    self._rps_dev)
         self._install_admitted(chunk, rows, toks_dev)
 
     def _admit_host_arrays(self, chunk: list[_Slot], rows: list[int],
@@ -3997,7 +3750,7 @@ class BatchScheduler:
         the single-shot programs and the chunked-prefill carry, so the
         two admission paths cannot drift. Returns (tokens [R,S], ints
         [5,R] = lens/rows/seeds/top_k/total-lens, floats [3,R], rings
-        [R,_RING], tables [R,mppr] or None); the non-prefix single-shot
+        [R,_RING], tables [R,mppr]); the non-prefix single-shot
         programs consume ``ints[:4]``.
 
         The requests take the FIRST entries and the dummy entries
@@ -4031,11 +3784,9 @@ class BatchScheduler:
                 start = max(0, len(slot.prompt_ids) - _RING)
                 for p_i in range(start, len(slot.prompt_ids)):
                     rings[r, p_i % _RING] = slot.prompt_ids[p_i]
-        tables = None
-        if self.kv_mode == "paged":
-            tables = np.zeros((R, self._cache.max_pages_per_row), np.int32)
-            for r, slot in enumerate(chunk):
-                tables[r, : len(slot.pages)] = slot.pages
+        tables = np.zeros((R, self._cache.max_pages_per_row), np.int32)
+        for r, slot in enumerate(chunk):
+            tables[r, : len(slot.pages)] = slot.pages
         return tokens, ints, floats, rings, tables
 
     def _install_admitted(self, chunk: list[_Slot], rows: list[int],
@@ -4061,10 +3812,10 @@ class BatchScheduler:
             # prompt in one batched dispatch — async, no readback, so it
             # overlaps the first-token streaming below and whatever target
             # work the loop does next (the PR 3 chunk ladder included).
-            # Gated on the runtime-togglable spec_k (bench A/B phases flip
-            # it): with speculation off, no drafter dispatches may run —
-            # sources late-bind at the next draft_batch instead (the model
-            # drafter's catch-up feed covers rows admitted while off).
+            # Gated on the runtime-togglable spec_k: with speculation
+            # off, no drafter dispatches may run — sources late-bind at
+            # the next draft_batch instead (the model drafter's catch-up
+            # feed covers rows admitted while off).
             if self.spec_k and self._sources and chunk:
                 ctxs = {row: slot.prompt_ids
                         for slot, row in zip(chunk, rows)}
@@ -4181,36 +3932,25 @@ class BatchScheduler:
             prog = self._prefill_chunk_for(P0, S, off, C)
         t = jnp.asarray(np.ascontiguousarray(tokens))
         ij = jnp.asarray(ints)
-        paged = self.kv_mode == "paged"
+        tb = jnp.asarray(tables)
         if first:
-            args = [self._params]
-            if P0:
-                args += [prefix.k, prefix.v]
-            args += [t, ij]
-            if paged:
-                args.append(jnp.asarray(tables))
-            args.append(self._cache)
-            kv, logits, self._cache = prog(*args)
+            pre = (prefix.k, prefix.v) if P0 else ()
+            kv, logits, self._cache = prog(self._params, *pre, t, ij, tb,
+                                           self._cache)
             self._chunk_shapes_run.add(shape_key)
             return kv, logits, None
         if not final:
-            args = [self._params, t, ij, kv, logits]
-            if paged:
-                args.append(jnp.asarray(tables))
-            args.append(self._cache)
-            kv, logits, self._cache = prog(*args)
+            kv, logits, self._cache = prog(self._params, t, ij, kv, logits,
+                                           tb, self._cache)
             self._chunk_shapes_run.add(shape_key)
             return kv, logits, None
-        args = [self._params, t, ij, jnp.asarray(floats),
-                jnp.asarray(rings), kv, logits]
-        if paged:
-            args.append(jnp.asarray(tables))
-        args += [self._cache, self._keys, self._next_dev, self._temps_dev,
-                 self._top_ks_dev, self._top_ps_dev, self._ring_dev,
-                 self._rps_dev]
         (toks_dev, self._cache, self._keys, self._next_dev,
          self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-         self._ring_dev, self._rps_dev) = prog(*args)
+         self._ring_dev, self._rps_dev) = prog(
+            self._params, t, ij, jnp.asarray(floats), jnp.asarray(rings),
+            kv, logits, tb, self._cache, self._keys, self._next_dev,
+            self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+            self._ring_dev, self._rps_dev)
         self._chunk_shapes_run.add(shape_key)
         return None, None, toks_dev
 
@@ -4686,8 +4426,8 @@ class BatchScheduler:
             slot.finish()
             return False
         # Context full: the next decode step would write slot ctx_len,
-        # which must stay < the slot's budget — max_seq for dense, the
-        # admitted page budget for paged (host mirror avoids a device sync).
+        # which must stay < the slot's admitted page budget (host mirror
+        # avoids a device sync).
         if slot.ctx_len + 1 >= slot.ctx_budget:
             self._flush_text(slot, final=True)
             slot.finish()
@@ -4737,24 +4477,9 @@ class BatchScheduler:
             slot.streamed = emit_to
         return False
 
-    def _recover_cache(self) -> bool:
-        """A failed donated call may have consumed the KV cache (or key /
-        next-token) buffers; without this, every later admission dies on
-        'Array has been deleted' while the engine appears up. If any buffer
-        is gone, fail in-flight requests (their context lives in the dead
-        buffer) and start fresh. Returns True when a reset happened."""
-        if not (self._cache.k.is_deleted() or self._next_dev.is_deleted()
-                or self._keys.is_deleted() or self._temps_dev.is_deleted()):
-            return False
-        log.warning("device state was donated to a failed call; recreating "
-                    "and failing %d in-flight requests",
-                    sum(s is not None for s in self._slots))
-        self._fail_all_and_reset()
-        return True
-
     def _fail_all_and_reset(self) -> None:
         """Error-path recovery: fail every in-flight request and rebuild the
-        device state (and, in paged mode, the page allocator) from scratch.
+        device state and the page allocator from scratch.
         Wholesale by design — selective recovery here risks leaking pages
         (slots cleared without ``_alloc.free``) or leaving a stale row
         table aimed at pages the allocator has handed to a new request,
@@ -4836,46 +4561,27 @@ class BatchScheduler:
         if len(toks) < slot.ctx_len:
             return False          # host mirror out of sync — don't trust
         from .kv_tier import SessionKV
-        if self.kv_mode == "paged":
-            if not slot.pages:
-                return False
-            keep = min(len(slot.pages),
-                       self._alloc.pages_for(slot.ctx_len))
-            kept, extra = slot.pages[:keep], slot.pages[keep:]
-            try:
-                self._cache = self._zero_row_j(
-                    self._cache, jnp.asarray(row, jnp.int32))
-            except Exception:   # noqa: BLE001 — same contract as _release
-                log.exception("row-table zero failed; resetting")
-                self._fail_all_and_reset()
-                return True
-            if extra:
-                self._alloc.free(extra)
-            slot.pages = None
-            old = self._tier.take(key)
-            if old is not None:
-                self._recycle_session(old)
-            self._tier.insert(SessionKV(key=key, tokens=tuple(toks),
-                                        length=slot.ctx_len, pages=kept))
-            self._tier_enforce()
+        if not slot.pages:
+            return False
+        keep = min(len(slot.pages), self._alloc.pages_for(slot.ctx_len))
+        kept, extra = slot.pages[:keep], slot.pages[keep:]
+        try:
+            self._cache = self._zero_row_j(
+                self._cache, jnp.asarray(row, jnp.int32))
+        except Exception:   # noqa: BLE001 — same contract as _release
+            log.exception("row-table zero failed; resetting")
+            self._fail_all_and_reset()
             return True
-        # Dense rows have no pool residency to retain: park the row's
-        # KV to host immediately (one slice-gather dispatch + readback).
-        W = _bucket(slot.ctx_len, self.max_seq)
-        k, v = self._extract_row_for(W)(self._cache,
-                                        jnp.asarray(row, jnp.int32))
-        with self._phase("readback"):
-            # graftcheck: sync-ok the park IS the host copy — one readback per finished session
-            payload = (np.asarray(k), np.asarray(v))
+        if extra:
+            self._alloc.free(extra)
+        slot.pages = None
         old = self._tier.take(key)
         if old is not None:
             self._recycle_session(old)
-        self._tier.insert(SessionKV(
-            key=key, tokens=tuple(toks), length=slot.ctx_len,
-            host=(payload, W), nbytes=sum(p.nbytes for p in payload)))
-        self._tier.note_parked()
+        self._tier.insert(SessionKV(key=key, tokens=tuple(toks),
+                                    length=slot.ctx_len, pages=kept))
         self._tier_enforce()
-        return False
+        return True
 
     def _recycle_session(self, sess) -> None:
         """Return a replaced session's resident pages to the allocator
@@ -4886,9 +4592,9 @@ class BatchScheduler:
 
     # graftcheck: runs-on _loop
     def _park_session(self, sess) -> None:
-        """Demote one resident session to a host-RAM copy (paged mode):
-        ONE gather dispatch of the raw pool words (int8 + head-major
-        scales included), one readback, pages back to the allocator.
+        """Demote one resident session to a host-RAM copy: ONE gather
+        dispatch of the raw pool words (int8 + head-major scales
+        included), one readback, pages back to the allocator.
         Wake re-uploads the same bits, so a parked-then-resumed greedy
         stream is byte-identical to one that never left HBM."""
         sess = self._tier.take(sess.key)
@@ -4942,7 +4648,7 @@ class BatchScheduler:
         """Idle parking: at most one park per ~250 ms loop pass (each
         is a gather dispatch + readback — a bounded stall, amortised
         the way promotion builds are)."""
-        if self._tier is None or self.kv_mode != "paged":
+        if self._tier is None:
             return
         now = time.monotonic()
         if now - self._last_tier_sweep < 0.25:
@@ -5000,7 +4706,7 @@ class BatchScheduler:
     # graftcheck: runs-on _loop
     def _wake_install_kv(self, slot: _Slot, row: int, sess,
                          tables: "np.ndarray") -> bool:
-        """Paged wake KV placement: reserve the row's full page budget,
+        """Wake KV placement: reserve the row's full page budget,
         scatter a parked payload into the first pages (one dispatch —
         the prefetched device arrays land here), and point the host
         table at session pages + growth pages in logical order. False =
@@ -5094,34 +4800,19 @@ class BatchScheduler:
                 demoted.append(slot)
                 unused.append(row)
             return demoted, unused
-        mppr = (self._cache.max_pages_per_row
-                if self.kv_mode == "paged" else 0)
         tokens = np.zeros((B, S), np.int32)
         ints = np.zeros((4, B), np.int32)
         floats = np.zeros((3, B), np.float32)
         floats[1] = 1.0
         floats[2] = 1.0
         rings = np.full((B, _RING), self.config.vocab_size, np.int32)
-        tables = (np.zeros((B, mppr), np.int32)
-                  if self.kv_mode == "paged" else None)
+        tables = np.zeros((B, self._cache.max_pages_per_row), np.int32)
         live: list[tuple[_Slot, int]] = []
         for slot, row, sess in claimed:
-            if self.kv_mode == "paged":
-                if not self._wake_install_kv(slot, row, sess, tables):
-                    demoted.append(slot)
-                    unused.append(row)
-                    continue
-            else:
-                arrays, Wb = sess.host
-                dev = None
-                if slot.wake_dev is not None and slot.wake_dev[0] is sess:
-                    dev = slot.wake_dev[1]
-                slot.wake_dev = None
-                if dev is None:
-                    dev = tuple(jnp.asarray(a) for a in arrays)
-                self._cache = self._inject_row_for(Wb)(
-                    self._cache, jnp.asarray(row, jnp.int32),
-                    dev[0], dev[1])
+            if not self._wake_install_kv(slot, row, sess, tables):
+                demoted.append(slot)
+                unused.append(row)
+                continue
             suffix = slot.prompt_ids[sess.length:]
             o = slot.req.options
             tokens[row, : len(suffix)] = suffix
@@ -5141,17 +4832,14 @@ class BatchScheduler:
         self._n_admit_rows_padded += B
         self._n_prefill_tokens += sum(int(ints[0, row]) for _, row in live)
         self._n_prefill_padded += B * S
-        prog = self._wake_for(w, S)
-        args = [self._params, jnp.asarray(tokens), jnp.asarray(ints),
-                jnp.asarray(floats), jnp.asarray(rings)]
-        if self.kv_mode == "paged":
-            args.append(jnp.asarray(tables))
-        args += [self._cache, self._keys, self._next_dev,
-                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                 self._ring_dev, self._rps_dev]
         (toks_dev, self._cache, self._keys, self._next_dev,
          self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-         self._ring_dev, self._rps_dev) = prog(*args)
+         self._ring_dev, self._rps_dev) = self._wake_for(w, S)(
+            self._params, jnp.asarray(tokens), jnp.asarray(ints),
+            jnp.asarray(floats), jnp.asarray(rings), jnp.asarray(tables),
+            self._cache, self._keys, self._next_dev, self._temps_dev,
+            self._top_ks_dev, self._top_ps_dev, self._ring_dev,
+            self._rps_dev)
         self._wake_shapes_run.add((w, S))
         with self._phase("readback", rows=len(live)):
             # graftcheck: sync-ok B int32 first tokens — wake TTFT depends on it
@@ -5196,8 +4884,8 @@ class BatchScheduler:
 
     def _release(self, row: int) -> None:
         """Free a row (finish() has already been queued where a consumer is
-        still listening; cancelled consumers are gone). Paged mode zeroes
-        the row's page table on device BEFORE returning its pages to the
+        still listening; cancelled consumers are gone). The row's page
+        table is zeroed on device BEFORE returning its pages to the
         allocator — a stale parked row keeps scattering per-step garbage,
         which must land in the garbage page, never a re-allocated one."""
         slot = self._slots[row]
@@ -5217,7 +4905,7 @@ class BatchScheduler:
         if slot is not None and self._tier is not None:
             if self._retain_session(slot, row):
                 return
-        if self.kv_mode == "paged" and slot is not None and slot.pages:
+        if slot is not None and slot.pages:
             try:
                 self._cache = self._zero_row_j(
                     self._cache, jnp.asarray(row, jnp.int32))

@@ -40,8 +40,7 @@ def env_opt(key: str, default: str) -> str:
 
     The one sanctioned exception to ``env_or``'s empty-is-unset contract,
     for optional-feature flags whose documented OFF spelling is the empty
-    string (``BENCH_QUANT=`` = plain bf16, ``BENCH_KV_QUANT=`` = bf16 KV
-    pool). graftcheck's env-hygiene analyzer recognizes it alongside the
+    string. graftcheck's env-hygiene analyzer recognizes it alongside the
     typed helpers.
     """
     return os.environ.get(key, default)

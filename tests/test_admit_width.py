@@ -200,13 +200,16 @@ def test_a_group_of_long_prompts_shares_one_wide_ladder():
                          ids=["dense", "moe"])
 @pytest.mark.parametrize("prompt", [SHORT, LONG, HEAD + LONG],
                          ids=["single-shot", "ladder", "prefix-ladder"])
+@pytest.mark.parametrize("kv_quant", [False, True],
+                         ids=["paged", "paged-int8"])
 def test_stream_at_one_row_equals_stream_padded_to_eight(family, config,
-                                                         prompt):
+                                                         prompt, kv_quant):
     params = (PARAMS if config is CFG else
               family.init_params(config, jax.random.PRNGKey(0),
                                  dtype=jnp.float32))
-    one = _scheduler(params, config, prefix_cache=True)
-    eight = _scheduler(params, config, prefix_cache=True, admit_chunk=8)
+    one = _scheduler(params, config, prefix_cache=True, kv_quant=kv_quant)
+    eight = _scheduler(params, config, prefix_cache=True, admit_chunk=8,
+                       kv_quant=kv_quant)
     try:
         for s in (one, eight):
             assert s.register_prefix(HEAD) > 0
@@ -247,7 +250,7 @@ def test_requests_come_before_the_dummy_entries():
     """A routed MLP's capacity buckets fill in entry order, so the
     requests must come first: ahead of them, the dummy entries (all
     token 0, all routed alike) took the buckets of their two experts."""
-    sched = _scheduler(kv_mode="paged", page_size=16)
+    sched = _scheduler(page_size=16)
     try:
         slots = []
         for i, p in enumerate((SHORT, SHORT + "!")):
@@ -277,8 +280,7 @@ def _jobs(shapes, C) -> int:
                for _, S, _, _ in shapes)
 
 
-@pytest.mark.parametrize("kv_mode", ["paged", "dense"])
-def test_benchmark_warmup_is_no_more_programs_than_the_old_ladder(kv_mode):
+def test_benchmark_warmup_is_no_more_programs_than_the_old_ladder():
     """The benchmark's warm-up: buckets 128..2048, 32 slots, chunk 256,
     the template's 88-token prefix. {8, 32} was 36 admission programs
     and 8 grain pre-warms (PERF.md §6, PR 24)."""
@@ -286,7 +288,7 @@ def test_benchmark_warmup_is_no_more_programs_than_the_old_ladder(kv_mode):
     params = llama.init_params(config, jax.random.PRNGKey(0),
                                dtype=jnp.float32)
     sched = _scheduler(params, config, num_slots=32, max_seq=2048,
-                       prefill_chunk=256, prefix_cache=True, kv_mode=kv_mode)
+                       prefill_chunk=256, prefix_cache=True)
     try:
         shapes = sched._admission_shapes([128, 256, 512, 1024, 2048], {88})
         real = [s for s in shapes if not s[3]]
@@ -315,8 +317,7 @@ def test_after_warmup_no_admission_the_chooser_can_pick_compiles(monkeypatch):
     chunk 32, the wide program capped at 256 tokens, a registered
     prefix."""
     monkeypatch.setattr(sched_mod, "_ADMIT_WIDE_TOKENS", 256)
-    sched = _scheduler(num_slots=8, prefix_cache=True, kv_mode="paged",
-                       page_size=16)
+    sched = _scheduler(num_slots=8, prefix_cache=True, page_size=16)
     buckets = (16, 32, 64, 128, 256)
     try:
         sched.warmup(prompt_buckets=buckets, prefix_texts=(HEAD,))

@@ -1,6 +1,6 @@
 """Bring-up contracts that need no device: where the compile cache
-lands, and that the chip smoke fails — fast, naming the device — on a
-machine with no chip."""
+lands, which KV layout a boot gets, and that the chip smoke fails —
+fast, naming the device — on a machine with no chip."""
 
 import json
 import os
@@ -51,6 +51,35 @@ def test_cache_dir_that_cannot_be_created_is_an_error(
 
 
 @pytest.mark.skipif(count_chips() > 0, reason="this host has a TPU chip")
+@pytest.mark.parametrize("serve_kv", [None, "paged", "dense"])
+def test_boot_serves_the_paged_pool_and_refuses_any_other_layout(
+        serve_kv, monkeypatch):
+    """SERVE_KV is no longer an option: unset and ``paged`` (which
+    deployments still export) boot the same pool, anything else ends
+    the boot with the reason."""
+    from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache
+    from p2p_llm_chat_tpu.serve.engine import build_engine_from_env
+    monkeypatch.delenv("SERVE_KV", raising=False)
+    if serve_kv is not None:
+        monkeypatch.setenv("SERVE_KV", serve_kv)
+    for key, value in (("MODEL_CONFIG", "tiny"), ("SERVE_SLOTS", "2"),
+                       ("SERVE_MAX_SEQ", "128"), ("SERVE_PAGE_SIZE", "16"),
+                       ("SERVE_WARMUP", "0")):
+        monkeypatch.setenv(key, value)
+    if serve_kv == "dense":
+        with pytest.raises(SystemExit, match="dense serving was removed "
+                                             "in PR 28"):
+            build_engine_from_env()
+        return
+    eng = build_engine_from_env()
+    try:
+        sched = eng.scheduler
+        assert isinstance(sched._cache, PagedKVCache)
+        assert sched.num_pages == 2 * (128 // 16) + 1
+    finally:
+        eng.stop()
+
+
 def test_chip_smoke_fails_fast_without_a_chip():
     """The smoke forces its server onto the TPU (it must not inherit
     this suite's JAX_PLATFORMS=cpu and pass on the CPU): with no chip

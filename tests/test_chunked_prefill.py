@@ -18,7 +18,7 @@ Three layers of pinning, mirroring tests/test_fused_decode.py:
   token's quantization never depends on which dispatch wrote it);
 - scheduler-level: the same requests through a chunked
   (``prefill_chunk=32``) and a single-shot (``prefill_chunk=0``)
-  scheduler produce identical streams across dense/paged x int8-KV x
+  scheduler produce identical streams across page sizes x int8-KV x
   prefix-cache hit and miss, the chunked scheduler actually chunked
   (``prefill_chunks_total`` advances), and warmup pre-compiles the
   whole continuation ladder so no chunk program compiles mid-serving.
@@ -230,13 +230,13 @@ OPTS = (GenerateOptions(max_tokens=8),
         GenerateOptions(max_tokens=8, temperature=0.8, top_p=0.9, seed=5))
 
 SCHED_MODES = {
-    "dense": {},
-    "paged": {"kv_mode": "paged", "page_size": 16},
-    "paged-int8": {"kv_mode": "paged", "page_size": 16, "kv_quant": True},
+    "paged": {"page_size": 16},
+    "paged-int8": {"page_size": 16, "kv_quant": True},
     # page_size > chunk: the second chunk's splice starts MID-page (the
     # per-token scatter path) on the live scheduler, not just in the
     # ops-level unit test.
-    "paged-midpage": {"kv_mode": "paged", "page_size": 64},
+    "paged-midpage": {"page_size": 64},
+    "paged-midpage-int8": {"page_size": 64, "kv_quant": True},
 }
 
 
@@ -261,7 +261,7 @@ def test_scheduler_stream_identical_chunked_vs_single_shot(mode):
         single.stop()
 
 
-@pytest.mark.parametrize("mode", ["dense", "paged"])
+@pytest.mark.parametrize("mode", ["paged", "paged-int8"])
 def test_scheduler_prefix_hit_and_miss_parity(mode):
     """Prefix-cache hit (suffix-continuation chunks resume at the
     prefix's non-power-of-two offset) and miss both stream identically

@@ -77,7 +77,7 @@ def run(engine, prompt, session="", max_tokens=8, ctx=()):
 
 def make_engine(slots=2, buckets=(64, 128), prefill_chunk=256):
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=slots, max_seq=256,
-                    kv_mode="paged", page_size=64, kv_quant=True,
+                    page_size=64, kv_quant=True,
                     kv_host_gb=1.0, kv_idle_s=1e9,
                     prefill_chunk=prefill_chunk)
     eng.warmup(buckets=buckets)
@@ -464,7 +464,6 @@ def _spawn_replica(port: int, cls: str) -> subprocess.Popen:
         LLM_MODEL="tiny",
         SERVE_MAX_SEQ="128",
         SERVE_SLOTS="2",
-        SERVE_KV="paged",
         SERVE_PAGE_SIZE="16",
         SERVE_KV_HOST_GB="1",
         SERVE_KV_IDLE_S="3600",

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from p2p_llm_chat_tpu.models import llama
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.models.llama import KVCache
+from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
 from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
                                             RequestStats)
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
@@ -31,32 +32,56 @@ TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
 STOP_IDS = set(CFG.eos_token_ids) | {TOK.eos_id}
 
 
-def oracle(prompt: str, max_new: int, max_seq: int = 128) -> str:
-    """Solo batch=1 greedy loop with the engine's stop rule."""
+def oracle(prompt: str, max_new: int, max_seq: int = 128,
+           kv_quant: bool = False) -> str:
+    """Solo batch=1 greedy loop with the engine's stop rule, on the
+    model layer's plain cache. ``kv_quant``: the same loop on a one-row
+    int8 pool (ops/paged_kv.py), the exact reference of an engine whose
+    pool is int8: the rounding is per (slot, kv-head), so it does not
+    depend on what else is in the batch."""
     ids = TOK.encode(prompt, add_bos=True)
-    cache = KVCache.create(CFG, 1, max_seq, jnp.float32)
+    n = len(ids)
+    cache = KVCache.create(CFG, 1, n if kv_quant else max_seq, jnp.float32)
     logits, cache = llama.prefill(PARAMS, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([len(ids)]), cache)
-    last = np.asarray(logits[0, len(ids) - 1])
+                                  jnp.asarray([n]), cache)
+    if kv_quant:
+        ps = 16
+        mppr = max_seq // ps
+        pool = PagedKVCache.create(CFG, 1, mppr + 1, ps,
+                                   max_pages_per_row=mppr, quantized=True)
+        cache = write_prefill_batch(
+            pool, cache.k, cache.v, jnp.arange(1), jnp.asarray([n]),
+            1 + jnp.arange(mppr, dtype=jnp.int32)[None, :])
+    last = np.asarray(logits[0, n - 1])
     out = []
     for _ in range(max_new):
         t = int(last.argmax())
         if t in STOP_IDS:
             break
         out.append(t)
-        lg, cache = llama.decode_step(PARAMS, CFG, jnp.asarray([[t]]), cache)
+        if kv_quant:
+            lg, cache = llama.decode_step_paged(
+                PARAMS, CFG, jnp.asarray([[t]]), cache, pages=mppr)
+        else:
+            lg, cache = llama.decode_step(PARAMS, CFG, jnp.asarray([[t]]),
+                                          cache)
         last = np.asarray(lg[0, 0])
     return TOK.decode(out)
 
 
-@pytest.fixture(scope="module", params=["dense", "paged"])
+@pytest.fixture(scope="module", params=["paged", "paged-int8"])
 def engine(request):
-    """Every oracle test runs against both KV backends: the dense cache
-    and the paged pool + Pallas kernel (interpret mode on CPU)."""
+    """Every oracle test runs against the pool as floats and as int8
+    (Pallas kernels in interpret mode on CPU)."""
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=128,
-                    kv_mode=request.param, page_size=16)
+                    page_size=16, kv_quant=request.param == "paged-int8")
     yield eng
     eng.stop()
+
+
+def want(engine, prompt: str, max_new: int) -> str:
+    """The oracle of the leg ``engine`` is."""
+    return oracle(prompt, max_new, kv_quant=engine.scheduler.kv_quant)
 
 
 def run(engine, prompt, max_tokens=12, **opts):
@@ -69,7 +94,7 @@ def run(engine, prompt, max_tokens=12, **opts):
 
 def test_single_request_matches_oracle(engine):
     text, stats = run(engine, "hello world", max_tokens=12)
-    assert text == oracle("hello world", 12)
+    assert text == want(engine, "hello world", 12)
     assert stats.prompt_tokens == len(TOK.encode("hello world", add_bos=True))
     assert stats.ttft_s is not None and stats.total_s is not None
     assert stats.total_s >= stats.ttft_s
@@ -86,7 +111,7 @@ def test_concurrent_requests_each_match_solo_run(engine):
     admission mid-decode, and slot reuse must not change any output."""
     prompts = ["a", "bb longer prompt here", "ccc", "d d d d",
                "a completely different prompt", "short"]
-    want = {p: oracle(p, 10) for p in prompts}
+    expected = {p: want(engine, p, 10) for p in prompts}
     got = {}
     errs = []
 
@@ -103,7 +128,7 @@ def test_concurrent_requests_each_match_solo_run(engine):
     for t in threads:
         t.join(timeout=120)
     assert not errs
-    assert got == want
+    assert got == expected
 
 
 def test_max_tokens_respected(engine):
@@ -139,7 +164,7 @@ def test_cancellation_frees_slot_and_others_complete(engine):
     it.close()        # client disconnects
     # Engine still serves fresh requests correctly afterwards.
     text, _ = run(engine, "after cancel", max_tokens=8)
-    assert text == oracle("after cancel", 8)
+    assert text == want(engine, "after cancel", 8)
 
 
 @pytest.mark.slow   # ~30 s/mode (decode to context-full); ci.sh full
@@ -149,7 +174,7 @@ def test_num_predict_unlimited(engine):
     unlimited, stats = run(engine, "unbounded", max_tokens=-1)
     assert unlimited.startswith(limited)
     budget = 128 - 1 - len(TOK.encode("unbounded", add_bos=True))
-    assert unlimited == oracle("unbounded", budget)
+    assert unlimited == want(engine, "unbounded", budget)
 
 
 def test_stop_string_straddling_tokens_never_leaks_prefix(engine):
@@ -196,15 +221,17 @@ def test_stop_unblocks_inflight_consumers():
 
 
 def test_recovers_after_cache_buffer_loss():
-    """A failed donated call consumes the KV cache buffer; the scheduler
-    must detect the dead buffer, fail in-flight work, and keep serving."""
+    """A failed donated call consumes the KV cache buffer; the admission
+    that finds it dead fails its own request, the scheduler rebuilds the
+    device state, and the engine keeps serving."""
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=128)
     try:
         text, _ = run(eng, "before failure", max_tokens=6)
         assert text == oracle("before failure", 6)
         # Simulate a call that raised after consuming its donated input.
         eng.scheduler._cache.k.delete()
-        eng.scheduler._recover_cache()
+        with pytest.raises(RuntimeError, match="admission failed"):
+            run(eng, "lost with the buffer", max_tokens=6)
         text, _ = run(eng, "after failure", max_tokens=6)
         assert text == oracle("after failure", 6)
     finally:
@@ -224,7 +251,7 @@ def test_paged_pool_exhaustion_backpressures_then_completes():
     # 7 usable pages x 16 slots: each request needs ~2 pages, so only ~3
     # of 6 requests hold pages at once.
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=128,
-                    kv_mode="paged", page_size=16, num_pages=8)
+                    page_size=16, num_pages=8)
     try:
         prompts = [f"backpressure {i}" for i in range(6)]
         want = {p: oracle(p, 8) for p in prompts}
@@ -265,7 +292,7 @@ def test_paged_oversized_fails_fast_even_behind_waiters():
     # 3 usable pages x 16: the holder's budget (21 prompt + 26 + 1 = 48
     # tokens = 3 pages) pins the whole pool while it decodes.
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=128,
-                    kv_mode="paged", page_size=16, num_pages=4)
+                    page_size=16, num_pages=4)
     try:
         results, errors = {}, {}
 
@@ -309,7 +336,7 @@ def test_paged_oversized_request_fails_fast_not_deadlocks():
     """A request whose budget exceeds the whole pool must fail cleanly
     (surfaced error), not wait forever."""
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=128,
-                    kv_mode="paged", page_size=16, num_pages=3)
+                    page_size=16, num_pages=3)
     try:
         # prompt+generation budget needs > 2 pages (32 tokens)
         req = GenerateRequest(prompt="x" * 80,
@@ -459,7 +486,7 @@ def test_moe_full_stack_composition_matches_oracle():
         return TOK.decode(out)
 
     eng = TPUEngine(qparams, mcfg, TOK, num_slots=3, max_seq=128,
-                    kv_mode="paged", page_size=16, kv_quant=True,
+                    page_size=16, kv_quant=True,
                     spec_k=2, prefix_cache=True,
                     prefix_texts=("moe prefix ",))
     try:

@@ -66,7 +66,7 @@ def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
 
     head = "olmoe shared head, "
     eng = TPUEngine(qparams, mcfg, TOK, num_slots=8, max_seq=256,
-                    kv_mode="paged", page_size=16, kv_quant=True,
+                    page_size=16, kv_quant=True,
                     prefix_cache=True, prefix_texts=(head,),
                     decode_fuse_max=4, prefill_chunk=32)
     try:

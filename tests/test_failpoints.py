@@ -315,8 +315,7 @@ def test_readyz_default_ready_without_probe(server):
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=MAX_SEQ,
-                    kv_mode="dense")
+    eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=MAX_SEQ)
     yield eng
     eng.stop()
 

@@ -1300,7 +1300,7 @@ class TestEnvHygiene:
         fs = check(tmp_path, """
             import os
             a = os.getenv("SERVE_ADDR")
-            b = os.environ["BENCH_SLOTS"]
+            b = os.environ["SERVE_SLOTS"]
         """, select=["env"], **self._cfg(tmp_path))
         assert rules(fs).count("env-hygiene/raw-read") == 2
 
@@ -1836,7 +1836,7 @@ class TestCLI:
         # exits 0 on the shipped tree (same invocation ci.sh runs).
         proc = subprocess.run(
             [sys.executable, "-m", "tools.graftcheck",
-             "p2p_llm_chat_tpu", "bench.py", "start_all.py", "tests"],
+             "p2p_llm_chat_tpu", "start_all.py", "tests"],
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stdout + proc.stderr
 

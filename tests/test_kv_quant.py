@@ -68,7 +68,7 @@ def test_full_stack_serves_on_quantized_pool():
     """Admission + decode + spec + prefix + release all compose on the
     int8 pool; pages return after drain."""
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=128,
-                    kv_mode="paged", page_size=16, spec_k=2,
+                    page_size=16, spec_k=2,
                     kv_quant=True)
     try:
         outs = []
@@ -103,7 +103,7 @@ def test_kv_quant_rejects_non_gather_impl_at_construction(monkeypatch):
     monkeypatch.setattr(pa, "_DEFAULT_IMPL", "kernel")
     with pytest.raises(ValueError, match="gather"):
         TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=64,
-                  kv_mode="paged", page_size=16, kv_quant=True)
+                  page_size=16, kv_quant=True)
 
 
 def test_spec_composes_with_quantized_pool():
@@ -116,7 +116,7 @@ def test_spec_composes_with_quantized_pool():
     because the suite runs f32 on CPU with fixed weights.)"""
     def serve(spec_k):
         eng = TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=128,
-                        kv_mode="paged", page_size=16, spec_k=spec_k,
+                        page_size=16, spec_k=spec_k,
                         kv_quant=True)
         try:
             req = GenerateRequest(

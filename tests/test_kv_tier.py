@@ -10,7 +10,7 @@ RAM between turns.
 
 Fast legs (tier-1, wired explicitly into ci.sh fast) cover the policy
 unit tests, the ops-level raw-bits round-trip, and the paged-int8 A/B;
-the dense / bf16 / prefix-composition matrix and the eviction-pressure
+the bf16 / prefix-composition matrix and the eviction-pressure
 leg are slow-marked into ci.sh full (the tier-1 sweep brushes its 870 s
 container budget — ROADMAP note).
 """
@@ -51,10 +51,10 @@ def run(engine, prompt, session="", max_tokens=8, ctx=()):
     return "".join(engine.generate_stream(req, stats)), stats
 
 
-def make_engine(kv="paged", kv_quant=True, prefix=False, pages=None,
+def make_engine(kv_quant=True, prefix=False, pages=None,
                 host_gb=1.0, idle_s=1e9, slots=2):
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=slots, max_seq=256,
-                    kv_mode=kv, page_size=64, num_pages=pages,
+                    page_size=64, num_pages=pages,
                     prefix_cache=prefix, kv_quant=kv_quant,
                     kv_host_gb=host_gb, kv_idle_s=idle_s)
     eng.warmup(buckets=(64, 128))
@@ -255,25 +255,6 @@ def test_session_rotates_and_rewakes_across_turns():
 
 
 @pytest.mark.slow
-def test_park_wake_bit_identity_dense():
-    """Dense rows park straight to host at finish (no residency tier);
-    wake must still be deterministic and exact across two engines."""
-    outs = []
-    for _ in range(2):
-        eng = make_engine(kv="dense", kv_quant=False)
-        try:
-            t1, s1 = run(eng, PROMPT1, "d")
-            wait_for(lambda: eng.scheduler._tier.counts() == (0, 1),
-                     msg="dense park-at-finish")
-            t2, _ = run(eng, PROMPT2, "d", ctx=s1.context)
-            assert eng.scheduler.metrics_snapshot()["kv_waked_total"] == 1
-            outs.append((t1, t2))
-        finally:
-            eng.stop()
-    assert outs[0] == outs[1]
-
-
-@pytest.mark.slow
 def test_park_wake_bit_identity_paged_bf16_pool():
     """bf16 (non-quantized) pool: same A/B contract as the int8 leg."""
     a = make_engine(kv_quant=False)
@@ -300,7 +281,7 @@ def test_park_wake_composes_with_prefix_cache():
 
     def turns(park):
         eng = TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=256,
-                        kv_mode="paged", page_size=64,
+                        page_size=64,
                         prefix_cache=True, prefix_texts=(head,),
                         kv_quant=True, kv_host_gb=1.0, kv_idle_s=1e9)
         try:

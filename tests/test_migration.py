@@ -22,6 +22,7 @@ and undrains under live loadgen churn traffic with
 client-visible errors, all failpoint contracts holding.
 """
 
+import io
 import json
 import os
 import socket
@@ -69,7 +70,7 @@ def run(engine, prompt, session="", max_tokens=8, ctx=()):
 
 def make_engine(slots=2, buckets=(64, 128)):
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=slots, max_seq=256,
-                    kv_mode="paged", page_size=64, kv_quant=True,
+                    page_size=64, kv_quant=True,
                     kv_host_gb=1.0, kv_idle_s=1e9)
     eng.warmup(buckets=buckets)
     return eng
@@ -86,7 +87,24 @@ def wait_for(fn, timeout=10.0, msg="condition"):
 
 # -- wire format --------------------------------------------------------------
 
-def test_session_wire_roundtrip_paged_and_dense():
+def dense_payload(k, v, width: int, n_tokens: int) -> bytes:
+    """What a peer that still served dense rows would send: kind
+    ``dense``, two arrays, span = the row's bucket width."""
+    arrays = {}
+    for i, a in enumerate((k, v)):
+        arrays[f"a{i}"] = np.frombuffer(a.tobytes(), np.uint8)
+        arrays[f"a{i}_dtype"] = np.bytes_(str(a.dtype).encode())
+        arrays[f"a{i}_shape"] = np.asarray(a.shape, np.int64)
+    buf = io.BytesIO()
+    np.savez_compressed(
+        buf, version=np.int64(1), key=np.bytes_(b"sid:d"),
+        kind=np.bytes_(b"dense"), tokens=np.arange(n_tokens, dtype=np.int64),
+        length=np.int64(n_tokens), span=np.int64(width),
+        present=np.asarray([True, True]), **arrays)
+    return buf.getvalue()
+
+
+def test_session_wire_roundtrip_paged():
     rng = np.random.RandomState(0)
     k = rng.randint(-127, 127, size=(2, 4, 8, 6), dtype=np.int8)
     ks = rng.randn(2, 4, 8).astype(np.float32)
@@ -107,16 +125,15 @@ def test_session_wire_roundtrip_paged_and_dense():
     got = deserialize_session(serialize_session(nq))
     assert got is not None and got.host[0][2] is None
 
-    dense = SessionKV(key="sid:d", tokens=tuple(range(35)), length=35,
-                      host=((ks, ks + 1), 64), nbytes=2 * ks.nbytes)
-    got = deserialize_session(serialize_session(dense))
-    assert got is not None and got.host[1] == 64
-    assert len(got.host[0]) == 2
-
     # Untrusted input never raises, only rejects.
     assert deserialize_session(b"") is None
     assert deserialize_session(b"garbage bytes, not an npz") is None
     assert deserialize_session(serialize_session(paged)[:40]) is None
+
+
+def test_a_dense_rows_payload_is_refused_like_any_unknown_kind():
+    ks = np.zeros((2, 64, 2, 8), np.float32)
+    assert deserialize_session(dense_payload(ks, ks + 1, 64, 35)) is None
 
 
 def test_tier_export_retains_adopt_and_forget():
@@ -278,9 +295,7 @@ def test_cross_engine_migration_byte_identity():
         assert b.session_import(b"not a payload") is None
         ks = np.zeros((CFG.num_layers, 64, CFG.num_kv_heads,
                        CFG.head_dim), np.float32)
-        dense = SessionKV(key="sid:d", tokens=tuple(range(40)), length=40,
-                          host=((ks, ks), 64), nbytes=2 * ks.nbytes)
-        assert b.session_import(serialize_session(dense)) is None
+        assert b.session_import(dense_payload(ks, ks, 64, 40)) is None
         bad = np.zeros((CFG.num_layers, 2, 16, 8), np.int8)
         sc = np.zeros((CFG.num_layers, 2, 16), np.float32)
         wrong = SessionKV(key="sid:w", tokens=tuple(range(40)), length=40,
@@ -314,7 +329,6 @@ def _spawn_replica(port: int) -> subprocess.Popen:
         LLM_MODEL="tiny",
         SERVE_MAX_SEQ="128",
         SERVE_SLOTS="2",
-        SERVE_KV="paged",
         SERVE_PAGE_SIZE="16",
         SERVE_KV_HOST_GB="1",
         SERVE_KV_IDLE_S="3600",

@@ -231,7 +231,7 @@ def test_counters_rise_by_layers_x_touched_and_are_absent_on_a_dense_model():
     tok = ByteTokenizer(vocab_size=cfg.vocab_size)
     params = mixtral.init_params_quantized(cfg, jax.random.PRNGKey(4))
     eng = TPUEngine(params, cfg, tok, num_slots=4, max_seq=128,
-                    kv_mode="paged", page_size=16, kv_quant=True,
+                    page_size=16, kv_quant=True,
                     decode_fuse_max=4)
     try:
         touched, slots = ("serve_moe_decode_experts_touched_total",

@@ -192,15 +192,18 @@ def test_send_joins_parked_backlog_preserving_order():
         # Pin the backlog: the worker can't re-resolve while this is
         # armed, but /send's direct path (dir.lookup) still can — the
         # exact shape of the bug: recipient reachable, backlog parked.
-        # (Armed BEFORE the deliver fault lifts: in between, the
-        # worker's first backoff tick could drain the backlog.)
-        fp.arm("p2p.node.resolve", "raise")
-        fp.disarm("p2p.node.deliver")
+        # The two arms change under the worker's own round lock: a round
+        # that had resolved before the first arm and dialled after the
+        # second would deliver "first" and leave no backlog to join.
+        with a._flush_mu:
+            fp.arm("p2p.node.resolve", "raise")
+            fp.disarm("p2p.node.deliver")
         _, resp = http_json("POST", f"{a.http_url}/send",
                             {"to_username": "cannan", "content": "second"},
                             timeout=20.0)
         assert resp["status"] == "queued"     # joins the queue, no jump
         fp.disarm("p2p.node.resolve")
+        a._flush_outbox()      # one round by hand, not the worker's backoff
         inbox = _wait_inbox(b.http_url, 3, timeout=15.0)
         assert [m["content"] for m in inbox] == ["warmup", "first", "second"]
     finally:

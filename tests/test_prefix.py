@@ -197,13 +197,11 @@ def test_store_export_import_roundtrip_by_token_hash():
 
 # -- admission parity against the uncached oracle -----------------------------
 
-@pytest.mark.parametrize("kv", ["dense", "paged"])
-def test_registered_template_admission_matches_oracle(kv):
+def test_registered_template_admission_matches_oracle():
     """Concurrent template-prefixed requests through a warmed prefix cache
     must be oracle-exact, and must actually take the prefix path."""
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=3, max_seq=256,
-                    kv_mode=kv, page_size=16,
-                    prefix_texts=(SUGGEST_PREFIX,))
+                    page_size=16, prefix_texts=(SUGGEST_PREFIX,))
     try:
         eng.warmup(buckets=(64, 128))
         store = eng.scheduler._prefix
@@ -330,7 +328,7 @@ def test_midtraffic_warmup_does_not_perturb_live_seeded_stream(spec_k):
     which must round-trip the live rows' pending next tokens."""
     def serve_once(do_warmup: bool) -> str:
         eng = TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=256,
-                        kv_mode="paged", page_size=16, prefix_texts=(),
+                        page_size=16, prefix_texts=(),
                         spec_k=spec_k)
         try:
             req = GenerateRequest(prompt="steady stream", options=

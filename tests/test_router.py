@@ -641,7 +641,6 @@ def _spawn_replica(port: int, extra_env: dict = ()) -> subprocess.Popen:
         # what the fleet doubles, and the workload must exceed it or the
         # comparison measures HTTP overhead, not serving.
         SERVE_SLOTS="2",
-        SERVE_KV="paged",
         SERVE_PAGE_SIZE="16",
         SERVE_SPEC="2",
         SERVE_PREFIX="1",

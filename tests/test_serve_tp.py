@@ -51,14 +51,13 @@ def oracle(prompt: str, max_new: int) -> str:
     return TOK.decode(out)
 
 
-@pytest.mark.parametrize("kv", ["dense", "paged"])
-def test_tp_engine_matches_unsharded_oracle(kv):
-    """Concurrent requests through a tp=2 engine (sharded params, both KV
-    backends) must be oracle-exact — sharding is a layout, not a model."""
+def test_tp_engine_matches_unsharded_oracle():
+    """Concurrent requests through a tp=2 engine (sharded params and
+    pool) must be oracle-exact — sharding is a layout, not a model."""
     mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
     sharded = shard_params(PARAMS, llama.param_axes(CFG), mesh)
     eng = TPUEngine(sharded, CFG, TOK, num_slots=2, max_seq=128,
-                    mesh=mesh, kv_mode=kv, page_size=16)
+                    mesh=mesh, page_size=16)
     try:
         prompts = ["tensor parallel", "serving check", "third request"]
         want = {p: oracle(p, 8) for p in prompts}
@@ -133,8 +132,7 @@ def test_tp_pool_and_fused_weights_are_sharded():
     mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
     sharded = shard_params(params, llama.param_axes(cfg), mesh)
     eng = TPUEngine(sharded, cfg, ByteTokenizer(vocab_size=cfg.vocab_size),
-                    num_slots=2, max_seq=128, mesh=mesh, kv_mode="paged",
-                    page_size=16)
+                    num_slots=2, max_seq=128, mesh=mesh, page_size=16)
     try:
         sched = eng.scheduler
         # fused projections exist and shard over tp on the column axis

@@ -203,15 +203,12 @@ def test_spec_verify_forced_rejection_samples_unmodified_distribution():
 
 # -- end-to-end ---------------------------------------------------------------
 
-@pytest.mark.parametrize("kv_mode", [
-    pytest.param("dense", marks=pytest.mark.slow),   # tier-1 budget
-    "paged"])
-def test_spec_engine_greedy_matches_oracle(kv_mode):
+def test_spec_engine_greedy_matches_oracle():
     """Greedy speculative serving is bit-exact with the sequential greedy
     oracle — accepted drafts and corrections interleave invisibly — on
-    both the dense cache and the paged pool (Pallas verify path)."""
+    the paged pool (Pallas verify path)."""
     eng = TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=128, spec_k=4,
-                    kv_mode=kv_mode, page_size=16)
+                    page_size=16)
     try:
         # Prompts with internal repetition so the n-gram drafter fires.
         for prompt in ["abab abab abab", "hello hello hello world",
@@ -219,15 +216,12 @@ def test_spec_engine_greedy_matches_oracle(kv_mode):
             req = GenerateRequest(prompt=prompt,
                                   options=GenerateOptions(max_tokens=16))
             got = "".join(eng.generate_stream(req, RequestStats()))
-            assert got == greedy_oracle(prompt, 16), (kv_mode, prompt)
+            assert got == greedy_oracle(prompt, 16), prompt
     finally:
         eng.stop()
 
 
-@pytest.mark.parametrize("kv_mode", [
-    pytest.param("dense", marks=pytest.mark.slow),   # tier-1 budget
-    "paged"])
-def test_spec_engine_moe_greedy_matches_oracle(kv_mode):
+def test_spec_engine_moe_greedy_matches_oracle():
     """The MoE leg of the same bit-exactness bar (round-4 verdict #3):
     speculative serving under a mixtral engine — the n-gram drafter
     feeding mixtral.verify_step(_paged) — must match the sequential
@@ -257,13 +251,13 @@ def test_spec_engine_moe_greedy_matches_oracle(kv_mode):
         return TOK.decode(out)
 
     eng = TPUEngine(mparams, mcfg, TOK, num_slots=2, max_seq=128,
-                    spec_k=4, kv_mode=kv_mode, page_size=16)
+                    spec_k=4, page_size=16)
     try:
         for prompt in ["moe moe moe moe", "expert expert expert routing"]:
             req = GenerateRequest(prompt=prompt,
                                   options=GenerateOptions(max_tokens=16))
             got = "".join(eng.generate_stream(req, RequestStats()))
-            assert got == moe_oracle(prompt, 16), (kv_mode, prompt)
+            assert got == moe_oracle(prompt, 16), prompt
     finally:
         eng.stop()
 
@@ -353,7 +347,7 @@ def test_all_serving_features_compose():
         return TOK.decode(out)
 
     eng = TPUEngine(qparams, CFG, TOK, num_slots=2, max_seq=128,
-                    kv_mode="paged", page_size=16, spec_k=4)
+                    page_size=16, spec_k=4)
     try:
         prompt = "compose compose compose everything"
         req = GenerateRequest(prompt=prompt,

@@ -168,33 +168,23 @@ def test_quote_workload_ngram_still_first():
 
 # -- exactness: draft on vs off ----------------------------------------------
 
-@pytest.mark.parametrize("kv_mode", [
-    "dense",
-    # The paged leg re-proves the same host-side routing over a second
-    # cache backend (the drafter itself is backend-blind); tier-1 keeps
-    # the dense leg + the paged acceptance-path fast leg below, and the
-    # slow matrix covers paged rejection too.
-    pytest.param("paged", marks=pytest.mark.slow),
-])
-def test_greedy_bit_identical_draft_on_off(kv_mode):
+def test_greedy_bit_identical_draft_on_off():
     """Bit-identity with SERVE_DRAFT on vs off, on the REJECTION-heavy
     path: an uncorrelated random drafter proposes garbage every tick and
     the exact-acceptance math must discard it invisibly."""
     want = greedy_oracle(FREEFORM, PROMPT, 20)
-    off, _ = run_engine(FREEFORM, PROMPT, 20, draft=None, kv_mode=kv_mode,
-                        page_size=16)
+    off, _ = run_engine(FREEFORM, PROMPT, 20, draft=None, page_size=16)
     on, snap = run_engine(FREEFORM, PROMPT, 20, draft=(DRAFT_RAND, DCFG),
-                          kv_mode=kv_mode, page_size=16)
+                          page_size=16)
     assert off == want
     assert on == want
     assert src(snap, "serve_spec_proposed_total", "model") > 0
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("kv_mode", ["dense", "paged"])
 @pytest.mark.parametrize("prefill_chunk", [0, 64])
 @pytest.mark.parametrize("fuse", [1, 4])
-def test_spec_draft_chunked_fused_matrix(kv_mode, prefill_chunk, fuse):
+def test_spec_draft_chunked_fused_matrix(prefill_chunk, fuse):
     """The spec x chunked-prefill x fused-K interaction table with the
     model drafter live: a long no-repeat prompt admits through the chunk
     ladder (when enabled), decode ramps fused K between spec ticks, and
@@ -204,8 +194,7 @@ def test_spec_draft_chunked_fused_matrix(kv_mode, prefill_chunk, fuse):
               "remain across the city")           # ~130 tokens, chunked
     want = greedy_oracle(FREEFORM, prompt, 24)
     got, snap = run_engine(FREEFORM, prompt, 24, draft=(DRAFT_FF, DCFG),
-                           kv_mode=kv_mode, page_size=16,
-                           prefill_chunk=prefill_chunk,
+                           page_size=16, prefill_chunk=prefill_chunk,
                            decode_fuse_max=fuse)
     assert got == want
     assert src(snap, "serve_spec_accepted_total", "model") > 0
@@ -219,7 +208,7 @@ def test_spec_draft_chunked_fused_fast_leg():
               "remain across the city")
     want = greedy_oracle(FREEFORM, prompt, 24)
     got, snap = run_engine(FREEFORM, prompt, 24, draft=(DRAFT_FF, DCFG),
-                           kv_mode="paged", page_size=16,
+                           page_size=16,
                            prefill_chunk=64, decode_fuse_max=4)
     assert got == want
     assert src(snap, "serve_spec_accepted_total", "model") > 0

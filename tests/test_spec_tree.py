@@ -212,15 +212,13 @@ def test_verify_tree_logits_match_sequential_paths():
 
 # -- exactness: tree on vs off ------------------------------------------------
 
-@pytest.mark.parametrize("kv_mode,kv_quant", [
-    ("dense", False),
-    # The paged and int8 legs re-prove the same acceptance + sibling
-    # compaction over the other cache backends; tier-1 keeps the dense
-    # leg lean and the slow matrix covers the rest.
-    pytest.param("paged", False, marks=pytest.mark.slow),
-    pytest.param("paged", True, marks=pytest.mark.slow),
+@pytest.mark.parametrize("kv_quant", [
+    False,
+    # The int8 leg re-proves the same acceptance + sibling compaction
+    # over the quantized pool; tier-1 keeps the float leg.
+    pytest.param(True, marks=pytest.mark.slow),
 ])
-def test_greedy_bit_identical_tree_on_off(kv_mode, kv_quant):
+def test_greedy_bit_identical_tree_on_off(kv_quant):
     """Bit-identity with tree speculation on vs off, on a workload that
     ACCEPTS a sibling every tick (CorruptMainSource: main chain wrong at
     position 1, truth as the branch) — the accepted-sibling emit, its
@@ -228,10 +226,9 @@ def test_greedy_bit_identical_tree_on_off(kv_mode, kv_quant):
     path."""
     want = greedy_oracle(FREEFORM, PROMPT, 24)
     off, _ = run_engine(FREEFORM, PROMPT, 24, source=CorruptMainSource(4),
-                        kv_mode=kv_mode, page_size=16, kv_quant=kv_quant)
+                        page_size=16, kv_quant=kv_quant)
     on, snap = run_engine(FREEFORM, PROMPT, 24, source=CorruptMainSource(4),
-                          spec_tree_nodes=8, kv_mode=kv_mode, page_size=16,
-                          kv_quant=kv_quant)
+                          spec_tree_nodes=8, page_size=16, kv_quant=kv_quant)
     assert off == want
     assert on == want
     # Mean accepted path length 3 (root + main pos 0 + sibling) proves

@@ -502,7 +502,7 @@ def test_stall_dump_names_dispatch_iteration(tmp_path):
     params = llama.init_params(cfg, jax.random.PRNGKey(0),
                                dtype=jnp.float32)
     eng = TPUEngine(params, cfg, ByteTokenizer(vocab_size=cfg.vocab_size),
-                    num_slots=2, max_seq=128, kv_mode="dense")
+                    num_slots=2, max_seq=128)
     sched = eng.scheduler
     path = str(tmp_path / "flight.json")
     sched._flight.path = path

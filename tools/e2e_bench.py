@@ -388,7 +388,6 @@ def main() -> int:
     )
     for k, v in (
             ("SERVE_MAX_SEQ", "4096"),
-            ("SERVE_KV", "paged"),
             ("SERVE_QUANT", "int8"),
             ("SERVE_KV_QUANT", "int8"),
             # The warmup ladder MUST include the top prompt bucket: the
@@ -483,7 +482,7 @@ def main() -> int:
                           launcher=launcher)
             # Warm the serving path: compiles any admission/decode
             # program the warmup ladder missed, so the measured run sees
-            # steady-state TTFT (bench.py does the same).
+            # steady-state TTFT.
             post(f"{serve_url}/api/generate",
                  {"model": args.config, "prompt": "warm", "stream": False,
                   "options": {"num_predict": 4}}, timeout=900).read()

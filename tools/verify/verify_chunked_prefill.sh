@@ -18,7 +18,7 @@ trap 'kill "$(cat /tmp/v/chunk.pid 2>/dev/null)" 2>/dev/null; true' EXIT
 
 # tiny's max_seq_len is 256, so 256 is the long bucket: 4 chunks of 64.
 SERVE_ADDR=127.0.0.1:18421 SERVE_BACKEND=tpu MODEL_CONFIG=tiny \
-  SERVE_KV=paged SERVE_MAX_SEQ=256 SERVE_SLOTS=8 \
+  SERVE_MAX_SEQ=256 SERVE_SLOTS=8 \
   SERVE_PREFILL_CHUNK=64 SERVE_WARMUP=128,256 SERVE_FUSE=4 \
   python -m p2p_llm_chat_tpu.serve >/tmp/v/chunk.log 2>&1 &
 echo $! > /tmp/v/chunk.pid

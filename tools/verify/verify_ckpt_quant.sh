@@ -24,7 +24,7 @@ print("checkpoint saved")
 EOF
 
 SERVE_ADDR=127.0.0.1:18421 SERVE_BACKEND=tpu CKPT_DIR=$CKPT LLM_MODEL=tiny \
-  SERVE_KV=paged SERVE_QUANT=int8 SERVE_KV_QUANT=int8 \
+  SERVE_QUANT=int8 SERVE_KV_QUANT=int8 \
   python -m p2p_llm_chat_tpu.serve >/tmp/v/serve_q.log 2>&1 &
 echo $! > /tmp/v/serve_q.pid
 

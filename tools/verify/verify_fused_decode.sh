@@ -5,7 +5,7 @@ set -u
 mkdir -p /tmp/vf
 cd "$(dirname "$0")/../.."
 PORT=18433
-SERVE_BACKEND=tpu MODEL_CONFIG=tiny SERVE_KV=paged SERVE_KV_QUANT=int8 \
+SERVE_BACKEND=tpu MODEL_CONFIG=tiny SERVE_KV_QUANT=int8 \
   SERVE_QUANT=int8 SERVE_FUSE=4 SERVE_SLOTS=4 SERVE_MAX_SEQ=256 \
   SERVE_WARMUP=64,128 SERVE_ADDR=127.0.0.1:$PORT \
   python -m p2p_llm_chat_tpu.serve >/tmp/vf/serve.log 2>&1 &

@@ -10,7 +10,7 @@ mkdir -p /tmp/v
 fail() { echo "FAIL: $1"; exit 1; }
 
 # 1. Shipped tree is clean (the exact ci.sh invocation).
-python -m tools.graftcheck p2p_llm_chat_tpu bench.py start_all.py tests \
+python -m tools.graftcheck p2p_llm_chat_tpu start_all.py tests \
   >/tmp/v/graftcheck_clean.log 2>&1 \
   || fail "shipped tree has findings: $(tail -3 /tmp/v/graftcheck_clean.log)"
 
@@ -172,7 +172,7 @@ seed_expect "$SEED/serve/shed.py" "http/503-no-retry-after"
 # copy of the tree (the real tree is never touched).
 TREE=/tmp/v/graftcheck_tree
 rm -rf "$TREE"; mkdir -p "$TREE"
-cp -r p2p_llm_chat_tpu tools bench.py start_all.py ci.sh pytest.ini \
+cp -r p2p_llm_chat_tpu tools start_all.py ci.sh pytest.ini \
       docs "$TREE/"
 mkdir -p "$TREE/tests"   # graftcheck target dir; tests themselves not needed
 # Seed an unguarded METHOD on DHTNode (guarded-by is per-class, so the

@@ -24,7 +24,7 @@ EOF
 run_serve() {
   local extra_env=$1 log=$2
   env $extra_env SERVE_BACKEND=tpu SERVE_ADDR=127.0.0.1:$PORT \
-      SERVE_KV=paged SERVE_KV_QUANT=int8 SERVE_QUANT=int8 SERVE_SPEC=2 \
+      SERVE_KV_QUANT=int8 SERVE_QUANT=int8 SERVE_SPEC=2 \
       SERVE_SLOTS=4 SERVE_MAX_SEQ=128 SERVE_WARMUP=0 \
       python -m p2p_llm_chat_tpu.serve > $log 2>&1 &
   echo $!

@@ -9,7 +9,7 @@ fail() { echo "FAIL: $1"; exit 1; }
 trap 'kill "$(cat /tmp/v/serve.pid 2>/dev/null)" 2>/dev/null; true' EXIT
 
 SERVE_ADDR=127.0.0.1:18411 SERVE_BACKEND=tpu MODEL_CONFIG=tiny \
-  SERVE_KV=paged SERVE_QUANT=int8 SERVE_SPEC=3 \
+  SERVE_QUANT=int8 SERVE_SPEC=3 \
   python -m p2p_llm_chat_tpu.serve >/tmp/v/serve.log 2>&1 &
 echo $! > /tmp/v/serve.pid
 
