@@ -53,12 +53,28 @@ class PagedKVCache(NamedTuple):
     (ops/paged_attention._append_kernel) DMAs one page's scales per
     (kv-head) as a contiguous ``[page_size]`` lane vector and folds them
     into the VMEM dequant — with Hkv (= 8) as the minor dim that slice is
-    strided 8 ways, a shape Mosaic cannot form. It also keeps the minor
-    dim >= a half-lane (64+) so XLA does not answer the decode scatter /
-    attention gather pair with transposed layouts and full-array copies
-    (an earlier slot-minor layout cost ~0.4 ms/step of pure layout
-    conversion). ``k_scale_view``/``v_scale_view`` return the logical
-    [L, N, ps, Hkv] order for oracles/tests.
+    strided 8 ways, a shape Mosaic cannot form. ``k_scale_view``/
+    ``v_scale_view`` return the logical [L, N, ps, Hkv] order for
+    oracles/tests.
+
+    What head-major does NOT settle is how the scales are written. The
+    slot is the lane (minor) dimension, and a scatter that indexes it
+    (``arr.at[:, phys, :, slot].set``) makes XLA's TPU backend copy the
+    whole array into a layout with the indexed dimensions major (``Hkv``
+    or ``L`` padded to 128 lanes), scatter there and copy it back: four
+    whole-array copies and 0.3-1.1 GB of temporaries a decode step, 1-2
+    ms at the benchmark's pools (PERF.md §6, PR 29; earlier notes blamed
+    the fused scan's while carry). The rule for a write path: **index
+    pages, never lanes** — gather the ``[Hkv, ps_pad]`` tiles of the
+    pages written, replace lanes with ``jnp.where``, scatter the tiles on
+    the page dimension (:func:`_scatter_scale_tiles`); that compiles to
+    an in-place update of the donated array. The decode write and the
+    chunk ladder's splices follow it (tests/test_pool_write_layout.py);
+    the lane scatters left (:func:`write_decode`,
+    :func:`write_decode_multi`, :func:`write_decode_multi_all_layers`,
+    :func:`copy_slot`, :func:`write_prefill`, :func:`write_prefill_row`:
+    speculation and the non-gather attention implementations) are
+    correct and pay the relayout.
     """
 
     k: jax.Array
@@ -165,8 +181,10 @@ def shard_cache(cache: PagedKVCache, mesh,
 def quant_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric int8 over the trailing head_dim axis: x [..., Hkv, D] ->
     (int8 [..., Hkv, D], f32 scale [..., Hkv]). (bf16 scales were tried
-    to shrink the while-carry layout copies; the bf16 scale GATHER is
-    ~5x slower than f32's on v5e and regressed the step — f32 stays.)"""
+    to shrink the scale arrays' whole-array copies — a lane-indexed
+    scatter's relayout, not the while carry: PagedKVCache's docstring —
+    and the bf16 scale GATHER is ~5x slower than f32's on v5e and
+    regressed the step: f32 stays.)"""
     xf = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=-1)
     s = jnp.where(amax > 0, amax / 127.0, 1.0)
@@ -231,6 +249,23 @@ def _scatter_kv(cache: PagedKVCache, new_k: jax.Array, new_v: jax.Array,
         k=scatter(cache.k, qk), v=scatter(cache.v, qv),
         k_scale=sscatter(cache.k_scale, sk),
         v_scale=sscatter(cache.v_scale, sv))
+
+
+def _scatter_scale_tiles(arr: jax.Array, phys: jax.Array, lanes: jax.Array,
+                         vals: jax.Array) -> jax.Array:
+    """Store scales into ``arr`` [L, N, Hkv, ps_pad] as WHOLE page tiles:
+    gather the ``[L, Hkv, ps_pad]`` tiles of pages ``phys`` [T], replace
+    the lanes ``lanes`` [T, ps_pad] (bool) marks with ``vals``
+    ([L, T, Hkv, ps_pad], or anything that broadcasts to it), write every
+    other lane back as it was read, and scatter the tiles on the page
+    dimension alone: the floats a lane-indexed
+    ``arr.at[:, phys, :, slot].set`` stores, without its relayout of the
+    whole array (PagedKVCache: "index pages, never lanes"). Pages in
+    ``phys`` must be distinct apart from garbage page 0, whose tile then
+    holds one of its writers' (garbage by contract); an out-of-range
+    page is dropped."""
+    tiles = jnp.where(lanes[None, :, None, :], vals, arr[:, phys])
+    return arr.at[:, phys].set(tiles, mode="drop")
 
 
 def write_prefill(cache: PagedKVCache, layer_k: jax.Array, layer_v: jax.Array,
@@ -369,19 +404,26 @@ def write_prefill_chunk(cache: PagedKVCache, chunk_k: jax.Array,
     ``start // page_size``; an unaligned start (a prefix-offset chunk —
     the broadcast prefix shifts every boundary by the registered prefix
     length — or a sub-page chunk budget) falls back to a per-token
-    scatter. Positions past a row's allocation hit zero table entries
+    scatter of the values, and lands an int8 pool's scales per page
+    tile (:func:`_scatter_scale_tiles`). Positions past a row's
+    allocation hit zero table entries
     (or the width clamp) and land in garbage page 0 — the containment
     write_prefill_batch documents."""
     L, R, C, Hkv, D = chunk_k.shape
     ps = cache.page_size
-    if start % ps == 0:
-        P, ps_eff = _page_tiling(C, ps)
+    def span_pages(P):
+        """[R*P] physical pages of logical pages start//ps .. +P-1 per
+        row; past the table's width: garbage page 0."""
         lp = start // ps + jnp.arange(P)               # logical pages
         idx = jnp.minimum(lp, tables.shape[1] - 1)
         phys = jnp.where((lp < tables.shape[1])[None, :],
                          tables.astype(jnp.int32)[:, idx], 0)
-        phys = phys.reshape(R * P)
-        return _tile_scatter(cache, chunk_k, chunk_v, phys, P, ps_eff)
+        return phys.reshape(R * P)
+
+    if start % ps == 0:
+        P, ps_eff = _page_tiling(C, ps)
+        return _tile_scatter(cache, chunk_k, chunk_v, span_pages(P), P,
+                             ps_eff)
     # Mid-page start: per-token indices (write_prefill's shape) with the
     # chunk's position offset; slower than page tiles but only the
     # prefix-offset chunks pay it.
@@ -393,13 +435,30 @@ def write_prefill_chunk(cache: PagedKVCache, chunk_k: jax.Array,
                                axis=1)                 # [R,C]
     phys = jnp.where((logical < tables.shape[1])[None, :], phys, 0)
     slot = jnp.broadcast_to((pos % ps)[None, :], (R, C))
+
+    def scale_tiles(arr, upd):                         # upd [L, R, C, Hkv]
+        # The scales go per PAGE, not per token (several tokens of the
+        # chunk share a page): each (row, page) tile of the static page
+        # span start//ps .. (start+C-1)//ps takes the chunk's scales on
+        # the lanes whose position falls inside start..start+C and keeps
+        # the rest.
+        p0 = start // ps
+        P = (start + C - 1) // ps - p0 + 1
+        lead, ps_pad = start - p0 * ps, arr.shape[3]
+        lane = jnp.arange(ps_pad)[None, :]
+        tpos = (p0 + jnp.arange(P))[:, None] * ps + lane   # [P, ps_pad]
+        lanes = (lane < ps) & (tpos >= start) & (tpos < start + C)
+        upd = jnp.pad(upd, ((0, 0), (0, 0),
+                            (lead, P * ps - lead - C), (0, 0)))
+        upd = upd.reshape(L, R * P, ps, Hkv).transpose(0, 1, 3, 2)
+        upd = jnp.pad(upd, ((0, 0),) * 3 + ((0, ps_pad - ps),))
+        return _scatter_scale_tiles(arr, span_pages(P),
+                                    jnp.tile(lanes, (R, 1)), upd)
+
     return _scatter_kv(cache, chunk_k, chunk_v,
                        lambda arr, upd: arr.at[:, phys, slot].set(
                            upd, mode="drop"),
-                       # head-major scale target; advanced dims 1, 3 ->
-                       # front: update [R, C, L, Hkv]
-                       lambda arr, upd: arr.at[:, phys, :, slot].set(
-                           upd.transpose(1, 2, 0, 3), mode="drop"))
+                       scale_tiles)
 
 
 def write_prefill_row(cache: PagedKVCache, row_k: jax.Array,
@@ -475,13 +534,16 @@ def write_decode_all_layers(cache: PagedKVCache, k_all: jax.Array,
                                axis=1)[:, 0]           # [B]
     slot = cache.lengths % ps
     # Advanced indices (phys, slot) sit on adjacent dims, so the update
-    # keeps array order: [L, B, Hkv, D] (and [L, B, Hkv] for scales).
+    # keeps array order: [L, B, Hkv, D]. The scales [L, B, Hkv] land as
+    # page tiles with lane ``slot`` replaced (live rows' current pages
+    # are disjoint; parked rows share garbage page 0).
     return _scatter_kv(cache, k_all, v_all,
                        lambda arr, upd: arr.at[:, phys, slot].set(
                            upd, mode="drop"),
-                       # update [B, L, Hkv] (advanced dims 1, 3 -> front)
-                       lambda arr, upd: arr.at[:, phys, :, slot].set(
-                           upd.transpose(1, 0, 2), mode="drop"))
+                       lambda arr, upd: _scatter_scale_tiles(
+                           arr, phys,
+                           jnp.arange(arr.shape[3])[None, :] == slot[:, None],
+                           upd[..., None]))
 
 
 def write_decode_burst(cache: PagedKVCache, k_all: jax.Array,

@@ -456,3 +456,156 @@ def test_flash_append_kernel_interpret_matches_gather(monkeypatch):
         # bf16-loose.
         np.testing.assert_allclose(np.asarray(kern), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5, err_msg=str(quantized))
+
+
+# -- PR 29: the int8 pool's scales land a page tile at a time ---------------
+#
+# write_decode_all_layers and write_prefill_chunk's unaligned path store
+# their scales as whole [Hkv, ps_pad] page tiles indexed on the page
+# dimension (paged_kv._scatter_scale_tiles). The index expressions they
+# replaced stay here as the oracle (over _scatter_kv, which quantises as
+# before): the pool must hold the same bits.
+
+def _oracle_decode_burst(cache, k_all, v_all, inc):
+    ps = cache.page_size
+    logical = cache.lengths // ps
+    phys = jnp.take_along_axis(cache.page_table, logical[:, None],
+                               axis=1)[:, 0]
+    slot = cache.lengths % ps
+    cache = paged_kv._scatter_kv(
+        cache, k_all, v_all,
+        lambda arr, upd: arr.at[:, phys, slot].set(upd, mode="drop"),
+        lambda arr, upd: arr.at[:, phys, :, slot].set(
+            upd.transpose(1, 0, 2), mode="drop"))
+    return cache._replace(lengths=cache.lengths + inc)
+
+
+def _oracle_chunk_unaligned(cache, chunk_k, chunk_v, tables, start):
+    R, C = chunk_k.shape[1:3]
+    ps = cache.page_size
+    pos = start + jnp.arange(C)
+    logical = pos // ps
+    safe = jnp.minimum(logical, tables.shape[1] - 1)
+    phys = jnp.take_along_axis(tables.astype(jnp.int32),
+                               jnp.broadcast_to(safe[None, :], (R, C)),
+                               axis=1)
+    phys = jnp.where((logical < tables.shape[1])[None, :], phys, 0)
+    slot = jnp.broadcast_to((pos % ps)[None, :], (R, C))
+    return paged_kv._scatter_kv(
+        cache, chunk_k, chunk_v,
+        lambda arr, upd: arr.at[:, phys, slot].set(upd, mode="drop"),
+        lambda arr, upd: arr.at[:, phys, :, slot].set(
+            upd.transpose(1, 2, 0, 3), mode="drop"))
+
+
+def _noisy_pool(rng, quantized, batch, num_pages, ps, width):
+    """A pool whose every word is random, the scales' lane padding
+    included: a write that touches a lane it should have kept shows."""
+    cache = PagedKVCache.create(CFG, batch, num_pages, ps,
+                                max_pages_per_row=width,
+                                dtype=jnp.float32, quantized=quantized)
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, size=a.shape), a.dtype)
+        return jnp.asarray(rng.uniform(0.01, 1.0, size=a.shape), a.dtype)
+
+    if quantized:
+        cache = cache._replace(k_scale=fill(cache.k_scale),
+                               v_scale=fill(cache.v_scale))
+    return cache._replace(k=fill(cache.k), v=fill(cache.v))
+
+
+def _assert_same_pool(got, want, garbage_tiles):
+    """Every pool array bit for bit. Where several writes of one call
+    land in garbage page 0 its scale tile holds one writer's (the
+    lane-indexed scatter kept every writer's lane): garbage by contract,
+    so that one tile is left out; every other page must match."""
+    names = ["k", "v", "lengths", "page_table"]
+    if want.quantized:
+        names += ["k_scale", "v_scale"]
+    for name in names:
+        a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        if name.endswith("_scale") and garbage_tiles > 1:
+            a, b = a[:, 1:], b[:, 1:]
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+# (lengths, table rows, active) for a batch of 4 at page size 8, width 3.
+_DECODE_CASES = {
+    # slot 0 of a fresh page, mid-page, slot ps - 1, slot ps - 1 of the
+    # row's last page
+    "live-slots": ([PS, 3, PS - 1, 3 * PS - 1],
+                   [[1, 2, 0], [3, 0, 0], [4, 0, 0], [5, 6, 7]],
+                   [1, 1, 1, 1]),
+    # one parked row with a zeroed table: its write is page 0's only one
+    "one-parked": ([PS + 2, 5, 0, 2 * PS],
+                   [[1, 2, 0], [0, 0, 0], [4, 0, 0], [5, 6, 7]],
+                   [1, 0, 1, 1]),
+    # parked rows share garbage page 0, at different slots and at one
+    "parked-rows": ([4, 5, 5, 2 * PS + 1],
+                    [[0, 0, 0], [0, 0, 0], [0, 0, 0], [5, 6, 7]],
+                    [0, 0, 0, 1]),
+    # a finished row kept resident writes its own page; an out-of-range
+    # physical page (pool of 16) is dropped
+    "resident-and-dropped": ([PS - 1, 2, PS + 4, 6],
+                             [[9, 0, 0], [21, 0, 0], [10, 11, 0], [12, 0, 0]],
+                             [0, 1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("case", sorted(_DECODE_CASES))
+def test_decode_write_is_bit_identical_to_lane_scatter(case, quantized):
+    lengths, table, active = _DECODE_CASES[case]
+    rng = np.random.default_rng(29)
+    B, width = len(lengths), len(table[0])
+    cache = _noisy_pool(rng, quantized, B, 16, PS, width)._replace(
+        page_table=jnp.asarray(table, jnp.int32),
+        lengths=jnp.asarray(lengths, jnp.int32))
+    shape = (CFG.num_layers, B, CFG.num_kv_heads, CFG.head_dim)
+    k_all = jnp.asarray(rng.normal(size=shape) * 3, jnp.float32)
+    v_all = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    inc = jnp.asarray(active, jnp.int32)
+    got = jax.jit(paged_kv.write_decode_burst)(cache, k_all, v_all, inc)
+    want = jax.jit(_oracle_decode_burst)(cache, k_all, v_all, inc)
+    page0 = sum(1 for b in range(B) if table[b][lengths[b] // PS] == 0)
+    _assert_same_pool(got, want, page0)
+    if quantized:       # the oracle moved something: the test can fail
+        assert not np.array_equal(np.asarray(want.k_scale),
+                                  np.asarray(cache.k_scale))
+
+
+# Page size 64 (the servers'), so the registered template head's 88
+# tokens end mid-page. Two rows: row 0 holds pages for 5 logical pages
+# (the table's width), row 1's table ends after two.
+_CHUNK_PS = 64
+_CHUNK_TABLES = [[1, 2, 3, 4, 5], [6, 7, 0, 0, 0]]
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("start", [88, _CHUNK_PS - 1], ids=["s88", "s63"])
+@pytest.mark.parametrize("C", [16, 64, 256], ids=["sub", "page", "pages"])
+def test_unaligned_chunk_splice_is_bit_identical_to_lane_scatter(
+        C, start, quantized):
+    """C below, at and several times a page from a mid-page start: row 1's
+    table ends inside the longer chunks (garbage page 0), and from 88 the
+    256-token chunk also runs past the table's width."""
+    rng = np.random.default_rng(88)
+    ps, R = _CHUNK_PS, len(_CHUNK_TABLES)
+    tables = jnp.asarray(_CHUNK_TABLES, jnp.int32)
+    cache = _noisy_pool(rng, quantized, 3, 9, ps, tables.shape[1])
+    shape = (CFG.num_layers, R, C, CFG.num_kv_heads, CFG.head_dim)
+    k = jnp.asarray(rng.normal(size=shape) * 2, jnp.float32)
+    v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    got = jax.jit(paged_kv.write_prefill_chunk, static_argnums=(4,))(
+        cache, k, v, tables, start)
+    want = jax.jit(_oracle_chunk_unaligned, static_argnums=(4,))(
+        cache, k, v, tables, start)
+    span = range(start // ps, (start + C - 1) // ps + 1)
+    page0 = sum(1 for row in _CHUNK_TABLES for p in span
+                if p >= len(row) or row[p] == 0)
+    _assert_same_pool(got, want, page0)
+    if quantized:
+        assert not np.array_equal(np.asarray(want.v_scale),
+                                  np.asarray(cache.v_scale))

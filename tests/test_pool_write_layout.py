@@ -1,0 +1,110 @@
+"""The int8 pool's hot writes update the scale arrays in place, off the
+chip: ``write_decode_burst`` and the unaligned ``write_prefill_chunk``,
+donated, compiled for a described v5e chip at the pool shape of
+``mixtral-8x7b-v0.1-l6`` (the TPU's compiler is installed here; nothing
+runs). A scatter that indexes the scales' lane (slot) dimension makes
+the compiler copy the whole ``f32[L, pages, Hkv, 128]`` array into a
+layout with the indexed dimensions major and back, four copies and half
+a gigabyte of temporaries a call (PERF.md §6, PR 29); the rule is
+"index pages, never lanes" (ops/paged_kv.py), and this file holds the
+two writes every cell runs to it.
+
+Everything that touches the topology sits inside fixtures, as the
+on-chip-measurement guide says: only the worker that runs this file
+loads the TPU's library.
+"""
+
+import re
+
+import pytest
+
+# The pool of benchmark/configs/mixtral-8x7b-v0.1-l6.json: 6 layers,
+# 1,024 pages and the garbage page, 64 slots a page, 8 kv heads x 128,
+# 32 rows of at most 2,048 tokens.
+L, PAGES, PS, HKV, D = 6, 1025, 64, 8, 128
+ROWS, PER_ROW = 32, 32
+SCALE_SHAPE = rf"f32\[{L},{PAGES},{HKV},128\]"
+TEMP_LIMIT = 8 * 1024 * 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def described(topo):
+    """shape, dtype -> a ShapeDtypeStruct on the described chip."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+@pytest.fixture()
+def pool(described):
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache
+    return PagedKVCache(
+        k=described((L, PAGES, PS, HKV, D), jnp.int8),
+        v=described((L, PAGES, PS, HKV, D), jnp.int8),
+        page_table=described((ROWS, PER_ROW), jnp.int32),
+        lengths=described((ROWS,), jnp.int32),
+        k_scale=described((L, PAGES, HKV, 128), jnp.float32),
+        v_scale=described((L, PAGES, HKV, 128), jnp.float32))
+
+
+@pytest.fixture()
+def no_cache():
+    """The persistent cache cannot hold what it cannot read back."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _assert_in_place(compiled):
+    copies = [line.strip()[:200] for line in compiled.as_text().splitlines()
+              if re.search(rf"= {SCALE_SHAPE}\S* copy(-start)?\(", line)]
+    assert not copies, "the scale array is copied whole:\n" + "\n".join(copies)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_LIMIT, f"{temp} bytes of temporaries"
+
+
+def test_decode_write_updates_the_scales_in_place(pool, described, no_cache):
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops.paged_kv import write_decode_burst
+    kv = described((L, ROWS, HKV, D), jnp.bfloat16)
+    inc = described((ROWS,), jnp.int32)
+    _assert_in_place(jax.jit(write_decode_burst, donate_argnums=(0,)).lower(
+        pool, kv, kv, inc).compile())
+
+
+@pytest.mark.parametrize("start", [88, 88 + 256], ids=["mid", "final"])
+def test_unaligned_chunk_splice_updates_the_scales_in_place(
+        start, pool, described, no_cache):
+    """A 1 x 256 chunk of the ladder behind the registered 88-token
+    template head: every ``prefill_chunk_mid`` / ``_final`` splice."""
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops.paged_kv import write_prefill_chunk
+    kv = described((L, 1, 256, HKV, D), jnp.bfloat16)
+    tables = described((1, PER_ROW), jnp.int32)
+
+    def splice(cache, k, v, tables):
+        return write_prefill_chunk(cache, k, v, tables, start)
+
+    _assert_in_place(jax.jit(splice, donate_argnums=(0,)).lower(
+        pool, kv, kv, tables).compile())
