@@ -22,13 +22,16 @@ from . import llama
 
 
 def family_for(config: ModelConfig):
-    """The model module (llama or mixtral) implementing this config.
+    """The model module (llama, mixtral or pangu) implementing this config.
 
     Both families expose the same functional surface — init_params,
     param_axes, prefill, decode_step (identical signatures and KVCache
     contract) — so the serving stack (serve/scheduler.py, serve/engine.py)
     and the driver dryrun dispatch on ``config.is_moe`` alone.
     """
+    if config.is_latent:
+        from . import pangu
+        return pangu
     if config.is_moe:
         from . import mixtral
         return mixtral

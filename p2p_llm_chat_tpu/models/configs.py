@@ -52,6 +52,35 @@ class ModelConfig:
     # heads, before RoPE (OLMoE). Leaves q_norm [L, q_dim], k_norm
     # [L, kv_dim].
     qk_norm_whole: bool = False
+    # Latent attention (MLA; models/pangu.py). ``kv_lora_rank`` > 0
+    # selects the family: queries through a low-rank pair (``q_lora_rank``),
+    # one latent row a token (``kv_lora_rank`` normed numbers and
+    # ``qk_rope_head_dim`` rotated ones) shared by every head, expanded
+    # per head into ``qk_nope_head_dim`` key and ``v_head_dim`` value
+    # numbers. ``head_dim`` is then the width of q.k (nope + rope) and
+    # ``num_kv_heads`` 1: the cache's geometry is ``cache_*`` below.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # RMSNorms after the attention and after the MLP too, before each
+    # residual add (four norms a layer).
+    sandwich_norm: bool = False
+    # Leading dense layers (SwiGLU of ``dense_intermediate_size``) before
+    # the routed ones; ``intermediate_size`` is then one expert's width.
+    first_k_dense: int = 0
+    dense_intermediate_size: int = 0
+    # Shared experts (each of ``intermediate_size``) added to every token.
+    num_shared_experts: int = 0
+    # Experts the router scores. ``num_experts`` of them, ids [0,
+    # num_experts), are held here; a pair routed to another is some other
+    # chip's work and adds nothing here. 0 = ``num_experts`` (all held).
+    moe_router_width: int = 0
+    # ``softmax`` over all experts (Mixtral, OLMoE) or ``sigmoid`` of
+    # each logit; ``routed_scaling_factor`` multiplies the kept weights.
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
     # token ids (llama3 defaults; byte tokenizer overrides)
     bos_token_id: int = 128000
     eos_token_ids: tuple[int, ...] = (128001, 128008, 128009)
@@ -67,6 +96,35 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    # What one token holds in a KV cache (models/llama.KVCache,
+    # ops/paged_kv.PagedKVCache): ``cache_kv_heads`` rows of
+    # ``cache_k_dim`` numbers in ``k`` and of ``cache_v_dim`` in ``v``.
+    # Per-head keys and values, or for a latent model the normed latent
+    # in ``k`` and the rotated shared key in ``v``, the latter padded to
+    # whole 128-lane tiles (a 64-wide minor dimension makes every pool
+    # write relayout the array, and no page DMA can be aligned to it).
+    @property
+    def cache_kv_heads(self) -> int:
+        return 1 if self.is_latent else self.num_kv_heads
+
+    @property
+    def cache_k_dim(self) -> int:
+        return self.kv_lora_rank if self.is_latent else self.head_dim
+
+    @property
+    def cache_v_dim(self) -> int:
+        if self.is_latent:
+            return -(-self.qk_rope_head_dim // 128) * 128
+        return self.head_dim
+
+    @property
+    def router_width(self) -> int:
+        return self.moe_router_width or self.num_experts
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -183,6 +241,43 @@ _register(ModelConfig(
     head_dim=32, max_seq_len=256, rope_theta=10000.0,
     num_experts=8, num_experts_per_tok=4, moe_renormalize=False,
     qk_norm_whole=True, bos_token_id=1, eos_token_ids=(2,),
+))
+
+# openPangu-Ultra-MoE-718B (FreedomIntelligence/openPangu-Ultra-MoE-718B
+# config.json), ONE chip's share of it: 16 chips share each layer (16 of
+# the 256 routed experts held, the router 256 wide, top-8 over all;
+# attention and the shared expert whole), 8 vocabulary slices (19,200
+# ids), 8 pipeline stages of which this is a chip of the first (one of
+# the three leading dense layers, 8 routed layers). No width, head
+# count, rank, top-k or router width is cut. MLA: 9.1 GB int8.
+_register(ModelConfig(
+    name="openpangu-ultra-moe-718b-l9e16", vocab_size=19200,
+    hidden_size=7680, intermediate_size=2048, num_layers=9, num_heads=128,
+    num_kv_heads=1, head_dim=192, max_seq_len=131072, rope_theta=25600000.0,
+    rms_norm_eps=1e-5, num_experts=16, num_experts_per_tok=8,
+    moe_renormalize=True, q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    sandwich_norm=True, first_k_dense=1, dense_intermediate_size=18432,
+    num_shared_experts=1, moe_router_width=256, moe_scoring="sigmoid",
+    routed_scaling_factor=2.5,
+    # The catalog's config.json has no token ids; the serving tokenizer
+    # supplies its own stop id.
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# The same block at test size: MLA, sandwich norms, one dense layer,
+# then routed layers holding 4 of 16 sigmoid-scored experts (top-4)
+# beside a shared one.
+_register(ModelConfig(
+    name="tiny-pangu", vocab_size=512, hidden_size=128,
+    intermediate_size=64, num_layers=3, num_heads=4, num_kv_heads=1,
+    head_dim=48, max_seq_len=256, rope_theta=10000.0,
+    num_experts=4, num_experts_per_tok=4, moe_renormalize=True,
+    q_lora_rank=48, kv_lora_rank=64, qk_nope_head_dim=32,
+    qk_rope_head_dim=16, v_head_dim=32, sandwich_norm=True,
+    first_k_dense=1, dense_intermediate_size=256, num_shared_experts=1,
+    moe_router_width=16, moe_scoring="sigmoid", routed_scaling_factor=2.5,
+    bos_token_id=1, eos_token_ids=(2,),
 ))
 
 # Loadgen CPU profile: ``tiny`` dims with a real context window, so the

@@ -43,7 +43,10 @@ from .layers import (
 
 
 class KVCache(NamedTuple):
-    """k/v: [L, B, max_seq, Hkv, D]; lengths: [B] valid slots per row."""
+    """k/v: [L, B, max_seq, Hkv, D]; lengths: [B] valid slots per row.
+    (A latent-attention model keeps one head: its normed latent in ``k``
+    and its shared rotated key in ``v``, of different widths:
+    ``ModelConfig.cache_*``.)"""
 
     k: jax.Array
     v: jax.Array
@@ -52,9 +55,9 @@ class KVCache(NamedTuple):
     @classmethod
     def create(cls, config: ModelConfig, batch: int, max_seq: int,
                dtype=DEFAULT_COMPUTE_DTYPE) -> "KVCache":
-        shape = (config.num_layers, batch, max_seq, config.num_kv_heads,
-                 config.head_dim)
-        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        lead = (config.num_layers, batch, max_seq, config.cache_kv_heads)
+        return cls(k=jnp.zeros(lead + (config.cache_k_dim,), dtype),
+                   v=jnp.zeros(lead + (config.cache_v_dim,), dtype),
                    lengths=jnp.zeros((batch,), jnp.int32))
 
 

@@ -385,6 +385,8 @@ _QUANT_LEAVES = frozenset({
     "wqkv", "wgu", "wgu_e",            # fused forms (llama.fuse_params)
     "w_gate", "w_up", "w_down",        # SwiGLU / expert FFNs
     "lm_head",                         # output projection
+    # latent attention and the shared expert (models/pangu.py)
+    "wqkva", "wqb", "wkvb", "wgu_s", "w_down_s",
 })
 
 

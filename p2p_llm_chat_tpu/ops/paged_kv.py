@@ -39,6 +39,12 @@ class PagedKVCache(NamedTuple):
     garbage page — so kernel-side fetches of dead pages stay in bounds);
     lengths: [B] live tokens per row.
 
+    A latent-attention model's pool (models/pangu.py) has the same
+    leaves under the same page table and write ops: one head, ``k`` =
+    the normed latent row [.., 1, kv_lora_rank] and ``v`` = the shared
+    rotated key [.., 1, rope dim padded to 128 lanes], each with its own
+    scale a token when int8 (``ModelConfig.cache_*``); nothing per head.
+
     Quantized pool (``create(..., quantized=True)``): k/v store int8 with
     per-(layer, slot, kv-head) float32 scales ``k_scale``/``v_scale``,
     stored HEAD-MAJOR as ``[L, num_pages, Hkv, page_size]`` — symmetric
@@ -115,8 +121,10 @@ class PagedKVCache(NamedTuple):
                page_size: int, max_pages_per_row: Optional[int] = None,
                dtype=jnp.bfloat16, quantized: bool = False,
                mesh=None) -> "PagedKVCache":
-        shape = (config.num_layers, num_pages, page_size,
-                 config.num_kv_heads, config.head_dim)
+        lead = (config.num_layers, num_pages, page_size,
+                config.cache_kv_heads)
+        shape = lead + (config.cache_k_dim,)
+        vshape = lead + (config.cache_v_dim,)
         if max_pages_per_row is None:
             max_pages_per_row = num_pages
         if quantized:
@@ -125,9 +133,9 @@ class PagedKVCache(NamedTuple):
             # a tile). Slots past page_size are never written or read.
             ps_pad = -(-page_size // 128) * 128
             sshape = (config.num_layers, num_pages,
-                      config.num_kv_heads, ps_pad)
+                      config.cache_kv_heads, ps_pad)
             cache = cls(
-                k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
+                k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(vshape, jnp.int8),
                 page_table=jnp.zeros((batch, max_pages_per_row), jnp.int32),
                 lengths=jnp.zeros((batch,), jnp.int32),
                 k_scale=jnp.zeros(sshape, jnp.float32),
@@ -135,7 +143,7 @@ class PagedKVCache(NamedTuple):
             )
         else:
             cache = cls(
-                k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                k=jnp.zeros(shape, dtype), v=jnp.zeros(vshape, dtype),
                 page_table=jnp.zeros((batch, max_pages_per_row), jnp.int32),
                 lengths=jnp.zeros((batch,), jnp.int32),
             )
@@ -537,9 +545,29 @@ def write_decode_all_layers(cache: PagedKVCache, k_all: jax.Array,
     # keeps array order: [L, B, Hkv, D]. The scales [L, B, Hkv] land as
     # page tiles with lane ``slot`` replaced (live rows' current pages
     # are disjoint; parked rows share garbage page 0).
-    return _scatter_kv(cache, k_all, v_all,
-                       lambda arr, upd: arr.at[:, phys, slot].set(
-                           upd, mode="drop"),
+    if cache.k.shape[3] == 1:
+        # One head (a latent pool): XLA lays the array out with the SLOT
+        # on the sublanes (int8 packs four slots a word). A slot-indexed
+        # scatter over all layers then relayouts the whole pool to put
+        # the layers there instead and copies it back (1.5 GB moved a
+        # step at the benchmark's pool); so does a dynamic-update-slice
+        # a row; and ONE gather of the rows' whole pages over all layers
+        # is split in two lane halves by first slicing the WHOLE pool
+        # into them (1.2 GB a step; the served trace, PERF.md section 6,
+        # PR 30). A layer at a time, the rows' whole pages with one slot
+        # replaced (the scales' rule) are small enough to gather as they
+        # are and scatter back in place.
+        at = (jnp.arange(ps)[None, :] == slot[:, None])[:, :, None, None]
+
+        def put(arr, upd):
+            for layer in range(arr.shape[0]):
+                tiles = jnp.where(at, upd[layer, :, None], arr[layer, phys])
+                arr = arr.at[layer, phys].set(tiles, mode="drop")
+            return arr
+    else:
+        def put(arr, upd):
+            return arr.at[:, phys, slot].set(upd, mode="drop")
+    return _scatter_kv(cache, k_all, v_all, put,
                        lambda arr, upd: _scatter_scale_tiles(
                            arr, phys,
                            jnp.arange(arr.shape[3])[None, :] == slot[:, None],

@@ -523,8 +523,15 @@ class OllamaServer:
                     "llama.embedding_length": cfg.hidden_size,
                     "llama.block_count": cfg.num_layers,
                     "llama.attention.head_count": cfg.num_heads,
-                    "llama.attention.head_count_kv": cfg.num_kv_heads,
+                    # What the model's cache holds a token: per-head K
+                    # and V, or one shared latent head (MLA).
+                    "llama.attention.head_count_kv": cfg.cache_kv_heads,
                     "llama.vocab_size": cfg.vocab_size}
+            if cfg.is_latent:
+                info["general.architecture"] = "pangu_ultra_moe"
+                info["llama.attention.kv_lora_rank"] = cfg.kv_lora_rank
+                info["llama.attention.q_lora_rank"] = cfg.q_lora_rank
+                info["llama.rope.dimension_count"] = cfg.qk_rope_head_dim
         return Response(200, {"modelfile": "", "parameters": "",
                               "template": "", "details": details,
                               "model_info": info})

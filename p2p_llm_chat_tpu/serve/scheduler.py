@@ -124,6 +124,12 @@ def _bucket(n: int, max_seq: int) -> int:
     return min(b, max_seq)
 
 
+def _causal_pairs(lo: int, hi: int) -> int:
+    """(query, key) pairs of prompt positions lo..hi-1 under a causal
+    mask: position i attends i + 1 positions."""
+    return (hi * (hi + 1) - lo * (lo + 1)) // 2
+
+
 def _pow2_floor(n: int) -> int:
     """Largest power of two <= n; 1 for n < 1."""
     return 1 << (max(n, 1).bit_length() - 1)
@@ -562,6 +568,23 @@ class BatchScheduler:
         # so dense and MoE configs serve through one scheduler.
         self._model = family_for(config)
         model = self._model
+        if config.is_latent:
+            # Paths that assume per-head K and V pages refuse a latent
+            # model here, by name, before anything is compiled.
+            for on, what in (
+                    (mesh is not None, "a mesh (its cache is one latent "
+                     "head, which cannot be split by heads)"),
+                    (bool(spec_k) or drafter is not None, "speculative "
+                     "decoding (the verify programs read per-head K and "
+                     "V pages)")):
+                if on:
+                    raise ValueError(
+                        f"{config.name} keeps a latent KV cache "
+                        f"(kv_lora_rank {config.kv_lora_rank}) and is not "
+                        f"served under {what}")
+        # Width of the counts a routed model's programs hand back behind
+        # their tokens (_with_moe): 2, or the family's own.
+        self._moe_w = getattr(model, "STATS_WIDTH", 2)
         # Decode is bandwidth-bound and pays a fixed cost per
         # weight-matmul call: fuse the column-parallel projection pairs
         # (wq|wk|wv, w_gate|w_up) into single wider matmuls
@@ -597,6 +620,15 @@ class BatchScheduler:
                      config.moe_capacity_factor or "none (dropless)",
                      "over the whole projection" if config.qk_norm_whole
                      else "none")
+            if config.router_width > config.num_experts:
+                log.info("%s holds %d of the %d experts its router scores "
+                         "(%s scores, scaling %.2f), %d shared, %d leading "
+                         "dense layer(s); pairs routed elsewhere are not "
+                         "computed here: serve_moe_routed_pairs_total, "
+                         "serve_moe_local_pairs_total", config.name,
+                         config.num_experts, config.router_width,
+                         config.moe_scoring, config.routed_scaling_factor,
+                         config.num_shared_experts, config.first_k_dense)
         self._log_kernels()
 
         self._slots: list[Optional[_Slot]] = [None] * num_slots  # owned-by: _loop
@@ -607,6 +639,7 @@ class BatchScheduler:
             self._stop_ids.add(eos)
 
         self._reset_device_state()
+        self._log_pool()
 
         self._admit_q: "queue.Queue[Optional[_Slot]]" = queue.Queue()
         self._admit_carry: list[_Slot] = []  # owned-by: _loop — prepared chunks awaiting rows
@@ -629,6 +662,9 @@ class BatchScheduler:
         self._n_admit_rows_padded = 0
         self._n_prefill_tokens = 0
         self._n_prefill_padded = 0
+        # (prompt position, context position) pairs the computed prompt
+        # positions attend causally: position i of a prompt sees i + 1.
+        self._n_prefill_pairs = 0
         # A routed model's prefill programs (admission at every width,
         # the chunk ladder, prefix builds): routed (token, expert) pairs
         # of real prompt positions, and those that found their capacity
@@ -640,6 +676,15 @@ class BatchScheduler:
         # the others' weights were not read (ops/quant_mm.py).
         self._n_moe_decode_touched = 0   # owned-by: _loop
         self._n_moe_decode_slots = 0     # owned-by: _loop
+        # A model that holds a share of the experts its router scores
+        # (ModelConfig.moe_router_width): (token, expert) pairs routed,
+        # prefill's real prompt positions and decode's live rows, and
+        # those of them routed to an expert held here.
+        self._n_moe_routed_pairs = 0     # owned-by: _loop
+        self._n_moe_local_pairs = 0      # owned-by: _loop
+        # Cache rows the decode steps' live rows attended (the sum, over
+        # row-steps, of the row's context length at that step).
+        self._n_attn_ctx_tokens = 0      # owned-by: _loop
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -1532,12 +1577,15 @@ class BatchScheduler:
         routed model the drop count so far."""
         logits = jnp.zeros((R, self.config.vocab_size), jnp.float32)
         if self.config.is_moe:
-            return logits, jnp.zeros((2,), jnp.int32)
+            return logits, jnp.zeros((self._moe_w,), jnp.int32)
         return logits
 
     def _count_moe(self, stats) -> None:
         self._n_moe_assigned += int(stats[0])
         self._n_moe_dropped += int(stats[1])
+        if len(stats) > 2:
+            self._n_moe_routed_pairs += int(stats[2])
+            self._n_moe_local_pairs += int(stats[0])
 
     def _build_prefix_kv(self, ids) -> tuple:
         """Prefix KV for ``ids`` — reads only immutable state (params +
@@ -2133,6 +2181,15 @@ class BatchScheduler:
                 note=(f", promoted off-thread, "
                       f"{len(aot_admit) + len(aot_chunks)} AOT programs"))
 
+    def _zero_prefix_entry(self, P: int) -> PrefixEntry:
+        """A prefix entry of the right shapes and no content (the grain
+        pre-warm: the compile cache keys on shapes only)."""
+        c = self.config
+        lead = (c.num_layers, P, c.cache_kv_heads)
+        return PrefixEntry(ids=tuple(range(P)),
+                           k=jnp.zeros(lead + (c.cache_k_dim,), self._dtype),
+                           v=jnp.zeros(lead + (c.cache_v_dim,), self._dtype))
+
     def _warm_prefix_combo(self, P: int, S: int, R: int,
                            synthetic: bool = False) -> None:
         """Compile+run ONE prefix-admission program (one queued warmup
@@ -2153,10 +2210,7 @@ class BatchScheduler:
         if entry is None:
             if not synthetic:
                 return
-            z = jnp.zeros((self.config.num_layers, P,
-                           self.config.num_kv_heads, self.config.head_dim),
-                          self._dtype)
-            entry = PrefixEntry(ids=tuple(range(P)), k=z, v=z)
+            entry = self._zero_prefix_entry(P)
         self._admit_chunk([], [], S, R, warm_prefix=entry)
 
     # graftcheck: runs-on _loop
@@ -2179,10 +2233,7 @@ class BatchScheduler:
             if entry is None:
                 if not synthetic:
                     return
-                z = jnp.zeros((self.config.num_layers, prefix_len,
-                               self.config.num_kv_heads,
-                               self.config.head_dim), self._dtype)
-                entry = PrefixEntry(ids=tuple(range(prefix_len)), k=z, v=z)
+                entry = self._zero_prefix_entry(prefix_len)
         if prefix_len + S > self.max_seq:
             return
         C = self.prefill_chunk
@@ -3217,12 +3268,14 @@ class BatchScheduler:
             "serve_admit_rows_padded_total": self._n_admit_rows_padded,
             "serve_prefill_tokens_total": self._n_prefill_tokens,
             "serve_prefill_tokens_padded_total": self._n_prefill_padded,
+            "serve_prefill_context_pairs_total": self._n_prefill_pairs,
             # Routed models: (token, expert) pairs the prefill programs
             # routed for real prompt positions, and those their
             # capacity buckets dropped (0 and 0 for a dense model).
             "serve_moe_assignments_total": self._n_moe_assigned,
             "serve_moe_dropped_total": self._n_moe_dropped,
             "serve_decode_row_steps_total": self._n_decode_row_steps,
+            "serve_attn_context_tokens_total": self._n_attn_ctx_tokens,
             "serve_decode_clean_seconds_total": self._clean_s,
             "serve_decode_clean_steps_total": self._clean_steps,
             # Boot, set once: process start (the OS's record) until
@@ -3242,6 +3295,11 @@ class BatchScheduler:
                 self._n_moe_decode_touched
             out["serve_moe_decode_expert_slots_total"] = \
                 self._n_moe_decode_slots
+        if self.config.router_width > self.config.num_experts:
+            # A share of the experts is held here: pairs routed (prefill
+            # and decode), and those routed to a held expert.
+            out["serve_moe_routed_pairs_total"] = self._n_moe_routed_pairs
+            out["serve_moe_local_pairs_total"] = self._n_moe_local_pairs
         if self.spec_k:
             out["serve_spec_accepted_total"] = self._n_spec_accepted
             # Back-compat aggregate: the most optimistic source (the
@@ -3348,16 +3406,42 @@ class BatchScheduler:
         if self._quant_mode:
             off[f"qmm-{self._quant_mode}"] = (
                 None if kernel_wanted() else why_off or "forced to XLA")
-        off["flash-append"] = (
-            None if self._paged_flash_min_w > 0 else
-            flash_append_blocked(sharded, self.config.head_dim)
-            or "disabled by PAGED_APPEND_FLASH_MIN_W/PAGED_APPEND_IMPL")
+        if self.config.is_latent:
+            off["mla-prefill"] = off["mla-decode"] = why_off
+            off["flash-append"] = "a latent pool: mla-decode reads it"
+        else:
+            off["flash-append"] = (
+                None if self._paged_flash_min_w > 0 else
+                flash_append_blocked(sharded, self.config.head_dim)
+                or "disabled by PAGED_APPEND_FLASH_MIN_W/PAGED_APPEND_IMPL")
         log.info("kernels on %s: %s; XLA instead of: %s; flash-append "
                  "min_w %d; pallas interpret %s", platform(),
                  ", ".join(k for k, why in off.items() if not why) or "none",
                  "; ".join(f"{k} ({why})" for k, why in off.items() if why)
                  or "none",
                  self._paged_flash_min_w, pallas_interpret())
+
+    def _log_pool(self) -> None:
+        """One boot line with the pool's geometry as the device holds
+        it: what a token costs a layer (per-head K and V, or a latent
+        model's one shared row), the scales, pages, and the bytes."""
+        c, cache = self.config, self._cache
+        per_layer = c.cache_kv_heads * (c.cache_k_dim + c.cache_v_dim)
+        item = cache.k.dtype.itemsize
+        total = sum(int(a.size) * a.dtype.itemsize for a in (
+            cache.k, cache.v, cache.k_scale, cache.v_scale)
+            if a is not None)
+        kind = (f"latent (MLA): 1 head x ({c.cache_k_dim} latent + "
+                f"{c.qk_rope_head_dim} rotated key in {c.cache_v_dim} "
+                "lanes)" if c.is_latent else
+                f"{c.cache_kv_heads} kv heads x 2 x {c.head_dim}")
+        log.info("KV pool: %s, %s%s; %d layers x %d bytes a token; %d pages "
+                 "x %d tokens, %d rows x %d pages a row; %.3f GB",
+                 kind, cache.k.dtype.name,
+                 ", a float32 scale a token a head for each" if
+                 cache.quantized else "", c.num_layers, per_layer * item,
+                 self.num_pages, self.page_size, self.num_slots,
+                 cache.max_pages_per_row, total / 1e9)
 
     @staticmethod
     def _flash_min_w(config, mesh) -> int:
@@ -3374,6 +3458,10 @@ class BatchScheduler:
         ops/paged_attention.effective_flash_min_w, next to the dispatch
         policy itself."""
         from ..ops.paged_attention import effective_flash_min_w
+        if config.is_latent:
+            # A latent pool is read by its own kernel at every window
+            # (ops/mla_attention.py); this policy is the per-head pools'.
+            return 0
         return effective_flash_min_w(config.kv_dim, mesh is not None,
                                      config.head_dim)
 
@@ -3706,6 +3794,8 @@ class BatchScheduler:
             self._n_admit_rows_padded += R
             self._n_prefill_tokens += sum(len(s.prompt_ids) - P
                                           for s in chunk)
+            self._n_prefill_pairs += sum(
+                _causal_pairs(P, len(s.prompt_ids)) for s in chunk)
             self._n_prefill_padded += R * S
 
         if prefix is not None:
@@ -3800,7 +3890,7 @@ class BatchScheduler:
             if self.config.is_moe:
                 # The prefill's drop count rides behind the first tokens
                 # (_with_moe); prefix builds left theirs waiting.
-                self._count_moe(first_toks[-2:])
+                self._count_moe(first_toks[-self._moe_w:])
                 while self._moe_unread:
                     # graftcheck: sync-ok 2 int32 of a build that ended before this admission was dispatched
                     self._count_moe(np.asarray(self._moe_unread.popleft()))
@@ -3878,6 +3968,8 @@ class BatchScheduler:
         self._n_admit_batches += 1
         self._n_admit_rows_padded += R
         self._n_prefill_tokens += sum(len(s.prompt_ids) - P for s in chunk)
+        self._n_prefill_pairs += sum(
+            _causal_pairs(P, len(s.prompt_ids)) for s in chunk)
         self._prefill_carry = _PrefillCarry(
             chunk=chunk, rows=rows, S=S, off=0, C=C,
             prefix=prefix, kv=None,
@@ -4042,6 +4134,10 @@ class BatchScheduler:
         self._last_dispatch = (now, K)
         active = tuple(s is not None for s in self._slots)
         self._n_decode_row_steps += sum(active) * K
+        # Step j of the K reads each live row's ctx_len + j cached rows.
+        self._n_attn_ctx_tokens += K * sum(
+            s.ctx_len + inflight for s in self._slots
+            if s is not None) + sum(active) * K * (K - 1) // 2
         if active != self._active_host:
             # Re-upload the mask only when the active set changed (it only
             # moves on admission/finish — not per tick).
@@ -4086,9 +4182,13 @@ class BatchScheduler:
         if self.config.is_moe:
             # The experts the dispatch touched ride behind its tokens
             # (_with_moe).
-            self._n_moe_decode_touched += int(toks[-2])
-            self._n_moe_decode_slots += int(toks[-1])
-            toks = toks[:-2]
+            w = self._moe_w
+            self._n_moe_decode_touched += int(toks[-w])
+            self._n_moe_decode_slots += int(toks[1 - w])
+            if w > 2:
+                self._n_moe_routed_pairs += int(toks[2 - w])
+                self._n_moe_local_pairs += int(toks[3 - w])
+            toks = toks[:-w]
         toks = toks.reshape(K, -1)
         with self._phase("stream"):
             for row, slot in enumerate(snapshot):
@@ -4831,6 +4931,10 @@ class BatchScheduler:
         self._n_admit_batches += 1
         self._n_admit_rows_padded += B
         self._n_prefill_tokens += sum(int(ints[0, row]) for _, row in live)
+        self._n_prefill_pairs += sum(
+            _causal_pairs(int(ints[1, row]),
+                          int(ints[1, row]) + int(ints[0, row]))
+            for _, row in live)
         self._n_prefill_padded += B * S
         (toks_dev, self._cache, self._keys, self._next_dev,
          self._temps_dev, self._top_ks_dev, self._top_ps_dev,

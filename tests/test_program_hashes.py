@@ -1,0 +1,52 @@
+"""The dense, Mixtral and OLMoE programs lower to the StableHLO they did
+before the latent-attention family came (PR 30's parent, commit
+69d2026): tools/hash_programs.py's digests, taken on that commit under
+this suite's conftest (its XLA flags are part of the text). A PR
+that means to change one of these programs replaces its digest, from a
+run of the tool on itself, and says so."""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "tools"))
+
+import hash_programs  # noqa: E402
+
+PARENT = {
+    "tiny.prefill": "d6edaf81216eb7fb30fedcfc076ff05e63dfa9aa12d0ac9ae97406bcaafaee04",
+    "tiny.prefill_chunk": "bf40353f50ae8d3fe22f55fb5b2467ec3f9b234e93a71907d3229928fb3fbf6c",
+    "tiny.decode_step_paged": "5bfef2aa51bd0a3e2ad2033d0bedf0d4aa6d81ffb02cb021bff299b867e635d5",
+    "tiny.decode_fused": "2b5ebc7755aae77d1e51cbf226fc476dd83063edbaf0a50932358c79681e4f86",
+    "tiny.write_prefill_batch": "53d756733b20a7c7bd4959d65a6b3d22fa0ff12af4acb5896d267c8addb291e4",
+    "tiny.write_prefill_chunk": "1576d14433a7082cd568b612a6c34cdf383ec71a3c6e7670d40830c538533aca",
+    "tiny-moe.prefill": "945eddc721d9bb94e3c9b223dc0f6a67f3fb94f683e34de479c3c48a0cf5956d",
+    "tiny-moe.prefill_chunk": "b9bfd5f7272f18d067935b35232b838374296ede31e8ff60eb4a317734d99fbf",
+    "tiny-moe.decode_step_paged": "43f3fc3e65e56ee813e130d65061a34017c6173a356f4684511af19a46a1740b",
+    "tiny-moe.decode_fused": "d6aa0b48b3de1e6fb1cbc5fc4c30fc8b344442e98c95d420ace38d685118e093",
+    "tiny-moe.write_prefill_batch": "53d756733b20a7c7bd4959d65a6b3d22fa0ff12af4acb5896d267c8addb291e4",
+    "tiny-moe.write_prefill_chunk": "1576d14433a7082cd568b612a6c34cdf383ec71a3c6e7670d40830c538533aca",
+    "tiny-olmoe.prefill": "26a39db12d865d9dfbc24a021f31ab85ffbc70fe0f919874d4e566ba6dde1810",
+    "tiny-olmoe.prefill_chunk": "be6d4cf4f8729a8a881ab49653ee007383b5e309e39a5d14f65d777283189461",
+    "tiny-olmoe.decode_step_paged": "a1a92274845ae1bfdc4d249b3d29b9dea6cb4a8de4bfce6587b6601ed6121a87",
+    "tiny-olmoe.decode_fused": "f0c2ae114434292e5e4ba72a0989c2faf41481401500bc666075d19157579608",
+    "tiny-olmoe.write_prefill_batch": "427d1c493a9fcfe813e321dc7eae89372e7149b1d0fa7d780f4e3f575f79207c",
+    "tiny-olmoe.write_prefill_chunk": "91a1f2403e57658367328f06ac514914122247be29d0dca99b76306d0f6f165d"
+}
+
+
+@pytest.fixture(scope="module")
+def texts():
+    return {name: hash_programs.programs(name)
+            for name in hash_programs.CONFIGS}
+
+
+@pytest.mark.parametrize("key", sorted(PARENT))
+def test_program_lowers_to_the_parents_stablehlo(texts, key):
+    import hashlib
+    name, label = key.split(".", 1)
+    got = hashlib.sha256(texts[name][label].encode()).hexdigest()
+    assert got == PARENT[key], (
+        f"{key} lowers to other StableHLO than at PR 30's parent")

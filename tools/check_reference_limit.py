@@ -11,7 +11,10 @@ does and compares it with
   ``renormalised`` (``norm_topk_prob`` flipped), ``no_q_norm`` (``q_norm``
   of ones), and ``int4_weights`` (every projection and expert matrix
   rounded to 4 bits a column, the precision below the int8 the stack
-  states).
+  states); or the architecture file's own list (``wrong_models``: the
+  openPangu family's softmax router, scaling factor 1, no ``n_kva``, no
+  post-norms, RoPE over the nope part, the absorbed form without
+  ``Wuv``, int4 weights).
 
     python tools/check_reference_limit.py benchmark/configs/<name>.json \
         --seeds 53,1,2 --wrong-seeds 53
@@ -40,10 +43,14 @@ def fake_quant(w, bits: int):
     return jnp.round(w / jnp.where(scale > 0, scale, 1.0)) * scale
 
 
-def wrong_models(cfg: dict, weights) -> dict:
+def wrong_models(cfg: dict, weights, arch=None) -> dict:
     """name -> (cfg, weights) of each wrong model this configuration can
-    have."""
+    have: the architecture file's own ``wrong_models(cfg, weights)``
+    where it has one (a family whose layer this file does not know),
+    else the Mistral and OLMoE families' below."""
     import jax.numpy as jnp
+    if arch is not None and hasattr(arch, "wrong_models"):
+        return arch.wrong_models(cfg, weights)
     out = {}
     if "norm_topk_prob" in cfg:
         out["renormalised"] = (
@@ -90,6 +97,7 @@ def main() -> None:
     sched = backend.scheduler
     arch = serve_cell.architecture(cfg)
     weights = arch.engine_weights(sched)
+    drive = getattr(arch, "system_logits", serve_cell.system_logits)
     sound = [int(s) for s in args.seeds.split(",")]
     wrong = [int(s) for s in args.wrong_seeds.split(",")]
     P, D = serve_cell.REF_PREFILL, serve_cell.REF_DECODE
@@ -103,13 +111,14 @@ def main() -> None:
         tokens = jnp.asarray(np.random.default_rng(seed).integers(
             0, sched.config.vocab_size, size=(serve_cell.REF_SEQS, P + D)),
             jnp.int32)
-        system = serve_cell.system_logits(sched, tokens, P)
+        system = drive(sched, tokens, P)
         if seed in sound:
             ref, facts = arch.forward(cfg, tokens, weights)
             say(model="sound", seed=seed, **arch.compare(
                 system, ref, {**facts, "n_prefill": P}, cfg))
         if seed in wrong:
-            for name, (wcfg, w) in wrong_models(cfg, weights).items():
+            for name, (wcfg, w) in wrong_models(cfg, weights,
+                                                arch).items():
                 ref, _ = arch.forward(wcfg, tokens, w)
                 got = reference.compare(system, ref, routed=True)
                 say(model=name, seed=seed, median=got["median"],
