@@ -1195,7 +1195,7 @@ def decode_step_paged_aux(params: dict, config: ModelConfig,
 
     Impl selection is delegated per layer call: paged_attention_append
     itself promotes to the multi-chunk flash-append kernel at windows
-    >= PAGED_APPEND_FLASH_MIN_W (2048) on TPU — the round-8 long-window
+    >= PAGED_APPEND_FLASH_MIN_W (1024) on TPU — the round-8 long-window
     default — and the decision is made ONCE per trace (the scan body
     traces once), so the serving scheduler's per-window jitted programs
     each bake in exactly one impl and warmup compiles the whole

@@ -85,11 +85,21 @@ VMEM instead of whole windows:
   tie.
 
 The grid kernel is now the DEFAULT dispatch for decode append at
-W >= ``PAGED_APPEND_FLASH_MIN_W`` (2048) on TPU; the gather path stays
-default below it and everywhere on CPU (non-interpret ``pallas_call``
-needs the hardware). See ``_flash_append_policy`` for the exact rule
-and docs/serving.md ("long-window kernel") for the dispatch table and
-measured ladder.
+W >= ``PAGED_APPEND_FLASH_MIN_W`` (1024; 2048 until PR 31) on TPU; the
+gather path stays default below it and everywhere on CPU (non-interpret
+``pallas_call`` needs the hardware). See ``_flash_append_policy`` for
+the exact rule and docs/serving.md ("long-window kernel") for the
+dispatch table and measured ladder.
+
+PR 31: the grid kernel's work follows the rows' LENGTHS, not the
+window: a (row, chunk) program whose chunk starts at or past its row's
+length fetches, waits for and folds nothing (``holds_rows`` in
+_flash_append_kernel_body; ops/mla_attention.py's decode kernel had the
+same skip first). The window is the power of two over the longest live
+row, so in a batch of ragged chat contexts two programs in three are
+such chunks, and in a part-full batch nearly all of them. The boundary
+above was measured again with that kernel, at a full and at a part-full
+batch (_flash_append_policy).
 """
 
 from __future__ import annotations
@@ -234,10 +244,14 @@ def _gqa_selection_matrices(Hq: int, Hkv: int, D: int, rep: int):
     cdiv2 = jax.lax.broadcasted_iota(jnp.int32, (Hq, HD), 1) // D
     hdiv2 = jax.lax.broadcasted_iota(jnp.int32, (Hq, HD), 0) // rep
     blockm_t = cdiv2 == hdiv2
+    return sel, blockm, blockm_t, _gqa_expander(Hq, Hkv, rep)
+
+
+def _gqa_expander(Hq: int, Hkv: int, rep: int):
+    """EXPT alone (f32 [Hq, Hkv]): kv-head rows to query-head rows."""
     hh = jax.lax.broadcasted_iota(jnp.int32, (Hq, Hkv), 0) // rep
     gg = jax.lax.broadcasted_iota(jnp.int32, (Hq, Hkv), 1)
-    expt = (hh == gg).astype(jnp.float32)
-    return sel, blockm, blockm_t, expt
+    return (hh == gg).astype(jnp.float32)
 
 
 def _append_kernel(len_ref, q_ref, kc_ref, vc_ref, kwin_ref, vwin_ref,
@@ -442,7 +456,7 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     The XLA gather+merge below is the DEFAULT at short windows and
     everywhere on CPU (it measured fastest at short serving windows —
     see the module docstring's round-4 history). At windows >=
-    ``PAGED_APPEND_FLASH_MIN_W`` (default 2048) on TPU the multi-chunk
+    ``PAGED_APPEND_FLASH_MIN_W`` (default 1024) on TPU the multi-chunk
     flash-append kernel (_paged_attention_flash_append) is the default
     instead: one HBM pass over the pages, no gathered-window
     materialisation — the round-8 long-window win. Overrides:
@@ -724,24 +738,10 @@ _FLASH_CHUNK_TOK_BYTES = 1024
 # were calibrated at (bench-1b / llama-8B class: 8 kv heads x 128).
 _FLASH_HD_REF = 1024
 
-# Floor for the geometry-scaled engagement boundary: below ~2 default
-# chunks the split-K grid cannot pipeline DMAs across programs and the
-# gather path's XLA fusion wins on every geometry measured.
+# Floor for the engagement boundary: no geometry measured engages below
+# it on the default rule (one chunk a row, nothing to skip at a full
+# batch, and the gather path's XLA fusion wins there at hd <= 1024).
 _FLASH_MIN_W_FLOOR = 256
-
-# The boundary for KV geometries WIDER than the calibration (hd >
-# _FLASH_HD_REF; OLMoE's MHA: 16 kv heads x 128 = 2048), as a share of
-# the knob like the scaled rule below: (numerator, denominator). Measured,
-# not extrapolated: on a v5e at hd=2048, int8 pool, 32 rows, the gather
-# path costs 0.62 / 1.42 / 3.74 ms a layer-step at W = 512 / 1024 / 2048
-# and the flash kernel 0.18 / 0.30 / 0.57 ms (3.5x / 4.8x / 6.6x; PERF.md
-# section 6, PR 26; tools/check_append_kernel.py time). The gather path's
-# dequantised window grows with hd and stops fitting what XLA fuses, so
-# the narrow geometries' ratio hd / 1024 does not continue upward (it
-# would say 4096 here, every window of a 2048-token deployment on the
-# gather path). A quarter of the default 2048 is 512, the smallest window
-# measured.
-_FLASH_WIDE_RATIO = (1, 4)
 
 
 def _flash_append_min_w() -> int:
@@ -753,7 +753,7 @@ def _flash_append_min_w() -> int:
     at runtime (the pattern serve/scheduler.py established for
     ``prefill_chunk``); each jitted caller traces the decision once per
     static shape."""
-    return env_int("PAGED_APPEND_FLASH_MIN_W", 2048)
+    return env_int("PAGED_APPEND_FLASH_MIN_W", 1024)
 
 
 def _flash_append_policy(window: int, append_impl: str, min_w: int,
@@ -765,22 +765,27 @@ def _flash_append_policy(window: int, append_impl: str, min_w: int,
     - ``PAGED_APPEND_IMPL=flash``  -> flash kernel at EVERY window;
     - ``PAGED_APPEND_IMPL=kernel`` -> never (the round-4 block kernel
       owns the dispatch upstream);
-    - otherwise flash iff ``min_w > 0`` and the window reaches the
-      GEOMETRY-SCALED boundary ``max(256, min_w * hd / 1024)`` where
-      ``hd = Hkv * head_dim``, for geometries up to the calibration's
-      (hd <= 1024); for wider ones ``max(256, min_w / 4)``
-      (``_FLASH_WIDE_RATIO``: the ratio is measured per geometry, the
-      knob scales every one).
+    - otherwise flash iff ``min_w > 0`` and the window reaches
+      ``max(256, min_w * 1024 / max(hd, 1024))`` where ``hd = Hkv *
+      head_dim``: the knob itself up to the calibration geometry
+      (hd <= 1024: W >= 1024), scaled down by ``1024 / hd`` for wider
+      ones (OLMoE's MHA, hd = 2048: W >= 512).
 
-    Why the scaling (round-18): the round-8 boundary (2048) was
-    measured at hd=1024. Per window token, the gather path pays hd
-    bytes of materialised copy PLUS a geometry-invariant index/mask
-    overhead, while the flash kernel pays the same hd bytes streamed
-    once plus a per-chunk fixed cost that the hd-aware chunk budget
-    AMORTISES OVER MORE TOKENS as hd shrinks (same VMEM bytes per
-    chunk). Narrow-KV geometries therefore cross over earlier in
-    tokens: at bench-moe's hd=512 the boundary halves to W >= 1024.
-    The floor keeps sub-2-chunk windows on gather everywhere.
+    Why one rule, and why it is a function of window and width alone
+    (PR 31; PERF.md section 6 has the table): the kernel's work follows
+    the rows' lengths, the gather path's the window, so what decides is
+    the FULL batch, where the kernel has least to skip. There a grid
+    program costs 3-5 us whatever the width while the gather path's
+    materialised, dequantised window grows with ``W * hd`` (and past
+    hd = 1024 stops fitting what XLA fuses): measured on a v5e at 32
+    live rows of chat-mix lengths, int8 pool, the kernel is level with
+    gather or ahead from W = 1024 at hd 512 (2% behind) and 1024 (10%
+    ahead) and from W = 256 at hd 2048, and at 2 live rows of 32 it is
+    1.2x-56x faster at every window measured (so the boundary is where the full batch stops
+    losing, never a function of live rows, which a trace cannot see).
+    Earlier rules scaled the boundary DOWN with hd below the
+    calibration (round 18) and took a measured ratio above it (PR 26);
+    both were measured with a kernel that walked every chunk.
     """
     if append_impl == "flash":
         return True
@@ -795,9 +800,8 @@ def _flash_boundary(min_w: int, hd: int) -> int:
     """The window from which the flash kernel serves geometry ``hd`` when
     ``PAGED_APPEND_FLASH_MIN_W`` is ``min_w`` > 0 (shared by the policy
     and its one-number export)."""
-    num, den = ((hd, _FLASH_HD_REF) if hd <= _FLASH_HD_REF
-                else _FLASH_WIDE_RATIO)
-    return max(_FLASH_MIN_W_FLOOR, min_w * num // den)
+    return max(_FLASH_MIN_W_FLOOR,
+               min_w * _FLASH_HD_REF // max(hd, _FLASH_HD_REF))
 
 
 def flash_append_blocked(sharded: bool = False,
@@ -854,6 +858,29 @@ def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
     return _flash_boundary(min_w, hd)
 
 
+def flash_append_chunk_pages(hd: int, itemsize: int, page_size: int,
+                             pages: int) -> int:
+    """Pages a flash-append grid program fetches and folds — its chunk
+    — for a pool of ``hd = Hkv * head_dim`` numbers a token, ``itemsize``
+    bytes each, walked ``pages`` pages a row. Pure, so the scheduler can
+    count the chunks a dispatch walks (``serve_attn_chunks_*``) with the
+    kernel's own arithmetic.
+
+    The budget is in TOKENS, bounded by the VMEM stack, NOT by the
+    window: _FLASH_CHUNK_TOK_BYTES derives the per-dtype chunk (1024
+    int8 / 512 bf16 / 256 f32 tokens at the hd=1024 calibration
+    geometry), scaled by _FLASH_HD_REF / hd so the chunk's VMEM BYTES
+    stay constant across KV geometries — narrow-KV models (bench-moe:
+    hd=512) carry 2x the tokens per chunk for the same VMEM, halving
+    the per-chunk fixed cost per window token. The grid — not a bigger
+    chunk — is what amortises per-chunk overhead, so chunks never grow
+    with W and the round-5 whole-chunk VMEM OOM cannot recur."""
+    tok_budget = max(page_size,
+                     _FLASH_CHUNK_TOK_BYTES * _FLASH_HD_REF
+                     // (hd * itemsize))
+    return max(1, min(pages, tok_budget // page_size))
+
+
 def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
                               chunk_pages: int, num_chunks: int, rep: int,
                               scale: float, compute_dtype):
@@ -877,12 +904,23 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
       indexed by global step parity — the grid replaces the round-5
       kernel-internal chunk loop, so launch overhead amortises across
       programs and no program serialises a whole window's DMA waits.
-    - **partial last chunks / non-chunk-multiple windows**: the page
-      walk index clamps to ``pages - 1`` (a redundant re-fetch of the
-      last real page) instead of skipping the DMA — uninitialised VMEM
-      garbage can be NaN, and a NaN row poisons the p.v dot even at
-      zero probability; clamped rows carry positions >= the window and
-      mask to NEG_INF like any dead slot.
+    - **work follows the rows' lengths** (``holds_rows``): a chunk that
+      starts at or past its row's length is skipped WHOLE — the
+      program before it does not fetch it, its own program neither
+      waits nor folds, and only the seed (chunk 0) and the finalise
+      (last chunk) still run. The window is the power of two over the
+      LONGEST live row, so among rows of ragged lengths (and free rows,
+      whose length is 0) most of the grid is such chunks; an empty
+      program costs a fraction of a microsecond, which is what makes
+      the window's size stop mattering.
+    - **inside a chunk that is folded** nothing is skipped: a page past
+      the row's last is fetched through its table entry (0, the garbage
+      page, by the pool contract) and masks to NEG_INF by position, and
+      in a non-chunk-multiple window the page walk index clamps to
+      ``pages - 1`` (a redundant re-fetch of the last real page).
+      Skipping single page DMAs would leave uninitialised VMEM, which
+      can be NaN, and a NaN row poisons the p.v dot even at zero
+      probability.
     - **int8 pools** (``quantized``): the per-page scale rows
       ([Hkv, ps_pad] f32, the head-major layout paged_kv.py stores for
       kernel DMAs) ride the same DMA slots; k scales fold into the
@@ -947,11 +985,25 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
 
         # Global step index orders the whole grid's chunk walk; its
         # parity picks the DMA slot (num_chunks may be odd, so parity
-        # must run THROUGH row boundaries, not reset per row).
+        # must run THROUGH row boundaries, not reset per row — and
+        # through skipped programs, which neither start nor wait on
+        # their slot).
         step = b * num_chunks + c
         slot = jax.lax.rem(step, 2)
+        rows = pl.num_programs(0)
+        Ct = chunk_pages * page_size
 
-        @pl.when(step == 0)
+        def holds_rows(bb, cc):
+            # A chunk that starts at or past its row's length (a free
+            # row's every chunk, a short row's tail under a window some
+            # other row set) is not fetched by the program before it,
+            # not waited for and not folded: every position in it would
+            # mask to NEG_INF and weigh exactly zero. The issuer and the
+            # waiter read the same length — the FETCHED row's, which at
+            # a row boundary is the next row's.
+            return cc * Ct < len_ref[jnp.minimum(bb, rows - 1)]
+
+        @pl.when((step == 0) & holds_rows(b, c))
         def _warmup():
             start_chunk(0, b, c)
 
@@ -960,7 +1012,7 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
         nb = jnp.where(c + 1 == num_chunks, b + 1, b)
         nc = jnp.where(c + 1 == num_chunks, 0, c + 1)
 
-        @pl.when(step + 1 < pl.num_programs(0) * pl.num_programs(1))
+        @pl.when((step + 1 < rows * num_chunks) & holds_rows(nb, nc))
         def _prefetch():
             start_chunk(jax.lax.rem(step + 1, 2), nb, nc)
 
@@ -969,24 +1021,14 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
         Hkv = Hq // rep
         HD = Hkv * D
 
-        # Constant selection matrices — shared with _append_kernel
-        # (_gqa_selection_matrices): the round-4 VPU win's machinery.
-        sel, blockm, blockm_t, expt = _gqa_selection_matrices(
-            Hq, Hkv, D, rep)
-        sel_c = sel.astype(compute_dtype)
-
-        # Q stacked into its kv block: [HD, Hq].
-        q_cols = jax.lax.dot(sel_c, q.T.astype(compute_dtype),
-                             preferred_element_type=jnp.float32)
-        q_blk = jnp.where(blockm, q_cols.astype(compute_dtype),
-                          jnp.zeros((), compute_dtype))          # [HD, Hq]
-
         @pl.when(c == 0)
         def _seed():
             # Append init: state = the current token's softmax term at
             # FULL precision (p_cur = exp(s_cur - m) = 1 at m = s_cur).
             # State layout matches the chunk math: m/l [1, Hq],
-            # acc [Hq, D].
+            # acc [Hq, D]. Unconditional: a row of length 0 returns
+            # this term alone.
+            expt = _gqa_expander(Hq, Hkv, rep)
             kcur = jax.lax.dot(expt, kc_ref[0].astype(jnp.float32),
                                preferred_element_type=jnp.float32)
             vcur = jax.lax.dot(expt, vc_ref[0].astype(jnp.float32),
@@ -996,46 +1038,63 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
             l_ref[:] = jnp.ones((1, Hq), jnp.float32)
             acc_ref[:] = vcur                                    # [Hq, D]
 
-        wait_chunk(slot, b, c)
-        Ct = chunk_pages * page_size
-        kflat = kbuf[slot].reshape(Ct, HD).astype(compute_dtype)
-        vflat = vbuf[slot].reshape(Ct, HD).astype(compute_dtype)
-        s = jax.lax.dot(kflat, q_blk,
-                        preferred_element_type=jnp.float32) * scale
-        if quantized:
-            # [Ct, Hkv] scale columns -> [Ct, Hq] via the expander dot
-            # (one MXU op; per-page segment concats measured
-            # overhead-bound on the VPU).
-            sk = jnp.concatenate(
-                [ksbuf[slot][i, :, :page_size].T
-                 for i in range(chunk_pages)], axis=0)           # [Ct, Hkv]
-            s = s * jax.lax.dot(sk, expt.T,
-                                preferred_element_type=jnp.float32)
-        pos = c * chunk_pages * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Ct, 1), dimension=0)
-        s = jnp.where(pos < length, s, NEG_INF)                  # [Ct, Hq]
+        @pl.when(holds_rows(b, c))
+        def _fold():
+            # Constant selection matrices — shared with _append_kernel
+            # (_gqa_selection_matrices): the round-4 VPU win's machinery.
+            sel, blockm, blockm_t, expt = _gqa_selection_matrices(
+                Hq, Hkv, D, rep)
+            sel_c = sel.astype(compute_dtype)
 
-        m_prev = m_ref[:]                                        # [1, Hq]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)                          # [1, Hq]
-        probs = jnp.exp(s - m_cur)                               # [Ct, Hq]
-        # Denominator sums the UNSCALED probabilities (v scales fold
-        # into the p.v dot only — the gather path's contract).
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=0,
-                                              keepdims=True)
-        if quantized:
-            sv = jnp.concatenate(
-                [vsbuf[slot][i, :, :page_size].T
-                 for i in range(chunk_pages)], axis=0)           # [Ct, Hkv]
-            probs = probs * jax.lax.dot(
-                sv, expt.T, preferred_element_type=jnp.float32)
-        out_full = jax.lax.dot(probs.T.astype(compute_dtype), vflat,
-                               preferred_element_type=jnp.float32)
-        out_full = jnp.where(blockm_t, out_full, 0.0)            # [Hq, HD]
-        acc_ref[:] = acc_ref[:] * alpha.T + jax.lax.dot(
-            out_full.astype(compute_dtype), sel_c,
-            preferred_element_type=jnp.float32)                  # [Hq, D]
-        m_ref[:] = m_cur
+            # Q stacked into its kv block: [HD, Hq]. Hkv copies of q's
+            # columns, the bits ``sel @ q.T`` gives (sel is 0/1), without
+            # the MXU round trip: inside this region that dot cost a
+            # live program 1.1 us of its 5 (v5e, PERF.md section 6,
+            # PR 31), and outside it every empty program 0.6 us.
+            q_cols = jnp.concatenate([q.T.astype(compute_dtype)] * Hkv,
+                                     axis=0)
+            q_blk = jnp.where(blockm, q_cols,
+                              jnp.zeros((), compute_dtype))      # [HD, Hq]
+
+            wait_chunk(slot, b, c)
+            kflat = kbuf[slot].reshape(Ct, HD).astype(compute_dtype)
+            vflat = vbuf[slot].reshape(Ct, HD).astype(compute_dtype)
+            s = jax.lax.dot(kflat, q_blk,
+                            preferred_element_type=jnp.float32) * scale
+            if quantized:
+                # [Ct, Hkv] scale columns -> [Ct, Hq] via the expander
+                # dot (one MXU op; per-page segment concats measured
+                # overhead-bound on the VPU).
+                sk = jnp.concatenate(
+                    [ksbuf[slot][i, :, :page_size].T
+                     for i in range(chunk_pages)], axis=0)       # [Ct, Hkv]
+                s = s * jax.lax.dot(sk, expt.T,
+                                    preferred_element_type=jnp.float32)
+            pos = c * Ct + jax.lax.broadcasted_iota(
+                jnp.int32, (Ct, 1), dimension=0)
+            s = jnp.where(pos < length, s, NEG_INF)              # [Ct, Hq]
+
+            m_prev = m_ref[:]                                    # [1, Hq]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)                      # [1, Hq]
+            probs = jnp.exp(s - m_cur)                           # [Ct, Hq]
+            # Denominator sums the UNSCALED probabilities (v scales fold
+            # into the p.v dot only — the gather path's contract).
+            l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=0,
+                                                  keepdims=True)
+            if quantized:
+                sv = jnp.concatenate(
+                    [vsbuf[slot][i, :, :page_size].T
+                     for i in range(chunk_pages)], axis=0)       # [Ct, Hkv]
+                probs = probs * jax.lax.dot(
+                    sv, expt.T, preferred_element_type=jnp.float32)
+            out_full = jax.lax.dot(probs.T.astype(compute_dtype), vflat,
+                                   preferred_element_type=jnp.float32)
+            out_full = jnp.where(blockm_t, out_full, 0.0)        # [Hq, HD]
+            acc_ref[:] = acc_ref[:] * alpha.T + jax.lax.dot(
+                out_full.astype(compute_dtype), sel_c,
+                preferred_element_type=jnp.float32)              # [Hq, D]
+            m_ref[:] = m_cur
 
         @pl.when(c == num_chunks - 1)
         def _finalise():
@@ -1056,32 +1115,19 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
     online softmax carried in VMEM scratch across the chunk axis and
     seeded with the current token (_flash_append_kernel_body). HBM reads
     each page exactly once per (layer, step) — no gathered-window
-    materialisation — which is what makes it the long-window win and,
-    since round 8, the DEFAULT dispatch at W >= 2048 on TPU; below
-    ``_flash_append_min_w()`` the gather path's XLA fusion amortises
-    better and stays default (module docstring has the measured
-    ladder)."""
+    materialisation — and only the pages of chunks that start inside
+    their row's context (PR 31). The DEFAULT dispatch from the
+    geometry's boundary up on TPU (_flash_append_policy: W >= 1024 at
+    hd <= 1024); below it the gather path's XLA fusion is no slower at
+    a full batch and stays default."""
     B, Hq, D = q.shape
     L, N, page_size, Hkv, _ = k_pages.shape
     rep = Hq // Hkv
     scale = 1.0 / (D ** 0.5)
     pt = page_table[:, :pages].astype(jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    # Chunk budget in TOKENS, bounded by the VMEM stack, NOT by the
-    # window: _FLASH_CHUNK_TOK_BYTES derives the per-dtype chunk (1024
-    # int8 / 512 bf16 / 256 f32 tokens at the hd=1024 calibration
-    # geometry), scaled by _FLASH_HD_REF / hd so the chunk's VMEM BYTES
-    # stay constant across KV geometries — narrow-KV models (bench-moe:
-    # hd=512) carry 2x the tokens per chunk for the same VMEM, halving
-    # the per-chunk fixed cost per window token. The grid — not a
-    # bigger chunk — is what amortises per-chunk overhead now, so
-    # chunks never grow with W and the round-5 whole-chunk VMEM OOM
-    # cannot recur.
-    hd = Hkv * D
-    tok_budget = max(page_size,
-                     _FLASH_CHUNK_TOK_BYTES * _FLASH_HD_REF
-                     // (hd * k_pages.dtype.itemsize))
-    chunk_pages = max(1, min(pages, tok_budget // page_size))
+    chunk_pages = flash_append_chunk_pages(
+        Hkv * D, k_pages.dtype.itemsize, page_size, pages)
     num_chunks = -(-pages // chunk_pages)
     # bf16 math on hardware; f32 in interpret mode so CPU parity tests
     # pin against the oracle at full precision (the body's dataflow is

@@ -59,6 +59,7 @@ from ..models.llama import KVCache
 from ..models.sampling import sample_batched, sample_step_batched
 from ..obs.flight import FlightRecorder
 from ..obs.phase import LoopPhases, compile_clock, process_age_s
+from ..ops.paged_attention import flash_append_chunk_pages
 from ..ops.paged_kv import (PageAllocator, PagedKVCache, copy_slot,
                             gather_pages, scatter_pages, set_row_table,
                             write_prefill_batch, write_prefill_chunk)
@@ -685,6 +686,12 @@ class BatchScheduler:
         # Cache rows the decode steps' live rows attended (the sum, over
         # row-steps, of the row's context length at that step).
         self._n_attn_ctx_tokens = 0      # owned-by: _loop
+        # Decode dispatches whose window runs the flash-append kernel:
+        # the (row, chunk) programs of its grid, a step, and those of
+        # them whose chunk starts inside its row's context — the ones
+        # the kernel fetches and folds (_note_attn_chunks).
+        self._n_attn_chunks = 0          # owned-by: _loop
+        self._n_attn_chunks_walked = 0   # owned-by: _loop
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -3276,6 +3283,8 @@ class BatchScheduler:
             "serve_moe_dropped_total": self._n_moe_dropped,
             "serve_decode_row_steps_total": self._n_decode_row_steps,
             "serve_attn_context_tokens_total": self._n_attn_ctx_tokens,
+            "serve_attn_chunks_total": self._n_attn_chunks,
+            "serve_attn_chunks_walked_total": self._n_attn_chunks_walked,
             "serve_decode_clean_seconds_total": self._clean_s,
             "serve_decode_clean_steps_total": self._clean_steps,
             # Boot, set once: process start (the OS's record) until
@@ -4150,6 +4159,8 @@ class BatchScheduler:
         # ctx_len + inflight + K - 1 (floor 1 keeps K=1 selection
         # identical to the pre-fusion program ladder).
         decode_w = self._window(extra=max(1, inflight + K - 1))
+        if 0 < self._paged_flash_min_w <= decode_w:
+            self._note_attn_chunks(decode_w, K, inflight)
         if K == 1:
             decode_j = self._decode_for(decode_w)
         else:
@@ -4160,6 +4171,26 @@ class BatchScheduler:
             self._temps_dev, self._top_ks_dev, self._top_ps_dev, self._keys,
             self._ring_dev, self._rps_dev)
         return toks_dev, list(self._slots), K
+
+    def _note_attn_chunks(self, window: int, K: int, inflight: int) -> None:
+        """Count what a decode dispatch at ``window`` asks of the
+        flash-append kernel (ops/paged_attention.py), a layer: its grid
+        is rows x chunks of the window at every one of the K steps, and
+        a program fetches and folds its chunk only where the chunk
+        starts inside its row's context (step j of the K sees ctx_len +
+        inflight + j cached rows; a free row holds none). Host
+        arithmetic on what the loop holds, with the kernel's own chunk
+        size."""
+        ps = self.page_size
+        pages = -(-window // ps)
+        chunk_pages = flash_append_chunk_pages(
+            self.config.kv_dim, self._cache.k.dtype.itemsize, ps, pages)
+        n_chunks = -(-pages // chunk_pages)
+        ct = chunk_pages * ps
+        self._n_attn_chunks += len(self._slots) * n_chunks * K
+        self._n_attn_chunks_walked += sum(
+            min(n_chunks, -(-(s.ctx_len + inflight + j) // ct))
+            for s in self._slots if s is not None for j in range(K))
 
     def _process_tick(self, toks_dev, snapshot: list, K: int = 1) -> None:
         """Host half of a decode tick: read the sampled tokens back and

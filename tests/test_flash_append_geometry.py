@@ -159,56 +159,211 @@ def test_mixed_length_batch_rows_finish_in_different_chunks(
                 monkeypatch, seed=1)
 
 
+def _poison_dead_chunks(cache, lengths, pages, chunk_pages, quantized):
+    """Overwrite every page of every chunk that starts at or past its
+    row's length: NaN in a float pool; in an int8 pool +-127 with NaN
+    scale rows. A kernel that fetched and folded such a chunk after all
+    would carry NaN through ``0 * NaN`` in the p.v dot or the v-scale
+    fold, instead of masking it out."""
+    ps = cache.page_size
+    dead = [1 + b * pages + p
+            for b, n in enumerate(lengths)
+            for p in range(-(-n // (chunk_pages * ps)) * chunk_pages, pages)]
+    if not dead:
+        return cache
+    dead = jnp.asarray(dead, jnp.int32)
+    if quantized:
+        k = cache.k.at[:, dead].set(127)
+        v = cache.v.at[:, dead].set(-127)
+        return cache._replace(
+            k=k, v=v, k_scale=cache.k_scale.at[:, dead].set(jnp.nan),
+            v_scale=cache.v_scale.at[:, dead].set(jnp.nan))
+    return cache._replace(k=cache.k.at[:, dead].set(jnp.nan),
+                          v=cache.v.at[:, dead].set(jnp.nan))
+
+
+# Ct = 16 tokens = 2 pages at PS = 8 in every case below (64 bytes of
+# f32, 16 of int8). Lengths are positions already in the pool.
+CT = 16
+_RAGGED = {
+    # 4 chunks a row (even): 0, 1, Ct - 1, Ct, Ct + 1 and the whole
+    # window in one batch. Behind the row boundary 0 -> 1 the next
+    # row's chunk 0 is live, behind 5 -> 6 it is dead (a free row after
+    # a full one), behind 6 -> 7 live again, after two dead rows.
+    "even-4-chunks": (8, [0, 1, CT - 1, CT, CT + 1, 64, 0, 0, 33]),
+    # 3 chunks a row (odd, the last one partial and clamped): slot
+    # parity runs through rows that skip everything; the batch opens on
+    # a full row (the warm-up fetch) and closes on a free one.
+    "odd-3-chunks": (5, [40, 0, 0, CT + 1, 1, 39, CT, 0]),
+    # The batch opens on a free row: no warm-up fetch at step 0, and
+    # the first live chunk is fetched by an empty program.
+    "free-row-first": (4, [0, 0, 2 * CT, 5, 0, 32, 31]),
+}
+
+
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("quantized", [False, True], ids=["fppool", "int8pool"])
+@pytest.mark.parametrize("case", sorted(_RAGGED))
+def test_chunks_past_a_rows_length_are_not_read(case, quantized, rep,
+                                                monkeypatch):
+    """The kernel's work follows the rows' lengths: against the gather
+    path (and, on a float pool, the index-naive reference) on the pool
+    as filled, while the kernel reads a copy whose dead chunks are
+    poisoned. Every row, lengths 0 and the whole window included,
+    agrees; nothing is NaN."""
+    pages, lengths = _RAGGED[case]
+    cfg = get_config("tiny").with_(num_heads=4, num_kv_heads=4 // rep)
+    monkeypatch.setattr(pa, "_FLASH_CHUNK_TOK_BYTES", 16 if quantized else 64)
+    monkeypatch.setattr(pa, "_FLASH_HD_REF",
+                        cfg.num_kv_heads * cfg.head_dim)
+    monkeypatch.setattr(pa, "_APPEND_IMPL", "gather")  # pin the oracle path
+    rng = np.random.default_rng(7)
+    cache = _filled_cache(cfg, pages, PS, lengths, quantized, rng)
+    chunk_pages = pa.flash_append_chunk_pages(
+        cfg.num_kv_heads * cfg.head_dim, cache.k.dtype.itemsize, PS, pages)
+    assert chunk_pages * PS == CT
+    poisoned = _poison_dead_chunks(cache, lengths, pages, chunk_pages,
+                                   quantized)
+    B = len(lengths)
+    q = jnp.asarray(rng.normal(size=(B, cfg.num_heads, cfg.head_dim)),
+                    jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(B, cfg.num_kv_heads, cfg.head_dim)),
+                     jnp.float32)
+    vc = jnp.asarray(rng.normal(size=kc.shape), jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    for layer in range(cfg.num_layers):
+        kern = np.asarray(pa._paged_attention_flash_append(
+            q, kc, vc, poisoned.k, poisoned.v, poisoned.k_scale,
+            poisoned.v_scale, poisoned.page_table, lens, jnp.asarray(layer),
+            pages=pages, quantized=quantized, interpret=True))
+        assert np.isfinite(kern).all(), f"a dead chunk was read: layer {layer}"
+        ref = pa.paged_attention_append(q, kc, vc, cache, lens,
+                                        jnp.asarray(layer), pages=pages,
+                                        interpret=True)
+        np.testing.assert_allclose(kern, np.asarray(ref), atol=2e-5,
+                                   rtol=2e-5, err_msg=f"layer {layer}")
+        # A row of length 0 returns its current token's value, exactly.
+        for b in (b for b, n in enumerate(lengths) if n == 0):
+            np.testing.assert_allclose(
+                kern[b], np.repeat(np.asarray(vc[b]), rep, axis=0),
+                atol=1e-6)
+        if not quantized:
+            # The independent oracle writes the current token first, so
+            # it has no slot for a row that already fills the window.
+            fits = np.asarray(lengths) < pages * PS
+            c2 = paged_kv.write_decode(cache, jnp.asarray(layer), kc, vc)
+            ref2 = paged_attention_reference(
+                q, c2.k, c2.v, c2.page_table, lens + 1, layer, pages=pages)
+            np.testing.assert_allclose(kern[fits], np.asarray(ref2)[fits],
+                                       atol=2e-5, rtol=2e-5,
+                                       err_msg=f"vs reference: layer {layer}")
+
+
+def _chunking_used(monkeypatch, hd, dtype, ps, pages):
+    """(chunk_pages, num_chunks) that _paged_attention_flash_append hands
+    its kernel body for a pool of this geometry, read while it traces."""
+    seen = []
+    real = pa._flash_append_kernel_body
+
+    def spy(quantized, page_size, n_pages, chunk_pages, num_chunks, *rest):
+        seen.append((chunk_pages, num_chunks))
+        return real(quantized, page_size, n_pages, chunk_pages, num_chunks,
+                    *rest)
+
+    monkeypatch.setattr(pa, "_flash_append_kernel_body", spy)
+    Hkv, D, B = hd // 128, 128, 2
+    quantized = dtype == jnp.int8
+    pool = jax.ShapeDtypeStruct((1, B * pages + 1, ps, Hkv, D), dtype)
+    scales = (jax.ShapeDtypeStruct((1, B * pages + 1, Hkv, 128), jnp.float32)
+              if quantized else None)
+    row = jax.ShapeDtypeStruct((B, Hkv, D), jnp.bfloat16)
+    jax.eval_shape(
+        lambda *a: pa._paged_attention_flash_append.__wrapped__(
+            *a, pages=pages, quantized=quantized, interpret=True),
+        row, row, row, pool, pool, scales, scales,
+        jax.ShapeDtypeStruct((B, pages), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    return seen[-1]
+
+
+@pytest.mark.parametrize("dtype,itemsize", [(jnp.int8, 1), (jnp.bfloat16, 2)],
+                         ids=["int8", "bf16"])
+@pytest.mark.parametrize("hd,int8_tokens", [(512, 2048), (1024, 1024),
+                                            (2048, 512)])
+def test_chunk_size_function_is_the_kernels(hd, int8_tokens, dtype, itemsize,
+                                            monkeypatch):
+    """flash_append_chunk_pages is the one home of the chunk arithmetic:
+    what the dispatch hands its kernel, at the served geometries
+    (bench-moe 512, Mistral / Mixtral 1,024, OLMoE 2,048), both pool
+    dtypes, below and above one chunk of window."""
+    ps = 64
+    for W in (256, 2048, 4096):
+        pages = W // ps
+        want = min(pages, int8_tokens // itemsize // ps)
+        assert pa.flash_append_chunk_pages(hd, itemsize, ps, pages) == want
+        assert _chunking_used(monkeypatch, hd, dtype, ps, pages) == (
+            want, -(-pages // want))
+
+
 def test_dispatch_policy_table(monkeypatch):
     """The pure dispatch rule (decision table) plus the two runtime
     properties the satellites pin: the threshold is read per decision —
     flipping PAGED_APPEND_FLASH_MIN_W needs NO re-import — and the
     platform guard keeps gather everywhere on CPU."""
-    # Default boundary: kernel at W >= 2048, gather below.
+    # Default boundary (PR 31; it was 2048 while the kernel walked every
+    # chunk): kernel at W >= 1024, gather below.
     monkeypatch.delenv("PAGED_APPEND_FLASH_MIN_W", raising=False)
-    assert pa._flash_append_min_w() == 2048
-    assert pa._flash_append_policy(2048, "gather", 2048)
-    assert pa._flash_append_policy(4096, "gather", 2048)
-    assert not pa._flash_append_policy(1024, "gather", 2048)
-    assert not pa._flash_append_policy(192, "gather", 2048)
+    assert pa._flash_append_min_w() == 1024
+    assert pa._flash_append_policy(2048, "gather", 1024)
+    assert pa._flash_append_policy(4096, "gather", 1024)
+    assert pa._flash_append_policy(1024, "gather", 1024)
+    assert not pa._flash_append_policy(512, "gather", 1024)
+    assert not pa._flash_append_policy(192, "gather", 1024)
     # 0 disables the flash default outright.
     assert not pa._flash_append_policy(1 << 20, "gather", 0)
     # Explicit impl overrides win in both directions.
-    assert pa._flash_append_policy(64, "flash", 2048)
-    assert not pa._flash_append_policy(1 << 20, "kernel", 2048)
-    # Geometry scaling (round-18): the boundary is min_w * hd / 1024.
-    # At the calibration geometry (hd=1024) nothing changes; at
-    # bench-moe's narrow KV (4 kv heads x 128 = 512) it halves to 1024
-    # — the window regime where the recorded ~1.3 ms MoE paged-walk gap
-    # lived; at 70B-class hd=1024 it is identity again.
-    assert pa._flash_append_policy(2048, "gather", 2048, hd=1024)
-    assert not pa._flash_append_policy(1024, "gather", 2048, hd=1024)
-    assert pa._flash_append_policy(1024, "gather", 2048, hd=512)
-    assert not pa._flash_append_policy(1023, "gather", 2048, hd=512)
-    assert pa._flash_append_policy(512, "gather", 2048, hd=256)
+    assert pa._flash_append_policy(64, "flash", 1024)
+    assert not pa._flash_append_policy(1 << 20, "kernel", 1024)
+    # One rule for every geometry (PR 31): the knob itself up to the
+    # calibration width (hd <= 1024), scaled down by 1024 / hd for wider
+    # ones. bench-moe's narrow KV (4 kv heads x 128 = 512) stays at 1024,
+    # where round 18's hd / 1024 scaling of the old knob had put it; the
+    # 70B class (hd = 1024) moves 2048 -> 1024.
+    assert pa._flash_append_policy(2048, "gather", 1024, hd=1024)
+    assert pa._flash_append_policy(1024, "gather", 1024, hd=1024)
+    assert not pa._flash_append_policy(1023, "gather", 1024, hd=1024)
+    assert pa._flash_append_policy(1024, "gather", 1024, hd=512)
+    assert not pa._flash_append_policy(1023, "gather", 1024, hd=512)
+    # Narrower than any served geometry: no longer scaled down (the
+    # full batch loses there; measured at hd 512, W = 512: 0.075 ms
+    # gather against 0.111).
+    assert not pa._flash_append_policy(512, "gather", 1024, hd=256)
+    assert pa._flash_append_policy(1024, "gather", 1024, hd=256)
     # The floor: no geometry engages below 256 tokens on the default
-    # rule (sub-2-chunk grids cannot pipeline).
-    assert not pa._flash_append_policy(255, "gather", 2048, hd=32)
-    assert pa._flash_append_policy(256, "gather", 2048, hd=32)
-    # Wider-than-calibration KV (OLMoE's MHA, hd=2048): the ratio to the
-    # knob is measured, not extrapolated: the kernel won 3.5-6.6x at W = 512..2048 on a v5e (PERF.md
-    # section 6, PR 26), where the scaled rule said gather below 4096.
-    assert pa._flash_append_policy(512, "gather", 2048, hd=2048)
-    assert pa._flash_append_policy(2048, "gather", 2048, hd=2048)
-    assert not pa._flash_append_policy(256, "gather", 2048, hd=2048)
+    # rule, however wide.
+    assert not pa._flash_append_policy(255, "gather", 1024, hd=8192)
+    assert pa._flash_append_policy(256, "gather", 1024, hd=8192)
+    assert not pa._flash_append_policy(256, "gather", 1024, hd=32)
+    # Wider-than-calibration KV (OLMoE's MHA, hd=2048): 1024 / hd of the
+    # knob, W >= 512 as PR 26 measured it (3.2-11.5x at 32 live rows on
+    # a v5e, PERF.md section 6, PR 31).
+    assert pa._flash_append_policy(512, "gather", 1024, hd=2048)
+    assert pa._flash_append_policy(2048, "gather", 1024, hd=2048)
+    assert not pa._flash_append_policy(256, "gather", 1024, hd=2048)
     assert not pa._flash_append_policy(2048, "gather", 0, hd=2048)
     assert pa.effective_flash_min_w(hd=2048) in (0, 512)   # 0: not a TPU
-    # The knob scales that geometry too: a quarter of it.
-    assert pa._flash_append_policy(1024, "gather", 4096, hd=2048)
-    assert not pa._flash_append_policy(512, "gather", 4096, hd=2048)
+    # The knob scales that geometry too: half of it.
+    assert pa._flash_append_policy(1024, "gather", 2048, hd=2048)
+    assert not pa._flash_append_policy(512, "gather", 2048, hd=2048)
     # Overrides ignore geometry.
-    assert pa._flash_append_policy(64, "flash", 2048, hd=2048)
-    assert not pa._flash_append_policy(1 << 20, "kernel", 2048, hd=256)
+    assert pa._flash_append_policy(64, "flash", 1024, hd=2048)
+    assert not pa._flash_append_policy(1 << 20, "kernel", 1024, hd=256)
     # Runtime toggle: read through utils/env at dispatch time.
     monkeypatch.setenv("PAGED_APPEND_FLASH_MIN_W", "4096")
     assert pa._flash_append_min_w() == 4096
     monkeypatch.setenv("PAGED_APPEND_FLASH_MIN_W", "")
-    assert pa._flash_append_min_w() == 2048      # empty = unset
+    assert pa._flash_append_min_w() == 1024      # empty = unset
     monkeypatch.delenv("PAGED_APPEND_FLASH_MIN_W", raising=False)
     if not pa.on_tpu():
         # CPU CI: the platform guard must hold regardless of the policy,
@@ -222,15 +377,16 @@ def test_dispatch_policy_table(monkeypatch):
     # -k dispatch_policy`; conftest would pin the CPU) — the guard opens
     # at the geometry-scaled boundary, and shuts again for a pool
     # sharded over a mesh (pallas_call cannot consume one).
-    assert pa._flash_append_wanted(2048) and not pa._flash_append_wanted(1024)
+    assert pa._flash_append_wanted(1024) and not pa._flash_append_wanted(512)
     assert pa._flash_append_wanted(1024, 512)
-    assert pa.effective_flash_min_w() == 2048
+    assert not pa._flash_append_wanted(512, 512)
+    assert pa.effective_flash_min_w() == 1024
     assert pa.effective_flash_min_w(512) == 1024
+    assert pa.effective_flash_min_w(2048) == 512
     assert not pa._flash_append_wanted(1 << 20, sharded=True)
     assert pa.effective_flash_min_w(sharded=True) == 0
     # ... and for a head_dim that does not fill 128-lane rows: Mosaic
-    # refuses the kernel there (seen on the chip at tiny's D=32, where
-    # the scaled boundary would engage it from W=256).
+    # refuses the kernel there (seen on the chip at tiny's D=32).
     assert not pa._flash_append_wanted(1 << 20, 64, head_dim=32)
     assert pa.effective_flash_min_w(64, head_dim=32) == 0
     assert "128 lanes" in pa.flash_append_blocked(head_dim=32)

@@ -10,11 +10,16 @@ the gather path; then the two write-then-attend decode kernels behind
 lowering; this is the hardware check. Shapes have llama3.1-8b's
 attention geometry (32 query / 8 kv heads x 128, page size 64, B=32).
 Every kernel runs to the end and gets one ``VERDICT <kernel>:
-PASS|FAIL`` line; the exit code is non-zero when any FAILs.
+PASS|FAIL`` line; the exit code is non-zero when any FAILs. The
+flash-append kernel also runs at ragged lengths with free rows (most of
+its grid skipped). ``time`` prints, by pool, width and window, gather
+against flash-append at a full and at a part-full batch: the
+measurement behind the dispatch boundary.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 import time
@@ -28,13 +33,34 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import importlib  # noqa: E402
 
 from p2p_llm_chat_tpu.models.configs import get_config  # noqa: E402
-from tools.kernel_verdicts import require_tpu, run_cases  # noqa: E402
+from tools.kernel_verdicts import (SlowerThanXLA, require_tpu,  # noqa: E402
+                                    run_cases)
 
 # The ops package __init__ rebinds `paged_attention` to the function;
 # importlib reaches the module.
 pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
 from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,  # noqa: E402
                                            write_prefill_row)
+
+
+@contextlib.contextmanager
+def _gather_pinned():
+    """Pin ``paged_attention_append`` to the XLA gather path on BOTH
+    dispatch axes while a reference or a gather timing is traced:
+    _APPEND_IMPL picks the impl family, and the min-W toggle must be 0
+    or the default rule would route the "reference" itself to the flash
+    kernel at long windows — a vacuous self-comparison."""
+    saved = (pa._APPEND_IMPL, os.environ.get("PAGED_APPEND_FLASH_MIN_W"))
+    pa._APPEND_IMPL = "gather"
+    os.environ["PAGED_APPEND_FLASH_MIN_W"] = "0"
+    try:
+        yield
+    finally:
+        pa._APPEND_IMPL = saved[0]
+        if saved[1] is None:
+            os.environ.pop("PAGED_APPEND_FLASH_MIN_W", None)
+        else:
+            os.environ["PAGED_APPEND_FLASH_MIN_W"] = saved[1]
 
 
 def _block_kernel(q, k_cur, v_cur, cache, lens, layer, *, pages,
@@ -55,6 +81,9 @@ def _flash_kernel(q, k_cur, v_cur, cache, lens, layer, *, pages,
 # MHA (rep 1, 16 heads — one-row scratch slices and [1, D] x [D, page]
 # dots, sixteen times over).
 GQA, MHA16 = (32, 8), (16, 16)
+# bench-moe's narrow KV (4 kv heads x 128 = 512 numbers a token), for the
+# boundary's timing only.
+NARROW = (32, 4)
 
 
 def _cfg(heads: tuple, layers=2):
@@ -63,7 +92,7 @@ def _cfg(heads: tuple, layers=2):
 
 
 def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
-        label="block", seed=0, heads=GQA) -> None:
+        label="block", seed=0, heads=GQA, lengths=None) -> None:
     """Shared harness: random bf16/int8 pool filled through the real
     splice op, ``kernel`` vs the gather append path at first/last layer.
 
@@ -83,10 +112,9 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
     cache = PagedKVCache.create(cfg, B, num_pages, ps,
                                 max_pages_per_row=mppr, dtype=jnp.bfloat16,
                                 quantized=quantized)
-    lengths = []
-    for b in range(B):
-        n = int(rng.integers(1, pages * ps - 1))
-        lengths.append(n)
+    if lengths is None:
+        lengths = [int(rng.integers(1, pages * ps - 1)) for _ in range(B)]
+    for b, n in enumerate(lengths):
         table = jnp.asarray(1 + b * mppr + np.arange(mppr), jnp.int32)
         # Pool contents come from the device's own generator: 32 rows
         # of host normals cost minutes of chip time.
@@ -108,24 +136,9 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
     for layer in (0, cfg.num_layers - 1):
         kern = kernel(q, k_cur, v_cur, cache, lens, jnp.asarray(layer),
                       pages=pages, quantized=quantized)
-        # Pin the reference to the XLA gather path on BOTH dispatch
-        # axes: _APPEND_IMPL picks the impl family, and the min-W
-        # toggle must be 0 or the round-8 default would route the
-        # "reference" itself to the flash kernel at the long windows
-        # run_flash uses (W=3072 >= 2048) — a vacuous self-comparison.
-        saved = pa._APPEND_IMPL
-        saved_min_w = os.environ.get("PAGED_APPEND_FLASH_MIN_W")
-        pa._APPEND_IMPL = "gather"
-        os.environ["PAGED_APPEND_FLASH_MIN_W"] = "0"
-        try:
+        with _gather_pinned():
             ref = pa.paged_attention_append(q, k_cur, v_cur, cache, lens,
                                             jnp.asarray(layer), pages=pages)
-        finally:
-            pa._APPEND_IMPL = saved
-            if saved_min_w is None:
-                os.environ.pop("PAGED_APPEND_FLASH_MIN_W", None)
-            else:
-                os.environ["PAGED_APPEND_FLASH_MIN_W"] = saved_min_w
         kn, rn = np.asarray(kern, np.float32), np.asarray(ref, np.float32)
         err = np.max(np.abs(kn - rn))
         denom = np.max(np.abs(rn)) or 1.0
@@ -139,6 +152,25 @@ def run_flash(quantized: bool, B=32, pages=48, ps=64, heads=GQA) -> None:
     window — see run()'s docstring for what that exercises."""
     run(quantized, B, pages, ps, kernel=_flash_kernel, label="flash",
         seed=1, heads=heads)
+
+
+def run_flash_ragged(quantized: bool, B=32, pages=48, ps=64,
+                     heads=GQA) -> None:
+    """The flash-append kernel where most of its grid is skipped: rows
+    of length 0 (a free row: no chunk fetched, the current token's term
+    alone), 1, one either side of a chunk boundary, and the whole
+    window, in an order that puts a dead chunk 0 and a live one behind
+    a row boundary. Mosaic's lowering of the guarded DMA starts and
+    waits is what the CPU tests cannot cover."""
+    cfg = _cfg(heads)
+    ct = ps * pa.flash_append_chunk_pages(
+        cfg.num_kv_heads * cfg.head_dim, 1 if quantized else 2, ps, pages)
+    W = pages * ps
+    edge = [0, 1, ct - 1, ct, ct + 1, W - 1, 0, 0, W - 1, 0, 2 * ct, 300]
+    rng = np.random.default_rng(5)
+    lengths = edge + [int(n) for n in rng.integers(0, W - 1, B - len(edge))]
+    run(quantized, B, pages, ps, kernel=_flash_kernel, label="flash ragged",
+        seed=5, heads=heads, lengths=lengths)
 
 
 def _close(got, ref, what: str) -> None:
@@ -190,21 +222,25 @@ def run_prefill_flash(B=1, S=2048, heads=GQA) -> None:
            f"prefill flash B={B} S={S}")
 
 
-def time_append(heads, W: int, B=32, ps=64, repeat=16, steps=10) -> None:
-    """Milliseconds a layer-step of the int8 pool's append attention, the
-    XLA gather path against the flash-append kernel, at window ``W``:
-    the measurement behind the flash-append boundary
-    (ops/paged_attention._flash_append_policy). Contexts as a full
-    backlog batch holds them: 128 to 900 tokens, and one row that needs
-    the window. One dispatch runs ``repeat`` layer-steps (a lone call
-    measures the host)."""
+def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
+                steps=10) -> None:
+    """Milliseconds a layer-step of append attention, the XLA gather
+    path against the flash-append kernel, at window ``W`` and two
+    occupancies: the measurement behind the flash-append boundary
+    (ops/paged_attention._flash_append_policy). *32 live rows*: contexts
+    as a full backlog batch holds them, 128 to 900 tokens, and one row
+    that needs the window. *2 live rows of 32*: that row and one other,
+    the rest free (length 0, their page-table rows zeroed as
+    ``_release`` leaves them): a steady cell's batch. The kernel's work
+    follows the lengths, the gather path's the window. One dispatch runs
+    ``repeat`` layer-steps (a lone call measures the host)."""
     cfg = _cfg(heads)
     pages = W // ps
     rng = np.random.default_rng(W)
     key = jax.random.PRNGKey(W)
     cache = PagedKVCache.create(cfg, B, B * pages + 1, ps,
                                 max_pages_per_row=pages, dtype=jnp.bfloat16,
-                                quantized=True)
+                                quantized=quantized)
     lengths = [int(n) for n in rng.integers(128, min(900, W - 1), size=B)]
     lengths[0] = W - 2
     for b, n in enumerate(lengths):
@@ -216,45 +252,56 @@ def time_append(heads, W: int, B=32, ps=64, repeat=16, steps=10) -> None:
                                jnp.bfloat16)
         cache = write_prefill_row(cache, rk, rv, jnp.asarray(b),
                                   jnp.asarray(n), table)
-    lens = jnp.asarray(lengths, jnp.int32)
     q = jax.random.normal(key, (B, cfg.num_heads, cfg.head_dim),
                           jnp.bfloat16)
     k_cur = jax.random.normal(jax.random.fold_in(key, 99),
                               (B, cfg.num_kv_heads, cfg.head_dim),
                               jnp.bfloat16)
 
-    def timed(one) -> float:
+    def timed(one, cache, lens) -> float:
         @jax.jit
-        def run(q, cache):
+        def run(q, cache, lens):
             def body(i, acc):
                 return acc + one(q, k_cur, k_cur, cache, lens,
                                  i % cfg.num_layers)
             return jax.lax.fori_loop(0, repeat, body, jnp.zeros_like(q))
-        np.asarray(run(q, cache)).ravel()[:1]
+        np.asarray(run(q, cache, lens)).ravel()[:1]
         t = time.monotonic()
         for _ in range(steps):
-            out = run(q, cache)
+            out = run(q, cache, lens)
         np.asarray(out).ravel()[:1]
         return (time.monotonic() - t) / steps / repeat * 1e3
 
-    saved = (pa._APPEND_IMPL, os.environ.get("PAGED_APPEND_FLASH_MIN_W"))
-    pa._APPEND_IMPL = "gather"
-    os.environ["PAGED_APPEND_FLASH_MIN_W"] = "0"
-    try:
-        gather = timed(lambda *a: pa.paged_attention_append(*a, pages=pages))
-    finally:
-        pa._APPEND_IMPL = saved[0]
-        if saved[1] is None:
-            os.environ.pop("PAGED_APPEND_FLASH_MIN_W", None)
-        else:
-            os.environ["PAGED_APPEND_FLASH_MIN_W"] = saved[1]
-    flash = timed(lambda *a: _flash_kernel(*a, pages=pages, quantized=True))
+    def gather_path(*a):
+        return pa.paged_attention_append(*a, pages=pages)
+
+    def flash_path(*a):
+        return _flash_kernel(*a, pages=pages, quantized=quantized)
+
     hd = cfg.num_kv_heads * cfg.head_dim
-    print(f"append int8 heads={heads} hd={hd} W={W} B={B}: gather "
-          f"{gather:.4f} ms, flash {flash:.4f} ms a layer-step "
-          f"({gather / flash:.2f}x); the rule says "
-          f"{'flash' if pa._flash_append_policy(W, 'auto', pa._flash_append_min_w(), hd) else 'gather'}",
-          flush=True)
+    rule = ("flash" if pa._flash_append_policy(
+        W, "auto", pa._flash_append_min_w(), hd) else "gather")
+    pool = "int8" if quantized else "bf16"
+    lost = []
+    for live in (B, 2):
+        lens = jnp.asarray(lengths[:live] + [0] * (B - live), jnp.int32)
+        state = cache._replace(
+            page_table=cache.page_table.at[live:].set(0), lengths=lens)
+        with _gather_pinned():
+            gather = timed(gather_path, state, lens)
+        flash = timed(flash_path, state, lens)
+        tokens = sum(lengths[:live])
+        print(f"append {pool} heads={heads} hd={hd} W={W} live={live}/"
+              f"{B} ({tokens} cached tokens): gather {gather:.4f} ms, "
+              f"flash {flash:.4f} ms a layer-step "
+              f"({gather / flash:.2f}x); the rule says {rule}",
+              flush=True)
+        if (flash > gather) == (rule == "flash"):
+            lost.append(f"{live} live: gather {gather:.4f}, flash "
+                        f"{flash:.4f}")
+    if lost:
+        raise SlowerThanXLA(f"the rule's {rule} is the slower path at "
+                            + "; ".join(lost))
 
 
 def main() -> int:
@@ -262,14 +309,22 @@ def main() -> int:
     # ``python tools/check_append_kernel.py time``: the timing behind the
     # flash-append boundary, not the verdicts.
     if len(sys.argv) > 1 and sys.argv[1] == "time":
-        for heads in (MHA16, GQA):
-            for W in (512, 1024, 2048):
-                time_append(heads, W)
+        run_cases(tuple(
+            (f"time {'int8' if quantized else 'bf16'} heads={heads} W={W}",
+             lambda h=heads, w=W, qz=quantized: time_append(h, w, qz))
+            for quantized, heads in ((True, MHA16), (True, GQA),
+                                     (True, NARROW), (False, MHA16),
+                                     (False, GQA))
+            for W in (256, 512, 1024, 2048)))
         return 0
     cases = (("block int8", lambda: run(quantized=True)),
              ("block bf16", lambda: run(quantized=False)),
              ("flash-append int8", lambda: run_flash(quantized=True)),
              ("flash-append bf16", lambda: run_flash(quantized=False)),
+             ("flash-append ragged int8",
+              lambda: run_flash_ragged(quantized=True)),
+             ("flash-append ragged bf16",
+              lambda: run_flash_ragged(quantized=False)),
              ("decode impl=kernel bf16", lambda: run_decode_impl("kernel")),
              ("decode impl=flash bf16", lambda: run_decode_impl("flash")),
              ("prefill flash", run_prefill_flash),
@@ -283,6 +338,8 @@ def main() -> int:
               lambda: run_flash(quantized=True, heads=MHA16)),
              ("mha16 flash-append bf16",
               lambda: run_flash(quantized=False, heads=MHA16)),
+             ("mha16 flash-append ragged int8",
+              lambda: run_flash_ragged(quantized=True, heads=MHA16)),
              ("mha16 decode impl=kernel bf16",
               lambda: run_decode_impl("kernel", heads=MHA16)),
              ("mha16 decode impl=flash bf16",
