@@ -22,7 +22,7 @@ from . import llama
 
 
 def family_for(config: ModelConfig):
-    """The model module (llama, mixtral or pangu) implementing this config.
+    """The model module (llama, mixtral, pangu or nemotron_h) implementing this config.
 
     Both families expose the same functional surface — init_params,
     param_axes, prefill, decode_step (identical signatures and KVCache
@@ -32,6 +32,9 @@ def family_for(config: ModelConfig):
     if config.is_latent:
         from . import pangu
         return pangu
+    if config.is_hybrid:
+        from . import nemotron_h
+        return nemotron_h
     if config.is_moe:
         from . import mixtral
         return mixtral

@@ -81,6 +81,36 @@ class ModelConfig:
     # each logit; ``routed_scaling_factor`` multiplies the kept weights.
     moe_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # Hybrid stack (models/nemotron_h.py). ``hybrid_pattern`` selects the
+    # family: one letter a layer, each layer ONE mixer behind a pre-norm
+    # and a residual: ``M`` a Mamba-2 layer, ``E`` a routed feed-forward
+    # layer, ``*`` an attention layer. ``num_layers`` is its length. Only
+    # the ``*`` layers hold pages (``cache_layers``); an ``M`` layer keeps
+    # per row a float32 state [mamba_num_heads, mamba_head_dim,
+    # ssm_state_size] and the last ``conv_kernel - 1`` inputs of its
+    # convolution (ops/state_pool.py).
+    hybrid_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1          # B and C are shared by heads // groups
+    conv_kernel: int = 4
+    ssm_chunk: int = 128         # block of the chunked (SSD) scan
+    # The experts' MLP: ``silu`` = gated SwiGLU (gate|up then down),
+    # ``relu2`` = ungated ``relu(x U)^2 D``.
+    mlp_activation: str = "silu"
+    # Experts that live in a latent of this width, reached through one
+    # shared down-projection before and one up-projection after them
+    # (0: the experts read the hidden state).
+    moe_latent_size: int = 0
+    # Width of the shared expert when it is not ``intermediate_size``.
+    shared_intermediate_size: int = 0
+    # A learned bias added to the router's scores for the CHOICE of the
+    # top-k only; the kept weights are the unbiased scores.
+    moe_selection_bias: bool = False
+    # False: attention without rotary embedding (position comes from the
+    # recurrent layers).
+    attn_rope: bool = True
     # token ids (llama3 defaults; byte tokenizer overrides)
     bos_token_id: int = 128000
     eos_token_ids: tuple[int, ...] = (128001, 128008, 128009)
@@ -100,6 +130,30 @@ class ModelConfig:
     @property
     def is_latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.hybrid_pattern)
+
+    @property
+    def ssm_layers(self) -> int:
+        return self.hybrid_pattern.count("M")
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers that hold pages: all of them, or a hybrid's ``*``."""
+        if self.is_hybrid:
+            return self.hybrid_pattern.count("*")
+        return self.num_layers
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the Mamba convolution runs over: x | B | C."""
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     # What one token holds in a KV cache (models/llama.KVCache,
     # ops/paged_kv.PagedKVCache): ``cache_kv_heads`` rows of
@@ -277,6 +331,47 @@ _register(ModelConfig(
     qk_rope_head_dim=16, v_head_dim=32, sandwich_norm=True,
     first_k_dense=1, dense_intermediate_size=256, num_shared_experts=1,
     moe_router_width=16, moe_scoring="sigmoid", routed_scaling_factor=2.5,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# NVIDIA-Nemotron-3-Super-120B-A12B (nvidia/NVIDIA-Nemotron-3-Super-120B-
+# A12B-BF16 config.json, model_type nemotron_h), ONE chip's share of it:
+# the first of 4 pipeline stages (22 of 88 layers, two periods of the
+# 5:5:1 pattern: 10 Mamba-2, 10 LatentMoE, 2 attention), whose 4 chips
+# share each layer: 128 of the 512 routed experts held (the router 512
+# wide, top-22 over all), Mamba, attention and the shared expert whole,
+# a quarter of the vocabulary. No width, head count, state size, latent
+# size, top-k or router width is cut. About 9.2 GB int8.
+_register(ModelConfig(
+    name="nemotron-3-super-120b-a12b-l22e128", vocab_size=32768,
+    hidden_size=4096, intermediate_size=2688, num_layers=22, num_heads=32,
+    num_kv_heads=2, head_dim=128, max_seq_len=262144, rope_theta=10000.0,
+    rms_norm_eps=1e-5, hybrid_pattern="MEMEMEM*EMEMEMEM*EMEME",
+    mamba_num_heads=128, mamba_head_dim=64, ssm_state_size=128,
+    ssm_groups=8, conv_kernel=4, ssm_chunk=128, num_experts=128,
+    num_experts_per_tok=22, moe_router_width=512, moe_scoring="sigmoid",
+    moe_renormalize=True, routed_scaling_factor=5.0,
+    moe_selection_bias=True, mlp_activation="relu2", moe_latent_size=1024,
+    num_shared_experts=1, shared_intermediate_size=5376, attn_rope=False,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# NVIDIA-Nemotron-3-Super-120B-A12B's three kinds of layer at test size
+# (models/nemotron_h.py): Mamba-2 (8 heads x 16, 2 groups, state 16),
+# GQA attention without rotary embedding, and a LatentMoE layer holding
+# 4 of 16 sigmoid-scored experts (top-3, selection bias, relu^2, latent
+# 64) beside a shared expert. The pattern has a lone M, an ME run, an EM
+# run and a trailing E, as the benchmark's 22-layer cut has.
+_register(ModelConfig(
+    name="tiny-nemotron-h", vocab_size=512, hidden_size=128,
+    intermediate_size=96, num_layers=11, num_heads=4, num_kv_heads=2,
+    head_dim=32, max_seq_len=256, rope_theta=10000.0,
+    hybrid_pattern="MEMEM*EMEME", mamba_num_heads=8, mamba_head_dim=16,
+    ssm_state_size=16, ssm_groups=2, conv_kernel=4, ssm_chunk=16,
+    num_experts=4, num_experts_per_tok=3, moe_router_width=16,
+    moe_scoring="sigmoid", moe_renormalize=True, routed_scaling_factor=5.0,
+    moe_selection_bias=True, mlp_activation="relu2", moe_latent_size=64,
+    num_shared_experts=1, shared_intermediate_size=192, attn_rope=False,
     bos_token_id=1, eos_token_ids=(2,),
 ))
 

@@ -46,19 +46,26 @@ class KVCache(NamedTuple):
     """k/v: [L, B, max_seq, Hkv, D]; lengths: [B] valid slots per row.
     (A latent-attention model keeps one head: its normed latent in ``k``
     and its shared rotated key in ``v``, of different widths:
-    ``ModelConfig.cache_*``.)"""
+    ``ModelConfig.cache_*``. A hybrid model's ``L`` is its attention
+    layers alone, and ``state`` the recurrent layers' state, a row an
+    entry, zero at the start: ops/state_pool.StatePool. None otherwise.)"""
 
     k: jax.Array
     v: jax.Array
     lengths: jax.Array
+    state: Optional[Any] = None
 
     @classmethod
     def create(cls, config: ModelConfig, batch: int, max_seq: int,
                dtype=DEFAULT_COMPUTE_DTYPE) -> "KVCache":
-        lead = (config.num_layers, batch, max_seq, config.cache_kv_heads)
+        lead = (config.cache_layers, batch, max_seq, config.cache_kv_heads)
+        state = None
+        if config.ssm_layers:
+            from ..ops.state_pool import StatePool
+            state = StatePool.create(config, batch, dtype)
         return cls(k=jnp.zeros(lead + (config.cache_k_dim,), dtype),
                    v=jnp.zeros(lead + (config.cache_v_dim,), dtype),
-                   lengths=jnp.zeros((batch,), jnp.int32))
+                   lengths=jnp.zeros((batch,), jnp.int32), state=state)
 
 
 # -- parameters ---------------------------------------------------------------

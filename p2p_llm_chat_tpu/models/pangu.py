@@ -174,27 +174,18 @@ def init_params(config: ModelConfig, key: jax.Array,
         k, shape, shape[0] ** -0.5, dtype))
 
 
-def init_params_quantized(config: ModelConfig, key: jax.Array,
-                          dtype=DEFAULT_COMPUTE_DTYPE,
-                          quant: str = "int8") -> dict:
-    """Random init streamed straight into the int8 tree, one leaf of one
-    layer (one expert of it) at a time: the bf16 tree of the benchmark's
-    cut is 18 GB and cannot exist on the chip, and one layer's sixteen
-    experts in float32 are 2 GB of transients beside 9 GB of weights."""
-    from .quant import quantize, stream_bufs
-
-    if quant != "int8":
-        raise ValueError(f"{config.name}: the latent-attention family "
-                         f"serves int8 or plain weights (the absorbed "
-                         f"form reads Wkvb's int8 numbers), not {quant!r}")
-
-    def leaf(k, shape):
-        return quantize(_normal(k, shape, shape[-2] ** -0.5, dtype))
+def streamed_stack(leaf, quant: str):
+    """``stack(k, L, dims)`` for :func:`_build` that streams random
+    leaves straight into the quantized buffers, one leaf of one layer
+    (one expert of it) at a time. ``leaf(k, shape, name) -> QTensor``
+    draws and quantizes one of them. (models/nemotron_h.py streams its
+    tree through this too.)"""
+    from .quant import stream_bufs
 
     @functools.partial(jax.jit, donate_argnums=(0,),
-                       static_argnames=("shape",))
-    def write(buf, k, at, *, shape):
-        qt = leaf(k, shape)
+                       static_argnames=("shape", "name"))
+    def write(buf, k, at, *, shape, name):
+        qt = leaf(k, shape, name)
         return QTensor(q=buf.q.at[tuple(at)].set(qt.q),
                        s=buf.s.at[tuple(at)].set(qt.s))
 
@@ -207,13 +198,35 @@ def init_params_quantized(config: ModelConfig, key: jax.Array,
                 if len(shape) == 3:         # an expert stack: one at a time
                     for e in range(shape[0]):
                         buf = write(buf, jax.random.fold_in(kl, e),
-                                    jnp.asarray([li, e]), shape=shape[1:])
+                                    jnp.asarray([li, e]), shape=shape[1:],
+                                    name=name)
                 else:
-                    buf = write(buf, kl, jnp.asarray([li]), shape=shape)
+                    buf = write(buf, kl, jnp.asarray([li]), shape=shape,
+                                name=name)
             out[name] = buf
         return out
 
-    return _build(config, key, dtype, stack, leaf)
+    return stack
+
+
+def init_params_quantized(config: ModelConfig, key: jax.Array,
+                          dtype=DEFAULT_COMPUTE_DTYPE,
+                          quant: str = "int8") -> dict:
+    """Random init streamed straight into the int8 tree, one leaf of one
+    layer (one expert of it) at a time: the bf16 tree of the benchmark's
+    cut is 18 GB and cannot exist on the chip, and one layer's sixteen
+    experts in float32 are 2 GB of transients beside 9 GB of weights."""
+    from .quant import quantize
+
+    if quant != "int8":
+        raise ValueError(f"{config.name}: the latent-attention family "
+                         f"serves int8 or plain weights (the absorbed "
+                         f"form reads Wkvb's int8 numbers), not {quant!r}")
+
+    def leaf(k, shape, name=""):
+        return quantize(_normal(k, shape, shape[-2] ** -0.5, dtype))
+
+    return _build(config, key, dtype, streamed_stack(leaf, quant), leaf)
 
 
 def _absorbed_leaves(wkvb, config: ModelConfig) -> dict:
@@ -425,27 +438,56 @@ def _shared_mlp(m, lp):
     return mm(jax.nn.silu(gu[..., :F]) * gu[..., F:], lp["w_down_s"])
 
 
-def route(xt: jax.Array, router: jax.Array, config: ModelConfig):
+def route(xt: jax.Array, router: jax.Array, config: ModelConfig,
+          bias: Optional[jax.Array] = None):
     """(top_w [T,k] float32, top_i [T,k]) over ALL ``router_width``
     experts, in float32: sigmoid (or softmax) scores, the k largest, the
     kept weights divided by their sum (``moe_renormalize``) and
-    multiplied by ``routed_scaling_factor``."""
-    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)
+    multiplied by ``routed_scaling_factor``. ``bias`` ([router_width],
+    ``moe_selection_bias``): added to the scores for the CHOICE alone;
+    the kept weights are the unbiased scores of the chosen."""
+    if bias is None:
+        logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)
+    else:
+        # A choice by biased score among hundreds of experts is decided
+        # in the scores' third decimal: float32 products, not the TPU's
+        # default single bfloat16 pass.
+        logits = jnp.matmul(xt.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
     scores = (jax.nn.sigmoid(logits) if config.moe_scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    top_w, top_i = jax.lax.top_k(scores, config.num_experts_per_tok)
+    if bias is None:
+        top_w, top_i = jax.lax.top_k(scores, config.num_experts_per_tok)
+    else:
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                 config.num_experts_per_tok)
+        top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     if config.moe_renormalize:
         top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
     return top_w * config.routed_scaling_factor, top_i
 
 
 def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
-                  counted: Optional[jax.Array], live: Optional[jax.Array]):
+                  counted: Optional[jax.Array], live: Optional[jax.Array],
+                  latent: Optional[jax.Array] = None):
     """The held experts' part of the routed sum, by scatter/gather into
     per-expert buckets (models/mixtral.moe_mlp's dispatch), and the
     counts. x [B,S,H]. ``counted`` ([B,S] bool): the positions the
     counts run over (a prefill's real prompt positions); ``live`` ([B]
     bool): a decode step's active rows, which alone take bucket slots.
+
+    What a model names, this one dispatch reads from its configuration
+    and its layer: a selection bias (``lp["router_bias"]``,
+    ``moe_selection_bias``); the experts' MLP (``mlp_activation``:
+    gated SwiGLU from ``wgu_e``, or ungated ``relu(.)^2`` from
+    ``w_up_e``); and ``latent`` ([B,S,latent width]): what the experts
+    read and write when they live in a latent (``moe_latent_size``: the
+    router still reads ``x``, and the sum comes back latent-wide, for
+    the caller to project up). That family's buckets also return to
+    their tokens through the placement matrix, as they came (its pairs a
+    token are many and its rows narrow; the other keeps the row gather
+    it was measured with).
 
     A pair routed to an expert this chip does not hold (id >=
     ``num_experts``) takes no slot and adds nothing. Dropless: a bucket
@@ -459,7 +501,10 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     NE, k = config.num_experts, config.num_experts_per_tok
     T = B * S
     xt = x.reshape(T, H)
-    top_w, top_i = route(xt, lp["router"], config)
+    top_w, top_i = route(xt, lp["router"], config, lp.get("router_bias"))
+    if latent is not None:
+        H = latent.shape[-1]
+        xt = latent.reshape(T, H)
     local = top_i < NE                                           # [T,k]
     takes = local
     if live is not None:
@@ -475,9 +520,34 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     expert = top_i.reshape(T * k)
     placed_any = takes.reshape(T * k)
 
+    def experts(xin, count):
+        if config.mlp_activation == "relu2":
+            up = q_einsum("ech,ehf->ecf", xin, lp["w_up_e"], count)
+            act = jnp.square(jax.nn.relu(up))
+        else:
+            gu = q_einsum("ech,ehf->ecf", xin, lp["wgu_e"], count)
+            F = gu.shape[-1] // 2
+            act = jax.nn.silu(gu[..., :F]) * gu[..., F:]
+        return q_einsum("ecf,efh->ech", act, lp["w_down"], count)
+
+    # A latent family's quarter-wide rows afford a placement matrix
+    # eight times the size.
+    by_matmul = _DISPATCH_MATMUL_ELEMS << (3 if latent is not None else 0)
+
     def run(C: int):
         count = jnp.minimum(sent, C)
         idx = jnp.where(placed_any & (slot < C), expert * C + slot, NE * C)
+        if latent is not None and NE * C * T <= by_matmul:
+            at = idx.reshape(T, k)[..., None] == jnp.arange(NE * C)
+            place = jnp.sum(at, axis=1).astype(xt.dtype)         # [T,NE*C]
+            xin = _einsum_f32("ts,th->sh", place, xt).astype(
+                xt.dtype).reshape(NE, C, H)
+            # A slot has one source pair: its weight, summed exactly.
+            w_slot = jnp.sum(jnp.where(at, top_w[..., None], 0.0),
+                             axis=(0, 1))                        # [NE*C]
+            y = experts(xin, count).reshape(NE * C, H)
+            y = (y.astype(jnp.float32) * w_slot[:, None]).astype(xt.dtype)
+            return _einsum_f32("ts,sh->th", place, y)
         if NE * C * T <= _DISPATCH_MATMUL_ELEMS:
             # Rows into buckets as a 0/1 matrix times the tokens (exact:
             # a slot has one source): a row-indexed scatter costs the
@@ -491,10 +561,7 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
         else:
             xin = jnp.zeros((NE * C, H), xt.dtype).at[idx].set(
                 jnp.repeat(xt, k, axis=0), mode="drop").reshape(NE, C, H)
-        gu = q_einsum("ech,ehf->ecf", xin, lp["wgu_e"], count)
-        F = gu.shape[-1] // 2
-        y = q_einsum("ecf,efh->ech", jax.nn.silu(gu[..., :F]) * gu[..., F:],
-                     lp["w_down"], count)
+        y = experts(xin, count)
         got = jnp.take(y.reshape(NE * C, H), idx, axis=0, mode="fill",
                        fill_value=0)
         return jnp.sum(got.reshape(T, k, H).astype(jnp.float32)

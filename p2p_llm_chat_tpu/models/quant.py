@@ -387,6 +387,9 @@ _QUANT_LEAVES = frozenset({
     "lm_head",                         # output projection
     # latent attention and the shared expert (models/pangu.py)
     "wqkva", "wqb", "wkvb", "wgu_s", "w_down_s",
+    # the hybrid family (models/nemotron_h.py): Mamba's projections, the
+    # latent projections, the ungated shared expert and experts
+    "w_in", "w_out", "w_fc1", "w_fc2", "w_up_s", "w_up_e",
 })
 
 

@@ -478,7 +478,8 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     W = pages * cache.k.shape[2]
     if not interpret and _flash_append_wanted(
             W, cache.k.shape[3] * cache.k.shape[4], sharded,
-            cache.k.shape[4]):
+            cache.k.shape[4],
+            cache.k.shape[3] if cache.k_scale is not None else 0):
         # Long-window default (round-8): the (B, chunk)-grid flash
         # kernel reads each page exactly once per (layer, step) and
         # holds only bounded tiles in VMEM, so there is no multi-chunk
@@ -804,8 +805,8 @@ def _flash_boundary(min_w: int, hd: int) -> int:
                min_w * _FLASH_HD_REF // max(hd, _FLASH_HD_REF))
 
 
-def flash_append_blocked(sharded: bool = False,
-                         head_dim: int = 128) -> str | None:
+def flash_append_blocked(sharded: bool = False, head_dim: int = 128,
+                         int8_kv_heads: int = 0) -> str | None:
     """Why the compiled flash-append kernel cannot run in this process
     for this pool, or None when it can — the guard around
     :func:`_flash_append_policy`, worded for the boot log:
@@ -819,26 +820,36 @@ def flash_append_blocked(sharded: bool = False,
       tile collapse unless ``head_dim`` fills whole 128-lane rows
       ("infer-vector-layout: unsupported shape cast", seen on a v5e at
       the ``tiny`` config's D=32, where the geometry-scaled boundary
-      engages the kernel from W=256)."""
+      engages the kernel from W=256);
+    - an int8 pool's tiles hold 4 kv heads on their sublanes, and Mosaic
+      refuses the kernel's page slice of fewer (``int8_kv_heads``: the
+      kv heads of an int8 pool, 0 for a bf16 one; "Slice shape along
+      dimension 3 must be aligned to tiling (4), but is 2", seen on a
+      v5e at 2 kv heads, PR 32). Such a pool is small: the gather path
+      serves every window."""
     if not on_tpu():
         return "not on a TPU"
     if sharded:
         return "the pool is sharded over a mesh"
     if head_dim % 128:
         return f"head_dim {head_dim} is not a multiple of 128 lanes"
+    if int8_kv_heads % 4:
+        return (f"an int8 pool of {int8_kv_heads} kv heads does not fill "
+                "its tiles' 4 sublanes")
     return None
 
 
 def _flash_append_wanted(window: int, hd: int = _FLASH_HD_REF,
-                         sharded: bool = False, head_dim: int = 128) -> bool:
-    if flash_append_blocked(sharded, head_dim):
+                         sharded: bool = False, head_dim: int = 128,
+                         int8_kv_heads: int = 0) -> bool:
+    if flash_append_blocked(sharded, head_dim, int8_kv_heads):
         return False
     return _flash_append_policy(window, _APPEND_IMPL,
                                 _flash_append_min_w(), hd)
 
 
 def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
-                          head_dim: int = 128) -> int:
+                          head_dim: int = 128, int8_kv_heads: int = 0) -> int:
     """The flash-append engagement boundary as ONE number, for gauges
     and logs (serve/scheduler.py's ``paged_flash_min_w``): 0 = the
     kernel cannot engage in this process (:func:`flash_append_blocked`,
@@ -846,7 +857,7 @@ def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
     (every window), else the geometry-scaled min-W threshold for ``hd =
     Hkv * head_dim`` (the scheduler passes its model's). Kept next to
     _flash_append_policy so the dispatch rule has exactly one home."""
-    if flash_append_blocked(sharded, head_dim):
+    if flash_append_blocked(sharded, head_dim, int8_kv_heads):
         return 0
     if _APPEND_IMPL == "flash":
         return 1

@@ -89,6 +89,10 @@ class PagedKVCache(NamedTuple):
     lengths: jax.Array
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    # A hybrid model's recurrent state beside the pages, indexed by slot
+    # (ops/state_pool.StatePool, ``batch`` + 1 rows: the last is the
+    # garbage row); ``L`` above is then its attention layers alone.
+    state: Optional[object] = None
 
     @property
     def page_size(self) -> int:
@@ -121,7 +125,7 @@ class PagedKVCache(NamedTuple):
                page_size: int, max_pages_per_row: Optional[int] = None,
                dtype=jnp.bfloat16, quantized: bool = False,
                mesh=None) -> "PagedKVCache":
-        lead = (config.num_layers, num_pages, page_size,
+        lead = (config.cache_layers, num_pages, page_size,
                 config.cache_kv_heads)
         shape = lead + (config.cache_k_dim,)
         vshape = lead + (config.cache_v_dim,)
@@ -132,7 +136,7 @@ class PagedKVCache(NamedTuple):
             # [Hkv, ps] scale page must be lane-aligned (ps = 64 is half
             # a tile). Slots past page_size are never written or read.
             ps_pad = -(-page_size // 128) * 128
-            sshape = (config.num_layers, num_pages,
+            sshape = (config.cache_layers, num_pages,
                       config.cache_kv_heads, ps_pad)
             cache = cls(
                 k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(vshape, jnp.int8),
@@ -147,6 +151,10 @@ class PagedKVCache(NamedTuple):
                 page_table=jnp.zeros((batch, max_pages_per_row), jnp.int32),
                 lengths=jnp.zeros((batch,), jnp.int32),
             )
+        if config.ssm_layers:
+            from .state_pool import StatePool
+            cache = cache._replace(
+                state=StatePool.create(config, batch + 1, dtype))
         if mesh is not None:
             cache = shard_cache(cache, mesh)
         return cache

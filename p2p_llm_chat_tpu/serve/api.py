@@ -532,6 +532,12 @@ class OllamaServer:
                 info["llama.attention.kv_lora_rank"] = cfg.kv_lora_rank
                 info["llama.attention.q_lora_rank"] = cfg.q_lora_rank
                 info["llama.rope.dimension_count"] = cfg.qk_rope_head_dim
+            if cfg.is_hybrid:
+                info["general.architecture"] = "nemotron_h"
+                info["nemotron_h.hybrid_pattern"] = cfg.hybrid_pattern
+                info["nemotron_h.attention.block_count"] = cfg.cache_layers
+                info["nemotron_h.ssm.block_count"] = cfg.ssm_layers
+                info["nemotron_h.ssm.state_size"] = cfg.ssm_state_size
         return Response(200, {"modelfile": "", "parameters": "",
                               "template": "", "details": details,
                               "model_info": info})

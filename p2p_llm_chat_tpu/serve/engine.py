@@ -325,11 +325,26 @@ class TPUEngine:
 
     def prefix_hashes(self):
         """{token_hash: {len, hits}} of cached prefixes, or None when
-        the prefix cache is off (the front answers 501)."""
+        the prefix cache is off (the front answers 501), or when the
+        entries cannot travel (below), so that no router asks."""
         store = self.scheduler._prefix
-        return None if store is None else store.hashes()
+        if store is None or self.config.ssm_layers:
+            return None
+        return store.hashes()
+
+    def _refuse_prefix_wire(self) -> None:
+        """The wire format carries K and V alone. An entry of a model
+        with recurrent state is its pages AND a state snapshot; one
+        without the other would serve wrong answers, so this family's
+        entries neither leave nor enter."""
+        if self.config.ssm_layers:
+            raise ValueError(
+                f"{self.config.name} keeps recurrent state beside its "
+                f"pages ({self.config.ssm_layers} Mamba layers): its prefix "
+                "entries are not exported or imported over /admin/prefix")
 
     def prefix_export(self, h: str):
+        self._refuse_prefix_wire()
         store = self.scheduler._prefix
         return None if store is None else store.export_payload(h)
 
@@ -338,6 +353,7 @@ class TPUEngine:
         the store locks; the scheduler reads entries between admission
         dispatches). Admission programs for grain-snapped imports are
         covered by warmup's grain pre-warm."""
+        self._refuse_prefix_wire()
         store = self.scheduler._prefix
         return None if store is None else store.import_payload(data)
 

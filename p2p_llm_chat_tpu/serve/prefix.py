@@ -89,13 +89,18 @@ def token_hash(ids) -> str:
 class PrefixEntry:
     """One cached prefix: ``ids`` (exactly P tokens — a ladder length for
     auto-promoted heads, any length for registered templates) and its
-    prefilled K/V, shaped [L, P, Hkv, D] on device."""
+    prefilled K/V, shaped [L, P, Hkv, D] on device. A hybrid model's
+    entry also keeps ``state``: the recurrent layers' state at the
+    prefix's END (ops/state_pool.snapshot), which a suffix starts from.
+    Pages can be cut back to a shorter prefix; a state cannot: an entry
+    serves at its exact length only, which is all ``match`` ever does."""
 
     ids: tuple[int, ...]
     k: object                    # jax.Array [L, P, Hkv, D]
     v: object                    # jax.Array [L, P, Hkv, D]
     hits: int = 0
     last_used: float = field(default_factory=time.monotonic)
+    state: object = None         # ops/state_pool.StatePool, no row axis
 
     @property
     def length(self) -> int:
@@ -105,7 +110,7 @@ class PrefixEntry:
     def nbytes(self) -> int:
         k = getattr(self.k, "nbytes", 0) or 0
         v = getattr(self.v, "nbytes", 0) or 0
-        return int(k) + int(v)
+        return int(k) + int(v) + int(getattr(self.state, "nbytes", 0) or 0)
 
     @property
     def token_hash(self) -> str:

@@ -63,6 +63,8 @@ from ..ops.paged_attention import flash_append_chunk_pages
 from ..ops.paged_kv import (PageAllocator, PagedKVCache, copy_slot,
                             gather_pages, scatter_pages, set_row_table,
                             write_prefill_batch, write_prefill_chunk)
+from ..ops.state_pool import (StatePool, from_snapshot, snapshot,
+                              write_rows)
 from ..tokenizer import Tokenizer
 from ..utils.env import env_float
 from ..utils.failpoints import failpoint
@@ -478,7 +480,7 @@ class BatchScheduler:
         # its ladder (the env toggle is runtime-flippable by design,
         # but the LIVE programs keep whatever they traced, so the gauge
         # must report the compiled-in value, not the current env).
-        self._paged_flash_min_w = self._flash_min_w(config, mesh)
+        self._paged_flash_min_w = self._flash_min_w(config, mesh, kv_quant)
         if admit_chunk is not None and admit_chunk < 1:
             raise ValueError(f"admit_chunk must be >= 1, got {admit_chunk}")
         self.admit_chunk = admit_chunk
@@ -583,6 +585,24 @@ class BatchScheduler:
                         f"{config.name} keeps a latent KV cache "
                         f"(kv_lora_rank {config.kv_lora_rank}) and is not "
                         f"served under {what}")
+        if config.is_hybrid:
+            # Paths that assume "a row's past is its pages" refuse a
+            # model with recurrent state here, by name: each would have
+            # to carry the state or roll it back.
+            for on, what in (
+                    (mesh is not None, "a mesh (the state pool is not "
+                     "laid out over one)"),
+                    (bool(spec_k) or drafter is not None, "speculative "
+                     "decoding (a rejected draft would have to roll the "
+                     "recurrent state back)"),
+                    (bool(kv_host_gb) and kv_host_gb > 0, "session "
+                     "parking (SERVE_KV_HOST_GB: park and wake move pages "
+                     "and would leave the recurrent state behind)")):
+                if on:
+                    raise ValueError(
+                        f"{config.name} keeps recurrent state beside its "
+                        f"pages ({config.ssm_layers} Mamba layers) and is "
+                        f"not served under {what}")
         # Width of the counts a routed model's programs hand back behind
         # their tokens (_with_moe): 2, or the family's own.
         self._moe_w = getattr(model, "STATS_WIDTH", 2)
@@ -692,6 +712,15 @@ class BatchScheduler:
         # the kernel fetches and folds (_note_attn_chunks).
         self._n_attn_chunks = 0          # owned-by: _loop
         self._n_attn_chunks_walked = 0   # owned-by: _loop
+        # Recurrent state (a hybrid model's ops/state_pool.py): rows whose
+        # state the decode dispatches moved (every slot's, each step:
+        # the update is one program over the pool's rows), those of them
+        # live, the bytes that is (read and written), and the prefix
+        # hits that started from an entry's snapshot.
+        self._n_state_row_steps = 0      # owned-by: _loop
+        self._n_state_row_steps_live = 0  # owned-by: _loop
+        self._n_state_bytes = 0          # owned-by: _loop
+        self._n_state_snapshots = 0      # owned-by: _loop
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -1238,6 +1267,25 @@ class BatchScheduler:
             rps = rps.at[rows].set(floats[2], mode="drop")
             return keys, next_tokens, temps, top_ks, top_ps, ring, rps
 
+        def _put_state(cache, small, rows):
+            """A hybrid model's recurrent state at the end of the rows'
+            prompts (``small.state``) into their slots' rows of the
+            pool, whole rows: a reused slot inherits nothing. Dummy
+            entries (row sentinel ``num_slots``) write the garbage row.
+            Nothing for a model whose past is its pages."""
+            if small.state is None:
+                return cache
+            return cache._replace(state=write_rows(cache.state, small.state,
+                                                   rows))
+
+        def _seed_state(small, ps):
+            """``small`` starting from a prefix entry's state snapshot
+            ``ps`` (None for a model without recurrent state)."""
+            if ps is None:
+                return small
+            return small._replace(
+                state=from_snapshot(ps, small.lengths.shape[0]))
+
         def prefill_admit_paged(params, tokens, ints, floats, rings, tables,
                                 cache, keys, next_tokens, temps, top_ks,
                                 top_ps, ring, rps):
@@ -1255,6 +1303,7 @@ class BatchScheduler:
                 params, tokens, ints, floats, rings)
             cache = write_prefill_batch(cache, small.k, small.v, rows, lens,
                                         tables)
+            cache = _put_state(cache, small, rows)
             (keys, next_tokens, temps, top_ks, top_ps, ring,
              rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
                                   keys, next_tokens, temps, top_ks, top_ps,
@@ -1262,8 +1311,8 @@ class BatchScheduler:
             return (_with_moe(toks, moe), cache, keys, next_tokens, temps,
                     top_ks, top_ps, ring, rps)
 
-        def _prefill_first_token_prefix(params, pk, pv, tokens, ints, floats,
-                                        rings):
+        def _prefill_first_token_prefix(params, pk, pv, ps, tokens, ints,
+                                        floats, rings):
             """Continuation-prefill admission prologue for prefix-cached
             prompts: the cached prefix KV ([L,P,Hkv,D], computed once by
             register_prefix) is broadcast into every chunk row's small
@@ -1284,6 +1333,7 @@ class BatchScheduler:
             v0 = jnp.broadcast_to(pv[:, None], (pv.shape[0], R) + pv.shape[1:])
             small = small._replace(k=small.k.at[:, :, :P].set(k0),
                                    v=small.v.at[:, :, :P].set(v0))
+            small = _seed_state(small, ps)
             positions = jnp.broadcast_to(P + jnp.arange(S)[None, :], (R, S))
             mask = causal_mask(S, P + S, P)
             moe = None
@@ -1303,8 +1353,8 @@ class BatchScheduler:
             rings = rings.at[jnp.arange(R), total_lens % _RING].set(toks)
             return small, toks, row_keys, rings, moe
 
-        def prefill_admit_paged_prefix(params, pk, pv, tokens, ints, floats,
-                                       rings, tables, cache, keys,
+        def prefill_admit_paged_prefix(params, pk, pv, ps, tokens, ints,
+                                       floats, rings, tables, cache, keys,
                                        next_tokens, temps, top_ks, top_ps,
                                        ring, rps):
             """prefill_admit_paged for a chunk sharing one cached prefix:
@@ -1315,9 +1365,10 @@ class BatchScheduler:
             untouched)."""
             rows, total_lens = ints[1], ints[4]
             small, toks, row_keys, rings, moe = _prefill_first_token_prefix(
-                params, pk, pv, tokens, ints, floats, rings)
+                params, pk, pv, ps, tokens, ints, floats, rings)
             cache = write_prefill_batch(cache, small.k, small.v, rows,
                                         total_lens, tables)
+            cache = _put_state(cache, small, rows)
             (keys, next_tokens, temps, top_ks, top_ps, ring,
              rps) = _install_rows(rows, row_keys, toks, ints, floats, rings,
                                   keys, next_tokens, temps, top_ks, top_ps,
@@ -1329,7 +1380,7 @@ class BatchScheduler:
                                 donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
         self._admit_prefix_j = jax.jit(
             prefill_admit_paged_prefix,
-            donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
+            donate_argnums=(9, 10, 11, 12, 13, 14, 15, 16))
 
         def kv_zero_row(cache, row):
             return set_row_table(
@@ -1427,14 +1478,15 @@ class BatchScheduler:
                         ints[4].astype(cache.lengths.dtype), mode="drop")
                     cache = cache._replace(page_table=table,
                                            lengths=lengths)
+                    cache = _put_state(cache, carry, rows)
                 return cache
 
             if first:
                 def prefill_chunk_first(params, *args):
                     if P0:
-                        pk, pv, tokens, ints, tables, cache = args
+                        pk, pv, ps, tokens, ints, tables, cache = args
                     else:
-                        pk = pv = None
+                        pk = pv = ps = None
                         tokens, ints, tables, cache = args
                     R = tokens.shape[0]
                     carry = KVCache.create(config, R, W, dtype=self._dtype)
@@ -1446,13 +1498,14 @@ class BatchScheduler:
                         carry = carry._replace(
                             k=carry.k.at[:, :, :P0].set(k0),
                             v=carry.v.at[:, :, :P0].set(v0))
+                        carry = _seed_state(carry, ps)
                     carry, logits_c = _fwd(params, tokens, ints, carry,
                                            self._chunk_logits0(R))
                     cache = _splice(cache, carry, ints, tables)
                     return carry, logits_c, cache
                 # donate the big cache (always the last argument)
                 return jax.jit(prefill_chunk_first,
-                               donate_argnums=(6 if P0 else 4,))
+                               donate_argnums=(7 if P0 else 4,))
 
             if not final:
                 def prefill_chunk_mid(params, tokens, ints, carry, logits_c,
@@ -1510,6 +1563,9 @@ class BatchScheduler:
                 _, cache, moe = model.prefill_counted(
                     params, config, toks, lens, cache,
                     jnp.ones((1, P), bool), mesh)
+                if cache.state is not None:
+                    return (cache.k[:, 0], cache.v[:, 0], moe,
+                            snapshot(cache.state))
                 return cache.k[:, 0], cache.v[:, 0], moe
             _, cache = model.prefill(params, config, toks, lens, cache, mesh)
             return cache.k[:, 0], cache.v[:, 0]
@@ -1605,18 +1661,20 @@ class BatchScheduler:
             # A build has no first token to ride on: its drop count
             # waits, on the device, for the next admission's readback.
             self._moe_unread.append(built[2])
-        return built[0], built[1]
+        # Third: a hybrid model's state at the prefix's end, else None.
+        return built[0], built[1], (built[3] if len(built) > 3 else None)
 
-    def _install_prefix(self, ids, k, v, note: str = "") -> None:
+    def _install_prefix(self, ids, k, v, note: str = "",
+                        state=None) -> None:
         """Store insert + log (scheduler thread only — single writer)."""
-        self._prefix.put(PrefixEntry(ids=tuple(ids), k=k, v=v))
+        self._prefix.put(PrefixEntry(ids=tuple(ids), k=k, v=v, state=state))
         log.info("cached prefix KV: %d tokens (%d entr%s%s)", len(ids),
                  len(self._prefix),
                  "y" if len(self._prefix) == 1 else "ies", note)
 
     def _register_prefix_ids(self, ids: list[int]) -> int:
-        k, v = self._build_prefix_kv(ids)
-        self._install_prefix(ids, k, v)
+        k, v, state = self._build_prefix_kv(ids)
+        self._install_prefix(ids, k, v, state=state)
         return len(ids)
 
     def _decode_for(self, window: int):
@@ -1946,7 +2004,7 @@ class BatchScheduler:
             # mid-serving never compiles over active streams.
             flash_note = ""
             min_w = self._paged_flash_min_w = self._flash_min_w(
-                self.config, self.mesh)
+                self.config, self.mesh, self.kv_quant)
             kernel_ws = [w for w in windows if min_w and w >= min_w]
             if kernel_ws:
                 flash_note = (f", flash-append kernel at windows "
@@ -2096,7 +2154,8 @@ class BatchScheduler:
             "mppr": self._cache.max_pages_per_row,
         }
 
-    def _compile_promotion_aot(self, P: int, k, v, combos: list[tuple],
+    def _compile_promotion_aot(self, P: int, k, v, state,
+                               combos: list[tuple],
                                structs: dict) -> tuple[dict, dict]:
         """AOT-compile (lower + compile — never execute) the splice
         programs for a promoted prefix of length ``P``. Runs on the
@@ -2109,6 +2168,8 @@ class BatchScheduler:
                                        structs["sample"])
         ks = jax.ShapeDtypeStruct(k.shape, k.dtype)
         vs = jax.ShapeDtypeStruct(v.shape, v.dtype)
+        ss = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          state)
         aot_admit: dict[tuple, object] = {}
         aot_chunks: dict[tuple, object] = {}
         for S, R, C, offs in combos:
@@ -2117,7 +2178,7 @@ class BatchScheduler:
             rings = jax.ShapeDtypeStruct((R, _RING), jnp.int32)
             tables = jax.ShapeDtypeStruct((R, structs["mppr"]), jnp.int32)
             if offs is None:
-                args = [params_s, ks, vs,
+                args = [params_s, ks, vs, ss,
                         jax.ShapeDtypeStruct((R, S), jnp.int32), ints5,
                         floats3, rings, tables, cache_s, *sample_s]
                 aot_admit[(P, S, R)] = (
@@ -2131,7 +2192,8 @@ class BatchScheduler:
             for off in offs:
                 prog = self._make_prefill_chunk_program(P, S, off, C)
                 if off == 0:
-                    args = [params_s, ks, vs, toks, ints5, tables, cache_s]
+                    args = [params_s, ks, vs, ss, toks, ints5, tables,
+                            cache_s]
                 elif off + C < S:
                     args = [params_s, toks, ints5, carry_s, logits_s, tables,
                             cache_s]
@@ -2158,13 +2220,14 @@ class BatchScheduler:
                 # Failpoint: a failed promotion build is dropped (it is
                 # an optimization) — serving must be untouched.
                 failpoint("serve.scheduler.promote")
-                k, v = self._build_prefix_kv(head)
+                k, v, state = self._build_prefix_kv(head)
                 aot_admit, aot_chunks = self._compile_promotion_aot(
-                    len(head), k, v, combos, structs)
-                self._promote_done.put((head, k, v, aot_admit, aot_chunks))
+                    len(head), k, v, state, combos, structs)
+                self._promote_done.put((head, k, v, state, aot_admit,
+                                        aot_chunks))
             except Exception:   # noqa: BLE001 — promotion is optional
                 log.exception("prefix promotion build failed")
-                self._promote_done.put((head, None, None, {}, {}))
+                self._promote_done.put((head, None, None, None, {}, {}))
 
     def _drain_promotions(self) -> None:
         """Install finished promotion builds (scheduler thread only —
@@ -2174,7 +2237,7 @@ class BatchScheduler:
         dispatches an already-compiled program."""
         while True:
             try:
-                (head, k, v, aot_admit,
+                (head, k, v, state, aot_admit,
                  aot_chunks) = self._promote_done.get_nowait()
             except queue.Empty:
                 return
@@ -2184,7 +2247,7 @@ class BatchScheduler:
             self._admit_prefix_aot.update(aot_admit)
             self._prefill_chunk_aot.update(aot_chunks)
             self._install_prefix(
-                head, k, v,
+                head, k, v, state=state,
                 note=(f", promoted off-thread, "
                       f"{len(aot_admit) + len(aot_chunks)} AOT programs"))
 
@@ -2192,10 +2255,13 @@ class BatchScheduler:
         """A prefix entry of the right shapes and no content (the grain
         pre-warm: the compile cache keys on shapes only)."""
         c = self.config
-        lead = (c.num_layers, P, c.cache_kv_heads)
+        lead = (c.cache_layers, P, c.cache_kv_heads)
+        state = (snapshot(StatePool.create(c, 1, self._dtype))
+                 if c.ssm_layers else None)
         return PrefixEntry(ids=tuple(range(P)),
                            k=jnp.zeros(lead + (c.cache_k_dim,), self._dtype),
-                           v=jnp.zeros(lead + (c.cache_v_dim,), self._dtype))
+                           v=jnp.zeros(lead + (c.cache_v_dim,), self._dtype),
+                           state=state)
 
     def _warm_prefix_combo(self, P: int, S: int, R: int,
                            synthetic: bool = False) -> None:
@@ -2440,6 +2506,9 @@ class BatchScheduler:
         self._rps_dev = jnp.ones((B,), jnp.float32)
         self._active_host: tuple = ()
         self._active_dev = jnp.zeros((B,), bool)
+        st = self._cache.state
+        self._state_pool_bytes = st.nbytes if st is not None else 0
+        self._state_row_bytes = st.row_bytes if st is not None else 0
 
     # -- client side (HTTP threads) ------------------------------------------
 
@@ -3309,6 +3378,18 @@ class BatchScheduler:
             # and decode), and those routed to a held expert.
             out["serve_moe_routed_pairs_total"] = self._n_moe_routed_pairs
             out["serve_moe_local_pairs_total"] = self._n_moe_local_pairs
+        if self.config.ssm_layers:
+            # Recurrent state beside the pages (ops/state_pool.py): the
+            # pool's bytes, the slots holding a live row's state, and
+            # what the decode dispatches moved of it.
+            out["serve_state_pool_bytes"] = self._state_pool_bytes
+            out["serve_state_rows_in_use"] = sum(
+                s is not None for s in self._slots)
+            out["serve_state_bytes_total"] = self._n_state_bytes
+            out["serve_state_row_steps_total"] = self._n_state_row_steps
+            out["serve_state_row_steps_live_total"] = \
+                self._n_state_row_steps_live
+            out["serve_state_snapshots_total"] = self._n_state_snapshots
         if self.spec_k:
             out["serve_spec_accepted_total"] = self._n_spec_accepted
             # Back-compat aggregate: the most optimistic source (the
@@ -3421,7 +3502,9 @@ class BatchScheduler:
         else:
             off["flash-append"] = (
                 None if self._paged_flash_min_w > 0 else
-                flash_append_blocked(sharded, self.config.head_dim)
+                flash_append_blocked(
+                    sharded, self.config.head_dim,
+                    self.config.num_kv_heads if self.kv_quant else 0)
                 or "disabled by PAGED_APPEND_FLASH_MIN_W/PAGED_APPEND_IMPL")
         log.info("kernels on %s: %s; XLA instead of: %s; flash-append "
                  "min_w %d; pallas interpret %s", platform(),
@@ -3448,12 +3531,22 @@ class BatchScheduler:
                  "x %d tokens, %d rows x %d pages a row; %.3f GB",
                  kind, cache.k.dtype.name,
                  ", a float32 scale a token a head for each" if
-                 cache.quantized else "", c.num_layers, per_layer * item,
+                 cache.quantized else "", c.cache_layers, per_layer * item,
                  self.num_pages, self.page_size, self.num_slots,
                  cache.max_pages_per_row, total / 1e9)
+        if cache.state is not None:
+            st = cache.state
+            log.info("state pool: %d Mamba layers x %d rows (%d slots and "
+                     "a garbage row) x (%s float32 state + %s %s window); "
+                     "%.3f MB a row, %.3f GB",
+                     st.ssm.shape[0], st.ssm.shape[1], self.num_slots,
+                     "x".join(map(str, st.ssm.shape[2:])),
+                     "x".join(map(str, st.conv.shape[2:])),
+                     st.conv.dtype.name, st.row_bytes / 1e6,
+                     st.nbytes / 1e9)
 
     @staticmethod
-    def _flash_min_w(config, mesh) -> int:
+    def _flash_min_w(config, mesh, kv_quant: bool = False) -> int:
         """Window threshold at which this process's paged decode
         programs dispatch the multi-chunk flash-append kernel instead of
         the gather path: 0 = cannot engage (CPU, a mesh-sharded pool, a
@@ -3471,8 +3564,9 @@ class BatchScheduler:
             # A latent pool is read by its own kernel at every window
             # (ops/mla_attention.py); this policy is the per-head pools'.
             return 0
-        return effective_flash_min_w(config.kv_dim, mesh is not None,
-                                     config.head_dim)
+        return effective_flash_min_w(
+            config.kv_dim, mesh is not None, config.head_dim,
+            config.num_kv_heads if kv_quant else 0)
 
     def _try_reserve(self, slot: _Slot) -> bool:
         """Claim the slot's page budget (prompt + generation
@@ -3810,6 +3904,8 @@ class BatchScheduler:
         if prefix is not None:
             self._n_prefix_admits += len(chunk)
             self._n_prefix_tokens += P * len(chunk)
+            if prefix.state is not None:
+                self._n_state_snapshots += len(chunk)
             # A promotion-built AOT executable (exact (P, S, R) match)
             # dispatches ahead of the jit wrapper — same signature, but
             # compiled on the worker thread instead of here.
@@ -3819,7 +3915,7 @@ class BatchScheduler:
              self._temps_dev, self._top_ks_dev, self._top_ps_dev,
              self._ring_dev, self._rps_dev) = \
                 prog(
-                    self._params, prefix.k, prefix.v,
+                    self._params, prefix.k, prefix.v, prefix.state,
                     jnp.asarray(tokens), jnp.asarray(ints),
                     jnp.asarray(floats), jnp.asarray(rings),
                     jnp.asarray(tables), self._cache, self._keys,
@@ -4013,6 +4109,8 @@ class BatchScheduler:
             if pc.prefix is not None:
                 self._n_prefix_admits += len(pc.chunk)
                 self._n_prefix_tokens += P0 * len(pc.chunk)
+                if pc.prefix.state is not None:
+                    self._n_state_snapshots += len(pc.chunk)
             self._install_admitted(pc.chunk, pc.rows, toks_dev)
 
     def _dispatch_prefill_chunk(self, P0: int, S: int, off: int, C: int,
@@ -4035,7 +4133,7 @@ class BatchScheduler:
         ij = jnp.asarray(ints)
         tb = jnp.asarray(tables)
         if first:
-            pre = (prefix.k, prefix.v) if P0 else ()
+            pre = (prefix.k, prefix.v, prefix.state) if P0 else ()
             kv, logits, self._cache = prog(self._params, *pre, t, ij, tb,
                                            self._cache)
             self._chunk_shapes_run.add(shape_key)
@@ -4143,6 +4241,13 @@ class BatchScheduler:
         self._last_dispatch = (now, K)
         active = tuple(s is not None for s in self._slots)
         self._n_decode_row_steps += sum(active) * K
+        if self._cache.state is not None:
+            # The step's state update is one program over every slot's
+            # row; a row not live comes back as it was.
+            self._n_state_row_steps += self.num_slots * K
+            self._n_state_row_steps_live += sum(active) * K
+            self._n_state_bytes += (2 * self.num_slots * K
+                                    * self._state_row_bytes)
         # Step j of the K reads each live row's ctx_len + j cached rows.
         self._n_attn_ctx_tokens += K * sum(
             s.ctx_len + inflight for s in self._slots

@@ -1,0 +1,253 @@
+"""tiny-nemotron-h (Mamba-2 layers, attention without rotary embedding,
+LatentMoE layers holding 4 of 16 sigmoid-scored experts beside a shared
+one) through the scheduler, end to end on the CPU, on the stack the
+benchmark serves with: int8 weights, the paged int8 pool of its
+attention layers AND the state pool of its Mamba layers, the prefix
+store, fused decode and a chunk ladder. A module of its own, so that its
+programs are freed before the next module's (tests/conftest.py)."""
+
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from p2p_llm_chat_tpu.models import family_for, nemotron_h
+from p2p_llm_chat_tpu.models.configs import get_config
+from p2p_llm_chat_tpu.models.llama import KVCache
+from p2p_llm_chat_tpu.ops import state_pool
+from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
+from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
+                                            RequestStats)
+from p2p_llm_chat_tpu.serve.engine import TPUEngine
+from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
+
+CFG = get_config("tiny-nemotron-h")
+TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
+
+
+def run(engine, prompt, max_tokens=12, **opts):
+    stats = RequestStats()
+    req = GenerateRequest(prompt=prompt, options=GenerateOptions(
+        max_tokens=max_tokens, **opts))
+    text = "".join(engine.generate_stream(req, stats))
+    return text, stats
+
+
+@pytest.fixture(scope="module")
+def qparams():
+    """int8 weights under float32 activations (tests/test_engine_pangu.py
+    says why: in bfloat16 the last bits pick the token)."""
+    return nemotron_h.init_params_quantized(CFG, jax.random.PRNGKey(4),
+                                            dtype=jnp.float32)
+
+
+def oracle(qparams, prompt: str, max_new: int) -> str:
+    """A solo loop on the same tree: one-shot prefill of the unpadded
+    prompt, K and V spliced into a one-row paged pool and the state into
+    its row of the state pool, then plain decode steps."""
+    stop_ids = set(CFG.eos_token_ids) | {TOK.eos_id}
+    ids = TOK.encode(prompt, add_bos=True)
+    n = len(ids)
+    small = KVCache.create(CFG, 1, n, dtype=jnp.float32)
+    logits, small = nemotron_h.prefill(
+        qparams, CFG, jnp.asarray([ids]), jnp.asarray([n]), small,
+        last_only=True)
+    pool = PagedKVCache.create(CFG, 1, 17, 16, max_pages_per_row=16,
+                               dtype=jnp.float32, quantized=True)
+    pool = write_prefill_batch(pool, small.k, small.v, jnp.asarray([0]),
+                               jnp.asarray([n]),
+                               1 + jnp.arange(16, dtype=jnp.int32)[None])
+    pool = pool._replace(state=state_pool.write_rows(
+        pool.state, small.state, jnp.asarray([0])))
+    last = np.asarray(logits[0, 0], np.float32)
+    out = []
+    for _ in range(max_new):
+        t = int(last.argmax())
+        if t in stop_ids:
+            break
+        out.append(t)
+        lg, pool = nemotron_h.decode_step_paged(
+            qparams, CFG, jnp.asarray([[t]]), pool, pages=16)
+        last = np.asarray(lg[0, 0], np.float32)
+    return TOK.decode(out)
+
+
+def test_family_and_pool_geometry():
+    assert family_for(CFG) is nemotron_h
+    assert (CFG.num_layers, CFG.ssm_layers, CFG.cache_layers) == (11, 5, 1)
+    big = get_config("nemotron-3-super-120b-a12b-l22e128")
+    assert (big.ssm_layers, big.cache_layers) == (10, 2)
+    assert (big.mamba_inner, big.conv_dim) == (8192, 10240)
+    assert big.router_width == 512 and big.num_experts == 128
+    pool = PagedKVCache.create(CFG, 3, 5, 16, quantized=True)
+    # Pages for the attention layer alone; state rows for 3 slots and a
+    # garbage row.
+    assert pool.k.shape == (1, 5, 16, 2, 32)
+    assert pool.state.ssm.shape == (5, 4, 8, 16, 16)
+    assert pool.state.ssm.dtype == jnp.float32
+    assert pool.state.conv.shape == (5, 4, 3, 8 * 16 + 2 * 2 * 16)
+    small = KVCache.create(CFG, 2, 24)
+    assert small.k.shape[0] == 1 and small.state.ssm.shape[:2] == (5, 2)
+    # The other families' caches carry no state leaf.
+    assert KVCache.create(get_config("tiny"), 2, 8).state is None
+    assert PagedKVCache.create(get_config("tiny-moe"), 2, 5, 16).state is None
+
+
+def test_admission_chunks_prefix_fused_decode_slot_reuse_and_counters(
+        qparams):
+    """A lone request, a prompt longer than a chunk (first / mid / final
+    chunk programs carrying the state), a burst sharing the registered
+    head (prefix admission from its state snapshot) beside prompts that
+    do not, then more requests than slots in turn (every slot reused):
+    greedy output equals the solo loop's on the unpadded prompt, and the
+    counters count what they say."""
+    head = "hybrid shared head, "
+    eng = TPUEngine(qparams, CFG, TOK, num_slots=4, max_seq=256,
+                    page_size=16, kv_quant=True, prefix_cache=True,
+                    prefix_texts=(head,), decode_fuse_max=4,
+                    prefill_chunk=32)
+    try:
+        sched = eng.scheduler
+        built = sched.register_prefix(head)
+        assert built == len(TOK.encode(head, add_bos=True)) - 1
+        entry = sched._prefix.snapshot()[0]
+        assert entry.state.ssm.shape == (5, 8, 16, 16)
+        assert entry.nbytes > entry.k.nbytes + entry.v.nbytes
+        lone = "a request that arrives alone"
+        long = head + "x" * 90          # suffix bucket 128: four chunks
+        longer = "y" * 75               # no head, bucket 128: four chunks
+        burst = [head + f"burst {i}" for i in range(5)] + [
+            f"no head {i}" for i in range(3)]
+        assert run(eng, lone, max_tokens=6)[0] == oracle(qparams, lone, 6)
+        assert run(eng, long, max_tokens=6)[0] == oracle(qparams, long, 6)
+        assert run(eng, longer, max_tokens=6)[0] == oracle(qparams, longer,
+                                                            6)
+        got, errs = {}, []
+
+        def worker(p):
+            try:
+                got[p] = run(eng, p, max_tokens=9)[0]
+            except Exception as e:   # noqa: BLE001
+                errs.append((p, e))
+
+        threads = [threading.Thread(target=worker, args=(p,))
+                   for p in burst]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=240)
+        assert not errs, errs
+        # Eight requests on four slots: every slot was freed and reused,
+        # after occupants of other lengths.
+        assert got == {p: oracle(qparams, p, 9) for p in burst}
+        m = eng.metrics_snapshot()
+        assert m["serve_admitted_total"] == 11
+        assert m["prefill_chunks_total"] >= 6
+        assert m["serve_prefix_admits_total"] >= 6
+        assert m["serve_state_snapshots_total"] == \
+            m["serve_prefix_admits_total"]
+        assert m["decode_fused_ticks_total"] > 0
+        pool = sched._cache.state
+        assert m["serve_state_pool_bytes"] == pool.nbytes
+        assert m["serve_state_rows_in_use"] == 0
+        steps = m["serve_state_row_steps_total"]
+        assert steps > 0 and steps % 4 == 0
+        assert m["serve_state_row_steps_live_total"] == \
+            m["serve_decode_row_steps_total"]
+        assert 0 < m["serve_state_row_steps_live_total"] <= steps
+        assert m["serve_state_bytes_total"] == 2 * steps * pool.row_bytes
+        per_token = CFG.num_experts_per_tok * CFG.hybrid_pattern.count("E")
+        assert m["serve_moe_routed_pairs_total"] == per_token * (
+            m["serve_prefill_tokens_total"] + built
+            + m["serve_decode_row_steps_total"])
+        assert 0 < m["serve_moe_local_pairs_total"] < (
+            m["serve_moe_routed_pairs_total"])
+        assert m["serve_moe_dropped_total"] == 0
+    finally:
+        eng.stop()
+
+
+def test_promoted_prefix_serves_from_its_snapshot(qparams):
+    """A head seen twice is promoted at a grain (64 tokens) with its
+    state snapshot; the third request admits through it and equals the
+    uncached solo loop."""
+    eng = TPUEngine(qparams, CFG, TOK, num_slots=2, max_seq=256,
+                    page_size=16, kv_quant=True, prefix_cache=True,
+                    prefix_texts=())
+    try:
+        head = "z y x w v u t s r q " * 5        # 100 chars -> grain 64
+        prompts = [head + tail for tail in ("alpha", "beta", "gamma")]
+        store = eng.scheduler._prefix
+        for i, p in enumerate(prompts):
+            assert run(eng, p, max_tokens=8)[0] == oracle(qparams, p, 8)
+            if i == 1:
+                deadline = time.monotonic() + 60
+                while len(store) < 1 and time.monotonic() < deadline:
+                    time.sleep(0.02)
+        assert len(store) == 1
+        assert store.snapshot()[0].length == 64
+        assert store.snapshot()[0].state is not None
+        m = eng.scheduler.metrics_snapshot()
+        assert m["serve_prefix_admits_total"] >= 1
+        assert m["serve_state_snapshots_total"] >= 1
+    finally:
+        eng.stop()
+
+
+def test_decode_leaves_a_free_rows_state_bit_equal(qparams):
+    """Decoding other rows moves nothing of a row that is not live."""
+    B = 3
+    pool = PagedKVCache.create(CFG, B, 13, 16, max_pages_per_row=4,
+                               dtype=jnp.float32, quantized=True)
+    key = jax.random.PRNGKey(0)
+    st = pool.state
+    pool = pool._replace(
+        state=state_pool.StatePool(
+            ssm=jax.random.normal(key, st.ssm.shape, jnp.float32),
+            conv=jax.random.normal(key, st.conv.shape, jnp.float32)),
+        page_table=1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4),
+        lengths=jnp.asarray([5, 7, 9], jnp.int32))
+    before = pool.state
+    active = jnp.asarray([True, False, True])
+    _, after = nemotron_h.decode_step_paged(
+        qparams, CFG, jnp.asarray([[3], [4], [5]]), pool, active=active,
+        pages=4)
+    for b, a in ((before.ssm, after.state.ssm),
+                 (before.conv, after.state.conv)):
+        b, a = np.asarray(b), np.asarray(a)
+        assert np.array_equal(b[:, 1], a[:, 1])      # the parked row
+        assert np.array_equal(b[:, 3], a[:, 3])      # the garbage row
+        assert not np.array_equal(b[:, 0], a[:, 0])
+        assert not np.array_equal(b[:, 2], a[:, 2])
+    assert list(np.asarray(after.lengths)) == [6, 7, 10]
+
+
+@pytest.mark.parametrize("kw,what", [
+    (dict(spec_k=2), "speculative decoding"),
+    (dict(kv_host_gb=0.5), "session parking"),
+    (dict(mesh="a mesh"), "a mesh"),
+])
+def test_paths_that_assume_pages_alone_refuse_by_name(qparams, kw, what):
+    from p2p_llm_chat_tpu.serve.scheduler import BatchScheduler
+    with pytest.raises(ValueError,
+                       match=f"tiny-nemotron-h keeps recurrent state.*{what}"):
+        BatchScheduler(qparams, CFG, TOK, num_slots=2, max_seq=64,
+                       page_size=16, **kw)
+
+
+def test_prefix_entries_do_not_travel(qparams):
+    eng = TPUEngine(qparams, CFG, TOK, num_slots=2, max_seq=64,
+                    page_size=16, prefix_cache=True, prefix_texts=())
+    try:
+        assert eng.prefix_hashes() is None
+        for call in (lambda: eng.prefix_export("00"),
+                     lambda: eng.prefix_import(b"")):
+            with pytest.raises(ValueError,
+                               match="tiny-nemotron-h keeps recurrent "
+                                     "state.*not exported or imported"):
+                call()
+    finally:
+        eng.stop()

@@ -391,6 +391,14 @@ def test_dispatch_policy_table(monkeypatch):
     assert pa.effective_flash_min_w(64, head_dim=32) == 0
     assert "128 lanes" in pa.flash_append_blocked(head_dim=32)
     assert pa.flash_append_blocked(head_dim=128) is None
+    # ... and for an int8 pool whose kv heads do not fill a tile's 4
+    # sublanes (seen on the chip at 2 kv heads, PR 32); 8 do, and a bf16
+    # pool has no such tile.
+    assert not pa._flash_append_wanted(1 << 20, 256, int8_kv_heads=2)
+    assert pa.effective_flash_min_w(256, int8_kv_heads=2) == 0
+    assert "2 kv heads" in pa.flash_append_blocked(int8_kv_heads=2)
+    assert pa.flash_append_blocked(int8_kv_heads=8) is None
+    assert pa.flash_append_blocked(int8_kv_heads=0) is None
 
 
 # -- long-window matrix (ci.sh full mode) -------------------------------------

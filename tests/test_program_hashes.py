@@ -1,6 +1,8 @@
 """The dense, Mixtral and OLMoE programs lower to the StableHLO they did
 before the latent-attention family came (PR 30's parent, commit
-69d2026): tools/hash_programs.py's digests, taken on that commit under
+69d2026), and the latent-attention family's to what they did before the
+hybrid family came (PR 32's parent, commit bdbf057):
+tools/hash_programs.py's digests, taken on those commits under
 this suite's conftest (its XLA flags are part of the text). A PR
 that means to change one of these programs replaces its digest, from a
 run of the tool on itself, and says so."""
@@ -33,7 +35,17 @@ PARENT = {
     "tiny-olmoe.decode_step_paged": "a1a92274845ae1bfdc4d249b3d29b9dea6cb4a8de4bfce6587b6601ed6121a87",
     "tiny-olmoe.decode_fused": "f0c2ae114434292e5e4ba72a0989c2faf41481401500bc666075d19157579608",
     "tiny-olmoe.write_prefill_batch": "427d1c493a9fcfe813e321dc7eae89372e7149b1d0fa7d780f4e3f575f79207c",
-    "tiny-olmoe.write_prefill_chunk": "91a1f2403e57658367328f06ac514914122247be29d0dca99b76306d0f6f165d"
+    "tiny-olmoe.write_prefill_chunk": "91a1f2403e57658367328f06ac514914122247be29d0dca99b76306d0f6f165d",
+    # The latent-attention family, taken on PR 32's parent (commit
+    # bdbf057): PR 32 widened its routed dispatch (a selection bias, the
+    # experts' activation, a latent width) for a fourth family and must
+    # not have moved what this one lowers to.
+    "tiny-pangu.prefill": "985aa303b10468b79d2b05d6785e7afd33d26f99c482d1168720081836f5ab1d",
+    "tiny-pangu.prefill_chunk": "81763f0731f3298e1db6cda5f17aa52d2f7f08b63abc1c5140e25bc17adba7e6",
+    "tiny-pangu.decode_step_paged": "e4a6fbb06dba46721175ad3bc256f89b02b89535b6370f404d7c1e04a126ec74",
+    "tiny-pangu.decode_fused": "1cb56c7e7e4a94045b891fcc67daac39af0f40122886d008616f884844c13c7c",
+    "tiny-pangu.write_prefill_batch": "9ecc20c4c0cb1821f120c465568e16e13b8a4cf8dbfae3bc0a7a30e362987f50",
+    "tiny-pangu.write_prefill_chunk": "cb91ef92e8ef392040a646765c53e1df4fa5aa56e1eb968427049ac279bf3b5a"
 }
 
 
@@ -49,4 +61,4 @@ def test_program_lowers_to_the_parents_stablehlo(texts, key):
     name, label = key.split(".", 1)
     got = hashlib.sha256(texts[name][label].encode()).hexdigest()
     assert got == PARENT[key], (
-        f"{key} lowers to other StableHLO than at PR 30's parent")
+        f"{key} lowers to other StableHLO than at its pinned parent")

@@ -1,8 +1,8 @@
-"""SHA-256 of the StableHLO the dense, Mixtral and OLMoE families lower
-to on the CPU at test size: the programs the scheduler serves with
-(one-shot and chunked prefill, a paged int8 decode step with its pool
-write, a fused decode, the admission splice). A PR that must not move
-another family's programs runs this on its parent and on itself
+"""SHA-256 of the StableHLO the dense, Mixtral, OLMoE and
+latent-attention families lower to on the CPU at test size: the
+programs the scheduler serves with (one-shot and chunked prefill, a
+paged int8 decode step with its pool write, a fused decode, the
+admission splice). A PR that must not move another family's programs runs this on its parent and on itself
 (``PYTHONPATH=<checkout> python tools/hash_programs.py``) and pins the
 parent's digests in tests/test_program_hashes.py.
 """
@@ -12,7 +12,7 @@ from __future__ import annotations
 import hashlib
 import json
 
-CONFIGS = ("tiny", "tiny-moe", "tiny-olmoe")
+CONFIGS = ("tiny", "tiny-moe", "tiny-olmoe", "tiny-pangu")
 
 
 def programs(name: str) -> dict:
