@@ -140,6 +140,16 @@ class ModelConfig:
         return self.hybrid_pattern.count("M")
 
     @property
+    def routed_layers(self) -> int:
+        """Layers with a routed MLP: a hybrid's ``E``, else every layer
+        past the leading dense ones (0 for a dense model)."""
+        if not self.is_moe:
+            return 0
+        if self.is_hybrid:
+            return self.hybrid_pattern.count("E")
+        return self.num_layers - self.first_k_dense
+
+    @property
     def cache_layers(self) -> int:
         """Layers that hold pages: all of them, or a hybrid's ``*``."""
         if self.is_hybrid:

@@ -76,9 +76,10 @@ from .quant import LayerSlice, QTensor, mm, q_einsum
 
 # Width of the counts this family's programs hand the scheduler (the
 # other routed family hands 2). Prefill: (pairs routed to held experts,
-# of those dropped, pairs routed, 0), real prompt positions only.
-# Decode: (held experts a live row reached, held experts there were,
-# pairs routed, pairs routed to held experts), live rows only.
+# of those dropped, pairs routed, routed layers that ran the all-T
+# buckets), real prompt positions only. Decode: (held experts a live
+# row reached, held experts there were, pairs routed, pairs routed to
+# held experts), live rows only.
 STATS_WIDTH = 4
 
 # Above this many elements of the 0/1 placement matrix ([NE*C, T]) the
@@ -473,9 +474,12 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
                   latent: Optional[jax.Array] = None):
     """The held experts' part of the routed sum, by scatter/gather into
     per-expert buckets (models/mixtral.moe_mlp's dispatch), and the
-    counts. x [B,S,H]. ``counted`` ([B,S] bool): the positions the
-    counts run over (a prefill's real prompt positions); ``live`` ([B]
-    bool): a decode step's active rows, which alone take bucket slots.
+    counts. x [B,S,H]. ``counted`` ([B,S] bool): a prefill's real
+    prompt positions, which the counts run over and which alone take
+    bucket slots (padding and an admission's dummy entries are sent
+    nowhere and their routed output is 0; None: every position is
+    real); ``live`` ([B] bool): a decode step's active rows, which
+    alone take bucket slots.
 
     What a model names, this one dispatch reads from its configuration
     and its layer: a selection bias (``lp["router_bias"]``,
@@ -494,9 +498,10 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     holds T/4 rows (a prefill's 16 held experts of 256 see a 32nd of its
     pairs each under even routing), and when any expert was sent more,
     the same computation runs again with buckets of all T rows
-    (``lax.cond``: one of the two runs). Decode buckets hold every row.
+    (``lax.cond``: one of the two runs; a prefill's last count says
+    which, 1 for all T). Decode buckets hold every row.
 
-    Returns (out [B,S,H], stats int32 [4])."""
+    Returns (out [B,S,H], stats int32 [STATS_WIDTH])."""
     B, S, H = x.shape
     NE, k = config.num_experts, config.num_experts_per_tok
     T = B * S
@@ -510,6 +515,8 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     if live is not None:
         takes = takes & jnp.broadcast_to(live[:, None, None],
                                          (B, S, k)).reshape(T, k)
+    elif counted is not None:
+        takes = takes & counted.reshape(T, 1)
     # one_hot of an id past NE is all zeros: an absent expert's queue
     # does not exist here.
     flat = (jax.nn.one_hot(top_i, NE, dtype=jnp.int32)
@@ -568,20 +575,19 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
                        * top_w[..., None], axis=1)
 
     small = max(8, (T // 4) // 8 * 8)
+    full = jnp.asarray(False)
     if live is not None or small >= T:
         out = run(T)
     else:
-        out = jax.lax.cond(jnp.max(sent) > small, lambda: run(T),
-                           lambda: run(small))
+        full = jnp.max(sent) > small
+        out = jax.lax.cond(full, lambda: run(T), lambda: run(small))
     if live is not None:
         n_live = jnp.sum(live) * S
         stats = jnp.stack([jnp.sum(sent > 0), jnp.asarray(NE),
                            n_live * k, jnp.sum(takes)])
     else:
-        real = (jnp.ones((T,), bool) if counted is None
-                else counted.reshape(T))
-        stats = jnp.stack([jnp.sum(local & real[:, None]), jnp.asarray(0),
-                           jnp.sum(real) * k, jnp.asarray(0)])
+        n_real = jnp.asarray(T) if counted is None else jnp.sum(counted)
+        stats = jnp.stack([jnp.sum(takes), jnp.asarray(0), n_real * k, full])
     return (out.astype(x.dtype).reshape(B, S, H), stats.astype(jnp.int32))
 
 

@@ -703,6 +703,14 @@ class BatchScheduler:
         # those of them routed to an expert held here.
         self._n_moe_routed_pairs = 0     # owned-by: _loop
         self._n_moe_local_pairs = 0      # owned-by: _loop
+        # The same model's prefill dispatches that carried a request
+        # (an admission, each chunk of its ladder, a prefix build), a
+        # routed layer each: how many there were, and those in which
+        # some held expert was sent more real pairs than the quarter
+        # bucket holds, so the layer ran buckets of every position
+        # (models/pangu._routed_local).
+        self._n_moe_prefill_layers = 0       # owned-by: _loop
+        self._n_moe_full_bucket_layers = 0   # owned-by: _loop
         # Cache rows the decode steps' live rows attended (the sum, over
         # row-steps, of the row's context length at that step).
         self._n_attn_ctx_tokens = 0      # owned-by: _loop
@@ -1643,12 +1651,18 @@ class BatchScheduler:
             return logits, jnp.zeros((self._moe_w,), jnp.int32)
         return logits
 
-    def _count_moe(self, stats) -> None:
+    def _count_moe(self, stats, dispatches: int) -> None:
+        """A prefill's counts, summed on the device over its routed
+        layers and over the ``dispatches`` that carried a request (the
+        chunks of a ladder share one vector)."""
         self._n_moe_assigned += int(stats[0])
         self._n_moe_dropped += int(stats[1])
         if len(stats) > 2:
             self._n_moe_routed_pairs += int(stats[2])
             self._n_moe_local_pairs += int(stats[0])
+            self._n_moe_full_bucket_layers += int(stats[3])
+            self._n_moe_prefill_layers += (dispatches
+                                           * self.config.routed_layers)
 
     def _build_prefix_kv(self, ids) -> tuple:
         """Prefix KV for ``ids`` — reads only immutable state (params +
@@ -3378,6 +3392,12 @@ class BatchScheduler:
             # and decode), and those routed to a held expert.
             out["serve_moe_routed_pairs_total"] = self._n_moe_routed_pairs
             out["serve_moe_local_pairs_total"] = self._n_moe_local_pairs
+            # Routed layers of the prefill dispatches that carried a
+            # request, and those that ran buckets of every position.
+            out["serve_moe_prefill_layers_total"] = \
+                self._n_moe_prefill_layers
+            out["serve_moe_full_bucket_layers_total"] = \
+                self._n_moe_full_bucket_layers
         if self.config.ssm_layers:
             # Recurrent state beside the pages (ops/state_pool.py): the
             # pool's bytes, the slots holding a live row's state, and
@@ -3985,20 +4005,24 @@ class BatchScheduler:
         return tokens, ints, floats, rings, tables
 
     def _install_admitted(self, chunk: list[_Slot], rows: list[int],
-                          toks_dev) -> None:
+                          toks_dev, dispatches: int = 1) -> None:
         """Admission epilogue shared by the single-shot program and the
-        final prefill chunk: read the first tokens back, install the
-        slots, stream/stop-check each first token."""
+        final prefill chunk (of a ladder of ``dispatches`` chunks): read
+        the first tokens back, install the slots, stream/stop-check each
+        first token."""
         with self._phase("readback", rows=len(chunk)):
             # graftcheck: sync-ok intentional: R int32 first tokens, TTFT depends on it
             first_toks = np.asarray(toks_dev)
             if self.config.is_moe:
                 # The prefill's drop count rides behind the first tokens
                 # (_with_moe); prefix builds left theirs waiting.
-                self._count_moe(first_toks[-self._moe_w:])
+                # Warm-up's all-padding dispatches carry no request.
+                self._count_moe(first_toks[-self._moe_w:],
+                                dispatches if chunk else 0)
                 while self._moe_unread:
                     # graftcheck: sync-ok 2 int32 of a build that ended before this admission was dispatched
-                    self._count_moe(np.asarray(self._moe_unread.popleft()))
+                    built = np.asarray(self._moe_unread.popleft())
+                    self._count_moe(built, 1)
         with self._phase("stream"):
             # Draft-source admission BEFORE the install loop (a row that
             # finishes on its very first token releases inside the loop, and
@@ -4111,7 +4135,8 @@ class BatchScheduler:
                 self._n_prefix_tokens += P0 * len(pc.chunk)
                 if pc.prefix.state is not None:
                     self._n_state_snapshots += len(pc.chunk)
-            self._install_admitted(pc.chunk, pc.rows, toks_dev)
+            self._install_admitted(pc.chunk, pc.rows, toks_dev,
+                                   dispatches=pc.S // C)
 
     def _dispatch_prefill_chunk(self, P0: int, S: int, off: int, C: int,
                                 tokens, ints, floats, rings, tables, kv,

@@ -170,6 +170,53 @@ def test_admission_chunks_prefix_fused_decode_slot_reuse_and_counters(
         eng.stop()
 
 
+def test_prefill_layer_counters_over_windows(qparams):
+    """``serve_moe_prefill_layers_total`` is the routed layers of every
+    prefill dispatch that carried a request (an admission, each chunk of
+    a ladder, a prefix build) and ``serve_moe_full_bucket_layers_total``
+    those of them that ran the all-T buckets, as differences between
+    snapshots. A three-token prompt in its bucket of padding cannot send
+    a held expert more than the quarter bucket holds, so it reads 0
+    however the padding routes; decode's use of the same entry of the
+    counts (pairs to held experts) goes on counting pairs beside it."""
+    head = "hybrid shared head, "
+    eng = TPUEngine(qparams, CFG, TOK, num_slots=4, max_seq=256,
+                    page_size=16, kv_quant=True, prefix_cache=True,
+                    prefix_texts=(), decode_fuse_max=4, prefill_chunk=32)
+    E = CFG.routed_layers
+    names = ("serve_moe_prefill_layers_total",
+             "serve_moe_full_bucket_layers_total",
+             "serve_moe_assignments_total", "serve_moe_local_pairs_total",
+             "prefill_chunks_total", "serve_decode_row_steps_total")
+
+    def window(m0):
+        m1 = eng.metrics_snapshot()
+        return [m1[n] - m0[n] for n in names], m1
+
+    try:
+        m = eng.metrics_snapshot()
+        assert m[names[0]] == m[names[1]] == 0
+        run(eng, "hi", max_tokens=6)
+        (layers, full, held, local, chunks, steps), m = window(m)
+        assert (layers, full, chunks) == (E, 0, 0)
+        per_token = CFG.num_experts_per_tok * E
+        assert 0 < local - held < per_token * steps
+        # A ladder of four chunks, the last of them mostly padding.
+        run(eng, "y" * 110, max_tokens=2)
+        (layers, full, _, _, chunks, _), m = window(m)
+        assert chunks == 4 and layers == 4 * E
+        assert 0 <= full <= layers
+        # A prefix build's counts wait for the next admission's read.
+        eng.scheduler.register_prefix(head)
+        assert window(m)[0][0] == 0
+        run(eng, head + "ok", max_tokens=2)
+        (layers, full, _, _, chunks, _), m = window(m)
+        assert chunks == 0 and layers == 2 * E
+        assert 0 <= full <= layers
+    finally:
+        eng.stop()
+
+
 def test_promoted_prefix_serves_from_its_snapshot(qparams):
     """A head seen twice is promoted at a grain (64 tokens) with its
     state snapshot; the third request admits through it and equals the
