@@ -320,7 +320,8 @@ class OllamaServer:
 
     def _run(self, req_body: dict, prompt: str, key: str,
              wrap, with_context: bool = False,
-             headers: Optional[dict] = None) -> Response:
+             headers: Optional[dict] = None,
+             entered: Optional[float] = None) -> Response:
         """Shared generate/chat execution. ``key``: response field holding
         text ('response' or 'message'); ``wrap``: delta -> field value;
         ``with_context``: /api/generate's conversation-state round trip
@@ -328,7 +329,9 @@ class OllamaServer:
         updated ids — Ollama's stateless continuation contract).
         ``headers``: the HTTP request headers — the session id
         (``X-Session-Id`` / ``session`` body field, the router's
-        affinity id) rides into the engine for KV tiering."""
+        affinity id) rides into the engine for KV tiering. ``entered``:
+        when the handler was entered, before it parsed the body (the
+        start of the ``api.accept`` span)."""
         # Failpoint: the request-parse/validate site. ``error`` returns
         # a well-formed Ollama error record; ``raise`` rides the
         # router's handler-error envelope (also a well-formed 500).
@@ -400,6 +403,12 @@ class OllamaServer:
             self._m_inflight.add(-1)
             log.exception("submit failed")
             return Response(500, {"error": str(e)})
+        if tctx.sampled:
+            # This thread's share before the scheduler has the request:
+            # the body's JSON, the options, the trace header, submit.
+            t_in = started if entered is None else entered
+            self.trace.add(tctx.trace_id, "api.accept", t_in,
+                           time.monotonic() - t_in, parent="api.request")
 
         if not stream:
             try:
@@ -422,6 +431,7 @@ class OllamaServer:
             return Response(200, rec)
 
         def ndjson() -> Iterator[bytes]:
+            first = tctx.sampled
             try:
                 for delta in deltas:
                     # Failpoint: the per-delta stream-yield site. ``drop``
@@ -433,7 +443,18 @@ class OllamaServer:
                         continue
                     chunk = {"model": model, "created_at": now_rfc3339(),
                              key: wrap(delta), "done": False}
-                    yield (json.dumps(chunk) + "\n").encode()
+                    line = (json.dumps(chunk) + "\n").encode()
+                    if first:
+                        # From the loop's first push to this thread's
+                        # first line: the wake-up, the dequeue, the JSON.
+                        first = False
+                        if stats.first_push_t is not None:
+                            self.trace.add(
+                                tctx.trace_id, "api.first_write",
+                                stats.first_push_t,
+                                time.monotonic() - stats.first_push_t,
+                                parent="api.request")
+                    yield line
                 rec = self._finalize_record(model, stats, started)
                 rec[key] = wrap("")
                 if with_context and stats.context is not None:
@@ -461,15 +482,18 @@ class OllamaServer:
     # -- handlers ------------------------------------------------------------
 
     def _generate(self, req: Request) -> Response:
+        entered = time.monotonic()
         try:
             body = req.json() or {}
         except ValueError:
             return Response(400, {"error": "invalid json"})
         prompt = str(body.get("prompt") or "")
         return self._run(body, prompt, "response", lambda t: t,
-                         with_context=True, headers=req.headers)
+                         with_context=True, headers=req.headers,
+                         entered=entered)
 
     def _chat(self, req: Request) -> Response:
+        entered = time.monotonic()
         try:
             body = req.json() or {}
         except ValueError:
@@ -484,7 +508,7 @@ class OllamaServer:
         prompt = render_chat_prompt(messages, resolved)
         return self._run(body, prompt, "message",
                          lambda t: {"role": "assistant", "content": t},
-                         headers=req.headers)
+                         headers=req.headers, entered=entered)
 
     def _tags(self, req: Request) -> Response:
         return Response(200, {"models": [

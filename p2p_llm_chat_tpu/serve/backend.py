@@ -105,6 +105,10 @@ class RequestStats:
     # (context + prompt + completion) a follow-up request can send back.
     # None = backend doesn't track ids (FakeLLM).
     context: Optional[list] = None
+    # When the scheduler loop handed the first delta to the stream
+    # (time.monotonic()); the HTTP front's ``api.first_write`` span
+    # starts here. None = nothing streamed, or a backend with no loop.
+    first_push_t: Optional[float] = None
 
 
 @runtime_checkable

@@ -145,7 +145,7 @@ class _Slot:
 
     req: GenerateRequest
     stats: Optional[RequestStats]
-    out_q: "queue.Queue[Optional[str]]"
+    out_q: "queue.Queue[Optional[tuple]]"             # (delta, put time)
     seed: int
     ids: list[int] = field(default_factory=list)      # generated ids
     prompt_ids: list[int] = field(default_factory=list)
@@ -188,9 +188,19 @@ class _Slot:
     on_depart: Optional[object] = None
     departed: bool = False
 
+    # The hand-off from the loop to the HTTP thread: seconds between
+    # a delta's put (push) and its dequeue (Scheduler._consume), summed
+    # by the one consumer and folded into the scheduler's counters when
+    # the stream ends.
+    handoff_s: float = 0.0
+    handoff_n: int = 0
+
     def push(self, delta: str) -> None:
         if delta:
-            self.out_q.put(delta)
+            now = time.monotonic()
+            if self.stats is not None and self.stats.first_push_t is None:
+                self.stats.first_push_t = now
+            self.out_q.put((delta, now))
 
     done: bool = False                                 # finish() has run
 
@@ -503,6 +513,9 @@ class BatchScheduler:
         self._depth_mu = threading.Lock()
         self._queued_requests = 0     # guarded-by: _depth_mu
         self._n_shed = 0              # guarded-by: _depth_mu
+        # The streams' hand-off (_Slot.handoff_s), folded as each ends.
+        self._handoff_s = 0.0         # guarded-by: _depth_mu
+        self._n_deltas = 0            # guarded-by: _depth_mu
         # Draining (replica-router mode, serve/router.py): a draining
         # scheduler finishes its in-flight streams but refuses NEW
         # submissions (OverloadError -> the front's 503) and reports
@@ -532,6 +545,15 @@ class BatchScheduler:
         # goes, as self times by phase, on the profiler's clock and as
         # window counters (serve_loop_*_seconds_total).
         self._phase = LoopPhases()    # owned-by: _loop
+        # Whether a launch found the device empty (_note_launch): the
+        # newest output the loop holds of its last launch (one no later
+        # call is given to donate before the next launch has looked at
+        # it), and by the kind of launch, how many there were and at how
+        # many of them that output had already arrived.
+        self._last_out = None         # owned-by: _loop
+        self._n_launch = {"admit": 0, "prefill_chunk": 0,
+                          "decode": 0}             # owned-by: _loop
+        self._n_starved = dict(self._n_launch)     # owned-by: _loop
         self._loop_s = 0.0            # owned-by: _loop — wall of all iterations
         self._warm_iter = 0           # owned-by: _loop — last iteration that ran a warm-up job
         # Boot gauges (serve_boot_*): set once each, at the end of this
@@ -2623,11 +2645,14 @@ class BatchScheduler:
     def _consume(self, slot: _Slot) -> Iterator[str]:
         try:
             while True:
-                delta = slot.out_q.get()
-                if delta is None:
+                item = slot.out_q.get()
+                if item is None:
                     if slot.error is not None:
                         raise RuntimeError(slot.error)
                     return
+                delta, put_t = item
+                slot.handoff_s += time.monotonic() - put_t
+                slot.handoff_n += 1
                 # Burst drain: a fused K-step tick (or a speculative
                 # tick) lands several deltas at once — coalesce whatever
                 # is already queued into ONE yield so the HTTP front
@@ -2645,7 +2670,9 @@ class BatchScheduler:
                     if nxt is None:
                         done = True
                         break
-                    parts.append(nxt)
+                    parts.append(nxt[0])
+                    slot.handoff_s += time.monotonic() - nxt[1]
+                    slot.handoff_n += 1
                 yield "".join(parts)
                 if done:
                     if slot.error is not None:
@@ -2653,6 +2680,11 @@ class BatchScheduler:
                     return
         finally:
             slot.cancelled.set()
+            # Once a stream, however it ends (finished, failed, closed
+            # by a client that left): its hand-off joins the counters.
+            with self._depth_mu:
+                self._handoff_s += slot.handoff_s
+                self._n_deltas += slot.handoff_n
 
     # graftcheck: lock-ok drains scheduler-owned state only AFTER _thread.join — the owner is gone
     def stop(self) -> None:
@@ -2712,84 +2744,90 @@ class BatchScheduler:
             phase.mark_iteration()
             warm0 = phase.inclusive("warmup")
             try:
-                self._drain_stall_reset()
-                self._drain_park_all()
-                # Admission inside the same recovery envelope as decode: an
-                # unexpected admission-path error must fail requests and
-                # reset, never kill the scheduler thread (which would leave
-                # every future submit() hanging on a dead queue).
-                with phase("admit"):
-                    self._admit_pending(block=not self._any_active()
-                                        and pending is None
-                                        and self._prefill_carry is None)
-                if self._closed.is_set():
-                    return
-                if self._prefix is not None:
-                    self._drain_promotions()
-                self._tier_sweep()
-                if self._prefill_carry is not None:
-                    # Chunked admission in progress: ONE continuation
-                    # chunk per loop iteration — the decode tick below
-                    # runs between chunks, so live streams stall at
-                    # most one chunk's compute per iteration (the
-                    # bounded-stall contract).
-                    self._prefill_step()
-                if not self._any_active():
-                    # No live decodes: the stall gauge must not bridge
-                    # this gap — a cold admission after idle time would
-                    # otherwise book the whole idle stretch as
-                    # decode_stall_ms (it stalled nobody).
-                    self._last_decode_t = None
-                    if pending is not None:
-                        self._process_tick(*pending)
-                        pending = None
-                    elif self._promote_q and self._prefill_carry is None:
-                        # Idle: build one deferred prefix promotion
-                        # (compile + prefill happen with no live streams
-                        # to stall).
-                        self._build_promotion()
-                    continue
-                # Flush the pipeline for a speculative tick only when one
-                # can actually run this tick (drafting needs current ids)
-                # — while the acceptance throttle has EVERY source backed
-                # off, plain ticks keep their pipelining.
-                if self.spec_k and not self._sources:
-                    self._ensure_sources()   # spec_k toggled 0 -> K
-                spec_allowed = (self._spec_sources_allowed()
-                                if self.spec_k else {})
-                spec_now = bool(self.spec_k) and any(spec_allowed.values())
-                if spec_now:
-                    if pending is not None:
-                        self._process_tick(*pending)
-                        pending = None
+                # The outermost mark: what the iteration does between the
+                # marks inside it is the phase "other".
+                with phase("other"):
+                    self._drain_stall_reset()
+                    self._drain_park_all()
+                    # Admission inside the same recovery envelope as decode: an
+                    # unexpected admission-path error must fail requests and
+                    # reset, never kill the scheduler thread (which would leave
+                    # every future submit() hanging on a dead queue).
+                    # "plan" is whatever _admit_pending does outside the
+                    # parts marked inside it: reservations, matching,
+                    # grouping, widths.
+                    with phase("admit"), phase("plan"):
+                        self._admit_pending(block=not self._any_active()
+                                            and pending is None
+                                            and self._prefill_carry is None)
+                    if self._closed.is_set():
+                        return
+                    if self._prefix is not None:
+                        self._drain_promotions()
+                    self._tier_sweep()
+                    if self._prefill_carry is not None:
+                        # Chunked admission in progress: ONE continuation
+                        # chunk per loop iteration — the decode tick below
+                        # runs between chunks, so live streams stall at
+                        # most one chunk's compute per iteration (the
+                        # bounded-stall contract).
+                        self._prefill_step()
                     if not self._any_active():
+                        # No live decodes: the stall gauge must not bridge
+                        # this gap — a cold admission after idle time would
+                        # otherwise book the whole idle stretch as
+                        # decode_stall_ms (it stalled nobody).
+                        self._last_decode_t = None
+                        if pending is not None:
+                            self._process_tick(*pending)
+                            pending = None
+                        elif self._promote_q and self._prefill_carry is None:
+                            # Idle: build one deferred prefix promotion
+                            # (compile + prefill happen with no live streams
+                            # to stall).
+                            self._build_promotion()
                         continue
+                    # Flush the pipeline for a speculative tick only when one
+                    # can actually run this tick (drafting needs current ids)
+                    # — while the acceptance throttle has EVERY source backed
+                    # off, plain ticks keep their pipelining.
+                    if self.spec_k and not self._sources:
+                        self._ensure_sources()   # spec_k toggled 0 -> K
+                    spec_allowed = (self._spec_sources_allowed()
+                                    if self.spec_k else {})
+                    spec_now = bool(self.spec_k) and any(spec_allowed.values())
+                    if spec_now:
+                        if pending is not None:
+                            self._process_tick(*pending)
+                            pending = None
+                        if not self._any_active():
+                            continue
+                        with phase("decode_dispatch"):
+                            # Its reads and per-row work mark their own
+                            # phases inside; what is left is the dispatch.
+                            spec_done = self._spec_tick(spec_allowed)
+                        if spec_done:
+                            continue
+                    # Fused K-step ticks ride the same one-tick-deep pipeline
+                    # as plain ones: tick t+1 (up to K steps) is enqueued
+                    # BEFORE tick t's K-token burst is drained, so the
+                    # readback/stream work overlaps device compute. K=1 while
+                    # speculation is live this iteration (a fused tick would
+                    # emit K tokens with no draft chance).
                     with phase("decode_dispatch"):
-                        # Its reads and per-row work mark their own
-                        # phases inside; what is left is the dispatch.
-                        spec_done = self._spec_tick(spec_allowed)
-                    if spec_done:
-                        continue
-                # Fused K-step ticks ride the same one-tick-deep pipeline
-                # as plain ones: tick t+1 (up to K steps) is enqueued
-                # BEFORE tick t's K-token burst is drained, so the
-                # readback/stream work overlaps device compute. K=1 while
-                # speculation is live this iteration (a fused tick would
-                # emit K tokens with no draft chance).
-                with phase("decode_dispatch"):
-                    new = self._dispatch_tick(
-                        allow_fuse=not spec_now,
-                        inflight=pending[2] if pending is not None else 0)
-                if pending is not None:
-                    self._process_tick(*pending)
-                pending = new
-                if (self._promote_q and self._n_decode_ticks
-                        - self._last_promote_tick > _PROMOTE_EVERY_TICKS):
-                    # Sustained load never goes idle — without this, hot
-                    # templates would never get their prefix built
-                    # exactly when it pays most. One bounded stall per
-                    # build, amortised over hundreds of ticks.
-                    self._build_promotion()
+                        new = self._dispatch_tick(
+                            allow_fuse=not spec_now,
+                            inflight=pending[2] if pending is not None else 0)
+                    if pending is not None:
+                        self._process_tick(*pending)
+                    pending = new
+                    if (self._promote_q and self._n_decode_ticks
+                            - self._last_promote_tick > _PROMOTE_EVERY_TICKS):
+                        # Sustained load never goes idle — without this, hot
+                        # templates would never get their prefix built
+                        # exactly when it pays most. One bounded stall per
+                        # build, amortised over hundreds of ticks.
+                        self._build_promotion()
             except Exception:   # noqa: BLE001 — fail requests, keep serving
                 log.exception("decode tick failed; failing in-flight requests")
                 pending = None
@@ -2891,10 +2929,13 @@ class BatchScheduler:
                     # the loop waits for work.
                     with self._phase("idle"):
                         slot = self._admit_q.get(timeout=0.2)
+                elif out:
+                    # A wait by design, not work: its own part, so that
+                    # "collect" off the CPU is a wait nobody asked for.
+                    with self._phase("gap"):
+                        slot = self._admit_q.get(timeout=0.003)
                 else:
-                    timeout = 0.003 if out else None
-                    slot = self._admit_q.get(block=timeout is not None,
-                                             timeout=timeout)
+                    slot = self._admit_q.get_nowait()
             except queue.Empty:
                 break
             if isinstance(slot, _WarmupJob):
@@ -3329,20 +3370,163 @@ class BatchScheduler:
             "inter_token_p95_ms": round(
                 self._tbt_hist.percentile(95) or 0.0, 4),
             # Loop phases (obs/phase.py): the loop thread's wall by
-            # phase, as self times, so the seven add up to no more than
-            # serve_loop_seconds_total; the rest is the loop's own
-            # bookkeeping between marks. Window differences of these
-            # are the benchmark's host_ms_per_step and
-            # device_wait_share.
-            "serve_loop_idle_seconds_total": ph.seconds("idle"),
-            "serve_loop_admit_seconds_total": ph.seconds("admit"),
+            # phase, as self times, so the eight add up to
+            # serve_loop_seconds_total ("other" is the iteration outside
+            # every other mark). A phase's seconds hold its parts'
+            # (below): they mean what they meant before there were
+            # parts. Window differences of these are the benchmark's
+            # host_ms_per_step, device_wait_share and admit_host_ms.
+            "serve_loop_idle_seconds_total": ph.total("idle"),
+            "serve_loop_admit_seconds_total": ph.total("admit"),
             "serve_loop_prefill_chunk_seconds_total":
-                ph.seconds("prefill_chunk"),
+                ph.total("prefill_chunk"),
             "serve_loop_decode_dispatch_seconds_total":
-                ph.seconds("decode_dispatch"),
-            "serve_loop_readback_seconds_total": ph.seconds("readback"),
-            "serve_loop_stream_seconds_total": ph.seconds("stream"),
-            "serve_loop_warmup_seconds_total": ph.seconds("warmup"),
+                ph.total("decode_dispatch"),
+            "serve_loop_readback_seconds_total": ph.total("readback"),
+            "serve_loop_stream_seconds_total": ph.total("stream"),
+            "serve_loop_warmup_seconds_total": ph.total("warmup"),
+            "serve_loop_other_seconds_total": ph.total("other"),
+            # The thread's CPU seconds (time.thread_time), read in one
+            # iteration of obs/phase.CPU_EVERY, and the wall seconds of
+            # those same marks: 1 - cpu / cpu_wall is the share of a
+            # phase the thread was off the CPU, which in a phase that
+            # is Python on the host is a wait for the interpreter lock
+            # or for the OS (loop_offcpu_share).
+            "serve_loop_idle_cpu_seconds_total": ph.cpu_total("idle"),
+            "serve_loop_idle_cpu_wall_seconds_total":
+                ph.cpu_wall_total("idle"),
+            "serve_loop_admit_cpu_seconds_total": ph.cpu_total("admit"),
+            "serve_loop_admit_cpu_wall_seconds_total":
+                ph.cpu_wall_total("admit"),
+            "serve_loop_prefill_chunk_cpu_seconds_total":
+                ph.cpu_total("prefill_chunk"),
+            "serve_loop_prefill_chunk_cpu_wall_seconds_total":
+                ph.cpu_wall_total("prefill_chunk"),
+            "serve_loop_decode_dispatch_cpu_seconds_total":
+                ph.cpu_total("decode_dispatch"),
+            "serve_loop_decode_dispatch_cpu_wall_seconds_total":
+                ph.cpu_wall_total("decode_dispatch"),
+            "serve_loop_readback_cpu_seconds_total": ph.cpu_total("readback"),
+            "serve_loop_readback_cpu_wall_seconds_total":
+                ph.cpu_wall_total("readback"),
+            "serve_loop_stream_cpu_seconds_total": ph.cpu_total("stream"),
+            "serve_loop_stream_cpu_wall_seconds_total":
+                ph.cpu_wall_total("stream"),
+            "serve_loop_warmup_cpu_seconds_total": ph.cpu_total("warmup"),
+            "serve_loop_warmup_cpu_wall_seconds_total":
+                ph.cpu_wall_total("warmup"),
+            "serve_loop_other_cpu_seconds_total": ph.cpu_total("other"),
+            "serve_loop_other_cpu_wall_seconds_total":
+                ph.cpu_wall_total("other"),
+            # The parts of a phase (obs/phase.PARTS_OF): self wall
+            # seconds, self CPU seconds and marks of each, so that "ms a
+            # launch" is a ratio of two counters (launch_ms).
+            "serve_loop_admit_collect_seconds_total":
+                ph.seconds("admit.collect"),
+            "serve_loop_admit_collect_cpu_seconds_total":
+                ph.cpu("admit.collect"),
+            "serve_loop_admit_collect_cpu_wall_seconds_total":
+                ph.cpu_wall("admit.collect"),
+            "serve_loop_admit_collect_marks_total": ph.marks("admit.collect"),
+            "serve_loop_admit_gap_seconds_total":
+                ph.seconds("admit.gap"),
+            "serve_loop_admit_gap_cpu_seconds_total":
+                ph.cpu("admit.gap"),
+            "serve_loop_admit_gap_cpu_wall_seconds_total":
+                ph.cpu_wall("admit.gap"),
+            "serve_loop_admit_gap_marks_total": ph.marks("admit.gap"),
+            "serve_loop_admit_plan_seconds_total":
+                ph.seconds("admit.plan"),
+            "serve_loop_admit_plan_cpu_seconds_total":
+                ph.cpu("admit.plan"),
+            "serve_loop_admit_plan_cpu_wall_seconds_total":
+                ph.cpu_wall("admit.plan"),
+            "serve_loop_admit_plan_marks_total": ph.marks("admit.plan"),
+            "serve_loop_admit_build_seconds_total":
+                ph.seconds("admit.build"),
+            "serve_loop_admit_build_cpu_seconds_total":
+                ph.cpu("admit.build"),
+            "serve_loop_admit_build_cpu_wall_seconds_total":
+                ph.cpu_wall("admit.build"),
+            "serve_loop_admit_build_marks_total": ph.marks("admit.build"),
+            "serve_loop_admit_upload_seconds_total":
+                ph.seconds("admit.upload"),
+            "serve_loop_admit_upload_cpu_seconds_total":
+                ph.cpu("admit.upload"),
+            "serve_loop_admit_upload_cpu_wall_seconds_total":
+                ph.cpu_wall("admit.upload"),
+            "serve_loop_admit_upload_marks_total": ph.marks("admit.upload"),
+            "serve_loop_admit_launch_seconds_total":
+                ph.seconds("admit.launch"),
+            "serve_loop_admit_launch_cpu_seconds_total":
+                ph.cpu("admit.launch"),
+            "serve_loop_admit_launch_cpu_wall_seconds_total":
+                ph.cpu_wall("admit.launch"),
+            "serve_loop_admit_launch_marks_total": ph.marks("admit.launch"),
+            "serve_loop_prefill_chunk_build_seconds_total":
+                ph.seconds("prefill_chunk.build"),
+            "serve_loop_prefill_chunk_build_cpu_seconds_total":
+                ph.cpu("prefill_chunk.build"),
+            "serve_loop_prefill_chunk_build_cpu_wall_seconds_total":
+                ph.cpu_wall("prefill_chunk.build"),
+            "serve_loop_prefill_chunk_build_marks_total":
+                ph.marks("prefill_chunk.build"),
+            "serve_loop_prefill_chunk_upload_seconds_total":
+                ph.seconds("prefill_chunk.upload"),
+            "serve_loop_prefill_chunk_upload_cpu_seconds_total":
+                ph.cpu("prefill_chunk.upload"),
+            "serve_loop_prefill_chunk_upload_cpu_wall_seconds_total":
+                ph.cpu_wall("prefill_chunk.upload"),
+            "serve_loop_prefill_chunk_upload_marks_total":
+                ph.marks("prefill_chunk.upload"),
+            "serve_loop_prefill_chunk_launch_seconds_total":
+                ph.seconds("prefill_chunk.launch"),
+            "serve_loop_prefill_chunk_launch_cpu_seconds_total":
+                ph.cpu("prefill_chunk.launch"),
+            "serve_loop_prefill_chunk_launch_cpu_wall_seconds_total":
+                ph.cpu_wall("prefill_chunk.launch"),
+            "serve_loop_prefill_chunk_launch_marks_total":
+                ph.marks("prefill_chunk.launch"),
+            "serve_loop_decode_dispatch_upload_seconds_total":
+                ph.seconds("decode_dispatch.upload"),
+            "serve_loop_decode_dispatch_upload_cpu_seconds_total":
+                ph.cpu("decode_dispatch.upload"),
+            "serve_loop_decode_dispatch_upload_cpu_wall_seconds_total":
+                ph.cpu_wall("decode_dispatch.upload"),
+            "serve_loop_decode_dispatch_upload_marks_total":
+                ph.marks("decode_dispatch.upload"),
+            "serve_loop_decode_dispatch_launch_seconds_total":
+                ph.seconds("decode_dispatch.launch"),
+            "serve_loop_decode_dispatch_launch_cpu_seconds_total":
+                ph.cpu("decode_dispatch.launch"),
+            "serve_loop_decode_dispatch_launch_cpu_wall_seconds_total":
+                ph.cpu_wall("decode_dispatch.launch"),
+            "serve_loop_decode_dispatch_launch_marks_total":
+                ph.marks("decode_dispatch.launch"),
+            "serve_loop_stream_launch_seconds_total":
+                ph.seconds("stream.launch"),
+            "serve_loop_stream_launch_cpu_seconds_total":
+                ph.cpu("stream.launch"),
+            "serve_loop_stream_launch_cpu_wall_seconds_total":
+                ph.cpu_wall("stream.launch"),
+            "serve_loop_stream_launch_marks_total": ph.marks("stream.launch"),
+            # Launches that feed the device, by kind, and those that
+            # found the last launch's output already there: the device
+            # had run dry (_note_launch; launch_starved_share).
+            "serve_launch_admit_total": self._n_launch["admit"],
+            "serve_launch_admit_starved_total":
+                self._n_starved["admit"],
+            "serve_launch_prefill_chunk_total": self._n_launch["prefill_chunk"],
+            "serve_launch_prefill_chunk_starved_total":
+                self._n_starved["prefill_chunk"],
+            "serve_launch_decode_total": self._n_launch["decode"],
+            "serve_launch_decode_starved_total":
+                self._n_starved["decode"],
+            # The loop's hand-off to the HTTP threads: seconds from a
+            # delta's put to its dequeue, summed over the streams that
+            # have ended, and their deltas (stream_handoff_ms).
+            "serve_stream_handoff_seconds_total": self._handoff_s,
+            "serve_stream_deltas_total": self._n_deltas,
             "serve_loop_seconds_total": self._loop_s,
             "serve_loop_iterations_total": self._loop_iter,
             # Counts at the dispatch sites: admissions started (with
@@ -3704,9 +3888,10 @@ class BatchScheduler:
             self._waiting = still
         room = len(free) - len(pending) - sum(len(g) for g in wakes.values())
         if room > 0:
-            fresh = self._collect_pending(
-                room, block and not pending and not wakes
-                and not self._waiting)
+            with self._phase("collect"):
+                fresh = self._collect_pending(
+                    room, block and not pending and not wakes
+                    and not self._waiting)
             for s in fresh:
                 if _classify(s):
                     continue
@@ -3874,6 +4059,18 @@ class BatchScheduler:
                     self._fail_all_and_reset()
                     return
 
+    # graftcheck: runs-on _loop
+    def _note_launch(self, kind: str) -> None:
+        """First thing inside the ``launch`` mark of a program that
+        feeds the device (an admission, a chunk, a decode dispatch): has
+        the last such launch's output arrived already, so that the
+        device has nothing of the loop's left to run? One non-blocking
+        read of an array's state."""
+        self._n_launch[kind] += 1
+        last = self._last_out
+        if last is None or last.is_ready():
+            self._n_starved[kind] += 1
+
     def _admit_chunk(self, chunk: list[_Slot], rows: list[int], S: int,
                      R: int,
                      warm_prefix: Optional[PrefixEntry] = None) -> None:
@@ -3909,8 +4106,9 @@ class BatchScheduler:
             s.admit_t = t_admit
         prefix = chunk[0].prefix if chunk else warm_prefix
         P = prefix.length if prefix is not None else 0
-        tokens, ints, floats, rings, tables = self._admit_host_arrays(
-            chunk, rows, S, R, prefix)
+        with self._phase("build"):
+            tokens, ints, floats, rings, tables = self._admit_host_arrays(
+                chunk, rows, S, R, prefix)
         self._admit_since_tick = True
         if chunk:       # warm-up's all-padding dispatches do not count
             self._n_admit_batches += 1
@@ -3931,31 +4129,24 @@ class BatchScheduler:
             # compiled on the worker thread instead of here.
             prog = self._admit_prefix_aot.get((P, S, R),
                                               self._admit_prefix_j)
-            (toks_dev, self._cache, self._keys, self._next_dev,
-             self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-             self._ring_dev, self._rps_dev) = \
-                prog(
-                    self._params, prefix.k, prefix.v, prefix.state,
-                    jnp.asarray(tokens), jnp.asarray(ints),
-                    jnp.asarray(floats), jnp.asarray(rings),
-                    jnp.asarray(tables), self._cache, self._keys,
-                    self._next_dev, self._temps_dev, self._top_ks_dev,
-                    self._top_ps_dev, self._ring_dev, self._rps_dev)
+            pre = (prefix.k, prefix.v, prefix.state)
         else:
             # Padding entries keep an all-zero table: their prefill writes
             # land in garbage page 0 (their table/length installs are
             # dropped via the row sentinel).
+            prog, pre, ints = self._admit_j, (), ints[:4]
+        with self._phase("upload"):
+            up = [jnp.asarray(a)
+                  for a in (tokens, ints, floats, rings, tables)]
+        with self._phase("launch"):
+            self._note_launch("admit")
             (toks_dev, self._cache, self._keys, self._next_dev,
              self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-             self._ring_dev, self._rps_dev) = \
-                self._admit_j(
-                    self._params, jnp.asarray(tokens),
-                    jnp.asarray(ints[:4]),
-                    jnp.asarray(floats), jnp.asarray(rings),
-                    jnp.asarray(tables), self._cache,
-                    self._keys, self._next_dev, self._temps_dev,
-                    self._top_ks_dev, self._top_ps_dev, self._ring_dev,
-                    self._rps_dev)
+             self._ring_dev, self._rps_dev) = prog(
+                self._params, *pre, *up, self._cache, self._keys,
+                self._next_dev, self._temps_dev, self._top_ks_dev,
+                self._top_ps_dev, self._ring_dev, self._rps_dev)
+        self._last_out = toks_dev
         self._install_admitted(chunk, rows, toks_dev)
 
     def _admit_host_arrays(self, chunk: list[_Slot], rows: list[int],
@@ -4090,8 +4281,9 @@ class BatchScheduler:
         t_admit = time.monotonic()
         for s in chunk:
             s.admit_t = t_admit
-        tokens, ints, floats, rings, tables = self._admit_host_arrays(
-            chunk, rows, S, R, prefix)
+        with self._phase("build"):
+            tokens, ints, floats, rings, tables = self._admit_host_arrays(
+                chunk, rows, S, R, prefix)
         # The padded positions are counted chunk by chunk (_prefill_step).
         P = prefix.length if prefix is not None else 0
         self._n_admit_batches += 1
@@ -4154,29 +4346,40 @@ class BatchScheduler:
         prog = self._prefill_chunk_aot.get(shape_key)
         if prog is None:
             prog = self._prefill_chunk_for(P0, S, off, C)
-        t = jnp.asarray(np.ascontiguousarray(tokens))
-        ij = jnp.asarray(ints)
-        tb = jnp.asarray(tables)
-        if first:
-            pre = (prefix.k, prefix.v, prefix.state) if P0 else ()
-            kv, logits, self._cache = prog(self._params, *pre, t, ij, tb,
-                                           self._cache)
-            self._chunk_shapes_run.add(shape_key)
-            return kv, logits, None
-        if not final:
-            kv, logits, self._cache = prog(self._params, t, ij, kv, logits,
-                                           tb, self._cache)
-            self._chunk_shapes_run.add(shape_key)
-            return kv, logits, None
-        (toks_dev, self._cache, self._keys, self._next_dev,
-         self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-         self._ring_dev, self._rps_dev) = prog(
-            self._params, t, ij, jnp.asarray(floats), jnp.asarray(rings),
-            kv, logits, tb, self._cache, self._keys, self._next_dev,
-            self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-            self._ring_dev, self._rps_dev)
+        with self._phase("build"):
+            tokens = np.ascontiguousarray(tokens)
+        with self._phase("upload"):
+            t = jnp.asarray(tokens)
+            ij = jnp.asarray(ints)
+            tb = jnp.asarray(tables)
+            if final:
+                fl, rg = jnp.asarray(floats), jnp.asarray(rings)
+        toks_dev = None
+        with self._phase("launch"):
+            self._note_launch("prefill_chunk")
+            if first:
+                pre = (prefix.k, prefix.v, prefix.state) if P0 else ()
+                kv, logits, self._cache = prog(self._params, *pre, t, ij,
+                                               tb, self._cache)
+            elif not final:
+                kv, logits, self._cache = prog(self._params, t, ij, kv,
+                                               logits, tb, self._cache)
+            else:
+                (toks_dev, self._cache, self._keys, self._next_dev,
+                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                 self._ring_dev, self._rps_dev) = prog(
+                    self._params, t, ij, fl, rg, kv, logits, tb,
+                    self._cache, self._keys, self._next_dev,
+                    self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                    self._ring_dev, self._rps_dev)
         self._chunk_shapes_run.add(shape_key)
-        return None, None, toks_dev
+        if toks_dev is not None:
+            self._last_out = toks_dev
+            return None, None, toks_dev
+        # The carry's logits: donated to the next chunk alone, after it
+        # has looked (for a routed model the drop count rides beside).
+        self._last_out = jax.tree.leaves(logits)[0]
+        return kv, logits, None
 
     # graftcheck: runs-on _loop
     def _note_admission_gap(self, now: float) -> None:
@@ -4281,8 +4484,9 @@ class BatchScheduler:
             # Re-upload the mask only when the active set changed (it only
             # moves on admission/finish — not per tick).
             self._active_host = active
-            # graftcheck: sync-ok host tuple -> device upload, not a readback
-            self._active_dev = jnp.asarray(np.array(active, bool))
+            with self._phase("upload"):
+                # graftcheck: sync-ok host tuple -> device upload, not a readback
+                self._active_dev = jnp.asarray(np.array(active, bool))
         # extra: under pipelining a row's device length can run up to
         # ``inflight`` slots ahead of the host's ctx_len, and this tick
         # writes K more slots — the deepest attended position is
@@ -4295,11 +4499,14 @@ class BatchScheduler:
             decode_j = self._decode_for(decode_w)
         else:
             decode_j = self._decode_fused_for(decode_w, K)
-        (toks_dev, self._next_dev, self._cache, self._keys,
-         self._ring_dev) = decode_j(
-            self._params, self._next_dev, self._cache, self._active_dev,
-            self._temps_dev, self._top_ks_dev, self._top_ps_dev, self._keys,
-            self._ring_dev, self._rps_dev)
+        with self._phase("launch"):
+            self._note_launch("decode")
+            (toks_dev, self._next_dev, self._cache, self._keys,
+             self._ring_dev) = decode_j(
+                self._params, self._next_dev, self._cache, self._active_dev,
+                self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                self._keys, self._ring_dev, self._rps_dev)
+        self._last_out = toks_dev
         return toks_dev, list(self._slots), K
 
     def _note_attn_chunks(self, window: int, K: int, inflight: int) -> None:
@@ -4566,33 +4773,40 @@ class BatchScheduler:
         active = tuple(s is not None for s in self._slots)
         if active != self._active_host:
             self._active_host = active
-            # graftcheck: sync-ok host tuple -> device upload, not a readback
-            self._active_dev = jnp.asarray(np.array(active, bool))
+            with self._phase("upload"):
+                # graftcheck: sync-ok host tuple -> device upload, not a readback
+                self._active_dev = jnp.asarray(np.array(active, bool))
         for name, rows_d in src_rows.items():
             if rows_d:
                 self._n_spec_dispatch_src[name] = (
                     self._n_spec_dispatch_src.get(name, 0) + 1)
         if tree:
             spec_j = self._spec_tree_for(self._window(extra=N - 1))
-            (accepted, used_sib, correction, self._next_dev,
-             self._cache, self._keys, self._ring_dev) = spec_j(
-                self._params, jnp.asarray(tokens), jnp.asarray(depths),
-                jnp.asarray(anc), jnp.asarray(drafts),
-                jnp.asarray(sib_tok), jnp.asarray(sib_node),
-                jnp.asarray(max_acc), self._cache, self._active_dev,
-                self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                self._keys, self._ring_dev, self._rps_dev)
+            with self._phase("upload"):
+                up = [jnp.asarray(a) for a in (tokens, depths, anc, drafts,
+                                               sib_tok, sib_node, max_acc)]
+            with self._phase("launch"):
+                self._note_launch("decode")
+                (accepted, used_sib, correction, self._next_dev,
+                 self._cache, self._keys, self._ring_dev) = spec_j(
+                    self._params, *up, self._cache, self._active_dev,
+                    self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                    self._keys, self._ring_dev, self._rps_dev)
             with self._phase("readback"):
                 used = np.asarray(used_sib)  # graftcheck: sync-ok 3xB int32 verify readback
         else:
             spec_j = self._spec_for(self._window(extra=K))
-            (accepted, correction, self._next_dev, self._cache,
-             self._keys, self._ring_dev) = spec_j(
-                self._params, jnp.asarray(tokens), jnp.asarray(drafts),
-                jnp.asarray(max_acc), self._cache, self._active_dev,
-                self._temps_dev, self._top_ks_dev, self._top_ps_dev, self._keys,
-                self._ring_dev, self._rps_dev)
+            with self._phase("upload"):
+                up = [jnp.asarray(a) for a in (tokens, drafts, max_acc)]
+            with self._phase("launch"):
+                self._note_launch("decode")
+                (accepted, correction, self._next_dev, self._cache,
+                 self._keys, self._ring_dev) = spec_j(
+                    self._params, *up, self._cache, self._active_dev,
+                    self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                    self._keys, self._ring_dev, self._rps_dev)
             used = np.zeros((B,), np.int32)
+        self._last_out = accepted
         with self._phase("readback"):
             acc = np.asarray(accepted)  # graftcheck: sync-ok 2xB int32 verify readback
             corr = np.asarray(correction)  # graftcheck: sync-ok same dispatch, already synced
@@ -4827,8 +5041,9 @@ class BatchScheduler:
         keep = min(len(slot.pages), self._alloc.pages_for(slot.ctx_len))
         kept, extra = slot.pages[:keep], slot.pages[keep:]
         try:
-            self._cache = self._zero_row_j(
-                self._cache, jnp.asarray(row, jnp.int32))
+            with self._phase("launch"):
+                self._cache = self._zero_row_j(
+                    self._cache, jnp.asarray(row, jnp.int32))
         except Exception:   # noqa: BLE001 — same contract as _release
             log.exception("row-table zero failed; resetting")
             self._fail_all_and_reset()
@@ -4864,8 +5079,9 @@ class BatchScheduler:
         pages, n = sess.pages, len(sess.pages)
         P2 = 1 << max(0, n - 1).bit_length()    # pow2 shape bucket
         padded = pages + [0] * (P2 - n)
-        out = self._gather_pages_j(self._cache,
-                                   jnp.asarray(padded, jnp.int32))
+        with self._phase("launch"):
+            out = self._gather_pages_j(self._cache,
+                                       jnp.asarray(padded, jnp.int32))
         with self._phase("readback"):
             # graftcheck: sync-ok the park IS the host copy — one readback per parked session
             payload = tuple(None if a is None else np.asarray(a)
@@ -4999,9 +5215,10 @@ class BatchScheduler:
                             for a in arrays)
             P2 = arrays[0].shape[1]
             padded = pages[:n] + [0] * (P2 - n)
-            self._cache = self._scatter_pages_j(
-                self._cache, jnp.asarray(padded, jnp.int32),
-                dev[0], dev[1], dev[2], dev[3])
+            with self._phase("launch"):
+                self._cache = self._scatter_pages_j(
+                    self._cache, jnp.asarray(padded, jnp.int32),
+                    dev[0], dev[1], dev[2], dev[3])
         else:
             extra = need - len(sess.pages)
             if extra > 0:
@@ -5097,14 +5314,19 @@ class BatchScheduler:
                           int(ints[1, row]) + int(ints[0, row]))
             for _, row in live)
         self._n_prefill_padded += B * S
-        (toks_dev, self._cache, self._keys, self._next_dev,
-         self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-         self._ring_dev, self._rps_dev) = self._wake_for(w, S)(
-            self._params, jnp.asarray(tokens), jnp.asarray(ints),
-            jnp.asarray(floats), jnp.asarray(rings), jnp.asarray(tables),
-            self._cache, self._keys, self._next_dev, self._temps_dev,
-            self._top_ks_dev, self._top_ps_dev, self._ring_dev,
-            self._rps_dev)
+        prog = self._wake_for(w, S)
+        with self._phase("upload"):
+            up = [jnp.asarray(a)
+                  for a in (tokens, ints, floats, rings, tables)]
+        with self._phase("launch"):
+            self._note_launch("admit")
+            (toks_dev, self._cache, self._keys, self._next_dev,
+             self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+             self._ring_dev, self._rps_dev) = prog(
+                self._params, *up, self._cache, self._keys, self._next_dev,
+                self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                self._ring_dev, self._rps_dev)
+        self._last_out = toks_dev
         self._wake_shapes_run.add((w, S))
         with self._phase("readback", rows=len(live)):
             # graftcheck: sync-ok B int32 first tokens — wake TTFT depends on it
@@ -5172,8 +5394,9 @@ class BatchScheduler:
                 return
         if slot is not None and slot.pages:
             try:
-                self._cache = self._zero_row_j(
-                    self._cache, jnp.asarray(row, jnp.int32))
+                with self._phase("launch"):
+                    self._cache = self._zero_row_j(
+                        self._cache, jnp.asarray(row, jnp.int32))
             except Exception:   # noqa: BLE001
                 # Whether or not the donated cache survived, the row's
                 # table was not provably zeroed, so its pages can't go

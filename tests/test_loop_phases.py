@@ -1,17 +1,19 @@
 """The scheduler loop and the boot path measure themselves.
 
-- the phase timer (obs/phase.py): nested self time, the current and the
-  slowest phase;
-- a tiny scheduler: the seven phases plus "other" close on the loop's
-  wall, the counts at the dispatch sites are exact for prompts of known
-  lengths and warm-up adds nothing to them, the boot gauges are set;
-- the phases are on the profiler's clock: a CPU profile holds
-  ``sched.*`` host events and the benchmark's gap attribution names
-  them;
+- the phase timer (obs/phase.py): nested self time, wall and CPU
+  alike, the parts of a phase, the current and the slowest phase;
+- a tiny scheduler: the eight phases, "other" among them, close on the
+  loop's wall, a phase's seconds hold its parts', the counts at the
+  dispatch sites are exact for prompts of known lengths and warm-up
+  adds nothing to them, the boot gauges are set;
+- the phases and their parts are on the profiler's clock: a CPU profile
+  holds ``sched.*`` host events, a part under its phase's name, and the
+  benchmark's gap attribution names them;
 - a cold warm-up job over the loop budget is no stall, a stall after
   ready still is, and its ``stall_enter`` event names the phase;
-- names are contracts: every ``pallas_call`` passes a literal ``name=``
-  and every program the scheduler jits is named by its kind.
+- names are contracts: every ``pallas_call`` passes a literal ``name=``,
+  every program the scheduler jits is named by its kind, and every call
+  of one from the serving loop sits inside a ``launch`` mark.
 
 All on the CPU: counts and control flow, never a device timing.
 """
@@ -31,7 +33,8 @@ import jax.numpy as jnp
 from p2p_llm_chat_tpu.models import llama
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.obs import phase as phase_mod
-from p2p_llm_chat_tpu.obs.phase import (PHASES, LoopPhases, compile_clock,
+from p2p_llm_chat_tpu.obs.phase import (CPU_EVERY, PARTS, PARTS_OF, PHASES,
+                                        LoopPhases, compile_clock,
                                         process_age_s)
 from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
                                             RequestStats)
@@ -44,6 +47,7 @@ CFG = get_config("tiny")
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
 PHASE_SERIES = [f"serve_loop_{n}_seconds_total" for n in PHASES]
+PART_NAMES = [f"{n}_{p}" for n, ps in PARTS_OF.items() for p in ps]
 SITE_COUNTERS = ("serve_admit_batches_total", "serve_admit_rows_padded_total",
                  "serve_prefill_tokens_total",
                  "serve_prefill_tokens_padded_total",
@@ -66,18 +70,34 @@ def _generate(sched: BatchScheduler, prompt: str, n: int) -> str:
 # -- the primitive -------------------------------------------------------------
 
 class _Clock:
+    """The wall (``t``) and the thread's CPU clock (``c``), moved by
+    hand: ``work`` moves both, ``wait`` the wall alone."""
+
     def __init__(self) -> None:
         self.t = 100.0
+        self.c = 7.0
+        self.cpu_reads = 0
 
     def monotonic(self) -> float:
         return self.t
+
+    def thread_time(self) -> float:
+        self.cpu_reads += 1
+        return self.c
+
+    def work(self, s: float) -> None:
+        self.t += s
+        self.c += s
+
+    def wait(self, s: float) -> None:
+        self.t += s
 
 
 @pytest.fixture()
 def clock(monkeypatch):
     c = _Clock()
-    monkeypatch.setattr(phase_mod, "time",
-                        types.SimpleNamespace(monotonic=c.monotonic))
+    monkeypatch.setattr(phase_mod, "time", types.SimpleNamespace(
+        monotonic=c.monotonic, thread_time=c.thread_time))
     return c
 
 
@@ -100,17 +120,98 @@ def test_phase_self_time_is_less_its_inner_phases(clock):
     assert sum(ph.seconds(n) for n in PHASES) == pytest.approx(3.875)
 
 
-def test_phase_names_the_current_and_the_slowest(clock):
+def test_nested_marks_subtract_wall_and_cpu_alike(clock):
     ph = LoopPhases()
-    assert ph.current == "" and ph.slowest == ""
+    with ph("other"):
+        clock.work(0.5)
+        with ph("admit"):
+            clock.work(1.0)
+            clock.wait(2.0)             # off the CPU inside admit itself
+            with ph("readback"):
+                clock.wait(4.0)         # blocked on the device
+                clock.work(0.25)
+            with ph("launch"):
+                clock.work(0.125)
+                clock.wait(0.5)         # blocked inside the runtime
+        clock.wait(0.0625)
+    want = {"other": (0.5625, 0.5), "admit": (3.0, 1.0),
+            "readback": (4.25, 0.25), "admit.launch": (0.625, 0.125)}
+    for name, (wall, cpu) in want.items():
+        assert ph.seconds(name) == pytest.approx(wall), name
+        assert ph.cpu(name) == pytest.approx(cpu), name
+        assert ph.marks(name) == 1
+    # A phase's total holds its parts' and not the phases inside it.
+    assert ph.total("admit") == pytest.approx(3.625)
+    assert ph.cpu_total("admit") == pytest.approx(1.125)
+    assert ph.inclusive("admit") == pytest.approx(7.875)
+    assert sum(ph.total(n) for n in PHASES) == pytest.approx(8.4375)
+    assert sum(ph.cpu_total(n) for n in PHASES) == pytest.approx(1.875)
+
+
+def test_the_cpu_clock_is_read_in_one_iteration_of_a_few(clock):
+    """At every mark of that iteration (nested marks subtract whole)
+    and at none of the others'; the wall of the marks it was read in is
+    kept beside it, so their ratio is exact."""
+    ph = LoopPhases()
+    for it in range(2 * CPU_EVERY):
+        ph.mark_iteration()
+        before = clock.cpu_reads
+        with ph("other"):
+            clock.work(0.25)
+            with ph("admit"):
+                clock.work(1.0)
+                clock.wait(1.0)
+                with ph("launch"):
+                    clock.work(0.5)
+        assert clock.cpu_reads - before == (6 if it % CPU_EVERY == 0 else 0)
+    assert ph.seconds("admit") == pytest.approx(2.0 * 2 * CPU_EVERY)
+    assert ph.marks("admit.launch") == 2 * CPU_EVERY
+    for name, wall, cpu in (("other", 0.25, 0.25), ("admit", 2.0, 1.0),
+                            ("admit.launch", 0.5, 0.5)):
+        assert ph.cpu_wall(name) == pytest.approx(2 * wall), name
+        assert ph.cpu(name) == pytest.approx(2 * cpu), name
+    assert ph.cpu_total("admit") == pytest.approx(3.0)
+    assert ph.cpu_wall_total("admit") == pytest.approx(5.0)
+
+
+@pytest.mark.parametrize("phase,part", [
+    (n, p) for n in PHASES for p in PARTS])
+def test_a_part_belongs_to_the_phase_around_it(clock, phase, part):
+    """Timed under the phases that list it (PARTS_OF), under its
+    phase's name; a no-op elsewhere: the time stays with the phase."""
+    ph = LoopPhases()
+    with ph(phase):
+        clock.work(1.0)
+        with ph("stream" if phase != "stream" else "readback"):
+            # A phase in between does not adopt the part...
+            clock.work(0.25)
+        with ph(part, rows=2) as mark:
+            clock.work(0.5)
+            with ph("launch" if part != "launch" else "upload"):
+                # ...and a part inside a part is its phase's too.
+                clock.work(0.125)
+    if part in PARTS_OF.get(phase, ()):
+        assert mark.label == f"sched.{phase}.{part}"
+        assert ph.marks(f"{phase}.{part}") == 1
+        assert ph.seconds(f"{phase}.{part}") >= 0.5
+        assert ph.seconds(phase) <= 1.0 + 0.125
+    else:
+        with pytest.raises(KeyError):
+            ph.seconds(f"{phase}.{part}")
+        assert ph.seconds(phase) >= 1.5
+    assert ph.total(phase) == pytest.approx(1.625)
+    assert ph.cpu_total(phase) == pytest.approx(1.625)
+    assert ph._top is None
+
+
+def test_phase_names_the_slowest_of_the_iteration(clock):
+    ph = LoopPhases()
+    assert ph.slowest == ""
     with ph("admit"):
-        assert ph.current == "admit"
         with ph("warmup"):
-            assert ph.current == "warmup"
             clock.t += 3.0
-        assert ph.current == "admit"
         clock.t += 1.0
-    assert ph.current == ""
+    assert ph._top is None
     with ph("decode_dispatch"):
         clock.t += 2.0
     assert ph.slowest == "warmup"
@@ -129,7 +230,7 @@ def test_phase_reentered_by_its_own_name_keeps_the_outer_time(clock):
             clock.t += 1.0
         clock.t += 1.0
     assert ph.seconds("readback") == pytest.approx(3.0)
-    assert ph.current == ""
+    assert ph._top is None
 
 
 def test_phase_time_is_kept_when_the_body_raises(clock):
@@ -140,7 +241,7 @@ def test_phase_time_is_kept_when_the_body_raises(clock):
                 clock.t += 1.0
                 raise RuntimeError("device reset")
     assert ph.seconds("readback") == pytest.approx(1.0)
-    assert ph.current == ""
+    assert ph._top is None
     with pytest.raises(KeyError):
         ph("no-such-phase")
 
@@ -182,19 +283,65 @@ def test_phases_and_other_close_on_the_loop_wall(served):
     m = served["end"]
     loop = m["serve_loop_seconds_total"]
     phases = sum(m[k] for k in PHASE_SERIES)
-    assert all(m[k] >= 0.0 for k in PHASE_SERIES)
     assert loop > 0.0 and m["serve_loop_iterations_total"] > 0
-    # "other" is a difference: never negative, and small.
+    # "other" is a phase: the eight are the loop's wall, less the few
+    # statements an iteration runs outside its outermost mark.
     assert phases <= loop + 1e-6
-    assert phases >= 0.9 * loop
-    for k in ("serve_loop_warmup_seconds_total",
-              "serve_loop_idle_seconds_total",
-              "serve_loop_readback_seconds_total",
-              "serve_loop_decode_dispatch_seconds_total",
-              "serve_loop_admit_seconds_total",
-              "serve_loop_stream_seconds_total",
-              "serve_loop_prefill_chunk_seconds_total"):
+    assert phases >= 0.99 * loop
+    for k in PHASE_SERIES:
         assert m[k] > 0.0, k
+
+
+@pytest.mark.parametrize("name", list(PHASES) + PART_NAMES)
+def test_cpu_seconds_sit_beside_the_wall_and_never_pass_it(served, name):
+    m = served["end"]
+    wall = m[f"serve_loop_{name}_seconds_total"]
+    cpu_wall = m[f"serve_loop_{name}_cpu_wall_seconds_total"]
+    cpu = m[f"serve_loop_{name}_cpu_seconds_total"]
+    # The CPU clock is read inside the wall clock's two reads, in some
+    # of the marks: CPU <= the wall of those marks <= the wall of all
+    # (a mark inside costs its outer one a clock read's worth of slack).
+    assert 0.0 <= cpu <= cpu_wall + 5e-5, (name, cpu, cpu_wall)
+    assert cpu_wall <= wall + 1e-9, (name, cpu_wall, wall)
+    if name == "idle" and cpu_wall:
+        assert cpu < 0.5 * cpu_wall     # a wait: the thread is off the CPU
+
+
+@pytest.mark.parametrize("phase", sorted(PARTS_OF))
+def test_a_phases_seconds_hold_its_parts(served, phase):
+    """``serve_loop_<phase>_seconds_total`` means what it meant before
+    the parts: the readers that divide it are the benchmark's."""
+    m = served["end"]
+    parts = [f"serve_loop_{phase}_{p}" for p in PARTS_OF[phase]]
+    for p in parts:
+        assert (m[p + "_marks_total"] > 0) == (m[p + "_seconds_total"] > 0)
+    assert m[f"serve_loop_{phase}_launch_marks_total"] > 0
+    total = m[f"serve_loop_{phase}_seconds_total"]
+    named = sum(m[p + "_seconds_total"] for p in parts)
+    assert named <= total + 1e-9
+    if phase in ("admit", "prefill_chunk"):
+        # The named parts hold the phase: what is left is bookkeeping.
+        assert named >= 0.8 * total, (phase, named, total)
+
+
+def test_launches_are_counted_by_kind_and_starved_ones_beside(served):
+    warm, m = served["warm"], served["live"]
+    d = {k: m[k] - warm[k] for k in m if k.startswith("serve_launch_")}
+    # Three admissions, one of them a ladder of two chunks; 18 decode
+    # dispatches (test_site_counters_are_exact).
+    assert d["serve_launch_admit_total"] == 2
+    assert d["serve_launch_prefill_chunk_total"] == 2
+    assert d["serve_launch_decode_total"] == 18
+    for kind in ("admit", "prefill_chunk", "decode"):
+        assert 0 <= d[f"serve_launch_{kind}_starved_total"] <= (
+            d[f"serve_launch_{kind}_total"])
+    # Each arrived at an empty device: nothing was in flight.
+    assert d["serve_launch_admit_starved_total"] == 2
+    # The launch marks of the three feeding phases are those launches.
+    for phase, kind in (("admit", "admit"), ("prefill_chunk",) * 2,
+                        ("decode_dispatch", "decode")):
+        assert m[f"serve_loop_{phase}_launch_marks_total"] == (
+            d[f"serve_launch_{kind}_total"])
 
 
 def test_warmup_adds_nothing_to_the_site_counters(served):
@@ -289,8 +436,26 @@ def test_profile_holds_the_phases_and_gaps_take_their_names(tmp_path):
         if ":sched." in n:
             by_phase.setdefault(n.split(":", 1)[1], []).append((s, e))
     for name in ("sched.decode_dispatch", "sched.readback", "sched.admit",
-                 "sched.stream"):
+                 "sched.stream", "sched.other",
+                 # The parts, under their phases' names.
+                 "sched.admit.collect", "sched.admit.plan",
+                 "sched.admit.build", "sched.admit.upload",
+                 "sched.admit.launch", "sched.decode_dispatch.launch"):
         assert by_phase.get(name), (name, sorted(by_phase))
+    # A part lies inside a mark of its phase.
+    for part in ("sched.admit.launch", "sched.decode_dispatch.launch"):
+        outer = by_phase[part.rsplit(".", 1)[0]]
+        assert all(any(s0 <= s and e <= e0 for s0, e0 in outer)
+                   for s, e in by_phase[part]), part
+    # A part, and "other", is annotated over its self time only: it
+    # covers no other mark (it would take every gap that spans two).
+    for leaf in ("sched.other", "sched.admit.plan"):
+        assert len(by_phase[leaf]) > len(by_phase["sched.admit"])
+        for name, events in by_phase.items():
+            if name != leaf:
+                assert not any(s0 <= s and e <= e0 and e > s
+                               for s0, e0 in by_phase[leaf]
+                               for s, e in events), (leaf, name)
     # Keyword arguments travel as the event's stats, not in its name.
     assert all("#" not in n for n in by_phase)
     # A gap inside a phase is given to that phase by the benchmark's own
@@ -307,8 +472,15 @@ def test_profile_holds_the_phases_and_gaps_take_their_names(tmp_path):
     only = [i for i, n in enumerate(names) if ":sched." in n]
     got = trace_reduce.attribute_gaps(
         gaps, (starts[only], ends[only], [names[i] for i in only]))
-    assert sorted(k.split(":", 1)[1] for k in got) == [
-        "sched.admit", "sched.decode_dispatch", "sched.readback"]
+    # A gap shorter than the part it falls in names the part; one that
+    # spans several names the phase around them.
+    assert sorted(k.split(":", 1)[1].split(".")[1] for k in got) == [
+        "admit", "decode_dispatch", "readback"]
+    s, e = by_phase["sched.admit.launch"][0]
+    got = trace_reduce.attribute_gaps(
+        [(s + (e - s) * 0.25, s + (e - s) * 0.75)],
+        (starts[only], ends[only], [names[i] for i in only]))
+    assert [k.split(":", 1)[1] for k in got] == ["sched.admit.launch"]
 
 
 # -- the watchdog and warm-up ------------------------------------------------------
@@ -349,6 +521,9 @@ def test_cold_warmup_is_no_stall_and_a_later_stall_names_its_phase(tmp_path):
         assert stall["phase"] == "decode_dispatch"
     finally:
         fp.disarm_all()
+        # The counts are the process's: a test of another file that
+        # lands on this worker reads its own site's from zero.
+        fp.reset_hits()
         sched.stop()
 
 
@@ -409,6 +584,69 @@ def test_every_scheduler_program_is_named_by_its_kind():
             f"scheduler.py:{call.lineno}: jax.jit of something other than "
             "a named function")
         assert fn.id.startswith(kinds), (call.lineno, fn.id)
+
+
+# The handles the scheduler keeps its compiled programs under, and the
+# local names it calls them by.
+_PROGRAM_ATTRS = ("_admit_j", "_admit_prefix_j", "_zero_row_j",
+                  "_gather_pages_j", "_scatter_pages_j")
+_PROGRAM_NAMES = ("prog", "decode_j", "spec_j")
+_PROGRAM_MAKERS = ("_decode_for", "_decode_fused_for", "_spec_for",
+                   "_spec_tree_for", "_wake_for", "_prefill_chunk_for")
+
+
+def _is_program_call(call: ast.Call) -> bool:
+    f = call.func
+    if isinstance(f, ast.Attribute):
+        return f.attr in _PROGRAM_ATTRS
+    if isinstance(f, ast.Name):
+        return f.id in _PROGRAM_NAMES
+    # self._wake_for(w, S)(...): the maker's result called in place.
+    return (isinstance(f, ast.Call) and isinstance(f.func, ast.Attribute)
+            and f.func.attr in _PROGRAM_MAKERS)
+
+
+def _is_launch_mark(node: ast.With) -> bool:
+    return any(isinstance(i.context_expr, ast.Call)
+               and i.context_expr.args
+               and isinstance(i.context_expr.args[0], ast.Constant)
+               and i.context_expr.args[0].value == "launch"
+               for i in node.items)
+
+
+def test_every_program_the_loop_calls_sits_inside_a_launch():
+    """Source-level: in the functions that serve (warm-up's and the
+    device probe's run under the ``warmup`` phase, whose parts are not
+    timed), a call of a compiled program has a ``with ...("launch")``
+    around it."""
+    with open(os.path.join(
+            ROOT, "p2p_llm_chat_tpu/serve/scheduler.py")) as f:
+        tree = ast.parse(f.read())
+    cls = next(n for n in tree.body if isinstance(n, ast.ClassDef)
+               and n.name == "BatchScheduler")
+    found, bare = [], []
+
+    def walk(node, fn, marked):
+        for child in ast.iter_child_nodes(node):
+            m = marked or (isinstance(child, ast.With)
+                           and _is_launch_mark(child))
+            if isinstance(child, ast.Call) and _is_program_call(child):
+                found.append(fn)
+                if not m:
+                    bare.append((fn, child.lineno))
+            walk(child, fn, m)
+
+    for fn in cls.body:
+        if isinstance(fn, ast.FunctionDef) and not fn.name.startswith(
+                ("_warm", "_probe_device_step", "__init__")):
+            walk(fn, fn.name, False)
+    assert not bare, bare
+    # The sites ISSUE 34 lists, and the small ones beside them.
+    assert set(found) >= {"_admit_chunk", "_admit_wake",
+                          "_dispatch_prefill_chunk", "_dispatch_tick",
+                          "_spec_tick", "_release", "_retain_session",
+                          "_park_session", "_wake_install_kv"}
+    assert len(found) >= 12
 
 
 def test_a_lowered_program_carries_its_kind():
