@@ -42,6 +42,7 @@ Design, shaped by XLA's compilation model (SURVEY.md §7 "hard parts"):
 from __future__ import annotations
 
 import collections
+import itertools
 import queue
 import threading
 import time
@@ -51,6 +52,7 @@ from typing import Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models import family_for
 from ..models.configs import ModelConfig
@@ -125,6 +127,48 @@ def _bucket(n: int, max_seq: int) -> int:
     while b < n:
         b *= 2
     return min(b, max_seq)
+
+
+def _admit_layout(mppr: int) -> tuple[int, ...]:
+    """The one int32 buffer that carries an admission to the device: a
+    row an entry, and in a row, back to back, the entry's five ints
+    (len / row / seed / top_k / total len), its three floats as their
+    bits (temperature / top_p / repeat_penalty), its penalty ring
+    [_RING], its page table [mppr] and its S tokens, so a buffer
+    [R, W] names R and S by its shape. Returns the four column cuts
+    between the parts; the last is where the tokens start. One
+    transfer an admission and none for a ladder's later chunk, where
+    five arrays went up a dispatch: -0.8 to -1.2 ms of host a loop
+    iteration in the full-batch cells (PERF.md §6, PR 36)."""
+    return tuple(itertools.accumulate((5, 3, _RING, mppr)))
+
+
+def _admit_unpack(buf, mppr: int) -> tuple:
+    """_admit_layout's inverse: (tokens [R,S], ints [5,R], floats
+    [3,R], rings [R,_RING], tables [R,mppr]) of a packed buffer. Of a
+    host buffer they are views, so what is written to them is written
+    to it; of a device buffer, inside a program, static slices.
+    ``floats`` comes back float32, bit for bit what the host wrote."""
+    cuts = _admit_layout(mppr)
+    ints, floats, rings, tables, tokens = (
+        buf[:, lo:hi] for lo, hi in zip((0, *cuts), (*cuts, None)))
+    if isinstance(buf, np.ndarray):
+        floats = floats.view(np.float32)
+    else:
+        floats = jax.lax.bitcast_convert_type(floats, jnp.float32)
+    return tokens, ints.T, floats.T, rings, tables
+
+
+def _admit_buffer(R: int, S: int, mppr: int, vocab_size: int) -> tuple:
+    """A fresh host buffer for R entries of S tokens and its five
+    views, holding what an entry that carries nothing holds: no
+    tokens, no pages, an empty penalty window (the sentinel
+    ``vocab_size``), top_p and repeat penalty 1.0."""
+    buf = np.zeros((R, _admit_layout(mppr)[-1] + S), np.int32)
+    views = _admit_unpack(buf, mppr)
+    views[2][1:] = 1.0
+    views[3][:] = vocab_size
+    return buf, views
 
 
 def _causal_pairs(lo: int, hi: int) -> int:
@@ -248,11 +292,9 @@ class _PrefillCarry:
     prefix: Optional[PrefixEntry]  # shared broadcast prefix (or None)
     kv: Optional[object]           # device carry cache [L,R,P0+S,Hkv,D]
     logits: Optional[object]       # device carry [R,V] f32
-    tokens: "np.ndarray"           # [R,S] right-padded suffix tokens
-    ints: "np.ndarray"             # [5,R] lens/rows/seeds/top_k/total-lens
-    floats: "np.ndarray"           # [3,R] temp/top_p/repeat_penalty
-    rings: "np.ndarray"            # [R,_RING] prompt-tail penalty windows
-    tables: "np.ndarray"            # [R,mppr] page maps
+    packed: object                 # the admission's packed buffer, on the
+    # device since the admission (_admit_layout): every chunk program
+    # takes it whole and slices its own tokens, so no chunk uploads
 
 
 class _SlotStream:
@@ -625,6 +667,11 @@ class BatchScheduler:
                         f"{config.name} keeps recurrent state beside its "
                         f"pages ({config.ssm_layers} Mamba layers) and is "
                         f"not served under {what}")
+        # Where an admission's packed buffer goes (_admit_upload): every
+        # device of a mesh, committed; else the default device.
+        self._packed_sharding = (
+            None if mesh is None
+            else NamedSharding(mesh, PartitionSpec()))
         # Width of the counts a routed model's programs hand back behind
         # their tokens (_with_moe): 2, or the family's own.
         self._moe_w = getattr(model, "STATS_WIDTH", 2)
@@ -702,6 +749,7 @@ class BatchScheduler:
         # x steps per decode dispatch, and the decode dispatch intervals
         # no admission work cut into (_note_clean_interval).
         self._n_admit_batches = 0
+        self._n_admit_uploads = 0
         self._n_admit_rows_padded = 0
         self._n_prefill_tokens = 0
         self._n_prefill_padded = 0
@@ -1186,14 +1234,17 @@ class BatchScheduler:
             beyond their trusted lengths or in the garbage page — the
             overwrite-before-trust invariant, same as a spec tick.
 
-            tokens [B,S] right-padded suffixes; ints [4,B] = suffix
+            ``packed`` is the admission buffer (_admit_layout) at R = B:
+            tokens [B,S] right-padded suffixes; ints[:4] = suffix
             lens (0 = not waking) / session lengths / seeds / top_k;
             floats [3,B] = temp/top_p/repeat_penalty; rings [B,_RING]
             prompt-tail penalty windows; tables [B,mppr] = each waking
             row's FULL page map (the session's kept pages plus
             freshly-allocated growth pages)."""
-            def kv_wake(params, tokens, ints, floats, rings, tables, cache,
-                        keys, next_tokens, temps, top_ks, top_ps, ring, rps):
+            def kv_wake(params, packed, cache, keys, next_tokens, temps,
+                        top_ks, top_ps, ring, rps):
+                tokens, ints, floats, rings, tables = _admit_unpack(
+                    packed, cache.max_pages_per_row)
                 suf, start = ints[0], ints[1]
                 mask = suf > 0
                 lengths = jnp.where(mask, start, cache.lengths).astype(
@@ -1228,7 +1279,7 @@ class BatchScheduler:
                 return (toks, cache, keys, next_tokens, temps, top_ks,
                         top_ps, ring, rps)
             return jax.jit(kv_wake,
-                           donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+                           donate_argnums=(2, 3, 4, 5, 6, 7, 8, 9))
 
         self._make_wake = _make_wake
         self._wake_programs: dict[tuple[int, int], object] = {}
@@ -1253,11 +1304,10 @@ class BatchScheduler:
             """Shared admission prologue: batched prefill of R prompts +
             each row's first sampled token.
 
-            Host scalars arrive packed (``ints`` [4,R] = lens/rows/seeds/
-            top_k, ``floats`` [3,R] = temperature/top_p/repeat_penalty,
-            ``rings`` [R,_RING] = prompt-tail penalty windows): every
-            separate H2D upload is its own host dispatch, so the dispatch
-            carries four arrays, not nine."""
+            The arguments are the packed buffer's parts (_admit_unpack:
+            ``ints`` rows 0-3 = lens/rows/seeds/top_k, ``floats`` [3,R] =
+            temperature/top_p/repeat_penalty, ``rings`` [R,_RING] =
+            prompt-tail penalty windows)."""
             R, S = tokens.shape
             lens, seeds = ints[0], ints[2]
             chunk_temps, chunk_tps = floats[0], floats[1]
@@ -1316,9 +1366,8 @@ class BatchScheduler:
             return small._replace(
                 state=from_snapshot(ps, small.lengths.shape[0]))
 
-        def prefill_admit_paged(params, tokens, ints, floats, rings, tables,
-                                cache, keys, next_tokens, temps, top_ks,
-                                top_ps, ring, rps):
+        def prefill_admit_paged(params, packed, cache, keys, next_tokens,
+                                temps, top_ks, top_ps, ring, rps):
             """Prefill R prompts together, splice each row's kv into the
             page pool, and sample each row's first token. R comes from a
             two-size ladder and S is the prompt bucket — two compiled
@@ -1327,7 +1376,10 @@ class BatchScheduler:
             R sequential scatters cost ~8x the TTFT). Padding entries
             carry an all-zero table (writes land in garbage page 0) and
             the out-of-range row sentinel ``num_slots`` (installs
-            dropped)."""
+            dropped). ``packed``: the admission's five host arrays in
+            one buffer (_admit_layout)."""
+            tokens, ints, floats, rings, tables = _admit_unpack(
+                packed, cache.max_pages_per_row)
             lens, rows = ints[0], ints[1]
             small, toks, row_keys, rings, moe = _prefill_first_token(
                 params, tokens, ints, floats, rings)
@@ -1351,8 +1403,8 @@ class BatchScheduler:
             continuation shape the speculative verify path uses), so
             admission compute scales with the suffix, not the prompt.
 
-            ``ints`` gains a 5th row vs the plain prologue: [0]=suffix
-            lens, [4]=total lens (prefix + suffix — the context length
+            ``ints`` row 4 is read here and not by the plain prologue:
+            [0]=suffix lens, [4]=total lens (prefix + suffix — the context length
             installed in the big cache and the penalty-ring position of
             the first sampled token)."""
             R, S = tokens.shape
@@ -1383,16 +1435,17 @@ class BatchScheduler:
             rings = rings.at[jnp.arange(R), total_lens % _RING].set(toks)
             return small, toks, row_keys, rings, moe
 
-        def prefill_admit_paged_prefix(params, pk, pv, ps, tokens, ints,
-                                       floats, rings, tables, cache, keys,
-                                       next_tokens, temps, top_ks, top_ps,
-                                       ring, rps):
+        def prefill_admit_paged_prefix(params, pk, pv, ps, packed, cache,
+                                       keys, next_tokens, temps, top_ks,
+                                       top_ps, ring, rps):
             """prefill_admit_paged for a chunk sharing one cached prefix:
             the combined [prefix + suffix] KV (the small cache, P+S wide)
             splices into each row's own pages through the one-scatter
             batch path, and lengths = total (copy-based sharing — rows
             own their prefix copy, so release/containment invariants are
             untouched)."""
+            tokens, ints, floats, rings, tables = _admit_unpack(
+                packed, cache.max_pages_per_row)
             rows, total_lens = ints[1], ints[4]
             small, toks, row_keys, rings, moe = _prefill_first_token_prefix(
                 params, pk, pv, ps, tokens, ints, floats, rings)
@@ -1407,10 +1460,10 @@ class BatchScheduler:
                     top_ks, top_ps, ring, rps)
 
         self._admit_j = jax.jit(prefill_admit_paged,
-                                donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+                                donate_argnums=(2, 3, 4, 5, 6, 7, 8, 9))
         self._admit_prefix_j = jax.jit(
             prefill_admit_paged_prefix,
-            donate_argnums=(9, 10, 11, 12, 13, 14, 15, 16))
+            donate_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 
         def kv_zero_row(cache, row):
             return set_row_table(
@@ -1473,6 +1526,14 @@ class BatchScheduler:
             W = P0 + S
             base = P0 + OFF
 
+            def _parts(packed, cache):
+                """The admission buffer's parts, ``tokens`` cut to this
+                chunk's columns: every chunk of a ladder takes the one
+                buffer its admission uploaded."""
+                tokens, *rest = _admit_unpack(packed,
+                                              cache.max_pages_per_row)
+                return (tokens[:, OFF: OFF + C], *rest)
+
             def _fwd(params, tokens, ints, carry, logits_c):
                 # A routed model's carried logits travel with its drop
                 # count so far: (logits [R,V], stats [2]).
@@ -1514,10 +1575,11 @@ class BatchScheduler:
             if first:
                 def prefill_chunk_first(params, *args):
                     if P0:
-                        pk, pv, ps, tokens, ints, tables, cache = args
+                        pk, pv, ps, packed, cache = args
                     else:
                         pk = pv = ps = None
-                        tokens, ints, tables, cache = args
+                        packed, cache = args
+                    tokens, ints, _, _, tables = _parts(packed, cache)
                     R = tokens.shape[0]
                     carry = KVCache.create(config, R, W, dtype=self._dtype)
                     if P0:
@@ -1535,21 +1597,22 @@ class BatchScheduler:
                     return carry, logits_c, cache
                 # donate the big cache (always the last argument)
                 return jax.jit(prefill_chunk_first,
-                               donate_argnums=(7 if P0 else 4,))
+                               donate_argnums=(5 if P0 else 2,))
 
             if not final:
-                def prefill_chunk_mid(params, tokens, ints, carry, logits_c,
-                                      tables, cache):
+                def prefill_chunk_mid(params, packed, carry, logits_c,
+                                      cache):
+                    tokens, ints, _, _, tables = _parts(packed, cache)
                     carry, logits_c = _fwd(params, tokens, ints, carry,
                                            logits_c)
                     cache = _splice(cache, carry, ints, tables)
                     return carry, logits_c, cache
-                return jax.jit(prefill_chunk_mid, donate_argnums=(3, 4, 6))
+                return jax.jit(prefill_chunk_mid, donate_argnums=(2, 3, 4))
 
-            def prefill_chunk_final(params, tokens, ints, floats, rings,
-                                    carry, logits_c, tables, cache, keys,
-                                    next_tokens, temps, top_ks, top_ps, ring,
-                                    rps):
+            def prefill_chunk_final(params, packed, carry, logits_c, cache,
+                                    keys, next_tokens, temps, top_ks, top_ps,
+                                    ring, rps):
+                tokens, ints, floats, rings, tables = _parts(packed, cache)
                 carry, logits_c = _fwd(params, tokens, ints, carry,
                                        logits_c)
                 moe = None
@@ -1575,7 +1638,7 @@ class BatchScheduler:
             # to alias into — donating them only trips XLA's unusable-
             # donation warning, so they are freed by refcount instead.
             return jax.jit(prefill_chunk_final,
-                           donate_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
+                           donate_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 
         self._make_prefill_chunk_program = _make_prefill_chunk_program
         self._prefill_chunk_programs: dict[tuple[int, int, int], object] = {}
@@ -2209,18 +2272,16 @@ class BatchScheduler:
         aot_admit: dict[tuple, object] = {}
         aot_chunks: dict[tuple, object] = {}
         for S, R, C, offs in combos:
-            ints5 = jax.ShapeDtypeStruct((5, R), jnp.int32)
-            floats3 = jax.ShapeDtypeStruct((3, R), jnp.float32)
-            rings = jax.ShapeDtypeStruct((R, _RING), jnp.int32)
-            tables = jax.ShapeDtypeStruct((R, structs["mppr"]), jnp.int32)
+            # The packed admission buffer (_admit_layout), whole for
+            # every program of the bucket.
+            packed = jax.ShapeDtypeStruct(
+                (R, _admit_layout(structs["mppr"])[-1] + S), jnp.int32,
+                sharding=self._packed_sharding)
             if offs is None:
-                args = [params_s, ks, vs, ss,
-                        jax.ShapeDtypeStruct((R, S), jnp.int32), ints5,
-                        floats3, rings, tables, cache_s, *sample_s]
+                args = [params_s, ks, vs, ss, packed, cache_s, *sample_s]
                 aot_admit[(P, S, R)] = (
                     self._admit_prefix_j.lower(*args).compile())
                 continue
-            toks = jax.ShapeDtypeStruct((R, C), jnp.int32)
             carry_s = jax.eval_shape(
                 lambda R=R, W=P + S: KVCache.create(self.config, R, W,
                                                     dtype=self._dtype))
@@ -2228,14 +2289,12 @@ class BatchScheduler:
             for off in offs:
                 prog = self._make_prefill_chunk_program(P, S, off, C)
                 if off == 0:
-                    args = [params_s, ks, vs, ss, toks, ints5, tables,
-                            cache_s]
+                    args = [params_s, ks, vs, ss, packed, cache_s]
                 elif off + C < S:
-                    args = [params_s, toks, ints5, carry_s, logits_s, tables,
-                            cache_s]
+                    args = [params_s, packed, carry_s, logits_s, cache_s]
                 else:
-                    args = [params_s, toks, ints5, floats3, rings, carry_s,
-                            logits_s, tables, cache_s, *sample_s]
+                    args = [params_s, packed, carry_s, logits_s, cache_s,
+                            *sample_s]
                 aot_chunks[(P, S, off, C, R)] = (
                     prog.lower(*args).compile())
         return aot_admit, aot_chunks
@@ -2346,25 +2405,16 @@ class BatchScheduler:
         if prefix_len + S > self.max_seq:
             return
         C = self.prefill_chunk
-        tokens = np.zeros((R, C), np.int32)
-        ints = np.zeros((5, R), np.int32)
-        ints[0] = 1
-        ints[1] = self.num_slots
-        ints[4] = prefix_len + 1
-        floats = np.zeros((3, R), np.float32)
-        floats[1] = 1.0
-        floats[2] = 1.0
-        rings = np.full((R, _RING), self.config.vocab_size, np.int32)
-        tables = np.zeros((R, self._cache.max_pages_per_row), np.int32)
+        packed = self._admit_upload(
+            self._admit_host_arrays([], [], S, R, entry), live=False)
         if off == 0:
             kv = logits = None
         else:
             kv = KVCache.create(self.config, R, prefix_len + S,
                                 dtype=self._dtype)
             logits = self._chunk_logits0(R)
-        self._dispatch_prefill_chunk(prefix_len, S, off, C, tokens, ints,
-                                     floats, rings, tables, kv, logits,
-                                     entry)
+        self._dispatch_prefill_chunk(prefix_len, S, off, C, packed, kv,
+                                     logits, entry)
 
     # graftcheck: runs-on _loop
     def _warm_window(self, w: int) -> None:
@@ -2448,19 +2498,13 @@ class BatchScheduler:
         lengths / in the garbage page."""
         if w < S:
             return   # dispatch never picks w < start + S
-        B = self.num_slots
-        tokens = np.zeros((B, S), np.int32)
-        ints = np.zeros((4, B), np.int32)
-        floats = np.zeros((3, B), np.float32)
-        floats[1] = 1.0
-        floats[2] = 1.0
-        rings = np.full((B, _RING), self.config.vocab_size, np.int32)
-        tables = np.zeros((B, self._cache.max_pages_per_row), np.int32)
+        buf, _ = _admit_buffer(self.num_slots, S,
+                               self._cache.max_pages_per_row,
+                               self.config.vocab_size)
         (_, self._cache, self._keys, self._next_dev, self._temps_dev,
          self._top_ks_dev, self._top_ps_dev, self._ring_dev,
          self._rps_dev) = self._wake_for(w, S)(
-            self._params, jnp.asarray(tokens), jnp.asarray(ints),
-            jnp.asarray(floats), jnp.asarray(rings), jnp.asarray(tables),
+            self._params, self._admit_upload(buf, live=False),
             self._cache, self._keys, self._next_dev, self._temps_dev,
             self._top_ks_dev, self._top_ps_dev, self._ring_dev,
             self._rps_dev)
@@ -3539,6 +3583,10 @@ class BatchScheduler:
             # admission work cut into with the steps they held
             # (_note_clean_interval).
             "serve_admit_batches_total": self._n_admit_batches,
+            # Host-to-device transfers the admission path issued: one
+            # an admission, none for a ladder's chunks (over batches +
+            # prefill_chunks_total: the transfers a dispatch).
+            "serve_admit_uploads_total": self._n_admit_uploads,
             "serve_admit_rows_padded_total": self._n_admit_rows_padded,
             "serve_prefill_tokens_total": self._n_prefill_tokens,
             "serve_prefill_tokens_padded_total": self._n_prefill_padded,
@@ -4088,13 +4136,18 @@ class BatchScheduler:
 
         A prefix-cached chunk (every slot carries the same
         ``slot.prefix``; _admit_pending groups by entry) uploads only the
-        suffix tokens: S is the *suffix* bucket, ``ints`` grows a 5th row
-        with total (prefix+suffix) lengths, and the prefix-variant
+        suffix tokens: S is the *suffix* bucket, ``ints[4]`` holds the
+        total (prefix+suffix) lengths, and the prefix-variant
         program broadcasts the cached KV instead of recomputing it.
 
         An EMPTY chunk is the warmup path: all R entries are padding, so
         the dispatch compiles-and-runs the exact serving program as a
-        device no-op (``warm_prefix`` selects the prefix variant)."""
+        device no-op (``warm_prefix`` selects the prefix variant).
+
+        The host's five arrays go up as ONE packed buffer
+        (_admit_host_arrays, _admit_upload) and the program takes them
+        apart: the dispatch enters the runtime twice, an upload and a
+        launch."""
         # Failpoint: an injected admission fault must fail THIS chunk's
         # requests cleanly (the _admit_pending recovery envelope) and
         # leave the loop serving — the contract tests/test_failpoints.py
@@ -4107,8 +4160,7 @@ class BatchScheduler:
         prefix = chunk[0].prefix if chunk else warm_prefix
         P = prefix.length if prefix is not None else 0
         with self._phase("build"):
-            tokens, ints, floats, rings, tables = self._admit_host_arrays(
-                chunk, rows, S, R, prefix)
+            packed = self._admit_host_arrays(chunk, rows, S, R, prefix)
         self._admit_since_tick = True
         if chunk:       # warm-up's all-padding dispatches do not count
             self._n_admit_batches += 1
@@ -4134,16 +4186,14 @@ class BatchScheduler:
             # Padding entries keep an all-zero table: their prefill writes
             # land in garbage page 0 (their table/length installs are
             # dropped via the row sentinel).
-            prog, pre, ints = self._admit_j, (), ints[:4]
-        with self._phase("upload"):
-            up = [jnp.asarray(a)
-                  for a in (tokens, ints, floats, rings, tables)]
+            prog, pre = self._admit_j, ()
+        packed = self._admit_upload(packed, live=bool(chunk))
         with self._phase("launch"):
             self._note_launch("admit")
             (toks_dev, self._cache, self._keys, self._next_dev,
              self._temps_dev, self._top_ks_dev, self._top_ps_dev,
              self._ring_dev, self._rps_dev) = prog(
-                self._params, *pre, *up, self._cache, self._keys,
+                self._params, *pre, packed, self._cache, self._keys,
                 self._next_dev, self._temps_dev, self._top_ks_dev,
                 self._top_ps_dev, self._ring_dev, self._rps_dev)
         self._last_out = toks_dev
@@ -4151,13 +4201,14 @@ class BatchScheduler:
 
     def _admit_host_arrays(self, chunk: list[_Slot], rows: list[int],
                            S: int, R: int,
-                           prefix: Optional[PrefixEntry]) -> tuple:
-        """Host-side upload arrays for one admission chunk — shared by
-        the single-shot programs and the chunked-prefill carry, so the
-        two admission paths cannot drift. Returns (tokens [R,S], ints
-        [5,R] = lens/rows/seeds/top_k/total-lens, floats [3,R], rings
-        [R,_RING], tables [R,mppr]); the non-prefix single-shot
-        programs consume ``ints[:4]``.
+                           prefix: Optional[PrefixEntry]) -> "np.ndarray":
+        """The host's side of one admission chunk — shared by the
+        single-shot programs and the chunked-prefill carry, so the two
+        admission paths cannot drift: ONE packed buffer (_admit_layout)
+        holding tokens [R,S], ints [5,R] = lens/rows/seeds/top_k/
+        total-lens, floats [3,R], rings [R,_RING], tables [R,mppr],
+        filled through its views; the programs without a prefix do not
+        read ``ints[4]``.
 
         The requests take the FIRST entries and the dummy entries
         follow: a routed MLP's capacity buckets fill in entry order
@@ -4166,15 +4217,11 @@ class BatchScheduler:
         requests they filled those buckets and a quarter of the real
         tokens' assignments were dropped (PERF.md §6, PR 24)."""
         P = prefix.length if prefix is not None else 0
-        tokens = np.zeros((R, S), np.int32)
-        ints = np.zeros((5, R), np.int32)
-        floats = np.zeros((3, R), np.float32)       # temp/top_p/repeat_pen
-        rings = np.full((R, _RING), self.config.vocab_size, np.int32)
+        packed, (tokens, ints, floats, rings, tables) = _admit_buffer(
+            R, S, self._cache.max_pages_per_row, self.config.vocab_size)
         ints[0] = 1                                 # padding: 1-token prompt
         ints[1] = self.num_slots                    # padding: dropped rows
         ints[4] = P + 1
-        floats[1] = 1.0
-        floats[2] = 1.0
         for r, (slot, row) in enumerate(zip(chunk, rows)):
             suffix = slot.prompt_ids[P:]
             tokens[r, : len(suffix)] = suffix
@@ -4190,10 +4237,21 @@ class BatchScheduler:
                 start = max(0, len(slot.prompt_ids) - _RING)
                 for p_i in range(start, len(slot.prompt_ids)):
                     rings[r, p_i % _RING] = slot.prompt_ids[p_i]
-        tables = np.zeros((R, self._cache.max_pages_per_row), np.int32)
         for r, slot in enumerate(chunk):
             tables[r, : len(slot.pages)] = slot.pages
-        return tokens, ints, floats, rings, tables
+        return packed
+
+    def _admit_upload(self, packed: "np.ndarray", live: bool = True):
+        """The admission path's one transfer: the packed host buffer to
+        the device, or under a mesh to every device of it, committed,
+        so that a ladder's later chunks find it where they run and no
+        launch places it again. ``live`` is False for warm-up's
+        dispatches, which ``serve_admit_uploads_total`` leaves out as
+        ``serve_admit_batches_total`` does."""
+        with self._phase("upload"):
+            if live:
+                self._n_admit_uploads += 1
+            return jax.device_put(packed, self._packed_sharding)
 
     def _install_admitted(self, chunk: list[_Slot], rows: list[int],
                           toks_dev, dispatches: int = 1) -> None:
@@ -4282,8 +4340,9 @@ class BatchScheduler:
         for s in chunk:
             s.admit_t = t_admit
         with self._phase("build"):
-            tokens, ints, floats, rings, tables = self._admit_host_arrays(
-                chunk, rows, S, R, prefix)
+            packed = self._admit_host_arrays(chunk, rows, S, R, prefix)
+        # The ladder's one upload: every chunk takes this buffer.
+        packed = self._admit_upload(packed)
         # The padded positions are counted chunk by chunk (_prefill_step).
         P = prefix.length if prefix is not None else 0
         self._n_admit_batches += 1
@@ -4293,9 +4352,7 @@ class BatchScheduler:
             _causal_pairs(P, len(s.prompt_ids)) for s in chunk)
         self._prefill_carry = _PrefillCarry(
             chunk=chunk, rows=rows, S=S, off=0, C=C,
-            prefix=prefix, kv=None,
-            logits=None, tokens=tokens, ints=ints, floats=floats,
-            rings=rings, tables=tables)
+            prefix=prefix, kv=None, logits=None, packed=packed)
 
     def _prefill_step(self) -> None:
         """Dispatch ONE continuation-prefill chunk of the in-progress
@@ -4308,7 +4365,7 @@ class BatchScheduler:
         C = pc.C    # the carry's own width — see _PrefillCarry.C
         P0 = pc.prefix.length if pc.prefix is not None else 0
         off = pc.off
-        R = pc.tokens.shape[0]
+        R = pc.packed.shape[0]
         self._n_prefill_chunks += 1
         self._n_prefill_padded += R * C
         self._admit_since_tick = True
@@ -4316,8 +4373,7 @@ class BatchScheduler:
                           off=off, C=C, S=pc.S, n=len(pc.chunk))
         with self._phase("prefill_chunk", R=R, S=pc.S, C=C, off=off):
             kv, logits, toks_dev = self._dispatch_prefill_chunk(
-                P0, pc.S, off, C, pc.tokens[:, off: off + C], pc.ints,
-                pc.floats, pc.rings, pc.tables, pc.kv, pc.logits, pc.prefix)
+                P0, pc.S, off, C, pc.packed, pc.kv, pc.logits, pc.prefix)
             if toks_dev is None:
                 pc.kv, pc.logits, pc.off = kv, logits, off + C
                 return
@@ -4331,44 +4387,37 @@ class BatchScheduler:
                                    dispatches=pc.S // C)
 
     def _dispatch_prefill_chunk(self, P0: int, S: int, off: int, C: int,
-                                tokens, ints, floats, rings, tables, kv,
-                                logits, prefix) -> tuple:
+                                packed, kv, logits, prefix) -> tuple:
         """Run one continuation-chunk program (live admission and warmup
         share this dispatch, so argument order cannot drift from the
         compiled signatures). ``C``: the chunk width — the carry's
         snapshot for live admissions, self.prefill_chunk for warmup.
+        ``packed``: the admission's buffer, on the device since its
+        admission; nothing is uploaded here.
         Returns (carry_kv, carry_logits, None) for a non-final chunk and
         (None, None, first_tokens_dev) for the final one."""
         first, final = off == 0, off + C == S
-        shape_key = (P0, S, off, C, tokens.shape[0])
+        shape_key = (P0, S, off, C, packed.shape[0])
         # Promotion-built AOT executables (keyed by the full R-specific
         # shape) dispatch ahead of the per-(P0,S,off,C) jit wrappers.
         prog = self._prefill_chunk_aot.get(shape_key)
         if prog is None:
             prog = self._prefill_chunk_for(P0, S, off, C)
-        with self._phase("build"):
-            tokens = np.ascontiguousarray(tokens)
-        with self._phase("upload"):
-            t = jnp.asarray(tokens)
-            ij = jnp.asarray(ints)
-            tb = jnp.asarray(tables)
-            if final:
-                fl, rg = jnp.asarray(floats), jnp.asarray(rings)
         toks_dev = None
         with self._phase("launch"):
             self._note_launch("prefill_chunk")
             if first:
                 pre = (prefix.k, prefix.v, prefix.state) if P0 else ()
-                kv, logits, self._cache = prog(self._params, *pre, t, ij,
-                                               tb, self._cache)
+                kv, logits, self._cache = prog(self._params, *pre, packed,
+                                               self._cache)
             elif not final:
-                kv, logits, self._cache = prog(self._params, t, ij, kv,
-                                               logits, tb, self._cache)
+                kv, logits, self._cache = prog(self._params, packed, kv,
+                                               logits, self._cache)
             else:
                 (toks_dev, self._cache, self._keys, self._next_dev,
                  self._temps_dev, self._top_ks_dev, self._top_ps_dev,
                  self._ring_dev, self._rps_dev) = prog(
-                    self._params, t, ij, fl, rg, kv, logits, tb,
+                    self._params, packed, kv, logits,
                     self._cache, self._keys, self._next_dev,
                     self._temps_dev, self._top_ks_dev, self._top_ps_dev,
                     self._ring_dev, self._rps_dev)
@@ -5278,13 +5327,8 @@ class BatchScheduler:
                 demoted.append(slot)
                 unused.append(row)
             return demoted, unused
-        tokens = np.zeros((B, S), np.int32)
-        ints = np.zeros((4, B), np.int32)
-        floats = np.zeros((3, B), np.float32)
-        floats[1] = 1.0
-        floats[2] = 1.0
-        rings = np.full((B, _RING), self.config.vocab_size, np.int32)
-        tables = np.zeros((B, self._cache.max_pages_per_row), np.int32)
+        packed, (tokens, ints, floats, rings, tables) = _admit_buffer(
+            B, S, self._cache.max_pages_per_row, self.config.vocab_size)
         live: list[tuple[_Slot, int]] = []
         for slot, row, sess in claimed:
             if not self._wake_install_kv(slot, row, sess, tables):
@@ -5294,7 +5338,7 @@ class BatchScheduler:
             suffix = slot.prompt_ids[sess.length:]
             o = slot.req.options
             tokens[row, : len(suffix)] = suffix
-            ints[:, row] = (len(suffix), sess.length, slot.seed, o.top_k)
+            ints[:4, row] = (len(suffix), sess.length, slot.seed, o.top_k)
             floats[:, row] = (o.temperature, o.top_p, o.repeat_penalty)
             if o.repeat_penalty != 1.0:
                 start_i = max(0, len(slot.prompt_ids) - _RING)
@@ -5315,15 +5359,13 @@ class BatchScheduler:
             for _, row in live)
         self._n_prefill_padded += B * S
         prog = self._wake_for(w, S)
-        with self._phase("upload"):
-            up = [jnp.asarray(a)
-                  for a in (tokens, ints, floats, rings, tables)]
+        packed = self._admit_upload(packed)
         with self._phase("launch"):
             self._note_launch("admit")
             (toks_dev, self._cache, self._keys, self._next_dev,
              self._temps_dev, self._top_ks_dev, self._top_ps_dev,
              self._ring_dev, self._rps_dev) = prog(
-                self._params, *up, self._cache, self._keys, self._next_dev,
+                self._params, packed, self._cache, self._keys, self._next_dev,
                 self._temps_dev, self._top_ks_dev, self._top_ps_dev,
                 self._ring_dev, self._rps_dev)
         self._last_out = toks_dev

@@ -260,8 +260,9 @@ def test_requests_come_before_the_dummy_entries():
                 out_q=None, seed=i)
             s.prompt_ids, s.pages = ids, [3 + i]
             slots.append(s)
-        tokens, ints, _, _, tables = sched._admit_host_arrays(
-            slots, [5, 2], 32, 8, None)
+        tokens, ints, _, _, tables = sched_mod._admit_unpack(
+            sched._admit_host_arrays(slots, [5, 2], 32, 8, None),
+            sched._cache.max_pages_per_row)
         assert ints[1].tolist() == [5, 2] + [sched.num_slots] * 6
         assert ints[0].tolist() == [len(s.prompt_ids) for s in slots] + [1] * 6
         assert tokens[0, :3].tolist() == slots[0].prompt_ids[:3]

@@ -586,6 +586,29 @@ def test_every_scheduler_program_is_named_by_its_kind():
         assert fn.id.startswith(kinds), (call.lineno, fn.id)
 
 
+@pytest.mark.parametrize("fn_name", [
+    "_admit_chunk", "_start_prefill_carry", "_prefill_step",
+    "_dispatch_prefill_chunk", "_admit_wake"])
+def test_no_dispatch_uploads_array_by_array(fn_name):
+    """Source-level: an admission goes up in one packed buffer, through
+    ``_admit_upload`` alone, and a chunk uploads nothing: none of these
+    says ``jnp.asarray`` or ``jax.device_put`` itself. (``_release``
+    still uploads its row: handing it over as a host scalar did not
+    shorten the launch on the chip, PERF.md §6, PR 36.)"""
+    with open(os.path.join(
+            ROOT, "p2p_llm_chat_tpu/serve/scheduler.py")) as f:
+        tree = ast.parse(f.read())
+    fn = next(n for n in ast.walk(tree)
+              if isinstance(n, ast.FunctionDef) and n.name == fn_name)
+    uploads = [(n.lineno, n.func.attr) for n in ast.walk(fn)
+               if isinstance(n, ast.Call)
+               and isinstance(n.func, ast.Attribute)
+               and n.func.attr in ("asarray", "device_put")
+               and isinstance(n.func.value, ast.Name)
+               and n.func.value.id in ("jnp", "jax")]
+    assert not uploads, (fn_name, uploads)
+
+
 # The handles the scheduler keeps its compiled programs under, and the
 # local names it calls them by.
 _PROGRAM_ATTRS = ("_admit_j", "_admit_prefix_j", "_zero_row_j",

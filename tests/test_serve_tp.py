@@ -121,6 +121,34 @@ def test_tp_engine_with_prefix_and_spec():
         eng.stop()
 
 
+def test_tp_chunk_ladder_takes_one_committed_buffer():
+    """A chunk ladder under a mesh: the admission's packed buffer goes
+    up once, committed to every device of the mesh (so no chunk launch
+    places it again), and the output stays oracle-exact."""
+    mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
+    sharded = shard_params(PARAMS, llama.param_axes(CFG), mesh)
+    eng = TPUEngine(sharded, CFG, TOK, num_slots=2, max_seq=256,
+                    mesh=mesh, page_size=16, prefill_chunk=32)
+    sched = eng.scheduler
+    seen = []
+    upload = sched._admit_upload
+    sched._admit_upload = lambda *a, **k: (seen.append(upload(*a, **k))
+                                           or seen[-1])
+    try:
+        p = "a prompt long enough to climb a ladder of chunks: " + "xyz " * 8
+        assert 64 < len(TOK.encode(p, add_bos=True)) < 120
+        req = GenerateRequest(prompt=p, options=GenerateOptions(max_tokens=8))
+        text = "".join(eng.generate_stream(req, RequestStats()))
+        assert text == oracle(p, 8)
+        snap = sched.metrics_snapshot()
+        assert snap["prefill_chunks_total"] == 4
+        assert snap["serve_admit_uploads_total"] == 1 == len(seen)
+        assert seen[0].committed and seen[0].sharding.is_fully_replicated
+        assert seen[0].sharding.device_set == set(mesh.devices.flat)
+    finally:
+        eng.stop()
+
+
 def test_tp_pool_and_fused_weights_are_sharded():
     """TP serving must actually PLACE the paged pool
     and the fused projections across the mesh — correctness alone
