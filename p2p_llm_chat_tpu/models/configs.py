@@ -111,6 +111,27 @@ class ModelConfig:
     # False: attention without rotary embedding (position comes from the
     # recurrent layers).
     attn_rope: bool = True
+    # Further layer kinds of the hybrid walk (models/nemotron_h.py; the
+    # SambaY stack of Phi-4-mini-flash). In ``hybrid_pattern``: ``1`` a
+    # Mamba-1 layer (``Y`` one that also publishes its scan output, the
+    # memory the ``g`` layers above it gate), ``w`` attention over the
+    # last ``sliding_window`` positions (a ring a row in the state pool,
+    # no pages), ``g`` a gated memory unit, ``x`` attention whose K and V
+    # are the ``*`` layer's below it (it owns neither), ``-`` a dense
+    # gated MLP of ``intermediate_size``. A published layer of that
+    # family is two letters, its mixer and ``-``.
+    # ``norm_kind`` "layer": biased LayerNorm in place of RMSNorm.
+    norm_kind: str = "rms"
+    attn_bias: bool = False          # biases on the q/k/v and output proj.
+    # Differential attention: heads paired, two softmaxes a pair, the
+    # second subtracted ``lam`` times, over both value heads of the KV
+    # pair (ops/diff_attention.py).
+    attn_diff: bool = False
+    sliding_window: int = 0          # keys a ``w`` layer's query reads
+    # Mamba-1: channels, state numbers a channel, rank of the time step.
+    mamba1_inner: int = 0
+    mamba1_state: int = 0
+    mamba1_dt_rank: int = 0
     # token ids (llama3 defaults; byte tokenizer overrides)
     bos_token_id: int = 128000
     eos_token_ids: tuple[int, ...] = (128001, 128008, 128009)
@@ -137,7 +158,23 @@ class ModelConfig:
 
     @property
     def ssm_layers(self) -> int:
-        return self.hybrid_pattern.count("M")
+        """Layers with a recurrent state: Mamba-2 (``M``) or Mamba-1."""
+        p = self.hybrid_pattern
+        return p.count("M") + p.count("1") + p.count("Y")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that keep a ring of ``sliding_window`` positions a row."""
+        return self.hybrid_pattern.count("w")
+
+    @property
+    def ssm_state_shape(self) -> tuple:
+        """One row's float32 state in one recurrent layer. Mamba-1's has
+        its channels minor (ops/state_pool.py says why)."""
+        if self.mamba1_inner:
+            return (self.mamba1_state, self.mamba1_inner)
+        return (self.mamba_num_heads, self.mamba_head_dim,
+                self.ssm_state_size)
 
     @property
     def routed_layers(self) -> int:
@@ -162,7 +199,10 @@ class ModelConfig:
 
     @property
     def conv_dim(self) -> int:
-        """Channels the Mamba convolution runs over: x | B | C."""
+        """Channels the Mamba convolution runs over: x | B | C (Mamba-2),
+        x alone (Mamba-1)."""
+        if self.mamba1_inner:
+            return self.mamba1_inner
         return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     # What one token holds in a KV cache (models/llama.KVCache,
@@ -172,19 +212,28 @@ class ModelConfig:
     # in ``k`` and the rotated shared key in ``v``, the latter padded to
     # whole 128-lane tiles (a 64-wide minor dimension makes every pool
     # write relayout the array, and no page DMA can be aligned to it).
+    # Differential attention caches ALL its KV heads side by side as one
+    # row a position (ops/diff_attention.py says why: a query reads its
+    # pair's two value heads together, a head of 64 is half a lane tile,
+    # and a second-minor dimension of 10 or 20 heads is padded to 32 in
+    # an int8 array); an int8 scale is then one a position.
     @property
     def cache_kv_heads(self) -> int:
-        return 1 if self.is_latent else self.num_kv_heads
+        if self.attn_diff or self.is_latent:
+            return 1
+        return self.num_kv_heads
 
     @property
     def cache_k_dim(self) -> int:
+        if self.attn_diff:
+            return self.kv_dim
         return self.kv_lora_rank if self.is_latent else self.head_dim
 
     @property
     def cache_v_dim(self) -> int:
         if self.is_latent:
             return -(-self.qk_rope_head_dim // 128) * 128
-        return self.head_dim
+        return self.cache_k_dim if self.attn_diff else self.head_dim
 
     @property
     def router_width(self) -> int:
@@ -382,6 +431,41 @@ _register(ModelConfig(
     moe_scoring="sigmoid", moe_renormalize=True, routed_scaling_factor=5.0,
     moe_selection_bias=True, mlp_activation="relu2", moe_latent_size=64,
     num_shared_experts=1, shared_intermediate_size=192, attn_rope=False,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# Phi-4-mini-flash-reasoning (microsoft/Phi-4-mini-flash-reasoning
+# config.json, model_type phi4flash), whole: 32 published layers, each a
+# mixer and a gated MLP behind biased LayerNorms. Layers 0-15 alternate
+# Mamba-1 and 512-token window attention, 16 is the Mamba-1 layer whose
+# scan output every gated memory unit above reads, 17 the one full
+# attention layer, the only one that holds pages, and 18-31 alternate
+# gated memory units and cross attention over layer 17's pages.
+# Differential attention, no positional encoding. 3.85 G parameters.
+PHI4FLASH_PATTERN = "1-w-" * 8 + "Y-*-" + "g-x-" * 7
+
+_register(ModelConfig(
+    name="phi-4-mini-flash-reasoning", vocab_size=200064, hidden_size=2560,
+    intermediate_size=10240, num_layers=32, num_heads=40, num_kv_heads=20,
+    head_dim=64, max_seq_len=262144, rms_norm_eps=1e-5,
+    tie_embeddings=True, hybrid_pattern=PHI4FLASH_PATTERN,
+    norm_kind="layer", attn_bias=True, attn_diff=True, attn_rope=False,
+    sliding_window=512, mamba1_inner=5120, mamba1_state=16,
+    mamba1_dt_rank=160, conv_kernel=4,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# The same eight kinds of step at test size: 8 published layers, so that
+# 0-3 are the lower half (two Mamba-1 / window pairs: one scan), 4
+# publishes the memory, 5 is the full layer, 6 a gated memory unit and 7
+# a cross layer; window 8.
+_register(ModelConfig(
+    name="tiny-phi4flash", vocab_size=512, hidden_size=128,
+    intermediate_size=256, num_layers=8, num_heads=8, num_kv_heads=4,
+    head_dim=16, max_seq_len=256, rms_norm_eps=1e-5, tie_embeddings=True,
+    hybrid_pattern="1-w-1-w-Y-*-g-x-", norm_kind="layer", attn_bias=True,
+    attn_diff=True, attn_rope=False, sliding_window=8, mamba1_inner=256,
+    mamba1_state=16, mamba1_dt_rank=8, conv_kernel=4,
     bos_token_id=1, eos_token_ids=(2,),
 ))
 

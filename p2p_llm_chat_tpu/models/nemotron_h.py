@@ -1,5 +1,9 @@
-"""Hybrid Mamba-2 / attention / LatentMoE decoder (the Nemotron-H block
-of NVIDIA-Nemotron-3-Super-120B-A12B). ``models.family_for`` picks this
+"""Hybrid decoders, walked by a pattern of layer kinds: the Nemotron-H
+block of NVIDIA-Nemotron-3-Super-120B-A12B (Mamba-2 / attention /
+LatentMoE) and the SambaY stack of Phi-4-mini-flash-reasoning (Mamba-1 /
+window attention / one full attention layer whose pages the upper half
+reads / gated memory units, each followed by a dense gated MLP).
+``models.family_for`` picks this
 module for a configuration with a ``hybrid_pattern``; the functional
 surface is the other families' (init_params, prefill, prefill_chunk,
 decode_step_paged, decode_fused, their ``_counted`` / ``_touched``
@@ -28,7 +32,31 @@ by the layer's letter in ``hybrid_pattern``:
   experts go through models/pangu._routed_local, the one dispatch for a
   held range of a wider router.
 
-**Two kinds of per-row past.** The ``*`` layers' K and V are pages
+The SambaY kinds (``ModelConfig.norm_kind`` "layer": every norm below
+is a biased LayerNorm; ``attn_diff``: every attention is the
+differential form of ops/diff_attention.py, biased projections, no
+positional encoding):
+
+- ``1``, Mamba-1 (``d = mamba1_inner``, ``N = mamba1_state``): ``[x | z]
+  = u W_in``; ``x <- silu(conv(x) + b)``; ``[dt_r | B | C] = x W_x``;
+  ``dt = softplus(dt_r W_dt + b_dt)``, ``A = -exp(A_log)`` float32; the
+  recurrence of ops/state_pool.ssm1_step over a float32 state [N, d];
+  ``m = y + D x``; ``out = (m silu(z)) W_out``. ``Y`` is the same layer
+  that also PUBLISHES ``m`` (before the ``z`` gate): a value of the step
+  the ``g`` layers above read, not a cache.
+- ``w``, attention over the query's own position and the
+  ``sliding_window - 1`` before it: K and V live in a ring a row in the
+  state pool (ops/state_pool.py), never in pages.
+- ``*`` with ``attn_diff``: causal over the whole context, from pages;
+  the only layer that owns any.
+- ``x``, cross attention: a query projection only; K and V are those of
+  the ``*`` layer below it, read from its pages where they lie.
+- ``g``, gated memory unit: ``out = (silu(u W_in) * m) W_out`` with ``m``
+  the publishing layer's output at the SAME position. No state.
+- ``-``, a dense gated MLP: ``[g | u] = x W_gu``; ``out = (silu(g) u)
+  W_mlp_down``. A published layer of this family is its mixer and ``-``.
+
+**Kinds of per-row past.** The ``*`` layers' K and V are pages
 (ops/paged_kv.py; ``ModelConfig.cache_layers`` of them); the ``M``
 layers' state and convolution window are rows of a
 :class:`~..ops.state_pool.StatePool` that rides in the cache objects'
@@ -39,10 +67,12 @@ slot in the scheduler's ``PagedKVCache``. Prefill programs mask with
 padding has ``dt`` = 0 and stays out of the window, so a padded row's
 state is its unpadded run's.
 
-**The stack** is three stacked parameter trees (``mamba``, ``moe``,
-``attn``) walked by the pattern: runs of equal letter pairs between
-attention layers are one ``lax.scan`` each (:func:`_plan`), so a
-program holds a few layer bodies and not one a layer.
+**The stack** is a stacked parameter tree a kind (``mamba``, ``moe``,
+``attn``; ``mamba1``, ``cross``, ``gmu``, ``mlp``) walked by the
+pattern: runs of equal groups of two or four letters between ``*``
+layers are one ``lax.scan`` each (:func:`_plan`), so a program holds a
+few layer bodies and not one a layer. ``*`` and ``Y`` publish to the
+layers above them and are never inside a scan.
 
 Single chip only; speculation and session parking would need the state
 rolled back or carried and are refused at boot (serve/scheduler.py).
@@ -57,7 +87,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ..ops import state_pool
+from ..ops import diff_attention, state_pool
+from ..ops.diff_attention import FlatKeys, Keys
 from ..ops.state_pool import StatePool
 from ..parallel.sharding import LogicalRules, DEFAULT_RULES
 from ..utils.device import pallas_interpret
@@ -75,13 +106,20 @@ __all__ = ["STATS_WIDTH", "no_stats", "no_touched"]
 
 # -- the pattern --------------------------------------------------------------
 
+# letter -> its parameter tree; letters of one tree are indexed together.
+TREES = {"M": "mamba", "E": "moe", "*": "attn", "w": "attn",
+         "1": "mamba1", "Y": "mamba1", "x": "cross", "g": "gmu", "-": "mlp"}
+
+
 @functools.cache
 def _plan(pattern: str) -> tuple:
-    """The walk of ``pattern``: a tuple of (letters, repeat, first index
-    of each letter's tree at this step). Between attention layers a run
-    of equal two-letter pairs is one step with ``repeat`` > 1 (one scan);
-    everything else is a step of one layer."""
-    at = {"M": 0, "E": 0, "*": 0}
+    """The walk of ``pattern``: a tuple of (letters, repeat, how many of
+    each letter came before this step). Between ``*`` layers a run of
+    equal groups of two letters, else of four, is one step with
+    ``repeat`` > 1 (one scan); everything else is a step of one layer.
+    ``*`` and ``Y`` hand values to the layers above them through the
+    trace and stay outside every scan."""
+    at = dict.fromkeys(TREES, 0)
     steps = []
 
     def emit(letters: str, n: int) -> None:
@@ -91,18 +129,45 @@ def _plan(pattern: str) -> tuple:
 
     i = 0
     while i < len(pattern):
-        pair = pattern[i: i + 2]
-        n = 0
-        if len(pair) == 2 and "*" not in pair and pair[0] != pair[1]:
-            while pattern[i + 2 * n: i + 2 * n + 2] == pair:
-                n += 1
+        for size in (2, 4):
+            group = pattern[i: i + size]
+            n = 0
+            if len(group) == size and not set(group) & {"*", "Y"} \
+                    and len(set(group)) > 1:
+                while pattern[i + size * n: i + size * (n + 1)] == group:
+                    n += 1
+            if n >= 2:
+                break
         if n >= 2:
-            emit(pair, n)
-            i += 2 * n
+            emit(group, n)
+            i += size * n
         else:
             emit(pattern[i], 1)
             i += 1
     return tuple(steps)
+
+
+# letter -> the layers it shares a per-row past with: a Mamba layer's row
+# in the state pool, a window layer's ring, a ``*`` layer's page layer.
+PASTS = {**{ch: ch for ch in TREES}, "Y": "1"}
+
+
+def _index(at: dict, letters: str, j: int, group: dict):
+    """Of the layer at place ``j`` of ``letters``, in round 0 of a step
+    that starts at counts ``at``: its index among the layers ``group``
+    puts it with (TREES: its parameter tree; PASTS: its per-row past),
+    and how many of them a round holds."""
+    mine = group[letters[j]]
+    same = [group[ch] == mine for ch in letters]
+    return (sum(n for ch, n in at.items() if group[ch] == mine)
+            + sum(same[:j]), sum(same))
+
+
+def published_layer(pattern: str, step: int) -> int:
+    """The published layer a step of the walk belongs to: ``-`` steps
+    are the second half of the layer their mixer opened."""
+    return sum(ch != "-" for ch in pattern[:step + 1]) - 1 \
+        if "-" in pattern else step
 
 
 # -- parameters ---------------------------------------------------------------
@@ -115,6 +180,8 @@ def _dims(config: ModelConfig) -> dict:
     F, Fs = config.intermediate_size, (config.shared_intermediate_size
                                        or config.intermediate_size)
     NE = config.num_experts
+    d1, N1, R1 = (config.mamba1_inner, config.mamba1_state,
+                  config.mamba1_dt_rank)
     return {
         "mamba": {"w_in": (H, d + config.conv_dim + nh), "w_out": (d, H)},
         "attn": {"wqkv": (H, config.q_dim + 2 * config.kv_dim),
@@ -122,6 +189,11 @@ def _dims(config: ModelConfig) -> dict:
         "moe": {"w_fc1": (H, Lw), "w_fc2": (Lw, H),
                 "w_up_s": (H, Fs), "w_down_s": (Fs, H),
                 "w_up_e": (NE, Lw, F), "w_down": (NE, F, Lw)},
+        "mamba1": {"w_in": (H, 2 * d1), "w_x": (d1, R1 + 2 * N1),
+                   "w_dt": (R1, d1), "w_out": (d1, H)},
+        "cross": {"wq": (H, config.q_dim), "wo": (config.q_dim, H)},
+        "gmu": {"w_in": (H, d1), "w_out": (d1, H)},
+        "mlp": {"w_gu": (H, 2 * F), "w_mlp_down": (F, H)},
     }
 
 
@@ -138,9 +210,11 @@ def _init_scale(name: str, shape: tuple, config: ModelConfig) -> float:
 
 
 def _counts(config: ModelConfig) -> dict:
-    p = config.hybrid_pattern
-    return {"mamba": p.count("M"), "moe": p.count("E"),
-            "attn": p.count("*")}
+    """Layers of each parameter tree the pattern uses."""
+    n: dict = {}
+    for ch in config.hybrid_pattern:
+        n[TREES[ch]] = n.get(TREES[ch], 0) + 1
+    return n
 
 
 def _uniform(k, shape, lo, hi, dtype=jnp.float32):
@@ -153,55 +227,137 @@ def _small_leaves(config: ModelConfig, key: jax.Array, dtype) -> dict:
     selection bias away from their neutral values, so that a model
     without one of them cannot pass a comparison (pangu._norm_leaves).
     ``dt_bias`` and ``A_log`` span what the published initialiser does:
-    time steps of 0.001 to 0.1 and decays ``A`` of 1 to 16."""
+    time steps of 0.001 to 0.1 and decays ``A`` of 1 to 16. Likewise the
+    SambaY kinds' LayerNorm biases, attention biases, sub-norms and
+    lambda vectors (wide enough that ``lam - lam0`` is a tenth or more
+    in most layers)."""
     n = _counts(config)
     H, nh = config.hidden_size, config.mamba_num_heads
+    # The first three trees draw from the stream they always did (their
+    # weights, and the reference limits read on them, stay what they
+    # were); the later kinds from one of their own.
     ks = iter(jax.random.split(key, 16))
-    Lm, Le, La = n["mamba"], n["moe"], n["attn"]
-    dt = jnp.exp(_uniform(next(ks), (Lm, nh), jnp.log(1e-3), jnp.log(1e-1)))
-    return {
-        "mamba": {
-            "norm": _uniform(next(ks), (Lm, H), 0.5, 1.5, dtype),
+    layer_norm = config.norm_kind == "layer"
+
+    def norm(L: int, name: str = "norm") -> dict:
+        out = {name: _uniform(next(ks), (L, H), 0.5, 1.5, dtype)}
+        if layer_norm:
+            out[name + "_b"] = _normal(next(ks), (L, H), 0.2, dtype)
+        return out
+
+    def dt_bias(shape):
+        dt = jnp.exp(_uniform(next(ks), shape, jnp.log(1e-3),
+                              jnp.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))    # softplus(dt_bias) = dt
+
+    def diff_leaves(L: int, steps: list) -> dict:
+        """Biases, lambda vectors and sub-norm of ``L`` attention layers
+        at the walk's ``steps``."""
+        D = config.head_dim
+        wide = (0.3 / D ** 0.5) ** 0.5
+        out = {"bo": _normal(next(ks), (L, H), 0.2, dtype),
+               "sub_w": _uniform(next(ks), (L, 2 * D), 0.5, 1.5, dtype),
+               "lam0": jnp.asarray(
+                   [diff_attention.lam0_of(published_layer(
+                       config.hybrid_pattern, j)) for j in steps],
+                   jnp.float32)}
+        for name in ("lq1", "lk1", "lq2", "lk2"):
+            out[name] = _normal(next(ks), (L, D), wide, jnp.float32)
+        return out
+
+    small: dict = {}
+    if "mamba" in n:
+        Lm = n["mamba"]
+        dtb = dt_bias((Lm, nh))
+        small["mamba"] = {
+            **norm(Lm),
             "conv_w": _normal(next(ks), (Lm, config.conv_kernel,
                                          config.conv_dim), 0.5, dtype),
             "conv_b": _normal(next(ks), (Lm, config.conv_dim), 0.5, dtype),
-            # softplus(dt_bias) = dt
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "dt_bias": dtb,
             "A_log": jnp.log(_uniform(next(ks), (Lm, nh), 1.0, 16.0)),
             "D": _uniform(next(ks), (Lm, nh), 0.5, 1.5),
             "gnorm": _uniform(next(ks), (Lm, config.mamba_inner), 0.5, 1.5,
                               dtype),
-        },
-        "attn": {"norm": _uniform(next(ks), (La, H), 0.5, 1.5, dtype)},
-        "moe": {
-            "norm": _uniform(next(ks), (Le, H), 0.5, 1.5, dtype),
+        }
+    if "attn" in n:
+        La = n["attn"]
+        small["attn"] = norm(La)
+    if "moe" in n:
+        Le = n["moe"]
+        small["moe"] = {
+            **norm(Le),
             # float32, as the published router is.
             "router": _normal(next(ks), (Le, H, config.router_width),
                               H ** -0.5, jnp.float32),
             "router_bias": _uniform(next(ks), (Le, config.router_width),
                                     -0.2, 0.2),
-        },
-    }
+        }
+    ks = iter(jax.random.split(jax.random.fold_in(key, 1), 64))
+    if "attn" in n and config.attn_diff:
+        steps = [j for j, ch in enumerate(config.hybrid_pattern)
+                 if TREES[ch] == "attn"]
+        small["attn"].update(
+            diff_leaves(n["attn"], steps),
+            bqkv=_normal(next(ks), (n["attn"], config.q_dim
+                                    + 2 * config.kv_dim), 0.2, dtype))
+    if "mamba1" in n:
+        L1, d1, N1 = n["mamba1"], config.mamba1_inner, config.mamba1_state
+        small["mamba1"] = {
+            **norm(L1),
+            "conv_w": _normal(next(ks), (L1, config.conv_kernel, d1), 0.5,
+                              dtype),
+            "conv_b": _normal(next(ks), (L1, d1), 0.5, dtype),
+            "dt_bias": dt_bias((L1, d1)),
+            # [N, d]: the state's layout (ops/state_pool.py).
+            "A_log": jnp.log(_uniform(next(ks), (L1, N1, d1), 1.0, 16.0)),
+            "D": _uniform(next(ks), (L1, d1), 0.5, 1.5),
+        }
+    if "cross" in n:
+        Lx = n["cross"]
+        steps = [j for j, ch in enumerate(config.hybrid_pattern)
+                 if ch == "x"]
+        small["cross"] = {**norm(Lx), **diff_leaves(Lx, steps),
+                          "bq": _normal(next(ks), (Lx, config.q_dim), 0.2,
+                                        dtype)}
+    for tree in ("gmu", "mlp"):
+        if tree in n:
+            small[tree] = norm(n[tree])
+    return small
 
 
-def _build(config: ModelConfig, key: jax.Array, dtype, stack, head) -> dict:
+def _build(config: ModelConfig, key: jax.Array, dtype, stack, head,
+           tied) -> dict:
     """The parameter tree both initialisers return (pangu._build)."""
-    if not config.moe_selection_bias or config.mlp_activation != "relu2" \
-            or not config.moe_latent_size:
+    n = _counts(config)
+    if "moe" in n and (not config.moe_selection_bias
+                       or config.mlp_activation != "relu2"
+                       or not config.moe_latent_size):
         raise ValueError(f"{config.name}: the hybrid family's routed layer "
                          "is a LatentMoE (selection bias, relu2 experts in "
                          "a latent)")
-    n = _counts(config)
+    if {"cross", "attn"} <= set(n) and not config.attn_diff:
+        raise ValueError(f"{config.name}: cross layers read a "
+                         "differential-attention layer's pages")
     H = config.hidden_size
     k_embed, k_head, k_small, k_stack = jax.random.split(key, 4)
     small = _small_leaves(config, k_small, dtype)
     dims = _dims(config)
     params = {"embed": _normal(k_embed, (config.vocab_size, H), 1.0, dtype),
-              "final_norm": jnp.ones((H,), dtype),
-              "lm_head": head(k_head, (H, config.vocab_size))}
-    for i, tree in enumerate(("mamba", "attn", "moe")):
-        params[tree] = {**stack(jax.random.fold_in(k_stack, i), n[tree],
-                                dims[tree]), **small[tree]}
+              "final_norm": jnp.ones((H,), dtype)}
+    if config.norm_kind == "layer":
+        k_fn, k_fb = jax.random.split(jax.random.fold_in(k_small, 1))
+        params.update(final_norm=_uniform(k_fn, (H,), 0.5, 1.5, dtype),
+                      final_norm_b=_normal(k_fb, (H,), 0.2, dtype))
+    # A tied head is the embedding transposed: a copy in the layout (and,
+    # in an int8 tree, the precision) the head's matmul reads.
+    params["lm_head"] = (tied(params["embed"]) if config.tie_embeddings
+                         else head(k_head, (H, config.vocab_size)))
+    for i, tree in enumerate(("mamba", "attn", "moe", "mamba1", "cross",
+                              "gmu", "mlp")):
+        if tree in n:
+            params[tree] = {**stack(jax.random.fold_in(k_stack, i), n[tree],
+                                    dims[tree]), **small[tree]}
     return params
 
 
@@ -214,7 +370,7 @@ def init_params(config: ModelConfig, key: jax.Array,
                 for i, (name, shape) in enumerate(dims.items())}
 
     return _build(config, key, dtype, stack, lambda k, shape: _normal(
-        k, shape, shape[0] ** -0.5, dtype))
+        k, shape, shape[0] ** -0.5, dtype), lambda embed: embed.T)
 
 
 def init_params_quantized(config: ModelConfig, key: jax.Array,
@@ -232,7 +388,8 @@ def init_params_quantized(config: ModelConfig, key: jax.Array,
         return quantize(_normal(k, shape, _init_scale(name, shape, config),
                                 dtype))
 
-    return _build(config, key, dtype, streamed_stack(leaf, quant), leaf)
+    return _build(config, key, dtype, streamed_stack(leaf, quant), leaf,
+                  jax.jit(lambda embed: quantize(embed.T)))
 
 
 def fuse_params(params: dict, tp: int = 1, mesh: Optional[Mesh] = None,
@@ -252,6 +409,18 @@ def param_axes(config: ModelConfig) -> dict:
 
 
 # -- the mixers ---------------------------------------------------------------
+
+def _norm(h, lp: dict, config: ModelConfig, name: str = "norm"):
+    """The pre-norm of a layer: RMSNorm, or a biased LayerNorm."""
+    if config.norm_kind != "layer":
+        return rms_norm(h, lp[name], config.rms_norm_eps)
+    x = h.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                          + config.rms_norm_eps)
+    return (x * lp[name].astype(jnp.float32)
+            + lp[name + "_b"].astype(jnp.float32)).astype(h.dtype)
+
 
 def _mamba_split(config: ModelConfig, lp: dict):
     """``split(conv_out, dt_raw) -> (x, dt, A, Bm, Cm)``: what
@@ -369,6 +538,195 @@ def _attn_decode(h, lp, config: ModelConfig, cache, layer: int, pages: int):
     return mm(attn.reshape(B, 1, config.q_dim), lp["wo"]), k[:, 0], v[:, 0]
 
 
+# -- the SambaY kinds ---------------------------------------------------------
+
+def _mamba1_in(h, lp, config: ModelConfig):
+    d = config.mamba1_inner
+    xz = mm(_norm(h, lp, config), lp["w_in"])
+    return xz[..., :d], xz[..., d:]
+
+
+def _mamba1_split(config: ModelConfig, lp: dict, dtype):
+    """``split(conv_out) -> (x, dt, A, Bm, Cm)`` in ssm1_step's shapes:
+    the time step and B, C are projections of the CONVOLVED channels."""
+    R, N = config.mamba1_dt_rank, config.mamba1_state
+    A = -jnp.exp(lp["A_log"].astype(jnp.float32))            # [N, d]
+
+    def split(conv_out):
+        x = jax.nn.silu(conv_out).astype(dtype)
+        dbc = mm(x, lp["w_x"])
+        dt = jax.nn.softplus(mm(dbc[..., :R], lp["w_dt"]).astype(jnp.float32)
+                             + lp["dt_bias"].astype(jnp.float32))
+        return x, dt, A, dbc[..., R: R + N], dbc[..., R + N:]
+
+    return split
+
+
+def _mamba1_out(y, x, z, lp, dtype):
+    """(out, m): ``m = y + D x`` is what a publishing layer hands up,
+    before the ``z`` gate."""
+    m = y + lp["D"].astype(jnp.float32) * x.astype(jnp.float32)
+    out = mm((m * jax.nn.silu(z.astype(jnp.float32))).astype(dtype),
+             lp["w_out"])
+    return out, m.astype(dtype)
+
+
+def _mamba1_prefill(h, lp, config: ModelConfig, state: StatePool, layer,
+                    valid: jax.Array):
+    """h [B,S,H] behind the carried state of Mamba-1 layer ``layer``.
+    Returns (out [B,S,H], m [B,S,d], state)."""
+    x, z = _mamba1_in(h, lp, config)
+    S_in = jax.lax.dynamic_index_in_dim(state.ssm, layer, 0, False)
+    win = jax.lax.dynamic_index_in_dim(state.conv, layer, 0, False)
+    conv_out, win = state_pool.conv_scan(x, win, jnp.sum(valid, axis=1),
+                                         lp["conv_w"], lp["conv_b"])
+    x, dt, A, Bm, Cm = _mamba1_split(config, lp, h.dtype)(conv_out)
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    y, S_out = state_pool.ssm1_scan(x, dt, A, Bm, Cm, S_in)
+    state = state._replace(
+        ssm=jax.lax.dynamic_update_index_in_dim(state.ssm, S_out, layer, 0),
+        conv=jax.lax.dynamic_update_index_in_dim(
+            state.conv, win.astype(state.conv.dtype), layer, 0))
+    return (*_mamba1_out(y, x, z, lp, h.dtype), state)
+
+
+def _mamba1_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
+                   live: jax.Array):
+    """h [B,1,H]: one step over the pool's first B rows. Returns (out
+    [B,1,H], m [B,1,d], pool)."""
+    x, z = _mamba1_in(h[:, 0], lp, config)
+    y, x, pool = state_pool.decode_update(
+        pool, layer, live, x, lp["conv_w"], lp["conv_b"],
+        _mamba1_split(config, lp, h.dtype), step=state_pool.ssm1_step)
+    out, m = _mamba1_out(y, x, z, lp, h.dtype)
+    return out[:, None], m[:, None], pool
+
+
+def _diff_qkv(h, lp, config: ModelConfig):
+    B, S, _ = h.shape
+    qkv = mm(_norm(h, lp, config), lp["wqkv"]) + lp["bqkv"]
+    Q, KV = config.q_dim, config.kv_dim
+    return (qkv[..., :Q].reshape(B, S, config.num_heads, config.head_dim),
+            qkv[..., Q: Q + KV].reshape(B, S, config.num_kv_heads,
+                                        config.head_dim),
+            qkv[..., Q + KV:].reshape(B, S, config.num_kv_heads,
+                                      config.head_dim))
+
+
+def _diff_q(h, lp, config: ModelConfig):
+    B, S, _ = h.shape
+    q = mm(_norm(h, lp, config), lp["wq"]) + lp["bq"]
+    return q.reshape(B, S, config.num_heads, config.head_dim)
+
+
+def _diff_out(q, parts: list, lp, config: ModelConfig):
+    """The differential form over ``parts`` and the output projection:
+    a chunk's queries [B,S,Hq,D] over :class:`Keys`, or one decode query
+    a row [B,1,Hq,D] over :class:`FlatKeys`, the caches' own layout."""
+    lam0 = lp["lam0"]
+    lam = diff_attention.lam_of(lp, lam0)
+    if isinstance(parts[0], FlatKeys):
+        o = diff_attention.attend_decode(q[:, 0], parts, lam, lam0,
+                                         lp["sub_w"],
+                                         config.rms_norm_eps)[:, None]
+    else:
+        o = diff_attention.attend(q, parts, lam, lam0, lp["sub_w"],
+                                  config.rms_norm_eps)
+    return mm(o, lp["wo"]) + lp["bo"]
+
+
+def _rows(x, config: ModelConfig):
+    """[..., Hkv, D] -> [..., 1, Hkv x D]: the caches' geometry, every
+    KV head of a position side by side."""
+    return x.reshape(*x.shape[:-2], config.cache_kv_heads,
+                     config.cache_k_dim)
+
+
+def _heads(x, config: ModelConfig):
+    """[..., 1, Hkv x D] -> [..., Hkv, D]."""
+    return x.reshape(*x.shape[:-2], config.num_kv_heads, config.head_dim)
+
+
+def _window_prefill(h, lp, config: ModelConfig, state: StatePool, layer,
+                    offset: int, valid: jax.Array):
+    """A chunk through window layer ``layer`` (its index among the
+    window layers): the queries read the carried ring and the chunk's
+    own keys, and the ring comes back holding each row's last real
+    positions. Returns (out [B,S,H], state)."""
+    S = h.shape[1]
+    q, k, v = _diff_qkv(h, lp, config)
+    rk = jax.lax.dynamic_index_in_dim(state.win_k, layer, 0, False)
+    rv = jax.lax.dynamic_index_in_dim(state.win_v, layer, 0, False)
+    in_ring, in_chunk = state_pool.ring_chunk_masks(
+        S, config.sliding_window, offset)
+    parts = [Keys(k, v, in_chunk[None])]
+    if offset:      # a fresh prompt's ring is empty
+        parts.insert(0, Keys(_heads(jnp.swapaxes(rk, 1, 2), config),
+                             _heads(jnp.swapaxes(rv, 1, 2), config),
+                             in_ring[None]))
+    out = _diff_out(q, parts, lp, config)
+    lengths = jnp.sum(valid, axis=1)
+    state = state._replace(
+        win_k=jax.lax.dynamic_update_index_in_dim(
+            state.win_k, state_pool.ring_after_chunk(
+                rk, _rows(k, config), offset, lengths), layer, 0),
+        win_v=jax.lax.dynamic_update_index_in_dim(
+            state.win_v, state_pool.ring_after_chunk(
+                rv, _rows(v, config), offset, lengths), layer, 0))
+    return out, state
+
+
+def _window_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
+                   live: jax.Array, lengths: jax.Array):
+    """One step of window layer ``layer``: the token's K and V go into
+    slot ``lengths mod W`` of each live row's ring, then the query reads
+    the ring's first ``min(lengths + 1, W)`` slots."""
+    B, W = h.shape[0], config.sliding_window
+    q, k, v = _diff_qkv(h, lp, config)
+    pool = state_pool.ring_decode_write(
+        pool, layer, live, lengths, _rows(k[:, 0], config),
+        _rows(v[:, 0], config))
+    ring = [a if a is None else a[:, 0]
+            for a in state_pool.ring_read(pool, layer, B)]
+    mask = jnp.arange(W)[None, :] <= lengths[:, None]
+    return _diff_out(q, [FlatKeys(*ring[:2], mask, *ring[2:])], lp,
+                     config), pool
+
+
+def _diff_attn_prefill(q, lp, config: ModelConfig, ck, cv, layer: int,
+                       offset: int):
+    """Queries at positions offset.. against cache layer ``layer`` of
+    the dense carry, causal over its whole width."""
+    mask = causal_mask(q.shape[1], ck.shape[2], offset)[0]
+    return _diff_out(q, [Keys(_heads(ck[layer], config),
+                              _heads(cv[layer], config), mask)], lp, config)
+
+
+def _pool_keys(cache, layer: int, pages: int, k_cur, v_cur) -> list:
+    """What a decode query of the ``*`` layer, or of a cross layer above
+    it, reads: the layer's window of the page pool, gathered ONCE a step
+    for all of them, and this step's own K and V (not in the pool
+    yet)."""
+    from ..ops.paged_attention import gather_window
+    k, v, ks, vs = gather_window(cache, layer, pages=pages)
+    mask = jnp.arange(k.shape[1])[None, :] < cache.lengths[:, None]
+    if ks is not None:
+        ks, vs = ks[:, 0], vs[:, 0]
+    return [FlatKeys(k[:, :, 0], v[:, :, 0], mask, ks, vs),
+            FlatKeys(k_cur, v_cur, jnp.ones((1, 1), bool))]
+
+
+def _gmu(h, lp, config: ModelConfig, m):
+    g = jax.nn.silu(mm(_norm(h, lp, config), lp["w_in"]))
+    return mm(g * m, lp["w_out"])
+
+
+def _mlp(h, lp, config: ModelConfig):
+    gu = mm(_norm(h, lp, config), lp["w_gu"])
+    F = config.intermediate_size
+    return mm(jax.nn.silu(gu[..., :F]) * gu[..., F:], lp["w_mlp_down"])
+
+
 def _relu2_mlp(x, w_up, w_down):
     return mm(jnp.square(jax.nn.relu(mm(x, w_up))), w_down)
 
@@ -384,39 +742,54 @@ def _moe(h, lp, config: ModelConfig, counted, live):
 
 # -- the stack ----------------------------------------------------------------
 
-def _run_stack(params: dict, config: ModelConfig, h: jax.Array, mamba,
-               attn, counted, live, carry):
-    """Walk the pattern. ``mamba(h, lp, layer, carry) -> (out, carry)``
-    with ``layer`` the Mamba layer's index in its tree (a tracer inside a
-    scan); ``attn(h, lp, layer, carry) -> (out, carry)`` with ``layer``
-    the attention layer's index, a Python int (attention layers are
-    never scanned). Returns (h, carry, stats)."""
-    def run_mamba(h, idx, carry, stats):
-        out, carry = mamba(h, _layer_view(params["mamba"], idx), idx, carry)
-        return h + out, carry, stats
-
-    def run_moe(h, idx, carry, stats):
-        out, st = _moe(h, _layer_view(params["moe"], idx), config, counted,
-                       live)
+def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
+               counted, live, carry):
+    """Walk the pattern. ``ops[letter](h, lp, k, carry) -> (out, carry)``
+    is the mode's (prefill's, decode's) mixer of that kind, ``lp`` the
+    layer's view of its tree and ``k`` its index among the layers that
+    share its per-row past: a Mamba layer's in the state pool, a window
+    layer's among the rings, a ``*`` layer's among the page layers (a
+    tracer inside a scan; ``*`` and ``Y`` are never scanned and get a
+    Python int). ``E`` and ``-`` keep nothing and are run here. Returns
+    (h, carry, stats)."""
+    def moe_step(h, lp, k, carry, stats):
+        out, st = _moe(h, lp, config, counted, live)
         return h + out, carry, stats + st
 
-    mixers = {"M": run_mamba, "E": run_moe}
+    def mlp_step(h, lp, k, carry, stats):
+        return h + _mlp(h, lp, config), carry, stats
+
+    def kept(op):
+        def step(h, lp, k, carry, stats):
+            out, carry = op(h, lp, k, carry)
+            return h + out, carry, stats
+        return step
+
+    steps = {"E": moe_step, "-": mlp_step,
+             **{ch: kept(op) for ch, op in ops.items()}}
+
+    def run(ch, h, idx, k, carry, stats):
+        return steps[ch](h, _layer_view(params[TREES[ch]], idx), k, carry,
+                         stats)
 
     stats = no_stats()
     for letters, n, at in _plan(config.hybrid_pattern):
-        if letters == "*":
-            lp = _layer_view(params["attn"], jnp.asarray(at["*"], jnp.int32))
-            out, carry = attn(h, lp, at["*"], carry)
-            h = h + out
-        elif n == 1:
-            h, carry, stats = mixers[letters](
-                h, jnp.asarray(at[letters], jnp.int32), carry, stats)
+        if n == 1:
+            idx, _ = _index(at, letters, 0, TREES)
+            k, _ = _index(at, letters, 0, PASTS)
+            if letters not in "*Y":
+                k = jnp.asarray(k, jnp.int32)
+            h, carry, stats = run(letters, h, jnp.asarray(idx, jnp.int32),
+                                  k, carry, stats)
         else:
             def body(state, i, letters=letters, at=at):
                 h, carry, stats = state
-                for ch in letters:
-                    h, carry, stats = mixers[ch](h, i + at[ch], carry,
-                                                 stats)
+                for j, ch in enumerate(letters):
+                    (t0, tn), (k0, kn) = (_index(at, letters, j, TREES),
+                                          _index(at, letters, j, PASTS))
+                    h, carry, stats = run(
+                        ch, h, i + t0 if tn == 1 else i * tn + t0,
+                        i + k0 if kn == 1 else i * kn + k0, carry, stats)
                 return (h, carry, stats), None
 
             (h, carry, stats), _ = jax.lax.scan(
@@ -428,7 +801,7 @@ def _logits(params, config, h, last_idx):
     if last_idx is not None:
         h = jnp.take_along_axis(h, last_idx[:, None, None].astype(jnp.int32),
                                 axis=1)
-    h = rms_norm(h, params["final_norm"], config.rms_norm_eps)
+    h = _norm(h, params, config, "final_norm")
     return mm(h, params["lm_head"]).astype(jnp.float32)
 
 
@@ -450,24 +823,59 @@ def _forward(params: dict, config: ModelConfig, tokens: jax.Array,
     if valid is None:
         valid = jnp.ones((B, S), bool)
     h = params["embed"][tokens]
+    # What ``Y`` and ``*`` hand to the layers above them in this step.
+    published: dict = {}
 
     def mamba(h, lp, layer, carry):
         ck, cv, state = carry
         out, state = _mamba_prefill(h, lp, config, state, layer, valid)
         return out, (ck, cv, state)
 
-    def attn(h, lp, layer, carry):
+    def mamba1(h, lp, layer, carry, publish=False):
         ck, cv, state = carry
-        out, ck, cv = _attn_prefill(h, lp, config, ck, cv, layer, offset)
+        out, m, state = _mamba1_prefill(h, lp, config, state, layer, valid)
+        if publish:
+            published["m"] = m
         return out, (ck, cv, state)
 
+    def window(h, lp, layer, carry):
+        ck, cv, state = carry
+        out, state = _window_prefill(h, lp, config, state, layer, offset,
+                                     valid)
+        return out, (ck, cv, state)
+
+    def attn(h, lp, layer, carry):
+        ck, cv, state = carry
+        if not config.attn_diff:
+            out, ck, cv = _attn_prefill(h, lp, config, ck, cv, layer, offset)
+            return out, (ck, cv, state)
+        q, k, v = _diff_qkv(h, lp, config)
+        zero = jnp.zeros((), jnp.int32)
+        at = (jnp.asarray(layer, jnp.int32), zero,
+              jnp.asarray(offset, jnp.int32), zero, zero)
+        ck = jax.lax.dynamic_update_slice(
+            ck, _rows(k, config)[None].astype(ck.dtype), at)
+        cv = jax.lax.dynamic_update_slice(
+            cv, _rows(v, config)[None].astype(cv.dtype), at)
+        published["kv_layer"] = layer
+        return _diff_attn_prefill(q, lp, config, ck, cv, layer,
+                                  offset), (ck, cv, state)
+
+    def cross(h, lp, _, carry):
+        ck, cv, state = carry
+        return _diff_attn_prefill(_diff_q(h, lp, config), lp, config, ck, cv,
+                                  published["kv_layer"], offset), carry
+
+    ops = {"M": mamba, "1": mamba1, "w": window, "*": attn, "x": cross,
+           "Y": functools.partial(mamba1, publish=True),
+           "g": lambda h, lp, _, carry: (_gmu(h, lp, config,
+                                              published["m"]), carry)}
     h, (ck, cv, state), stats = _run_stack(
-        params, config, h, mamba, attn, valid, None,
+        params, config, h, ops, valid, None,
         (cache.k, cache.v, cache.state))
     cache = KVCache(ck, cv, cache.lengths, state)
     if hidden:
-        return rms_norm(h, params["final_norm"], config.rms_norm_eps), \
-            cache, stats
+        return _norm(h, params, config, "final_norm"), cache, stats
     return _logits(params, config, h, last_idx), cache, stats
 
 
@@ -587,18 +995,47 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
     h = params["embed"][tokens]
     live = jnp.ones((B,), bool) if active is None else active
 
+    published: dict = {}
+
     def mamba(h, lp, layer, carry):
         pool, kv = carry
         out, pool = _mamba_decode(h, lp, config, pool, layer, live)
         return out, (pool, kv)
 
+    def mamba1(h, lp, layer, carry, publish=False):
+        pool, kv = carry
+        out, m, pool = _mamba1_decode(h, lp, config, pool, layer, live)
+        if publish:
+            published["m"] = m
+        return out, (pool, kv)
+
+    def window(h, lp, layer, carry):
+        pool, kv = carry
+        out, pool = _window_decode(h, lp, config, pool, layer, live,
+                                   cache.lengths)
+        return out, (pool, kv)
+
     def attn(h, lp, layer, carry):
         pool, kv = carry
-        out, k, v = _attn_decode(h, lp, config, cache, layer, pages)
-        return out, (pool, kv + ((k, v),))
+        if not config.attn_diff:
+            out, k, v = _attn_decode(h, lp, config, cache, layer, pages)
+            return out, (pool, kv + ((k, v),))
+        q, k, v = _diff_qkv(h, lp, config)
+        k, v = _rows(k[:, 0], config), _rows(v[:, 0], config)
+        published["keys"] = _pool_keys(cache, layer, pages, k, v)
+        return (_diff_out(q, published["keys"], lp, config),
+                (pool, kv + ((k, v),)))
 
-    h, (pool, kv), stats = _run_stack(params, config, h, mamba, attn, None,
-                                      live, (cache.state, ()))
+    def cross(h, lp, _, carry):
+        return _diff_out(_diff_q(h, lp, config), published["keys"], lp,
+                         config), carry
+
+    ops = {"M": mamba, "1": mamba1, "w": window, "*": attn, "x": cross,
+           "Y": functools.partial(mamba1, publish=True),
+           "g": lambda h, lp, _, carry: (_gmu(h, lp, config,
+                                              published["m"]), carry)}
+    h, (pool, kv), stats = _run_stack(params, config, h, ops, None, live,
+                                      (cache.state, ()))
     k_all = jnp.stack([k for k, _ in kv])
     v_all = jnp.stack([v for _, v in kv])
     cache = write_decode_burst(cache._replace(state=pool), k_all, v_all,

@@ -555,6 +555,29 @@ def _gather_window_scores(q4, k_pages, v_pages, k_scale, v_scale,
     return jnp.where(mask, scores, NEG_INF), v, sv
 
 
+def gather_window(cache, layer, *, pages: int) -> tuple:
+    """One layer's window of every row, gathered once for whoever reads
+    it (a layer whose pages other layers read too): (k [B,W,Hkv,D], v, k
+    scales [B,Hkv,W] | None, v scales), W = ``pages`` x page_size. The
+    gather of :func:`_gather_window_scores`, without the scores."""
+    L, N, ps, Hkv, D = cache.k.shape
+    B = cache.page_table.shape[0]
+    W = pages * ps
+    pt = layer * N + cache.page_table[:, :pages].astype(jnp.int32)
+    k = cache.k.reshape(L * N, ps, Hkv, D)[pt].reshape(B, W, Hkv, D)
+    v = cache.v.reshape(L * N, ps, Hkv, cache.v.shape[-1])[pt].reshape(
+        B, W, Hkv, cache.v.shape[-1])
+    if cache.k_scale is None:
+        return k, v, None, None
+    ps_pad = cache.k_scale.shape[-1]
+
+    def scales(a):
+        return a.reshape(L * N, Hkv, ps_pad)[pt][..., :ps].transpose(
+            0, 2, 1, 3).reshape(B, Hkv, W)
+
+    return k, v, scales(cache.k_scale), scales(cache.v_scale)
+
+
 def _paged_attention_gather_quant(q, k_pages, v_pages, k_scale, v_scale,
                                   page_table, lengths, layer, *, pages: int):
     """Gather-path decode attention over an int8 pool

@@ -154,7 +154,8 @@ class PagedKVCache(NamedTuple):
         if config.ssm_layers:
             from .state_pool import StatePool
             cache = cache._replace(
-                state=StatePool.create(config, batch + 1, dtype))
+                state=StatePool.create(config, batch + 1, dtype,
+                                       quantized=quantized))
         if mesh is not None:
             cache = shard_cache(cache, mesh)
         return cache

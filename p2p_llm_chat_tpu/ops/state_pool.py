@@ -1,5 +1,7 @@
-"""Recurrent state beside the page pool: what a Mamba-2 layer keeps for
-each row, whatever the row's length, and the programs over it.
+"""Recurrent state beside the page pool: what a Mamba layer keeps for
+each row, whatever the row's length, the ring a window-attention layer
+keeps of its last ``sliding_window`` positions, and the programs over
+them.
 
 A row of a layer is a float32 state ``ssm`` [heads, head_dim, state_size]
 and the last ``conv_kernel - 1`` inputs of the layer's causal depthwise
@@ -29,7 +31,41 @@ Every operation on the scheduler's pool is in place on a donated buffer
 - :func:`snapshot` / :func:`from_snapshot`: a prefix entry's state out
   of a one-row carry, and into every row of a fresh one.
 
-The recurrence (``A`` < 0 a head, ``dt`` > 0 a head and token, ``B`` and
+**Mamba-1** (``ModelConfig.mamba1_inner`` channels ``d``, ``N`` state
+numbers a channel) keeps ``ssm`` [L_m, rows, N, d]: the channels are the
+minor dimension, because a minor dimension of 16 would be padded to a
+128-lane tile and the pool would move eight times its bytes. Its decay
+is one number a channel AND state number a token, so nothing of
+:func:`ssd_scan` applies; :func:`ssm1_scan` carries the state through
+the positions one at a time and :func:`ssm1_step` is one of them:
+
+    S_t = exp(dt_t (outer) A) * S_{t-1} + (dt_t x_t) (outer) B_t
+    y_t = S_t C_t
+
+**Window rings** (``ModelConfig.window_layers`` > 0). A window layer
+reads its query's own position and the ``W - 1`` before it, so a row
+keeps ``W`` positions of K and V a layer, whatever its length: position
+``p`` lives in slot ``p mod W`` (no positional encoding is applied to
+the keys, and a softmax does not care in which order it sums):
+
+    win_k, win_v: [L_w, rows, G, W, C]
+    win_ks, win_vs: [L_w, rows, G, W]   float32, int8 pools only
+
+with ``G x C`` the cache's geometry (``ModelConfig.cache_kv_heads`` rows
+of ``cache_k_dim`` numbers a position: for differential attention a PAIR
+of KV heads side by side, 128 numbers at a head of 64). The head comes
+before the position so that a row's ``[W, C]`` block of one head is what
+a dot contracts, as it lies: the positions on sublanes, ``C`` on lanes
+(with the position before the head the compiler transposed the whole
+ring every step). In the scheduler's pool they are int8 with a scale a
+position a head when the page pool is (ops/paged_kv.quant_kv); in a
+prefill's carry they are the activations' dtype and :func:`write_rows`
+quantises them.
+:func:`ring_decode_write` lands a step's K and V in each live row's
+slot (the others write the garbage row); :func:`ring_after_chunk` is the
+ring a chunk leaves behind; the masks say which slots a query may read.
+
+The Mamba-2 recurrence (``A`` < 0 a head, ``dt`` > 0 a head and token, ``B`` and
 ``C`` shared by ``heads // groups`` heads):
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (outer) B_t      y_t = S_t C_t
@@ -44,7 +80,7 @@ neither decays nor feeds the state: that is how padding is masked.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,25 +91,59 @@ from ..models.configs import ModelConfig
 class StatePool(NamedTuple):
     ssm: jax.Array
     conv: jax.Array
+    win_k: Optional[jax.Array] = None
+    win_v: Optional[jax.Array] = None
+    win_ks: Optional[jax.Array] = None
+    win_vs: Optional[jax.Array] = None
 
     @classmethod
-    def create(cls, config: ModelConfig, rows: int, dtype) -> "StatePool":
+    def create(cls, config: ModelConfig, rows: int, dtype,
+               quantized: bool = False) -> "StatePool":
         L = config.ssm_layers
-        return cls(
-            ssm=jnp.zeros((L, rows, config.mamba_num_heads,
-                           config.mamba_head_dim, config.ssm_state_size),
-                          jnp.float32),
+        pool = cls(
+            ssm=jnp.zeros((L, rows) + config.ssm_state_shape, jnp.float32),
             conv=jnp.zeros((L, rows, config.conv_kernel - 1,
                             config.conv_dim), dtype))
+        Lw, W = config.window_layers, config.sliding_window
+        if not Lw:
+            return pool
+        shape = (Lw, rows, config.cache_kv_heads, W, config.cache_k_dim)
+        if not quantized:
+            return pool._replace(win_k=jnp.zeros(shape, dtype),
+                                 win_v=jnp.zeros(shape, dtype))
+        scales = shape[:-1]
+        return pool._replace(
+            win_k=jnp.zeros(shape, jnp.int8), win_v=jnp.zeros(shape, jnp.int8),
+            win_ks=jnp.zeros(scales, jnp.float32),
+            win_vs=jnp.zeros(scales, jnp.float32))
+
+    @property
+    def rows(self) -> int:
+        return self.ssm.shape[1]
+
+    @property
+    def ring_nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in self[2:] if a is not None)
 
     @property
     def nbytes(self) -> int:
-        return int(self.ssm.nbytes) + int(self.conv.nbytes)
+        return int(self.ssm.nbytes) + int(self.conv.nbytes) \
+            + self.ring_nbytes
 
     @property
     def row_bytes(self) -> int:
-        """Bytes of ONE row over all layers (state and window)."""
-        return self.nbytes // self.ssm.shape[1]
+        """Bytes of ONE row's recurrent state over all layers (state and
+        convolution window): what a decode step reads and writes."""
+        return (int(self.ssm.nbytes) + int(self.conv.nbytes)) // self.rows
+
+    @property
+    def ring_position_bytes(self) -> int:
+        """Bytes of ONE position of one row in ONE window layer's ring (K
+        and V, with their scales)."""
+        if self.win_k is None:
+            return 0
+        return self.ring_nbytes // (self.win_k.shape[0] * self.rows
+                                    * self.win_k.shape[3])
 
 
 def _by_head(g: jax.Array, heads: int) -> jax.Array:
@@ -181,55 +251,179 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     return y, S_out.reshape(S_in.shape)
 
 
+def ssm1_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+              Bm: jax.Array, Cm: jax.Array) -> tuple:
+    """One position of Mamba-1. S [B,N,d] float32; x [B,d]; dt [B,d]
+    float32 (0: the state does not move); A [N,d] (< 0); Bm, Cm [B,N].
+    Returns (y [B,d] float32, S_new)."""
+    f32 = jnp.float32
+    decay = jnp.exp(dt[:, None, :] * A[None])                 # [B,N,d]
+    dtx = dt * x.astype(f32)                                  # [B,d]
+    S_new = decay * S + Bm.astype(f32)[:, :, None] * dtx[:, None, :]
+    y = jnp.sum(S_new * Cm.astype(f32)[:, :, None], axis=1)
+    return y, S_new
+
+
+def ssm1_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+              Cm: jax.Array, S_in: jax.Array, unroll: int = 8) -> tuple:
+    """Mamba-1 over S positions: x, dt [B,S,d]; Bm, Cm [B,S,N]; S_in
+    [B,N,d] float32. The state is carried a position at a time (its
+    decay differs by channel and state number, so no block of positions
+    is a matmul), ``unroll`` positions a loop iteration. Returns (y
+    [B,S,d] float32, S_out)."""
+    def step(S, inp):
+        y, S = ssm1_step(S, *inp[:2], A, *inp[2:])
+        return S, y
+
+    S_out, y = jax.lax.scan(
+        step, S_in, tuple(jnp.moveaxis(a, 1, 0) for a in (x, dt, Bm, Cm)),
+        unroll=min(unroll, x.shape[1]))
+    return jnp.moveaxis(y, 0, 1), S_out
+
+
 def decode_update(pool: StatePool, layer: jax.Array, live: jax.Array,
                   xbc: jax.Array, conv_w: jax.Array, conv_b: jax.Array,
-                  split) -> tuple:
+                  split, step=ssm_step) -> tuple:
     """One decode step of Mamba layer ``layer`` for the pool's first B
     rows, in place. ``xbc`` [B,C]: the convolution's new input;
-    ``split(conv_out [B,C] float32) -> (x [B,H,P], dt [B,H], A [H], Bm
-    [B,G,N], Cm [B,G,N])``: the model's reading of the convolved
-    channels. ``live`` [B] bool: the other rows' state and window come
-    back bit for bit. Returns (y [B,H,P] float32, x, pool)."""
+    ``split(conv_out [B,C] float32) -> (x, dt, A, Bm, Cm)``: the model's
+    reading of the convolved channels, in the shapes ``step`` takes
+    (:func:`ssm_step`, or :func:`ssm1_step` for Mamba-1). ``live`` [B]
+    bool: the other rows' state and window come back bit for bit.
+    Returns (y float32, x, pool)."""
     B = xbc.shape[0]
     zero = jnp.zeros((), jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
+    tail = (zero,) * (pool.ssm.ndim - 2)
     S = jax.lax.dynamic_slice(
-        pool.ssm, (layer, zero, zero, zero, zero),
-        (1, B) + pool.ssm.shape[2:])[0]
+        pool.ssm, (layer, zero) + tail, (1, B) + pool.ssm.shape[2:])[0]
     win = jax.lax.dynamic_slice(
         pool.conv, (layer, zero, zero, zero),
         (1, B) + pool.conv.shape[2:])[0]
     out, win_new = conv_step(win, xbc, conv_w, conv_b)
     x, dt, A, Bm, Cm = split(out)
-    y, S_new = ssm_step(S, x, dt, A, Bm, Cm)
-    S_new = jnp.where(live[:, None, None, None], S_new, S)
+    y, S_new = step(S, x, dt, A, Bm, Cm)
+    S_new = jnp.where(live.reshape((B,) + (1,) * (S.ndim - 1)), S_new, S)
     win_new = jnp.where(live[:, None, None], win_new, win)
-    return y, x, StatePool(
+    return y, x, pool._replace(
         ssm=jax.lax.dynamic_update_slice(
-            pool.ssm, S_new[None], (layer, zero, zero, zero, zero)),
+            pool.ssm, S_new[None], (layer, zero) + tail),
         conv=jax.lax.dynamic_update_slice(
             pool.conv, win_new[None], (layer, zero, zero, zero)))
 
 
+# -- window rings -------------------------------------------------------------
+
+def ring_chunk_masks(S: int, W: int, offset: int) -> tuple:
+    """What a chunk of S queries at positions offset..offset+S of a
+    window layer may read: (of the carried ring [S,W], of the chunk's own
+    keys [S,S]). Slot j of the ring holds the newest position below
+    ``offset`` that is j mod W; query i reads positions above ``offset +
+    i - W``, its own the last."""
+    i = jnp.arange(S)[:, None]
+    held = offset - 1 - (offset - 1 - jnp.arange(W)[None, :]) % W
+    ring = (held >= 0) & (held > offset + i - W)
+    j = jnp.arange(S)[None, :]
+    return ring, (j <= i) & (i - j < W)
+
+
+def ring_after_chunk(ring: jax.Array, new: jax.Array, offset: int,
+                     lengths: jax.Array) -> jax.Array:
+    """The ring [B,G,W,C] after a chunk ``new`` [B,S,G,C] whose first
+    ``lengths`` [B] positions are real, at positions offset..: each slot
+    takes the newest real position of its residue, or keeps what it
+    held. Padding is never written."""
+    W, S = ring.shape[2], new.shape[1]
+    last = offset + lengths.astype(jnp.int32)[:, None] - 1       # [B,1]
+    newest = last - (last - jnp.arange(W)[None, :]) % W          # [B,W]
+    idx = jnp.clip(newest - offset, 0, S - 1)
+    taken = jnp.take_along_axis(new, idx[:, :, None, None], axis=1)
+    return jnp.where((newest >= offset)[:, None, :, None],
+                     jnp.swapaxes(taken, 1, 2).astype(ring.dtype), ring)
+
+
+def ring_decode_write(pool: StatePool, layer: jax.Array, live: jax.Array,
+                      lengths: jax.Array, k: jax.Array,
+                      v: jax.Array) -> StatePool:
+    """A decode step's K and V ([B,G,C], position ``lengths`` [B] of
+    each row) into window layer ``layer``'s ring, in place. Rows not
+    live write the garbage row (the pool's last) and keep theirs bit for
+    bit."""
+    B, G, _ = k.shape
+    W = pool.win_k.shape[3]
+    layer = jnp.asarray(layer, jnp.int32)
+    rows = jnp.where(live, jnp.arange(B, dtype=jnp.int32),
+                     pool.rows - 1)[:, None]
+    slot = (lengths.astype(jnp.int32) % W)[:, None]
+    heads = jnp.arange(G, dtype=jnp.int32)[None, :]
+
+    def put(ring, new):
+        return ring.at[layer, rows, heads, slot].set(new.astype(ring.dtype))
+
+    if pool.win_ks is None:
+        return pool._replace(win_k=put(pool.win_k, k),
+                             win_v=put(pool.win_v, v))
+    from .paged_kv import quant_kv
+    (kq, ks), (vq, vs) = quant_kv(k), quant_kv(v)
+    zero = jnp.zeros((), jnp.int32)
+    at = (jnp.arange(W)[None, None, :] == slot[:, :, None]) \
+        & live[:, None, None]
+
+    def put_scale(scales, s):
+        # The slot is the lane dimension: replace lanes of the layer's
+        # [B, G, W] block, never scatter into them (paged_kv's rule).
+        old = jax.lax.dynamic_slice(scales, (layer, zero, zero, zero),
+                                    (1, B) + scales.shape[2:])[0]
+        return jax.lax.dynamic_update_slice(
+            scales, jnp.where(at, s[:, :, None], old)[None],
+            (layer, zero, zero, zero))
+
+    return pool._replace(
+        win_k=put(pool.win_k, kq), win_v=put(pool.win_v, vq),
+        win_ks=put_scale(pool.win_ks, ks), win_vs=put_scale(pool.win_vs, vs))
+
+
+def ring_read(pool: StatePool, layer: jax.Array, B: int) -> tuple:
+    """Window layer ``layer``'s ring of the pool's first B rows: (k
+    [B,G,W,C], v, k scales [B,G,W] | None, v scales)."""
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def rows(a):
+        if a is None:
+            return None
+        return jax.lax.dynamic_slice(
+            a, (layer, zero) + (zero,) * (a.ndim - 2),
+            (1, B) + a.shape[2:])[0]
+
+    return tuple(rows(a) for a in pool[2:])
+
+
 def write_rows(pool: StatePool, state: StatePool,
                rows: jax.Array) -> StatePool:
-    """``state`` ([L_m, R, ...], a prefill's carry) into rows ``rows``
-    [R] of ``pool``, whole rows. An admission's dummy entries name the
-    garbage row (the row sentinel ``num_slots`` is its index)."""
+    """``state`` ([L, R, ...], a prefill's carry) into rows ``rows`` [R]
+    of ``pool``, whole rows (rings too, quantised here where the pool's
+    are int8). An admission's dummy entries name the garbage row (the
+    row sentinel ``num_slots`` is its index)."""
     rows = rows.astype(jnp.int32)
-    return StatePool(ssm=pool.ssm.at[:, rows].set(state.ssm, mode="drop"),
-                     conv=pool.conv.at[:, rows].set(
-                         state.conv.astype(pool.conv.dtype), mode="drop"))
+    if pool.win_ks is not None:
+        from .paged_kv import quant_kv
+        (kq, ks), (vq, vs) = quant_kv(state.win_k), quant_kv(state.win_v)
+        state = state._replace(win_k=kq, win_v=vq, win_ks=ks, win_vs=vs)
+    return StatePool(*(
+        None if a is None else a.at[:, rows].set(b.astype(a.dtype),
+                                                 mode="drop")
+        for a, b in zip(pool, state)))
 
 
 def snapshot(state: StatePool, row: int = 0) -> StatePool:
-    """One row of a carry, without the row axis ([L_m, ...]): what a
+    """One row of a carry, without the row axis ([L, ...]): what a
     prefix entry keeps beside its K and V."""
-    return StatePool(ssm=state.ssm[:, row], conv=state.conv[:, row])
+    return StatePool(*(None if a is None else a[:, row] for a in state))
 
 
 def from_snapshot(snap: StatePool, rows: int) -> StatePool:
     """A carry of ``rows`` rows that all start from ``snap``."""
     def rep(a):
         return jnp.broadcast_to(a[:, None], (a.shape[0], rows) + a.shape[1:])
-    return StatePool(ssm=rep(snap.ssm), conv=rep(snap.conv))
+    return StatePool(*(None if a is None else rep(a) for a in snap))

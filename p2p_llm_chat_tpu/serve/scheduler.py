@@ -675,6 +675,11 @@ class BatchScheduler:
         # Width of the counts a routed model's programs hand back behind
         # their tokens (_with_moe): 2, or the family's own.
         self._moe_w = getattr(model, "STATS_WIDTH", 2)
+        # Models whose prefill programs take the mask of real positions
+        # and hand counts back (the ``_counted`` / ``_touched`` forms): a
+        # routed model's, and a hybrid's, whose recurrent state and
+        # window rings padding must not move, routed layers or none.
+        self._counted = config.is_moe or config.is_hybrid
         # Decode is bandwidth-bound and pays a fixed cost per
         # weight-matmul call: fuse the column-parallel projection pairs
         # (wq|wk|wv, w_gate|w_up) into single wider matmuls
@@ -799,6 +804,17 @@ class BatchScheduler:
         self._n_state_row_steps_live = 0  # owned-by: _loop
         self._n_state_bytes = 0          # owned-by: _loop
         self._n_state_snapshots = 0      # owned-by: _loop
+        # Window rings and a page layer that other layers read too (the
+        # SambaY kinds of models/nemotron_h.py), as bytes the decode
+        # dispatches had to read: live rows x window layers x the
+        # positions a row's ring holds (its length, at most the window),
+        # and live rows x context x the layers that read the one pool.
+        self._n_window_bytes = 0         # owned-by: _loop
+        self._n_shared_kv_bytes = 0      # owned-by: _loop
+        self._shared_kv_readers = (
+            config.hybrid_pattern.count("*")
+            + config.hybrid_pattern.count("x")
+            if "x" in config.hybrid_pattern else 0)
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -1011,7 +1027,7 @@ class BatchScheduler:
         # kv_), which is what a reader of a device trace sums by
         # (`XLA Modules` events read jit_prefill_..., jit_decode_...;
         # tests/test_loop_phases.py holds every jit site to it).
-        routed = config.is_moe
+        routed = self._counted
 
         def _with_moe(toks, moe):
             """A dispatch's tokens with a routed model's count behind
@@ -1732,7 +1748,7 @@ class BatchScheduler:
         KV, at its start: the rows' last-position logits, and for a
         routed model the drop count so far."""
         logits = jnp.zeros((R, self.config.vocab_size), jnp.float32)
-        if self.config.is_moe:
+        if self._counted:
             return logits, jnp.zeros((self._moe_w,), jnp.int32)
         return logits
 
@@ -1756,7 +1772,7 @@ class BatchScheduler:
         built = self._build_prefix_j(
             self._params,  # graftcheck: sync-ok host token ids, upload not readback
             jnp.asarray(np.asarray(ids, np.int32)[None, :]))
-        if self.config.is_moe:
+        if self._counted:
             # A build has no first token to ride on: its drop count
             # waits, on the device, for the next admission's readback.
             self._moe_unread.append(built[2])
@@ -2589,6 +2605,15 @@ class BatchScheduler:
         st = self._cache.state
         self._state_pool_bytes = st.nbytes if st is not None else 0
         self._state_row_bytes = st.row_bytes if st is not None else 0
+        self._ring_position_bytes = (st.ring_position_bytes
+                                     if st is not None else 0)
+        # One position of ONE layer in the page pool, scales included.
+        c = self._cache
+        self._page_token_bytes = sum(
+            int(a.nbytes) for a in (c.k, c.v)) // (
+                c.k.shape[0] * c.num_pages * c.page_size)
+        if c.quantized:
+            self._page_token_bytes += 2 * 4 * c.k.shape[3]
 
     # -- client side (HTTP threads) ------------------------------------------
 
@@ -3642,6 +3667,10 @@ class BatchScheduler:
             out["serve_state_row_steps_live_total"] = \
                 self._n_state_row_steps_live
             out["serve_state_snapshots_total"] = self._n_state_snapshots
+        if self.config.window_layers:
+            out["serve_window_bytes_total"] = self._n_window_bytes
+        if self._shared_kv_readers:
+            out["serve_shared_kv_bytes_total"] = self._n_shared_kv_bytes
         if self.spec_k:
             out["serve_spec_accepted_total"] = self._n_spec_accepted
             # Back-compat aggregate: the most optimistic source (the
@@ -3778,7 +3807,7 @@ class BatchScheduler:
         kind = (f"latent (MLA): 1 head x ({c.cache_k_dim} latent + "
                 f"{c.qk_rope_head_dim} rotated key in {c.cache_v_dim} "
                 "lanes)" if c.is_latent else
-                f"{c.cache_kv_heads} kv heads x 2 x {c.head_dim}")
+                f"{c.cache_kv_heads} kv heads x 2 x {c.cache_k_dim}")
         log.info("KV pool: %s, %s%s; %d layers x %d bytes a token; %d pages "
                  "x %d tokens, %d rows x %d pages a row; %.3f GB",
                  kind, cache.k.dtype.name,
@@ -3795,7 +3824,16 @@ class BatchScheduler:
                      "x".join(map(str, st.ssm.shape[2:])),
                      "x".join(map(str, st.conv.shape[2:])),
                      st.conv.dtype.name, st.row_bytes / 1e6,
-                     st.nbytes / 1e9)
+                     (st.nbytes - st.ring_nbytes) / 1e9)
+            if st.win_k is not None:
+                log.info("window rings: %d layers x %d rows x %d positions "
+                         "x %dx%d %s%s; %d bytes a position a layer, %.3f GB",
+                         st.win_k.shape[0], st.rows, st.win_k.shape[3],
+                         st.win_k.shape[2], st.win_k.shape[4],
+                         st.win_k.dtype.name,
+                         ", a float32 scale a position a head for each"
+                         if st.win_ks is not None else "",
+                         st.ring_position_bytes, st.ring_nbytes / 1e9)
 
     @staticmethod
     def _flash_min_w(config, mesh, kv_quant: bool = False) -> int:
@@ -4262,7 +4300,7 @@ class BatchScheduler:
         with self._phase("readback", rows=len(chunk)):
             # graftcheck: sync-ok intentional: R int32 first tokens, TTFT depends on it
             first_toks = np.asarray(toks_dev)
-            if self.config.is_moe:
+            if self._counted:
                 # The prefill's drop count rides behind the first tokens
                 # (_with_moe); prefix builds left theirs waiting.
                 # Warm-up's all-padding dispatches carry no request.
@@ -4526,9 +4564,19 @@ class BatchScheduler:
             self._n_state_bytes += (2 * self.num_slots * K
                                     * self._state_row_bytes)
         # Step j of the K reads each live row's ctx_len + j cached rows.
-        self._n_attn_ctx_tokens += K * sum(
+        ctx_tokens = K * sum(
             s.ctx_len + inflight for s in self._slots
             if s is not None) + sum(active) * K * (K - 1) // 2
+        self._n_attn_ctx_tokens += ctx_tokens
+        if self._ring_position_bytes:
+            W = self.config.sliding_window
+            self._n_window_bytes += (
+                self.config.window_layers * self._ring_position_bytes
+                * sum(min(s.ctx_len + inflight + j + 1, W)
+                      for s in self._slots if s is not None
+                      for j in range(K)))
+        self._n_shared_kv_bytes += (self._shared_kv_readers * ctx_tokens
+                                    * self._page_token_bytes)
         if active != self._active_host:
             # Re-upload the mask only when the active set changed (it only
             # moves on admission/finish — not per tick).
@@ -4596,7 +4644,7 @@ class BatchScheduler:
             failpoint("serve.engine.readback")
             # graftcheck: sync-ok intentional: [B] or [K,B] int32, the tick's readback
             toks = np.asarray(toks_dev)
-        if self.config.is_moe:
+        if self._counted:
             # The experts the dispatch touched ride behind its tokens
             # (_with_moe).
             w = self._moe_w

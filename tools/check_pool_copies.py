@@ -52,10 +52,16 @@ def pool_shapes(cfg: dict) -> dict:
     out = {f"{kv}[{L},{pages},{ps},{hk},{c.cache_k_dim}]": "pages k/v",
            f"f32[{L},{pages},{hk},{-(-ps // 128) * 128}]": "page scales"}
     if c.ssm_layers:
-        out[f"f32[{c.ssm_layers},{slots + 1},{c.mamba_num_heads},"
-            f"{c.mamba_head_dim},{c.ssm_state_size}]"] = "state"
+        state = ",".join(map(str, c.ssm_state_shape))
+        out[f"f32[{c.ssm_layers},{slots + 1},{state}]"] = "state"
         out[f"bf16[{c.ssm_layers},{slots + 1},{c.conv_kernel - 1},"
             f"{c.conv_dim}]"] = "window"
+    if c.window_layers:
+        lead = f"{c.window_layers},{slots + 1}"
+        out[f"{kv}[{lead},{c.sliding_window},{c.num_kv_heads},"
+            f"{c.head_dim}]"] = "ring k/v"
+        out[f"f32[{lead},{c.num_kv_heads},{c.sliding_window}]"] = \
+            "ring scales"
     return out
 
 
