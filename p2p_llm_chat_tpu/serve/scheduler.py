@@ -80,14 +80,22 @@ log = get_logger("serve.scheduler")
 _MIN_BUCKET = 16
 # Admission programs come in two widths a bucket: 1 row, for the request
 # that arrives alone or takes the one row a finished request freed, and
-# one wide program for bursts — the widest power of two, at most
-# _MAX_ADMIT_CHUNK rows (and no wider than the batch), whose
-# R x (P + S) tokens stay inside _ADMIT_WIDE_TOKENS. At about 2,048
-# tokens a prefill's matmuls are compute-bound, so a wider program buys
-# a burst no device time over several of these, and decode ticks run
-# between them (_admit_widths).
-_MAX_ADMIT_CHUNK = 8
-_ADMIT_WIDE_TOKENS = 2048
+# 2 rows, for requests collected together, wherever two rows' P + S
+# tokens stay inside _ADMIT_PAIR_TOKENS (_admit_widths). No wider,
+# because a second row is not free and further rows buy nothing: on a
+# v5e a 2 x 256 dispatch costs 1.8x a 1 x 256 one in Mixtral-8x7B's
+# layers (29.0 ms against 16.0), 2.2x in OLMoE's and 1.9-2.3x in
+# Nemotron-H's, in the single-shot splice and in every chunk of a
+# ladder (tools/check_admit_pair.py; PERF.md section 6, PR 39). A routed
+# model's one-row dispatch of 256 positions already sits at the ridge:
+# its expert buckets hold twice the positions routed to them (the
+# capacity factor) or all of them (dropless), so their arithmetic takes
+# as long as the weight stream it was meant to hide under, and from
+# there a dispatch's time grows with its rows, dummy entries included.
+# So a burst runs pair after pair, decode ticks in between, and two
+# requests never pay for a 4- or 8-row program; and nothing waits for a
+# partner: sharing a dispatch saves a tenth of two, at best.
+_ADMIT_PAIR_TOKENS = 2048
 # Cap on the R x S footprint of an operator-fixed width (admit_chunk):
 # the fused prefill materialises a [L, R, S(+P), Hkv, D] small cache, so
 # wide chunks at long prompt buckets would transiently eat gigabytes of
@@ -386,8 +394,8 @@ class BatchScheduler:
                  spec_tree_gap: float = 4.0) -> None:
         """``admit_chunk``: a fixed admission width an operator may set.
         None (default): a dispatch is as wide as what it carries — the
-        1-row program for a request admitted alone, the bucket's wide
-        program (at most 8 rows, at most about 2,048 tokens:
+        1-row program for a request admitted alone, the bucket's 2-row
+        program (where two rows stay inside about 2,048 tokens:
         _admit_widths) for requests collected together, a larger burst
         through several of those with decode ticks in between. A fixed
         power of two makes that the ONLY width, for every admission
@@ -755,6 +763,9 @@ class BatchScheduler:
         # no admission work cut into (_note_clean_interval).
         self._n_admit_batches = 0
         self._n_admit_uploads = 0
+        # Dispatches, single-shot or chunk, that carried more than one
+        # request (over batches + chunks: how often a dispatch is shared).
+        self._n_admit_pair_dispatches = 0     # owned-by: _loop
         self._n_admit_rows_padded = 0
         self._n_prefill_tokens = 0
         self._n_prefill_padded = 0
@@ -1952,16 +1963,17 @@ class BatchScheduler:
         exactly these, and _admit_width picks among them, so an
         admission never meets a width nobody compiled.
 
-        Default: 1, and one wide program for bursts (see
-        _ADMIT_WIDE_TOKENS). A fixed ``admit_chunk`` is the ONLY width,
-        narrowed where the bucket would pass the HBM budget."""
+        Default: 1, and 2 where two rows of this footprint stay inside
+        _ADMIT_PAIR_TOKENS (the comment there says why no wider): a
+        burst of n requests runs ceil(n/2) pair dispatches. A fixed
+        ``admit_chunk`` is the ONLY width, narrowed where the bucket
+        would pass the HBM budget."""
         if self.admit_chunk:
             return (min(self.admit_chunk,
                         _pow2_floor(_ADMIT_TOKEN_BUDGET // footprint)),)
-        wide = min(_MAX_ADMIT_CHUNK,
-                   1 << max(0, self.num_slots - 1).bit_length(),
-                   _pow2_floor(_ADMIT_WIDE_TOKENS // footprint))
-        return (1, wide) if wide > 1 else (1,)
+        if self.num_slots > 1 and 2 * footprint <= _ADMIT_PAIR_TOKENS:
+            return (1, 2)
+        return (1,)
 
     def _admit_width(self, n: int, footprint: int) -> int:
         """Width of the dispatch for ``n`` requests collected together:
@@ -1987,7 +1999,7 @@ class BatchScheduler:
         """Pre-compile the serving programs (first compile is tens of
         seconds on TPU — it must not land on real requests' TTFT): the
         admit programs of every prompt bucket at each width admission
-        can choose (_admit_widths: 1 row and one wide program a bucket,
+        can choose (_admit_widths: 1 row and 2 rows a bucket,
         with their chunk ladders and prefix splices —
         _admission_shapes), one decode (and spec) program per attention
         window.
@@ -3612,6 +3624,8 @@ class BatchScheduler:
             # an admission, none for a ladder's chunks (over batches +
             # prefill_chunks_total: the transfers a dispatch).
             "serve_admit_uploads_total": self._n_admit_uploads,
+            "serve_admit_pair_dispatches_total":
+                self._n_admit_pair_dispatches,
             "serve_admit_rows_padded_total": self._n_admit_rows_padded,
             "serve_prefill_tokens_total": self._n_prefill_tokens,
             "serve_prefill_tokens_padded_total": self._n_prefill_padded,
@@ -4081,7 +4095,7 @@ class BatchScheduler:
             while group:
                 # The dispatch is as wide as what it carries: a lone
                 # request runs the 1-row program, a burst the bucket's
-                # wide one, several times over if the group is larger
+                # 2-row one, several times over if the group is larger
                 # (both warmed: _admit_widths). A prefix-cached group
                 # counts its broadcast prefix in the footprint too.
                 R = self._admit_width(len(group), S + len(pkey))
@@ -4164,8 +4178,8 @@ class BatchScheduler:
         ``rows`` + first-token sample per row.
 
         The program shape is (R, S) with R the narrowest of the bucket's
-        widths that holds the chunk (_admit_widths: 1 row, and one wide
-        program for bursts — two programs per prompt bucket). A chunk
+        widths that holds the chunk (_admit_widths: 1 row and 2 rows —
+        two programs per prompt bucket). A chunk
         shorter than R is padded with dummy entries whose row index is
         the out-of-range sentinel ``num_slots`` — every install of
         theirs is scatter-dropped. ``serve_admit_rows_padded_total``
@@ -4202,6 +4216,7 @@ class BatchScheduler:
         self._admit_since_tick = True
         if chunk:       # warm-up's all-padding dispatches do not count
             self._n_admit_batches += 1
+            self._n_admit_pair_dispatches += len(chunk) > 1
             self._n_admit_rows_padded += R
             self._n_prefill_tokens += sum(len(s.prompt_ids) - P
                                           for s in chunk)
@@ -4405,6 +4420,7 @@ class BatchScheduler:
         off = pc.off
         R = pc.packed.shape[0]
         self._n_prefill_chunks += 1
+        self._n_admit_pair_dispatches += len(pc.chunk) > 1
         self._n_prefill_padded += R * C
         self._admit_since_tick = True
         self._flight.note("prefill_chunk", self._loop_iter,

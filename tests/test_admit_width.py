@@ -1,15 +1,16 @@
 """Admission dispatches as many rows as it collected.
 
 - the rule (``_admit_widths`` / ``_admit_width``): two widths a bucket,
-  1 and one wide program capped by a dispatch's tokens, the batch and
-  8 rows; the narrowest that holds the group is chosen; a fixed
-  ``admit_chunk`` stays the only width;
+  1 and 2 where two rows stay inside a dispatch's token cap; the
+  narrowest that holds the group is chosen; a fixed ``admit_chunk``
+  stays the only width;
 - a lone request runs the 1-row program, single-shot and through a
   chunk ladder, prefix hit and miss, and the counters at the dispatch
   site say so (``serve_admit_rows_padded_total``,
   ``serve_prefill_tokens_padded_total``);
 - k requests collected together run the narrowest warmed width that
-  holds them;
+  holds them, pair by pair (tests/test_admit_pairs.py: what a pair
+  generates, and that nothing waits for a partner);
 - what a request generates does not depend on the width it was
   admitted at (dense and MoE streams; dense logits within float32
   rounding), and the requests come before the dummy entries, which
@@ -85,14 +86,14 @@ def _delta(sched, before: dict, key: str):
 # -- the rule ----------------------------------------------------------------
 
 @pytest.mark.parametrize("slots,footprint,widths", [
-    (32, 128, (1, 8)), (32, 256, (1, 8)), (32, 512, (1, 4)),
+    (32, 128, (1, 2)), (32, 256, (1, 2)), (32, 512, (1, 2)),
     (32, 1024, (1, 2)), (32, 2048, (1,)),
-    (32, 88 + 128, (1, 8)), (32, 88 + 256, (1, 4)), (32, 88 + 512, (1, 2)),
+    (32, 88 + 128, (1, 2)), (32, 88 + 256, (1, 2)), (32, 88 + 512, (1, 2)),
     (32, 88 + 1024, (1,)),
-    (2, 128, (1, 2)), (5, 128, (1, 8)), (1, 128, (1,)),
+    (2, 128, (1, 2)), (5, 128, (1, 2)), (1, 128, (1,)),
 ])
-def test_two_widths_a_bucket_the_wide_one_capped_by_tokens(slots, footprint,
-                                                           widths):
+def test_two_widths_a_bucket_the_second_capped_by_tokens(slots, footprint,
+                                                         widths):
     sched = _scheduler(num_slots=slots)
     try:
         assert sched._admit_widths(footprint) == widths
@@ -101,8 +102,8 @@ def test_two_widths_a_bucket_the_wide_one_capped_by_tokens(slots, footprint,
 
 
 @pytest.mark.parametrize("n,footprint,R", [
-    (1, 256, 1), (2, 256, 8), (8, 256, 8), (9, 256, 8), (32, 256, 8),
-    (1, 512, 1), (3, 512, 4), (5, 512, 4), (2, 1024, 2), (3, 2048, 1),
+    (1, 256, 1), (2, 256, 2), (8, 256, 2), (9, 256, 2), (32, 256, 2),
+    (1, 512, 1), (3, 512, 2), (5, 512, 2), (2, 1024, 2), (3, 2048, 1),
 ])
 def test_width_is_the_narrowest_that_holds_the_group(n, footprint, R):
     sched = _scheduler(num_slots=32)
@@ -154,8 +155,8 @@ def test_a_lone_request_is_one_row(body, chunks, hit):
 # -- requests collected together ---------------------------------------------------
 
 @pytest.mark.parametrize("k,slots,dispatches", [
-    (2, 8, [8]), (3, 4, [4]), (2, 2, [2]), (8, 8, [8]),
-    (5, 4, [4, 1]),        # four rows free: the fifth follows alone
+    (2, 8, [2]), (3, 4, [2, 1]), (2, 2, [2]), (8, 8, [2, 2, 2, 2]),
+    (5, 4, [2, 2, 1]),     # four rows free: the fifth follows alone
 ])
 def test_a_group_runs_the_narrowest_width_that_holds_it(k, slots, dispatches):
     sched = _scheduler(num_slots=slots)
@@ -178,18 +179,20 @@ def test_a_group_runs_the_narrowest_width_that_holds_it(k, slots, dispatches):
         sched.stop()
 
 
-def test_a_group_of_long_prompts_shares_one_wide_ladder():
+def test_two_long_prompts_share_one_ladder():
     sched = _scheduler(num_slots=4)
     try:
         before = sched.metrics_snapshot()
         # 100 + 3 characters and a BOS: still the 128 bucket, four chunks.
-        group = [f"{LONG[:100]} #{i}" for i in range(3)]
-        assert len(_together(sched, group)) == 3
+        group = [f"{LONG[:100]} #{i}" for i in range(2)]
+        assert len(_together(sched, group)) == 2
         assert _delta(sched, before, "serve_admit_batches_total") == 1
-        assert _delta(sched, before, "serve_admit_rows_padded_total") == 4
+        assert _delta(sched, before, "serve_admit_rows_padded_total") == 2
         assert _delta(sched, before, "prefill_chunks_total") == 4
         assert _delta(sched, before,
-                      "serve_prefill_tokens_padded_total") == 4 * 128
+                      "serve_admit_pair_dispatches_total") == 4
+        assert _delta(sched, before,
+                      "serve_prefill_tokens_padded_total") == 2 * 128
     finally:
         sched.stop()
 
@@ -301,7 +304,7 @@ def test_benchmark_warmup_is_no_more_programs_than_the_old_ladder():
             for S in (128, 256, 512, 1024, 2048):
                 if P + S <= 2048:
                     assert (P, S, 1, False) in shapes
-        assert all(R == 1 or R * (P + S) <= sched_mod._ADMIT_WIDE_TOKENS
+        assert all(R == 1 or R * (P + S) <= sched_mod._ADMIT_PAIR_TOKENS
                    for P, S, R, _ in shapes)
     finally:
         sched.stop()
@@ -315,9 +318,9 @@ def _compiled(sched) -> int:
 
 def test_after_warmup_no_admission_the_chooser_can_pick_compiles(monkeypatch):
     """The benchmark's ladder at an eighth of its size: buckets 16..256,
-    chunk 32, the wide program capped at 256 tokens, a registered
+    chunk 32, the 2-row program capped at 256 tokens, a registered
     prefix."""
-    monkeypatch.setattr(sched_mod, "_ADMIT_WIDE_TOKENS", 256)
+    monkeypatch.setattr(sched_mod, "_ADMIT_PAIR_TOKENS", 256)
     sched = _scheduler(num_slots=8, prefix_cache=True, page_size=16)
     buckets = (16, 32, 64, 128, 256)
     try:

@@ -97,7 +97,10 @@ def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
         assert got == {p: moe_oracle(p, 9) for p in burst}
         m = eng.metrics_snapshot()
         assert m["serve_admitted_total"] == 10
-        assert m["serve_admit_rows_padded_total"] > m["serve_admitted_total"]
+        # Pair by pair: a dummy entry only where a group was odd.
+        assert (m["serve_admitted_total"]
+                <= m["serve_admit_rows_padded_total"]
+                <= m["serve_admitted_total"] + m["serve_admit_batches_total"])
         assert m["prefill_chunks_total"] >= 3
         assert m["serve_prefix_admits_total"] >= 7
         assert m["decode_fused_ticks_total"] > 0
