@@ -14,12 +14,30 @@ from typing import Optional
 
 @dataclass(frozen=True)
 class RopeScaling:
-    """llama3.1-style NTK-by-parts rope scaling."""
+    """How the rotary table is stretched past the trained context; ``kind``
+    names the rule (models/layers.rope_table computes both):
+
+    - ``"llama3"``: llama3.1's NTK-by-parts: wavelengths past
+      ``original_max_position / low_freq_factor`` slowed by ``factor``,
+      those under ``original_max_position / high_freq_factor`` kept, a
+      ramp between.
+    - ``"yarn"``: a blend by frequency INDEX: index ``i`` below ``low``
+      keeps its frequency, above ``high`` has it divided by ``factor``,
+      a linear ramp between, ``low`` / ``high`` the indices that turn
+      ``beta_fast`` / ``beta_slow`` times over ``original_max_position``
+      (floor / ceil). cos and sin are both multiplied by
+      ``attention_factor`` (0: ``0.1 ln(factor) + 1``), so q.k carries
+      its square.
+    """
 
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position: int = 8192
+    kind: str = "llama3"
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -34,6 +52,11 @@ class ModelConfig:
     head_dim: int
     max_seq_len: int = 8192
     rope_theta: float = 500000.0
+    # The rule of the layers that read their whole context. A window
+    # layer (``w`` of a hybrid pattern) rotates by the plain table of
+    # ``rope_theta`` whatever this says: its keys are never further than
+    # ``sliding_window`` from their query (Mellum's ``rope_parameters``:
+    # ``sliding_attention`` default, ``full_attention`` YaRN).
     rope_scaling: Optional[RopeScaling] = None
     rms_norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -85,7 +108,9 @@ class ModelConfig:
     # family: one letter a layer, each layer ONE mixer behind a pre-norm
     # and a residual: ``M`` a Mamba-2 layer, ``E`` a routed feed-forward
     # layer, ``*`` an attention layer. ``num_layers`` is its length. Only
-    # the ``*`` layers hold pages (``cache_layers``); an ``M`` layer keeps
+    # the ``*`` layers hold pages (``cache_layers``), each its own page
+    # layer, read by itself alone (Mellum: one of every four attention
+    # layers) unless ``x`` layers above it read it too; an ``M`` layer keeps
     # per row a float32 state [mamba_num_heads, mamba_head_dim,
     # ssm_state_size] and the last ``conv_kernel - 1`` inputs of its
     # convolution (ops/state_pool.py).
@@ -101,7 +126,8 @@ class ModelConfig:
     mlp_activation: str = "silu"
     # Experts that live in a latent of this width, reached through one
     # shared down-projection before and one up-projection after them
-    # (0: the experts read the hidden state).
+    # (0: the experts read the hidden state; a hybrid's ``E`` is then the
+    # plain routed layer, ``wgu_e`` / ``w_down`` and no shared expert).
     moe_latent_size: int = 0
     # Width of the shared expert when it is not ``intermediate_size``.
     shared_intermediate_size: int = 0
@@ -109,14 +135,17 @@ class ModelConfig:
     # top-k only; the kept weights are the unbiased scores.
     moe_selection_bias: bool = False
     # False: attention without rotary embedding (position comes from the
-    # recurrent layers).
+    # recurrent layers). True in a hybrid pattern: ``w`` and ``*`` layers
+    # rotate q and k, each kind by its own table (``rope_scaling``).
     attn_rope: bool = True
     # Further layer kinds of the hybrid walk (models/nemotron_h.py; the
     # SambaY stack of Phi-4-mini-flash). In ``hybrid_pattern``: ``1`` a
     # Mamba-1 layer (``Y`` one that also publishes its scan output, the
     # memory the ``g`` layers above it gate), ``w`` attention over the
     # last ``sliding_window`` positions (a ring a row in the state pool,
-    # no pages), ``g`` a gated memory unit, ``x`` attention whose K and V
+    # no pages: differential under ``attn_diff``, else plain GQA, whose
+    # ring keeps its KV heads apart, ``cache_kv_heads`` x ``cache_k_dim``),
+    # ``g`` a gated memory unit, ``x`` attention whose K and V
     # are the ``*`` layer's below it (it owns neither), ``-`` a dense
     # gated MLP of ``intermediate_size``. A published layer of that
     # family is two letters, its mixer and ``-``.
@@ -166,6 +195,21 @@ class ModelConfig:
     def window_layers(self) -> int:
         """Layers that keep a ring of ``sliding_window`` positions a row."""
         return self.hybrid_pattern.count("w")
+
+    @property
+    def state_layers(self) -> int:
+        """Layers whose per-row past is a row of the state pool
+        (ops/state_pool.py) and not pages: recurrent ones and window
+        rings. 0: the caches carry no ``state``."""
+        return self.ssm_layers + self.window_layers
+
+    @property
+    def state_kinds(self) -> str:
+        """``state_layers`` in words, for a refusal or a log line."""
+        return " and ".join(
+            f"{what} ({n} {of})" for n, what, of in (
+                (self.ssm_layers, "recurrent state", "Mamba layers"),
+                (self.window_layers, "window rings", "layers")) if n)
 
     @property
     def ssm_state_shape(self) -> tuple:
@@ -466,6 +510,29 @@ _register(ModelConfig(
     hybrid_pattern="1-w-1-w-Y-*-g-x-", norm_kind="layer", attn_bias=True,
     attn_diff=True, attn_rope=False, sliding_window=8, mamba1_inner=256,
     mamba1_state=16, mamba1_dt_rank=8, conv_kernel=4,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# Mellum2-12B-A2.5B-Instruct (JetBrains/Mellum2-12B-A2.5B-Instruct
+# config.json, model_type mellum) at test size: two periods of three
+# window layers to one full layer, each followed by a routed layer that
+# reads the hidden state (8 softmax-routed SwiGLU experts, the 2 largest
+# renormalised, no shared expert); GQA 4 / 2 heads rotated over the whole
+# head, the window layers by the plain table and the full ones by YaRN
+# (factor 4 over an original 16, betas 2 and 0.02: of 8 frequencies the
+# first is kept, four are blended and three divided by 4), so a 40-token
+# sequence wraps the window of 8 four times and leaves the original 16.
+MELLUM_PERIOD = "wEwEwE*E"
+
+_register(ModelConfig(
+    name="tiny-mellum2", vocab_size=512, hidden_size=64,
+    intermediate_size=32, num_layers=16, num_heads=4, num_kv_heads=2,
+    head_dim=16, max_seq_len=256, rope_theta=10000.0, rms_norm_eps=1e-6,
+    rope_scaling=RopeScaling(kind="yarn", factor=4.0,
+                             original_max_position=16, beta_fast=2.0,
+                             beta_slow=0.02),
+    hybrid_pattern=MELLUM_PERIOD * 2, sliding_window=8, num_experts=8,
+    num_experts_per_tok=2, moe_renormalize=True,
     bos_token_id=1, eos_token_ids=(2,),
 ))
 

@@ -12,6 +12,7 @@ Conventions:
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -35,14 +36,47 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (normed * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def yarn_ramp(s: RopeScaling, theta: float, d: int) -> tuple:
+    """(low, high) of YaRN's ramp over the ``d / 2`` frequency indices:
+    the indices whose frequency turns ``beta_fast`` / ``beta_slow`` times
+    over the original context, floor / ceil (the published ``truncate``),
+    clamped to [0, d - 1]."""
+    def turns(n: float) -> float:
+        return (d * math.log(s.original_max_position / (n * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(turns(s.beta_fast)), 0),
+            min(math.ceil(turns(s.beta_slow)), d - 1))
+
+
+def rope_table(config: ModelConfig, window: bool = False) -> tuple:
+    """(inverse frequencies [head_dim/2], the factor on cos and sin) of
+    one kind of layer: ``window`` layers rotate by the plain table,
+    the others by ``config.rope_scaling``'s rule (ModelConfig says why
+    a model has two)."""
+    s = None if window else config.rope_scaling
+    if s is None or s.kind != "yarn":
+        return rope_frequencies(config.with_(rope_scaling=s)), 1.0
+    d = config.head_dim
+    plain = rope_frequencies(config.with_(rope_scaling=None))
+    low, high = yarn_ramp(s, config.rope_theta, d)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return ((1.0 - ramp) * plain + ramp * plain / s.factor,
+            s.attention_factor or 0.1 * math.log(s.factor) + 1.0)
+
+
 def rope_frequencies(config: ModelConfig) -> jax.Array:
     """Inverse frequencies [head_dim/2], with llama3.1 NTK-by-parts scaling
-    applied when configured."""
+    applied when configured (a YaRN rule comes with a factor on cos and
+    sin: :func:`rope_table`)."""
     d = config.head_dim
     inv_freq = 1.0 / (config.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     s = config.rope_scaling
     if s is None:
         return inv_freq
+    if s.kind != "llama3":
+        raise ValueError(f"{config.name}: the {s.kind!r} rule has a factor "
+                         "on cos and sin beside its table: call rope_table")
     # llama3.1 scaling: low-frequency components are slowed by `factor`,
     # high-frequency kept, a smooth ramp in between.
     low_wavelen = s.original_max_position / s.low_freq_factor
@@ -58,16 +92,19 @@ def rope_frequencies(config: ModelConfig) -> jax.Array:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
-               inv_freq: jax.Array) -> jax.Array:
+               inv_freq: jax.Array, factor: float = 1.0) -> jax.Array:
     """Rotate pairs (x[..., :d/2], x[..., d/2:]) by position*freq.
 
     x: [..., seq, heads, head_dim]; positions: [..., seq] (broadcastable).
     Uses the half-split convention (HF llama's rotate_half), so HF
-    checkpoints work without permutation.
+    checkpoints work without permutation. ``factor`` multiplies cos and
+    sin (:func:`rope_table`'s second value).
     """
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # [..., S, d/2]
     cos = jnp.cos(angles)[..., :, None, :]   # [..., S, 1, d/2]
     sin = jnp.sin(angles)[..., :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -212,6 +249,11 @@ def flash_attend_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
 # threshold sits at 2^25 (128 MB of f32 scores) rather than the HBM-fit
 # bound it started as.
 _FLASH_SCORE_ELEMS = 2 ** 25
+# The flash scan's KV chunk (1024 measured ~6% faster than 512 on v5e at
+# long-prefill shapes: fewer scan steps, same VMEM fit), and so the
+# granule a caller cuts its keys to where it wants the scan to take them
+# (nemotron_h._attn_prefill).
+FLASH_KV_CHUNK = 1024
 
 
 def attend_gqa_causal0(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
@@ -260,17 +302,16 @@ def attend_gqa_auto(q: jax.Array, k: jax.Array, v: jax.Array,
     if (big and causal0_len is not None and causal0_len == Sq
             and on_tpu() and Sq % 512 == 0 and D % 128 == 0):
         return attend_gqa_causal0(q, k[:, :Sq], v[:, :Sq])
-    if big and Sq >= 256 and Skv >= 1024 and Skv % 512 == 0:
+    if big and Sq >= 256 and Skv >= FLASH_KV_CHUNK and Skv % 512 == 0:
         # Sq >= 256 keeps DECODE-side shapes (speculative verify: a few
         # query positions against a long window) off the flash scan,
         # whose repeat_kv-expanded chunks would pay rep-fold KV traffic
         # on a bandwidth-bound path; the dense attend materialises the
         # modest [B,G,rep,Sq,W] scores once instead.
-        # Chunk 1024 measured ~6% faster than 512 on v5e at long-prefill
-        # shapes (fewer scan steps, same VMEM fit); fall back to 512 when
-        # the KV length doesn't divide.
-        return flash_attend_gqa(q, k, v, mask,
-                                chunk=1024 if Skv % 1024 == 0 else 512)
+        # Falls back to half the chunk when the KV length doesn't divide.
+        return flash_attend_gqa(
+            q, k, v, mask, chunk=FLASH_KV_CHUNK if Skv % FLASH_KV_CHUNK == 0
+            else FLASH_KV_CHUNK // 2)
     return attend_gqa(q, k, v, mask)
 
 

@@ -47,8 +47,9 @@ class KVCache(NamedTuple):
     (A latent-attention model keeps one head: its normed latent in ``k``
     and its shared rotated key in ``v``, of different widths:
     ``ModelConfig.cache_*``. A hybrid model's ``L`` is its attention
-    layers alone, and ``state`` the recurrent layers' state, a row an
-    entry, zero at the start: ops/state_pool.StatePool. None otherwise.)"""
+    layers alone, and ``state`` the recurrent layers' state and the window
+    layers' rings, a row an entry, zero at the start:
+    ops/state_pool.StatePool. None otherwise.)"""
 
     k: jax.Array
     v: jax.Array
@@ -60,7 +61,7 @@ class KVCache(NamedTuple):
                dtype=DEFAULT_COMPUTE_DTYPE) -> "KVCache":
         lead = (config.cache_layers, batch, max_seq, config.cache_kv_heads)
         state = None
-        if config.ssm_layers:
+        if config.state_layers:
             from ..ops.state_pool import StatePool
             state = StatePool.create(config, batch, dtype)
         return cls(k=jnp.zeros(lead + (config.cache_k_dim,), dtype),

@@ -1,8 +1,10 @@
 """Hybrid decoders, walked by a pattern of layer kinds: the Nemotron-H
 block of NVIDIA-Nemotron-3-Super-120B-A12B (Mamba-2 / attention /
-LatentMoE) and the SambaY stack of Phi-4-mini-flash-reasoning (Mamba-1 /
+LatentMoE), the SambaY stack of Phi-4-mini-flash-reasoning (Mamba-1 /
 window attention / one full attention layer whose pages the upper half
-reads / gated memory units, each followed by a dense gated MLP).
+reads / gated memory units, each followed by a dense gated MLP) and the
+Mellum 2 stack (three window layers to one full layer, GQA rotated by
+two tables, each followed by a routed layer of thin experts).
 ``models.family_for`` picks this
 module for a configuration with a ``hybrid_pattern``; the functional
 surface is the other families' (init_params, prefill, prefill_chunk,
@@ -21,8 +23,20 @@ by the layer's letter in ``hybrid_pattern``:
   recurrence of ops/state_pool.py over a float32 state; ``y <- y + D x``;
   the gated norm, gate first: ``RMSNorm_grouped(y silu(z); G groups) w``;
   ``out = y W_out``.
-- ``*``, attention: GQA, no biases, causal, no rotary embedding
-  (``attn_rope`` False: position comes from the recurrent layers).
+- ``*``, attention: GQA, no biases, causal. ``attn_rope`` False: no
+  rotary embedding (position comes from the recurrent layers). True
+  (Mellum): q and k rotated over the whole head (rotate-half) by
+  ``rope_scaling``'s table, YaRN with its factor on cos and sin; the
+  pages hold rotated keys.
+- ``w`` without ``attn_diff`` (Mellum): the same GQA over the query's
+  own position and the ``sliding_window - 1`` before it, q and k
+  rotated by the PLAIN table (layers.rope_table: a model has two); K
+  and V live in a ring a row, rotated as they were written, ``[G, W,
+  D]`` a row with the KV heads apart.
+- ``E`` without ``moe_latent_size`` (Mellum): ``p = softmax(x W_r)`` in
+  float32 over all experts, the ``num_experts_per_tok`` largest kept
+  and divided by their sum (``moe_renormalize``); ``out = sum_e p_e
+  (silu(x Wg_e) * (x Wu_e)) Wd_e``. No shared expert.
 - ``E``, LatentMoE: ``s = sigmoid(x W_r)`` in float32 over ALL
   ``router_width`` experts; the top-k of ``s + router_bias`` chosen,
   weighed ``routed_scaling_factor x s / (sum of the chosen s + 1e-20)``;
@@ -57,7 +71,9 @@ positional encoding):
   W_mlp_down``. A published layer of this family is its mixer and ``-``.
 
 **Kinds of per-row past.** The ``*`` layers' K and V are pages
-(ops/paged_kv.py; ``ModelConfig.cache_layers`` of them); the ``M``
+(ops/paged_kv.py; ``ModelConfig.cache_layers`` of them, each read by
+its own layer, and by the ``x`` layers above it where there are any);
+the ``w`` layers' are rings in the state pool; the ``M``
 layers' state and convolution window are rows of a
 :class:`~..ops.state_pool.StatePool` that rides in the cache objects'
 ``state`` leaf: per entry in a prefill's ``KVCache`` (zero for a fresh
@@ -72,10 +88,13 @@ state is its unpadded run's.
 pattern: runs of equal groups of two or four letters between ``*``
 layers are one ``lax.scan`` each (:func:`_plan`), so a program holds a
 few layer bodies and not one a layer. ``*`` and ``Y`` publish to the
-layers above them and are never inside a scan.
+layers above them and stay outside those scans; where nothing reads
+what they publish and the pattern is a whole number of periods (Mellum),
+the periods are one scan around that walk (:func:`_rounds`).
 
 Single chip only; speculation and session parking would need the state
-rolled back or carried and are refused at boot (serve/scheduler.py).
+(or a ring) rolled back or carried and are refused at boot
+(serve/scheduler.py).
 """
 
 from __future__ import annotations
@@ -93,12 +112,13 @@ from ..ops.state_pool import StatePool
 from ..parallel.sharding import LogicalRules, DEFAULT_RULES
 from ..utils.device import pallas_interpret
 from .configs import ModelConfig
-from .layers import (DEFAULT_COMPUTE_DTYPE, attend_gqa_auto, causal_mask,
-                     rms_norm)
+from .layers import (DEFAULT_COMPUTE_DTYPE, FLASH_KV_CHUNK, NEG_INF,
+                     apply_rope, attend_gqa_auto, causal_mask, rms_norm,
+                     rope_table)
 from .llama import KVCache, _layer_view
-from .pangu import (STATS_WIDTH, _normal, _routed_local, no_stats,
+from .pangu import (STATS_WIDTH, _normal, _routed_local, no_stats, route,
                     streamed_stack)
-from .quant import mm
+from .quant import mm, q_einsum
 
 no_touched = no_stats
 __all__ = ["STATS_WIDTH", "no_stats", "no_touched"]
@@ -147,6 +167,24 @@ def _plan(pattern: str) -> tuple:
     return tuple(steps)
 
 
+@functools.cache
+def _rounds(pattern: str) -> tuple:
+    """(period, rounds): ``pattern`` as ``rounds`` copies of its shortest
+    period, where a whole period can be one scan's body: it holds a ``*``
+    (a pattern without one is scanned by its groups already) and nothing
+    in it hands a value up the trace (no ``Y``, ``x`` or ``g``). Then a
+    program holds one period's layer bodies and not every period's: for
+    Mellum's four a third of the HLO and half the time to compile, in
+    each of the 52 chunk programs a boot compiles (PERF.md section 6,
+    PR 40). (pattern, 1) otherwise."""
+    if "*" in pattern and not set(pattern) & set("Yxg"):
+        for size in range(2, len(pattern) // 2 + 1):
+            rounds, rest = divmod(len(pattern), size)
+            if not rest and pattern == pattern[:size] * rounds:
+                return pattern[:size], rounds
+    return pattern, 1
+
+
 # letter -> the layers it shares a per-row past with: a Mamba layer's row
 # in the state pool, a window layer's ring, a ``*`` layer's page layer.
 PASTS = {**{ch: ch for ch in TREES}, "Y": "1"}
@@ -188,7 +226,8 @@ def _dims(config: ModelConfig) -> dict:
                  "wo": (config.q_dim, H)},
         "moe": {"w_fc1": (H, Lw), "w_fc2": (Lw, H),
                 "w_up_s": (H, Fs), "w_down_s": (Fs, H),
-                "w_up_e": (NE, Lw, F), "w_down": (NE, F, Lw)},
+                "w_up_e": (NE, Lw, F), "w_down": (NE, F, Lw)} if Lw else
+               {"wgu_e": (NE, H, 2 * F), "w_down": (NE, F, H)},
         "mamba1": {"w_in": (H, 2 * d1), "w_x": (d1, R1 + 2 * N1),
                    "w_dt": (R1, d1), "w_out": (d1, H)},
         "cross": {"wq": (H, config.q_dim), "wo": (config.q_dim, H)},
@@ -290,9 +329,10 @@ def _small_leaves(config: ModelConfig, key: jax.Array, dtype) -> dict:
             # float32, as the published router is.
             "router": _normal(next(ks), (Le, H, config.router_width),
                               H ** -0.5, jnp.float32),
-            "router_bias": _uniform(next(ks), (Le, config.router_width),
-                                    -0.2, 0.2),
         }
+        if config.moe_selection_bias:
+            small["moe"]["router_bias"] = _uniform(
+                next(ks), (Le, config.router_width), -0.2, 0.2)
     ks = iter(jax.random.split(jax.random.fold_in(key, 1), 64))
     if "attn" in n and config.attn_diff:
         steps = [j for j, ch in enumerate(config.hybrid_pattern)
@@ -330,12 +370,21 @@ def _build(config: ModelConfig, key: jax.Array, dtype, stack, head,
            tied) -> dict:
     """The parameter tree both initialisers return (pangu._build)."""
     n = _counts(config)
-    if "moe" in n and (not config.moe_selection_bias
-                       or config.mlp_activation != "relu2"
-                       or not config.moe_latent_size):
+    latent_moe = (config.moe_selection_bias
+                  and config.mlp_activation == "relu2"
+                  and config.moe_latent_size)
+    plain_moe = not (config.moe_selection_bias or config.moe_latent_size
+                     or config.num_shared_experts
+                     or config.mlp_activation != "silu"
+                     or config.router_width != config.num_experts)
+    if "moe" in n and not (latent_moe or plain_moe):
         raise ValueError(f"{config.name}: the hybrid family's routed layer "
                          "is a LatentMoE (selection bias, relu2 experts in "
-                         "a latent)")
+                         "a latent) or a plain one (SwiGLU experts on the "
+                         "hidden state, all held, no shared expert)")
+    if config.attn_diff and config.attn_rope:
+        raise ValueError(f"{config.name}: differential attention is "
+                         "served without rotary embedding")
     if {"cross", "attn"} <= set(n) and not config.attn_diff:
         raise ValueError(f"{config.name}: cross layers read a "
                          "differential-attention layer's pages")
@@ -498,32 +547,50 @@ def _mamba_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
     return _mamba_out(y, x, z, lp, config, h.dtype)[:, None], pool
 
 
-def _qkv(h, lp, config: ModelConfig):
+def _qkv(h, lp, config: ModelConfig, positions=None, window: bool = False):
+    """q [B,S,Hq,D], k, v [B,S,Hkv,D] of a GQA layer. ``positions``
+    ([B|1,S]): where ``attn_rope``, q and k come back rotated by the
+    layer kind's table (``window``: the plain one)."""
     B, S, _ = h.shape
     qkv = mm(rms_norm(h, lp["norm"], config.rms_norm_eps), lp["wqkv"])
     Q, KV = config.q_dim, config.kv_dim
-    return (qkv[..., :Q].reshape(B, S, config.num_heads, config.head_dim),
-            qkv[..., Q: Q + KV].reshape(B, S, config.num_kv_heads,
-                                        config.head_dim),
-            qkv[..., Q + KV:].reshape(B, S, config.num_kv_heads,
-                                      config.head_dim))
+    q = qkv[..., :Q].reshape(B, S, config.num_heads, config.head_dim)
+    k = qkv[..., Q: Q + KV].reshape(B, S, config.num_kv_heads,
+                                    config.head_dim)
+    v = qkv[..., Q + KV:].reshape(B, S, config.num_kv_heads,
+                                  config.head_dim)
+    if config.attn_rope:
+        inv_freq, factor = rope_table(config, window)
+        q = apply_rope(q, positions, inv_freq, factor)
+        k = apply_rope(k, positions, inv_freq, factor)
+    return q, k, v
+
+
+def _chunk_positions(offset: int, S: int) -> jax.Array:
+    return (offset + jnp.arange(S, dtype=jnp.int32))[None, :]
 
 
 def _attn_prefill(h, lp, config: ModelConfig, ck, cv, layer: int,
                   offset: int):
     """llama._block's attention against the dense carry: the chunk's K
     and V land at slots offset..offset+S of cache layer ``layer`` and the
-    chunk attends the carry's whole width under the offset causal
-    mask."""
+    chunk attends the carry under the offset causal mask, as far as its
+    own last position: nothing lies past it yet, and the chunk ladder of
+    a 16 K bucket would score sixteen times what it keeps. The cut is to
+    the next whole chunk of the flash scan (layers.FLASH_KV_CHUNK), the
+    widths that scan takes; a carry no wider than one is read whole, as
+    it always was."""
     B, S, _ = h.shape
-    q, k, v = _qkv(h, lp, config)
+    q, k, v = _qkv(h, lp, config, _chunk_positions(offset, S))
     zero = jnp.zeros((), jnp.int32)
     at = (jnp.asarray(layer, jnp.int32), zero,
           jnp.asarray(offset, jnp.int32), zero, zero)
     ck = jax.lax.dynamic_update_slice(ck, k[None].astype(ck.dtype), at)
     cv = jax.lax.dynamic_update_slice(cv, v[None].astype(cv.dtype), at)
-    attn = attend_gqa_auto(q, ck[layer], cv[layer],
-                           causal_mask(S, ck.shape[2], offset),
+    T = min(ck.shape[2],
+            -(-(offset + S) // FLASH_KV_CHUNK) * FLASH_KV_CHUNK)
+    attn = attend_gqa_auto(q, ck[layer][:, :T], cv[layer][:, :T],
+                           causal_mask(S, T, offset),
                            causal0_len=S if offset == 0 else None)
     return mm(attn.reshape(B, S, config.q_dim), lp["wo"]), ck, cv
 
@@ -531,11 +598,69 @@ def _attn_prefill(h, lp, config: ModelConfig, ck, cv, layer: int,
 def _attn_decode(h, lp, config: ModelConfig, cache, layer: int, pages: int):
     from ..ops.paged_attention import paged_attention_append
     B = h.shape[0]
-    q, k, v = _qkv(h, lp, config)
+    q, k, v = _qkv(h, lp, config, cache.lengths[:, None])
     attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
                                   cache.lengths, layer, pages=pages,
                                   interpret=pallas_interpret())
     return mm(attn.reshape(B, 1, config.q_dim), lp["wo"]), k[:, 0], v[:, 0]
+
+
+def _gqa_window_prefill(h, lp, config: ModelConfig, state: StatePool, layer,
+                        offset: int, valid: jax.Array):
+    """:func:`_window_prefill` in the plain GQA form: the chunk's rotated
+    queries over the carried ring (rotated when it was written) and the
+    chunk's own rotated keys, one softmax over both."""
+    B, S, _ = h.shape
+    W = config.sliding_window
+    q, k, v = _qkv(h, lp, config, _chunk_positions(offset, S), window=True)
+    rk = jax.lax.dynamic_index_in_dim(state.win_k, layer, 0, False)
+    rv = jax.lax.dynamic_index_in_dim(state.win_v, layer, 0, False)
+    in_ring, in_chunk = state_pool.ring_chunk_masks(S, W, offset)
+    keys, vals, mask = k, v, in_chunk
+    if offset:      # a fresh prompt's ring is empty
+        keys = jnp.concatenate([jnp.swapaxes(rk, 1, 2).astype(k.dtype), k], 1)
+        vals = jnp.concatenate([jnp.swapaxes(rv, 1, 2).astype(v.dtype), v], 1)
+        mask = jnp.concatenate([in_ring, in_chunk], axis=1)
+    attn = attend_gqa_auto(q, keys, vals, mask[None, None],
+                           causal0_len=S if not offset and S <= W else None)
+    lengths = jnp.sum(valid, axis=1)
+    state = state._replace(
+        win_k=jax.lax.dynamic_update_index_in_dim(
+            state.win_k, state_pool.ring_after_chunk(rk, k, offset, lengths),
+            layer, 0),
+        win_v=jax.lax.dynamic_update_index_in_dim(
+            state.win_v, state_pool.ring_after_chunk(rv, v, offset, lengths),
+            layer, 0))
+    return mm(attn.reshape(B, S, config.q_dim), lp["wo"]), state
+
+
+def _gqa_window_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
+                       live: jax.Array, lengths: jax.Array):
+    """:func:`_window_decode` in the plain GQA form: query head ``j``
+    reads KV head ``j // rep`` of the ring where it lies, [B,G,W,D]; an
+    int8 ring's scales fold outside the dots, as ops/paged_attention's
+    do."""
+    f32 = jnp.float32
+    B, W, D = h.shape[0], config.sliding_window, config.head_dim
+    G = config.num_kv_heads
+    q, k, v = _qkv(h, lp, config, lengths[:, None], window=True)
+    pool = state_pool.ring_decode_write(pool, layer, live, lengths, k[:, 0],
+                                        v[:, 0])
+    rk, rv, ks, vs = state_pool.ring_read(pool, layer, B)
+    qg = q[:, 0].reshape(B, G, config.num_heads // G, D)
+    sc = jnp.einsum("bgrd,bgwd->bgrw", qg, rk.astype(qg.dtype),
+                    preferred_element_type=f32) / jnp.sqrt(D).astype(f32)
+    if ks is not None:
+        sc = sc * ks[:, :, None, :]
+    seen = jnp.arange(W)[None, :] <= lengths[:, None]
+    probs = jax.nn.softmax(jnp.where(seen[:, None, None, :], sc, NEG_INF),
+                           axis=-1)
+    if vs is not None:
+        probs = probs * vs[:, :, None, :]
+    attn = jnp.einsum("bgrw,bgwd->bgrd", probs.astype(qg.dtype),
+                      rv.astype(qg.dtype), preferred_element_type=f32)
+    return mm(attn.astype(h.dtype).reshape(B, 1, config.q_dim),
+              lp["wo"]), pool
 
 
 # -- the SambaY kinds ---------------------------------------------------------
@@ -653,6 +778,9 @@ def _window_prefill(h, lp, config: ModelConfig, state: StatePool, layer,
     window layers): the queries read the carried ring and the chunk's
     own keys, and the ring comes back holding each row's last real
     positions. Returns (out [B,S,H], state)."""
+    if not config.attn_diff:
+        return _gqa_window_prefill(h, lp, config, state, layer, offset,
+                                   valid)
     S = h.shape[1]
     q, k, v = _diff_qkv(h, lp, config)
     rk = jax.lax.dynamic_index_in_dim(state.win_k, layer, 0, False)
@@ -681,6 +809,8 @@ def _window_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
     """One step of window layer ``layer``: the token's K and V go into
     slot ``lengths mod W`` of each live row's ring, then the query reads
     the ring's first ``min(lengths + 1, W)`` slots."""
+    if not config.attn_diff:
+        return _gqa_window_decode(h, lp, config, pool, layer, live, lengths)
     B, W = h.shape[0], config.sliding_window
     q, k, v = _diff_qkv(h, lp, config)
     pool = state_pool.ring_decode_write(
@@ -731,9 +861,89 @@ def _relu2_mlp(x, w_up, w_down):
     return mm(jnp.square(jax.nn.relu(mm(x, w_up))), w_down)
 
 
+def _tile_rows(pairs: int, experts: int) -> int:
+    """Rows a tile of :func:`_routed_tiles`: the even share of an expert,
+    a power of two of 8 to 128 (a tile is one MXU pass of one expert's
+    weights; smaller tiles pad less and fetch the weights more often)."""
+    rows = 8
+    while rows < 128 and rows * 2 * experts <= pairs:
+        rows *= 2
+    return rows
+
+
+def _routed_tiles(x: jax.Array, lp: dict, config: ModelConfig,
+                  counted: Optional[jax.Array]):
+    """A prefill's routed sum over experts that are all held here,
+    dropless without the buckets: the chunk's (token, expert) pairs laid
+    out SORTED by expert, each expert's run padded to whole tiles of
+    ``tm`` rows, so that the experts' matmuls run over ``pairs +
+    experts x tm`` rows at the most where pangu._routed_local's all-T
+    buckets run ``experts x T`` (eight times what 64 experts top-8
+    need, and under random weights a 64-way router overflows the small
+    buckets in nearly every chunk: PERF.md section 6, PR 40). A tile is
+    one expert's: the expert-stripe kernel walks tiles and reads each
+    tile's expert (quant.q_einsum's ``source``). Rows come to their
+    tiles by ONE gather and go back by one (no row is scattered).
+    ``counted`` ([B,S] bool): the real prompt positions; padding is sent
+    nowhere and gets 0. Returns pangu._routed_local's (out [B,S,H],
+    stats) for a prefill."""
+    B, S, H = x.shape
+    NE, k = config.num_experts, config.num_experts_per_tok
+    T = B * S
+    P = T * k
+    tm = _tile_rows(P, NE)
+    NT = -(-P // tm) + NE               # tiles: every run's last is part full
+    xt = x.reshape(T, H)
+    top_w, top_i = route(xt, lp["router"], config)
+    takes = jnp.ones((T, k), bool) if counted is None else \
+        jnp.broadcast_to(counted.reshape(T, 1), (T, k))
+    expert = jnp.where(takes, top_i, NE).reshape(P)      # NE: sent nowhere
+    flat = jax.nn.one_hot(expert, NE, dtype=jnp.int32)   # [P, NE]
+    sent = jnp.sum(flat, axis=0)                         # [NE]
+    slot = jnp.sum(flat * (jnp.cumsum(flat, axis=0) - flat), axis=-1)
+    tiles = -(-sent // tm)
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    run_start = jnp.cumsum(sent) - sent                  # in sorted order
+    order = jnp.argsort(expert, stable=True)             # pairs by expert
+    # Of every tile: its expert, and how many of its rows are filled.
+    t = jnp.arange(NT, dtype=jnp.int32)
+    used = t < tile_end[-1]
+    # (a count of the runs that end at or before it: no search loop)
+    source = jnp.minimum(jnp.sum(tile_end[None, :] <= t[:, None], axis=1),
+                         NE - 1).astype(jnp.int32)
+    source = jnp.where(used, source, source[jnp.maximum(tile_end[-1] - 1,
+                                                        0)])
+    first = (t - tile_start[source]) * tm                # rank of its row 0
+    count = jnp.where(used, jnp.clip(sent[source] - first, 0, tm), 0)
+    # Of every row of the layout: the pair it holds.
+    rank = first[:, None] + jnp.arange(tm, dtype=jnp.int32)[None, :]
+    held = used[:, None] & (rank < sent[source][:, None])
+    pair = order[jnp.clip(run_start[source][:, None] + rank, 0, P - 1)]
+    xin = jnp.where(held[..., None], xt[pair // k], 0).astype(xt.dtype)
+    gu = q_einsum("ech,ehf->ecf", xin, lp["wgu_e"], count, source)
+    F = gu.shape[-1] // 2
+    y = q_einsum("ecf,efh->ech", jax.nn.silu(gu[..., :F]) * gu[..., F:],
+                 lp["w_down"], count, source)
+    # Back to the tokens: pair p sits at its expert's run, its slot on.
+    at = jnp.where(expert < NE,
+                   tile_start[jnp.minimum(expert, NE - 1)] * tm + slot, 0)
+    got = y.reshape(NT * tm, H)[at].reshape(T, k, H)
+    out = jnp.sum(got.astype(jnp.float32)
+                  * jnp.where(takes, top_w, 0.0)[..., None], axis=1)
+    n_real = jnp.asarray(T) if counted is None else jnp.sum(counted)
+    stats = jnp.stack([jnp.sum(takes), jnp.asarray(0), n_real * k,
+                       jnp.asarray(0)])
+    return out.astype(x.dtype).reshape(B, S, H), stats.astype(jnp.int32)
+
+
 def _moe(h, lp, config: ModelConfig, counted, live):
     """(out [B,S,H], stats int32 [4])."""
     x = rms_norm(h, lp["norm"], config.rms_norm_eps)
+    if not config.moe_latent_size:
+        if live is None:
+            return _routed_tiles(x, lp, config, counted)
+        return _routed_local(x, lp, config, counted, live)
     latent = mm(x, lp["w_fc1"])
     routed, stats = _routed_local(x, lp, config, counted, live, latent)
     return (mm(routed, lp["w_fc2"])
@@ -749,9 +959,10 @@ def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
     layer's view of its tree and ``k`` its index among the layers that
     share its per-row past: a Mamba layer's in the state pool, a window
     layer's among the rings, a ``*`` layer's among the page layers (a
-    tracer inside a scan; ``*`` and ``Y`` are never scanned and get a
-    Python int). ``E`` and ``-`` keep nothing and are run here. Returns
-    (h, carry, stats)."""
+    tracer inside a scan; ``*`` is scanned only with its whole period,
+    :func:`_rounds`, ``Y`` never, and they get a Python int otherwise).
+    ``E`` and ``-`` keep nothing and are run here. Returns (h, carry,
+    stats)."""
     def moe_step(h, lp, k, carry, stats):
         out, st = _moe(h, lp, config, counted, live)
         return h + out, carry, stats + st
@@ -772,29 +983,50 @@ def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
         return steps[ch](h, _layer_view(params[TREES[ch]], idx), k, carry,
                          stats)
 
-    stats = no_stats()
-    for letters, n, at in _plan(config.hybrid_pattern):
-        if n == 1:
-            idx, _ = _index(at, letters, 0, TREES)
-            k, _ = _index(at, letters, 0, PASTS)
-            if letters not in "*Y":
-                k = jnp.asarray(k, jnp.int32)
-            h, carry, stats = run(letters, h, jnp.asarray(idx, jnp.int32),
-                                  k, carry, stats)
-        else:
-            def body(state, i, letters=letters, at=at):
-                h, carry, stats = state
-                for j, ch in enumerate(letters):
-                    (t0, tn), (k0, kn) = (_index(at, letters, j, TREES),
-                                          _index(at, letters, j, PASTS))
-                    h, carry, stats = run(
-                        ch, h, i + t0 if tn == 1 else i * tn + t0,
-                        i + k0 if kn == 1 else i * kn + k0, carry, stats)
-                return (h, carry, stats), None
+    period, rounds = _rounds(config.hybrid_pattern)
 
-            (h, carry, stats), _ = jax.lax.scan(
-                body, (h, carry, stats), jnp.arange(n, dtype=jnp.int32))
-    return h, carry, stats
+    def walk(state, r):
+        """One period, the ``r``-th (a tracer inside the scan over
+        periods; None where the pattern is its own period)."""
+        def place(x, ch, group):
+            if r is None:
+                return x
+            return x + r * sum(group[c] == group[ch] for c in period)
+
+        h, carry, stats = state
+        for letters, n, at in _plan(period):
+            if n == 1:
+                idx, _ = _index(at, letters, 0, TREES)
+                k, _ = _index(at, letters, 0, PASTS)
+                if letters not in "*Y":
+                    k = jnp.asarray(k, jnp.int32)
+                h, carry, stats = run(
+                    letters, h, place(jnp.asarray(idx, jnp.int32), letters,
+                                      TREES), place(k, letters, PASTS),
+                    carry, stats)
+            else:
+                def body(state, i, letters=letters, at=at):
+                    h, carry, stats = state
+                    for j, ch in enumerate(letters):
+                        (t0, tn), (k0, kn) = (_index(at, letters, j, TREES),
+                                              _index(at, letters, j, PASTS))
+                        h, carry, stats = run(
+                            ch, h,
+                            place(i + t0 if tn == 1 else i * tn + t0, ch,
+                                  TREES),
+                            place(i + k0 if kn == 1 else i * kn + k0, ch,
+                                  PASTS), carry, stats)
+                    return (h, carry, stats), None
+
+                (h, carry, stats), _ = jax.lax.scan(
+                    body, (h, carry, stats), jnp.arange(n, dtype=jnp.int32))
+        return h, carry, stats
+
+    state = (h, carry, no_stats())
+    if rounds == 1:
+        return walk(state, None)
+    return jax.lax.scan(lambda state, r: (walk(state, r), None), state,
+                        jnp.arange(rounds, dtype=jnp.int32))[0]
 
 
 def _logits(params, config, h, last_idx):
@@ -994,6 +1226,8 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
     B = tokens.shape[0]
     h = params["embed"][tokens]
     live = jnp.ones((B,), bool) if active is None else active
+    # The ``*`` layers inside the scan over periods (_rounds).
+    scanned = _rounds(config.hybrid_pattern)[1] > 1
 
     published: dict = {}
 
@@ -1019,6 +1253,11 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
         pool, kv = carry
         if not config.attn_diff:
             out, k, v = _attn_decode(h, lp, config, cache, layer, pages)
+            if scanned:     # a scan's carry cannot grow: one slot a layer
+                kv = tuple(jax.lax.dynamic_update_index_in_dim(
+                    a, x.astype(a.dtype), layer, 0)
+                    for a, x in zip(kv, (k, v)))
+                return out, (pool, kv)
             return out, (pool, kv + ((k, v),))
         q, k, v = _diff_qkv(h, lp, config)
         k, v = _rows(k[:, 0], config), _rows(v[:, 0], config)
@@ -1034,10 +1273,14 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
            "Y": functools.partial(mamba1, publish=True),
            "g": lambda h, lp, _, carry: (_gmu(h, lp, config,
                                               published["m"]), carry)}
+    kv = ()
+    if scanned:
+        kv = (jnp.zeros((config.cache_layers, B, config.num_kv_heads,
+                         config.head_dim), h.dtype),) * 2
     h, (pool, kv), stats = _run_stack(params, config, h, ops, None, live,
-                                      (cache.state, ()))
-    k_all = jnp.stack([k for k, _ in kv])
-    v_all = jnp.stack([v for _, v in kv])
+                                      (cache.state, kv))
+    k_all, v_all = kv if scanned else (jnp.stack([k for k, _ in kv]),
+                                       jnp.stack([v for _, v in kv]))
     cache = write_decode_burst(cache._replace(state=pool), k_all, v_all,
                                live.astype(jnp.int32))
     return _logits(params, config, h, None), cache, stats

@@ -323,7 +323,8 @@ def mm(x: jax.Array, w) -> jax.Array:
 _EXPERT_MM_SPECS = frozenset({"ech,ehf->ecf", "ecf,efh->ech"})
 
 
-def q_einsum(spec: str, x: jax.Array, w, count=None) -> jax.Array:
+def q_einsum(spec: str, x: jax.Array, w, count=None,
+             source=None) -> jax.Array:
     """``einsum(spec, x, w)`` for plain or quantized ``w``. The spec's
     contraction over ``w`` must be its -2 axis (the quantize() axis) and
     the output must end with ``w``'s out axis — true for every expert
@@ -340,7 +341,12 @@ def q_einsum(spec: str, x: jax.Array, w, count=None) -> jax.Array:
     ``count`` ([NE] int32, the filled slots of each expert's bucket
     ``x[e]``; None = unknown): handed to the expert-stripe kernels, which
     read no weights for an expert whose count is 0. Every XLA path
-    ignores it: an empty bucket's zeros give zeros there at full cost."""
+    ignores it: an empty bucket's zeros give zeros there at full cost.
+
+    ``source`` ([x.shape[0]] int32; None = bucket e reads expert e): the
+    expert each bucket of ``x`` reads, where the buckets are tiles of a
+    row-sorted dispatch (ops/quant_mm.quant_matmul_experts_stacked says
+    how the kernel takes it; the XLA paths gather the tiles' weights)."""
     if isinstance(w, LayerSlice):
         inner, layer = w.w, w.layer
         if not isinstance(inner, (QTensor, QTensor4)):
@@ -353,9 +359,9 @@ def q_einsum(spec: str, x: jax.Array, w, count=None) -> jax.Array:
                 from ..ops.quant_mm import (pick_expert_bo,
                                             quant_matmul_experts_stacked)
                 if pick_expert_bo(C, H, O, x.dtype.itemsize):
-                    return quant_matmul_experts_stacked(x, inner.q, inner.s,
-                                                        layer, count)
-            else:
+                    return quant_matmul_experts_stacked(
+                        x, inner.q, inner.s, layer, count, source=source)
+            elif source is None:    # the int4 kernel walks experts only
                 from ..ops.quant_mm import (pick_int4_bo,
                                             quant_matmul_experts_stacked4)
                 if pick_int4_bo(C, H, O, inner.s.shape[-2],
@@ -366,7 +372,9 @@ def q_einsum(spec: str, x: jax.Array, w, count=None) -> jax.Array:
         inner = type(inner)(
             q=jax.lax.dynamic_index_in_dim(inner.q, layer, 0, False),
             s=jax.lax.dynamic_index_in_dim(inner.s, layer, 0, False))
-        return q_einsum(spec, x, inner)
+        return q_einsum(spec, x, inner, source=source)
+    if source is not None:
+        w = jax.tree.map(lambda a: a[source], w)
     if isinstance(w, QTensor):
         y = jnp.einsum(spec, x, w.q.astype(x.dtype))
         return y * w.s.astype(x.dtype)       # s: [..., 1, out] broadcasts

@@ -151,7 +151,7 @@ class PagedKVCache(NamedTuple):
                 page_table=jnp.zeros((batch, max_pages_per_row), jnp.int32),
                 lengths=jnp.zeros((batch,), jnp.int32),
             )
-        if config.ssm_layers:
+        if config.state_layers:
             from .state_pool import StatePool
             cache = cache._replace(
                 state=StatePool.create(config, batch + 1, dtype,
@@ -390,9 +390,21 @@ def _tile_scatter(cache: PagedKVCache, chunk_k: jax.Array,
             x = jnp.pad(x, pad)
         return x.reshape(L, R * P, ps_eff, *x.shape[3:])
 
-    return _scatter_kv(cache, chunk_k, chunk_v,
-                       lambda arr, upd: arr.at[:, phys, :ps_eff].set(
-                           tiles(upd), mode="drop"),
+    def values(arr, upd):
+        if cache.quantized and arr.shape[3:] == (4, 128):
+            # An int8 pool of FOUR KV heads x 128: the TPU compiler packs
+            # a token's four heads into one sublane row, takes the whole
+            # pool through a token-minor layout for a page-window scatter
+            # and copies it back (two 1 GB copies of each of K and V a
+            # write in Mellum's cell: PERF.md section 6, PR 40; 2, 8 and
+            # 16 heads x 128 are written where they lie). The same tiles
+            # indexed (page, slot) a token keep the pool's layout, as
+            # write_prefill_chunk's mid-page path does.
+            return arr.at[:, phys[:, None], jnp.arange(ps_eff)[None, :]].set(
+                tiles(upd), mode="drop")
+        return arr.at[:, phys, :ps_eff].set(tiles(upd), mode="drop")
+
+    return _scatter_kv(cache, chunk_k, chunk_v, values,
                        lambda arr, upd: arr.at[:, phys, :, :ps_eff].set(
                            tiles(upd).transpose(0, 1, 3, 2), mode="drop"))
 

@@ -579,7 +579,8 @@ def _expert_weight_map(NE: int, stripes: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
                                  layer: jax.Array,
-                                 count: jax.Array | None = None, *,
+                                 count: jax.Array | None = None,
+                                 source: jax.Array | None = None, *,
                                  interpret: bool = False) -> jax.Array:
     """Batched per-expert ``x[e] @ dequant(q[layer, e], s[layer, e])``
     reading the 4-D expert pool directly — the MoE twin of
@@ -598,6 +599,13 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
     are not read (:func:`_expert_weight_map`) and its output is zeros.
     Decode at a part-full batch is where that pays: the step's time is
     the experts' bytes, and 2 live rows x top-2 reach at most 4 of 8.
+
+    ``source`` ([NE] int32, None = bucket e reads expert e): the expert
+    whose weights each bucket reads, for buckets that are TILES of a
+    row-sorted dispatch, several of them one expert's
+    (models/nemotron_h._routed_tiles): ``x`` is then [tiles, rows a tile,
+    H] and the grid walks tiles. A tile whose count is 0 must name the
+    expert of the last filled tile before it.
     """
     NE, C, H = x.shape
     O = q.shape[-1]
@@ -611,6 +619,12 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
     cp = C + pad
     ly = jnp.asarray(layer, jnp.int32).reshape(1)
+    if source is None:
+        route = _expert_route(count, NE)
+    else:
+        filled = jnp.ones((NE,), jnp.int32) if count is None else count
+        route = jnp.concatenate([filled.astype(jnp.int32),
+                                 source.astype(jnp.int32)])
     weight_map = _expert_weight_map(NE, O // bo)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -629,7 +643,7 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NE, cp, O), x.dtype),
         interpret=interpret,
-    )(ly, _expert_route(count, NE), x, q, s)
+    )(ly, route, x, q, s)
     return out[:, :C] if pad else out
 
 

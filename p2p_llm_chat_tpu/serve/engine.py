@@ -328,20 +328,20 @@ class TPUEngine:
         the prefix cache is off (the front answers 501), or when the
         entries cannot travel (below), so that no router asks."""
         store = self.scheduler._prefix
-        if store is None or self.config.ssm_layers:
+        if store is None or self.config.state_layers:
             return None
         return store.hashes()
 
     def _refuse_prefix_wire(self) -> None:
         """The wire format carries K and V alone. An entry of a model
-        with recurrent state is its pages AND a state snapshot; one
-        without the other would serve wrong answers, so this family's
-        entries neither leave nor enter."""
-        if self.config.ssm_layers:
+        with recurrent state or window rings is its pages AND a state
+        snapshot; one without the other would serve wrong answers, so
+        this family's entries neither leave nor enter."""
+        if self.config.state_layers:
             raise ValueError(
-                f"{self.config.name} keeps recurrent state beside its "
-                f"pages ({self.config.ssm_layers} Mamba layers): its prefix "
-                "entries are not exported or imported over /admin/prefix")
+                f"{self.config.name} keeps {self.config.state_kinds} beside "
+                "its pages: its prefix entries are not exported or imported "
+                "over /admin/prefix")
 
     def prefix_export(self, h: str):
         self._refuse_prefix_wire()

@@ -659,22 +659,21 @@ class BatchScheduler:
                         f"served under {what}")
         if config.is_hybrid:
             # Paths that assume "a row's past is its pages" refuse a
-            # model with recurrent state here, by name: each would have
-            # to carry the state or roll it back.
+            # model with recurrent state or window rings here, by name:
+            # each would have to carry the state or roll it back.
             for on, what in (
                     (mesh is not None, "a mesh (the state pool is not "
                      "laid out over one)"),
                     (bool(spec_k) or drafter is not None, "speculative "
-                     "decoding (a rejected draft would have to roll the "
-                     "recurrent state back)"),
+                     "decoding (a rejected draft would have to roll them "
+                     "back)"),
                     (bool(kv_host_gb) and kv_host_gb > 0, "session "
                      "parking (SERVE_KV_HOST_GB: park and wake move pages "
-                     "and would leave the recurrent state behind)")):
+                     "and would leave them behind)")):
                 if on:
                     raise ValueError(
-                        f"{config.name} keeps recurrent state beside its "
-                        f"pages ({config.ssm_layers} Mamba layers) and is "
-                        f"not served under {what}")
+                        f"{config.name} keeps {config.state_kinds} "
+                        f"beside its pages and is not served under {what}")
         # Where an admission's packed buffer goes (_admit_upload): every
         # device of a mesh, committed; else the default device.
         self._packed_sharding = (
@@ -826,6 +825,13 @@ class BatchScheduler:
             config.hybrid_pattern.count("*")
             + config.hybrid_pattern.count("x")
             if "x" in config.hybrid_pattern else 0)
+        # Page layers beside rings, each read by its own layer alone (a
+        # model whose window layers keep no pages): live rows x context
+        # x bytes a token x those layers.
+        self._n_page_kv_bytes = 0        # owned-by: _loop
+        self._page_kv_layers = (
+            config.cache_layers
+            if config.window_layers and not self._shared_kv_readers else 0)
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -2380,7 +2386,7 @@ class BatchScheduler:
         c = self.config
         lead = (c.cache_layers, P, c.cache_kv_heads)
         state = (snapshot(StatePool.create(c, 1, self._dtype))
-                 if c.ssm_layers else None)
+                 if c.state_layers else None)
         return PrefixEntry(ids=tuple(range(P)),
                            k=jnp.zeros(lead + (c.cache_k_dim,), self._dtype),
                            v=jnp.zeros(lead + (c.cache_v_dim,), self._dtype),
@@ -3685,6 +3691,8 @@ class BatchScheduler:
             out["serve_window_bytes_total"] = self._n_window_bytes
         if self._shared_kv_readers:
             out["serve_shared_kv_bytes_total"] = self._n_shared_kv_bytes
+        if self._page_kv_layers:
+            out["serve_page_kv_bytes_total"] = self._n_page_kv_bytes
         if self.spec_k:
             out["serve_spec_accepted_total"] = self._n_spec_accepted
             # Back-compat aggregate: the most optimistic source (the
@@ -3829,8 +3837,8 @@ class BatchScheduler:
                  cache.quantized else "", c.cache_layers, per_layer * item,
                  self.num_pages, self.page_size, self.num_slots,
                  cache.max_pages_per_row, total / 1e9)
-        if cache.state is not None:
-            st = cache.state
+        st = cache.state
+        if st is not None and st.ssm.shape[0]:
             log.info("state pool: %d Mamba layers x %d rows (%d slots and "
                      "a garbage row) x (%s float32 state + %s %s window); "
                      "%.3f MB a row, %.3f GB",
@@ -3839,15 +3847,15 @@ class BatchScheduler:
                      "x".join(map(str, st.conv.shape[2:])),
                      st.conv.dtype.name, st.row_bytes / 1e6,
                      (st.nbytes - st.ring_nbytes) / 1e9)
-            if st.win_k is not None:
-                log.info("window rings: %d layers x %d rows x %d positions "
-                         "x %dx%d %s%s; %d bytes a position a layer, %.3f GB",
-                         st.win_k.shape[0], st.rows, st.win_k.shape[3],
-                         st.win_k.shape[2], st.win_k.shape[4],
-                         st.win_k.dtype.name,
-                         ", a float32 scale a position a head for each"
-                         if st.win_ks is not None else "",
-                         st.ring_position_bytes, st.ring_nbytes / 1e9)
+        if st is not None and st.win_k is not None:
+            log.info("window rings: %d layers x %d rows x %d positions "
+                     "x %dx%d %s%s; %d bytes a position a layer, %.3f GB",
+                     st.win_k.shape[0], st.rows, st.win_k.shape[3],
+                     st.win_k.shape[2], st.win_k.shape[4],
+                     st.win_k.dtype.name,
+                     ", a float32 scale a position a head for each"
+                     if st.win_ks is not None else "",
+                     st.ring_position_bytes, st.ring_nbytes / 1e9)
 
     @staticmethod
     def _flash_min_w(config, mesh, kv_quant: bool = False) -> int:
@@ -4593,6 +4601,8 @@ class BatchScheduler:
                       for j in range(K)))
         self._n_shared_kv_bytes += (self._shared_kv_readers * ctx_tokens
                                     * self._page_token_bytes)
+        self._n_page_kv_bytes += (self._page_kv_layers * ctx_tokens
+                                  * self._page_token_bytes)
         if active != self._active_host:
             # Re-upload the mask only when the active set changed (it only
             # moves on admission/finish — not per tick).

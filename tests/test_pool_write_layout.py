@@ -108,3 +108,42 @@ def test_unaligned_chunk_splice_updates_the_scales_in_place(
 
     _assert_in_place(jax.jit(splice, donate_argnums=(0,)).lower(
         pool, kv, kv, tables).compile())
+
+
+@pytest.mark.parametrize("heads", [4, 8])
+def test_a_first_chunks_page_tiles_leave_the_values_where_they_lie(
+        heads, described, no_cache):
+    """The page-ALIGNED splice (a ladder's first chunk, with the 88-token
+    head in front; every single-shot admission) at the pool shape of
+    ``mellum2-12b-a2.5b-instruct-l16``, four KV heads x 128 in int8: around
+    a page-window scatter the compiler takes such a pool through a
+    token-minor layout and back, two copies of each of K and V and a
+    gigabyte of temporaries a write at the cell's 8,193 pages (PERF.md
+    §6, PR 40), so ``_tile_scatter`` indexes it a token at a time. Eight
+    heads, the other cells' shape, are written where they lie by the
+    window scatter."""
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,
+                                               write_prefill_chunk)
+    layers = 4
+    pool = PagedKVCache(
+        k=described((layers, PAGES, PS, heads, D), jnp.int8),
+        v=described((layers, PAGES, PS, heads, D), jnp.int8),
+        page_table=described((ROWS, PER_ROW), jnp.int32),
+        lengths=described((ROWS,), jnp.int32),
+        k_scale=described((layers, PAGES, heads, 128), jnp.float32),
+        v_scale=described((layers, PAGES, heads, 128), jnp.float32))
+    kv = described((layers, 1, 88 + 1024, heads, D), jnp.bfloat16)
+    tables = described((1, PER_ROW), jnp.int32)
+
+    def splice(cache, k, v, tables):
+        return write_prefill_chunk(cache, k, v, tables, 0)
+
+    compiled = jax.jit(splice, donate_argnums=(0,)).lower(
+        pool, kv, kv, tables).compile()
+    values = rf"s8\[{layers},{PAGES},{PS},{heads},{D}\]"
+    copies = [line.strip()[:200] for line in compiled.as_text().splitlines()
+              if re.search(rf"= {values}\S* copy\(", line)]
+    assert not copies, "the pool is copied whole:\n" + "\n".join(copies)
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT

@@ -58,9 +58,10 @@ def pool_shapes(cfg: dict) -> dict:
             f"{c.conv_dim}]"] = "window"
     if c.window_layers:
         lead = f"{c.window_layers},{slots + 1}"
-        out[f"{kv}[{lead},{c.sliding_window},{c.num_kv_heads},"
-            f"{c.head_dim}]"] = "ring k/v"
-        out[f"f32[{lead},{c.num_kv_heads},{c.sliding_window}]"] = \
+        # ops/state_pool.StatePool's layout: the head before the position.
+        out[f"{kv}[{lead},{c.cache_kv_heads},{c.sliding_window},"
+            f"{c.cache_k_dim}]"] = "ring k/v"
+        out[f"f32[{lead},{c.cache_kv_heads},{c.sliding_window}]"] = \
             "ring scales"
     return out
 
