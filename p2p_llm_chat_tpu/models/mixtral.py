@@ -20,6 +20,19 @@ TPU-first choices:
   ``factor * T * k / NE`` (the standard GShard-style capacity): overflow
   tokens lose only their MLP contribution (residual carries them), and
   bucket memory stays ~``factor/NE``-proportional instead of NE-fold.
+- **A dropless prefill on one device computes the pairs it routed**
+  (:func:`moe_mlp_counted`, the form every admission program runs: C = T
+  buckets would multiply ``NE x T`` rows for ``T x k`` pairs, eight times
+  what OLMoE's 64 experts top-8 route): its real positions' pairs go,
+  sorted by expert, into tiles (:func:`_moe_tiles` over
+  models/moe_tiles.py, the dispatch Mellum's routed layers share), and
+  padding is sent nowhere. What chooses the path is what the call
+  carries (the mask of real positions, no capacity, no mesh, S > 1), so
+  a capacity-factor model, the decode step and a sharded model keep the
+  buckets, and so does the maskless :func:`moe_mlp` whatever it
+  carries: a verify and a session wake pass no capacity for EVERY
+  model of the family (their bucket is exact by design), and eight
+  experts of 176 MB read twice by a second tile are no gain.
 - **Expert parallelism** via the ``"experts": ("ep","tp")`` logical rule
   (parallel/sharding.py): expert-stacked weights and the ``[NE, C, H]``
   buckets shard over the expert axis; the combine's contraction becomes
@@ -30,6 +43,7 @@ TPU-first choices:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -39,7 +53,7 @@ from jax.sharding import Mesh
 from ..parallel.sharding import LogicalRules, DEFAULT_RULES, constrain
 from .configs import ModelConfig
 from .layers import DEFAULT_COMPUTE_DTYPE, causal_mask, length_mask
-from .quant import q_einsum
+from .moe_tiles import routed_tiles, swiglu_experts, tile_rows
 from . import llama
 from .llama import KVCache  # same cache layout/contract as the dense family
 # Fused transform: attention projections fuse exactly as the dense
@@ -230,9 +244,21 @@ def moe_mlp(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     expert's weights unread (ops/quant_mm.py). A live row's output does
     not depend on the mask: with an exact bucket it only moves to
     another slot of the same matmul.
+
+    This form keeps the buckets whatever it carries (generate, a verify,
+    a session wake, the embedding): only :func:`moe_mlp_counted`, the
+    form an admission runs, leaves them for tiles.
     """
     return _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok,
                     mesh, rules, capacity, w_gu, renormalize, live)[0]
+
+
+def _tiled(x: jax.Array, mesh, capacity) -> bool:
+    """Whether a counted dispatch leaves the buckets for tiles, from what
+    the call carries: no capacity to drop at (a tile layout cannot
+    drop), no mesh (a mesh shards buckets over ``experts``), more than
+    one position a row (a step's buckets hold its few rows exactly)."""
+    return capacity is None and mesh is None and x.shape[1] > 1
 
 
 def moe_mlp_counted(x: jax.Array, router: jax.Array, w_gate: jax.Array,
@@ -248,13 +274,62 @@ def moe_mlp_counted(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     ``valid`` positions ([B,S] bool: the real prompt positions), and
     those of them that found their bucket full. Padding positions still
     take slots in (token, slot) order; what they displace is counted,
-    what they lose is not."""
+    what they lose is not.
+
+    Dropless (``capacity`` None) the vector has a third entry, the rows
+    the experts' matmuls ran over (:func:`no_stats`), and a prefill on
+    one device (:func:`_tiled`) leaves the buckets: its real pairs
+    go sorted into tiles (:func:`_moe_tiles`), padding is sent nowhere
+    and its output is 0. A real position's output is the buckets' up to
+    float rounding: the same pairs, weights and order of the sum over
+    k."""
+    B, S, _ = x.shape
+    if _tiled(x, mesh, capacity):
+        return _moe_tiles(x, router, w_gate, w_up, w_down,
+                          num_experts_per_tok, w_gu, renormalize, valid)
     out, full, _ = _moe_mlp(x, router, w_gate, w_up, w_down,
                             num_experts_per_tok, mesh, rules, capacity, w_gu,
                             renormalize, None)
     real = jnp.repeat(valid.reshape(-1), num_experts_per_tok)      # [T*k]
-    stats = jnp.stack([jnp.sum(real), jnp.sum(real & full)])
-    return out, stats.astype(jnp.int32)
+    stats = [jnp.sum(real), jnp.sum(real & full)]
+    if capacity is None:
+        # Every expert's bucket holds every position of the dispatch.
+        stats.append(jnp.where(jnp.any(valid), router.shape[-1] * B * S, 0))
+    return out, jnp.stack(stats).astype(jnp.int32)
+
+
+def _route(xt: jax.Array, router: jax.Array, k: int,
+           renormalize: bool) -> tuple[jax.Array, jax.Array]:
+    """Routing in f32 (HF parity: softmax over ALL experts, then top-k,
+    then, for Mixtral, renormalise the selected weights): (top_w, top_i)
+    [T,k]."""
+    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)   # [T,NE]
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, k)                         # [T,k]
+    if renormalize:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return top_w, top_i
+
+
+def _moe_tiles(x, router, w_gate, w_up, w_down, num_experts_per_tok, w_gu,
+               renormalize, valid) -> tuple[jax.Array, jax.Array]:
+    """A dropless prefill that computes the pairs it routed: this
+    family's router in front of the tree's one sorted-tile dispatch
+    (models/moe_tiles.routed_tiles). ``valid`` [B,S] bool: the positions
+    that take tile rows. Returns (out [B,S,H], stats int32 [3] = pairs
+    of the valid positions, 0 dropped, tile rows multiplied = filled
+    tiles x rows a tile)."""
+    B, S, H = x.shape
+    NE, k = router.shape[-1], num_experts_per_tok
+    T = B * S
+    xt = x.reshape(T, H)
+    top_w, top_i = _route(xt, router, k, renormalize)
+    takes = jnp.broadcast_to(valid.reshape(T, 1), (T, k))
+    out, tiles = routed_tiles(xt, top_w, top_i, takes, NE, functools.partial(
+        swiglu_experts, w_gu=w_gu, w_down=w_down, w_gate=w_gate, w_up=w_up))
+    stats = jnp.stack([jnp.sum(takes), jnp.asarray(0),
+                       jnp.sum(tiles) * tile_rows(T * k, NE)])
+    return out.astype(x.dtype).reshape(B, S, H), stats.astype(jnp.int32)
 
 
 def _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok, mesh,
@@ -269,13 +344,7 @@ def _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok, mesh,
     C = T if capacity is None else max(1, min(capacity, T))
     xt = x.reshape(T, H)
 
-    # Routing in f32 (HF parity: softmax over ALL experts, then top-k,
-    # then, for Mixtral, renormalise the selected weights).
-    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)   # [T,NE]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, k)                         # [T,k]
-    if renormalize:
-        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    top_w, top_i = _route(xt, router, k, renormalize)
 
     # Position-in-expert with (token, selection-slot) priority: cumsum of
     # the selection one-hot over the t-major flattened [T*k] selections.
@@ -300,15 +369,7 @@ def _moe_mlp(x, router, w_gate, w_up, w_down, num_experts_per_tok, mesh,
     xin = jnp.zeros((NE * C, H), xt.dtype).at[idx].set(x_rep, mode="drop")
     xin = constrain(xin.reshape(NE, C, H), mesh,
                     ("experts", None, "act_embed"), rules)
-    if w_gu is not None:
-        gu = q_einsum("ech,ehf->ecf", xin, w_gu, count)            # [NE,C,2F]
-        F = gu.shape[-1] // 2
-        g = jax.nn.silu(gu[..., :F])
-        u = gu[..., F:]
-    else:
-        g = jax.nn.silu(q_einsum("ech,ehf->ecf", xin, w_gate, count))
-        u = q_einsum("ech,ehf->ecf", xin, w_up, count)
-    y = q_einsum("ecf,efh->ech", g * u, w_down, count)             # [NE,C,H]
+    y = swiglu_experts(xin, count, None, w_gu, w_down, w_gate, w_up)
     y = constrain(y, mesh, ("experts", None, "act_embed"), rules)
 
     gathered = jnp.take(y.reshape(NE * C, H), idx, axis=0,
@@ -370,10 +431,21 @@ def _mlp_fn_touched(config: ModelConfig, live: Optional[jax.Array]):
     return fn
 
 
-def no_stats() -> jax.Array:
+def no_stats(dropless: bool = False) -> jax.Array:
     """A drop count's start: int32 [2] = (routed pairs of real prompt
-    positions, those dropped), summed over the layers."""
-    return jnp.zeros((2,), jnp.int32)
+    positions, those dropped), summed over the layers; ``dropless`` (no
+    capacity) int32 [3], third the rows the experts' matmuls ran over
+    (the filled tiles' of :func:`_moe_tiles`: over the pairs it is what
+    the tiles' padding costs)."""
+    return jnp.zeros((3 if dropless else 2,), jnp.int32)
+
+
+def prefill_stats(config: ModelConfig) -> tuple[str, ...]:
+    """What the entries of the counts are that the ``_counted`` prefills
+    of ``config`` hand back, in their order, for the scheduler that
+    reads them (BatchScheduler._count_moe)."""
+    dropless = config.moe_capacity_factor is None
+    return ("assigned", "dropped") + (("rows",) if dropless else ())
 
 
 def forward(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -407,8 +479,9 @@ def forward_counted(params: dict, config: ModelConfig, tokens: jax.Array,
     cap = _capacity_for(config, int(tokens.shape[0] * tokens.shape[1]),
                         capacity)
     return llama.forward_aux(params, config, tokens, positions, cache, mask,
-                             _mlp_fn_counted(config, cap, valid), no_stats(),
-                             mesh, rules, causal0=causal0, last_idx=last_idx)
+                             _mlp_fn_counted(config, cap, valid),
+                             no_stats(cap is None), mesh, rules,
+                             causal0=causal0, last_idx=last_idx)
 
 
 def _prefill_geometry(tokens: jax.Array, cache: KVCache) -> tuple:
@@ -483,8 +556,8 @@ def prefill_chunk_counted(params: dict, config: ModelConfig,
                         capacity)
     return llama.prefill_chunk_aux(
         params, config, tokens, cache, offset,
-        _mlp_fn_counted(config, cap, valid), no_stats(), mesh, rules,
-        last_idx=last_idx)
+        _mlp_fn_counted(config, cap, valid), no_stats(cap is None), mesh,
+        rules, last_idx=last_idx)
 
 
 def no_touched() -> jax.Array:

@@ -116,12 +116,13 @@ from .layers import (DEFAULT_COMPUTE_DTYPE, FLASH_KV_CHUNK, NEG_INF,
                      apply_rope, attend_gqa_auto, causal_mask, rms_norm,
                      rope_table)
 from .llama import KVCache, _layer_view
-from .pangu import (STATS_WIDTH, _normal, _routed_local, no_stats, route,
-                    streamed_stack)
-from .quant import mm, q_einsum
+from .pangu import (STATS_WIDTH, _normal, _routed_local, no_stats,
+                    prefill_stats, route, streamed_stack)
+from .moe_tiles import routed_tiles, swiglu_experts
+from .quant import mm
 
 no_touched = no_stats
-__all__ = ["STATS_WIDTH", "no_stats", "no_touched"]
+__all__ = ["STATS_WIDTH", "no_stats", "no_touched", "prefill_stats"]
 
 
 # -- the pattern --------------------------------------------------------------
@@ -861,76 +862,26 @@ def _relu2_mlp(x, w_up, w_down):
     return mm(jnp.square(jax.nn.relu(mm(x, w_up))), w_down)
 
 
-def _tile_rows(pairs: int, experts: int) -> int:
-    """Rows a tile of :func:`_routed_tiles`: the even share of an expert,
-    a power of two of 8 to 128 (a tile is one MXU pass of one expert's
-    weights; smaller tiles pad less and fetch the weights more often)."""
-    rows = 8
-    while rows < 128 and rows * 2 * experts <= pairs:
-        rows *= 2
-    return rows
-
-
 def _routed_tiles(x: jax.Array, lp: dict, config: ModelConfig,
                   counted: Optional[jax.Array]):
     """A prefill's routed sum over experts that are all held here,
-    dropless without the buckets: the chunk's (token, expert) pairs laid
-    out SORTED by expert, each expert's run padded to whole tiles of
-    ``tm`` rows, so that the experts' matmuls run over ``pairs +
-    experts x tm`` rows at the most where pangu._routed_local's all-T
-    buckets run ``experts x T`` (eight times what 64 experts top-8
-    need, and under random weights a 64-way router overflows the small
-    buckets in nearly every chunk: PERF.md section 6, PR 40). A tile is
-    one expert's: the expert-stripe kernel walks tiles and reads each
-    tile's expert (quant.q_einsum's ``source``). Rows come to their
-    tiles by ONE gather and go back by one (no row is scattered).
-    ``counted`` ([B,S] bool): the real prompt positions; padding is sent
-    nowhere and gets 0. Returns pangu._routed_local's (out [B,S,H],
-    stats) for a prefill."""
+    dropless without the buckets: this family's router
+    (pangu.route) in front of the tree's one sorted-tile dispatch
+    (models/moe_tiles.routed_tiles, which says what it saves over
+    pangu._routed_local's all-T buckets; under random weights a 64-way
+    router overflows the small buckets in nearly every chunk: PERF.md
+    section 6, PR 40). ``counted`` ([B,S] bool): the real prompt
+    positions; padding is sent nowhere and gets 0. Returns
+    pangu._routed_local's (out [B,S,H], stats) for a prefill."""
     B, S, H = x.shape
     NE, k = config.num_experts, config.num_experts_per_tok
     T = B * S
-    P = T * k
-    tm = _tile_rows(P, NE)
-    NT = -(-P // tm) + NE               # tiles: every run's last is part full
     xt = x.reshape(T, H)
     top_w, top_i = route(xt, lp["router"], config)
     takes = jnp.ones((T, k), bool) if counted is None else \
         jnp.broadcast_to(counted.reshape(T, 1), (T, k))
-    expert = jnp.where(takes, top_i, NE).reshape(P)      # NE: sent nowhere
-    flat = jax.nn.one_hot(expert, NE, dtype=jnp.int32)   # [P, NE]
-    sent = jnp.sum(flat, axis=0)                         # [NE]
-    slot = jnp.sum(flat * (jnp.cumsum(flat, axis=0) - flat), axis=-1)
-    tiles = -(-sent // tm)
-    tile_end = jnp.cumsum(tiles)
-    tile_start = tile_end - tiles
-    run_start = jnp.cumsum(sent) - sent                  # in sorted order
-    order = jnp.argsort(expert, stable=True)             # pairs by expert
-    # Of every tile: its expert, and how many of its rows are filled.
-    t = jnp.arange(NT, dtype=jnp.int32)
-    used = t < tile_end[-1]
-    # (a count of the runs that end at or before it: no search loop)
-    source = jnp.minimum(jnp.sum(tile_end[None, :] <= t[:, None], axis=1),
-                         NE - 1).astype(jnp.int32)
-    source = jnp.where(used, source, source[jnp.maximum(tile_end[-1] - 1,
-                                                        0)])
-    first = (t - tile_start[source]) * tm                # rank of its row 0
-    count = jnp.where(used, jnp.clip(sent[source] - first, 0, tm), 0)
-    # Of every row of the layout: the pair it holds.
-    rank = first[:, None] + jnp.arange(tm, dtype=jnp.int32)[None, :]
-    held = used[:, None] & (rank < sent[source][:, None])
-    pair = order[jnp.clip(run_start[source][:, None] + rank, 0, P - 1)]
-    xin = jnp.where(held[..., None], xt[pair // k], 0).astype(xt.dtype)
-    gu = q_einsum("ech,ehf->ecf", xin, lp["wgu_e"], count, source)
-    F = gu.shape[-1] // 2
-    y = q_einsum("ecf,efh->ech", jax.nn.silu(gu[..., :F]) * gu[..., F:],
-                 lp["w_down"], count, source)
-    # Back to the tokens: pair p sits at its expert's run, its slot on.
-    at = jnp.where(expert < NE,
-                   tile_start[jnp.minimum(expert, NE - 1)] * tm + slot, 0)
-    got = y.reshape(NT * tm, H)[at].reshape(T, k, H)
-    out = jnp.sum(got.astype(jnp.float32)
-                  * jnp.where(takes, top_w, 0.0)[..., None], axis=1)
+    out, _ = routed_tiles(xt, top_w, top_i, takes, NE, functools.partial(
+        swiglu_experts, w_gu=lp["wgu_e"], w_down=lp["w_down"]))
     n_real = jnp.asarray(T) if counted is None else jnp.sum(counted)
     stats = jnp.stack([jnp.sum(takes), jnp.asarray(0), n_real * k,
                        jnp.asarray(0)])

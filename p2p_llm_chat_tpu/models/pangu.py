@@ -595,6 +595,12 @@ def no_stats() -> jax.Array:
     return jnp.zeros((STATS_WIDTH,), jnp.int32)
 
 
+def prefill_stats(config: ModelConfig) -> tuple[str, ...]:
+    """The prefill entries above by name, for the scheduler that reads
+    them (BatchScheduler._count_moe; mixtral.prefill_stats)."""
+    return ("assigned", "dropped", "routed", "full_layers")
+
+
 no_touched = no_stats
 
 

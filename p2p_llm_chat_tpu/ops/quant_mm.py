@@ -603,7 +603,7 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
     ``source`` ([NE] int32, None = bucket e reads expert e): the expert
     whose weights each bucket reads, for buckets that are TILES of a
     row-sorted dispatch, several of them one expert's
-    (models/nemotron_h._routed_tiles): ``x`` is then [tiles, rows a tile,
+    (models/moe_tiles.routed_tiles): ``x`` is then [tiles, rows a tile,
     H] and the grid walks tiles. A tile whose count is 0 must name the
     expert of the last filled tile before it.
     """

@@ -687,6 +687,10 @@ class BatchScheduler:
         # routed model's, and a hybrid's, whose recurrent state and
         # window rings padding must not move, routed layers or none.
         self._counted = config.is_moe or config.is_hybrid
+        # What the counts behind a prefill's first tokens are, entry by
+        # entry, in the family's own words (_count_moe reads them).
+        self._moe_prefill = (model.prefill_stats(config)
+                             if self._counted else ())
         # Decode is bandwidth-bound and pays a fixed cost per
         # weight-matmul call: fuse the column-parallel projection pairs
         # (wq|wk|wv, w_gate|w_up) into single wider matmuls
@@ -777,6 +781,9 @@ class BatchScheduler:
         # bucket full. Decode and wake buckets are exact and add nothing.
         self._n_moe_assigned = 0
         self._n_moe_dropped = 0
+        # Rows the experts' matmuls of the dropless prefills ran over
+        # (mixtral.no_stats): over the assignments, the layout's padding.
+        self._n_moe_prefill_rows = 0     # owned-by: _loop
         # Of the experts a decode step could have streamed (layers x
         # experts, each step of each dispatch), those a live row reached:
         # the others' weights were not read (ops/quant_mm.py).
@@ -1766,19 +1773,21 @@ class BatchScheduler:
         routed model the drop count so far."""
         logits = jnp.zeros((R, self.config.vocab_size), jnp.float32)
         if self._counted:
-            return logits, jnp.zeros((self._moe_w,), jnp.int32)
+            return logits, jnp.zeros((len(self._moe_prefill),), jnp.int32)
         return logits
 
     def _count_moe(self, stats, dispatches: int) -> None:
         """A prefill's counts, summed on the device over its routed
         layers and over the ``dispatches`` that carried a request (the
         chunks of a ladder share one vector)."""
-        self._n_moe_assigned += int(stats[0])
-        self._n_moe_dropped += int(stats[1])
-        if len(stats) > 2:
-            self._n_moe_routed_pairs += int(stats[2])
-            self._n_moe_local_pairs += int(stats[0])
-            self._n_moe_full_bucket_layers += int(stats[3])
+        n = dict(zip(self._moe_prefill, map(int, stats)))
+        self._n_moe_assigned += n["assigned"]
+        self._n_moe_dropped += n["dropped"]
+        self._n_moe_prefill_rows += n.get("rows", 0)
+        if "routed" in n:
+            self._n_moe_routed_pairs += n["routed"]
+            self._n_moe_local_pairs += n["assigned"]
+            self._n_moe_full_bucket_layers += n["full_layers"]
             self._n_moe_prefill_layers += (dispatches
                                            * self.config.routed_layers)
 
@@ -3664,6 +3673,12 @@ class BatchScheduler:
                 self._n_moe_decode_touched
             out["serve_moe_decode_expert_slots_total"] = \
                 self._n_moe_decode_slots
+        if "rows" in self._moe_prefill:
+            # A dropless Mixtral-family model: the rows its prefills'
+            # expert matmuls ran over (filled tiles x rows a tile),
+            # beside serve_moe_assignments_total, the pairs they were
+            # for: rows / pairs is what the tiles' padding costs.
+            out["serve_moe_prefill_rows_total"] = self._n_moe_prefill_rows
         if self.config.router_width > self.config.num_experts:
             # A share of the experts is held here: pairs routed (prefill
             # and decode), and those routed to a held expert.
@@ -4327,7 +4342,7 @@ class BatchScheduler:
                 # The prefill's drop count rides behind the first tokens
                 # (_with_moe); prefix builds left theirs waiting.
                 # Warm-up's all-padding dispatches carry no request.
-                self._count_moe(first_toks[-self._moe_w:],
+                self._count_moe(first_toks[-len(self._moe_prefill):],
                                 dispatches if chunk else 0)
                 while self._moe_unread:
                     # graftcheck: sync-ok 2 int32 of a build that ended before this admission was dispatched

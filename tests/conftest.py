@@ -100,14 +100,9 @@ import pytest  # noqa: E402
 
 def pytest_runtest_logreport(report):
     if report.when == "call" and os.environ.get("DEBUG_MAPS"):
-        try:
-            with open("/proc/self/maps") as f:
-                n = sum(1 for _ in f)
-            import threading
-            print(f" [maps={n} threads={threading.active_count()}]",
-                  file=sys.stderr, flush=True)
-        except OSError:
-            pass
+        import threading
+        print(f" [maps={_maps()} threads={threading.active_count()}]",
+              file=sys.stderr, flush=True)
 
 
 # The model suites compile hundreds of XLA:CPU executables in one pytest
@@ -119,11 +114,25 @@ def pytest_runtest_logreport(report):
 #
 # 1. drop every cached executable between test modules — modules build
 #    their own engines/programs anyway, and the persistent compilation
-#    cache (above) makes re-loads cheap;
+#    cache (above) makes re-loads cheap — and inside a module once it
+#    has come two thirds of the way to the limit (tests/test_engine.py
+#    alone ends at 61-65 K regions: PR 42 met the limit there);
 # 2. where permitted (root), raise the kernel limit outright.
 
+_MAPS_HIGH = 44_000
+
+
+def _maps() -> int:
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
 def pytest_runtest_teardown(item, nextitem):
-    if nextitem is None or item.module is not nextitem.module:
+    if (nextitem is None or item.module is not nextitem.module
+            or _maps() > _MAPS_HIGH):
         import gc
         import jax as _jax
         # clear_caches() walks a weakref set that any still-settling

@@ -1,7 +1,8 @@
 """tiny-olmoe (MHA, whole-projection QK-norm, 8 experts top-4 without
 renormalisation) through the scheduler, end to end on the CPU: every
-prefill program it has, the stack the benchmark serves with, and the two
-counters of what the capacity buckets drop. A module of its own, so that
+prefill program it has, the stack the benchmark serves with, the two
+counters of what the capacity buckets drop and the one of the tile rows
+its dropless prefills multiply. A module of its own, so that
 its programs are freed before the next module's (tests/conftest.py)."""
 
 import threading
@@ -108,5 +109,16 @@ def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
         assert m["serve_moe_assignments_total"] == per_token * (
             m["serve_prefill_tokens_total"] + built)
         assert m["serve_moe_dropped_total"] == 0
+        # The admissions ran tiles (a dropless prefill on one device):
+        # the rows they multiplied are the pairs and each run's last
+        # tile's padding, under an expert's worth of tiles a layer a
+        # dispatch, far from the buckets' num_experts / top-k = 2 rows a
+        # pair over the real share of the positions.
+        rows = m["serve_moe_prefill_rows_total"]
+        assert m["serve_moe_assignments_total"] <= rows
+        dispatches = (m["serve_admit_batches_total"]
+                      + m["prefill_chunks_total"] + 1)      # + the build
+        assert rows < m["serve_moe_assignments_total"] + (
+            dispatches * mcfg.num_layers * mcfg.num_experts * 128)
     finally:
         eng.stop()

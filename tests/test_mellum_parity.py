@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from benchmark import manifest, reference, serve_cell
-from p2p_llm_chat_tpu.models import family_for, layers, nemotron_h
+from p2p_llm_chat_tpu.models import (family_for, layers, moe_tiles,
+                                     nemotron_h)
 from p2p_llm_chat_tpu.models.configs import (ModelConfig, RopeScaling,
                                              get_config)
 from p2p_llm_chat_tpu.models.llama import KVCache
@@ -473,12 +474,36 @@ def test_tile_dispatch_equals_the_bucket_dispatch(plain, shape, real):
     np.testing.assert_allclose(np.asarray(free), np.asarray(want), atol=1e-5)
 
 
-def test_tiles_are_an_experts_even_share_of_8_to_128_rows():
-    assert nemotron_h._tile_rows(1024 * 8, 64) == 128
-    assert nemotron_h._tile_rows(2048 * 8, 64) == 128
-    assert nemotron_h._tile_rows(256 * 8, 64) == 32
-    assert nemotron_h._tile_rows(32 * 2, 8) == 8
-    assert nemotron_h._tile_rows(4, 8) == 8
+def _parent_tile_rows(pairs: int, experts: int) -> int:
+    """nemotron_h._tile_rows as PR 40 left it (PR 42's parent)."""
+    rows = 8
+    while rows < 128 and rows * 2 * experts <= pairs:
+        rows *= 2
+    return rows
+
+
+@pytest.mark.parametrize("tokens,parent,rows", [
+    (16, 8, 16), (32, 8, 16), (64, 8, 16), (128, 16, 32), (256, 32, 64),
+    (512, 64, 64), (1024, 128, 128), (2048, 128, 128), (16384, 128, 128)])
+def test_tile_rows_over_mellums_real_buckets(tokens, parent, rows):
+    """The rule at Mellum's real width (64 experts top-8) by the tokens
+    of a dispatch: every bucket from the smallest to a two-row chunk and
+    the longest ladder. PR 42 doubled the tile where the experts
+    outnumber the mean run, so the programs UNDER 512 tokens a dispatch
+    moved (tools/hash_programs.py cannot see it: tiny-mellum2 has 8
+    experts); the 1,024-token chunks the cell's traffic drives, at one
+    row and at two, and the 512 bucket did not."""
+    pairs = tokens * 8
+    assert _parent_tile_rows(pairs, 64) == parent
+    assert moe_tiles.tile_rows(pairs, 64) == rows
+    assert (rows == parent) == (tokens >= 512)
+
+
+def test_tile_rows_at_the_test_sizes_are_the_parents():
+    for pairs in (4, 32 * 2, 128 * 2, 256 * 2, 256 * 4, 4096):
+        assert moe_tiles.tile_rows(pairs, 8) == _parent_tile_rows(pairs, 8)
+    assert moe_tiles.tile_rows(32 * 2, 8) == 8
+    assert moe_tiles.tile_rows(128 * 2, 8) == 32    # tiny-mellum2's chunk
 
 
 def test_expert_kernel_walks_tiles_that_name_their_expert():

@@ -1,11 +1,44 @@
 """The dense, Mixtral and OLMoE programs lower to the StableHLO they did
 before the latent-attention family came (PR 30's parent, commit
-69d2026), and the latent-attention family's to what they did before the
-hybrid family came (PR 32's parent, commit bdbf057):
-tools/hash_programs.py's digests, taken on those commits under
+69d2026), the latent-attention family's to what they did before the
+hybrid family came (PR 32's parent, commit bdbf057), and the hybrid
+family's three test sizes (a prefill chunk and a decode step each) to
+what they did before its sorted-tile dispatch was lifted into
+models/moe_tiles.py for a second caller (PR 42's parent, commit
+3b42824): tools/hash_programs.py's digests, taken on those commits under
 this suite's conftest (its XLA flags are part of the text). A PR
 that means to change one of these programs replaces its digest, from a
-run of the tool on itself, and says so."""
+run of the tool on itself, and says so.
+
+PR 42 moved the Mixtral family's dropless ``_counted`` prefills (the
+mask of real positions, no capacity factor, one device, more than one
+position a row: every program an admission runs) from buckets onto
+tiles. The tool did not lower those programs before; it does now, and
+``PR42`` holds their four digests, taken on PR 42 itself:
+``tiny-olmoe.prefill_counted`` and ``tiny-olmoe.prefill_chunk_counted``,
+and ``tiny-moe``'s two with them, because tiny-moe registers no
+capacity factor either and so is dropless as tiny-olmoe is. On the
+parent the four read
+``tiny-moe.prefill_counted`` 6575ab7a135b,
+``tiny-moe.prefill_chunk_counted`` f1a2e6cc801e,
+``tiny-olmoe.prefill_counted`` 0a7796269c0e,
+``tiny-olmoe.prefill_chunk_counted`` 7eba53a3a7e8.
+NO digest that was pinned before PR 42 is replaced: the maskless
+``prefill`` and ``prefill_chunk`` of both sizes (ISSUE 42 expected
+tiny-olmoe's two to move; they are generate's and keep the buckets),
+both sizes' decode programs and pool writes, the dense and the
+latent-attention families. ``verify_step_paged`` of both sizes is new
+in the tool and pinned at the parent's digest: a speculative verify and
+a session wake run more than one position a row with no mask and no
+capacity for every model of the family, Mixtral's included, and must
+stay on the buckets (the review of PR 42 found them on tiles).
+
+What this fence cannot see: the test sizes have 8 experts, and
+``moe_tiles.tile_rows`` differs from the parent's rule only where the
+experts outnumber an expert's even share of the pairs, which 8 experts
+never do. Mellum's real-width programs under 512 tokens a dispatch did
+move; tests/test_mellum_parity.py pins the rule over its bucket set
+with the parent's values beside them."""
 
 import os
 import sys
@@ -28,12 +61,14 @@ PARENT = {
     "tiny-moe.prefill_chunk": "b9bfd5f7272f18d067935b35232b838374296ede31e8ff60eb4a317734d99fbf",
     "tiny-moe.decode_step_paged": "43f3fc3e65e56ee813e130d65061a34017c6173a356f4684511af19a46a1740b",
     "tiny-moe.decode_fused": "d6aa0b48b3de1e6fb1cbc5fc4c30fc8b344442e98c95d420ace38d685118e093",
+    "tiny-moe.verify_step_paged": "154a904b240ea3d85b15cffd9952308b2d1ebc7c346a0b0b4d5721a5058a9065",
     "tiny-moe.write_prefill_batch": "53d756733b20a7c7bd4959d65a6b3d22fa0ff12af4acb5896d267c8addb291e4",
     "tiny-moe.write_prefill_chunk": "1576d14433a7082cd568b612a6c34cdf383ec71a3c6e7670d40830c538533aca",
     "tiny-olmoe.prefill": "26a39db12d865d9dfbc24a021f31ab85ffbc70fe0f919874d4e566ba6dde1810",
     "tiny-olmoe.prefill_chunk": "be6d4cf4f8729a8a881ab49653ee007383b5e309e39a5d14f65d777283189461",
     "tiny-olmoe.decode_step_paged": "a1a92274845ae1bfdc4d249b3d29b9dea6cb4a8de4bfce6587b6601ed6121a87",
     "tiny-olmoe.decode_fused": "f0c2ae114434292e5e4ba72a0989c2faf41481401500bc666075d19157579608",
+    "tiny-olmoe.verify_step_paged": "6c912595e96663af5ad8a14a2df2f49de813b487b4fc31759ebd54aaefe994fb",
     "tiny-olmoe.write_prefill_batch": "427d1c493a9fcfe813e321dc7eae89372e7149b1d0fa7d780f4e3f575f79207c",
     "tiny-olmoe.write_prefill_chunk": "91a1f2403e57658367328f06ac514914122247be29d0dca99b76306d0f6f165d",
     # The latent-attention family, taken on PR 32's parent (commit
@@ -45,8 +80,26 @@ PARENT = {
     "tiny-pangu.decode_step_paged": "e4a6fbb06dba46721175ad3bc256f89b02b89535b6370f404d7c1e04a126ec74",
     "tiny-pangu.decode_fused": "1cb56c7e7e4a94045b891fcc67daac39af0f40122886d008616f884844c13c7c",
     "tiny-pangu.write_prefill_batch": "9ecc20c4c0cb1821f120c465568e16e13b8a4cf8dbfae3bc0a7a30e362987f50",
-    "tiny-pangu.write_prefill_chunk": "cb91ef92e8ef392040a646765c53e1df4fa5aa56e1eb968427049ac279bf3b5a"
+    "tiny-pangu.write_prefill_chunk": "cb91ef92e8ef392040a646765c53e1df4fa5aa56e1eb968427049ac279bf3b5a",
+    # The hybrid family, taken on PR 42's parent (commit 3b42824): PR 42
+    # made nemotron_h._routed_tiles a call of models/moe_tiles.py and
+    # must not have moved what Mellum's, Nemotron's or Phi's lower to.
+    "tiny-nemotron-h.prefill_chunk": "2a8087dca4b7c76675fcf30ba7ba09d3daf5fa2482627bb7ef93cdca8f6d5d56",
+    "tiny-nemotron-h.decode_step_paged": "932546385b870da5fba9df1ac26696a6ec04e841cbd52bf57a893874eedc6487",
+    "tiny-phi4flash.prefill_chunk": "8f22bad36d77b59b4b29d01eb0642c0bf38b92a9eb46da2668a4becb7859b4fc",
+    "tiny-phi4flash.decode_step_paged": "0d0c35622a61befdd2259ea103ab478fbb6979015c794e9d36babf6733d4b15d",
+    "tiny-mellum2.prefill_chunk": "7dfaa925f7d1bb6ecd753365c45255bdcc3ebefac26b2403f4cb8d3deae04366",
+    "tiny-mellum2.decode_step_paged": "208add085376db497aeb54b8e684cf51f7b9ad8645ed214ca38a9c1db22cb323"
 }
+
+# Taken on PR 42 itself: the programs it meant to change.
+PR42 = {
+    "tiny-moe.prefill_counted": "639e3b7782d1a33d248ae7de5cabd668ada0b1c67ae414998902abc016e69200",
+    "tiny-moe.prefill_chunk_counted": "d5ad8d29d82755bb2fd062a726a3cc3a2e326b5e65713a66d88b5a7f51b520aa",
+    "tiny-olmoe.prefill_counted": "66afbe3371e2d0a62780b7b94b9f392be476ed44b14be877c71d0e68515260ef",
+    "tiny-olmoe.prefill_chunk_counted": "9ed598610acfe30f91ae98fc058c9d17c17b7fc4fd749db6cb387bfeee56387e"
+}
+PINNED = {**PARENT, **PR42}
 
 
 @pytest.fixture(scope="module")
@@ -55,10 +108,15 @@ def texts():
             for name in hash_programs.CONFIGS}
 
 
-@pytest.mark.parametrize("key", sorted(PARENT))
+@pytest.mark.parametrize("key", sorted(PINNED))
 def test_program_lowers_to_the_parents_stablehlo(texts, key):
     import hashlib
     name, label = key.split(".", 1)
     got = hashlib.sha256(texts[name][label].encode()).hexdigest()
-    assert got == PARENT[key], (
-        f"{key} lowers to other StableHLO than at its pinned parent")
+    assert got == PINNED[key], (
+        f"{key} lowers to other StableHLO than at its pinned commit")
+
+
+def test_every_program_the_tool_lowers_is_pinned(texts):
+    assert {f"{name}.{label}" for name, labels in texts.items()
+            for label in labels} == set(PINNED)

@@ -19,7 +19,11 @@ from its launch to ``block_until_ready`` on what it returned.
     python tools/check_admit_pair.py benchmark/configs/<name>.json
 
 Prints one JSON line a program and writes them all to
-``chiprun_out/admit_pair.<name>.json``.
+``chiprun_out/admit_pair.<name>.json``. Where the model's prefills count
+the rows their experts multiplied (a dropless Mixtral-family model:
+``serve_moe_prefill_rows_total``), a dispatch that ends an admission
+also reads ``moe_pairs`` and ``moe_rows`` from the counts behind its
+first tokens (a ladder's are summed over its chunks).
 """
 
 from __future__ import annotations
@@ -112,15 +116,17 @@ def main() -> None:
                 live=False)
             jax.block_until_ready(packed)
             times: dict = {}
+            toks = None
             for rep in range(WARM + REPS):
                 if S <= C:
-                    ms = {"splice": timed(lambda: single_shot(packed))[0]}
+                    ms = {}
+                    ms["splice"], toks = timed(lambda: single_shot(packed))
                 else:
                     ms, kv, logits = {}, None, None
                     for off in range(0, S, C):
                         kind = ("first" if off == 0 else
                                 "final" if off + C == S else f"mid{off // C}")
-                        ms[kind], (kv, logits, _) = timed(
+                        ms[kind], (kv, logits, toks) = timed(
                             lambda: sched._dispatch_prefill_chunk(
                                 P, S, off, C, packed, kv, logits, entry))
                 if rep >= WARM:
@@ -134,6 +140,12 @@ def main() -> None:
                            "ms_median": statistics.median(v),
                            "ms_min": min(v), "ms_max": max(v),
                            "device": jax.devices()[0].device_kind}
+                names = sched._moe_prefill
+                if kind in ("splice", "final") and "rows" in names:
+                    n = dict(zip(names,
+                                 np.asarray(toks)[-len(names):].tolist()))
+                    reading.update(moe_pairs=n["assigned"],
+                                   moe_rows=n["rows"])
                 readings.append(reading)
                 print(json.dumps(reading), flush=True)
     out = os.path.join(ROOT, "chiprun_out")

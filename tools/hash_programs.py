@@ -1,8 +1,12 @@
-"""SHA-256 of the StableHLO the dense, Mixtral, OLMoE and
-latent-attention families lower to on the CPU at test size: the
-programs the scheduler serves with (one-shot and chunked prefill, a
+"""SHA-256 of the StableHLO the dense, Mixtral, OLMoE,
+latent-attention and hybrid families lower to on the CPU at test size:
+the programs the scheduler serves with (one-shot and chunked prefill, a
 paged int8 decode step with its pool write, a fused decode, the
-admission splice). A PR that must not move another family's programs runs this on its parent and on itself
+admission splice; for a routed Mixtral-family model also the
+``_counted`` prefills, which are what an admission runs, and a verify
+step, which is also what a session wake runs; for the hybrid
+family's three test sizes a prefill chunk and a decode step). A PR that
+must not move another family's programs runs this on its parent and on itself
 (``PYTHONPATH=<checkout> python tools/hash_programs.py``) and pins the
 parent's digests in tests/test_program_hashes.py.
 """
@@ -12,14 +16,17 @@ from __future__ import annotations
 import hashlib
 import json
 
-CONFIGS = ("tiny", "tiny-moe", "tiny-olmoe", "tiny-pangu")
+CONFIGS = ("tiny", "tiny-moe", "tiny-olmoe", "tiny-pangu",
+           "tiny-nemotron-h", "tiny-phi4flash", "tiny-mellum2")
+# Of the hybrid family (models/nemotron_h.py) only these labels.
+HYBRID_LABELS = ("prefill_chunk", "decode_step_paged")
 
 
 def programs(name: str) -> dict:
     """label -> StableHLO text of ``name``'s programs."""
     import jax
     import jax.numpy as jnp
-    from p2p_llm_chat_tpu.models import family_for, get_config
+    from p2p_llm_chat_tpu.models import family_for, get_config, mixtral
     from p2p_llm_chat_tpu.models.llama import KVCache
     from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,
                                                write_prefill_batch,
@@ -62,6 +69,27 @@ def programs(name: str) -> dict:
         "write_prefill_chunk": (lambda c, k, v, t: write_prefill_chunk(
             c, k, v, t, 16), (pool, small.k, small.v, tables)),
     }
+    if cfg.is_hybrid:
+        fns = {label: fns[label] for label in HYBRID_LABELS}
+    elif model is mixtral:
+        # What an admission of a routed Mixtral-family model runs: the
+        # mask of real positions goes in, the counts come out.
+        valid = jax.ShapeDtypeStruct((B, S), jnp.bool_)
+        fns["prefill_counted"] = (
+            lambda p, t, l, c, v: model.prefill_counted(
+                p, cfg, t, l, c, v, last_only=True),
+            (params, toks, lens, small, valid))
+        fns["prefill_chunk_counted"] = (
+            lambda p, t, c, v: model.prefill_chunk_counted(
+                p, cfg, t, c, C, v),
+            (params, jax.ShapeDtypeStruct((B, C), jnp.int32), small,
+             jax.ShapeDtypeStruct((B, C), jnp.bool_)))
+        # ... and what a speculative verify and a session wake run: more
+        # than one position a row with no mask and no capacity, whatever
+        # the configuration's factor (their bucket is exact).
+        fns["verify_step_paged"] = (
+            lambda p, t, c: model.verify_step_paged(p, cfg, t, c, pages=4),
+            (params, jax.ShapeDtypeStruct((B, 5), jnp.int32), pool))
     return {label: jax.jit(fn).lower(*args).as_text()
             for label, (fn, args) in fns.items()}
 
