@@ -1,17 +1,21 @@
 """A dropless routed prefill without the buckets: the sorted-tile
-dispatch, one in the tree, for every family whose prefill holds all its
-experts on one device (models/nemotron_h._routed_tiles: Mellum's routed
-layers; models/mixtral._moe_tiles: OLMoE's dropless prefill).
+dispatch, one in the tree, for every family's prefill on one device
+(models/pangu._routed_local's prefill half: openPangu's held range,
+Nemotron's LatentMoE and Mellum's routed layers;
+models/mixtral._moe_tiles: OLMoE's dropless prefill).
 
 Each family keeps its own router and hands over what it chose
-(``top_w``, ``top_i``), which pairs are real (``takes``) and its
-experts' feed-forward; the bookkeeping here is the same for all: the
-(token, expert) pairs laid out SORTED by expert, each expert's run
-padded to whole tiles of :func:`tile_rows` rows, the expert-stripe
-kernel walking tiles and reading each tile's expert
-(quant.q_einsum's ``source``). Rows come to their tiles by ONE gather
-and go back by one; no row is scattered, and a pair that is not taken
-is sent nowhere and gets 0.
+(``top_w``, ``top_i``), which pairs are to be computed (``takes``: a
+real position's, and of a held range of a wider router those routed to
+an expert held here), the rows its experts read (the hidden state, or
+the latent of a family whose experts live in one) and its experts'
+feed-forward (:func:`swiglu_experts`, :func:`relu2_experts`); the
+bookkeeping here is the same for all: the (token, expert) pairs laid
+out SORTED by expert, each expert's run padded to whole tiles of
+:func:`tile_rows` rows, the expert-stripe kernel walking tiles and
+reading each tile's expert (quant.q_einsum's ``source``). Rows come to
+their tiles by ONE gather and go back by one; no row is scattered, and
+a pair that is not taken is sent nowhere and gets 0.
 """
 
 from __future__ import annotations
@@ -24,7 +28,7 @@ import jax.numpy as jnp
 from .quant import q_einsum
 
 
-def tile_rows(pairs: int, experts: int) -> int:
+def tile_rows(pairs: int, experts: int, width: Optional[int] = None) -> int:
     """Rows a tile of :func:`routed_tiles`, from the dispatch's shapes
     alone. In pairs an expert: the mean run (``pairs / experts``)
     rounded down to a power of two of 8 to 128 (a tile is one MXU pass
@@ -56,11 +60,40 @@ def tile_rows(pairs: int, experts: int) -> int:
     (32), 512 tokens 64 (64), 1,024 and up 128 (128). So every program
     of OLMoE's AND of Mellum's under 512 tokens a dispatch lowers to
     other tiles than the parent's rule gave; of those only 256 tokens
-    was measured, and Mellum's cell drives none of them."""
+    was measured, and Mellum's cell drives none of them.
+
+    ``width`` (PR 43): the experts the router scores, where ``experts``
+    is a range of them held here (None, or no wider: all are held, and
+    nothing above changes). The rule then reads the pairs the held
+    experts EXPECT, ``pairs x experts / width``, and gives no fewer
+    than 64 rows. Why a floor: the layout is sized for every selection
+    being local (``pairs / rows + experts`` tiles) while a quarter or a
+    sixteenth of them are, so most tiles are empty, and the kernel's
+    grid steps over an empty tile as over a filled one (21-23 steps a
+    tile at the two benchmark configurations' widths, about 0.16 us a
+    step): small tiles buy little padding with many steps, and under
+    hot experts (the rule under the benchmark's weights) they read a
+    long run's expert again and again. Why not 128: the rows of a filled
+    tile are multiplied whether real or not, and at 128 that shows.
+    Measured with tools/check_admit_pair.py, a one-row 256-token
+    dispatch / a two-row one, ms (PERF.md section 6, PR 43): Nemotron
+    (128 of 512 experts, top-22; the buckets it left 40.6 / 75.8) 33.1 /
+    49.9 at 16 and 32 rows (this rule without its floor), 30.8 / 49.9
+    at 32, **29.4 / 47.4 at 64**, 29.3 / 49.8 at 64 and 128
+    (``tile_rows(pairs, experts)``, all selections counted), 34.7 / 50.1
+    at 128; openPangu (16 of 256, top-8; 18.9 / 35.7) 22.4 / 41.5 at
+    16, 20.2 / 37.4 at 32, **20.1 / 35.6 at 64**, 22.0 / 36.3 at 128.
+    Above a mean expected run of 64 (a dispatch of 2,048 tokens there,
+    4,096 here) the rule's own 128 takes over; nothing was measured
+    there."""
+    held = width is not None and width > experts
+    if held:
+        pairs = pairs * experts // width
     rows = 8
     while rows < 128 and rows * 2 * experts <= pairs:
         rows *= 2
-    return min(2 * rows, 128) if rows < experts else rows
+    rows = min(2 * rows, 128) if rows < experts else rows
+    return max(rows, 64) if held else rows
 
 
 def swiglu_experts(xin: jax.Array, count: Optional[jax.Array],
@@ -81,15 +114,29 @@ def swiglu_experts(xin: jax.Array, count: Optional[jax.Array],
     return q_einsum("ecf,efh->ech", g * u, w_down, count, source)
 
 
+def relu2_experts(xin: jax.Array, count: Optional[jax.Array],
+                  source: Optional[jax.Array], w_up, w_down) -> jax.Array:
+    """Ungated ``relu(.)^2`` experts over buckets or tiles, as
+    :func:`swiglu_experts`: ``w_up`` [NE,H,F], ``w_down`` [NE,F,H]."""
+    up = q_einsum("ech,ehf->ecf", xin, w_up, count, source)
+    return q_einsum("ecf,efh->ech", jnp.square(jax.nn.relu(up)), w_down,
+                    count, source)
+
+
 def routed_tiles(xt: jax.Array, top_w: jax.Array, top_i: jax.Array,
-                 takes: jax.Array, experts: int,
-                 ffn: Callable) -> tuple[jax.Array, jax.Array]:
+                 takes: jax.Array, experts: int, ffn: Callable,
+                 width: Optional[int] = None) -> tuple[jax.Array, jax.Array]:
     """The routed sum of ``xt`` [T,H] over the pairs its router chose:
     ``top_w``, ``top_i`` [T,k] (weight and expert of each selection) and
     ``takes`` [T,k] bool (the pairs to compute: a real position's;
     the others get 0). ``ffn(xin [tiles,tm,H], count [tiles], source
     [tiles]) -> [tiles,tm,H]`` is the experts' feed-forward over tiles
-    (:func:`swiglu_experts` with the family's weights bound).
+    (:func:`swiglu_experts` or :func:`relu2_experts` with the family's
+    weights bound). ``width`` is :func:`tile_rows`': the experts the
+    router scores where ``experts`` is a held range of them (a pair
+    past it must come with ``takes`` False). The layout is sized for
+    every one of the ``T x k`` selections being taken, whatever share
+    of them is expected.
 
     The experts' matmuls run over ``pairs + experts x tm`` rows at the
     most where all-T buckets run ``experts x T`` (eight times what 64
@@ -102,7 +149,7 @@ def routed_tiles(xt: jax.Array, top_w: jax.Array, top_i: jax.Array,
     T, H = xt.shape
     NE, k = experts, top_i.shape[-1]
     P = T * k
-    tm = tile_rows(P, NE)
+    tm = tile_rows(P, NE, width)
     NT = -(-P // tm) + NE               # tiles: every run's last is part full
     expert = jnp.where(takes, top_i, NE).reshape(P)      # NE: sent nowhere
     flat = jax.nn.one_hot(expert, NE, dtype=jnp.int32)   # [P, NE]

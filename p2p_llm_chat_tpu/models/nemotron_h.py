@@ -42,9 +42,13 @@ by the layer's letter in ``hybrid_pattern``:
   weighed ``routed_scaling_factor x s / (sum of the chosen s + 1e-20)``;
   ``l = x W_fc1`` (hidden -> latent); expert e is ``relu(l U_e)^2 D_e``
   in the latent; ``routed = (sum over chosen AND held e) W_fc2``;
-  ``shared = relu(x U_s)^2 D_s``; ``out = routed + shared``. The held
-  experts go through models/pangu._routed_local, the one dispatch for a
-  held range of a wider router.
+  ``shared = relu(x U_s)^2 D_s``; ``out = routed + shared``.
+
+Both kinds of ``E`` go through models/pangu._routed_local, the one
+dispatch of this family and the latent-attention one: a prefill's pairs
+sorted by expert into tiles (models/moe_tiles.routed_tiles; for a held
+range of a wider router the pairs routed elsewhere take no row), a
+decode step's into buckets that hold every row.
 
 The SambaY kinds (``ModelConfig.norm_kind`` "layer": every norm below
 is a biased LayerNorm; ``attn_diff``: every attention is the
@@ -117,8 +121,7 @@ from .layers import (DEFAULT_COMPUTE_DTYPE, FLASH_KV_CHUNK, NEG_INF,
                      rope_table)
 from .llama import KVCache, _layer_view
 from .pangu import (STATS_WIDTH, _normal, _routed_local, no_stats,
-                    prefill_stats, route, streamed_stack)
-from .moe_tiles import routed_tiles, swiglu_experts
+                    prefill_stats, streamed_stack)
 from .quant import mm
 
 no_touched = no_stats
@@ -862,38 +865,10 @@ def _relu2_mlp(x, w_up, w_down):
     return mm(jnp.square(jax.nn.relu(mm(x, w_up))), w_down)
 
 
-def _routed_tiles(x: jax.Array, lp: dict, config: ModelConfig,
-                  counted: Optional[jax.Array]):
-    """A prefill's routed sum over experts that are all held here,
-    dropless without the buckets: this family's router
-    (pangu.route) in front of the tree's one sorted-tile dispatch
-    (models/moe_tiles.routed_tiles, which says what it saves over
-    pangu._routed_local's all-T buckets; under random weights a 64-way
-    router overflows the small buckets in nearly every chunk: PERF.md
-    section 6, PR 40). ``counted`` ([B,S] bool): the real prompt
-    positions; padding is sent nowhere and gets 0. Returns
-    pangu._routed_local's (out [B,S,H], stats) for a prefill."""
-    B, S, H = x.shape
-    NE, k = config.num_experts, config.num_experts_per_tok
-    T = B * S
-    xt = x.reshape(T, H)
-    top_w, top_i = route(xt, lp["router"], config)
-    takes = jnp.ones((T, k), bool) if counted is None else \
-        jnp.broadcast_to(counted.reshape(T, 1), (T, k))
-    out, _ = routed_tiles(xt, top_w, top_i, takes, NE, functools.partial(
-        swiglu_experts, w_gu=lp["wgu_e"], w_down=lp["w_down"]))
-    n_real = jnp.asarray(T) if counted is None else jnp.sum(counted)
-    stats = jnp.stack([jnp.sum(takes), jnp.asarray(0), n_real * k,
-                       jnp.asarray(0)])
-    return out.astype(x.dtype).reshape(B, S, H), stats.astype(jnp.int32)
-
-
 def _moe(h, lp, config: ModelConfig, counted, live):
     """(out [B,S,H], stats int32 [4])."""
     x = rms_norm(h, lp["norm"], config.rms_norm_eps)
     if not config.moe_latent_size:
-        if live is None:
-            return _routed_tiles(x, lp, config, counted)
         return _routed_local(x, lp, config, counted, live)
     latent = mm(x, lp["w_fc1"])
     routed, stats = _routed_local(x, lp, config, counted, live, latent)

@@ -37,6 +37,12 @@ serves it through the same programs.
   expert id is below ``num_experts`` are computed here (the experts this
   chip holds); the others are another chip's work, left out here as in
   the plain reference. Nothing stands in for the absent chips.
+  :func:`_routed_local` is that sum, for this family and for
+  models/nemotron_h.py's routed layers: a prefill's pairs go sorted by
+  expert into tiles (models/moe_tiles.py, the one prefill dispatch of
+  the tree: the pairs that were routed here are the rows multiplied), a
+  decode step's into per-expert buckets that hold every row. Dropless
+  both ways.
 
 **The stack is not one scan**: ``params["dense_layers"]`` (the leading
 dense layers, unrolled) then ``params["layers"]`` (the routed ones, one
@@ -72,19 +78,17 @@ from ..utils.device import on_tpu, pallas_interpret
 from .configs import ModelConfig
 from .layers import DEFAULT_COMPUTE_DTYPE, apply_rope, rms_norm
 from .llama import KVCache, _default_mlp, _layer_view  # same cache contract
-from .quant import LayerSlice, QTensor, mm, q_einsum
+from .moe_tiles import relu2_experts, routed_tiles, swiglu_experts, tile_rows
+from .quant import LayerSlice, QTensor, mm
 
 # Width of the counts this family's programs hand the scheduler (the
-# other routed family hands 2). Prefill: (pairs routed to held experts,
-# of those dropped, pairs routed, routed layers that ran the all-T
-# buckets), real prompt positions only. Decode: (held experts a live
-# row reached, held experts there were, pairs routed, pairs routed to
-# held experts), live rows only.
+# other routed family hands 2 or 3). Prefill: (pairs routed to held
+# experts, of those dropped, pairs routed, rows the experts multiplied:
+# filled tiles x rows a tile, as mixtral.prefill_stats' third), real
+# prompt positions only. Decode: (held experts a live row reached, held
+# experts there were, pairs routed, pairs routed to held experts), live
+# rows only.
 STATS_WIDTH = 4
-
-# Above this many elements of the 0/1 placement matrix ([NE*C, T]) the
-# routed dispatch scatters rows instead of multiplying by it.
-_DISPATCH_MATMUL_ELEMS = 1 << 24
 
 def _pad128(n: int) -> int:
     return -(-n // 128) * 128
@@ -469,37 +473,49 @@ def route(xt: jax.Array, router: jax.Array, config: ModelConfig,
     return top_w * config.routed_scaling_factor, top_i
 
 
+def _experts_ffn(lp: dict, config: ModelConfig):
+    """The held experts' feed-forward over buckets or tiles, ``ffn(xin
+    [N,C,H], count, source) -> [N,C,H]`` as models/moe_tiles.py takes it
+    (``count`` and ``source`` are quant.q_einsum's): gated SwiGLU from
+    ``wgu_e``, or ungated ``relu(.)^2`` from ``w_up_e``
+    (``mlp_activation``)."""
+    if config.mlp_activation == "relu2":
+        return functools.partial(relu2_experts, w_up=lp["w_up_e"],
+                                 w_down=lp["w_down"])
+    return functools.partial(swiglu_experts, w_gu=lp["wgu_e"],
+                             w_down=lp["w_down"])
+
+
 def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
                   counted: Optional[jax.Array], live: Optional[jax.Array],
                   latent: Optional[jax.Array] = None):
-    """The held experts' part of the routed sum, by scatter/gather into
-    per-expert buckets (models/mixtral.moe_mlp's dispatch), and the
-    counts. x [B,S,H]. ``counted`` ([B,S] bool): a prefill's real
-    prompt positions, which the counts run over and which alone take
-    bucket slots (padding and an admission's dummy entries are sent
-    nowhere and their routed output is 0; None: every position is
-    real); ``live`` ([B] bool): a decode step's active rows, which
-    alone take bucket slots.
+    """The held experts' part of the routed sum, and the counts. x
+    [B,S,H]. ``live`` ([B] bool) given: a decode step, its active rows
+    alone take slots in per-expert buckets that hold every row.
+    ``live`` None: a prefill (an admission, a chunk of a ladder, a
+    prefix build, generate, a session wake's suffix), whose pairs go
+    sorted by expert into tiles (models/moe_tiles.routed_tiles, the one
+    prefill dispatch of the tree): the experts multiply the pairs that
+    were routed here, padded to tiles, whatever the router's skew.
+    ``counted`` ([B,S] bool): such a prefill's real prompt positions,
+    which the counts run over and which alone are sent anywhere
+    (padding and an admission's dummy entries get 0; None: every
+    position is real).
 
     What a model names, this one dispatch reads from its configuration
     and its layer: a selection bias (``lp["router_bias"]``,
-    ``moe_selection_bias``); the experts' MLP (``mlp_activation``:
-    gated SwiGLU from ``wgu_e``, or ungated ``relu(.)^2`` from
-    ``w_up_e``); and ``latent`` ([B,S,latent width]): what the experts
-    read and write when they live in a latent (``moe_latent_size``: the
-    router still reads ``x``, and the sum comes back latent-wide, for
-    the caller to project up). That family's buckets also return to
-    their tokens through the placement matrix, as they came (its pairs a
-    token are many and its rows narrow; the other keeps the row gather
-    it was measured with).
+    ``moe_selection_bias``); the experts' MLP (:func:`_experts_ffn`);
+    and ``latent`` ([B,S,latent width]): what the experts read and
+    write when they live in a latent (``moe_latent_size``: the router
+    still reads ``x``, and the sum comes back latent-wide, for the
+    caller to project up). That family's decode buckets also return to
+    their tokens through the placement matrix, as they came (its pairs
+    a token are many and its rows narrow; the other keeps the row
+    gather it was measured with).
 
     A pair routed to an expert this chip does not hold (id >=
-    ``num_experts``) takes no slot and adds nothing. Dropless: a bucket
-    holds T/4 rows (a prefill's 16 held experts of 256 see a 32nd of its
-    pairs each under even routing), and when any expert was sent more,
-    the same computation runs again with buckets of all T rows
-    (``lax.cond``: one of the two runs; a prefill's last count says
-    which, 1 for all T). Decode buckets hold every row.
+    ``num_experts``) is sent nowhere and adds nothing. Dropless both
+    ways.
 
     Returns (out [B,S,H], stats int32 [STATS_WIDTH])."""
     B, S, H = x.shape
@@ -510,13 +526,22 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     if latent is not None:
         H = latent.shape[-1]
         xt = latent.reshape(T, H)
-    local = top_i < NE                                           # [T,k]
-    takes = local
-    if live is not None:
-        takes = takes & jnp.broadcast_to(live[:, None, None],
-                                         (B, S, k)).reshape(T, k)
-    elif counted is not None:
-        takes = takes & counted.reshape(T, 1)
+    ffn = _experts_ffn(lp, config)
+    if live is None:
+        takes = jnp.ones((T, k), bool) if counted is None else \
+            jnp.broadcast_to(counted.reshape(T, 1), (T, k))
+        if config.router_width > NE:
+            takes = takes & (top_i < NE)
+        out, tiles = routed_tiles(xt, top_w, top_i, takes, NE, ffn,
+                                  config.router_width)
+        n_real = jnp.asarray(T) if counted is None else jnp.sum(counted)
+        stats = jnp.stack([jnp.sum(takes), jnp.asarray(0), n_real * k,
+                           jnp.sum(tiles) * tile_rows(
+                               T * k, NE, config.router_width)])
+        return (out.astype(x.dtype).reshape(B, S, H),
+                stats.astype(jnp.int32))
+    takes = (top_i < NE) & jnp.broadcast_to(live[:, None, None],
+                                            (B, S, k)).reshape(T, k)
     # one_hot of an id past NE is all zeros: an absent expert's queue
     # does not exist here.
     flat = (jax.nn.one_hot(top_i, NE, dtype=jnp.int32)
@@ -524,70 +549,38 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     pos = jnp.cumsum(flat, axis=0) - flat
     slot = jnp.sum(flat * pos, axis=-1)                          # [T*k]
     sent = jnp.sum(flat, axis=0)                                 # [NE]
-    expert = top_i.reshape(T * k)
-    placed_any = takes.reshape(T * k)
-
-    def experts(xin, count):
-        if config.mlp_activation == "relu2":
-            up = q_einsum("ech,ehf->ecf", xin, lp["w_up_e"], count)
-            act = jnp.square(jax.nn.relu(up))
-        else:
-            gu = q_einsum("ech,ehf->ecf", xin, lp["wgu_e"], count)
-            F = gu.shape[-1] // 2
-            act = jax.nn.silu(gu[..., :F]) * gu[..., F:]
-        return q_einsum("ecf,efh->ech", act, lp["w_down"], count)
-
-    # A latent family's quarter-wide rows afford a placement matrix
-    # eight times the size.
-    by_matmul = _DISPATCH_MATMUL_ELEMS << (3 if latent is not None else 0)
-
-    def run(C: int):
-        count = jnp.minimum(sent, C)
-        idx = jnp.where(placed_any & (slot < C), expert * C + slot, NE * C)
-        if latent is not None and NE * C * T <= by_matmul:
-            at = idx.reshape(T, k)[..., None] == jnp.arange(NE * C)
-            place = jnp.sum(at, axis=1).astype(xt.dtype)         # [T,NE*C]
-            xin = _einsum_f32("ts,th->sh", place, xt).astype(
-                xt.dtype).reshape(NE, C, H)
-            # A slot has one source pair: its weight, summed exactly.
-            w_slot = jnp.sum(jnp.where(at, top_w[..., None], 0.0),
-                             axis=(0, 1))                        # [NE*C]
-            y = experts(xin, count).reshape(NE * C, H)
-            y = (y.astype(jnp.float32) * w_slot[:, None]).astype(xt.dtype)
-            return _einsum_f32("ts,sh->th", place, y)
-        if NE * C * T <= _DISPATCH_MATMUL_ELEMS:
-            # Rows into buckets as a 0/1 matrix times the tokens (exact:
-            # a slot has one source): a row-indexed scatter costs the
-            # TPU about 1.4 us an index, 0.35 ms a layer at decode's 256
-            # pairs and 1.2 ms at a chunk's 2,048 (PERF.md section 6,
-            # PR 30); this is a [NE*C, T] x [T, H] product.
-            place = jnp.sum(jax.nn.one_hot(idx.reshape(T, k), NE * C,
-                                           dtype=xt.dtype), axis=1)
-            xin = _einsum_f32("ts,th->sh", place, xt).astype(
-                xt.dtype).reshape(NE, C, H)
-        else:
-            xin = jnp.zeros((NE * C, H), xt.dtype).at[idx].set(
-                jnp.repeat(xt, k, axis=0), mode="drop").reshape(NE, C, H)
-        y = experts(xin, count)
-        got = jnp.take(y.reshape(NE * C, H), idx, axis=0, mode="fill",
-                       fill_value=0)
-        return jnp.sum(got.reshape(T, k, H).astype(jnp.float32)
-                       * top_w[..., None], axis=1)
-
-    small = max(8, (T // 4) // 8 * 8)
-    full = jnp.asarray(False)
-    if live is not None or small >= T:
-        out = run(T)
+    # A bucket of T slots holds whatever its expert was sent. Rows go
+    # into buckets as a 0/1 matrix times the tokens (exact: a slot has
+    # one source): a row-indexed scatter costs the TPU about 1.4 us an
+    # index, 0.35 ms a layer at decode's 256 pairs (PERF.md section 6,
+    # PR 30); this is a [NE*T, T] x [T, H] product over a step's rows.
+    # (``count`` and ``slot < T`` say nothing new, a bucket cannot
+    # overflow; they keep the decode programs the text they were.)
+    expert, placed = top_i.reshape(T * k), takes.reshape(T * k)
+    count = jnp.minimum(sent, T)
+    idx = jnp.where(placed & (slot < T), expert * T + slot, NE * T)
+    if latent is not None:
+        at = idx.reshape(T, k)[..., None] == jnp.arange(NE * T)
+        place = jnp.sum(at, axis=1).astype(xt.dtype)             # [T,NE*T]
+        xin = _einsum_f32("ts,th->sh", place, xt).astype(
+            xt.dtype).reshape(NE, T, H)
+        # A slot has one source pair: its weight, summed exactly.
+        w_slot = jnp.sum(jnp.where(at, top_w[..., None], 0.0),
+                         axis=(0, 1))                            # [NE*T]
+        y = ffn(xin, count, None).reshape(NE * T, H)
+        y = (y.astype(jnp.float32) * w_slot[:, None]).astype(xt.dtype)
+        out = _einsum_f32("ts,sh->th", place, y)
     else:
-        full = jnp.max(sent) > small
-        out = jax.lax.cond(full, lambda: run(T), lambda: run(small))
-    if live is not None:
-        n_live = jnp.sum(live) * S
-        stats = jnp.stack([jnp.sum(sent > 0), jnp.asarray(NE),
-                           n_live * k, jnp.sum(takes)])
-    else:
-        n_real = jnp.asarray(T) if counted is None else jnp.sum(counted)
-        stats = jnp.stack([jnp.sum(takes), jnp.asarray(0), n_real * k, full])
+        place = jnp.sum(jax.nn.one_hot(idx.reshape(T, k), NE * T,
+                                       dtype=xt.dtype), axis=1)
+        xin = _einsum_f32("ts,th->sh", place, xt).astype(
+            xt.dtype).reshape(NE, T, H)
+        got = jnp.take(ffn(xin, count, None).reshape(NE * T, H), idx, axis=0,
+                       mode="fill", fill_value=0)
+        out = jnp.sum(got.reshape(T, k, H).astype(jnp.float32)
+                      * top_w[..., None], axis=1)
+    stats = jnp.stack([jnp.sum(sent > 0), jnp.asarray(NE),
+                       jnp.sum(live) * S * k, jnp.sum(takes)])
     return (out.astype(x.dtype).reshape(B, S, H), stats.astype(jnp.int32))
 
 
@@ -598,7 +591,7 @@ def no_stats() -> jax.Array:
 def prefill_stats(config: ModelConfig) -> tuple[str, ...]:
     """The prefill entries above by name, for the scheduler that reads
     them (BatchScheduler._count_moe; mixtral.prefill_stats)."""
-    return ("assigned", "dropped", "routed", "full_layers")
+    return ("assigned", "dropped", "routed", "rows")
 
 
 no_touched = no_stats

@@ -797,12 +797,10 @@ class BatchScheduler:
         self._n_moe_local_pairs = 0      # owned-by: _loop
         # The same model's prefill dispatches that carried a request
         # (an admission, each chunk of its ladder, a prefix build), a
-        # routed layer each: how many there were, and those in which
-        # some held expert was sent more real pairs than the quarter
-        # bucket holds, so the layer ran buckets of every position
-        # (models/pangu._routed_local).
+        # routed layer each: how many there were. What each multiplied
+        # is _n_moe_prefill_rows, beside _n_moe_local_pairs: its
+        # prefills go onto tiles too (models/pangu._routed_local).
         self._n_moe_prefill_layers = 0       # owned-by: _loop
-        self._n_moe_full_bucket_layers = 0   # owned-by: _loop
         # Cache rows the decode steps' live rows attended (the sum, over
         # row-steps, of the row's context length at that step).
         self._n_attn_ctx_tokens = 0      # owned-by: _loop
@@ -1787,7 +1785,6 @@ class BatchScheduler:
         if "routed" in n:
             self._n_moe_routed_pairs += n["routed"]
             self._n_moe_local_pairs += n["assigned"]
-            self._n_moe_full_bucket_layers += n["full_layers"]
             self._n_moe_prefill_layers += (dispatches
                                            * self.config.routed_layers)
 
@@ -3673,11 +3670,14 @@ class BatchScheduler:
                 self._n_moe_decode_touched
             out["serve_moe_decode_expert_slots_total"] = \
                 self._n_moe_decode_slots
-        if "rows" in self._moe_prefill:
-            # A dropless Mixtral-family model: the rows its prefills'
-            # expert matmuls ran over (filled tiles x rows a tile),
-            # beside serve_moe_assignments_total, the pairs they were
-            # for: rows / pairs is what the tiles' padding costs.
+        if self.config.is_moe and "rows" in self._moe_prefill:
+            # A model whose prefills go sorted onto tiles (a dropless
+            # Mixtral-family model; the held-range and hybrid families):
+            # the rows their expert matmuls ran over (filled tiles x
+            # rows a tile), beside serve_moe_assignments_total, the
+            # pairs they were for (of a held range, those routed to a
+            # held expert): rows / pairs is what the tiles' padding
+            # costs.
             out["serve_moe_prefill_rows_total"] = self._n_moe_prefill_rows
         if self.config.router_width > self.config.num_experts:
             # A share of the experts is held here: pairs routed (prefill
@@ -3685,11 +3685,9 @@ class BatchScheduler:
             out["serve_moe_routed_pairs_total"] = self._n_moe_routed_pairs
             out["serve_moe_local_pairs_total"] = self._n_moe_local_pairs
             # Routed layers of the prefill dispatches that carried a
-            # request, and those that ran buckets of every position.
+            # request.
             out["serve_moe_prefill_layers_total"] = \
                 self._n_moe_prefill_layers
-            out["serve_moe_full_bucket_layers_total"] = \
-                self._n_moe_full_bucket_layers
         if self.config.ssm_layers:
             # Recurrent state beside the pages (ops/state_pool.py): the
             # pool's bytes, the slots holding a live row's state, and

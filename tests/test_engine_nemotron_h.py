@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from p2p_llm_chat_tpu.models import family_for, nemotron_h
+from p2p_llm_chat_tpu.models import family_for, moe_tiles, nemotron_h
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import state_pool
@@ -173,19 +173,22 @@ def test_admission_chunks_prefix_fused_decode_slot_reuse_and_counters(
 def test_prefill_layer_counters_over_windows(qparams):
     """``serve_moe_prefill_layers_total`` is the routed layers of every
     prefill dispatch that carried a request (an admission, each chunk of
-    a ladder, a prefix build) and ``serve_moe_full_bucket_layers_total``
-    those of them that ran the all-T buckets, as differences between
-    snapshots. A three-token prompt in its bucket of padding cannot send
-    a held expert more than the quarter bucket holds, so it reads 0
-    however the padding routes; decode's use of the same entry of the
-    counts (pairs to held experts) goes on counting pairs beside it."""
+    a ladder, a prefix build) and ``serve_moe_prefill_rows_total`` the
+    rows their experts multiplied, filled tiles x rows a tile, beside
+    ``serve_moe_assignments_total``, the pairs those were for (the
+    prefills' share of ``serve_moe_local_pairs_total``), as differences
+    between snapshots: never fewer rows than pairs, never more than a
+    part-filled tile a held expert a layer over them. (Until PR 43 the
+    second counter here was the layers that ran all-T buckets.)
+    Decode's use of the same entry of the counts (pairs to held
+    experts) goes on counting pairs beside it."""
     head = "hybrid shared head, "
     eng = TPUEngine(qparams, CFG, TOK, num_slots=4, max_seq=256,
                     page_size=16, kv_quant=True, prefix_cache=True,
                     prefix_texts=(), decode_fuse_max=4, prefill_chunk=32)
     E = CFG.routed_layers
     names = ("serve_moe_prefill_layers_total",
-             "serve_moe_full_bucket_layers_total",
+             "serve_moe_prefill_rows_total",
              "serve_moe_assignments_total", "serve_moe_local_pairs_total",
              "prefill_chunks_total", "serve_decode_row_steps_total")
 
@@ -193,26 +196,38 @@ def test_prefill_layer_counters_over_windows(qparams):
         m1 = eng.metrics_snapshot()
         return [m1[n] - m0[n] for n in names], m1
 
+    def padding(rows, held, layers):
+        """Rows over pairs: at least 1, at most a part-filled tile (of
+        under 128 rows) an expert a layer more."""
+        assert held <= rows < held + layers * CFG.num_experts * 128
+        assert rows % 8 == 0
+
     try:
         m = eng.metrics_snapshot()
         assert m[names[0]] == m[names[1]] == 0
         run(eng, "hi", max_tokens=6)
-        (layers, full, held, local, chunks, steps), m = window(m)
-        assert (layers, full, chunks) == (E, 0, 0)
+        (layers, rows, held, local, chunks, steps), m = window(m)
+        assert (layers, chunks) == (E, 0)
+        padding(rows, held, layers)
+        # Three real positions: 9 pairs a layer at the most, so no
+        # expert's run passes one tile.
+        assert 0 < held <= 3 * CFG.num_experts_per_tok * E
+        assert rows <= E * CFG.num_experts * moe_tiles.tile_rows(
+            32 * CFG.num_experts_per_tok, CFG.num_experts, CFG.router_width)
         per_token = CFG.num_experts_per_tok * E
         assert 0 < local - held < per_token * steps
         # A ladder of four chunks, the last of them mostly padding.
         run(eng, "y" * 110, max_tokens=2)
-        (layers, full, _, _, chunks, _), m = window(m)
+        (layers, rows, held, _, chunks, _), m = window(m)
         assert chunks == 4 and layers == 4 * E
-        assert 0 <= full <= layers
+        padding(rows, held, layers)
         # A prefix build's counts wait for the next admission's read.
         eng.scheduler.register_prefix(head)
-        assert window(m)[0][0] == 0
+        assert window(m)[0][:2] == [0, 0]
         run(eng, head + "ok", max_tokens=2)
-        (layers, full, _, _, chunks, _), m = window(m)
+        (layers, rows, held, _, chunks, _), m = window(m)
         assert chunks == 0 and layers == 2 * E
-        assert 0 <= full <= layers
+        padding(rows, held, layers)
     finally:
         eng.stop()
 

@@ -454,23 +454,32 @@ def test_routed_layer_is_dropless_and_counts_its_pairs(qparams):
 @pytest.mark.parametrize("shape,real", [((2, 24), (24, 9)), ((1, 16), (16,)),
                                         ((3, 4), (4, 0, 2))])
 def test_tile_dispatch_equals_the_bucket_dispatch(plain, shape, real):
-    """``_routed_tiles`` (pairs sorted by expert, runs padded to tiles)
-    gives what pangu._routed_local's dropless buckets give, padding sent
-    nowhere, with the same counts: float32 on both sides, so what is left
-    is the order of a token's k-term sum."""
+    """A prefill of this family (``live`` None: pairs sorted by expert,
+    runs padded to tiles; since PR 43 the prefill half of
+    pangu._routed_local itself) gives what the dropless buckets of its
+    decode half give, padding sent nowhere, with the counts of the
+    pairs: float32 on both sides, so what is left is the order of a
+    token's k-term sum. Its last count is the rows of the filled
+    tiles."""
     from p2p_llm_chat_tpu.models import pangu
     sched, _ = plain
     lp = nemotron_h._layer_view(sched._params["moe"], jnp.asarray(3))
     B, S = shape
     x = jax.random.normal(jax.random.PRNGKey(9), (B, S, CFG.hidden_size))
     counted = jnp.arange(S)[None, :] < jnp.asarray(real)[:, None]
-    got, st = nemotron_h._routed_tiles(x, lp, CFG, counted)
-    want, st_w = pangu._routed_local(x, lp, CFG, counted, None)
+    got, st = pangu._routed_local(x, lp, CFG, counted, None)
+    want, _ = pangu._routed_local(x, lp, CFG, None, jnp.ones((B,), bool))
+    want = jnp.where(counted[..., None], want, 0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
-    assert list(np.asarray(st[:3])) == list(np.asarray(st_w[:3]))
+    pairs = int(counted.sum()) * CFG.num_experts_per_tok
+    tm = moe_tiles.tile_rows(B * S * CFG.num_experts_per_tok,
+                             CFG.num_experts)
+    assert list(np.asarray(st[:3])) == [pairs, 0, pairs]
+    assert pairs <= int(st[3]) < pairs + CFG.num_experts * tm
+    assert int(st[3]) % tm == 0
     assert not np.asarray(got)[~np.asarray(counted)].any()
-    free, _ = nemotron_h._routed_tiles(x, lp, CFG, None)
-    want, _ = pangu._routed_local(x, lp, CFG, None, None)
+    free, _ = pangu._routed_local(x, lp, CFG, None, None)
+    want, _ = pangu._routed_local(x, lp, CFG, None, jnp.ones((B,), bool))
     np.testing.assert_allclose(np.asarray(free), np.asarray(want), atol=1e-5)
 
 

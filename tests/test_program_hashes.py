@@ -38,7 +38,36 @@ What this fence cannot see: the test sizes have 8 experts, and
 experts outnumber an expert's even share of the pairs, which 8 experts
 never do. Mellum's real-width programs under 512 tokens a dispatch did
 move; tests/test_mellum_parity.py pins the rule over its bucket set
-with the parent's values beside them."""
+with the parent's values beside them.
+
+PR 43 moved the prefill half of models/pangu._routed_local (``live``
+None) from buckets onto the same tiles, for the latent-attention family
+and for the hybrid family's LatentMoE; Mellum's routed layers, which
+were on the tiles already, now reach them through that function
+(nemotron_h._routed_tiles is gone). The tool now also lowers the
+latent-attention family's ``_counted`` prefills and its verify step (a
+session wake: more than one position a row, ``live`` None, so a prefill
+and on tiles), and the ``_counted`` chunk of the two hybrid sizes that
+route. ``PR43`` holds the eight digests that differ from the parent's,
+taken on PR 43 itself; on the parent (commit 1715d06, the new tool run
+on it) they read
+``tiny-pangu.prefill`` 985aa303b104,
+``tiny-pangu.prefill_chunk`` 81763f0731f3,
+``tiny-pangu.prefill_counted`` 2ce771961429,
+``tiny-pangu.prefill_chunk_counted`` 9f448938712e,
+``tiny-pangu.verify_step_paged`` e0be7e78d05e,
+``tiny-nemotron-h.prefill_chunk`` 2a8087dca4b7,
+``tiny-nemotron-h.prefill_chunk_counted`` 472f5d375948,
+``tiny-mellum2.prefill_chunk_counted`` be88d39debc0.
+The last moved by ONE thing: the fourth entry of the counts is now the
+rows the tiles multiplied (``sum(tiles) x rows a tile``, which the
+parent computed and dropped) where it was a constant 0; the maskless
+``tiny-mellum2.prefill_chunk``, whose counts nobody reads, lowers to
+the parent's text byte for byte and keeps its pin in ``PARENT`` (ISSUE
+43 expected it to move by that sum; it does not, the sum is dead code
+there). Every decode digest of the three families holds: the decode
+half of ``_routed_local`` is the bucket path as it lowered, written
+straight where it was one branch of several."""
 
 import os
 import sys
@@ -75,8 +104,6 @@ PARENT = {
     # bdbf057): PR 32 widened its routed dispatch (a selection bias, the
     # experts' activation, a latent width) for a fourth family and must
     # not have moved what this one lowers to.
-    "tiny-pangu.prefill": "985aa303b10468b79d2b05d6785e7afd33d26f99c482d1168720081836f5ab1d",
-    "tiny-pangu.prefill_chunk": "81763f0731f3298e1db6cda5f17aa52d2f7f08b63abc1c5140e25bc17adba7e6",
     "tiny-pangu.decode_step_paged": "e4a6fbb06dba46721175ad3bc256f89b02b89535b6370f404d7c1e04a126ec74",
     "tiny-pangu.decode_fused": "1cb56c7e7e4a94045b891fcc67daac39af0f40122886d008616f884844c13c7c",
     "tiny-pangu.write_prefill_batch": "9ecc20c4c0cb1821f120c465568e16e13b8a4cf8dbfae3bc0a7a30e362987f50",
@@ -84,7 +111,6 @@ PARENT = {
     # The hybrid family, taken on PR 42's parent (commit 3b42824): PR 42
     # made nemotron_h._routed_tiles a call of models/moe_tiles.py and
     # must not have moved what Mellum's, Nemotron's or Phi's lower to.
-    "tiny-nemotron-h.prefill_chunk": "2a8087dca4b7c76675fcf30ba7ba09d3daf5fa2482627bb7ef93cdca8f6d5d56",
     "tiny-nemotron-h.decode_step_paged": "932546385b870da5fba9df1ac26696a6ec04e841cbd52bf57a893874eedc6487",
     "tiny-phi4flash.prefill_chunk": "8f22bad36d77b59b4b29d01eb0642c0bf38b92a9eb46da2668a4becb7859b4fc",
     "tiny-phi4flash.decode_step_paged": "0d0c35622a61befdd2259ea103ab478fbb6979015c794e9d36babf6733d4b15d",
@@ -99,7 +125,19 @@ PR42 = {
     "tiny-olmoe.prefill_counted": "66afbe3371e2d0a62780b7b94b9f392be476ed44b14be877c71d0e68515260ef",
     "tiny-olmoe.prefill_chunk_counted": "9ed598610acfe30f91ae98fc058c9d17c17b7fc4fd749db6cb387bfeee56387e"
 }
-PINNED = {**PARENT, **PR42}
+# Taken on PR 43 itself: the programs it meant to change, and the ones
+# the tool newly lowers for the families it touched (docstring).
+PR43 = {
+    "tiny-pangu.prefill": "e809892fb885f1814b6c626e5aa9e3a5d10cb05eed7d353d054aa6fb900f502c",
+    "tiny-pangu.prefill_chunk": "cee662ecbecda0f00f1a22da12f7d5f6d739d6cc895f1bebc2535e69d1d1882a",
+    "tiny-pangu.prefill_chunk_counted": "eb8af9a8c1ae8570ee9fd19cbc2cc398b9c41d036baf878bd1ad43028f5f9242",
+    "tiny-pangu.prefill_counted": "292b11090d2e1deff9b6c020d061e98c60931239d617cbab74b10bffda5c2f69",
+    "tiny-pangu.verify_step_paged": "6f275815983c9c98b6b383624f3aa20afbd5dfc5d787dfa9703ba893f32e3e79",
+    "tiny-nemotron-h.prefill_chunk": "8a775d1a6c69808dc370820cc95ce93f05fda8c4088b9bb8207155f6354e6e90",
+    "tiny-nemotron-h.prefill_chunk_counted": "e6df4473e41757e5848ec6ab476bbbee47449f5b5a40a3f806fc386909ff68fc",
+    "tiny-mellum2.prefill_chunk_counted": "94e53d00357a558e0bfb394d3fc190fd1f56ea9ffeb6ed3079a34308a1c677a4"
+}
+PINNED = {**PARENT, **PR42, **PR43}
 
 
 @pytest.fixture(scope="module")

@@ -20,10 +20,12 @@ from its launch to ``block_until_ready`` on what it returned.
 
 Prints one JSON line a program and writes them all to
 ``chiprun_out/admit_pair.<name>.json``. Where the model's prefills count
-the rows their experts multiplied (a dropless Mixtral-family model:
-``serve_moe_prefill_rows_total``), a dispatch that ends an admission
-also reads ``moe_pairs`` and ``moe_rows`` from the counts behind its
-first tokens (a ladder's are summed over its chunks).
+the rows their experts multiplied (a dropless Mixtral-family model, and
+the held-range and hybrid families: ``serve_moe_prefill_rows_total``),
+a dispatch that ends an admission also reads ``moe_pairs`` and
+``moe_rows`` from the counts behind its first tokens (a ladder's are
+summed over its chunks; of a held range the pairs are those routed to
+an expert held here).
 """
 
 from __future__ import annotations

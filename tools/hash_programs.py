@@ -2,10 +2,11 @@
 latent-attention and hybrid families lower to on the CPU at test size:
 the programs the scheduler serves with (one-shot and chunked prefill, a
 paged int8 decode step with its pool write, a fused decode, the
-admission splice; for a routed Mixtral-family model also the
-``_counted`` prefills, which are what an admission runs, and a verify
-step, which is also what a session wake runs; for the hybrid
-family's three test sizes a prefill chunk and a decode step). A PR that
+admission splice; for a routed Mixtral-family or latent-attention model
+also the ``_counted`` prefills, which are what an admission runs, and a
+verify step, which is also what a session wake runs; for the hybrid
+family's three test sizes a prefill chunk and a decode step, and for
+the two that route the ``_counted`` chunk). A PR that
 must not move another family's programs runs this on its parent and on itself
 (``PYTHONPATH=<checkout> python tools/hash_programs.py``) and pins the
 parent's digests in tests/test_program_hashes.py.
@@ -26,7 +27,8 @@ def programs(name: str) -> dict:
     """label -> StableHLO text of ``name``'s programs."""
     import jax
     import jax.numpy as jnp
-    from p2p_llm_chat_tpu.models import family_for, get_config, mixtral
+    from p2p_llm_chat_tpu.models import (family_for, get_config, mixtral,
+                                         pangu)
     from p2p_llm_chat_tpu.models.llama import KVCache
     from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,
                                                write_prefill_batch,
@@ -71,22 +73,25 @@ def programs(name: str) -> dict:
     }
     if cfg.is_hybrid:
         fns = {label: fns[label] for label in HYBRID_LABELS}
-    elif model is mixtral:
-        # What an admission of a routed Mixtral-family model runs: the
-        # mask of real positions goes in, the counts come out.
-        valid = jax.ShapeDtypeStruct((B, S), jnp.bool_)
-        fns["prefill_counted"] = (
-            lambda p, t, l, c, v: model.prefill_counted(
-                p, cfg, t, l, c, v, last_only=True),
-            (params, toks, lens, small, valid))
+    if cfg.routed_layers:
+        # What an admission of a routed model runs: the mask of real
+        # positions goes in, the counts come out.
         fns["prefill_chunk_counted"] = (
             lambda p, t, c, v: model.prefill_chunk_counted(
                 p, cfg, t, c, C, v),
             (params, jax.ShapeDtypeStruct((B, C), jnp.int32), small,
              jax.ShapeDtypeStruct((B, C), jnp.bool_)))
+    if model in (mixtral, pangu):
+        valid = jax.ShapeDtypeStruct((B, S), jnp.bool_)
+        fns["prefill_counted"] = (
+            lambda p, t, l, c, v: model.prefill_counted(
+                p, cfg, t, l, c, v, last_only=True),
+            (params, toks, lens, small, valid))
         # ... and what a speculative verify and a session wake run: more
-        # than one position a row with no mask and no capacity, whatever
-        # the configuration's factor (their bucket is exact).
+        # than one position a row with no mask (the Mixtral family: no
+        # capacity either, whatever the configuration's factor, and an
+        # exact bucket; the latent-attention family: a prefill, on
+        # tiles).
         fns["verify_step_paged"] = (
             lambda p, t, c: model.verify_step_paged(p, cfg, t, c, pages=4),
             (params, jax.ShapeDtypeStruct((B, 5), jnp.int32), pool))
