@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..parallel.sharding import LogicalRules, DEFAULT_RULES, constrain
-from ..utils.device import pallas_interpret
 from .configs import ModelConfig
 from .quant import LayerSlice, QTensor, QTensor4, mm
 from .layers import (
@@ -1032,19 +1031,16 @@ def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     contract (S candidate positions, lengths unchanged; caller advances
     by accepted+1) on a PagedKVCache.
 
-    Structure mirrors decode_step_paged's default path: position j
-    attends the pool window plus block positions i <= j from the
-    in-register k/v (ops/paged_attention.paged_attention_verify_append —
-    one softmax over the concatenated scores, identical results to the
-    write-then-attend ordering), the scan stacks each layer's block k/v,
-    and ONE batched scatter lands everything afterwards
+    Structure mirrors decode_step_paged: position j attends the pool
+    window plus block positions i <= j from the in-register k/v
+    (ops/paged_attention.paged_attention_verify_append — one softmax
+    over the concatenated scores), the scan stacks each layer's block
+    k/v, and ONE batched scatter lands everything afterwards
     (write_decode_multi_all_layers — positions past a row's allocation
     land in garbage page 0, so rollback/containment is inherent). The
     weight stream, the quantity speculation amortises, is still read
-    once. ``pages`` must cover ``lengths`` on the gather path and
-    ``lengths + S`` on the non-gather impls, which keep the per-layer
-    write-then-attend ordering and read the drafts back from the pool
-    (the scheduler sizes for ``kv_window + S``, covering both).
+    once. ``pages`` must cover ``lengths`` (the scheduler sizes for
+    ``kv_window + S``).
 
     Unlike the decode tick, verify stays on the gather path at EVERY
     window: the flash-append kernel is single-position (its online-
@@ -1053,13 +1049,9 @@ def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     are landing — a multi-position flash verify is recorded headroom,
     not a gap (docs/serving.md round-8).
     """
-    from ..ops import paged_attention
-    from ..ops.paged_attention import (_DEFAULT_IMPL,
-                                       paged_attention_verify_append)
-    from ..ops.paged_kv import (write_decode_multi,
-                                write_decode_multi_all_layers)
+    from ..ops.paged_attention import paged_attention_verify_append
+    from ..ops.paged_kv import write_decode_multi_all_layers
 
-    interpret = pallas_interpret()
     cache = _constrain_pool(cache, mesh, rules)
     B, S = tokens.shape
     positions = cache.lengths[:, None] + jnp.arange(S)[None, :]    # [B,S]
@@ -1081,44 +1073,17 @@ def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
         logits = mm(h, lm_head).astype(jnp.float32)
         return constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
 
-    if _DEFAULT_IMPL == "gather":
-        def body(h, layer):
-            lp = _layer_view(params["layers"], layer)
-            q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh,
-                                rules)
-            attn = paged_attention_verify_append(
-                q, k, v, cache, cache.lengths, layer, pages=pages)
-            h = _post_attn(h, attn, lp, config, mesh, rules, mlp_fn)
-            return h, (k, v)
-
-        h, (k_all, v_all) = jax.lax.scan(
-            body, h, jnp.arange(config.num_layers))
-        cache = write_decode_multi_all_layers(cache, k_all, v_all)
-        return finish(h), cache
-
-    def body(carry, layer):
-        h, pk, pv, sk, sv = carry
+    def body(h, layer):
         lp = _layer_view(params["layers"], layer)
         q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh, rules)
-        step_cache = cache._replace(k=pk, v=pv, k_scale=sk, v_scale=sv)
-        step_cache = write_decode_multi(step_cache, layer, k, v)
-        outs = []
-        for j in range(S):         # static unroll — S = spec_k+1, small
-            outs.append(paged_attention(
-                q[:, j], step_cache.k, step_cache.v, cache.page_table,
-                cache.lengths + j + 1, layer, pages=pages,
-                interpret=interpret, k_scale=step_cache.k_scale,
-                v_scale=step_cache.v_scale))
-        attn = jnp.stack(outs, axis=1)                             # [B,S,H,D]
+        attn = paged_attention_verify_append(
+            q, k, v, cache, cache.lengths, layer, pages=pages)
         h = _post_attn(h, attn, lp, config, mesh, rules, mlp_fn)
-        return (h, step_cache.k, step_cache.v, step_cache.k_scale,
-                step_cache.v_scale), None
+        return h, (k, v)
 
-    (h, new_k, new_v, new_sk, new_sv), _ = jax.lax.scan(
-        body, (h, cache.k, cache.v, cache.k_scale, cache.v_scale),
-        jnp.arange(config.num_layers))
-    return finish(h), cache._replace(k=new_k, v=new_v, k_scale=new_sk,
-                                     v_scale=new_sv)
+    h, (k_all, v_all) = jax.lax.scan(body, h, jnp.arange(config.num_layers))
+    cache = write_decode_multi_all_layers(cache, k_all, v_all)
+    return finish(h), cache
 
 
 def verify_tree_paged(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -1128,10 +1093,10 @@ def verify_tree_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                       *, pages: int, mlp_fn=None):
     """:func:`verify_tree` on a PagedKVCache.
 
-    Always rides verify_step_paged's gather path (regardless of the
-    decode impl): every node's query attends the committed pool window
-    (ops/paged_attention._gather_window_scores — ``pos < lengths`` is
-    already branch-agnostic) plus the in-register block k/v filtered by
+    verify_step_paged's walk: every node's query attends the committed
+    pool window (ops/paged_attention._gather_window_scores — ``pos <
+    lengths`` is already branch-agnostic) plus the in-register block k/v
+    filtered by
     the ancestor matrix ``anc`` instead of the chain-causal triangle.
     RoPE positions are ``lengths+depths``; ONE batched scatter lands
     node i at pool position ``lengths+i`` afterwards
@@ -1183,39 +1148,33 @@ def decode_step_paged_aux(params: dict, config: ModelConfig,
     Same contract as :func:`decode_step` — including the parked-row
     invariant, which paging strengthens: a released row's zeroed page
     table routes its garbage writes to the shared garbage page, so parked
-    rows cannot touch any live page. Attention runs the Pallas
-    flash-decode kernel (ops/paged_attention.py) walking ``pages`` table
+    rows cannot touch any live page. Attention walks ``pages`` table
     entries per row (the serving window ladder:
     ``pages = ceil(window / page_size)``).
 
     cache: ops.paged_kv.PagedKVCache. Returns (logits [B,1,vocab], cache
     with lengths advanced where active, aux).
 
-    Structure note: the default (gather-impl) path attends BEFORE the
-    pool write — the current token's k/v folds into attention via one
-    exact online-softmax merge (ops/paged_attention.
-    paged_attention_append) — and the scan stacks each layer's k/v so
-    ONE batched scatter lands the whole step afterwards
-    (write_decode_all_layers). Per-layer pool scatters inside the scan
-    carry a fixed cost that was measurable against the decode bandwidth
-    bound. Non-gather attention impls keep the write-then-attend
-    ordering (their kernels read the pool for every position).
+    Structure note: a layer attends BEFORE the pool write — the current
+    token's k/v folds into attention via one exact online-softmax merge
+    (ops/paged_attention.paged_attention_append) — and the scan stacks
+    each layer's k/v so ONE batched scatter lands the whole step
+    afterwards (write_decode_burst). Per-layer pool scatters inside the
+    scan carry a fixed cost that was measurable against the decode
+    bandwidth bound.
 
-    Impl selection is delegated per layer call: paged_attention_append
-    itself promotes to the multi-chunk flash-append kernel at windows
-    >= PAGED_APPEND_FLASH_MIN_W (1024) on TPU — the round-8 long-window
-    default — and the decision is made ONCE per trace (the scan body
-    traces once), so the serving scheduler's per-window jitted programs
-    each bake in exactly one impl and warmup compiles the whole
-    gather/kernel ladder up front (serve/scheduler.warmup).
+    paged_attention_append chooses its implementation per layer call,
+    from the window and the pool's geometry alone (the XLA gather below
+    the flash boundary, the multi-chunk flash-append kernel from it up
+    on a TPU), ONCE per trace (the scan body traces once), so the
+    serving scheduler's per-window jitted programs each bake in exactly
+    one implementation and warmup compiles the whole ladder up front
+    (serve/scheduler.warmup).
     """
-    from ..ops import paged_attention
-    from ..ops.paged_kv import PagedKVCache, write_decode, write_decode_burst
-    from ..ops.paged_attention import _DEFAULT_IMPL, paged_attention_append
+    from ..ops.paged_kv import write_decode_burst
+    from ..ops.paged_attention import paged_attention_append
 
-    interpret = pallas_interpret()
     cache = _constrain_pool(cache, mesh, rules)
-    B = tokens.shape[0]
     positions = cache.lengths[:, None]                 # [B,1]
     h = params["embed"][tokens]
     h = constrain(h, mesh, ("batch", None, "act_embed"), rules)
@@ -1230,46 +1189,20 @@ def decode_step_paged_aux(params: dict, config: ModelConfig,
         logits = mm(h, lm_head).astype(jnp.float32)
         return constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
 
-    if _DEFAULT_IMPL == "gather":
-        def body(carry, layer):
-            h, aux = carry
-            fn, aux_after = _mlp_carrying(mlp_fn, aux)
-            lp = _layer_view(params["layers"], layer)
-            q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh,
-                                rules)
-            attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
-                                          cache.lengths, layer, pages=pages,
-                                          interpret=interpret,
-                                          sharded=mesh is not None)
-            h = _post_attn(h, attn[:, None], lp, config, mesh, rules, fn)
-            return (h, aux_after()), (k[:, 0], v[:, 0])
-
-        (h, aux), (k_all, v_all) = jax.lax.scan(
-            body, (h, mlp_aux), jnp.arange(config.num_layers))
-        return finish(h), write_decode_burst(cache, k_all, v_all, inc), aux
-
     def body(carry, layer):
-        h, pk, pv, sk, sv, aux = carry
+        h, aux = carry
         fn, aux_after = _mlp_carrying(mlp_fn, aux)
         lp = _layer_view(params["layers"], layer)
         q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh, rules)
-        step_cache = cache._replace(k=pk, v=pv, k_scale=sk, v_scale=sv)
-        step_cache = write_decode(step_cache, layer, k[:, 0], v[:, 0])
-        attn = paged_attention(q[:, 0], step_cache.k, step_cache.v,
-                               cache.page_table, cache.lengths + 1, layer,
-                               pages=pages, interpret=interpret,
-                               k_scale=step_cache.k_scale,
-                               v_scale=step_cache.v_scale)
+        attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
+                                      cache.lengths, layer, pages=pages,
+                                      sharded=mesh is not None)
         h = _post_attn(h, attn[:, None], lp, config, mesh, rules, fn)
-        return (h, step_cache.k, step_cache.v, step_cache.k_scale,
-                step_cache.v_scale, aux_after()), None
+        return (h, aux_after()), (k[:, 0], v[:, 0])
 
-    (h, new_k, new_v, new_sk, new_sv, aux), _ = jax.lax.scan(
-        body, (h, cache.k, cache.v, cache.k_scale, cache.v_scale, mlp_aux),
-        jnp.arange(config.num_layers))
-    return finish(h), cache._replace(k=new_k, v=new_v, k_scale=new_sk,
-                                     v_scale=new_sv,
-                                     lengths=cache.lengths + inc), aux
+    (h, aux), (k_all, v_all) = jax.lax.scan(
+        body, (h, mlp_aux), jnp.arange(config.num_layers))
+    return finish(h), write_decode_burst(cache, k_all, v_all, inc), aux
 
 
 def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,

@@ -114,7 +114,6 @@ from ..ops import diff_attention, state_pool
 from ..ops.diff_attention import FlatKeys, Keys
 from ..ops.state_pool import StatePool
 from ..parallel.sharding import LogicalRules, DEFAULT_RULES
-from ..utils.device import pallas_interpret
 from .configs import ModelConfig
 from .layers import (DEFAULT_COMPUTE_DTYPE, FLASH_KV_CHUNK, NEG_INF,
                      apply_rope, attend_gqa_auto, causal_mask, rms_norm,
@@ -604,8 +603,7 @@ def _attn_decode(h, lp, config: ModelConfig, cache, layer: int, pages: int):
     B = h.shape[0]
     q, k, v = _qkv(h, lp, config, cache.lengths[:, None])
     attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
-                                  cache.lengths, layer, pages=pages,
-                                  interpret=pallas_interpret())
+                                  cache.lengths, layer, pages=pages)
     return mm(attn.reshape(B, 1, config.q_dim), lp["wo"]), k[:, 0], v[:, 0]
 
 

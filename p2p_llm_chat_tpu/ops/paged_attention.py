@@ -1,105 +1,48 @@
-"""Decode attention over the paged KV pool — gather path + Pallas kernel.
+"""Decode attention over the paged KV pool.
 
-The decode-attention op named by the north star (BASELINE.json; the
-reference has no kernels at all — its attention lives inside Ollama,
-web/streamlit_app.py:91). One query token per batch row attends to that
-row's live context through its page table. Two interchangeable
-implementations, both pinned to the same oracle (tests/test_ops_paged.py):
+One query token per batch row attends that row's live context through
+its page table, BEFORE the step's own k/v is in the pool: the current
+token folds in as one extra softmax term and the caller lands every
+layer's k/v with one batched scatter after its layer scan
+(ops/paged_kv.write_decode_burst). Three entry points:
 
-- ``impl="gather"`` (default): gather each row's pages as whole
-  contiguous ``[page_size, Hkv, D]`` blocks (B x pages block reads — the
-  token-major pool layout makes the result a pure reshape, no
-  transpose), then run the fused dense GQA attend. XLA fuses the mask/
-  softmax chain, and the gathered window is the same bytes a dense cache
-  would read. Pure-XLA, so it is also the fast path for CPU tests.
-- ``impl="kernel"``: a Pallas flash-decode kernel, grid ``(B, pages)``,
-  each program DMA-ing one whole page (``[page_size, Hkv, D]`` — full
-  trailing block dims, the layout Mosaic lowers without relayouts) via
-  scalar-prefetched page-table indices, accumulating online-softmax
-  state in VMEM scratch across the page walk.
+- :func:`paged_attention_append` — the decode tick (models/llama.py,
+  models/mixtral.py through it, models/nemotron_h.py's page layers).
+- :func:`paged_attention_verify_append` — a block of S positions a row
+  (speculative verify, a session wake's suffix): the pool window plus
+  the in-register block under a causal or tree mask.
+- :func:`gather_window` — one layer's window gathered once, for a layer
+  whose pages other layers read too (models/nemotron_h.py's cross
+  layers).
 
-Measured on a v5e chip at serving shapes (B=32, bench-1b, W=192): the
-gather path wins and is the default everywhere. Two history lessons,
-for the record. (1) The first kernel used grid ``(B, Hkv, pages)`` over
-a head-major pool layout — 8x more programs, each fetching a strided
-``[page_size, D]`` tile — and per-program overhead made the full step
-227 ms: few big blocks beat many small ones. (2) Round 4 rebuilt the
-append path as a Pallas kernel three ways (manual page DMAs; gathered
-windows with per-head dots; gathered windows with GQA-as-selection-
-matmuls) and every variant lost to XLA's gather + fused VPU math — see
-_append_kernel's docstring for the numbers. The durable round-4 wins
-were XLA-side instead: joint (layer, page) indexing so the gather reads
-only the window (not a materialised layer slice), and head-major
-lane-padded scale storage so the scale arrays stop layout-thrashing in
-the decode carry (together ~0.7 ms off a 3.9 ms step).
+``paged_attention_append`` has two implementations, the same f32 softmax
+over the same scores:
 
-``PAGED_ATTN_IMPL`` selects the process-wide default; ``interpret=True``
-runs the kernel on CPU for hardware-free tests (SURVEY.md §4);
-:func:`paged_attention_reference` is the jnp oracle.
+- :func:`_append_gather` — XLA: one joint (layer, page) gather of each
+  row's whole ``[page_size, Hkv, D]`` pages (the token-major pool makes
+  the window a pure reshape), scores, the merge. Runs everywhere; its
+  cost follows the WINDOW (the gathered, dequantised copy is
+  ``B x W x hd``).
+- :func:`_paged_attention_flash_append` — a Pallas kernel, grid ``(row,
+  chunk)``: each program DMAs one bounded chunk of pages (and scale
+  rows) and folds it into online-softmax state held in VMEM scratch
+  across the chunk axis; a chunk that starts past its row's length is
+  neither fetched nor folded, so its cost follows the rows' LENGTHS.
+  HBM sees each live page once. TPU only.
 
-Round-5 closure of the short-window kernel question (the round-4
-verdict's "(B x Hkv)-grid with rep folded into the dot"): the shape is
-settled by launch arithmetic derived from the kernels already measured
-here. Attention must run inside the per-layer scan (layer i+1's q
-depends on layer i's output), so ANY kernel pays 22 launches per step;
-the flash kernel's measured overhead is ~1 us per program (32 programs
-x 22 calls = 704 programs, 1.4 ms total vs its 0.7 ms byte bound). A
-(B x Hkv) grid is B*Hkv = 256 programs x 22 calls = 5,632 programs
-~= 5.6 ms of program overhead alone — 2x the ENTIRE 2.97 ms step. The
-gather path's only waste is the materialise round trip of the bf16
-window (~0.5 ms/step at W=192), strictly smaller than any per-program
-overhead a Pallas grid can reach at these shapes. The calculus flips
-at long windows, where the materialise waste grows linearly with W
-(~33 ms of the 40 ms step at W=4096) and per-program overhead does
-not — which is why the flash-APPEND kernel below owns that regime.
+The rule that chooses (:func:`_flash_append_policy`, guarded by
+:func:`flash_append_blocked`): the kernel from ``W >= max(256, 1024 *
+1024 / max(hd, 1024))`` with ``hd = Hkv * head_dim``, the gather below
+it and wherever the kernel cannot run (no TPU, a pool sharded over a
+mesh, a head_dim that does not fill 128 lanes, an int8 pool of fewer
+than 4 kv heads). A function of window and pool geometry alone, decided
+once per trace; its measurement is PERF.md section 6, PR 31, and
+``python tools/check_append_kernel.py time`` measures it again by calling
+the two implementations by name. The block verify stays on the gather
+at every window (the kernel's state is seeded with ONE current token).
 
-Round-8 closure of the long-window regime (the round-5 verdict's
-top-ranked item): the round-5 flash-append kernel was pinned to the
-single-chunk band by a VMEM stack OOM — double-buffered WHOLE-CHUNK
-scratch plus whole-chunk bf16 dequant copies (20.7 MB measured at
-2048-token chunks) — so W > 2048 fell back to the gather path and its
-linear materialise waste (40.2 ms at W=4096 int8 B=32, 5.5x the ~7 ms
-byte bound). Two restructurings were prototyped, both holding TILES in
-VMEM instead of whole windows:
-
-- **(B, chunk) grid with cross-chunk online-softmax merge in VMEM
-  scratch accumulators** (split-K / flash-decoding shape, Dao et al.;
-  the paged pool walk is vLLM PagedAttention's): each program folds one
-  bounded chunk (1024 int8 / 512 bf16 tokens, 8.2 MB VMEM ceiling
-  including the double-buffered DMA slots and the chunk-local dequant
-  view) into (m, l, acc) scratch that persists across the chunk axis of
-  the grid; the next chunk's page DMAs issue before the current chunk's
-  compute, crossing row boundaries, so launch overhead amortises across
-  the grid instead of a kernel-internal chunk loop. **KEPT — the
-  winner**: W=4096 int8 B=32 measures 11.6-12.4 ms per step
-  (3.2-3.5x the gather path, 1.7x the byte bound) and W=8192 measures
-  21.8 ms, both page sizes within the session spread.
-- per-tile int8 dequant inside the softmax loop of the old (B,) grid
-  (the chunk stays int8 in VMEM; each [128, HD] tile converts in
-  registers as it feeds the MXU, so the whole-chunk bf16 copy never
-  exists). **DROPPED — the loser, recorded here**: the VMEM ceiling
-  clears (9.1 MB at 2048-token chunks) but the kernel-internal chunk
-  loop serialises DMA waits against the tile loop — W=4096 int8 B=32
-  measured 24.9 ms (2.1x the grid form) and the tile-granular
-  dequant added ~8% VPU time at W=2048 where the two shapes otherwise
-  tie.
-
-The grid kernel is now the DEFAULT dispatch for decode append at
-W >= ``PAGED_APPEND_FLASH_MIN_W`` (1024; 2048 until PR 31) on TPU; the
-gather path stays default below it and everywhere on CPU (non-interpret
-``pallas_call`` needs the hardware). See ``_flash_append_policy`` for
-the exact rule and docs/serving.md ("long-window kernel") for the
-dispatch table and measured ladder.
-
-PR 31: the grid kernel's work follows the rows' LENGTHS, not the
-window: a (row, chunk) program whose chunk starts at or past its row's
-length fetches, waits for and folds nothing (``holds_rows`` in
-_flash_append_kernel_body; ops/mla_attention.py's decode kernel had the
-same skip first). The window is the power of two over the longest live
-row, so in a batch of ragged chat contexts two programs in three are
-such chunks, and in a part-full batch nearly all of them. The boundary
-above was measured again with that kernel, at a full and at a part-full
-batch (_flash_append_policy).
+:func:`paged_attention_reference` is the index-naive jnp oracle the tests
+hold both implementations to (tests/test_ops_paged.py).
 """
 
 from __future__ import annotations
@@ -112,126 +55,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.device import on_tpu
-from ..utils.env import env_int, env_or
 
 NEG_INF = -1e30
-
-_DEFAULT_IMPL = env_or("PAGED_ATTN_IMPL", "gather")
-
-
-def _kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, page_size: int, rep: int,
-            scale: float):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    num_p = pl.num_programs(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]
-    page_start = p * page_size
-
-    @pl.when(page_start < length)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)               # [Hq, D]
-        kpage = k_ref[0, 0].astype(jnp.float32)        # [ps, Hkv, D]
-        vpage = v_ref[0, 0].astype(jnp.float32)
-        Hkv = kpage.shape[1]
-        for h in range(Hkv):                           # static unroll
-            sl = slice(h * rep, (h + 1) * rep)
-            s = jax.lax.dot_general(                   # [rep, ps]
-                q[sl], kpage[:, h], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            pos = page_start + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, dimension=1)
-            s = jnp.where(pos < length, s, NEG_INF)
-
-            m_prev = m_ref[sl, :1]                     # [rep, 1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_cur)
-            probs = jnp.exp(s - m_cur)                 # [rep, ps]
-            l_ref[sl, :1] = l_ref[sl, :1] * alpha + jnp.sum(
-                probs, -1, keepdims=True)
-            acc_ref[sl, :] = acc_ref[sl, :] * alpha + jnp.dot(
-                probs, vpage[:, h], preferred_element_type=jnp.float32)
-            m_ref[sl, :1] = m_cur
-
-    @pl.when(p == num_p - 1)
-    def _finalise():
-        # length >= 1 by the serving contract (the slot just written is
-        # always attended), so l > 0.
-        o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
-def _paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, layer,
-                            *, pages: int, interpret: bool = False):
-    B, Hq, D = q.shape
-    L, N, page_size, Hkv, _ = k_pages.shape
-    rep = Hq // Hkv
-    scale = 1.0 / (D ** 0.5)
-    pt = page_table[:, :pages].astype(jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,       # page_table, lengths, layer
-        grid=(B, pages),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, p, pt, ln, ly: (b, 0, 0)),
-            # One whole page per program: full trailing dims, fetched at
-            # the scalar-prefetched (layer, physical page) address.
-            pl.BlockSpec((1, 1, page_size, Hkv, D),
-                         lambda b, p, pt, ln, ly: (ly[0], pt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, Hkv, D),
-                         lambda b, p, pt, ln, ly: (ly[0], pt[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, p, pt, ln, ly: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, 128), jnp.float32),    # running max m
-            pltpu.VMEM((Hq, 128), jnp.float32),    # running sum l
-            pltpu.VMEM((Hq, D), jnp.float32),      # unnormalised acc
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, rep=rep, scale=scale),
-        name="paged_attention_block",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(pt, lengths.astype(jnp.int32), layer, q, k_pages, v_pages)
-
-
-def _paged_attention_gather(q, k_pages, v_pages, page_table, lengths, layer,
-                            *, pages: int):
-    """Whole-page block gather + fused dense GQA attend (see module
-    docstring for why this wins at decode shapes)."""
-    from ..models.layers import attend_gqa
-
-    B = q.shape[0]
-    L, N, ps, Hkv, D = k_pages.shape
-    W = pages * ps
-    # Joint (layer, page) index into the flat [L*N] page axis: slicing the
-    # layer first (k_pages[layer][pt]) materialises the layer's ENTIRE
-    # pool slice before the gather — measured at ~0.4 ms/step of pure
-    # copy at bench serving shapes. One gather from the flat pool reads
-    # only the window's pages.
-    pt = layer * N + page_table[:, :pages].astype(jnp.int32)
-    k = k_pages.reshape(L * N, ps, Hkv, D)[pt].reshape(B, W, Hkv, D)
-    v = v_pages.reshape(L * N, ps, Hkv, D)[pt].reshape(B, W, Hkv, D)
-    mask = (jnp.arange(W)[None, :] < lengths[:, None])[:, None, None, :]
-    return attend_gqa(q[:, None], k, v, mask)[:, 0]
 
 
 def _gqa_selection_matrices(Hq: int, Hkv: int, D: int, rep: int):
     """Constant 0/1 selection matrices built from in-register iotas
-    (shared by _append_kernel and the flash-append kernel): SEL tiles /
-    collapses per-head D-blocks, BLOCKM masks q columns to their own kv
-    block (built both ways — Mosaic cannot transpose i1), EXPT expands
-    kv-head rows to query-head columns. Returns
+    for the flash-append kernel, which turns every GQA shuffle into an
+    MXU dot: SEL tiles / collapses per-head D-blocks, BLOCKM masks q
+    columns to their own kv block (built both ways — Mosaic cannot
+    transpose i1), EXPT expands kv-head rows to query-head columns.
+    Returns
     (sel bf16 [HD, D], blockm bool [HD, Hq], blockm_t bool [Hq, HD],
     expt f32 [Hq, Hkv])."""
     HD = Hkv * D
@@ -254,179 +88,8 @@ def _gqa_expander(Hq: int, Hkv: int, rep: int):
     return (hh == gg).astype(jnp.float32)
 
 
-def _append_kernel(len_ref, q_ref, kc_ref, vc_ref, kwin_ref, vwin_ref,
-                   skw_ref, svw_ref, o_ref, *, page_size: int,
-                   pages: int, rep: int, rows: int, scale: float,
-                   quantized: bool):
-    """Append-attention over GATHERED windows, one program per
-    ``rows``-row block.
-
-    Division of labour, settled by measurement: XLA's native gather
-    fetches each row's pages from the paged pool (its scattered-page
-    DMA machinery runs at ~1 TB/s effective; a manual per-page
-    ``make_async_copy`` loop in an earlier version of this kernel spent
-    ~280 us/layer-call on DMA-descriptor issue alone), and this kernel
-    consumes the gathered windows as auto-pipelined VMEM blocks and
-    replaces what XLA did WORSE: the rep(=2)-row GQA attention math that
-    lowered onto the VPU with layout copies around the scale arrays
-    (~0.8 ms of a 3.0 ms bench-1b step).
-
-    Constant 0/1 selection matrices (built in-register from iotas) turn
-    every GQA shuffle into an MXU dot: ONE [W, HD] x [HD, Hq] score dot
-    and one [Hq, W] x [W, HD] output dot per row, with the kv-head ->
-    query-head expansion and the output block-collapse as tiny constant
-    matmuls. All big dots take bf16 inputs with f32 accumulation — the
-    same precision contract as the gather path's attend_gqa. The current
-    token's (k, v) folds in as one extra softmax term, so pool writes
-    batch AFTER the layer scan (write_decode_all_layers).
-    """
-    W = pages * page_size
-    Hkv = kc_ref.shape[1]
-    Hq = rep * Hkv
-    D = kc_ref.shape[2]
-    HD = Hkv * D
-    pos_col = jax.lax.broadcasted_iota(jnp.int32, (W, 1), dimension=0)
-    sel, blockm, blockm_t, expt = _gqa_selection_matrices(Hq, Hkv, D, rep)
-    expt = expt.astype(jnp.bfloat16)
-
-    g0 = pl.program_id(0)
-    for r in range(rows):
-        length = len_ref[g0 * rows + r]
-        q_r = q_ref[r].astype(jnp.bfloat16)                     # [Hq, D]
-        valid_col = pos_col < length                            # [W, 1]
-        kflat = kwin_ref[r].reshape(W, HD).astype(jnp.bfloat16)
-        vflat = vwin_ref[r].reshape(W, HD).astype(jnp.bfloat16)
-
-        # Q stacked into its kv block: [HD, Hq] = tile q columns via SEL,
-        # zero the off-block copies.
-        q_cols = jax.lax.dot(sel, q_r.T,
-                             preferred_element_type=jnp.float32)
-        q_blk = jnp.where(blockm, q_cols.astype(jnp.bfloat16),
-                          jnp.zeros((), jnp.bfloat16))          # [HD, Hq]
-        s = jax.lax.dot(kflat, q_blk,
-                        preferred_element_type=jnp.float32) * scale
-        if quantized:
-            sk_all = jnp.concatenate(
-                [skw_ref[r, p][:, :page_size] for p in range(pages)],
-                axis=1)                                         # [Hkv, W]
-            sv_all = jnp.concatenate(
-                [svw_ref[r, p][:, :page_size] for p in range(pages)],
-                axis=1)
-            skw = jax.lax.dot(sk_all.T, expt.T.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
-            s = s * skw                                         # [W, Hq]
-        s = jnp.where(valid_col, s, NEG_INF)
-
-        # Current token's k/v, expanded kv-head -> query-head via EXPT.
-        kcur = jax.lax.dot(expt, kc_ref[r].astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)  # [Hq, D]
-        vcur = jax.lax.dot(expt, vc_ref[r].astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)
-        s_cur = jnp.sum(q_r.astype(jnp.float32) * kcur, axis=-1,
-                        keepdims=True).T * scale
-
-        m = jnp.maximum(jnp.max(s, 0, keepdims=True), s_cur)    # [1, Hq]
-        p_w = jnp.exp(s - m)                                    # [W, Hq]
-        p_c = jnp.exp(s_cur - m)                                # [1, Hq]
-        den = jnp.sum(p_w, 0, keepdims=True) + p_c              # [1, Hq]
-        if quantized:
-            svw = jax.lax.dot(sv_all.T, expt.T.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
-            p_w = p_w * svw
-        out_full = jax.lax.dot(p_w.T.astype(jnp.bfloat16), vflat,
-                               preferred_element_type=jnp.float32)
-        out_full = jnp.where(blockm_t, out_full, 0.0)           # [Hq, HD]
-        out = jax.lax.dot(out_full.astype(jnp.bfloat16), sel,
-                          preferred_element_type=jnp.float32)   # [Hq, D]
-        out = (out + p_c.T * vcur) / den.T
-        o_ref[r] = out.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("pages", "interpret", "quantized"))
-def _paged_append_kernel_call(q, k_cur, v_cur, k_pages, v_pages, k_scale,
-                              v_scale, page_table, lengths, layer, *,
-                              pages: int, quantized: bool,
-                              interpret: bool = False):
-    B, Hq, D = q.shape
-    L, N, page_size, Hkv, _ = k_pages.shape
-    rep = Hq // Hkv
-    scale = 1.0 / (D ** 0.5)
-    W = pages * page_size
-    # XLA joint-index gather fetches the windows (see _append_kernel for
-    # why this beats in-kernel page DMAs).
-    pt = layer * N + page_table[:, :pages].astype(jnp.int32)
-    kwin = k_pages.reshape(L * N, page_size, Hkv, D)[pt].reshape(
-        B, W, Hkv, D)
-    vwin = v_pages.reshape(L * N, page_size, Hkv, D)[pt].reshape(
-        B, W, Hkv, D)
-    if quantized:
-        ps_pad = k_scale.shape[-1]
-        skw = k_scale.reshape(L * N, Hkv, ps_pad)[pt]   # [B, P, Hkv, pad]
-        svw = v_scale.reshape(L * N, Hkv, ps_pad)[pt]
-    else:
-        ps_pad = 128
-        skw = jnp.zeros((B, pages, Hkv, ps_pad), jnp.float32)
-        svw = skw
-
-    # Rows per program bounded by the window VMEM footprint (k+v blocks
-    # + f32 scales, double-buffered by Mosaic); target ~4 MB.
-    bytes_per_row = 2 * W * Hkv * D * k_pages.dtype.itemsize
-    if quantized:
-        bytes_per_row += 2 * pages * Hkv * ps_pad * 4
-    rows = max(1, min(B, (4 * 1024 * 1024) // max(1, bytes_per_row)))
-    while B % rows:
-        rows -= 1
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,       # lengths (SMEM scalars)
-        grid=(B // rows,),
-        in_specs=[
-            pl.BlockSpec((rows, Hq, D), lambda i, ln: (i, 0, 0)),
-            pl.BlockSpec((rows, Hkv, D), lambda i, ln: (i, 0, 0)),
-            pl.BlockSpec((rows, Hkv, D), lambda i, ln: (i, 0, 0)),
-            pl.BlockSpec((rows, W, Hkv, D), lambda i, ln: (i, 0, 0, 0)),
-            pl.BlockSpec((rows, W, Hkv, D), lambda i, ln: (i, 0, 0, 0)),
-            pl.BlockSpec((rows, pages, Hkv, ps_pad),
-                         lambda i, ln: (i, 0, 0, 0)),
-            pl.BlockSpec((rows, pages, Hkv, ps_pad),
-                         lambda i, ln: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, Hq, D), lambda i, ln: (i, 0, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_append_kernel, page_size=page_size, pages=pages,
-                          rep=rep, rows=rows, scale=scale,
-                          quantized=quantized),
-        name="paged_attention_append",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_cur, v_cur, kwin, vwin, skw, svw)
-    return out
-
-
-# Decode append-attention implementation default at SHORT windows.
-# "gather" (XLA) wins at serving shapes and stays the default there; the
-# Pallas block kernel (PAGED_APPEND_IMPL=kernel) is kept for the record.
-# Measured on v5e, bench-1b B=32 W=192, per step: XLA gather+attend
-# ~1.0 ms; manual-DMA kernel ~6.2 ms in DMA-descriptor issue alone (384
-# page copies); the gather-fed block kernel ~1.8 ms (the GQA-via-
-# selection-matmul form spends 8x the MXU passes; per-head dots relayout
-# instead). At rep=2 decode GQA, XLA's fused VPU math is simply the
-# better tool — until the window is long enough that the gather's
-# materialise copy dominates, where the multi-chunk flash-append kernel
-# takes over by default (see _flash_append_policy).
-_APPEND_IMPL = env_or("PAGED_APPEND_IMPL", "gather")
-
-
-def _append_kernel_wanted() -> bool:
-    return _APPEND_IMPL == "kernel"
-
-
 def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
-                           *, pages: int, interpret: bool = False,
-                           sharded: bool = False):
+                           *, pages: int, sharded: bool = False):
     """Decode attention where this step's k/v is NOT yet in the pool:
     attend over the pool window (positions < ``lengths``) and merge the
     current token's own (k_cur, v_cur) contribution with one exact
@@ -437,61 +100,45 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     small scatters per step whose fixed cost is measurable against the
     bandwidth bound. With the merge, the scan collects per-layer k/v as
     stacked outputs and ONE batched scatter (ops/paged_kv.
-    write_decode_all_layers) lands the whole step after the trunk.
-    On bf16 pools results are identical to write-then-attend (same f32
-    softmax over the same set; pinned by tests/test_ops_paged.py). On
-    int8 pools the CURRENT token is attended at FULL precision here,
-    where write-then-attend would read it back quantized — a
-    sub-quantisation-noise difference that can flip logit ties (the
-    same caveat verify_append documents for drafts; see the scheduler's
-    kv_quant notes).
+    write_decode_burst) lands the whole step after the trunk. On int8
+    pools the CURRENT token is attended at FULL precision, where a later
+    step reads it back quantized — a sub-quantisation-noise difference
+    that can flip logit ties (the same caveat verify_append documents
+    for drafts; see the scheduler's kv_quant notes).
 
     q/k_cur/v_cur: [B, Hq|Hkv, D] (one token per row); cache: the
     PagedKVCache (bf16 or int8 pools); lengths: positions already in
     the pool per row (NOT including the current token). Returns
     [B, Hq, D] in q.dtype. ``sharded``: the pool is sharded over a mesh
-    (TP serving) — the Pallas kernels cannot consume it, so every
-    window stays on the XLA path.
+    (TP serving), which the Pallas kernel cannot consume.
 
-    The XLA gather+merge below is the DEFAULT at short windows and
-    everywhere on CPU (it measured fastest at short serving windows —
-    see the module docstring's round-4 history). At windows >=
-    ``PAGED_APPEND_FLASH_MIN_W`` (default 1024) on TPU the multi-chunk
-    flash-append kernel (_paged_attention_flash_append) is the default
-    instead: one HBM pass over the pages, no gathered-window
-    materialisation — the round-8 long-window win. Overrides:
-    ``PAGED_APPEND_IMPL=kernel`` pins the round-4 gathered-window block
-    kernel (_append_kernel); ``PAGED_APPEND_IMPL=flash`` pins the flash
-    kernel at every window; ``PAGED_APPEND_FLASH_MIN_W=0`` disables the
-    flash default (gather everywhere). See _flash_append_policy for the
-    exact rule. All paths compute the same f32 softmax over the same
-    score set.
+    Chooses between :func:`_append_gather` and
+    :func:`_paged_attention_flash_append` from what it can observe — the
+    window, the pool's geometry, the platform — and nothing else (module
+    docstring). A caller that wants one side calls it by name.
     """
+    Hkv, D = cache.k.shape[3], cache.k.shape[4]
+    quantized = cache.k_scale is not None
+    args = (q, k_cur, v_cur, cache.k, cache.v, cache.k_scale, cache.v_scale,
+            cache.page_table, lengths, layer)
+    blocked = flash_append_blocked(sharded, D, Hkv if quantized else 0)
+    if not blocked and _flash_append_policy(pages * cache.k.shape[2],
+                                            Hkv * D):
+        return _paged_attention_flash_append(*args, pages=pages,
+                                             quantized=quantized)
+    return _append_gather(*args, pages=pages)
+
+
+def _append_gather(q, k_cur, v_cur, k_pages, v_pages, k_scale, v_scale,
+                   page_table, lengths, layer, *, pages: int):
+    """:func:`paged_attention_append` in XLA: gather the window, score
+    it, merge the current token's term. ``k_scale`` None = a bf16 pool."""
     B, Hq, D = q.shape
     Hkv = k_cur.shape[1]
     rep = Hq // Hkv
-    if _append_kernel_wanted() and not sharded:
-        return _paged_append_kernel_call(
-            q, k_cur, v_cur, cache.k, cache.v, cache.k_scale,
-            cache.v_scale, cache.page_table, lengths, layer, pages=pages,
-            quantized=cache.k_scale is not None, interpret=interpret)
-    W = pages * cache.k.shape[2]
-    if not interpret and _flash_append_wanted(
-            W, cache.k.shape[3] * cache.k.shape[4], sharded,
-            cache.k.shape[4],
-            cache.k.shape[3] if cache.k_scale is not None else 0):
-        # Long-window default (round-8): the (B, chunk)-grid flash
-        # kernel reads each page exactly once per (layer, step) and
-        # holds only bounded tiles in VMEM, so there is no multi-chunk
-        # regime restriction any more. Explicit interpret=True callers
-        # (CPU tests) drive the kernel directly.
-        return _paged_attention_flash_append(
-            q, k_cur, v_cur, cache.k, cache.v, cache.k_scale,
-            cache.v_scale, cache.page_table, lengths, layer, pages=pages,
-            quantized=cache.k_scale is not None)
     scores, v, sv = _gather_window_scores(
-        q[:, None], cache.k, cache.v, cache.k_scale, cache.v_scale,
-        cache.page_table, lengths, layer, pages=pages)
+        q[:, None], k_pages, v_pages, k_scale, v_scale, page_table,
+        lengths, layer, pages=pages)
 
     # Current token's own score: q . k_cur per kv head.
     qg = q.reshape(B, 1, Hkv, rep, D)
@@ -519,7 +166,7 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
 
 def _gather_window_scores(q4, k_pages, v_pages, k_scale, v_scale,
                           page_table, lengths, layer, *, pages: int):
-    """Shared preamble of the quantized gather and append paths: gather
+    """Shared preamble of the gather append and the block verify: gather
     one layer's window, compute masked pre-softmax scores (per-position
     k scales folded in when the pool is int8), and return
     (scores [B,G,rep,S,W] f32, v [B,W,Hkv,D], sv [B,G,W] | None).
@@ -530,8 +177,11 @@ def _gather_window_scores(q4, k_pages, v_pages, k_scale, v_scale,
     L, N, ps, Hkv, _ = k_pages.shape
     rep = Hq // Hkv
     W = pages * ps
-    # Joint (layer, page) gather from the flat pool — no layer-slice copy
-    # (see _paged_attention_gather).
+    # Joint (layer, page) index into the flat [L*N] page axis: slicing the
+    # layer first (k_pages[layer][pt]) materialises the layer's ENTIRE
+    # pool slice before the gather (~0.4 ms a step of pure copy at bench
+    # serving shapes); one gather from the flat pool reads only the
+    # window's pages.
     pt = layer * N + page_table[:, :pages].astype(jnp.int32)
     k = k_pages.reshape(L * N, ps, Hkv, D)[pt].reshape(B, W, Hkv, D)
     v = v_pages.reshape(L * N, ps, Hkv, D)[pt].reshape(B, W, Hkv, D)
@@ -542,9 +192,9 @@ def _gather_window_scores(q4, k_pages, v_pages, k_scale, v_scale,
     sv = None
     if k_scale is not None:
         # Scales are stored head-major, lane-padded [L, N, Hkv, ps_pad]
-        # (paged_kv.py — the layout the append kernel DMAs); the gathered
-        # [B, P, Hkv, ps] window transposes to [B, G, W] with one cheap
-        # swap of small middle axes (no full-array relayout).
+        # (paged_kv.py: the layout the flash-append kernel DMAs); the
+        # gathered [B, P, Hkv, ps] window transposes to [B, G, W] with one
+        # cheap swap of small middle axes (no full-array relayout).
         ps_pad = k_scale.shape[-1]
         sk = k_scale.reshape(L * N, Hkv, ps_pad)[pt][..., :ps].transpose(
             0, 2, 1, 3).reshape(B, Hkv, W)                     # [B,G,W]
@@ -578,115 +228,14 @@ def gather_window(cache, layer, *, pages: int) -> tuple:
     return k, v, scales(cache.k_scale), scales(cache.v_scale)
 
 
-def _paged_attention_gather_quant(q, k_pages, v_pages, k_scale, v_scale,
-                                  page_table, lengths, layer, *, pages: int):
-    """Gather-path decode attention over an int8 pool
-    (ops/paged_kv.PagedKVCache quantized=True).
-
-    The per-(slot, kv-head) scales fold OUTSIDE the two dots: scores
-    scale per kv position after the q.k contraction, and v's scale folds
-    into the softmax probabilities before the p.v contraction — so the
-    MXU consumes the int8 stream converted in registers, and HBM sees
-    half the bf16 pool traffic (measured ~0.3 ms off a 22-layer B=32
-    W=192 walk on v5e). Math mirrors models/layers.attend_gqa (f32
-    scores/softmax)."""
-    B, Hq, D = q.shape
-    scores, v, sv = _gather_window_scores(
-        q[:, None], k_pages, v_pages, k_scale, v_scale, page_table,
-        lengths, layer, pages=pages)
-    probs = jax.nn.softmax(scores, axis=-1)
-    probs = probs * sv[:, :, None, None, :]
-    out = jnp.einsum("bgrst,btgd->bsgrd", probs.astype(q.dtype),
-                     v.astype(q.dtype))
-    return out.reshape(B, 1, Hq, D)[:, 0]
-
-
-def _flash_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, sems, *, page_size: int, pages: int,
-                  chunk_pages: int, rep: int, scale: float):
-    """One program per batch row: manually DMA that row's pages (whole
-    [ps, Hkv, D] blocks, double-buffered per chunk) and fold them into an
-    online-softmax accumulator carried as VALUES across a static chunk
-    loop. One program per row (vs (B, pages) in ``_kernel``) keeps the
-    q tile and softmax state resident and amortises program overhead —
-    and unlike the gather path, HBM sees each page exactly once (the
-    gather materialises a [B, W, Hkv, D] copy first: 2x the traffic of
-    the bandwidth bound, measured ~1.4 ms vs the ~0.7 ms bound for a
-    22-layer walk at W=192, B=32 on v5e)."""
-    b = pl.program_id(0)
-    ly = layer_ref[0]
-    length = len_ref[b]
-    num_chunks = -(-pages // chunk_pages)
-
-    def dma(slot: int, c: int, i: int):
-        page = pt_ref[b, c * chunk_pages + i]
-        return (
-            pltpu.make_async_copy(k_hbm.at[ly, page],
-                                  kbuf.at[slot, i], sems.at[0, slot, i]),
-            pltpu.make_async_copy(v_hbm.at[ly, page],
-                                  vbuf.at[slot, i], sems.at[1, slot, i]),
-        )
-
-    def start_chunk(slot: int, c: int) -> None:
-        for i in range(min(chunk_pages, pages - c * chunk_pages)):
-            for d in dma(slot, c, i):
-                d.start()
-
-    start_chunk(0, 0)
-    q = q_ref[0].astype(jnp.float32)                     # [Hq, D]
-    Hq, D = q.shape
-    Hkv = Hq // rep
-    # Online-softmax state carried as per-kv-head VALUES across the
-    # static chunk/head unrolls (Mosaic has no scatter: value-level
-    # .at[].set would not lower).
-    ms = [jnp.full((rep, 1), NEG_INF, jnp.float32) for _ in range(Hkv)]
-    ls = [jnp.zeros((rep, 1), jnp.float32) for _ in range(Hkv)]
-    accs = [jnp.zeros((rep, D), jnp.float32) for _ in range(Hkv)]
-
-    for c in range(num_chunks):
-        slot = c % 2
-        if c + 1 < num_chunks:
-            start_chunk((c + 1) % 2, c + 1)
-        n_pages = min(chunk_pages, pages - c * chunk_pages)
-        for i in range(n_pages):
-            for d in dma(slot, c, i):
-                d.wait()
-        kc = kbuf[slot].astype(jnp.float32)       # [chunk_pages, ps, Hkv, D]
-        vc = vbuf[slot].astype(jnp.float32)
-        Ct = n_pages * page_size
-        kc = kc[:n_pages].reshape(Ct, Hkv, D)
-        vc = vc[:n_pages].reshape(Ct, Hkv, D)
-        pos = c * chunk_pages * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, Ct), dimension=1)             # [1, Ct]
-        valid = pos < length
-        for h in range(Hkv):                             # static unroll
-            sl = slice(h * rep, (h + 1) * rep)
-            s = jax.lax.dot_general(                     # [rep, Ct]
-                q[sl], kc[:, h], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)
-            m_cur = jnp.maximum(ms[h], jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(ms[h] - m_cur)
-            probs = jnp.exp(s - m_cur)
-            ls[h] = ls[h] * alpha + jnp.sum(probs, -1, keepdims=True)
-            accs[h] = accs[h] * alpha + jnp.dot(
-                probs, vc[:, h], preferred_element_type=jnp.float32)
-            ms[h] = m_cur
-
-    out = jnp.concatenate(accs, axis=0) / jnp.concatenate(ls, axis=0)
-    o_ref[0] = out.astype(o_ref.dtype)
-
-
 def paged_attention_verify_append(q_blk, k_blk, v_blk, cache, lengths,
                                   layer, *, pages: int, block_mask=None):
     """Speculative-verify attention where the candidate block's k/v is
     NOT yet in the pool: position j attends the pool window (positions
     < ``lengths``, identical mask for every j) plus block positions
     i <= j from the in-register k/v — one softmax over the concatenated
-    score axis, so on bf16 pools results equal the write-then-attend
-    ordering exactly. (On int8 pools the block is attended at FULL
-    precision — unlike the old ordering, which quantized drafts before
-    attending. Position 0 then sees exactly what the plain tick's
+    score axis. (On int8 pools the block is attended at FULL
+    precision. Position 0 then sees exactly what the plain tick's
     paged_attention_append sees; positions j >= 1 view EARLIER drafts
     at full precision where the plain path, once those drafts commit,
     reads them quantized — so spec output under int8 KV tracks the
@@ -735,97 +284,57 @@ def paged_attention_verify_append(q_blk, k_blk, v_blk, cache, lengths,
         q_blk.dtype)
 
 
-# VMEM budget for one double-buffered chunk side (k + v, bf16): chunks of
-# up to 8 pages x 64 slots x Hkv x D. At bench shapes (8 heads, D=128)
-# that is 1 MB per buffer side — 4 MB total with double buffering.
-_FLASH_CHUNK_PAGES = 8
-
 # Per-dtype chunk sizing for the flash-append DMA pipeline: bytes of
 # one (k or v) buffer side per token AT THE CALIBRATION GEOMETRY
 # (_FLASH_HD_REF) — the chunk token budget is
 # _FLASH_CHUNK_TOK_BYTES * _FLASH_HD_REF / (hd * pool_itemsize), i.e.
 # 1024 int8 tokens / 512 bf16 tokens / 256 f32 tokens per grid step at
-# the bench-1b geometry where the budget was measured (Hkv=8, D=128,
-# hd=1024), and proportionally MORE tokens per chunk at narrower KV
-# geometries (bench-moe's Hkv=4: 2048 int8 tokens — same VMEM bytes,
-# half the grid programs, which is half the per-chunk fixed cost the
-# round-5 MoE paged-walk gap is made of). VMEM ceiling is
+# hd=1024, and proportionally MORE tokens per chunk at narrower KV
+# geometries (same VMEM bytes, fewer grid programs). The VMEM ceiling is
 # geometry-invariant by construction: double-buffered int8 k+v DMA
 # slots 4 MB + the chunk-local bf16 dequant view 4 MB + f32 softmax
-# state ~0.2 MB = 8.2 MB, comfortably under the 16 MB stack that the
-# round-5 whole-chunk design overflowed (20.7 MB). Module-level so
-# tests can shrink both knobs to exercise many-chunk grids in
-# interpret mode at tiny geometries.
+# state ~0.2 MB = 8.2 MB, under the 16 MB stack. Module-level so tests
+# can shrink it to exercise many-chunk grids in interpret mode at tiny
+# geometries.
 _FLASH_CHUNK_TOK_BYTES = 1024
 
-# The Hkv * head_dim the chunk budget and the round-8 min-W boundary
-# were calibrated at (bench-1b / llama-8B class: 8 kv heads x 128).
+# The Hkv * head_dim the chunk budget and the boundary were calibrated
+# at (llama-8B class: 8 kv heads x 128), and the boundary there.
 _FLASH_HD_REF = 1024
+_FLASH_MIN_W = 1024
 
 # Floor for the engagement boundary: no geometry measured engages below
-# it on the default rule (one chunk a row, nothing to skip at a full
-# batch, and the gather path's XLA fusion wins there at hd <= 1024).
+# it (one chunk a row, nothing to skip at a full batch).
 _FLASH_MIN_W_FLOOR = 256
 
 
-def _flash_append_min_w() -> int:
-    """Engage the flash append kernel at windows >= this many tokens
-    AT THE CALIBRATION GEOMETRY (see _flash_append_policy for the
-    per-geometry scaling; TPU only; <=0 disables it and the gather path
-    runs everywhere). Read per dispatch decision — NOT frozen at import
-    — so tests and bench phases can flip ``PAGED_APPEND_FLASH_MIN_W``
-    at runtime (the pattern serve/scheduler.py established for
-    ``prefill_chunk``); each jitted caller traces the decision once per
-    static shape."""
-    return env_int("PAGED_APPEND_FLASH_MIN_W", 1024)
-
-
-def _flash_append_policy(window: int, append_impl: str, min_w: int,
-                         hd: int = _FLASH_HD_REF) -> bool:
-    """The pure dispatch rule for the append path on TPU, split from
-    the platform guard so CPU tests can pin the decision table
-    hardware-free (tests/test_flash_append_geometry.py):
-
-    - ``PAGED_APPEND_IMPL=flash``  -> flash kernel at EVERY window;
-    - ``PAGED_APPEND_IMPL=kernel`` -> never (the round-4 block kernel
-      owns the dispatch upstream);
-    - otherwise flash iff ``min_w > 0`` and the window reaches
-      ``max(256, min_w * 1024 / max(hd, 1024))`` where ``hd = Hkv *
-      head_dim``: the knob itself up to the calibration geometry
-      (hd <= 1024: W >= 1024), scaled down by ``1024 / hd`` for wider
-      ones (OLMoE's MHA, hd = 2048: W >= 512).
-
-    Why one rule, and why it is a function of window and width alone
-    (PR 31; PERF.md section 6 has the table): the kernel's work follows
-    the rows' lengths, the gather path's the window, so what decides is
-    the FULL batch, where the kernel has least to skip. There a grid
-    program costs 3-5 us whatever the width while the gather path's
-    materialised, dequantised window grows with ``W * hd`` (and past
-    hd = 1024 stops fitting what XLA fuses): measured on a v5e at 32
-    live rows of chat-mix lengths, int8 pool, the kernel is level with
-    gather or ahead from W = 1024 at hd 512 (2% behind) and 1024 (10%
-    ahead) and from W = 256 at hd 2048, and at 2 live rows of 32 it is
-    1.2x-56x faster at every window measured (so the boundary is where the full batch stops
-    losing, never a function of live rows, which a trace cannot see).
-    Earlier rules scaled the boundary DOWN with hd below the
-    calibration (round 18) and took a measured ratio above it (PR 26);
-    both were measured with a kernel that walked every chunk.
-    """
-    if append_impl == "flash":
-        return True
-    if append_impl == "kernel":
-        return False
-    if min_w <= 0:
-        return False
-    return window >= _flash_boundary(min_w, hd)
-
-
-def _flash_boundary(min_w: int, hd: int) -> int:
-    """The window from which the flash kernel serves geometry ``hd`` when
-    ``PAGED_APPEND_FLASH_MIN_W`` is ``min_w`` > 0 (shared by the policy
-    and its one-number export)."""
+def _flash_boundary(hd: int) -> int:
+    """The window from which the flash kernel serves a pool of ``hd =
+    Hkv * head_dim`` numbers a token: 1,024 up to the calibration
+    geometry, scaled down by ``1024 / hd`` for wider ones (OLMoE's MHA,
+    hd = 2048: 512), never below 256."""
     return max(_FLASH_MIN_W_FLOOR,
-               min_w * _FLASH_HD_REF // max(hd, _FLASH_HD_REF))
+               _FLASH_MIN_W * _FLASH_HD_REF // max(hd, _FLASH_HD_REF))
+
+
+def _flash_append_policy(window: int, hd: int = _FLASH_HD_REF) -> bool:
+    """The dispatch rule for the append path where the kernel can run
+    (:func:`flash_append_blocked` is the guard), pure so CPU tests pin
+    its table (tests/test_flash_append_geometry.py).
+
+    Why a function of window and width alone (PR 31; PERF.md section 6
+    has the table): the kernel's work follows the rows' lengths, the
+    gather path's the window, so what decides is the FULL batch, where
+    the kernel has least to skip. There a grid program costs 3-5 us
+    whatever the width while the gather path's materialised, dequantised
+    window grows with ``W * hd`` (and past hd = 1024 stops fitting what
+    XLA fuses): measured on a v5e at 32 live rows of chat-mix lengths,
+    int8 pool, the kernel is level with gather or ahead from W = 1024 at
+    hd 512 (2% behind) and 1024 (10% ahead) and from W = 256 at hd 2048,
+    and at 2 live rows of 32 it is 1.2x-56x faster at every window
+    measured (so the boundary is where the full batch stops losing,
+    never a function of live rows, which a trace cannot see)."""
+    return window >= _flash_boundary(hd)
 
 
 def flash_append_blocked(sharded: bool = False, head_dim: int = 128,
@@ -842,8 +351,7 @@ def flash_append_blocked(sharded: bool = False, head_dim: int = 128,
     - Mosaic (libtpu 0.0.34) refuses the kernel's ``[Hkv, D] -> [Hkv*D]``
       tile collapse unless ``head_dim`` fills whole 128-lane rows
       ("infer-vector-layout: unsupported shape cast", seen on a v5e at
-      the ``tiny`` config's D=32, where the geometry-scaled boundary
-      engages the kernel from W=256);
+      the ``tiny`` config's D=32);
     - an int8 pool's tiles hold 4 kv heads on their sublanes, and Mosaic
       refuses the kernel's page slice of fewer (``int8_kv_heads``: the
       kv heads of an int8 pool, 0 for a bf16 one; "Slice shape along
@@ -862,34 +370,16 @@ def flash_append_blocked(sharded: bool = False, head_dim: int = 128,
     return None
 
 
-def _flash_append_wanted(window: int, hd: int = _FLASH_HD_REF,
-                         sharded: bool = False, head_dim: int = 128,
-                         int8_kv_heads: int = 0) -> bool:
-    if flash_append_blocked(sharded, head_dim, int8_kv_heads):
-        return False
-    return _flash_append_policy(window, _APPEND_IMPL,
-                                _flash_append_min_w(), hd)
-
-
 def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
                           head_dim: int = 128, int8_kv_heads: int = 0) -> int:
     """The flash-append engagement boundary as ONE number, for gauges
     and logs (serve/scheduler.py's ``paged_flash_min_w``): 0 = the
-    kernel cannot engage in this process (:func:`flash_append_blocked`,
-    disabled, or the block-kernel override), 1 = the flash override
-    (every window), else the geometry-scaled min-W threshold for ``hd =
-    Hkv * head_dim`` (the scheduler passes its model's). Kept next to
-    _flash_append_policy so the dispatch rule has exactly one home."""
+    kernel cannot engage in this process (:func:`flash_append_blocked`),
+    else the boundary for ``hd = Hkv * head_dim`` (the scheduler passes
+    its model's)."""
     if flash_append_blocked(sharded, head_dim, int8_kv_heads):
         return 0
-    if _APPEND_IMPL == "flash":
-        return 1
-    if _APPEND_IMPL == "kernel":
-        return 0
-    min_w = _flash_append_min_w()
-    if min_w <= 0:
-        return 0
-    return _flash_boundary(min_w, hd)
+    return _flash_boundary(hd)
 
 
 def flash_append_chunk_pages(hd: int, itemsize: int, page_size: int,
@@ -904,11 +394,11 @@ def flash_append_chunk_pages(hd: int, itemsize: int, page_size: int,
     window: _FLASH_CHUNK_TOK_BYTES derives the per-dtype chunk (1024
     int8 / 512 bf16 / 256 f32 tokens at the hd=1024 calibration
     geometry), scaled by _FLASH_HD_REF / hd so the chunk's VMEM BYTES
-    stay constant across KV geometries — narrow-KV models (bench-moe:
-    hd=512) carry 2x the tokens per chunk for the same VMEM, halving
-    the per-chunk fixed cost per window token. The grid — not a bigger
+    stay constant across KV geometries — narrow-KV models (hd=512)
+    carry 2x the tokens per chunk for the same VMEM, halving the
+    per-chunk fixed cost per window token. The grid — not a bigger
     chunk — is what amortises per-chunk overhead, so chunks never grow
-    with W and the round-5 whole-chunk VMEM OOM cannot recur."""
+    with W."""
     tok_budget = max(page_size,
                      _FLASH_CHUNK_TOK_BYTES * _FLASH_HD_REF
                      // (hd * itemsize))
@@ -920,12 +410,13 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
                               scale: float, compute_dtype):
     """Build the multi-chunk flash-append kernel body: ONE program per
     (row, chunk) of a ``(B, num_chunks)`` grid — the split-K /
-    flash-decoding shape (module docstring, round-8). The chunk axis is
-    the grid's minor dimension, so for a fixed row the chunk programs
-    run back to back and the online-softmax state (m, l, acc) lives in
-    VMEM **scratch accumulators** that persist across them — VMEM holds
-    one bounded chunk's tiles, never a whole window, which is what
-    cleared the round-5 VMEM stack OOM. Structure:
+    flash-decoding shape (Dao et al.; the paged pool walk is vLLM
+    PagedAttention's). The chunk axis is the grid's minor dimension, so
+    for a fixed row the chunk programs run back to back and the
+    online-softmax state (m, l, acc) lives in VMEM **scratch
+    accumulators** that persist across them — VMEM holds one bounded
+    chunk's tiles, never a whole window (a whole-window scratch
+    overflowed the VMEM stack at 2,048-token chunks). Structure:
 
     - **append semantics**: chunk 0 INITIALISES the scratch state with
       the current token's term (m = s_cur, l = 1, acc = v_cur) — exactly
@@ -935,7 +426,7 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
     - **cross-program double buffering**: each program issues the NEXT
       chunk's page DMAs (rolling over to the next row's chunk 0 at row
       boundaries) before waiting on its own, into 2-slot DMA scratch
-      indexed by global step parity — the grid replaces the round-5
+      indexed by global step parity — the grid replaces a
       kernel-internal chunk loop, so launch overhead amortises across
       programs and no program serialises a whole window's DMA waits.
     - **work follows the rows' lengths** (``holds_rows``): a chunk that
@@ -961,10 +452,10 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
       scores, v scales into the probabilities — the same
       fold-outside-the-dots contract as the gather path, so HBM sees
       int8 KV only.
-    - **selection-matmul GQA math** (from _append_kernel, the round-4
-      VPU win): scores run as ONE [Ct, HD] x [HD, Hq] dot per chunk and
-      the softmax chain on full-width [Ct, Hq] arrays; the scale folds
-      are one [Ct, Hkv] x [Hkv, Hq] expander dot each.
+    - **selection-matmul GQA math** (_gqa_selection_matrices): scores
+      run as ONE [Ct, HD] x [HD, Hq] dot per chunk and the softmax chain
+      on full-width [Ct, Hq] arrays; the scale folds are one
+      [Ct, Hkv] x [Hkv, Hq] expander dot each.
     - ``compute_dtype``: bf16 on hardware (the MXU's preferred operand
       dtype; int8 -> bf16 is the cheap unpack), f32 in interpret mode so
       the CPU parity tests pin the kernel against the oracle at f32
@@ -1074,8 +565,7 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
 
         @pl.when(holds_rows(b, c))
         def _fold():
-            # Constant selection matrices — shared with _append_kernel
-            # (_gqa_selection_matrices): the round-4 VPU win's machinery.
+            # Constant selection matrices (_gqa_selection_matrices).
             sel, blockm, blockm_t, expt = _gqa_selection_matrices(
                 Hq, Hkv, D, rep)
             sel_c = sel.astype(compute_dtype)
@@ -1150,10 +640,8 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
     seeded with the current token (_flash_append_kernel_body). HBM reads
     each page exactly once per (layer, step) — no gathered-window
     materialisation — and only the pages of chunks that start inside
-    their row's context (PR 31). The DEFAULT dispatch from the
-    geometry's boundary up on TPU (_flash_append_policy: W >= 1024 at
-    hd <= 1024); below it the gather path's XLA fusion is no slower at
-    a full batch and stays default."""
+    their row's context (PR 31). ``interpret`` runs it on the CPU, with
+    f32 dot operands, for hardware-free parity tests."""
     B, Hq, D = q.shape
     L, N, page_size, Hkv, _ = k_pages.shape
     rep = Hq // Hkv
@@ -1218,86 +706,6 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
     )(pt, lengths.astype(jnp.int32), layer, *operands)
-
-
-@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
-def _paged_attention_flash(q, k_pages, v_pages, page_table, lengths, layer,
-                           *, pages: int, interpret: bool = False):
-    B, Hq, D = q.shape
-    L, N, page_size, Hkv, _ = k_pages.shape
-    rep = Hq // Hkv
-    scale = 1.0 / (D ** 0.5)
-    pt = page_table[:, :pages].astype(jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    chunk_pages = min(pages, _FLASH_CHUNK_PAGES)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,       # page_table, lengths, layer
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, pt, ln, ly: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),      # k pool stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),      # v pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, pt, ln, ly: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk_pages, page_size, Hkv, D), k_pages.dtype),
-            pltpu.VMEM((2, chunk_pages, page_size, Hkv, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2, chunk_pages)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_flash_kernel, page_size=page_size, pages=pages,
-                          chunk_pages=chunk_pages, rep=rep, scale=scale),
-        name="paged_attention_flash",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(pt, lengths.astype(jnp.int32), layer, q, k_pages, v_pages)
-
-
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    page_table: jax.Array, lengths: jax.Array,
-                    layer: jax.Array, *, pages: int,
-                    interpret: bool = False,
-                    impl: str | None = None,
-                    k_scale: jax.Array | None = None,
-                    v_scale: jax.Array | None = None) -> jax.Array:
-    """Decode attention for one layer over the paged pool.
-
-    q: [B, Hq, D] (one token per row); k_pages/v_pages: the full pool
-    [L, N, page_size, Hkv, D] (stays in HBM — ``layer`` selects inside
-    the op, so no layer copy is materialised); page_table: [B, >=pages];
-    lengths: [B] tokens to attend per row (including the slot this step
-    wrote — callers pass ``cache.lengths + 1``); layer: scalar int32;
-    pages: static page-walk count (the serving window ladder:
-    ``ceil(window / page_size)``); impl: gather | flash | kernel (None =
-    the ``PAGED_ATTN_IMPL`` env default, gather). For an int8 pool
-    (ops/paged_kv quantized=True) pass ``k_scale``/``v_scale``
-    (head-major [L, N, Hkv, ps_pad] f32, ps_pad = page_size padded to a
-    128 multiple — PagedKVCache's storage layout) — gather-impl only. Returns [B, Hq, D]
-    in q.dtype.
-    """
-    if impl is None:
-        impl = _DEFAULT_IMPL
-    if k_scale is not None:
-        if impl != "gather":
-            raise ValueError(
-                f"int8 KV pools support impl='gather' only, got {impl!r}")
-        return _paged_attention_gather_quant(
-            q, k_pages, v_pages, k_scale, v_scale, page_table, lengths,
-            layer, pages=pages)
-    if impl == "gather":
-        return _paged_attention_gather(q, k_pages, v_pages, page_table,
-                                       lengths, layer, pages=pages)
-    if impl == "flash":
-        return _paged_attention_flash(q, k_pages, v_pages, page_table,
-                                      lengths, layer, pages=pages,
-                                      interpret=interpret)
-    if impl != "kernel":
-        raise ValueError(f"impl must be gather|flash|kernel, got {impl!r}")
-    return _paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
-                                   layer, pages=pages, interpret=interpret)
 
 
 def paged_attention_reference(q: jax.Array, k_pages: jax.Array,

@@ -11,7 +11,7 @@ layout is **token-major within a page**:
 contiguous ``[page_size, Hkv, D]`` block, exactly the dense cache's slot
 order. That makes the decode write a dense-shaped scatter, the admission
 splice a transpose-free reshape, and a whole-page gather a contiguous
-block read (ops/paged_attention.py's default gather path) — measured ~10x
+block read (ops/paged_attention.py's gather path) — measured ~10x
 faster end-to-end than the earlier head-major layout, whose strided
 windows made XLA scatters and per-(head,page) kernel programs dominate
 the decode tick. Page 0 is a permanent garbage bin: padded prefill slots
@@ -55,11 +55,11 @@ class PagedKVCache(NamedTuple):
     into k/v at the in-register dequant, so the MXU still consumes the
     int8 stream directly. bf16 pools keep scale = None.
 
-    Why head-major: the decode append kernel
-    (ops/paged_attention._append_kernel) DMAs one page's scales per
-    (kv-head) as a contiguous ``[page_size]`` lane vector and folds them
-    into the VMEM dequant — with Hkv (= 8) as the minor dim that slice is
-    strided 8 ways, a shape Mosaic cannot form. ``k_scale_view``/
+    Why head-major: the flash-append kernel
+    (ops/paged_attention._flash_append_kernel_body) DMAs one page's
+    scales as contiguous ``[page_size]`` lane vectors a kv-head and folds
+    them into the VMEM dequant — with Hkv (= 8) as the minor dim that
+    slice is strided 8 ways, a shape Mosaic cannot form. ``k_scale_view``/
     ``v_scale_view`` return the logical [L, N, ps, Hkv] order for
     oracles/tests.
 
@@ -76,11 +76,10 @@ class PagedKVCache(NamedTuple):
     the page dimension (:func:`_scatter_scale_tiles`); that compiles to
     an in-place update of the donated array. The decode write and the
     chunk ladder's splices follow it (tests/test_pool_write_layout.py);
-    the lane scatters left (:func:`write_decode`,
-    :func:`write_decode_multi`, :func:`write_decode_multi_all_layers`,
+    the lane scatters left (:func:`write_decode_multi_all_layers`,
     :func:`copy_slot`, :func:`write_prefill`, :func:`write_prefill_row`:
-    speculation and the non-gather attention implementations) are
-    correct and pay the relayout.
+    speculation and the one-shot prefills; :func:`write_decode`, which
+    only tests call) are correct and pay the relayout.
     """
 
     k: jax.Array
@@ -522,7 +521,10 @@ def write_prefill_row(cache: PagedKVCache, row_k: jax.Array,
 
 def write_decode(cache: PagedKVCache, layer: jax.Array, k: jax.Array,
                  v: jax.Array) -> PagedKVCache:
-    """Write one decode step's k/v for every row into its current slot.
+    """Write one decode step's k/v for every row into its current slot,
+    one layer. No serving program calls this (a step's layers land
+    together, :func:`write_decode_burst`): tests use it to build the pool
+    a reference attends, with the current token written in.
 
     k/v: [B, Hkv, D]; row b writes page ``page_table[b, lengths[b]//ps]``
     slot ``lengths[b] % ps`` of ``layer``. Parked rows (whose length the
@@ -646,10 +648,12 @@ def _multi_write_indices(cache: PagedKVCache,
 def write_decode_multi_all_layers(cache: PagedKVCache, k_all: jax.Array,
                                   v_all: jax.Array) -> PagedKVCache:
     """Write S candidate slots per row for EVERY layer in one scatter —
-    :func:`write_decode_all_layers`'s speculative-verify generalisation
-    (and :func:`write_decode_multi`'s all-layer one). k_all/v_all:
-    [L, B, S, Hkv, D]; same beyond-table garbage containment as
-    write_decode_multi."""
+    :func:`write_decode_all_layers`'s speculative-verify generalisation.
+    k_all/v_all: [L, B, S, Hkv, D]; row b's position j goes to page
+    ``page_table[b, (lengths[b]+j) // ps]`` slot ``(lengths[b]+j) % ps``.
+    Positions past the row's page allocation hit table entries that are 0
+    by contract — the garbage page — so near-budget rows' untrusted draft
+    writes are naturally contained (see _multi_write_indices)."""
     phys, slot = _multi_write_indices(cache, k_all.shape[2])
     return _scatter_kv(cache, k_all, v_all,
                        lambda arr, upd: arr.at[:, phys, slot].set(
@@ -657,26 +661,6 @@ def write_decode_multi_all_layers(cache: PagedKVCache, k_all: jax.Array,
                        # update [B, S, L, Hkv] (advanced dims 1, 3 front)
                        lambda arr, upd: arr.at[:, phys, :, slot].set(
                            upd.transpose(1, 2, 0, 3), mode="drop"))
-
-
-def write_decode_multi(cache: PagedKVCache, layer: jax.Array, k: jax.Array,
-                       v: jax.Array) -> PagedKVCache:
-    """Write S consecutive candidate slots per row for one layer — the
-    speculative-verify generalisation of :func:`write_decode`.
-
-    k/v: [B, S, Hkv, D]; row b's position j goes to page
-    ``page_table[b, (lengths[b]+j) // ps]`` slot ``(lengths[b]+j) % ps``.
-    Positions past the row's page allocation hit table entries that are 0
-    by contract — the garbage page — so near-budget rows' untrusted draft
-    writes are naturally contained (see _multi_write_indices)."""
-    phys, slot = _multi_write_indices(cache, k.shape[1])
-    return _scatter_kv(cache, k, v,
-                       lambda arr, upd: arr.at[layer, phys, slot].set(
-                           upd, mode="drop"),
-                       # layer-sliced target [N, Hkv, ps]; update [B, S,
-                       # Hkv] as-is (advanced dims 0, 2 -> front)
-                       lambda arr, upd: arr.at[layer, phys, :, slot].set(
-                           upd, mode="drop"))
 
 
 def copy_slot(cache: PagedKVCache, src_pos: jax.Array,

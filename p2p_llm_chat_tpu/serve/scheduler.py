@@ -521,25 +521,10 @@ class BatchScheduler:
         O(full prompt) to O(suffix). Register known templates via
         :meth:`register_prefix` / warmup ``prefix_texts``; repeated
         heads auto-promote after ``prefix_promote_after`` sightings."""
-        if kv_quant:
-            # ops/__init__ rebinds the `paged_attention` attribute to the
-            # FUNCTION, so module access must go through importlib.
-            import importlib
-            _pa = importlib.import_module(
-                "p2p_llm_chat_tpu.ops.paged_attention")
-            if _pa._DEFAULT_IMPL != "gather":
-                # Fail at construction, not on the scheduler thread at
-                # the first decode tick (which would strand queued
-                # requests until their timeout).
-                raise ValueError(
-                    "kv_quant=True requires the gather attention impl; "
-                    f"PAGED_ATTN_IMPL={_pa._DEFAULT_IMPL!r} is set")
         self.kv_quant = kv_quant
-        # The gather->flash-append boundary this process's programs will
-        # bake in at trace time. Snapshotted again when warmup records
-        # its ladder (the env toggle is runtime-flippable by design,
-        # but the LIVE programs keep whatever they traced, so the gauge
-        # must report the compiled-in value, not the current env).
+        # The gather->flash-append boundary this process's programs bake
+        # in at trace time: a function of the model's pool geometry, the
+        # mesh and the platform, so fixed for the engine's life.
         self._paged_flash_min_w = self._flash_min_w(config, mesh, kv_quant)
         if admit_chunk is not None and admit_chunk < 1:
             raise ValueError(f"admit_chunk must be >= 1, got {admit_chunk}")
@@ -2142,8 +2127,7 @@ class BatchScheduler:
             # batch promoting from a gather window into a kernel window
             # mid-serving never compiles over active streams.
             flash_note = ""
-            min_w = self._paged_flash_min_w = self._flash_min_w(
-                self.config, self.mesh, self.kv_quant)
+            min_w = self._paged_flash_min_w
             kernel_ws = [w for w in windows if min_w and w >= min_w]
             if kernel_ws:
                 flash_note = (f", flash-append kernel at windows "
@@ -2464,7 +2448,7 @@ class BatchScheduler:
         a mid-traffic warmup must not perturb seeded requests' outputs.
 
         Each window's program bakes in its attention impl at trace time
-        (gather below PAGED_APPEND_FLASH_MIN_W, the
+        (gather below the model's flash boundary, the
         multi-chunk flash-append kernel at and above it on TPU), so
         running this across the default whole ladder up to max_seq
         warms the kernel's Mosaic compiles at every long-window bucket
@@ -3816,12 +3800,9 @@ class BatchScheduler:
             off["mla-prefill"] = off["mla-decode"] = why_off
             off["flash-append"] = "a latent pool: mla-decode reads it"
         else:
-            off["flash-append"] = (
-                None if self._paged_flash_min_w > 0 else
-                flash_append_blocked(
-                    sharded, self.config.head_dim,
-                    self.config.num_kv_heads if self.kv_quant else 0)
-                or "disabled by PAGED_APPEND_FLASH_MIN_W/PAGED_APPEND_IMPL")
+            off["flash-append"] = flash_append_blocked(
+                sharded, self.config.head_dim,
+                self.config.num_kv_heads if self.kv_quant else 0)
         log.info("kernels on %s: %s; XLA instead of: %s; flash-append "
                  "min_w %d; pallas interpret %s", platform(),
                  ", ".join(k for k, why in off.items() if not why) or "none",
@@ -3875,13 +3856,10 @@ class BatchScheduler:
         """Window threshold at which this process's paged decode
         programs dispatch the multi-chunk flash-append kernel instead of
         the gather path: 0 = cannot engage (CPU, a mesh-sharded pool, a
-        head_dim Mosaic refuses, disabled, block-kernel override), 1 =
-        the flash override (every window). The threshold scales with
-        the model's per-token KV row width (kv_dim = num_kv_heads *
-        head_dim): narrow-KV models cross into the kernel at smaller
-        windows (round 18 — the gather path's per-token index/mask
-        overhead is geometry-invariant while its payload shrinks with
-        hd). One source of truth:
+        head_dim or an int8 pool Mosaic refuses). The threshold is a
+        function of the model's per-token KV row width (kv_dim =
+        num_kv_heads * head_dim): 1,024 up to kv_dim 1,024, lower for
+        wider rows. One source of truth:
         ops/paged_attention.effective_flash_min_w, next to the dispatch
         policy itself."""
         from ..ops.paged_attention import effective_flash_min_w

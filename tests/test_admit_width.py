@@ -39,6 +39,8 @@ from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
 from p2p_llm_chat_tpu.serve.scheduler import BatchScheduler, _WarmupJob
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
+from solo import jit_model
+
 CFG = get_config("tiny")
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
@@ -235,12 +237,11 @@ def test_dense_logits_at_one_row_match_the_first_row_of_eight():
     # behind it: the layout _admit_host_arrays builds.
     padded = jnp.concatenate([toks, jnp.zeros((7, S), jnp.int32)])
     lens = jnp.asarray([n] + [1] * 7, jnp.int32)
-    lg1, c1 = llama.prefill(PARAMS, CFG, toks, lens[:1],
-                            KVCache.create(CFG, 1, S, dtype=jnp.float32),
-                            last_only=True)
-    lg8, c8 = llama.prefill(PARAMS, CFG, padded, lens,
-                            KVCache.create(CFG, 8, S, dtype=jnp.float32),
-                            last_only=True)
+    prefill = jit_model(llama.prefill, CFG, last_only=True)
+    lg1, c1 = prefill(PARAMS, toks, lens[:1],
+                      KVCache.create(CFG, 1, S, dtype=jnp.float32))
+    lg8, c8 = prefill(PARAMS, padded, lens,
+                      KVCache.create(CFG, 8, S, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(lg1[0]), np.asarray(lg8[0]),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(c1.k[:, 0, :n]),
@@ -365,11 +366,11 @@ def test_dummy_entries_behind_the_request_take_no_expert_capacity():
                               config.vocab_size)
     dummies = jnp.zeros((7, S), jnp.int32)
 
+    prefill = jit_model(mixtral.prefill, config, last_only=True)
+
     def last_logits(tokens, lens, row):
         cache = KVCache.create(config, tokens.shape[0], S, dtype=jnp.float32)
-        lg, _ = mixtral.prefill(params, config, tokens,
-                                jnp.asarray(lens, jnp.int32), cache,
-                                last_only=True)
+        lg, _ = prefill(params, tokens, jnp.asarray(lens, jnp.int32), cache)
         return np.asarray(lg[row, 0])
 
     alone = last_logits(toks, [n], 0)

@@ -10,15 +10,12 @@ what other requests are in flight, in which slots, or in what order
 import threading
 import time
 
-import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
 from p2p_llm_chat_tpu.models import llama
 from p2p_llm_chat_tpu.models.configs import get_config
-from p2p_llm_chat_tpu.models.llama import KVCache
-from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
 from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
                                             RequestStats)
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
@@ -26,47 +23,23 @@ from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
 import jax
 
+from solo import Solo, generate as run
+
 CFG = get_config("tiny")
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
 STOP_IDS = set(CFG.eos_token_ids) | {TOK.eos_id}
 
 
-def oracle(prompt: str, max_new: int, max_seq: int = 128,
-           kv_quant: bool = False) -> str:
-    """Solo batch=1 greedy loop with the engine's stop rule, on the
-    model layer's plain cache. ``kv_quant``: the same loop on a one-row
-    int8 pool (ops/paged_kv.py), the exact reference of an engine whose
-    pool is int8: the rounding is per (slot, kv-head), so it does not
-    depend on what else is in the batch."""
-    ids = TOK.encode(prompt, add_bos=True)
-    n = len(ids)
-    cache = KVCache.create(CFG, 1, n if kv_quant else max_seq, jnp.float32)
-    logits, cache = llama.prefill(PARAMS, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([n]), cache)
-    if kv_quant:
-        ps = 16
-        mppr = max_seq // ps
-        pool = PagedKVCache.create(CFG, 1, mppr + 1, ps,
-                                   max_pages_per_row=mppr, quantized=True)
-        cache = write_prefill_batch(
-            pool, cache.k, cache.v, jnp.arange(1), jnp.asarray([n]),
-            1 + jnp.arange(mppr, dtype=jnp.int32)[None, :])
-    last = np.asarray(logits[0, n - 1])
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in STOP_IDS:
-            break
-        out.append(t)
-        if kv_quant:
-            lg, cache = llama.decode_step_paged(
-                PARAMS, CFG, jnp.asarray([[t]]), cache, pages=mppr)
-        else:
-            lg, cache = llama.decode_step(PARAMS, CFG, jnp.asarray([[t]]),
-                                          cache)
-        last = np.asarray(lg[0, 0])
-    return TOK.decode(out)
+# The solo batch=1 greedy loop with the engine's stop rule (tests/solo.py)
+# on the model layer's plain cache, and the same loop on a one-row int8
+# pool: the exact reference of an engine whose pool is int8.
+SOLO = {False: Solo(llama, CFG, TOK),
+        True: Solo(llama, CFG, TOK, pool="int8")}
+
+
+def oracle(prompt: str, max_new: int, kv_quant: bool = False) -> str:
+    return SOLO[kv_quant](PARAMS, prompt, max_new)
 
 
 @pytest.fixture(scope="module", params=["paged", "paged-int8"])
@@ -82,14 +55,6 @@ def engine(request):
 def want(engine, prompt: str, max_new: int) -> str:
     """The oracle of the leg ``engine`` is."""
     return oracle(prompt, max_new, kv_quant=engine.scheduler.kv_quant)
-
-
-def run(engine, prompt, max_tokens=12, **opts):
-    stats = RequestStats()
-    req = GenerateRequest(prompt=prompt, options=GenerateOptions(
-        max_tokens=max_tokens, **opts))
-    text = "".join(engine.generate_stream(req, stats))
-    return text, stats
 
 
 def test_single_request_matches_oracle(engine):
@@ -414,29 +379,12 @@ def test_moe_family_serves_through_same_scheduler(moe_config):
     mcfg = get_config(moe_config)
     mparams = mixtral.init_params(mcfg, jax.random.PRNGKey(1),
                                   dtype=jnp.float32)
-    stop_ids = set(mcfg.eos_token_ids) | {TOK.eos_id}
-
-    def moe_oracle(prompt: str, max_new: int) -> str:
-        ids = TOK.encode(prompt, add_bos=True)
-        cache = KVCache.create(mcfg, 1, 128, jnp.float32)
-        logits, cache = mixtral.prefill(mparams, mcfg, jnp.asarray([ids]),
-                                        jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1])
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in stop_ids:
-                break
-            out.append(t)
-            lg, cache = mixtral.decode_step(mparams, mcfg,
-                                            jnp.asarray([[t]]), cache)
-            last = np.asarray(lg[0, 0])
-        return TOK.decode(out)
+    solo = Solo(mixtral, mcfg, TOK)
 
     eng = TPUEngine(mparams, mcfg, TOK, num_slots=2, max_seq=128)
     try:
         prompts = ["moe hello", "a different moe prompt"]
-        want = {p: moe_oracle(p, 8) for p in prompts}
+        want = {p: solo(mparams, p, 8) for p in prompts}
         got, errs = {}, []
 
         def worker(p):
@@ -466,24 +414,7 @@ def test_moe_full_stack_composition_matches_oracle():
 
     mcfg = get_config("tiny-moe")
     qparams = mixtral.init_params_quantized(mcfg, jax.random.PRNGKey(9))
-    stop_ids = set(mcfg.eos_token_ids) | {TOK.eos_id}
-
-    def moe_oracle(prompt: str, max_new: int) -> str:
-        ids = TOK.encode(prompt, add_bos=True)
-        cache = KVCache.create(mcfg, 1, 128)
-        logits, cache = mixtral.prefill(qparams, mcfg, jnp.asarray([ids]),
-                                        jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1], np.float32)
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in stop_ids:
-                break
-            out.append(t)
-            lg, cache = mixtral.decode_step(qparams, mcfg,
-                                            jnp.asarray([[t]]), cache)
-            last = np.asarray(lg[0, 0], np.float32)
-        return TOK.decode(out)
+    solo = Solo(mixtral, mcfg, TOK, dtype=jnp.bfloat16)
 
     eng = TPUEngine(qparams, mcfg, TOK, num_slots=3, max_seq=128,
                     page_size=16, kv_quant=True,
@@ -492,7 +423,7 @@ def test_moe_full_stack_composition_matches_oracle():
     try:
         prompts = ["moe prefix alpha", "moe prefix bravo",
                    "unrelated charlie"]
-        want = {p: moe_oracle(p, 8) for p in prompts}
+        want = {p: solo(qparams, p, 8) for p in prompts}
         got, errs = {}, []
 
         def worker(p):
@@ -625,19 +556,7 @@ def test_context_round_trip_continues_conversation():
 
         # Oracle: one dense run over the full id stream.
         full_ids = ctx + TOK.encode(" three")
-        cache = KVCache.create(CFG, 1, 128, jnp.float32)
-        logits, cache = llama.prefill(PARAMS, CFG, jnp.asarray([full_ids]),
-                                      jnp.asarray([len(full_ids)]), cache)
-        last = np.asarray(logits[0, len(full_ids) - 1])
-        out = []
-        for _ in range(4):
-            t = int(last.argmax())
-            if t in STOP_IDS:
-                break
-            out.append(t)
-            lg, cache = llama.decode_step(PARAMS, CFG, jnp.asarray([[t]]),
-                                          cache)
-            last = np.asarray(lg[0, 0])
+        out = SOLO[False].tokens(PARAMS, full_ids, 4)
         assert t2 == TOK.decode(out)
         assert s2.context[: len(full_ids)] == full_ids
     finally:

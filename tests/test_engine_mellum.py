@@ -17,22 +17,17 @@ from p2p_llm_chat_tpu.models import family_for, nemotron_h
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import state_pool
-from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
-from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
-                                            RequestStats)
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
+from solo import Solo, generate as run, jit_model
+
 CFG = get_config("tiny-mellum2")
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
-
-
-def run(engine, prompt, max_tokens=12, **opts):
-    stats = RequestStats()
-    req = GenerateRequest(prompt=prompt, options=GenerateOptions(
-        max_tokens=max_tokens, **opts))
-    text = "".join(engine.generate_stream(req, stats))
-    return text, stats
+# One-shot prefill of the unpadded prompt, K and V spliced into a one-row
+# int8 pool, rings into its row of the state pool, plain decode
+# steps (tests/solo.py).
+SOLO = Solo(nemotron_h, CFG, TOK, pool="int8", max_seq=256, last_only=True)
 
 
 @pytest.fixture(scope="module")
@@ -43,35 +38,20 @@ def qparams():
                                             dtype=jnp.float32)
 
 
-def oracle(qparams, prompt: str, max_new: int) -> str:
-    """A solo loop on the same tree: one-shot prefill of the unpadded
-    prompt, K and V spliced into a one-row paged pool, rings into its row
-    of the state pool, then plain decode steps."""
-    stop_ids = set(CFG.eos_token_ids) | {TOK.eos_id}
-    ids = TOK.encode(prompt, add_bos=True)
-    n = len(ids)
-    small = KVCache.create(CFG, 1, n, dtype=jnp.float32)
-    logits, small = nemotron_h.prefill(
-        qparams, CFG, jnp.asarray([ids]), jnp.asarray([n]), small,
-        last_only=True)
-    pool = PagedKVCache.create(CFG, 1, 17, 16, max_pages_per_row=16,
-                               dtype=jnp.float32, quantized=True)
-    pool = write_prefill_batch(pool, small.k, small.v, jnp.asarray([0]),
-                               jnp.asarray([n]),
-                               1 + jnp.arange(16, dtype=jnp.int32)[None])
-    pool = pool._replace(state=state_pool.write_rows(
-        pool.state, small.state, jnp.asarray([0])))
-    last = np.asarray(logits[0, 0], np.float32)
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in stop_ids:
-            break
-        out.append(t)
-        lg, pool = nemotron_h.decode_step_paged(
-            qparams, CFG, jnp.asarray([[t]]), pool, pages=16)
-        last = np.asarray(lg[0, 0], np.float32)
-    return TOK.decode(out)
+HEAD = "mellum shared head, "
+
+
+@pytest.fixture(scope="module")
+def engine(qparams):
+    """The stack the benchmark serves with, booted once for the module:
+    the tests that serve through it read counters as differences between
+    snapshots, and only the second registers ``HEAD``."""
+    eng = TPUEngine(qparams, CFG, TOK, num_slots=4, max_seq=256,
+                    page_size=16, kv_quant=True, prefix_cache=True,
+                    prefix_texts=(HEAD,), decode_fuse_max=4,
+                    prefill_chunk=32)
+    yield eng
+    eng.stop()
 
 
 def test_family_is_the_hybrid_walk_with_no_branch_of_its_own():
@@ -87,14 +67,14 @@ def test_family_is_the_hybrid_walk_with_no_branch_of_its_own():
 
 
 def test_three_lengths_in_one_batch_stream_the_models_greedy_tokens(
-        qparams):
+        qparams, engine):
     """The scheduler end to end: three requests of different lengths
     (one inside a window, one past several, one a chunk ladder) admitted
-    together decode in one batch and each streams the solo loop's
-    tokens."""
-    eng = TPUEngine(qparams, CFG, TOK, num_slots=4, max_seq=256,
-                    page_size=16, kv_quant=True, prefix_cache=False,
-                    decode_fuse_max=4, prefill_chunk=32)
+    together, cold (nothing is in the prefix store yet), decode in one
+    batch and each streams the solo loop's tokens."""
+    eng = engine
+    assert len(eng.scheduler._prefix) == 0
+    m0 = eng.metrics_snapshot()
     prompts = ["hi", "a prompt well past the window of eight",
                "z" * 70]
     got, errs = {}, []
@@ -105,23 +85,20 @@ def test_three_lengths_in_one_batch_stream_the_models_greedy_tokens(
         except Exception as e:   # noqa: BLE001
             errs.append((p, e))
 
-    try:
-        threads = [threading.Thread(target=worker, args=(p,))
-                   for p in prompts]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=240)
-        assert not errs, errs
-        assert got == {p: oracle(qparams, p, 10) for p in prompts}
-        m = eng.metrics_snapshot()
-        assert m["serve_admitted_total"] == 3
-        assert m["serve_kv_free_pages"] == m["serve_kv_total_pages"]
-    finally:
-        eng.stop()
+    threads = [threading.Thread(target=worker, args=(p,)) for p in prompts]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=240)
+    assert not errs, errs
+    assert got == {p: SOLO(qparams, p, 10) for p in prompts}
+    m = eng.metrics_snapshot()
+    assert m["serve_admitted_total"] - m0["serve_admitted_total"] == 3
+    assert m["serve_kv_free_pages"] == m["serve_kv_total_pages"]
 
 
-def test_prefix_hit_chunks_fused_decode_slot_reuse_and_counters(qparams):
+def test_prefix_hit_chunks_fused_decode_slot_reuse_and_counters(qparams,
+                                                                engine):
     """A lone request, a prompt longer than a chunk behind the registered
     head (a prefix hit that starts from the entry's ring snapshot, then
     first / mid / final chunk programs), a cold one of the same length,
@@ -130,73 +107,66 @@ def test_prefix_hit_chunks_fused_decode_slot_reuse_and_counters(qparams):
     would not stream the solo loop's tokens): greedy output equals the
     solo loop's on the unpadded prompt, and the counters count what they
     say."""
-    head = "mellum shared head, "
-    eng = TPUEngine(qparams, CFG, TOK, num_slots=4, max_seq=256,
-                    page_size=16, kv_quant=True, prefix_cache=True,
-                    prefix_texts=(head,), decode_fuse_max=4,
-                    prefill_chunk=32)
-    try:
-        sched = eng.scheduler
-        built = sched.register_prefix(head)
-        assert built == len(TOK.encode(head, add_bos=True)) - 1
-        entry = sched._prefix.snapshot()[0]
-        # Two page layers' K and V, six rings, no recurrent state.
-        assert entry.k.shape[0] == 2
-        assert entry.state.ssm.size == 0
-        assert entry.state.win_k.shape == (6, 2, 8, 16)
-        assert entry.nbytes > entry.k.nbytes + entry.v.nbytes
-        lone = "a request that arrives alone"
-        long = head + "x" * 90          # suffix bucket 128: four chunks
-        longer = "y" * 75               # no head, bucket 128: four chunks
-        burst = [head + "a long tenant " * 4 + str(i) for i in range(4)] + [
-            f"t{i}" for i in range(4)]
-        assert run(eng, lone, max_tokens=6)[0] == oracle(qparams, lone, 6)
-        assert run(eng, long, max_tokens=6)[0] == oracle(qparams, long, 6)
-        assert run(eng, longer, max_tokens=6)[0] == oracle(qparams, longer,
-                                                            6)
-        got, errs = {}, []
+    head, eng = HEAD, engine
+    m0 = eng.metrics_snapshot()
+    sched = eng.scheduler
+    built = sched.register_prefix(head)
+    assert built == len(TOK.encode(head, add_bos=True)) - 1
+    entry = sched._prefix.snapshot()[0]
+    # Two page layers' K and V, six rings, no recurrent state.
+    assert entry.k.shape[0] == 2
+    assert entry.state.ssm.size == 0
+    assert entry.state.win_k.shape == (6, 2, 8, 16)
+    assert entry.nbytes > entry.k.nbytes + entry.v.nbytes
+    lone = "a request that arrives alone"
+    long = head + "x" * 90          # suffix bucket 128: four chunks
+    longer = "y" * 75               # no head, bucket 128: four chunks
+    burst = [head + "a long tenant " * 4 + str(i) for i in range(4)] + [
+        f"t{i}" for i in range(4)]
+    assert run(eng, lone, max_tokens=6)[0] == SOLO(qparams, lone, 6)
+    assert run(eng, long, max_tokens=6)[0] == SOLO(qparams, long, 6)
+    assert run(eng, longer, max_tokens=6)[0] == SOLO(qparams, longer, 6)
+    got, errs = {}, []
 
-        def worker(p):
-            try:
-                got[p] = run(eng, p, max_tokens=9)[0]
-            except Exception as e:   # noqa: BLE001
-                errs.append((p, e))
+    def worker(p):
+        try:
+            got[p] = run(eng, p, max_tokens=9)[0]
+        except Exception as e:   # noqa: BLE001
+            errs.append((p, e))
 
-        threads = [threading.Thread(target=worker, args=(p,))
-                   for p in burst]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=240)
-        assert not errs, errs
-        assert got == {p: oracle(qparams, p, 9) for p in burst}
-        m = eng.metrics_snapshot()
-        assert m["serve_admitted_total"] == 11
-        assert m["prefill_chunks_total"] >= 6
-        assert m["serve_prefix_admits_total"] >= 5
-        assert m["decode_fused_ticks_total"] > 0
-        assert m["serve_moe_dropped_total"] == 0
-        assert m["serve_moe_assignments_total"] > 0
-        # No recurrent state, so none of its series; rings and page
-        # layers have theirs.
-        assert "serve_state_pool_bytes" not in m
-        assert "serve_shared_kv_bytes_total" not in m
-        pool = sched._cache.state
-        live = m["serve_decode_row_steps_total"]
-        ring = pool.ring_position_bytes
-        assert ring == 2 * 2 * (16 + 4)      # K and V, 2 heads, a scale each
-        assert 6 * ring * live <= m["serve_window_bytes_total"] \
-            <= 6 * ring * live * CFG.sliding_window
-        # Every live row-step read its whole context from each of the two
-        # page layers, once: a page token costs what a ring position does.
-        assert m["serve_page_kv_bytes_total"] == \
-            2 * ring * m["serve_attn_context_tokens_total"]
-        # The window is honoured in the count: contexts here run to 100
-        # positions, the rings hold 8.
-        assert m["serve_window_bytes_total"] * 2 < \
-            6 * ring * m["serve_attn_context_tokens_total"]
-    finally:
-        eng.stop()
+    threads = [threading.Thread(target=worker, args=(p,))
+               for p in burst]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=240)
+    assert not errs, errs
+    assert got == {p: SOLO(qparams, p, 9) for p in burst}
+    m = eng.metrics_snapshot()
+    assert m["serve_admitted_total"] - m0["serve_admitted_total"] == 11
+    assert m["prefill_chunks_total"] >= 6
+    assert m["serve_prefix_admits_total"] >= 5
+    assert m["decode_fused_ticks_total"] > 0
+    assert m["serve_moe_dropped_total"] == 0
+    assert m["serve_moe_assignments_total"] > 0
+    # No recurrent state, so none of its series; rings and page
+    # layers have theirs.
+    assert "serve_state_pool_bytes" not in m
+    assert "serve_shared_kv_bytes_total" not in m
+    pool = sched._cache.state
+    live = m["serve_decode_row_steps_total"]
+    ring = pool.ring_position_bytes
+    assert ring == 2 * 2 * (16 + 4)      # K and V, 2 heads, a scale each
+    assert 6 * ring * live <= m["serve_window_bytes_total"] \
+        <= 6 * ring * live * CFG.sliding_window
+    # Every live row-step read its whole context from each of the two
+    # page layers, once: a page token costs what a ring position does.
+    assert m["serve_page_kv_bytes_total"] == \
+        2 * ring * m["serve_attn_context_tokens_total"]
+    # The window is honoured in the count: contexts here run to 100
+    # positions, the rings hold 8.
+    assert m["serve_window_bytes_total"] * 2 < \
+        6 * ring * m["serve_attn_context_tokens_total"]
 
 
 def test_prefix_hit_and_cold_admission_give_the_same_logits(qparams):
@@ -208,11 +178,11 @@ def test_prefix_hit_and_cold_admission_give_the_same_logits(qparams):
         3, 500, (1, 37)), jnp.int32)
     P = 21                                  # past two windows of 8
     cold = KVCache.create(CFG, 1, 37, dtype=jnp.float32)
-    want, cold = nemotron_h.prefill(qparams, CFG, ids, jnp.asarray([37]),
-                                    cold, last_only=True)
+    want, cold = jit_model(nemotron_h.prefill, CFG, last_only=True)(
+        qparams, ids, jnp.asarray([37]), cold)
     pre = KVCache.create(CFG, 1, P, dtype=jnp.float32)
-    _, pre = nemotron_h.prefill(qparams, CFG, ids[:, :P], jnp.asarray([P]),
-                                pre)
+    _, pre = jit_model(nemotron_h.prefill, CFG)(
+        qparams, ids[:, :P], jnp.asarray([P]), pre)
     snap = state_pool.snapshot(pre.state)
     # As the scheduler seeds a suffix: the entry's K and V in the carry's
     # first P slots, its rings in every row, 3 padding positions behind.
@@ -223,9 +193,9 @@ def test_prefix_hit_and_cold_admission_give_the_same_logits(qparams):
                            state=state_pool.from_snapshot(snap, 1))
     toks = jnp.pad(ids[:, P:], ((0, 0), (0, S - 16)))
     valid = jnp.arange(S)[None, :] < 16
-    got, small, _ = nemotron_h.forward_counted(
-        qparams, CFG, toks, None, small, None, valid,
-        last_idx=jnp.asarray([15]))
+    got, small, _ = jit_model(nemotron_h.forward_counted, CFG,
+                              last_idx=jnp.asarray([15]))(
+        qparams, toks, None, small, None, valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
     for a, b in zip(small.state[2:], cold.state[2:]):
         if a is not None:

@@ -18,22 +18,18 @@ from p2p_llm_chat_tpu.models import family_for, moe_tiles, nemotron_h
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import state_pool
-from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
-from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
-                                            RequestStats)
+from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
+from solo import Solo, generate as run, jit_model
+
 CFG = get_config("tiny-nemotron-h")
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
-
-
-def run(engine, prompt, max_tokens=12, **opts):
-    stats = RequestStats()
-    req = GenerateRequest(prompt=prompt, options=GenerateOptions(
-        max_tokens=max_tokens, **opts))
-    text = "".join(engine.generate_stream(req, stats))
-    return text, stats
+# One-shot prefill of the unpadded prompt, K and V spliced into a one-row
+# int8 pool and the state into its row of the state pool, plain decode
+# steps (tests/solo.py).
+SOLO = Solo(nemotron_h, CFG, TOK, pool="int8", max_seq=256, last_only=True)
 
 
 @pytest.fixture(scope="module")
@@ -42,37 +38,6 @@ def qparams():
     says why: in bfloat16 the last bits pick the token)."""
     return nemotron_h.init_params_quantized(CFG, jax.random.PRNGKey(4),
                                             dtype=jnp.float32)
-
-
-def oracle(qparams, prompt: str, max_new: int) -> str:
-    """A solo loop on the same tree: one-shot prefill of the unpadded
-    prompt, K and V spliced into a one-row paged pool and the state into
-    its row of the state pool, then plain decode steps."""
-    stop_ids = set(CFG.eos_token_ids) | {TOK.eos_id}
-    ids = TOK.encode(prompt, add_bos=True)
-    n = len(ids)
-    small = KVCache.create(CFG, 1, n, dtype=jnp.float32)
-    logits, small = nemotron_h.prefill(
-        qparams, CFG, jnp.asarray([ids]), jnp.asarray([n]), small,
-        last_only=True)
-    pool = PagedKVCache.create(CFG, 1, 17, 16, max_pages_per_row=16,
-                               dtype=jnp.float32, quantized=True)
-    pool = write_prefill_batch(pool, small.k, small.v, jnp.asarray([0]),
-                               jnp.asarray([n]),
-                               1 + jnp.arange(16, dtype=jnp.int32)[None])
-    pool = pool._replace(state=state_pool.write_rows(
-        pool.state, small.state, jnp.asarray([0])))
-    last = np.asarray(logits[0, 0], np.float32)
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in stop_ids:
-            break
-        out.append(t)
-        lg, pool = nemotron_h.decode_step_paged(
-            qparams, CFG, jnp.asarray([[t]]), pool, pages=16)
-        last = np.asarray(lg[0, 0], np.float32)
-    return TOK.decode(out)
 
 
 def test_family_and_pool_geometry():
@@ -121,10 +86,9 @@ def test_admission_chunks_prefix_fused_decode_slot_reuse_and_counters(
         longer = "y" * 75               # no head, bucket 128: four chunks
         burst = [head + f"burst {i}" for i in range(5)] + [
             f"no head {i}" for i in range(3)]
-        assert run(eng, lone, max_tokens=6)[0] == oracle(qparams, lone, 6)
-        assert run(eng, long, max_tokens=6)[0] == oracle(qparams, long, 6)
-        assert run(eng, longer, max_tokens=6)[0] == oracle(qparams, longer,
-                                                            6)
+        assert run(eng, lone, max_tokens=6)[0] == SOLO(qparams, lone, 6)
+        assert run(eng, long, max_tokens=6)[0] == SOLO(qparams, long, 6)
+        assert run(eng, longer, max_tokens=6)[0] == SOLO(qparams, longer, 6)
         got, errs = {}, []
 
         def worker(p):
@@ -142,7 +106,7 @@ def test_admission_chunks_prefix_fused_decode_slot_reuse_and_counters(
         assert not errs, errs
         # Eight requests on four slots: every slot was freed and reused,
         # after occupants of other lengths.
-        assert got == {p: oracle(qparams, p, 9) for p in burst}
+        assert got == {p: SOLO(qparams, p, 9) for p in burst}
         m = eng.metrics_snapshot()
         assert m["serve_admitted_total"] == 11
         assert m["prefill_chunks_total"] >= 6
@@ -244,7 +208,7 @@ def test_promoted_prefix_serves_from_its_snapshot(qparams):
         prompts = [head + tail for tail in ("alpha", "beta", "gamma")]
         store = eng.scheduler._prefix
         for i, p in enumerate(prompts):
-            assert run(eng, p, max_tokens=8)[0] == oracle(qparams, p, 8)
+            assert run(eng, p, max_tokens=8)[0] == SOLO(qparams, p, 8)
             if i == 1:
                 deadline = time.monotonic() + 60
                 while len(store) < 1 and time.monotonic() < deadline:
@@ -274,9 +238,8 @@ def test_decode_leaves_a_free_rows_state_bit_equal(qparams):
         lengths=jnp.asarray([5, 7, 9], jnp.int32))
     before = pool.state
     active = jnp.asarray([True, False, True])
-    _, after = nemotron_h.decode_step_paged(
-        qparams, CFG, jnp.asarray([[3], [4], [5]]), pool, active=active,
-        pages=4)
+    _, after = jit_model(nemotron_h.decode_step_paged, CFG, active=active,
+                         pages=4)(qparams, jnp.asarray([[3], [4], [5]]), pool)
     for b, a in ((before.ssm, after.state.ssm),
                  (before.conv, after.state.conv)):
         b, a = np.asarray(b), np.asarray(a)

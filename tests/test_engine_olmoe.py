@@ -9,24 +9,14 @@ import threading
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from p2p_llm_chat_tpu.models.configs import get_config
-from p2p_llm_chat_tpu.models.llama import KVCache
-from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
-                                            RequestStats)
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
+from solo import Solo, generate as run
+
 TOK = ByteTokenizer(vocab_size=get_config("tiny-olmoe").vocab_size)
-
-
-def run(engine, prompt, max_tokens=12, **opts):
-    stats = RequestStats()
-    req = GenerateRequest(prompt=prompt, options=GenerateOptions(
-        max_tokens=max_tokens, **opts))
-    text = "".join(engine.generate_stream(req, stats))
-    return text, stats
 
 
 def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
@@ -46,25 +36,8 @@ def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
     mcfg = get_config("tiny-olmoe")
     assert mcfg.moe_capacity_factor is None
     qparams = mixtral.init_params_quantized(mcfg, jax.random.PRNGKey(4))
-    stop_ids = set(mcfg.eos_token_ids) | {TOK.eos_id}
-
-    def moe_oracle(prompt: str, max_new: int) -> str:
-        ids = TOK.encode(prompt, add_bos=True)
-        cache = KVCache.create(mcfg, 1, 256)
-        logits, cache = mixtral.prefill(qparams, mcfg, jnp.asarray([ids]),
-                                        jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1], np.float32)
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in stop_ids:
-                break
-            out.append(t)
-            lg, cache = mixtral.decode_step(qparams, mcfg,
-                                            jnp.asarray([[t]]), cache)
-            last = np.asarray(lg[0, 0], np.float32)
-        return TOK.decode(out)
-
+    # The solo loop on the model layer's dense cache (tests/solo.py).
+    solo = Solo(mixtral, mcfg, TOK, max_seq=256, dtype=jnp.bfloat16)
     head = "olmoe shared head, "
     eng = TPUEngine(qparams, mcfg, TOK, num_slots=8, max_seq=256,
                     page_size=16, kv_quant=True,
@@ -78,8 +51,8 @@ def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
         long = head + "x" * 90          # suffix bucket 128: four chunks
         burst = [head + f"burst {i}" for i in range(6)] + [
             f"no head {i}" for i in range(2)]
-        assert run(eng, lone, max_tokens=6)[0] == moe_oracle(lone, 6)
-        assert run(eng, long, max_tokens=6)[0] == moe_oracle(long, 6)
+        assert run(eng, lone, max_tokens=6)[0] == solo(qparams, lone, 6)
+        assert run(eng, long, max_tokens=6)[0] == solo(qparams, long, 6)
         got, errs = {}, []
 
         def worker(p):
@@ -95,7 +68,7 @@ def test_olmoe_admission_widths_chunks_prefix_and_drop_counters():
         for t in threads:
             t.join(timeout=180)
         assert not errs, errs
-        assert got == {p: moe_oracle(p, 9) for p in burst}
+        assert got == {p: solo(qparams, p, 9) for p in burst}
         m = eng.metrics_snapshot()
         assert m["serve_admitted_total"] == 10
         # Pair by pair: a dummy entry only where a group was odd.

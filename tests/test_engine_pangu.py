@@ -10,28 +10,21 @@ import threading
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from p2p_llm_chat_tpu.models import family_for, pangu
 from p2p_llm_chat_tpu.models.configs import get_config
-from p2p_llm_chat_tpu.models.llama import KVCache
-from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
-from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
-                                            RequestStats)
+from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
+from solo import Solo, generate as run
+
 CFG = get_config("tiny-pangu")
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
-
-
-def run(engine, prompt, max_tokens=12, **opts):
-    stats = RequestStats()
-    req = GenerateRequest(prompt=prompt, options=GenerateOptions(
-        max_tokens=max_tokens, **opts))
-    text = "".join(engine.generate_stream(req, stats))
-    return text, stats
+# One-shot prefill, the latents spliced into a one-row int8 pool, plain
+# decode steps (tests/solo.py).
+SOLO = Solo(pangu, CFG, TOK, pool="int8", max_seq=256, last_only=True)
 
 
 @pytest.fixture(scope="module")
@@ -42,33 +35,6 @@ def qparams():
     window's width, then pick the token."""
     return pangu.init_params_quantized(CFG, jax.random.PRNGKey(4),
                                        dtype=jnp.float32)
-
-
-def oracle(qparams, prompt: str, max_new: int, kv_quant: bool = True) -> str:
-    """A solo loop on the same tree: one-shot prefill, the latents
-    spliced into a one-row paged pool, then plain decode steps."""
-    stop_ids = set(CFG.eos_token_ids) | {TOK.eos_id}
-    ids = TOK.encode(prompt, add_bos=True)
-    n = len(ids)
-    small = KVCache.create(CFG, 1, n, dtype=jnp.float32)
-    logits, small = pangu.prefill(qparams, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([n]), small, last_only=True)
-    pool = PagedKVCache.create(CFG, 1, 17, 16, max_pages_per_row=16,
-                               dtype=jnp.float32, quantized=kv_quant)
-    pool = write_prefill_batch(pool, small.k, small.v, jnp.asarray([0]),
-                               jnp.asarray([n]),
-                               1 + jnp.arange(16, dtype=jnp.int32)[None])
-    last = np.asarray(logits[0, 0], np.float32)
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in stop_ids:
-            break
-        out.append(t)
-        lg, pool = pangu.decode_step_paged(qparams, CFG, jnp.asarray([[t]]),
-                                           pool, pages=16)
-        last = np.asarray(lg[0, 0], np.float32)
-    return TOK.decode(out)
 
 
 def test_family_and_cache_geometry():
@@ -105,8 +71,8 @@ def test_pangu_admission_chunks_prefix_fused_decode_and_counters(qparams):
         long = head + "x" * 90          # suffix bucket 128: four chunks
         burst = [head + f"burst {i}" for i in range(6)] + [
             f"no head {i}" for i in range(2)]
-        assert run(eng, lone, max_tokens=6)[0] == oracle(qparams, lone, 6)
-        assert run(eng, long, max_tokens=6)[0] == oracle(qparams, long, 6)
+        assert run(eng, lone, max_tokens=6)[0] == SOLO(qparams, lone, 6)
+        assert run(eng, long, max_tokens=6)[0] == SOLO(qparams, long, 6)
         got, errs = {}, []
 
         def worker(p):
@@ -122,7 +88,7 @@ def test_pangu_admission_chunks_prefix_fused_decode_and_counters(qparams):
         for t in threads:
             t.join(timeout=180)
         assert not errs, errs
-        assert got == {p: oracle(qparams, p, 9) for p in burst}
+        assert got == {p: SOLO(qparams, p, 9) for p in burst}
         m = eng.metrics_snapshot()
         assert m["serve_admitted_total"] == 10
         assert m["prefill_chunks_total"] >= 3

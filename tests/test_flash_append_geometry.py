@@ -1,15 +1,14 @@
 """Edge-geometry parity for the multi-chunk flash-append kernel.
 
-The round-8 long-window kernel (ops/paged_attention.
+The long-window kernel (ops/paged_attention.
 _paged_attention_flash_append: grid ``(B, chunks)``, cross-chunk
 online-softmax merge in VMEM scratch, clamped partial-chunk DMAs) runs
 here in ``interpret=True`` mode — SURVEY.md §4 "TPU without a TPU" —
 against two oracles:
 
-- the gather append path (``paged_attention_append`` with
-  ``_APPEND_IMPL`` pinned to "gather"), which shares the kernel's exact
-  append semantics (current token attended at full precision, pool
-  writes batched after the scan);
+- the gather append path (``_append_gather``, called by name), which
+  shares the kernel's exact append semantics (current token attended at
+  full precision, pool writes batched after the scan);
 - for bf16/f32 pools, the index-naive :func:`paged_attention_reference`
   over a pool with the current token written in (``write_decode`` +
   ``lengths + 1``) — the independent oracle the acceptance criteria
@@ -29,6 +28,7 @@ sizes — ci.sh full mode).
 """
 
 import importlib
+import types
 
 import numpy as np
 import pytest
@@ -42,6 +42,11 @@ from p2p_llm_chat_tpu.ops import paged_attention_reference, paged_kv
 pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
 
 pytestmark = pytest.mark.model
+
+# The two oracles, each lowered whole: called eagerly they are a program
+# an operation, for every shape of the matrix below.
+append_gather = jax.jit(pa._append_gather, static_argnames="pages")
+reference = jax.jit(paged_attention_reference, static_argnames="pages")
 
 # 64 bytes / f32 = 16 tokens = 2 pages at PS=8: pages=5 walks as 3
 # chunks (2 + 2 + 1-clamped) — the geometry the fast cases pin.
@@ -75,12 +80,12 @@ def _check_case(cfg_name, pages, ps, lengths, quantized, monkeypatch,
     if chunk_bytes is not None:
         monkeypatch.setattr(pa, "_FLASH_CHUNK_TOK_BYTES", chunk_bytes)
     # Pin the calibration geometry AT the test config's hd so the
-    # round-18 hd-aware scaling is identity here and the chunk layouts
+    # chunk budget's hd scaling is identity here and the chunk layouts
     # documented per case (pages/chunk, boundary positions) hold
-    # exactly; the scaling itself is pinned by the policy-table test.
+    # exactly; the scaling itself is pinned by
+    # test_chunk_size_function_is_the_kernels.
     monkeypatch.setattr(pa, "_FLASH_HD_REF",
                         cfg.num_kv_heads * cfg.head_dim)
-    monkeypatch.setattr(pa, "_APPEND_IMPL", "gather")  # pin the oracle path
     cache = _filled_cache(cfg, pages, ps, lengths, quantized, rng)
     B = len(lengths)
     q = jnp.asarray(rng.normal(size=(B, cfg.num_heads, cfg.head_dim)),
@@ -94,9 +99,9 @@ def _check_case(cfg_name, pages, ps, lengths, quantized, monkeypatch,
             q, kc, vc, cache.k, cache.v, cache.k_scale, cache.v_scale,
             cache.page_table, lens, jnp.asarray(layer), pages=pages,
             quantized=quantized, interpret=True)
-        ref = pa.paged_attention_append(q, kc, vc, cache, lens,
-                                        jnp.asarray(layer), pages=pages,
-                                        interpret=True)
+        ref = append_gather(
+            q, kc, vc, cache.k, cache.v, cache.k_scale, cache.v_scale,
+            cache.page_table, lens, jnp.asarray(layer), pages=pages)
         np.testing.assert_allclose(
             np.asarray(kern), np.asarray(ref), atol=2e-5, rtol=2e-5,
             err_msg=f"vs gather append: layer {layer} q={quantized}")
@@ -105,7 +110,7 @@ def _check_case(cfg_name, pages, ps, lengths, quantized, monkeypatch,
             # pool WITH the current token written (write-then-attend
             # ordering — identical on full-precision pools).
             c2 = paged_kv.write_decode(cache, jnp.asarray(layer), kc, vc)
-            ref2 = paged_attention_reference(
+            ref2 = reference(
                 q, c2.k, c2.v, c2.page_table, lens + 1, layer, pages=pages)
             np.testing.assert_allclose(
                 np.asarray(kern), np.asarray(ref2), atol=2e-5, rtol=2e-5,
@@ -216,7 +221,6 @@ def test_chunks_past_a_rows_length_are_not_read(case, quantized, rep,
     monkeypatch.setattr(pa, "_FLASH_CHUNK_TOK_BYTES", 16 if quantized else 64)
     monkeypatch.setattr(pa, "_FLASH_HD_REF",
                         cfg.num_kv_heads * cfg.head_dim)
-    monkeypatch.setattr(pa, "_APPEND_IMPL", "gather")  # pin the oracle path
     rng = np.random.default_rng(7)
     cache = _filled_cache(cfg, pages, PS, lengths, quantized, rng)
     chunk_pages = pa.flash_append_chunk_pages(
@@ -237,9 +241,9 @@ def test_chunks_past_a_rows_length_are_not_read(case, quantized, rep,
             poisoned.v_scale, poisoned.page_table, lens, jnp.asarray(layer),
             pages=pages, quantized=quantized, interpret=True))
         assert np.isfinite(kern).all(), f"a dead chunk was read: layer {layer}"
-        ref = pa.paged_attention_append(q, kc, vc, cache, lens,
-                                        jnp.asarray(layer), pages=pages,
-                                        interpret=True)
+        ref = append_gather(
+            q, kc, vc, cache.k, cache.v, cache.k_scale, cache.v_scale,
+            cache.page_table, lens, jnp.asarray(layer), pages=pages)
         np.testing.assert_allclose(kern, np.asarray(ref), atol=2e-5,
                                    rtol=2e-5, err_msg=f"layer {layer}")
         # A row of length 0 returns its current token's value, exactly.
@@ -252,7 +256,7 @@ def test_chunks_past_a_rows_length_are_not_read(case, quantized, rep,
             # it has no slot for a row that already fills the window.
             fits = np.asarray(lengths) < pages * PS
             c2 = paged_kv.write_decode(cache, jnp.asarray(layer), kc, vc)
-            ref2 = paged_attention_reference(
+            ref2 = reference(
                 q, c2.k, c2.v, c2.page_table, lens + 1, layer, pages=pages)
             np.testing.assert_allclose(kern[fits], np.asarray(ref2)[fits],
                                        atol=2e-5, rtol=2e-5,
@@ -306,99 +310,104 @@ def test_chunk_size_function_is_the_kernels(hd, int8_tokens, dtype, itemsize,
             want, -(-pages // want))
 
 
-def test_dispatch_policy_table(monkeypatch):
-    """The pure dispatch rule (decision table) plus the two runtime
-    properties the satellites pin: the threshold is read per decision —
-    flipping PAGED_APPEND_FLASH_MIN_W needs NO re-import — and the
-    platform guard keeps gather everywhere on CPU."""
-    # Default boundary (PR 31; it was 2048 while the kernel walked every
-    # chunk): kernel at W >= 1024, gather below.
-    monkeypatch.delenv("PAGED_APPEND_FLASH_MIN_W", raising=False)
-    assert pa._flash_append_min_w() == 1024
-    assert pa._flash_append_policy(2048, "gather", 1024)
-    assert pa._flash_append_policy(4096, "gather", 1024)
-    assert pa._flash_append_policy(1024, "gather", 1024)
-    assert not pa._flash_append_policy(512, "gather", 1024)
-    assert not pa._flash_append_policy(192, "gather", 1024)
-    # 0 disables the flash default outright.
-    assert not pa._flash_append_policy(1 << 20, "gather", 0)
-    # Explicit impl overrides win in both directions.
-    assert pa._flash_append_policy(64, "flash", 1024)
-    assert not pa._flash_append_policy(1 << 20, "kernel", 1024)
-    # One rule for every geometry (PR 31): the knob itself up to the
-    # calibration width (hd <= 1024), scaled down by 1024 / hd for wider
-    # ones. bench-moe's narrow KV (4 kv heads x 128 = 512) stays at 1024,
-    # where round 18's hd / 1024 scaling of the old knob had put it; the
-    # 70B class (hd = 1024) moves 2048 -> 1024.
-    assert pa._flash_append_policy(2048, "gather", 1024, hd=1024)
-    assert pa._flash_append_policy(1024, "gather", 1024, hd=1024)
-    assert not pa._flash_append_policy(1023, "gather", 1024, hd=1024)
-    assert pa._flash_append_policy(1024, "gather", 1024, hd=512)
-    assert not pa._flash_append_policy(1023, "gather", 1024, hd=512)
-    # Narrower than any served geometry: no longer scaled down (the
-    # full batch loses there; measured at hd 512, W = 512: 0.075 ms
-    # gather against 0.111).
-    assert not pa._flash_append_policy(512, "gather", 1024, hd=256)
-    assert pa._flash_append_policy(1024, "gather", 1024, hd=256)
-    # The floor: no geometry engages below 256 tokens on the default
-    # rule, however wide.
-    assert not pa._flash_append_policy(255, "gather", 1024, hd=8192)
-    assert pa._flash_append_policy(256, "gather", 1024, hd=8192)
-    assert not pa._flash_append_policy(256, "gather", 1024, hd=32)
-    # Wider-than-calibration KV (OLMoE's MHA, hd=2048): 1024 / hd of the
-    # knob, W >= 512 as PR 26 measured it (3.2-11.5x at 32 live rows on
-    # a v5e, PERF.md section 6, PR 31).
-    assert pa._flash_append_policy(512, "gather", 1024, hd=2048)
-    assert pa._flash_append_policy(2048, "gather", 1024, hd=2048)
-    assert not pa._flash_append_policy(256, "gather", 1024, hd=2048)
-    assert not pa._flash_append_policy(2048, "gather", 0, hd=2048)
-    assert pa.effective_flash_min_w(hd=2048) in (0, 512)   # 0: not a TPU
-    # The knob scales that geometry too: half of it.
-    assert pa._flash_append_policy(1024, "gather", 2048, hd=2048)
-    assert not pa._flash_append_policy(512, "gather", 2048, hd=2048)
-    # Overrides ignore geometry.
-    assert pa._flash_append_policy(64, "flash", 1024, hd=2048)
-    assert not pa._flash_append_policy(1 << 20, "kernel", 1024, hd=256)
-    # Runtime toggle: read through utils/env at dispatch time.
-    monkeypatch.setenv("PAGED_APPEND_FLASH_MIN_W", "4096")
-    assert pa._flash_append_min_w() == 4096
-    monkeypatch.setenv("PAGED_APPEND_FLASH_MIN_W", "")
-    assert pa._flash_append_min_w() == 1024      # empty = unset
-    monkeypatch.delenv("PAGED_APPEND_FLASH_MIN_W", raising=False)
-    if not pa.on_tpu():
-        # CPU CI: the platform guard must hold regardless of the policy,
-        # and the gauge helper (serve/scheduler.py `paged_flash_min_w`)
-        # must report "cannot engage" = 0.
-        assert not pa._flash_append_wanted(1 << 20)
-        assert pa.effective_flash_min_w() == 0
-        monkeypatch.setattr(pa, "on_tpu", lambda: True)
-    # On the TPU — the probe patched open above, or for real on the chip
-    # (`python -m pytest --noconftest tests/test_flash_append_geometry.py
-    # -k dispatch_policy`; conftest would pin the CPU) — the guard opens
-    # at the geometry-scaled boundary, and shuts again for a pool
-    # sharded over a mesh (pallas_call cannot consume one).
-    assert pa._flash_append_wanted(1024) and not pa._flash_append_wanted(512)
-    assert pa._flash_append_wanted(1024, 512)
-    assert not pa._flash_append_wanted(512, 512)
-    assert pa.effective_flash_min_w() == 1024
-    assert pa.effective_flash_min_w(512) == 1024
-    assert pa.effective_flash_min_w(2048) == 512
-    assert not pa._flash_append_wanted(1 << 20, sharded=True)
-    assert pa.effective_flash_min_w(sharded=True) == 0
-    # ... and for a head_dim that does not fill 128-lane rows: Mosaic
-    # refuses the kernel there (seen on the chip at tiny's D=32).
-    assert not pa._flash_append_wanted(1 << 20, 64, head_dim=32)
-    assert pa.effective_flash_min_w(64, head_dim=32) == 0
-    assert "128 lanes" in pa.flash_append_blocked(head_dim=32)
-    assert pa.flash_append_blocked(head_dim=128) is None
-    # ... and for an int8 pool whose kv heads do not fill a tile's 4
-    # sublanes (seen on the chip at 2 kv heads, PR 32); 8 do, and a bf16
-    # pool has no such tile.
-    assert not pa._flash_append_wanted(1 << 20, 256, int8_kv_heads=2)
-    assert pa.effective_flash_min_w(256, int8_kv_heads=2) == 0
-    assert "2 kv heads" in pa.flash_append_blocked(int8_kv_heads=2)
-    assert pa.flash_append_blocked(int8_kv_heads=8) is None
-    assert pa.flash_append_blocked(int8_kv_heads=0) is None
+# The boundary by pool geometry (hd = Hkv * head_dim): the first window
+# the kernel serves, and the last it does not.
+_BOUNDARY = {
+    # Narrower than any served geometry: not scaled down (the full batch
+    # loses there; measured at hd 512, W = 512: 0.075 ms gather against
+    # 0.111).
+    32: 1024, 256: 1024,
+    # bench-moe's narrow KV (4 kv heads x 128) and the calibration width
+    # (Mistral, Mixtral, the 70B class): 1,024 (PR 31; 2,048 while the
+    # kernel walked every chunk).
+    512: 1024, 1024: 1024,
+    # Wider than the calibration (OLMoE's MHA): 1024 / hd of it, as
+    # PR 26 measured (3.2-11.5x at 32 live rows on a v5e, PERF.md
+    # section 6, PR 31).
+    2048: 512,
+    # The floor: no geometry engages below 256 tokens, however wide.
+    8192: 256,
+}
+
+
+@pytest.mark.parametrize("hd", sorted(_BOUNDARY))
+def test_dispatch_policy_table(hd, monkeypatch):
+    """The dispatch rule is a function of (window, hd) and nothing else:
+    its table by geometry, with no other argument to give it and no
+    environment variable that moves it."""
+    w = _BOUNDARY[hd]
+    for env in ({}, {"PAGED_APPEND_FLASH_MIN_W": "4096",
+                     "PAGED_APPEND_IMPL": "flash",
+                     "PAGED_ATTN_IMPL": "kernel"}):
+        for name, value in env.items():
+            monkeypatch.setenv(name, value)
+        assert pa._flash_boundary(hd) == w
+        assert pa._flash_append_policy(w, hd)
+        assert pa._flash_append_policy(4 * w, hd)
+        assert not pa._flash_append_policy(w - 1, hd)
+        assert not pa._flash_append_policy(192, hd)
+    if hd == 1024:
+        assert pa._flash_append_policy(w) and not pa._flash_append_policy(w - 1)
+
+
+def _pool(Hkv=8, D=128, quantized=True, ps=64, pages=16, B=2):
+    """What the chooser reads of a cache: shapes, and whether it has
+    scales."""
+    N = B * pages + 1
+    pool = jax.ShapeDtypeStruct((1, N, ps, Hkv, D),
+                                jnp.int8 if quantized else jnp.bfloat16)
+    scale = (jax.ShapeDtypeStruct((1, N, Hkv, 128), jnp.float32)
+             if quantized else None)
+    return types.SimpleNamespace(
+        k=pool, v=pool, k_scale=scale, v_scale=scale,
+        page_table=jax.ShapeDtypeStruct((B, pages), jnp.int32))
+
+
+# Each reason flash_append_blocked can give, as (what the reason says, the
+# platform probe's answer, the pool, ``sharded``), then a pool nothing
+# blocks. Every pool's window (1,024) is at or past its boundary.
+_GUARD = {
+    "not-a-tpu": ("not on a TPU", False, _pool(), False),
+    "sharded": ("sharded over a mesh", True, _pool(), True),
+    "lanes": ("128 lanes", True, _pool(Hkv=16, D=32, quantized=False), False),
+    "sublanes": ("2 kv heads", True, _pool(Hkv=2), False),
+    "bf16-2-heads": (None, True, _pool(Hkv=2, quantized=False), False),
+    "open": (None, True, _pool(), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GUARD))
+def test_chooser_takes_gather_wherever_the_kernel_is_blocked(case,
+                                                             monkeypatch):
+    """paged_attention_append picks by what it observes alone: the gather
+    for each reason the guard names, the kernel where it names none, and
+    the gauge (``effective_flash_min_w``) says the same."""
+    reason, on_tpu, cache, sharded = _GUARD[case]
+    monkeypatch.setattr(pa, "on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(pa, "_append_gather",
+                        lambda *a, pages: ("gather", pages))
+    monkeypatch.setattr(
+        pa, "_paged_attention_flash_append",
+        lambda *a, pages, quantized: ("flash", pages, quantized))
+    Hkv, D = cache.k.shape[3:]
+    quantized = cache.k_scale is not None
+    guard = (sharded, D, Hkv if quantized else 0)
+    pages = cache.page_table.shape[1]
+    for short in (False, True):
+        n = pages // 2 if short else pages       # below / at the boundary
+        got = pa.paged_attention_append(None, None, None, cache, None, 0,
+                                        pages=n, sharded=sharded)
+        if reason or short:
+            assert got == ("gather", n)
+        else:
+            assert got == ("flash", n, quantized)
+    if reason:
+        assert reason in pa.flash_append_blocked(*guard)
+        assert pa.effective_flash_min_w(Hkv * D, *guard) == 0
+    else:
+        assert pa.flash_append_blocked(*guard) is None
+        assert pa.effective_flash_min_w(Hkv * D, *guard) == max(
+            256, 1024 * 1024 // max(Hkv * D, 1024))
 
 
 # -- long-window matrix (ci.sh full mode) -------------------------------------

@@ -1346,6 +1346,19 @@ class TestEnvHygiene:
         """, select=["env"], **self._cfg(tmp_path))
         assert fs == []
 
+    def test_no_paged_variable_under_package_or_tools(self):
+        # Decode attention chooses from the window, the pool's geometry
+        # and the platform (ops/paged_attention.py): no PAGED_* variable
+        # is read, named or scrubbed for under the package or the tools.
+        import pathlib
+        import re
+        hits = [f"{path}:{n}"
+                for top in ("p2p_llm_chat_tpu", "tools")
+                for path in pathlib.Path(REPO_ROOT, top).rglob("*.py")
+                for n, line in enumerate(path.read_text().splitlines(), 1)
+                if re.search(r"PAGED_[A-Z*]", line)]
+        assert hits == []
+
 
 # -- pytest-marker hygiene ---------------------------------------------------
 

@@ -94,18 +94,6 @@ def test_full_stack_serves_on_quantized_pool():
         eng.stop()
 
 
-def test_kv_quant_rejects_non_gather_impl_at_construction(monkeypatch):
-    """PAGED_ATTN_IMPL=kernel|flash with an int8 pool must fail at
-    engine construction, not on the scheduler thread mid-traffic."""
-    import importlib
-    import pytest
-    pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
-    monkeypatch.setattr(pa, "_DEFAULT_IMPL", "kernel")
-    with pytest.raises(ValueError, match="gather"):
-        TPUEngine(PARAMS, CFG, TOK, num_slots=2, max_seq=64,
-                  page_size=16, kv_quant=True)
-
-
 def test_spec_composes_with_quantized_pool():
     """Speculation + int8 pool: in-flight positions are attended at full
     precision in both tick kinds (paged_attention_append /

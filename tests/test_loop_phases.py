@@ -559,7 +559,7 @@ def _calls(path: str, attr: str) -> list:
 
 @pytest.mark.parametrize("path,count", [
     ("p2p_llm_chat_tpu/ops/quant_mm.py", 8),
-    ("p2p_llm_chat_tpu/ops/paged_attention.py", 4)])
+    ("p2p_llm_chat_tpu/ops/paged_attention.py", 1)])
 def test_every_pallas_call_passes_a_literal_name(path, count):
     calls = _calls(path, "pallas_call")
     assert len(calls) == count

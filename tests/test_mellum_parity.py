@@ -28,11 +28,15 @@ from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import state_pool
 from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
 
+from solo import jit_model
+
 ROOT = os.path.join(manifest.REPO, "benchmark")
 NAME = "mellum2-12b-a2.5b-instruct-l16"
 CFG = get_config("tiny-mellum2")
 W = CFG.sliding_window
 CHUNK = 16
+# The program's entry points, each lowered whole (tests/solo.py).
+prefill = jit_model(nemotron_h.prefill, CFG)
 
 
 def published() -> dict:
@@ -216,8 +220,7 @@ def test_one_piece_prefill_equals_the_reference(plain):
     long = jnp.asarray(ARCH.long_tokens(TOKENS, 512, CHUNK, W))
     T = long.shape[1]
     cache = KVCache.create(CFG, 1, T, dtype=jnp.float32)
-    logits, cache = nemotron_h.prefill(sched._params, CFG, long,
-                                       jnp.asarray([T]), cache)
+    logits, cache = prefill(sched._params, long, jnp.asarray([T]), cache)
     ref, _, _ = ARCH._stack(FILE, long, weights, W)
     assert float(jnp.max(reference.position_errors(logits, ref))) < 1e-4
 
@@ -231,8 +234,8 @@ def _decode_from(params, tokens, lens, steps, fused: bool):
     B, S = tokens.shape
     lens = jnp.asarray(lens, jnp.int32)
     small = KVCache.create(CFG, B, S, dtype=jnp.float32)
-    last, small = nemotron_h.prefill(params, CFG, tokens, lens, small,
-                                     last_only=True)
+    last, small = jit_model(nemotron_h.prefill, CFG, last_only=True)(
+        params, tokens, lens, small)
     pool = PagedKVCache.create(CFG, B, 1 + B * 16, 4, max_pages_per_row=16,
                                dtype=jnp.float32, quantized=False)
     pool = write_prefill_batch(
@@ -243,9 +246,9 @@ def _decode_from(params, tokens, lens, steps, fused: bool):
     n = steps.shape[1]
     if not fused:
         out = []
+        step = jit_model(nemotron_h.decode_step_paged, CFG, pages=16)
         for t in range(n):
-            lg, pool = nemotron_h.decode_step_paged(
-                params, CFG, steps[:, t: t + 1], pool, pages=16)
+            lg, pool = step(params, steps[:, t: t + 1], pool)
             out.append(lg)
         return last, jnp.concatenate(out, axis=1), pool
 
@@ -258,11 +261,11 @@ def _decode_from(params, tokens, lens, steps, fused: bool):
         return (jnp.take(script, i + 1, axis=1),
                 (i + 1, kept.at[:, i].set(logits)))
 
-    res = nemotron_h.decode_fused(
-        params, CFG, steps[:, :1], pool, num_steps=n, sample_fn=sample,
+    res = jit_model(
+        nemotron_h.decode_fused, CFG, num_steps=n, sample_fn=sample,
         sample_state=(jnp.zeros((), jnp.int32),
                       jnp.zeros((B, n, CFG.vocab_size), jnp.float32)),
-        stop_ids=jnp.asarray([-1]), pages=16)
+        stop_ids=jnp.asarray([-1]), pages=16)(params, steps[:, :1], pool)
     return last, res[5][1], res[3]
 
 
@@ -299,11 +302,10 @@ def test_a_padded_row_leaves_its_ring_as_its_unpadded_run_would(plain):
     ids = jnp.asarray(np.random.default_rng(3).integers(0, 512, (1, 21)),
                       jnp.int32)
     a = KVCache.create(CFG, 1, 21, dtype=jnp.float32)
-    _, a = nemotron_h.prefill(sched._params, CFG, ids, jnp.asarray([21]), a)
+    _, a = prefill(sched._params, ids, jnp.asarray([21]), a)
     b = KVCache.create(CFG, 1, 32, dtype=jnp.float32)
-    _, b = nemotron_h.prefill(sched._params, CFG,
-                              jnp.pad(ids, ((0, 0), (0, 11))),
-                              jnp.asarray([21]), b)
+    _, b = prefill(sched._params, jnp.pad(ids, ((0, 0), (0, 11))),
+                   jnp.asarray([21]), b)
     for x, y in ((a.state.win_k, b.state.win_k),
                  (a.state.win_v, b.state.win_v)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
@@ -404,14 +406,14 @@ def test_rows_not_live_keep_their_rings_bit_for_bit(qparams, fused):
         def sample(logits, st, emit_pos, act):
             return jnp.argmax(logits, -1).astype(jnp.int32), st
 
-        after = nemotron_h.decode_fused(
-            qparams, CFG, toks, pool, active=active, num_steps=2,
+        after = jit_model(
+            nemotron_h.decode_fused, CFG, active=active, num_steps=2,
             sample_fn=sample, sample_state=(),
-            stop_ids=jnp.asarray([-1]), pages=4)[3]
+            stop_ids=jnp.asarray([-1]), pages=4)(qparams, toks, pool)[3]
         steps = 2
     else:
-        _, after = nemotron_h.decode_step_paged(qparams, CFG, toks, pool,
-                                                active=active, pages=4)
+        _, after = jit_model(nemotron_h.decode_step_paged, CFG,
+                             active=active, pages=4)(qparams, toks, pool)
         steps = 1
     for b, a in zip(before[2:], after.state[2:]):
         b, a = np.asarray(b), np.asarray(a)
@@ -437,14 +439,15 @@ def test_routed_layer_is_dropless_and_counts_its_pairs(qparams):
     lens = jnp.asarray([24, 9])
     valid = jnp.arange(24)[None, :] < lens[:, None]
     small = KVCache.create(CFG, 2, 24, dtype=jnp.float32)
-    _, _, stats = nemotron_h.prefill_counted(qparams, CFG, ids, lens, small,
-                                             valid)
+    _, _, stats = jit_model(nemotron_h.prefill_counted, CFG)(
+        qparams, ids, lens, small, valid)
     pairs = 33 * CFG.num_experts_per_tok * CFG.routed_layers
     assert [int(stats[0]), int(stats[1]), int(stats[2])] == [pairs, 0, pairs]
     pool = _filled_pool()
-    _, _, st = nemotron_h.decode_step_paged_touched(
-        qparams, CFG, jnp.asarray([[3], [4], [5]]), pool,
-        active=jnp.asarray([True, False, True]), pages=4)
+    _, _, st = jit_model(
+        nemotron_h.decode_step_paged_touched, CFG,
+        active=jnp.asarray([True, False, True]), pages=4)(
+            qparams, jnp.asarray([[3], [4], [5]]), pool)
     assert int(st[1]) == CFG.num_experts * CFG.routed_layers
     assert int(st[2]) == int(st[3]) == 2 * 2 * CFG.routed_layers
 

@@ -31,6 +31,8 @@ from p2p_llm_chat_tpu.ops import state_pool  # noqa: E402
 from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,  # noqa: E402
                                            write_prefill_batch)
 
+from solo import jit_model  # noqa: E402
+
 CFG = get_config("tiny-nemotron-h")
 # The published key names of the same model, as the reference reads them.
 KEYS = {"name": "tiny-nemotron-h", "hidden_size": 128, "vocab_size": 512,
@@ -47,6 +49,11 @@ KEYS = {"name": "tiny-nemotron-h", "hidden_size": 128, "vocab_size": 512,
         "max_position_embeddings": 256, "tie_word_embeddings": False}
 B, P, D = 2, 40, 8
 PS, PER_ROW = 16, 4
+# The program's entry points, each lowered whole (tests/solo.py): called
+# eagerly a forward is a program an operation.
+prefill = jit_model(nemotron_h.prefill, CFG)
+prefill_last = jit_model(nemotron_h.prefill, CFG, last_only=True)
+decode_step = jit_model(nemotron_h.decode_step_paged, CFG, pages=PER_ROW)
 
 
 @pytest.fixture(scope="module")
@@ -86,8 +93,8 @@ def pools_from(carry, quantized, lens=None):
 
 def one_shot(params, tokens, n=P):
     cache = KVCache.create(CFG, B, n, dtype=jnp.float32)
-    return nemotron_h.prefill(params, CFG, tokens[:, :n],
-                              jnp.full((B,), n, jnp.int32), cache)
+    return prefill(params, tokens[:, :n], jnp.full((B,), n, jnp.int32),
+                   cache)
 
 
 def close(a, b, tol=2e-3):
@@ -122,8 +129,8 @@ def test_chunked_prefill_with_carry_is_one_piece(setup, edges):
     carry = KVCache.create(CFG, B, P, dtype=jnp.float32)
     out = []
     for lo, hi in zip(edges, edges[1:]):
-        logits, carry = nemotron_h.prefill_chunk(
-            params, CFG, tokens[:, lo:hi], carry, lo)
+        logits, carry = jit_model(nemotron_h.prefill_chunk, CFG, offset=lo)(
+            params, tokens[:, lo:hi], carry)
         out.append(logits)
     close(jnp.concatenate(out, axis=1), ref[:, :P])
     for got, want in ((carry.k, whole.k), (carry.state.ssm, whole.state.ssm),
@@ -143,13 +150,13 @@ def test_padded_admission_is_each_rows_unpadded_run(setup):
     valid = (jnp.arange(64)[None, :] < lens[:, None]) & jnp.asarray(
         [True, True, False])[:, None]
     cache = KVCache.create(CFG, 3, 64, dtype=jnp.float32)
-    logits, cache, _ = nemotron_h.prefill_counted(
-        params, CFG, padded, lens, cache, valid, last_only=True)
+    logits, cache, _ = jit_model(nemotron_h.prefill_counted, CFG,
+                                 last_only=True)(
+        params, padded, lens, cache, valid)
     for row, n in ((0, 23), (1, 40)):
         solo = KVCache.create(CFG, 1, n, dtype=jnp.float32)
-        want, solo = nemotron_h.prefill(
-            params, CFG, tokens[row: row + 1, :n], jnp.asarray([n]), solo,
-            last_only=True)
+        want, solo = prefill_last(params, tokens[row: row + 1, :n],
+                                  jnp.asarray([n]), solo)
         close(logits[row: row + 1], want)
         np.testing.assert_allclose(np.asarray(cache.state.ssm[:, row]),
                                    np.asarray(solo.state.ssm[:, 0]),
@@ -168,8 +175,7 @@ def test_decode_through_both_pools_is_the_reference(setup, quantized, tol):
     pool = pools_from(carry, quantized)
     out = []
     for t in range(P, P + D):
-        logits, pool = nemotron_h.decode_step_paged(
-            params, CFG, tokens[:, t: t + 1], pool, pages=PER_ROW)
+        logits, pool = decode_step(params, tokens[:, t: t + 1], pool)
         out.append(logits)
     close(jnp.concatenate(out, axis=1), ref[:, P:], tol)
     assert list(np.asarray(pool.lengths)) == [P + D] * B
@@ -187,14 +193,13 @@ def test_fused_decode_is_the_plain_steps(setup):
 
     plain, toks, tok = pools_from(carry, True), [], tokens[:, P: P + 1]
     for _ in range(4):
-        logits, plain = nemotron_h.decode_step_paged(
-            params, CFG, tok, plain, pages=PER_ROW)
+        logits, plain = decode_step(params, tok, plain)
         tok = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)[:, None]
         toks.append(tok[:, 0])
-    fused = nemotron_h.decode_fused(
-        params, CFG, tokens[:, P: P + 1], pools_from(carry, True),
-        num_steps=4, sample_fn=greedy, sample_state=(), stop_ids=(),
-        pages=PER_ROW)
+    fused = jit_model(
+        nemotron_h.decode_fused, CFG, num_steps=4, sample_fn=greedy,
+        sample_state=(), stop_ids=(), pages=PER_ROW)(
+            params, tokens[:, P: P + 1], pools_from(carry, True))
     assert np.array_equal(np.asarray(fused[0]), np.asarray(jnp.stack(toks)))
     np.testing.assert_allclose(np.asarray(fused[3].state.ssm),
                                np.asarray(plain.state.ssm), rtol=1e-5,

@@ -27,6 +27,8 @@ from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
 from p2p_llm_chat_tpu.parallel.mesh import MeshConfig, make_mesh
 from p2p_llm_chat_tpu.parallel.sharding import shard_params
 
+from solo import jit_model
+
 CFG = get_config("tiny-olmoe")
 OLMOE = manifest.load_architecture(manifest.REPO + "/benchmark", "olmoe")
 # The published key names of tiny-olmoe, as a configuration file has them.
@@ -132,8 +134,8 @@ def system_logits(params, config, tokens, paged: bool, mesh=None):
     dtype = params["embed"].dtype
     lens = jnp.full((B,), P, jnp.int32)
     small = KVCache.create(config, B, P if paged else P + D, dtype=dtype)
-    logits, small = mixtral.prefill(params, config, tokens[:, :P], lens,
-                                    small, mesh)
+    logits, small = jit_model(mixtral.prefill, config, mesh=mesh)(
+        params, tokens[:, :P], lens, small)
     out = [logits.astype(jnp.float32)]
     if paged:
         ps, per_row = 8, 4
@@ -147,14 +149,10 @@ def system_logits(params, config, tokens, paged: bool, mesh=None):
                                     tables)
     else:
         cache = small
+    decode = (jit_model(mixtral.decode_step_paged, config, mesh=mesh, pages=4)
+              if paged else jit_model(mixtral.decode_step, config, mesh=mesh))
     for t in range(P, P + D):
-        tok = tokens[:, t:t + 1]
-        if paged:
-            step, cache = mixtral.decode_step_paged(params, config, tok,
-                                                    cache, mesh, pages=4)
-        else:
-            step, cache = mixtral.decode_step(params, config, tok, cache,
-                                              mesh)
+        step, cache = decode(params, tokens[:, t:t + 1], cache)
         out.append(step.astype(jnp.float32))
     return jnp.concatenate(out, axis=1)
 

@@ -1,10 +1,14 @@
-"""Paged KV cache + Pallas paged-attention kernel tests (CPU).
+"""Paged KV cache + paged decode attention tests (CPU).
 
-The kernel runs in ``interpret=True`` mode against two oracles (SURVEY.md
-§4 "TPU without a TPU"): the jnp reference over gathered-dense pages, and
-models/layers.attend_gqa over an equivalent dense cache. Write ops are
-checked for slot/page math, garbage-page routing, and allocator hygiene.
+The decode-attention entry points (the gather append, the flash-append
+kernel in ``interpret=True`` mode, the block verify at one position) run
+against two oracles (SURVEY.md §4 "TPU without a TPU"): the jnp reference
+over gathered-dense pages, and models/layers.attend_gqa over an
+equivalent dense cache. Write ops are checked for slot/page math,
+garbage-page routing, and allocator hygiene.
 """
+
+import importlib
 
 import numpy as np
 import pytest
@@ -14,8 +18,17 @@ import jax.numpy as jnp
 
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.ops import (PageAllocator, PagedKVCache,
-                                  paged_attention, paged_attention_reference)
+                                  paged_attention_reference)
 from p2p_llm_chat_tpu.ops import paged_kv
+
+pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
+# The XLA entry points and the oracle, each lowered whole (the kernel's
+# entry point is jitted where it is defined).
+append_gather = jax.jit(pa._append_gather, static_argnames="pages")
+verify_append = jax.jit(pa.paged_attention_verify_append,
+                        static_argnames="pages")
+paged_attention_reference = jax.jit(paged_attention_reference,
+                                    static_argnames="pages")
 
 pytestmark = pytest.mark.model
 
@@ -190,13 +203,41 @@ def test_parked_row_with_zero_table_writes_garbage_only():
     assert np.any(np.asarray(cache2.k[0, 0]) == 99.0)
 
 
+def _attend_last(entry, q, cache, k_cur, v_cur, pool_lens, layer, pages):
+    """One decode-attention entry point by name: the pool's first
+    ``pool_lens`` positions plus the current token's k/v."""
+    pool = (cache.k, cache.v, cache.k_scale, cache.v_scale,
+            cache.page_table, pool_lens, jnp.asarray(layer))
+    if entry == "gather":
+        return append_gather(q, k_cur, v_cur, *pool, pages=pages)
+    if entry == "flash":
+        return pa._paged_attention_flash_append(
+            q, k_cur, v_cur, *pool, pages=pages,
+            quantized=cache.k_scale is not None, interpret=True)
+    assert entry == "verify"
+    return verify_append(
+        q[:, None], k_cur[:, None], v_cur[:, None], cache, pool_lens,
+        jnp.asarray(layer), pages=pages)[:, 0]
+
+
+def _last_token(dense, layer, lengths):
+    """[B, Hkv, D]: each row's token at ``lengths - 1``."""
+    return jnp.asarray(np.stack(
+        [dense[layer, b, n - 1] for b, n in enumerate(lengths)]))
+
+
+ENTRIES = ["gather", "flash", "verify"]
+
+
 @pytest.mark.parametrize("CFG", [CFG, MHA], ids=["gqa", "mha"])
-@pytest.mark.parametrize("impl", ["gather", "kernel", "flash"])
+@pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("lengths", [[1, 9, 16], [8, 8, 8], [3, 27, 1]])
-def test_kernel_matches_reference_and_dense(lengths, impl, CFG):
-    """Both production implementations (gather default + Pallas kernel in
-    interpret mode) against the index-naive reference AND an independent
-    dense oracle, at rep 2 and at rep 1."""
+def test_kernel_matches_reference_and_dense(lengths, entry, CFG):
+    """Every decode-attention entry point (the gather append, the
+    flash-append kernel in interpret mode, the block verify at one
+    position), attending a pool of ``lengths - 1`` plus the last token
+    as the current one, against the index-naive reference over
+    ``lengths`` AND an independent dense oracle, at rep 2 and at rep 1."""
     rng = np.random.default_rng(7)
     cache, dense_k, dense_v, _, _ = random_filled_cache(
         rng, lengths, num_pages=32, CFG=CFG)
@@ -207,9 +248,10 @@ def test_kernel_matches_reference_and_dense(lengths, impl, CFG):
     pages = -(-max(lengths) // PS)
 
     for layer in range(CFG.num_layers):
-        got = paged_attention(q, cache.k, cache.v, cache.page_table, lens,
-                              jnp.asarray(layer), pages=pages, interpret=True,
-                              impl=impl)
+        got = _attend_last(entry, q, cache,
+                           _last_token(dense_k, layer, lengths),
+                           _last_token(dense_v, layer, lengths),
+                           lens - 1, layer, pages)
         ref = paged_attention_reference(q, cache.k, cache.v,
                                         cache.page_table, lens, layer,
                                         pages=pages)
@@ -227,21 +269,23 @@ def test_kernel_matches_reference_and_dense(lengths, impl, CFG):
                                    atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("impl", ["gather", "kernel", "flash"])
-def test_kernel_ignores_garbage_table_entries_past_length(impl):
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_kernel_ignores_garbage_table_entries_past_length(entry):
     """Dead page-table entries (0) beyond a row's live pages must not
     affect the result even when the page walk covers them."""
     rng = np.random.default_rng(8)
-    cache, _, _, _, _ = random_filled_cache(rng, [3, 20], num_pages=32)
+    lengths = [3, 20]
+    cache, dense_k, dense_v, _, _ = random_filled_cache(rng, lengths,
+                                                        num_pages=32)
     # Poison the garbage page with huge values.
     cache = cache._replace(k=cache.k.at[:, 0].set(1e4),
                            v=cache.v.at[:, 0].set(1e4))
     B = 2
     q = jnp.asarray(rng.normal(size=(B, CFG.num_heads, CFG.head_dim)),
                     jnp.float32)
-    lens = jnp.asarray([3, 20], jnp.int32)
-    got = paged_attention(q, cache.k, cache.v, cache.page_table, lens,
-                          jnp.asarray(0), pages=3, interpret=True, impl=impl)
+    lens = jnp.asarray(lengths, jnp.int32)
+    got = _attend_last(entry, q, cache, _last_token(dense_k, 0, lengths),
+                       _last_token(dense_v, 0, lengths), lens - 1, 0, 3)
     ref = paged_attention_reference(q, cache.k, cache.v, cache.page_table,
                                     lens, 0, pages=3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -265,7 +309,8 @@ def test_write_decode_multi_out_of_table_goes_to_garbage():
 
     S = 4                                       # 2 in-range + 2 past-table
     k = jnp.full((B, S, CFG.num_kv_heads, CFG.head_dim), 7.0, jnp.float32)
-    out = paged_kv.write_decode_multi(cache, jnp.asarray(0), k, k)
+    k_all = jnp.broadcast_to(k, (CFG.num_layers,) + k.shape)
+    out = paged_kv.write_decode_multi_all_layers(cache, k_all, k_all)
     got = np.asarray(out.k[0, 5])               # [PS, Hkv, D]
     # Slots 0..PS-3 of the last real page are untouched; only the two
     # in-range positions (slots PS-2, PS-1) changed.
@@ -292,9 +337,9 @@ def test_quant_kv_roundtrip_bound():
 
 def test_quantized_pool_write_paths_and_attention():
     """All write paths quantize transparently; gather_dense dequantizes;
-    int8 paged_attention matches the reference run on the dequantized
-    pool exactly (scale folding is algebra, not approximation) and the
-    bf16 attend within the rounding bound."""
+    int8 decode attention (every entry point) matches the reference run
+    on the dequantized pool exactly (scale folding is algebra, not
+    approximation), the current token at full precision."""
     rng = np.random.default_rng(1)
     B, mppr = 3, 4
     cache = PagedKVCache.create(CFG, B, 16, PS, max_pages_per_row=mppr,
@@ -318,20 +363,27 @@ def test_quantized_pool_write_paths_and_attention():
     cache2 = paged_kv.write_decode(cache, jnp.asarray(0), k1, k1 * 2)
     lens = jnp.asarray(lengths, jnp.int32)
 
-    # int8 attention == reference over the dequantized pool (exact)
+    # int8 attention == reference over the dequantized pool (exact): the
+    # pool before the append plus k1 as the current token, against the
+    # dequantized pool with k1 written in at full precision.
     q = jnp.asarray(rng.normal(size=(B, CFG.num_heads, CFG.head_dim)),
                     jnp.float32)
-    got = paged_attention(q, cache2.k, cache2.v, cache2.page_table,
-                          lens + 1, jnp.asarray(0), pages=mppr,
-                          k_scale=cache2.k_scale, v_scale=cache2.v_scale)
-    deq_k = (cache2.k.astype(jnp.float32)
-             * cache2.k_scale_view[..., None]).astype(jnp.float32)
-    deq_v = (cache2.v.astype(jnp.float32)
-             * cache2.v_scale_view[..., None]).astype(jnp.float32)
-    ref = paged_attention_reference(q, deq_k, deq_v, cache2.page_table,
+
+    def dequantized(c):
+        return c._replace(
+            k=c.k.astype(jnp.float32) * c.k_scale_view[..., None],
+            v=c.v.astype(jnp.float32) * c.v_scale_view[..., None],
+            k_scale=None, v_scale=None)
+
+    full = paged_kv.write_decode(dequantized(cache), jnp.asarray(0), k1,
+                                 k1 * 2)
+    ref = paged_attention_reference(q, full.k, full.v, full.page_table,
                                     lens + 1, 0, pages=mppr)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=1e-4, rtol=1e-4)
+    for entry in ENTRIES:
+        got = _attend_last(entry, q, cache, k1, k1 * 2, lens, 0, mppr)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-4, rtol=1e-4, err_msg=entry)
+    deq_k = dequantized(cache2).k
 
     # gather_dense dequantizes to the same values the attend saw
     kd, vd = paged_kv.gather_dense(cache2, 0, mppr * PS)
@@ -339,64 +391,9 @@ def test_quantized_pool_write_paths_and_attention():
         np.asarray(kd[0, :5]),
         np.asarray(deq_k[0][cache2.page_table[0, 0], :5]), rtol=1e-6)
 
-    # non-gather impls reject int8 pools
-    with pytest.raises(ValueError, match="gather"):
-        paged_attention(q, cache2.k, cache2.v, cache2.page_table, lens + 1,
-                        jnp.asarray(0), pages=mppr, impl="kernel",
-                        k_scale=cache2.k_scale, v_scale=cache2.v_scale)
-
-
-def test_append_kernel_interpret_matches_gather():
-    """The opt-in Pallas append kernel (PAGED_APPEND_IMPL=kernel) agrees
-    with the gather path in interpret mode — CPU coverage for the Mosaic
-    program the TPU parity check (tools/check_append_kernel.py) runs on
-    hardware."""
-    import importlib
-
-    pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
-    cfg = get_config("tiny-tp")     # 4 kv heads, head_dim 32
-    rng = np.random.default_rng(5)
-    B, pages, ps = 4, 2, 16
-    mppr = pages
-    for quantized in (False, True):
-        cache = paged_kv.PagedKVCache.create(
-            cfg, B, B * mppr + 1, ps, max_pages_per_row=mppr,
-            dtype=jnp.float32, quantized=quantized)
-        lens = []
-        for b in range(B):
-            n = int(rng.integers(1, pages * ps - 1))
-            lens.append(n)
-            table = jnp.asarray(1 + b * mppr + np.arange(mppr), jnp.int32)
-            rk = jnp.asarray(rng.normal(size=(cfg.num_layers, pages * ps,
-                                              cfg.num_kv_heads,
-                                              cfg.head_dim)), jnp.float32)
-            rv = jnp.asarray(rng.normal(size=rk.shape), jnp.float32)
-            cache = paged_kv.write_prefill_row(cache, rk, rv,
-                                               jnp.asarray(b),
-                                               jnp.asarray(n), table)
-        lens = jnp.asarray(lens, jnp.int32)
-        q = jnp.asarray(rng.normal(size=(B, cfg.num_heads, cfg.head_dim)),
-                        jnp.float32)
-        kc = jnp.asarray(rng.normal(size=(B, cfg.num_kv_heads,
-                                          cfg.head_dim)), jnp.float32)
-        vc = jnp.asarray(rng.normal(size=kc.shape), jnp.float32)
-        kern = pa._paged_append_kernel_call(
-            q, kc, vc, cache.k, cache.v, cache.k_scale, cache.v_scale,
-            cache.page_table, lens, jnp.asarray(0), pages=pages,
-            quantized=quantized, interpret=True)
-        saved = pa._APPEND_IMPL
-        pa._APPEND_IMPL = "gather"      # pin the reference path
-        try:
-            ref = pa.paged_attention_append(q, kc, vc, cache, lens,
-                                            jnp.asarray(0), pages=pages)
-        finally:
-            pa._APPEND_IMPL = saved
-        np.testing.assert_allclose(np.asarray(kern), np.asarray(ref),
-                                   atol=2e-2, rtol=2e-2)
-
 
 def test_flash_append_kernel_interpret_matches_gather(monkeypatch):
-    """The long-window flash-append kernel (round-8 multi-chunk
+    """The long-window flash-append kernel (multi-chunk
     ``(B, chunks)`` grid: manual page + scale DMAs, online softmax
     carried in VMEM scratch across the chunk axis, seeded with the
     current token) agrees with the gather append path in interpret
@@ -406,9 +403,6 @@ def test_flash_append_kernel_interpret_matches_gather(monkeypatch):
     clamping (the riskiest logic) all execute hardware-free. The
     deeper edge-geometry matrix lives in
     tests/test_flash_append_geometry.py."""
-    import importlib
-
-    pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
     monkeypatch.setattr(pa, "_FLASH_CHUNK_TOK_BYTES", 64)  # 16 f32 tokens
     cfg = get_config("tiny-tp")     # 4 kv heads, head_dim 32
     # Identity hd scaling at the test geometry (see
@@ -444,16 +438,11 @@ def test_flash_append_kernel_interpret_matches_gather(monkeypatch):
             q, kc, vc, cache.k, cache.v, cache.k_scale, cache.v_scale,
             cache.page_table, lens, jnp.asarray(0), pages=pages,
             quantized=quantized, interpret=True)
-        saved = pa._APPEND_IMPL
-        pa._APPEND_IMPL = "gather"      # pin the reference path
-        try:
-            ref = pa.paged_attention_append(q, kc, vc, cache, lens,
-                                            jnp.asarray(0), pages=pages)
-        finally:
-            pa._APPEND_IMPL = saved
-        # Tight: interpret mode computes in f32 (the round-8 dispatch
-        # swaps the bf16 MXU dtype out), so parity is no longer
-        # bf16-loose.
+        ref = append_gather(
+            q, kc, vc, cache.k, cache.v, cache.k_scale, cache.v_scale,
+            cache.page_table, lens, jnp.asarray(0), pages=pages)
+        # Tight: interpret mode computes in f32 (the dispatch swaps the
+        # bf16 MXU dtype out).
         np.testing.assert_allclose(np.asarray(kern), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5, err_msg=str(quantized))
 

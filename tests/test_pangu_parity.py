@@ -27,6 +27,8 @@ from p2p_llm_chat_tpu.models.llama import KVCache  # noqa: E402
 from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,  # noqa: E402
                                            write_prefill_batch)
 
+from solo import jit_model  # noqa: E402
+
 CFG = get_config("tiny-pangu")
 # The published key names of the same model, as the reference reads them.
 KEYS = {"name": "tiny-pangu", "hidden_size": 128, "intermediate_size": 256,
@@ -40,6 +42,11 @@ KEYS = {"name": "tiny-pangu", "hidden_size": 128, "intermediate_size": 256,
         "rope_theta": 10000.0, "vocab_size": 512}
 B, P, D = 2, 32, 8
 PS, PER_ROW = 16, 4
+# The program's entry points, each lowered whole (tests/solo.py).
+prefill = jit_model(pangu.prefill, CFG)
+decode_step = jit_model(pangu.decode_step_paged, CFG, pages=PER_ROW)
+decode_step_touched = jit_model(pangu.decode_step_paged_touched, CFG,
+                                pages=PER_ROW)
 
 
 @pytest.fixture(scope="module")
@@ -71,8 +78,8 @@ def pool_from(carry, quantized):
 
 def one_shot(params, tokens):
     cache = KVCache.create(CFG, B, P, dtype=jnp.float32)
-    return pangu.prefill(params, CFG, tokens[:, :P],
-                         jnp.full((B,), P, jnp.int32), cache)
+    return prefill(params, tokens[:, :P], jnp.full((B,), P, jnp.int32),
+                   cache)
 
 
 def test_one_shot_prefill_is_the_reference(setup):
@@ -91,8 +98,8 @@ def test_chunked_prefill_is_the_one_shot_prefill(setup, chunk):
     carry = KVCache.create(CFG, B, P, dtype=jnp.float32)
     got = []
     for off in range(0, P, chunk):
-        logits, carry = pangu.prefill_chunk(
-            params, CFG, tokens[:, off:off + chunk], carry, off)
+        logits, carry = jit_model(pangu.prefill_chunk, CFG, offset=off)(
+            params, tokens[:, off:off + chunk], carry)
         got.append(logits)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(got, 1)),
                                np.asarray(want), atol=2e-4)
@@ -118,8 +125,8 @@ def test_decode_through_the_latent_pool_is_the_reference(setup, quantized,
     pool = pool_from(carry, quantized)
     out = []
     for t in range(P, P + D):
-        logits, pool, stats = pangu.decode_step_paged_touched(
-            params, CFG, tokens[:, t:t + 1], pool, pages=PER_ROW)
+        logits, pool, stats = decode_step_touched(
+            params, tokens[:, t:t + 1], pool)
         out.append(logits)
     err = reference.position_errors(jnp.concatenate(out, 1), ref[:, P:])
     assert float(jnp.max(err)) < limit
@@ -140,14 +147,15 @@ def test_fused_decode_is_the_plain_steps(setup):
         return jnp.argmax(logits, -1).astype(jnp.int32), state
 
     first = tokens[:, P:P + 1]
-    toks, _, _, fused_pool, _, _, stats = pangu.decode_fused_touched(
-        params, CFG, first, pool_from(carry, True), active=active,
-        num_steps=4, sample_fn=greedy, sample_state=(), stop_ids=(),
-        pages=PER_ROW)
+    toks, _, _, fused_pool, _, _, stats = jit_model(
+        pangu.decode_fused_touched, CFG, active=active, num_steps=4,
+        sample_fn=greedy, sample_state=(), stop_ids=(), pages=PER_ROW)(
+            params, first, pool_from(carry, True))
     pool, tok, want = pool_from(carry, True), first, []
+    step = jit_model(pangu.decode_step_paged, CFG, active=active,
+                     pages=PER_ROW)
     for _ in range(4):
-        logits, pool = pangu.decode_step_paged(params, CFG, tok, pool,
-                                               active=active, pages=PER_ROW)
+        logits, pool = step(params, tok, pool)
         nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
         want.append(nxt)
         tok = jnp.where(active[:, None], nxt[:, None], tok)
@@ -167,12 +175,11 @@ def test_a_session_wakes_suffix_is_the_decode_steps(setup):
     params, tokens, _ = setup
     _, carry = one_shot(params, tokens)
     pool = pool_from(carry, False)
-    blk, after = pangu.verify_step_paged(params, CFG, tokens[:, P:P + 4],
-                                         pool, pages=PER_ROW)
+    blk, after = jit_model(pangu.verify_step_paged, CFG, pages=PER_ROW)(
+        params, tokens[:, P:P + 4], pool)
     out = []
     for t in range(P, P + 4):
-        logits, pool = pangu.decode_step_paged(
-            params, CFG, tokens[:, t:t + 1], pool, pages=PER_ROW)
+        logits, pool = decode_step(params, tokens[:, t:t + 1], pool)
         out.append(logits)
     np.testing.assert_allclose(np.asarray(blk),
                                np.asarray(jnp.concatenate(out, 1)),

@@ -23,6 +23,8 @@ from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import state_pool
 from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache
 
+from solo import jit_model
+
 ROOT = os.path.join(manifest.REPO, "benchmark")
 CFG = get_config("tiny-phi4flash")
 CHUNK = 16
@@ -126,8 +128,8 @@ def test_one_piece_prefill_equals_the_reference(plain):
     long = jnp.asarray(ARCH.long_tokens(TOKENS, 512, CHUNK))
     T = long.shape[1]
     cache = KVCache.create(CFG, 1, T, dtype=jnp.float32)
-    logits, cache = nemotron_h.prefill(sched._params, CFG, long,
-                                       jnp.asarray([T]), cache)
+    logits, cache = jit_model(nemotron_h.prefill, CFG)(
+        sched._params, long, jnp.asarray([T]), cache)
     ref, _ = ARCH._stack(FILE, long, weights, CFG.sliding_window)
     assert float(jnp.max(reference.position_errors(logits, ref))) < 1e-4
 
@@ -195,14 +197,14 @@ def test_rows_not_live_keep_ring_and_state_bit_for_bit(qparams, fused):
         def sample(logits, st, emit_pos, act):
             return jnp.argmax(logits, -1).astype(jnp.int32), st
 
-        after = nemotron_h.decode_fused(
-            qparams, CFG, toks, pool, active=active, num_steps=2,
+        after = jit_model(
+            nemotron_h.decode_fused, CFG, active=active, num_steps=2,
             sample_fn=sample, sample_state=(),
-            stop_ids=jnp.asarray([-1]), pages=4)[3]
+            stop_ids=jnp.asarray([-1]), pages=4)(qparams, toks, pool)[3]
         steps = 2
     else:
-        _, after = nemotron_h.decode_step_paged(qparams, CFG, toks, pool,
-                                                active=active, pages=4)
+        _, after = jit_model(nemotron_h.decode_step_paged, CFG,
+                             active=active, pages=4)(qparams, toks, pool)
         steps = 1
     for b, a in zip(before, after.state):
         b, a = np.asarray(b), np.asarray(a)

@@ -31,10 +31,16 @@ from p2p_llm_chat_tpu.models.quant import (QTensor, QTensor4, dequantize,
                                            quantize, quantize4,
                                            quantize_params, unpack4)
 
+from solo import Solo, jit_model
+
 pytestmark = pytest.mark.model
 
 CFG = get_config("tiny")
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
+# The model's entry points, each lowered whole (tests/solo.py): a
+# quantized and a dequantized tree are two lowerings of the same function.
+prefill = jit_model(llama.prefill, CFG)
+decode_step = jit_model(llama.decode_step, CFG)
 
 
 def dequantize_tree(params):
@@ -88,14 +94,14 @@ def test_quantized_forward_matches_dequantized_oracle():
     lens = jnp.asarray([12, 9], jnp.int32)
     cache_q = KVCache.create(CFG, 2, 32, jnp.float32)
     cache_d = KVCache.create(CFG, 2, 32, jnp.float32)
-    lq, cache_q = llama.prefill(qparams, CFG, tokens, lens, cache_q)
-    ld, cache_d = llama.prefill(dparams, CFG, tokens, lens, cache_d)
+    lq, cache_q = prefill(qparams, tokens, lens, cache_q)
+    ld, cache_d = prefill(dparams, tokens, lens, cache_d)
     np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                rtol=2e-4, atol=2e-4)
     nxt = jnp.argmax(lq[:, -1], -1).astype(jnp.int32)[:, None]
     for _ in range(3):
-        lq, cache_q = llama.decode_step(qparams, CFG, nxt, cache_q)
-        ld, cache_d = llama.decode_step(dparams, CFG, nxt, cache_d)
+        lq, cache_q = decode_step(qparams, nxt, cache_q)
+        ld, cache_d = decode_step(dparams, nxt, cache_d)
         np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                    rtol=2e-4, atol=2e-4)
         nxt = jnp.argmax(lq[:, 0], -1).astype(jnp.int32)[:, None]
@@ -109,10 +115,10 @@ def test_quantized_close_to_full_precision():
         np.random.default_rng(3).integers(0, CFG.vocab_size, (1, 10)),
         jnp.int32)
     lens = jnp.asarray([10], jnp.int32)
-    lq, _ = llama.prefill(qparams, CFG, tokens, lens,
-                          KVCache.create(CFG, 1, 16, jnp.float32))
-    lf, _ = llama.prefill(PARAMS, CFG, tokens, lens,
-                          KVCache.create(CFG, 1, 16, jnp.float32))
+    lq, _ = prefill(qparams, tokens, lens,
+                    KVCache.create(CFG, 1, 16, jnp.float32))
+    lf, _ = prefill(PARAMS, tokens, lens,
+                    KVCache.create(CFG, 1, 16, jnp.float32))
     a = np.asarray(lq).reshape(-1)
     b = np.asarray(lf).reshape(-1)
     cos = float(a @ b / (np.linalg.norm(a) * np.linalg.norm(b) + 1e-9))
@@ -129,10 +135,11 @@ def test_moe_quantized_matches_dequantized_oracle():
         np.random.default_rng(4).integers(0, mcfg.vocab_size, (2, 8)),
         jnp.int32)
     lens = jnp.asarray([8, 6], jnp.int32)
-    lq, _ = mixtral.prefill(qparams, mcfg, tokens, lens,
-                            KVCache.create(mcfg, 2, 16, jnp.float32))
-    ld, _ = mixtral.prefill(dparams, mcfg, tokens, lens,
-                            KVCache.create(mcfg, 2, 16, jnp.float32))
+    moe_prefill = jit_model(mixtral.prefill, mcfg)
+    lq, _ = moe_prefill(qparams, tokens, lens,
+                        KVCache.create(mcfg, 2, 16, jnp.float32))
+    ld, _ = moe_prefill(dparams, tokens, lens,
+                        KVCache.create(mcfg, 2, 16, jnp.float32))
     np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                rtol=2e-4, atol=2e-4)
 
@@ -149,31 +156,14 @@ def test_quantized_params_serve_through_engine():
 
     tok = ByteTokenizer(vocab_size=CFG.vocab_size)
     qparams = quantize_params(PARAMS)
-    stop_ids = set(CFG.eos_token_ids) | {tok.eos_id}
-
-    def oracle(prompt, max_new):
-        ids = tok.encode(prompt, add_bos=True)
-        cache = KVCache.create(CFG, 1, 64, jnp.float32)
-        logits, cache = llama.prefill(qparams, CFG, jnp.asarray([ids]),
-                                      jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1])
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in stop_ids:
-                break
-            out.append(t)
-            lg, cache = llama.decode_step(qparams, CFG, jnp.asarray([[t]]),
-                                          cache)
-            last = np.asarray(lg[0, 0])
-        return tok.decode(out)
+    oracle = Solo(llama, CFG, tok, max_seq=64)
 
     eng = TPUEngine(qparams, CFG, tok, num_slots=2, max_seq=64)
     try:
         req = GenerateRequest(prompt="quantized serving",
                               options=GenerateOptions(max_tokens=8))
         got = "".join(eng.generate_stream(req, RequestStats()))
-        assert got == oracle("quantized serving", 8)
+        assert got == oracle(qparams, "quantized serving", 8)
     finally:
         eng.stop()
 
@@ -190,13 +180,12 @@ def test_quantize_after_shard_matches_unsharded():
         np.random.default_rng(5).integers(0, CFG.vocab_size, (2, 8)),
         jnp.int32)
     lens = jnp.asarray([8, 8], jnp.int32)
-    ref, _ = llama.prefill(quantize_params(PARAMS), CFG, tokens, lens,
-                           KVCache.create(CFG, 2, 16, jnp.float32))
+    ref, _ = prefill(quantize_params(PARAMS), tokens, lens,
+                     KVCache.create(CFG, 2, 16, jnp.float32))
     sharded = shard_params(PARAMS, llama.param_axes(CFG), mesh)
     qsharded = quantize_params(sharded)
-    got, _ = llama.prefill(qsharded, CFG, tokens, lens,
-                           KVCache.create(CFG, 2, 16, jnp.float32),
-                           mesh=mesh)
+    got, _ = jit_model(llama.prefill, CFG, mesh=mesh)(
+        qsharded, tokens, lens, KVCache.create(CFG, 2, 16, jnp.float32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
@@ -257,10 +246,11 @@ def test_init_params_quantized_streams_to_fused_int8():
     B, S = 2, 8
     cache = llama.KVCache.create(cfg, B, 32, dtype=params["embed"].dtype)
     toks = jnp.ones((B, S), jnp.int32)
-    logits, cache = llama.prefill(params, cfg, toks,
-                                  jnp.full((B,), S, jnp.int32), cache)
+    logits, cache = jit_model(llama.prefill, cfg)(
+        params, toks, jnp.full((B,), S, jnp.int32), cache)
     assert logits.shape == (B, S, cfg.vocab_size)
-    step, cache = llama.decode_step(params, cfg, toks[:, :1], cache)
+    step, cache = jit_model(llama.decode_step, cfg)(params, toks[:, :1],
+                                                    cache)
     assert step.shape == (B, 1, cfg.vocab_size)
     assert bool(jnp.isfinite(step).all())
 
@@ -413,14 +403,14 @@ def test_int4_forward_matches_dequantized_oracle():
     lens = jnp.asarray([12, 9], jnp.int32)
     cache_q = KVCache.create(CFG, 2, 32, jnp.float32)
     cache_d = KVCache.create(CFG, 2, 32, jnp.float32)
-    lq, cache_q = llama.prefill(qparams, CFG, tokens, lens, cache_q)
-    ld, cache_d = llama.prefill(dparams, CFG, tokens, lens, cache_d)
+    lq, cache_q = prefill(qparams, tokens, lens, cache_q)
+    ld, cache_d = prefill(dparams, tokens, lens, cache_d)
     np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                rtol=2e-4, atol=2e-4)
     nxt = jnp.argmax(lq[:, -1], -1).astype(jnp.int32)[:, None]
     for _ in range(3):
-        lq, cache_q = llama.decode_step(qparams, CFG, nxt, cache_q)
-        ld, cache_d = llama.decode_step(dparams, CFG, nxt, cache_d)
+        lq, cache_q = decode_step(qparams, nxt, cache_q)
+        ld, cache_d = decode_step(dparams, nxt, cache_d)
         np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                    rtol=2e-4, atol=2e-4)
         nxt = jnp.argmax(lq[:, 0], -1).astype(jnp.int32)[:, None]
@@ -437,10 +427,10 @@ def test_int4_close_to_full_precision():
         np.random.default_rng(13).integers(0, CFG.vocab_size, (1, 10)),
         jnp.int32)
     lens = jnp.asarray([10], jnp.int32)
-    lq, _ = llama.prefill(qparams, CFG, tokens, lens,
-                          KVCache.create(CFG, 1, 16, jnp.float32))
-    lf, _ = llama.prefill(PARAMS, CFG, tokens, lens,
-                          KVCache.create(CFG, 1, 16, jnp.float32))
+    lq, _ = prefill(qparams, tokens, lens,
+                    KVCache.create(CFG, 1, 16, jnp.float32))
+    lf, _ = prefill(PARAMS, tokens, lens,
+                    KVCache.create(CFG, 1, 16, jnp.float32))
     a = np.asarray(lq).reshape(-1)
     b = np.asarray(lf).reshape(-1)
     cos = float(a @ b / (np.linalg.norm(a) * np.linalg.norm(b) + 1e-9))
@@ -459,10 +449,11 @@ def test_moe_int4_matches_dequantized_oracle():
         np.random.default_rng(14).integers(0, mcfg.vocab_size, (2, 8)),
         jnp.int32)
     lens = jnp.asarray([8, 6], jnp.int32)
-    lq, _ = mixtral.prefill(qparams, mcfg, tokens, lens,
-                            KVCache.create(mcfg, 2, 16, jnp.float32))
-    ld, _ = mixtral.prefill(dparams, mcfg, tokens, lens,
-                            KVCache.create(mcfg, 2, 16, jnp.float32))
+    moe_prefill = jit_model(mixtral.prefill, mcfg)
+    lq, _ = moe_prefill(qparams, tokens, lens,
+                        KVCache.create(mcfg, 2, 16, jnp.float32))
+    ld, _ = moe_prefill(dparams, tokens, lens,
+                        KVCache.create(mcfg, 2, 16, jnp.float32))
     np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                rtol=2e-4, atol=2e-4)
 
@@ -479,31 +470,14 @@ def test_int4_params_serve_through_engine():
 
     tok = ByteTokenizer(vocab_size=CFG.vocab_size)
     qparams = quantize_params(PARAMS, mode="int4")
-    stop_ids = set(CFG.eos_token_ids) | {tok.eos_id}
-
-    def oracle(prompt, max_new):
-        ids = tok.encode(prompt, add_bos=True)
-        cache = KVCache.create(CFG, 1, 64, jnp.float32)
-        logits, cache = llama.prefill(qparams, CFG, jnp.asarray([ids]),
-                                      jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1])
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in stop_ids:
-                break
-            out.append(t)
-            lg, cache = llama.decode_step(qparams, CFG, jnp.asarray([[t]]),
-                                          cache)
-            last = np.asarray(lg[0, 0])
-        return tok.decode(out)
+    oracle = Solo(llama, CFG, tok, max_seq=64)
 
     eng = TPUEngine(qparams, CFG, tok, num_slots=2, max_seq=64)
     try:
         req = GenerateRequest(prompt="int4 serving",
                               options=GenerateOptions(max_tokens=8))
         got = "".join(eng.generate_stream(req, RequestStats()))
-        assert got == oracle("int4 serving", 8)
+        assert got == oracle(qparams, "int4 serving", 8)
     finally:
         eng.stop()
 
@@ -526,10 +500,11 @@ def test_init_params_quantized_streams_to_fused_int4():
     B, S = 2, 8
     cache = llama.KVCache.create(cfg, B, 32, dtype=params["embed"].dtype)
     toks = jnp.ones((B, S), jnp.int32)
-    logits, cache = llama.prefill(params, cfg, toks,
-                                  jnp.full((B,), S, jnp.int32), cache)
+    logits, cache = jit_model(llama.prefill, cfg)(
+        params, toks, jnp.full((B,), S, jnp.int32), cache)
     assert logits.shape == (B, S, cfg.vocab_size)
-    step, cache = llama.decode_step(params, cfg, toks[:, :1], cache)
+    step, cache = jit_model(llama.decode_step, cfg)(params, toks[:, :1],
+                                                    cache)
     assert bool(jnp.isfinite(step).all())
 
 

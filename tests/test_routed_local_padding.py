@@ -24,6 +24,8 @@ from p2p_llm_chat_tpu.models import moe_tiles, nemotron_h, pangu
 from p2p_llm_chat_tpu.models.configs import get_config
 from p2p_llm_chat_tpu.models.llama import KVCache, _layer_view
 
+from solo import jit_model
+
 FAMILIES = {"tiny-pangu": (pangu, "layers"),
             "tiny-nemotron-h": (nemotron_h, "moe")}
 T, REAL = 128, 64
@@ -187,14 +189,14 @@ def test_a_padded_prefill_is_the_unpadded_run_at_its_real_positions(served):
     padding and the dummy entry add nothing to."""
     cfg, model, params, tokens = served
     solo = KVCache.create(cfg, 1, REAL, dtype=jnp.float32)
-    want, _, stats_solo = model.prefill_counted(
-        params, cfg, tokens, jnp.asarray([REAL]), solo,
-        jnp.ones((1, REAL), bool))
+    prefill_counted = jit_model(model.prefill_counted, cfg)
+    want, _, stats_solo = prefill_counted(
+        params, tokens, jnp.asarray([REAL]), solo, jnp.ones((1, REAL), bool))
     padded = jnp.zeros((2, T), jnp.int32).at[0, :REAL].set(tokens[0])
     counted = mask() & jnp.asarray([True, False])[:, None]
     cache = KVCache.create(cfg, 2, T, dtype=jnp.float32)
-    got, _, stats = model.prefill_counted(
-        params, cfg, padded, jnp.asarray([REAL, 1]), cache, counted)
+    got, _, stats = prefill_counted(
+        params, padded, jnp.asarray([REAL, 1]), cache, counted)
     np.testing.assert_allclose(np.asarray(got[0, :REAL]),
                                np.asarray(want[0]), rtol=2e-3, atol=2e-3)
     stats, stats_solo = np.asarray(stats), np.asarray(stats_solo)

@@ -22,29 +22,22 @@ from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 from p2p_llm_chat_tpu.utils.draft import NGramDrafter
 
+from solo import Solo, jit_model
+
 pytestmark = pytest.mark.model
 
 CFG = get_config("tiny")
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
-STOP_IDS = set(CFG.eos_token_ids) | {TOK.eos_id}
 
 
-def greedy_oracle(prompt: str, max_new: int, max_seq: int = 128) -> str:
-    ids = TOK.encode(prompt, add_bos=True)
-    cache = KVCache.create(CFG, 1, max_seq, jnp.float32)
-    logits, cache = llama.prefill(PARAMS, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([len(ids)]), cache)
-    last = np.asarray(logits[0, len(ids) - 1])
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in STOP_IDS:
-            break
-        out.append(t)
-        lg, cache = llama.decode_step(PARAMS, CFG, jnp.asarray([[t]]), cache)
-        last = np.asarray(lg[0, 0])
-    return TOK.decode(out)
+# The sequential greedy loop on the model layer's dense cache
+# (tests/solo.py), by the cache's rows.
+SOLO = {n: Solo(llama, CFG, TOK, max_seq=n) for n in (64, 128, 256)}
+
+
+def greedy_oracle(prompt: str, max_new: int) -> str:
+    return SOLO[128](PARAMS, prompt, max_new)
 
 
 # -- drafting -----------------------------------------------------------------
@@ -77,8 +70,10 @@ def test_verify_step_logits_match_sequential_decode():
     lens = jnp.full((B,), P, jnp.int32)
 
     cache_a = KVCache.create(CFG, B, 32, jnp.float32)
-    logits, cache_a = llama.prefill(PARAMS, CFG, tokens, lens, cache_a)
+    logits, cache_a = jit_model(llama.prefill, CFG)(PARAMS, tokens, lens,
+                                                   cache_a)
     cache_b = jax.tree.map(lambda x: x, cache_a)     # deep copy
+    decode_step = jit_model(llama.decode_step, CFG)
 
     # Sequential: current token + K greedy steps.
     cur = jnp.argmax(logits[:, P - 1], -1).astype(jnp.int32)[:, None]
@@ -87,13 +82,14 @@ def test_verify_step_logits_match_sequential_decode():
     c = cache_a
     t = cur
     for _ in range(K + 1):
-        lg, c = llama.decode_step(PARAMS, CFG, t, c)
+        lg, c = decode_step(PARAMS, t, c)
         seq_logits.append(np.asarray(lg[:, 0]))
         t = jnp.argmax(lg[:, 0], -1).astype(jnp.int32)[:, None]
         toks.append(t)
     stream = jnp.concatenate(toks[: K + 1], axis=1)   # [B, K+1]
 
-    ver_logits, cache_v = llama.verify_step(PARAMS, CFG, stream, cache_b)
+    ver_logits, cache_v = jit_model(llama.verify_step, CFG)(PARAMS, stream,
+                                                            cache_b)
     for j in range(K + 1):
         np.testing.assert_allclose(np.asarray(ver_logits[:, j]),
                                    seq_logits[j], atol=2e-4, rtol=2e-4)
@@ -231,24 +227,7 @@ def test_spec_engine_moe_greedy_matches_oracle():
     mcfg = get_config("tiny-moe")
     mparams = mixtral.init_params(mcfg, jax.random.PRNGKey(2),
                                   dtype=jnp.float32)
-    stop_ids = set(mcfg.eos_token_ids) | {TOK.eos_id}
-
-    def moe_oracle(prompt: str, max_new: int) -> str:
-        ids = TOK.encode(prompt, add_bos=True)
-        cache = KVCache.create(mcfg, 1, 128, jnp.float32)
-        logits, cache = mixtral.prefill(mparams, mcfg, jnp.asarray([ids]),
-                                        jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1])
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in stop_ids:
-                break
-            out.append(t)
-            lg, cache = mixtral.decode_step(mparams, mcfg,
-                                            jnp.asarray([[t]]), cache)
-            last = np.asarray(lg[0, 0])
-        return TOK.decode(out)
+    solo = Solo(mixtral, mcfg, TOK)
 
     eng = TPUEngine(mparams, mcfg, TOK, num_slots=2, max_seq=128,
                     spec_k=4, page_size=16)
@@ -257,22 +236,15 @@ def test_spec_engine_moe_greedy_matches_oracle():
             req = GenerateRequest(prompt=prompt,
                                   options=GenerateOptions(max_tokens=16))
             got = "".join(eng.generate_stream(req, RequestStats()))
-            assert got == moe_oracle(prompt, 16), prompt
+            assert got == solo(mparams, prompt, 16), prompt
     finally:
         eng.stop()
 
 
-@pytest.mark.parametrize("impl", ["gather", "kernel"])
-def test_verify_step_paged_matches_dense(impl, monkeypatch):
-    """The paged verify forward must produce the dense verify_step's
-    logits for the same state — on the default gather path
-    (attend-before-write + one batched scatter) AND the non-gather
-    write-then-attend branch (per-layer pool writes + per-position
-    kernel calls), which no serving default exercises."""
-    import importlib
-    pa_mod = importlib.import_module(
-        "p2p_llm_chat_tpu.ops.paged_attention")
-    monkeypatch.setattr(pa_mod, "_DEFAULT_IMPL", impl)
+def test_verify_step_paged_matches_dense():
+    """The paged verify forward (attend-before-write + one batched
+    scatter) must produce the dense verify_step's logits for the same
+    state."""
     from p2p_llm_chat_tpu.ops.paged_kv import (PageAllocator, PagedKVCache,
                                                set_row_table, write_prefill)
     rng = np.random.default_rng(3)
@@ -281,7 +253,7 @@ def test_verify_step_paged_matches_dense(impl, monkeypatch):
     lens = jnp.full((B,), P, jnp.int32)
 
     dense = KVCache.create(CFG, B, 32, jnp.float32)
-    logits, dense = llama.prefill(PARAMS, CFG, tokens, lens, dense)
+    logits, dense = jit_model(llama.prefill, CFG)(PARAMS, tokens, lens, dense)
 
     alloc = PageAllocator(16, PS)
     paged = PagedKVCache.create(CFG, B, 16, PS, max_pages_per_row=4,
@@ -295,8 +267,9 @@ def test_verify_step_paged_matches_dense(impl, monkeypatch):
                           dense.v[:, :, :P], jnp.arange(B), lens)
 
     stream = jnp.asarray(rng.integers(0, CFG.vocab_size, (B, S)), jnp.int32)
-    ref, _ = llama.verify_step(PARAMS, CFG, stream, dense)
-    got, _ = llama.verify_step_paged(PARAMS, CFG, stream, paged, pages=2)
+    ref, _ = jit_model(llama.verify_step, CFG)(PARAMS, stream, dense)
+    got, _ = jit_model(llama.verify_step_paged, CFG, pages=2)(PARAMS, stream,
+                                                              paged)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-4, rtol=2e-4)
 
@@ -329,23 +302,6 @@ def test_all_serving_features_compose():
 
     qparams = quantize_params(PARAMS)
 
-    def oracle(prompt, max_new):
-        ids = TOK.encode(prompt, add_bos=True)
-        cache = KVCache.create(CFG, 1, 128, jnp.float32)
-        logits, cache = llama.prefill(qparams, CFG, jnp.asarray([ids]),
-                                      jnp.asarray([len(ids)]), cache)
-        last = np.asarray(logits[0, len(ids) - 1])
-        out = []
-        for _ in range(max_new):
-            t = int(last.argmax())
-            if t in STOP_IDS:
-                break
-            out.append(t)
-            lg, cache = llama.decode_step(qparams, CFG, jnp.asarray([[t]]),
-                                          cache)
-            last = np.asarray(lg[0, 0])
-        return TOK.decode(out)
-
     eng = TPUEngine(qparams, CFG, TOK, num_slots=2, max_seq=128,
                     page_size=16, spec_k=4)
     try:
@@ -353,7 +309,7 @@ def test_all_serving_features_compose():
         req = GenerateRequest(prompt=prompt,
                               options=GenerateOptions(max_tokens=12))
         got = "".join(eng.generate_stream(req, RequestStats()))
-        assert got == oracle(prompt, 12)
+        assert got == SOLO[128](qparams, prompt, 12)
     finally:
         eng.stop()
 
@@ -362,24 +318,13 @@ def _penalty_oracle(prompt: str, max_new: int, rp: float,
                     max_seq: int = 128) -> str:
     """Sequential greedy loop with the Ollama repeat penalty over the
     last-64-token window (prompt + generated), mirroring the engine."""
-    ids = TOK.encode(prompt, add_bos=True)
-    context = list(ids)
-    cache = KVCache.create(CFG, 1, max_seq, jnp.float32)
-    logits, cache = llama.prefill(PARAMS, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([len(ids)]), cache)
-    last = np.asarray(logits[0, len(ids) - 1])
     rng = np.random.default_rng(0)
-    out = []
-    for _ in range(max_new):
-        t = sampling.sample_np(last, rng, temperature=0.0,
-                               recent=context[-64:], repeat_penalty=rp)
-        if t in STOP_IDS:
-            break
-        out.append(t)
-        context.append(t)
-        lg, cache = llama.decode_step(PARAMS, CFG, jnp.asarray([[t]]), cache)
-        last = np.asarray(lg[0, 0])
-    return TOK.decode(out)
+
+    def pick(last, seen):
+        return sampling.sample_np(last, rng, temperature=0.0,
+                                  recent=seen[-64:], repeat_penalty=rp)
+
+    return SOLO[max_seq](PARAMS, prompt, max_new, pick)
 
 
 @pytest.mark.parametrize("spec_k", [
@@ -438,29 +383,15 @@ def test_quote_params_greedy_follows_printable_cycle():
     """models/synth.quote_params: greedy decode follows the printable
     successor cycles (the property that makes prompt-lookup drafts land
     and suggestion streams decode as text)."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-
-    from p2p_llm_chat_tpu.models import llama
-    from p2p_llm_chat_tpu.models.configs import get_config
-    from p2p_llm_chat_tpu.models.llama import KVCache
     from p2p_llm_chat_tpu.models.synth import quote_params, successor_map
 
-    cfg = get_config("tiny")
-    params = quote_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    succ = successor_map(cfg.vocab_size)
+    params = quote_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
+    succ = successor_map(CFG.vocab_size)
     ids = [1, ord("H"), ord("i")]          # BOS + printable prompt
-    cache = KVCache.create(cfg, 1, 64, dtype=jnp.float32)
-    logits, cache = llama.prefill(params, cfg, jnp.asarray([ids]),
-                                  jnp.asarray([len(ids)]), cache)
-    last = np.asarray(logits[0, len(ids) - 1])
+    out = SOLO[64].tokens(params, ids, 24)
+    assert len(out) == 24
     cur = ids[-1]
-    for _ in range(24):
-        t = int(last.argmax())
+    for t in out:
         assert t == int(succ[cur]), (cur, t, int(succ[cur]))
         assert 32 <= t < 127          # printable: streams as UTF-8 text
         cur = t
-        lg, cache = llama.decode_step(params, cfg, jnp.asarray([[t]]),
-                                      cache)
-        last = np.asarray(lg[0, 0])

@@ -24,7 +24,6 @@ successor cycle: the drafter genuinely predicts the target (acceptance
 scores ~0) — the free-form statistic the round exists to win.
 """
 
-import numpy as np
 import pytest
 
 import jax
@@ -32,7 +31,6 @@ import jax.numpy as jnp
 
 from p2p_llm_chat_tpu.models import llama
 from p2p_llm_chat_tpu.models.configs import get_config
-from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.models.synth import quote_params, successor_map
 from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
                                             RequestStats)
@@ -40,11 +38,12 @@ from p2p_llm_chat_tpu.serve.draft_model import ModelDrafter
 from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
+from solo import Solo
+
 pytestmark = pytest.mark.model
 
 CFG = get_config("tiny")
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
-STOP_IDS = set(CFG.eos_token_ids) | {TOK.eos_id}
 # Freeform pair: target + 1-layer drafter share the successor map.
 FREEFORM = quote_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32,
                         mode="freeform")
@@ -58,22 +57,9 @@ DRAFT_RAND = llama.init_params(DCFG, jax.random.PRNGKey(3),
 PROMPT = "Tell me something new about the harbor lights"
 
 
-def greedy_oracle(params, prompt: str, max_new: int,
-                  max_seq: int = 256) -> str:
-    ids = TOK.encode(prompt, add_bos=True)
-    cache = KVCache.create(CFG, 1, max_seq, jnp.float32)
-    logits, cache = llama.prefill(params, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([len(ids)]), cache)
-    last = np.asarray(logits[0, len(ids) - 1])
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in STOP_IDS:
-            break
-        out.append(t)
-        lg, cache = llama.decode_step(params, CFG, jnp.asarray([[t]]), cache)
-        last = np.asarray(lg[0, 0])
-    return TOK.decode(out)
+# The sequential greedy loop on the model layer's dense cache
+# (tests/solo.py).
+greedy_oracle = Solo(llama, CFG, TOK, max_seq=256)
 
 
 def run_engine(params, prompt: str, max_new: int, *, draft=None,

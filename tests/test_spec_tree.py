@@ -40,11 +40,12 @@ from p2p_llm_chat_tpu.serve.engine import TPUEngine
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 from p2p_llm_chat_tpu.utils.draft import DraftSource, NGramSource
 
+from solo import Solo
+
 pytestmark = pytest.mark.model
 
 CFG = get_config("tiny")
 TOK = ByteTokenizer(vocab_size=CFG.vocab_size)
-STOP_IDS = set(CFG.eos_token_ids) | {TOK.eos_id}
 FREEFORM = quote_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32,
                         mode="freeform")
 SUCC = successor_map(CFG.vocab_size, mode="freeform")
@@ -54,22 +55,9 @@ DRAFT_FF = quote_params(DCFG, jax.random.PRNGKey(1), dtype=jnp.float32,
 PROMPT = "Tell me something new about the harbor lights"
 
 
-def greedy_oracle(params, prompt: str, max_new: int,
-                  max_seq: int = 256) -> str:
-    ids = TOK.encode(prompt, add_bos=True)
-    cache = KVCache.create(CFG, 1, max_seq, jnp.float32)
-    logits, cache = llama.prefill(params, CFG, jnp.asarray([ids]),
-                                  jnp.asarray([len(ids)]), cache)
-    last = np.asarray(logits[0, len(ids) - 1])
-    out = []
-    for _ in range(max_new):
-        t = int(last.argmax())
-        if t in STOP_IDS:
-            break
-        out.append(t)
-        lg, cache = llama.decode_step(params, CFG, jnp.asarray([[t]]), cache)
-        last = np.asarray(lg[0, 0])
-    return TOK.decode(out)
+# The sequential greedy loop on the model layer's dense cache
+# (tests/solo.py).
+greedy_oracle = Solo(llama, CFG, TOK, max_seq=256)
 
 
 class CorruptMainSource(DraftSource):

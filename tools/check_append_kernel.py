@@ -1,11 +1,10 @@
 """TPU parity check: the Pallas attention kernels vs their XLA paths.
 
-Runs both Pallas implementations of
-ops/paged_attention.paged_attention_append — the round-4 gathered-window
-block kernel and the round-8 multi-chunk flash-append kernel — on the
-real chip over random pools (bf16 and int8) and checks closeness to
-the gather path; then the two write-then-attend decode kernels behind
-``PAGED_ATTN_IMPL=kernel|flash``, and the prefill flash kernel
+Runs the two implementations of
+ops/paged_attention.paged_attention_append by name — the multi-chunk
+flash-append kernel (``_paged_attention_flash_append``) against the XLA
+gather (``_append_gather``) — on the real chip over random pools (bf16
+and int8) and checks closeness; then the prefill flash kernel
 (models/layers.attend_gqa_causal0). CPU tests can't cover the Mosaic
 lowering; this is the hardware check. Shapes have llama3.1-8b's
 attention geometry (32 query / 8 kv heads x 128, page size 64, B=32).
@@ -19,7 +18,7 @@ measurement behind the dispatch boundary.
 
 from __future__ import annotations
 
-import contextlib
+import functools
 import os
 import sys
 import time
@@ -30,51 +29,29 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import importlib  # noqa: E402
-
 from p2p_llm_chat_tpu.models.configs import get_config  # noqa: E402
 from tools.kernel_verdicts import (SlowerThanXLA, require_tpu,  # noqa: E402
                                     run_cases)
 
-# The ops package __init__ rebinds `paged_attention` to the function;
-# importlib reaches the module.
-pa = importlib.import_module("p2p_llm_chat_tpu.ops.paged_attention")
+from p2p_llm_chat_tpu.ops import paged_attention as pa  # noqa: E402
 from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache,  # noqa: E402
                                            write_prefill_row)
 
 
-@contextlib.contextmanager
-def _gather_pinned():
-    """Pin ``paged_attention_append`` to the XLA gather path on BOTH
-    dispatch axes while a reference or a gather timing is traced:
-    _APPEND_IMPL picks the impl family, and the min-W toggle must be 0
-    or the default rule would route the "reference" itself to the flash
-    kernel at long windows — a vacuous self-comparison."""
-    saved = (pa._APPEND_IMPL, os.environ.get("PAGED_APPEND_FLASH_MIN_W"))
-    pa._APPEND_IMPL = "gather"
-    os.environ["PAGED_APPEND_FLASH_MIN_W"] = "0"
-    try:
-        yield
-    finally:
-        pa._APPEND_IMPL = saved[0]
-        if saved[1] is None:
-            os.environ.pop("PAGED_APPEND_FLASH_MIN_W", None)
-        else:
-            os.environ["PAGED_APPEND_FLASH_MIN_W"] = saved[1]
+def _pool_args(cache, lens, layer) -> tuple:
+    return (cache.k, cache.v, cache.k_scale, cache.v_scale,
+            cache.page_table, lens, layer)
 
 
-def _block_kernel(q, k_cur, v_cur, cache, lens, layer, *, pages,
-                  quantized):
-    return pa._paged_append_kernel_call(
-        q, k_cur, v_cur, cache.k, cache.v, cache.k_scale, cache.v_scale,
-        cache.page_table, lens, layer, pages=pages, quantized=quantized)
-
-
-def _flash_kernel(q, k_cur, v_cur, cache, lens, layer, *, pages,
-                  quantized):
+def _flash(q, k_cur, v_cur, cache, lens, layer, *, pages):
     return pa._paged_attention_flash_append(
-        q, k_cur, v_cur, cache.k, cache.v, cache.k_scale, cache.v_scale,
-        cache.page_table, lens, layer, pages=pages, quantized=quantized)
+        q, k_cur, v_cur, *_pool_args(cache, lens, layer), pages=pages,
+        quantized=cache.k_scale is not None)
+
+
+def _gather(q, k_cur, v_cur, cache, lens, layer, *, pages):
+    return pa._append_gather(q, k_cur, v_cur,
+                             *_pool_args(cache, lens, layer), pages=pages)
 
 
 # (query heads, key-value heads): llama3.1-8b's GQA (rep 4) and OLMoE's
@@ -91,17 +68,15 @@ def _cfg(heads: tuple, layers=2):
         num_layers=layers, num_heads=heads[0], num_kv_heads=heads[1])
 
 
-def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
-        label="block", seed=0, heads=GQA, lengths=None) -> None:
+def run(quantized: bool, B=32, pages=48, ps=64, *, label="flash", seed=1,
+        heads=GQA, lengths=None) -> None:
     """Shared harness: random bf16/int8 pool filled through the real
-    splice op, ``kernel`` vs the gather append path at first/last layer.
-
-    Defaults check the round-4 block kernel at a serving window; the
-    __main__ matrix also runs the round-8 multi-chunk flash kernel at
-    pages=48 (W=3072: 3 chunks of 1024 int8 tokens / 6 of 512 bf16 —
-    the cross-chunk scratch merge, slot parity through row boundaries,
-    and the clamped partial chunk all execute on real Mosaic, not just
-    in interpret mode)."""
+    splice op, the flash-append kernel vs the gather append path at
+    first/last layer, at a long (multi-chunk) window: pages=48 is W=3072,
+    3 chunks of 1024 int8 tokens / 6 of 512 bf16 — the cross-chunk
+    scratch merge, slot parity through row boundaries, and the clamped
+    partial chunk all execute on real Mosaic, not just in interpret
+    mode."""
     # Two layers are all the check reads (first and last), and what
     # keeps the bf16 pool at W=3072 x B=32 inside a 16 GB chip.
     cfg = _cfg(heads)
@@ -133,25 +108,17 @@ def run(quantized: bool, B=32, pages=3, ps=64, *, kernel=_block_kernel,
                         jnp.bfloat16)
     v_cur = jnp.asarray(rng.normal(size=k_cur.shape), jnp.bfloat16)
 
+    gather = jax.jit(_gather, static_argnames="pages")
     for layer in (0, cfg.num_layers - 1):
-        kern = kernel(q, k_cur, v_cur, cache, lens, jnp.asarray(layer),
-                      pages=pages, quantized=quantized)
-        with _gather_pinned():
-            ref = pa.paged_attention_append(q, k_cur, v_cur, cache, lens,
-                                            jnp.asarray(layer), pages=pages)
+        args = (q, k_cur, v_cur, cache, lens, jnp.asarray(layer))
+        kern = _flash(*args, pages=pages)
+        ref = gather(*args, pages=pages)
         kn, rn = np.asarray(kern, np.float32), np.asarray(ref, np.float32)
         err = np.max(np.abs(kn - rn))
         denom = np.max(np.abs(rn)) or 1.0
         print(f"{label} quantized={quantized} layer={layer}: max abs err "
               f"{err:.5f} (rel {err/denom:.5f})")
         assert err / denom < 2e-2, f"{label} kernel diverges from gather path"
-
-
-def run_flash(quantized: bool, B=32, pages=48, ps=64, heads=GQA) -> None:
-    """The multi-chunk flash-append kernel at a long (multi-chunk)
-    window — see run()'s docstring for what that exercises."""
-    run(quantized, B, pages, ps, kernel=_flash_kernel, label="flash",
-        seed=1, heads=heads)
 
 
 def run_flash_ragged(quantized: bool, B=32, pages=48, ps=64,
@@ -169,8 +136,8 @@ def run_flash_ragged(quantized: bool, B=32, pages=48, ps=64,
     edge = [0, 1, ct - 1, ct, ct + 1, W - 1, 0, 0, W - 1, 0, 2 * ct, 300]
     rng = np.random.default_rng(5)
     lengths = edge + [int(n) for n in rng.integers(0, W - 1, B - len(edge))]
-    run(quantized, B, pages, ps, kernel=_flash_kernel, label="flash ragged",
-        seed=5, heads=heads, lengths=lengths)
+    run(quantized, B, pages, ps, label="flash ragged", seed=5, heads=heads,
+        lengths=lengths)
 
 
 def _close(got, ref, what: str) -> None:
@@ -178,29 +145,6 @@ def _close(got, ref, what: str) -> None:
     rel = np.max(np.abs(gn - rn)) / (np.max(np.abs(rn)) or 1.0)
     print(f"{what}: rel {rel:.5f}")
     assert rel < 2e-2, f"{what} diverges from the XLA path"
-
-
-def run_decode_impl(impl: str, B=32, pages=3, ps=64, heads=GQA) -> None:
-    """``PAGED_ATTN_IMPL=kernel|flash`` (write-then-attend decode over a
-    bf16 pool) vs the gather impl."""
-    cfg = _cfg(heads)
-    key = jax.random.PRNGKey(2)
-    shape = (cfg.num_layers, B * pages + 1, ps, cfg.num_kv_heads,
-             cfg.head_dim)
-    k_pages = jax.random.normal(key, shape, jnp.bfloat16)
-    v_pages = jax.random.normal(jax.random.fold_in(key, 1), shape,
-                                jnp.bfloat16)
-    table = jnp.asarray(1 + np.arange(B * pages).reshape(B, pages),
-                        jnp.int32)
-    lens = jnp.asarray(np.random.default_rng(2).integers(
-        1, pages * ps, size=B), jnp.int32)
-    q = jax.random.normal(jax.random.fold_in(key, 2),
-                          (B, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
-    for layer in (0, cfg.num_layers - 1):
-        args = (q, k_pages, v_pages, table, lens, jnp.asarray(layer))
-        _close(pa.paged_attention(*args, pages=pages, impl=impl),
-               pa.paged_attention(*args, pages=pages, impl="gather"),
-               f"decode impl={impl} layer={layer}")
 
 
 def run_prefill_flash(B=1, S=2048, heads=GQA) -> None:
@@ -272,24 +216,16 @@ def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
         np.asarray(out).ravel()[:1]
         return (time.monotonic() - t) / steps / repeat * 1e3
 
-    def gather_path(*a):
-        return pa.paged_attention_append(*a, pages=pages)
-
-    def flash_path(*a):
-        return _flash_kernel(*a, pages=pages, quantized=quantized)
-
     hd = cfg.num_kv_heads * cfg.head_dim
-    rule = ("flash" if pa._flash_append_policy(
-        W, "auto", pa._flash_append_min_w(), hd) else "gather")
+    rule = "flash" if pa._flash_append_policy(W, hd) else "gather"
     pool = "int8" if quantized else "bf16"
     lost = []
     for live in (B, 2):
         lens = jnp.asarray(lengths[:live] + [0] * (B - live), jnp.int32)
         state = cache._replace(
             page_table=cache.page_table.at[live:].set(0), lengths=lens)
-        with _gather_pinned():
-            gather = timed(gather_path, state, lens)
-        flash = timed(flash_path, state, lens)
+        gather = timed(functools.partial(_gather, pages=pages), state, lens)
+        flash = timed(functools.partial(_flash, pages=pages), state, lens)
         tokens = sum(lengths[:live])
         print(f"append {pool} heads={heads} hd={hd} W={W} live={live}/"
               f"{B} ({tokens} cached tokens): gather {gather:.4f} ms, "
@@ -317,33 +253,21 @@ def main() -> int:
                                      (False, GQA))
             for W in (256, 512, 1024, 2048)))
         return 0
-    cases = (("block int8", lambda: run(quantized=True)),
-             ("block bf16", lambda: run(quantized=False)),
-             ("flash-append int8", lambda: run_flash(quantized=True)),
-             ("flash-append bf16", lambda: run_flash(quantized=False)),
+    cases = (("flash-append int8", lambda: run(quantized=True)),
+             ("flash-append bf16", lambda: run(quantized=False)),
              ("flash-append ragged int8",
               lambda: run_flash_ragged(quantized=True)),
              ("flash-append ragged bf16",
               lambda: run_flash_ragged(quantized=False)),
-             ("decode impl=kernel bf16", lambda: run_decode_impl("kernel")),
-             ("decode impl=flash bf16", lambda: run_decode_impl("flash")),
              ("prefill flash", run_prefill_flash),
              # OLMoE's geometry: rep 1, 16 heads. The int8 pool is what
              # the benchmark's stack serves from.
-             ("mha16 block int8",
-              lambda: run(quantized=True, heads=MHA16)),
-             ("mha16 block bf16",
-              lambda: run(quantized=False, heads=MHA16)),
              ("mha16 flash-append int8",
-              lambda: run_flash(quantized=True, heads=MHA16)),
+              lambda: run(quantized=True, heads=MHA16)),
              ("mha16 flash-append bf16",
-              lambda: run_flash(quantized=False, heads=MHA16)),
+              lambda: run(quantized=False, heads=MHA16)),
              ("mha16 flash-append ragged int8",
               lambda: run_flash_ragged(quantized=True, heads=MHA16)),
-             ("mha16 decode impl=kernel bf16",
-              lambda: run_decode_impl("kernel", heads=MHA16)),
-             ("mha16 decode impl=flash bf16",
-              lambda: run_decode_impl("flash", heads=MHA16)),
              ("mha16 prefill flash",
               lambda: run_prefill_flash(heads=MHA16)))
     # ``python tools/check_append_kernel.py mha16``: only the cases whose

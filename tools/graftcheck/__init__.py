@@ -12,7 +12,7 @@ invisible to generic linters:
   serving/P2P planes accessed outside their declared lock
   (``# guarded-by: <lock>``) or off their owning thread
   (``# owned-by: <entry>``).
-- **env-flag hygiene**: ``SERVE_*``/``PAGED_*`` reads that bypass
+- **env-flag hygiene**: ``SERVE_*``/``FAIL_*`` reads that bypass
   ``utils/env.py`` or are missing from the docs flag table.
 
 Run: ``python -m tools.graftcheck p2p_llm_chat_tpu/`` (see

@@ -52,7 +52,7 @@ class Finding:
 class Config:
     """Knobs shared by the analyzers (defaults match this repo)."""
 
-    env_prefixes: tuple[str, ...] = ("SERVE_", "PAGED_", "FAIL_", "LOADGEN_",
+    env_prefixes: tuple[str, ...] = ("SERVE_", "FAIL_", "LOADGEN_",
                                      "P2P_", "TRACE_", "DIR_")
     env_module: str = "utils/env.py"           # the one blessed reader
     docs_files: tuple[str, ...] = ("docs/serving.md",)

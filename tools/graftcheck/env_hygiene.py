@@ -1,6 +1,6 @@
 """Env-flag hygiene analyzer.
 
-Every ``SERVE_*``/``PAGED_*``/``FAIL_*``
+Every ``SERVE_*``/``FAIL_*``
 (config.env_prefixes) environment read must:
 
 - go through the typed helpers in ``utils/env.py`` (``env_or``,
