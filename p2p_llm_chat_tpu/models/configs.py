@@ -75,6 +75,11 @@ class ModelConfig:
     # heads, before RoPE (OLMoE). Leaves q_norm [L, q_dim], k_norm
     # [L, kv_dim].
     qk_norm_whole: bool = False
+    # RMSNorm over EACH head's ``head_dim`` numbers, one learned vector
+    # for q and one for k a layer, shared by the heads, between the split
+    # into heads and RoPE (LFM2; a hybrid pattern's ``*`` layers). Leaves
+    # q_norm, k_norm [L, head_dim].
+    qk_norm_head: bool = False
     # Latent attention (MLA; models/pangu.py). ``kv_lora_rank`` > 0
     # selects the family: queries through a low-rank pair (``q_lora_rank``),
     # one latent row a token (``kv_lora_rank`` normed numbers and
@@ -104,6 +109,9 @@ class ModelConfig:
     # each logit; ``routed_scaling_factor`` multiplies the kept weights.
     moe_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # What ``moe_renormalize`` adds to the kept weights' sum before it
+    # divides by it (LFM2 publishes 1e-6).
+    moe_renorm_eps: float = 1e-20
     # Hybrid stack (models/nemotron_h.py). ``hybrid_pattern`` selects the
     # family: one letter a layer, each layer ONE mixer behind a pre-norm
     # and a residual: ``M`` a Mamba-2 layer, ``E`` a routed feed-forward
@@ -147,8 +155,15 @@ class ModelConfig:
     # ring keeps its KV heads apart, ``cache_kv_heads`` x ``cache_k_dim``),
     # ``g`` a gated memory unit, ``x`` attention whose K and V
     # are the ``*`` layer's below it (it owns neither), ``-`` a dense
-    # gated MLP of ``intermediate_size``. A published layer of that
-    # family is two letters, its mixer and ``-``.
+    # gated MLP of ``dense_intermediate_size`` (0: ``intermediate_size``).
+    # A published layer of that family is two letters, its mixer and
+    # ``-``.
+    # ``c`` a gated short convolution (LFM2): ``[B | C | x] = u W_in``,
+    # ``out = (C * conv(B * x)) W_out`` with a causal depthwise
+    # convolution over ``conv_kernel`` positions, no bias, no activation;
+    # per row it keeps the last ``conv_kernel - 1`` values of ``B * x``
+    # (``hidden_size`` wide) in the state pool's ``conv`` rows and no
+    # recurrent state behind them.
     # ``norm_kind`` "layer": biased LayerNorm in place of RMSNorm.
     norm_kind: str = "rms"
     attn_bias: bool = False          # biases on the q/k/v and output proj.
@@ -192,6 +207,19 @@ class ModelConfig:
         return p.count("M") + p.count("1") + p.count("Y")
 
     @property
+    def short_conv_layers(self) -> int:
+        """Gated short convolutions (``c``): a convolution window a row
+        and nothing recurrent."""
+        return self.hybrid_pattern.count("c")
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers with rows in the state pool's ``conv``: the recurrent
+        ones' convolution, or the ``c`` layers' (a pattern has one kind
+        or the other, models/nemotron_h._build)."""
+        return self.ssm_layers + self.short_conv_layers
+
+    @property
     def window_layers(self) -> int:
         """Layers that keep a ring of ``sliding_window`` positions a row."""
         return self.hybrid_pattern.count("w")
@@ -199,9 +227,10 @@ class ModelConfig:
     @property
     def state_layers(self) -> int:
         """Layers whose per-row past is a row of the state pool
-        (ops/state_pool.py) and not pages: recurrent ones and window
-        rings. 0: the caches carry no ``state``."""
-        return self.ssm_layers + self.window_layers
+        (ops/state_pool.py) and not pages: recurrent ones, short
+        convolutions and window rings. 0: the caches carry no
+        ``state``."""
+        return self.conv_layers + self.window_layers
 
     @property
     def state_kinds(self) -> str:
@@ -209,6 +238,7 @@ class ModelConfig:
         return " and ".join(
             f"{what} ({n} {of})" for n, what, of in (
                 (self.ssm_layers, "recurrent state", "Mamba layers"),
+                (self.short_conv_layers, "convolution windows", "layers"),
                 (self.window_layers, "window rings", "layers")) if n)
 
     @property
@@ -243,8 +273,10 @@ class ModelConfig:
 
     @property
     def conv_dim(self) -> int:
-        """Channels the Mamba convolution runs over: x | B | C (Mamba-2),
-        x alone (Mamba-1)."""
+        """Channels the convolution runs over: x | B | C (Mamba-2), x
+        alone (Mamba-1), ``B * x`` (a ``c`` layer)."""
+        if self.short_conv_layers:
+            return self.hidden_size
         if self.mamba1_inner:
             return self.mamba1_inner
         return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state_size
@@ -261,23 +293,39 @@ class ModelConfig:
     # pair's two value heads together, a head of 64 is half a lane tile,
     # and a second-minor dimension of 10 or 20 heads is padded to 32 in
     # an int8 array); an int8 scale is then one a position.
+    # A hybrid pattern's plain GQA at a head of 64 (LFM2) caches its KV
+    # heads in PAIRS side by side (``kv_paired``: heads 2g and 2g + 1 are
+    # row g, 128 numbers, an int8 scale a pair a position): whole lanes
+    # for every write and page DMA, and the geometry the flash-append
+    # kernel takes, which reads a pair's row with its queries
+    # zero-extended onto their own half
+    # (ops/paged_attention.paged_attention_append_paired).
+    @property
+    def kv_paired(self) -> bool:
+        return (self.is_hybrid and not self.attn_diff
+                and self.head_dim == 64 and self.num_kv_heads % 2 == 0)
+
     @property
     def cache_kv_heads(self) -> int:
         if self.attn_diff or self.is_latent:
             return 1
-        return self.num_kv_heads
+        return self.num_kv_heads // 2 if self.kv_paired \
+            else self.num_kv_heads
 
     @property
     def cache_k_dim(self) -> int:
         if self.attn_diff:
             return self.kv_dim
+        if self.kv_paired:
+            return 2 * self.head_dim
         return self.kv_lora_rank if self.is_latent else self.head_dim
 
     @property
     def cache_v_dim(self) -> int:
         if self.is_latent:
             return -(-self.qk_rope_head_dim // 128) * 128
-        return self.cache_k_dim if self.attn_diff else self.head_dim
+        return self.cache_k_dim if self.attn_diff or self.kv_paired \
+            else self.head_dim
 
     @property
     def router_width(self) -> int:
@@ -534,6 +582,46 @@ _register(ModelConfig(
     hybrid_pattern=MELLUM_PERIOD * 2, sliding_window=8, num_experts=8,
     num_experts_per_tok=2, moe_renormalize=True,
     bos_token_id=1, eos_token_ids=(2,),
+))
+
+# LFM2-8B-A1B (LiquidAI/LFM2-8B-A1B config.json, model_type lfm2_moe),
+# whole: 24 published layers, each an operator and a feed-forward behind
+# RMSNorms. Eighteen operators are gated short convolutions (a window of
+# ``conv_L_cache - 1`` = 2 positions a row and no pages), six are GQA
+# (32 / 8 heads x 64, per-head QK-norm, rotated, theta 1e6) at layers 2,
+# 6, 10, 14, 18, 21 and own pages, KV heads in pairs (``kv_paired``).
+# The first two feed-forwards are dense SwiGLU of 7,168, the other 22
+# route over 32 SwiGLU experts of 1,792: sigmoid scores, the 4 largest
+# of score + bias kept, weighed by their unbiased scores over (their sum
+# + 1e-6). Tied head. 8.34 G parameters, about 1.5 G a token.
+LFM2_PATTERN = "c-c-*E" + "cEcEcE*E" * 4 + "cEcE*E" + "cEcE"
+
+_register(ModelConfig(
+    name="lfm2-8b-a1b", vocab_size=65536, hidden_size=2048,
+    intermediate_size=1792, dense_intermediate_size=7168, num_layers=48,
+    num_heads=32, num_kv_heads=8, head_dim=64, max_seq_len=128000,
+    rope_theta=1e6, rms_norm_eps=1e-5, tie_embeddings=True,
+    hybrid_pattern=LFM2_PATTERN, conv_kernel=3, qk_norm_head=True,
+    num_experts=32, num_experts_per_tok=4, moe_scoring="sigmoid",
+    moe_renormalize=True, moe_renorm_eps=1e-6, moe_selection_bias=True,
+    routed_scaling_factor=1.0, bos_token_id=1, eos_token_ids=(2,),
+))
+
+# The same kinds of step at test size, with the head (two dense layers),
+# three whole periods and the short tail the published pattern has (one
+# scan over the five, models/nemotron_h._segments): 15 published layers,
+# attention at 2, 5, 8, 11, 13; a
+# head of 64 (8 / 4 heads) so that the pool keeps two pairs of KV heads;
+# 8 experts, the 2 largest of score + bias.
+_register(ModelConfig(
+    name="tiny-lfm2", vocab_size=512, hidden_size=128,
+    intermediate_size=64, dense_intermediate_size=192, num_layers=30,
+    num_heads=8, num_kv_heads=4, head_dim=64, max_seq_len=256,
+    rope_theta=10000.0, rms_norm_eps=1e-5, tie_embeddings=True,
+    hybrid_pattern="c-c-*E" + "cEcE*E" * 3 + "cE*E" + "cE", conv_kernel=3,
+    qk_norm_head=True, num_experts=8, num_experts_per_tok=2,
+    moe_scoring="sigmoid", moe_renormalize=True, moe_renorm_eps=1e-6,
+    moe_selection_bias=True, bos_token_id=1, eos_token_ids=(2,),
 ))
 
 # Loadgen CPU profile: ``tiny`` dims with a real context window, so the

@@ -2,9 +2,12 @@
 block of NVIDIA-Nemotron-3-Super-120B-A12B (Mamba-2 / attention /
 LatentMoE), the SambaY stack of Phi-4-mini-flash-reasoning (Mamba-1 /
 window attention / one full attention layer whose pages the upper half
-reads / gated memory units, each followed by a dense gated MLP) and the
+reads / gated memory units, each followed by a dense gated MLP), the
 Mellum 2 stack (three window layers to one full layer, GQA rotated by
-two tables, each followed by a routed layer of thin experts).
+two tables, each followed by a routed layer of thin experts) and the
+LFM2 stack (gated short convolutions, three to one GQA layer at a head
+of 64, a dense MLP behind the first two and a biased-sigmoid routed
+layer behind the others).
 ``models.family_for`` picks this
 module for a configuration with a ``hybrid_pattern``; the functional
 surface is the other families' (init_params, prefill, prefill_chunk,
@@ -37,6 +40,24 @@ by the layer's letter in ``hybrid_pattern``:
   float32 over all experts, the ``num_experts_per_tok`` largest kept
   and divided by their sum (``moe_renormalize``); ``out = sum_e p_e
   (silu(x Wg_e) * (x Wu_e)) Wd_e``. No shared expert.
+- ``E`` with ``moe_selection_bias`` and no latent (LFM2): ``s =
+  sigmoid(x W_r)`` in float32 over all experts; the
+  ``num_experts_per_tok`` largest of ``s + router_bias`` chosen, weighed
+  by their unbiased ``s`` over ``(their sum + moe_renorm_eps)`` times
+  ``routed_scaling_factor``; SwiGLU experts on the hidden state, all
+  held, no shared expert.
+- ``c``, a gated short convolution (LFM2): ``[B | C | x] = u W_in`` (H
+  -> 3H, that order); ``z = B * x``; ``y_t = sum_j w_j z_{t-(K-1)+j}``
+  over the position and the ``conv_kernel - 1`` before it (``w_{K-1}``
+  on the current one, zeros before position 0, no bias, no activation);
+  ``out = (C * y) W_out``. Its only past is ``z`` of a row's last
+  ``conv_kernel - 1`` positions.
+- ``*`` with ``qk_norm_head`` (LFM2): an RMSNorm over each head's
+  numbers, one weight vector for q and one for k a layer, before the
+  rotation. At a head of 64 the carry and the pool keep the KV heads in
+  PAIRS (``ModelConfig.kv_paired``), and decode reads a pair's row with
+  its queries zero-extended onto their own half
+  (ops/paged_attention.paged_attention_append_paired).
 - ``E``, LatentMoE: ``s = sigmoid(x W_r)`` in float32 over ALL
   ``router_width`` experts; the top-k of ``s + router_bias`` chosen,
   weighed ``routed_scaling_factor x s / (sum of the chosen s + 1e-20)``;
@@ -44,7 +65,7 @@ by the layer's letter in ``hybrid_pattern``:
   in the latent; ``routed = (sum over chosen AND held e) W_fc2``;
   ``shared = relu(x U_s)^2 D_s``; ``out = routed + shared``.
 
-Both kinds of ``E`` go through models/pangu._routed_local, the one
+All three kinds of ``E`` go through models/pangu._routed_local, the one
 dispatch of this family and the latent-attention one: a prefill's pairs
 sorted by expert into tiles (models/moe_tiles.routed_tiles; for a held
 range of a wider router the pairs routed elsewhere take no row), a
@@ -72,12 +93,17 @@ positional encoding):
 - ``g``, gated memory unit: ``out = (silu(u W_in) * m) W_out`` with ``m``
   the publishing layer's output at the SAME position. No state.
 - ``-``, a dense gated MLP: ``[g | u] = x W_gu``; ``out = (silu(g) u)
-  W_mlp_down``. A published layer of this family is its mixer and ``-``.
+  W_mlp_down``, at ``dense_intermediate_size`` where a pattern has
+  routed layers of another width beside it (LFM2), else at
+  ``intermediate_size``. A published layer of this family is its mixer
+  and ``-``.
 
 **Kinds of per-row past.** The ``*`` layers' K and V are pages
 (ops/paged_kv.py; ``ModelConfig.cache_layers`` of them, each read by
 its own layer, and by the ``x`` layers above it where there are any);
-the ``w`` layers' are rings in the state pool; the ``M``
+the ``w`` layers' are rings in the state pool; a ``c`` layer's
+convolution window is a row of the pool's ``conv`` with no ``ssm`` row
+behind it; the ``M``
 layers' state and convolution window are rows of a
 :class:`~..ops.state_pool.StatePool` that rides in the cache objects'
 ``state`` leaf: per entry in a prefill's ``KVCache`` (zero for a fresh
@@ -94,16 +120,22 @@ layers are one ``lax.scan`` each (:func:`_plan`), so a program holds a
 few layer bodies and not one a layer. ``*`` and ``Y`` publish to the
 layers above them and stay outside those scans; where nothing reads
 what they publish and the pattern is a whole number of periods (Mellum),
-the periods are one scan around that walk (:func:`_rounds`).
+the periods are one scan around that walk (:func:`_rounds`); else the
+pattern is cut before every ``*``, and equal neighbours, or neighbours
+that differ only in how often a group of two letters repeats, are one
+scan (LFM2: behind a dense head six rounds of ``*``, three or two
+``Ec`` and an ``E``; :func:`_segments`).
 
 Single chip only; speculation and session parking would need the state
-(or a ring) rolled back or carried and are refused at boot
+(a ring, a convolution window) rolled back or carried and are refused at
+boot
 (serve/scheduler.py).
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional
 
 import jax
@@ -131,7 +163,8 @@ __all__ = ["STATS_WIDTH", "no_stats", "no_touched", "prefill_stats"]
 
 # letter -> its parameter tree; letters of one tree are indexed together.
 TREES = {"M": "mamba", "E": "moe", "*": "attn", "w": "attn",
-         "1": "mamba1", "Y": "mamba1", "x": "cross", "g": "gmu", "-": "mlp"}
+         "1": "mamba1", "Y": "mamba1", "x": "cross", "g": "gmu", "-": "mlp",
+         "c": "conv"}
 
 
 @functools.cache
@@ -188,6 +221,76 @@ def _rounds(pattern: str) -> tuple:
     return pattern, 1
 
 
+def _varied(a: str, b: str) -> Optional[tuple]:
+    """(head, group, tail) where two stretches that open with ``*`` are
+    both ``head + group * m + tail``, with a group of two letters and
+    different ``m`` >= 1; None where they are not."""
+    s, t = sorted((a, b), key=len)
+    d = len(t) - len(s)
+    if a[:1] + b[:1] != "**" or not d or d % 2:
+        return None
+    for h in range(1, len(s) - 1):
+        g = s[h: h + 2]
+        if t[:h + d] == s[:h] + g * (d // 2) and t[h + d:] == s[h:]:
+            m = 1
+            while s[h + 2 * m: h + 2 * m + 2] == g:
+                m += 1
+            return s[:h], g, s[h + 2 * m:]
+    return None
+
+
+def _copies(piece: str, parts: tuple) -> int:
+    """``m`` where ``piece`` is head + group * m + tail, else 0."""
+    head, group, tail = parts
+    m, rest = divmod(len(piece) - len(head) - len(tail), 2)
+    return m if not rest and m > 0 \
+        and piece == head + group * m + tail else 0
+
+
+@functools.cache
+def _segments(pattern: str) -> tuple:
+    """The pattern as stretches ``(letters, rounds)`` in order: ``rounds``
+    > 1 is one scan over that many copies of ``letters``, 1 is walked as
+    it stands. Whole periods (:func:`_rounds`) are one stretch. Else,
+    under :func:`_rounds`'s conditions, the pattern is cut before every
+    ``*`` and equal neighbours are one scan; neighbours that differ only
+    in how often a group of two letters repeats (:func:`_varied`) are one
+    scan too, ``((head, group, tail), (m of each round, ...))``, the group
+    a loop of as many turns as the round has copies. LFM2 behind its two
+    dense layers: ``(("*", "Ec", "E"), (3, 3, 3, 3, 2, 2))``, so a program
+    holds one attention body and two routed ones, not six and twenty-two
+    (compilation in a cold boot: 498 s with the tail unrolled, 389-411 s
+    with the tail a scan of its own, 358 s so, PERF.md section 6, PR 45).
+    Stretches with no such
+    neighbour are walked together, as they stand (Nemotron's cut: no two
+    of its ``*`` stretches are alike, and its programs are the text they
+    were)."""
+    period, rounds = _rounds(pattern)
+    if rounds > 1 or "*" not in pattern or set(pattern) & set("Yxg"):
+        return ((period, rounds),)
+    cut = [0] + [i for i, ch in enumerate(pattern) if ch == "*" and i]
+    runs: list = []
+    for piece, same in itertools.groupby(
+            pattern[i:j] for i, j in zip(cut, cut[1:] + [None])):
+        n = len(list(same))
+        last, many = runs[-1] if runs else ("", 0)
+        if isinstance(last, tuple) and _copies(piece, last):
+            runs[-1] = (last, many + (_copies(piece, last),) * n)
+        elif isinstance(last, str) and _varied(last, piece):
+            parts = _varied(last, piece)
+            runs[-1] = (parts, (_copies(last, parts),) * many
+                        + (_copies(piece, parts),) * n)
+        else:
+            runs.append((piece, n))
+    out: list = []
+    for letters, n in runs:
+        if n == 1 and out and out[-1][1] == 1:
+            out[-1] = (out[-1][0] + letters, 1)
+        else:
+            out.append((letters, n))
+    return tuple(out)
+
+
 # letter -> the layers it shares a per-row past with: a Mamba layer's row
 # in the state pool, a window layer's ring, a ``*`` layer's page layer.
 PASTS = {**{ch: ch for ch in TREES}, "Y": "1"}
@@ -220,6 +323,7 @@ def _dims(config: ModelConfig) -> dict:
     Lw = config.moe_latent_size
     F, Fs = config.intermediate_size, (config.shared_intermediate_size
                                        or config.intermediate_size)
+    Fd = config.dense_intermediate_size or F
     NE = config.num_experts
     d1, N1, R1 = (config.mamba1_inner, config.mamba1_state,
                   config.mamba1_dt_rank)
@@ -235,7 +339,8 @@ def _dims(config: ModelConfig) -> dict:
                    "w_dt": (R1, d1), "w_out": (d1, H)},
         "cross": {"wq": (H, config.q_dim), "wo": (config.q_dim, H)},
         "gmu": {"w_in": (H, d1), "w_out": (d1, H)},
-        "mlp": {"w_gu": (H, 2 * F), "w_mlp_down": (F, H)},
+        "mlp": {"w_gu": (H, 2 * Fd), "w_mlp_down": (Fd, H)},
+        "conv": {"w_in": (H, 3 * H), "w_out": (H, H)},
     }
 
 
@@ -366,6 +471,15 @@ def _small_leaves(config: ModelConfig, key: jax.Array, dtype) -> dict:
     for tree in ("gmu", "mlp"):
         if tree in n:
             small[tree] = norm(n[tree])
+    if "conv" in n:
+        small["conv"] = {
+            **norm(n["conv"]),
+            "conv_w": _normal(next(ks), (n["conv"], config.conv_kernel, H),
+                              0.5, dtype)}
+    if "attn" in n and config.qk_norm_head:
+        for name in ("q_norm", "k_norm"):
+            small["attn"][name] = _uniform(
+                next(ks), (n["attn"], config.head_dim), 0.5, 1.5, dtype)
     return small
 
 
@@ -380,11 +494,23 @@ def _build(config: ModelConfig, key: jax.Array, dtype, stack, head,
                      or config.num_shared_experts
                      or config.mlp_activation != "silu"
                      or config.router_width != config.num_experts)
-    if "moe" in n and not (latent_moe or plain_moe):
+    biased_moe = (config.moe_selection_bias
+                  and config.moe_scoring == "sigmoid"
+                  and config.mlp_activation == "silu"
+                  and not (config.moe_latent_size
+                           or config.num_shared_experts)
+                  and config.router_width == config.num_experts)
+    if "moe" in n and not (latent_moe or plain_moe or biased_moe):
         raise ValueError(f"{config.name}: the hybrid family's routed layer "
                          "is a LatentMoE (selection bias, relu2 experts in "
                          "a latent) or a plain one (SwiGLU experts on the "
-                         "hidden state, all held, no shared expert)")
+                         "hidden state, all held, no shared expert), its "
+                         "scores a softmax, or sigmoids with a selection "
+                         "bias")
+    if "conv" in n and {"mamba", "mamba1"} & set(n):
+        raise ValueError(f"{config.name}: short convolutions and Mamba "
+                         "layers would share the state pool's conv rows "
+                         "at two widths")
     if config.attn_diff and config.attn_rope:
         raise ValueError(f"{config.name}: differential attention is "
                          "served without rotary embedding")
@@ -406,7 +532,7 @@ def _build(config: ModelConfig, key: jax.Array, dtype, stack, head,
     params["lm_head"] = (tied(params["embed"]) if config.tie_embeddings
                          else head(k_head, (H, config.vocab_size)))
     for i, tree in enumerate(("mamba", "attn", "moe", "mamba1", "cross",
-                              "gmu", "mlp")):
+                              "gmu", "mlp", "conv")):
         if tree in n:
             params[tree] = {**stack(jax.random.fold_in(k_stack, i), n[tree],
                                     dims[tree]), **small[tree]}
@@ -553,7 +679,8 @@ def _mamba_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
 def _qkv(h, lp, config: ModelConfig, positions=None, window: bool = False):
     """q [B,S,Hq,D], k, v [B,S,Hkv,D] of a GQA layer. ``positions``
     ([B|1,S]): where ``attn_rope``, q and k come back rotated by the
-    layer kind's table (``window``: the plain one)."""
+    layer kind's table (``window``: the plain one), behind the per-head
+    RMSNorm where the model has one (``qk_norm_head``)."""
     B, S, _ = h.shape
     qkv = mm(rms_norm(h, lp["norm"], config.rms_norm_eps), lp["wqkv"])
     Q, KV = config.q_dim, config.kv_dim
@@ -562,6 +689,9 @@ def _qkv(h, lp, config: ModelConfig, positions=None, window: bool = False):
                                     config.head_dim)
     v = qkv[..., Q + KV:].reshape(B, S, config.num_kv_heads,
                                   config.head_dim)
+    if config.qk_norm_head:
+        q = rms_norm(q, lp["q_norm"], config.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], config.rms_norm_eps)
     if config.attn_rope:
         inv_freq, factor = rope_table(config, window)
         q = apply_rope(q, positions, inv_freq, factor)
@@ -588,20 +718,34 @@ def _attn_prefill(h, lp, config: ModelConfig, ck, cv, layer: int,
     zero = jnp.zeros((), jnp.int32)
     at = (jnp.asarray(layer, jnp.int32), zero,
           jnp.asarray(offset, jnp.int32), zero, zero)
+    # The carry has the pool's geometry: KV heads in pairs at a head of
+    # 64 (ModelConfig.kv_paired), a reshape either way.
+    paired = config.kv_paired
+    if paired:
+        k, v = _rows(k, config), _rows(v, config)
     ck = jax.lax.dynamic_update_slice(ck, k[None].astype(ck.dtype), at)
     cv = jax.lax.dynamic_update_slice(cv, v[None].astype(cv.dtype), at)
     T = min(ck.shape[2],
             -(-(offset + S) // FLASH_KV_CHUNK) * FLASH_KV_CHUNK)
-    attn = attend_gqa_auto(q, ck[layer][:, :T], cv[layer][:, :T],
-                           causal_mask(S, T, offset),
+    keys, vals = ck[layer][:, :T], cv[layer][:, :T]
+    if paired:
+        keys, vals = _heads(keys, config), _heads(vals, config)
+    attn = attend_gqa_auto(q, keys, vals, causal_mask(S, T, offset),
                            causal0_len=S if offset == 0 else None)
     return mm(attn.reshape(B, S, config.q_dim), lp["wo"]), ck, cv
 
 
 def _attn_decode(h, lp, config: ModelConfig, cache, layer: int, pages: int):
-    from ..ops.paged_attention import paged_attention_append
+    from ..ops.paged_attention import (paged_attention_append,
+                                       paged_attention_append_paired)
     B = h.shape[0]
     q, k, v = _qkv(h, lp, config, cache.lengths[:, None])
+    if config.kv_paired:
+        attn = paged_attention_append_paired(
+            q[:, 0], k[:, 0], v[:, 0], cache, cache.lengths, layer,
+            pages=pages)
+        return (mm(attn.reshape(B, 1, config.q_dim), lp["wo"]),
+                _rows(k[:, 0], config), _rows(v[:, 0], config))
     attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
                                   cache.lengths, layer, pages=pages)
     return mm(attn.reshape(B, 1, config.q_dim), lp["wo"]), k[:, 0], v[:, 0]
@@ -663,6 +807,38 @@ def _gqa_window_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
                       rv.astype(qg.dtype), preferred_element_type=f32)
     return mm(attn.astype(h.dtype).reshape(B, 1, config.q_dim),
               lp["wo"]), pool
+
+
+def _conv_in(u, lp, config: ModelConfig):
+    """(z, C): the convolution's gated input ``B * x`` and the gate on
+    its output, of ``[B | C | x] = u W_in``."""
+    H = config.hidden_size
+    bcx = mm(rms_norm(u, lp["norm"], config.rms_norm_eps), lp["w_in"])
+    return bcx[..., :H] * bcx[..., 2 * H:], bcx[..., H: 2 * H]
+
+
+def _conv_prefill(h, lp, config: ModelConfig, state: StatePool, layer,
+                  valid: jax.Array):
+    """h [B,S,H] through short-convolution layer ``layer`` behind the
+    carried window ([L_c, B, K-1, H]; zeros before position 0). No bias,
+    no activation: ``out = (C * conv(B * x)) W_out``. Returns (out,
+    state)."""
+    z, gate = _conv_in(h, lp, config)
+    win = jax.lax.dynamic_index_in_dim(state.conv, layer, 0, False)
+    y, win = state_pool.conv_scan(z, win, jnp.sum(valid, axis=1),
+                                  lp["conv_w"], None)
+    state = state._replace(conv=jax.lax.dynamic_update_index_in_dim(
+        state.conv, win.astype(state.conv.dtype), layer, 0))
+    return mm(gate * y.astype(h.dtype), lp["w_out"]), state
+
+
+def _conv_decode(h, lp, config: ModelConfig, pool: StatePool, layer,
+                 live: jax.Array):
+    """h [B,1,H]: one step over the pool's first B rows. Returns (out
+    [B,1,H], pool)."""
+    z, gate = _conv_in(h[:, 0], lp, config)
+    y, pool = state_pool.conv_update(pool, layer, live, z, lp["conv_w"])
+    return mm(gate * y.astype(h.dtype), lp["w_out"])[:, None], pool
 
 
 # -- the SambaY kinds ---------------------------------------------------------
@@ -855,7 +1031,7 @@ def _gmu(h, lp, config: ModelConfig, m):
 
 def _mlp(h, lp, config: ModelConfig):
     gu = mm(_norm(h, lp, config), lp["w_gu"])
-    F = config.intermediate_size
+    F = config.dense_intermediate_size or config.intermediate_size
     return mm(jax.nn.silu(gu[..., :F]) * gu[..., F:], lp["w_mlp_down"])
 
 
@@ -863,33 +1039,43 @@ def _relu2_mlp(x, w_up, w_down):
     return mm(jnp.square(jax.nn.relu(mm(x, w_up))), w_down)
 
 
-def _moe(h, lp, config: ModelConfig, counted, live):
-    """(out [B,S,H], stats int32 [4])."""
+def _moe(h, lp, config: ModelConfig, counted, live, chosen: bool = False):
+    """(out [B,S,H], stats int32 [4]) and, where ``chosen``, third the
+    experts the router kept (int32 [B*S,k])."""
     x = rms_norm(h, lp["norm"], config.rms_norm_eps)
     if not config.moe_latent_size:
-        return _routed_local(x, lp, config, counted, live)
+        return _routed_local(x, lp, config, counted, live, chosen=chosen)
     latent = mm(x, lp["w_fc1"])
-    routed, stats = _routed_local(x, lp, config, counted, live, latent)
+    routed, *rest = _routed_local(x, lp, config, counted, live, latent,
+                                  chosen)
     return (mm(routed, lp["w_fc2"])
-            + _relu2_mlp(x, lp["w_up_s"], lp["w_down_s"])), stats
+            + _relu2_mlp(x, lp["w_up_s"], lp["w_down_s"])), *rest
 
 
 # -- the stack ----------------------------------------------------------------
 
 def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
-               counted, live, carry):
+               counted, live, carry, chosen: bool = False):
     """Walk the pattern. ``ops[letter](h, lp, k, carry) -> (out, carry)``
     is the mode's (prefill's, decode's) mixer of that kind, ``lp`` the
     layer's view of its tree and ``k`` its index among the layers that
     share its per-row past: a Mamba layer's in the state pool, a window
     layer's among the rings, a ``*`` layer's among the page layers (a
-    tracer inside a scan; ``*`` is scanned only with its whole period,
-    :func:`_rounds`, ``Y`` never, and they get a Python int otherwise).
+    tracer inside a scan; ``*`` is scanned only with its whole stretch,
+    :func:`_segments`, ``Y`` never, and they get a Python int otherwise).
     ``E`` and ``-`` keep nothing and are run here. Returns (h, carry,
-    stats)."""
+    stats); with ``chosen`` the stats are a pair, the counts and the
+    experts every routed layer's router kept (int32 [``E`` layers, B, S,
+    k]: what a reference replays to tell a wrong layer from a near tie
+    decided the other way, benchmark/architectures/lfm2.py)."""
     def moe_step(h, lp, k, carry, stats):
-        out, st = _moe(h, lp, config, counted, live)
-        return h + out, carry, stats + st
+        out, st, *top_i = _moe(h, lp, config, counted, live, chosen)
+        if not chosen:
+            return h + out, carry, stats + st
+        (top_i,), (counts, kept) = top_i, stats
+        return h + out, carry, (
+            counts + st, jax.lax.dynamic_update_index_in_dim(
+                kept, top_i.reshape(kept.shape[1:]), k, 0))
 
     def mlp_step(h, lp, k, carry, stats):
         return h + _mlp(h, lp, config), carry, stats
@@ -907,18 +1093,29 @@ def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
         return steps[ch](h, _layer_view(params[TREES[ch]], idx), k, carry,
                          stats)
 
-    period, rounds = _rounds(config.hybrid_pattern)
+    def walk(state, r, segment, before, round_letters=None, more=None):
+        """One stretch of the pattern behind the layers ``before``:
+        ``segment`` as it stands (``r`` None), or its ``r``-th copy (a
+        tracer inside the scan over a stretch's periods). A round of
+        varied length (:func:`varied`) is walked in parts: a round's
+        fixed letters are ``round_letters``, and ``more(count)`` is the
+        layers its repeated group has put before this part."""
+        # Layers of a kind (a tree, a past) in some letters: a Python int.
+        def count(ch, group, letters):
+            return sum(group[c] == group[ch] for c in letters)
 
-    def walk(state, r):
-        """One period, the ``r``-th (a tracer inside the scan over
-        periods; None where the pattern is its own period)."""
         def place(x, ch, group):
+            if before:
+                x = x + count(ch, group, before)
             if r is None:
                 return x
-            return x + r * sum(group[c] == group[ch] for c in period)
+            x = x + r * count(ch, group, round_letters or segment)
+            if more is None:
+                return x
+            return x + more(lambda letters: count(ch, group, letters))
 
         h, carry, stats = state
-        for letters, n, at in _plan(period):
+        for letters, n, at in _plan(segment):
             if n == 1:
                 idx, _ = _index(at, letters, 0, TREES)
                 k, _ = _index(at, letters, 0, PASTS)
@@ -946,11 +1143,52 @@ def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
                     body, (h, carry, stats), jnp.arange(n, dtype=jnp.int32))
         return h, carry, stats
 
-    state = (h, carry, no_stats())
-    if rounds == 1:
-        return walk(state, None)
-    return jax.lax.scan(lambda state, r: (walk(state, r), None), state,
-                        jnp.arange(rounds, dtype=jnp.int32))[0]
+    def varied(state, parts, copies, before):
+        """One scan over rounds of ``head + group * m + tail`` with ``m``
+        = ``copies`` of each round: the group a loop of ``m`` turns."""
+        head, group, tail = parts
+        fixed = head + tail
+        earlier = jnp.asarray([sum(copies[:i]) for i in range(len(copies))],
+                              jnp.int32)
+        copies = jnp.asarray(copies, jnp.int32)
+
+        def one(state, r):
+            m, done = copies[r], earlier[r]
+            state = walk(state, r, head, before, fixed,
+                         lambda count: done * count(group))
+            state = jax.lax.fori_loop(
+                0, m, lambda j, state: walk(
+                    state, r, group, before + head, fixed,
+                    lambda count: (done + j) * count(group)), state)
+            if tail:
+                state = walk(state, r, tail, before + head, fixed,
+                             lambda count: (done + m) * count(group))
+            return state, None
+
+        return jax.lax.scan(one, state,
+                            jnp.arange(len(copies), dtype=jnp.int32))[0]
+
+    stats = no_stats()
+    if chosen:
+        stats = (stats, jnp.zeros(
+            (config.hybrid_pattern.count("E"), *h.shape[:2],
+             config.num_experts_per_tok), jnp.int32))
+    state, before = (h, carry, stats), ""
+    for letters, rounds in _segments(config.hybrid_pattern):
+        if rounds == 1:
+            state = walk(state, None, letters, before)
+        elif isinstance(rounds, tuple):
+            state = varied(state, letters, rounds, before)
+            head, group, tail = letters
+            before += "".join(head + group * m + tail for m in rounds)
+            continue
+        else:
+            state = jax.lax.scan(
+                lambda state, r, letters=letters, before=before: (
+                    walk(state, r, letters, before), None),
+                state, jnp.arange(rounds, dtype=jnp.int32))[0]
+        before += letters * rounds
+    return state
 
 
 def _logits(params, config, h, last_idx):
@@ -969,12 +1207,14 @@ def _refuse_mesh(mesh) -> None:
 
 def _forward(params: dict, config: ModelConfig, tokens: jax.Array,
              cache: KVCache, offset: int, valid: Optional[jax.Array],
-             last_idx: Optional[jax.Array], hidden: bool = False):
+             last_idx: Optional[jax.Array], hidden: bool = False,
+             chosen: bool = False):
     """Tokens [B,S] at positions offset..offset+S behind the carry
     ``cache``: K and V of the context in its slots below ``offset``, the
     recurrent state at position ``offset`` in ``cache.state``. ``valid``
     [B,S]: a row's real positions (a prefix of it; None = all). Returns
-    (logits | hidden states, cache, stats)."""
+    (logits | hidden states, cache, stats); ``chosen``:
+    :func:`_run_stack`'s."""
     B, S = tokens.shape
     if valid is None:
         valid = jnp.ones((B, S), bool)
@@ -1000,6 +1240,11 @@ def _forward(params: dict, config: ModelConfig, tokens: jax.Array,
                                      valid)
         return out, (ck, cv, state)
 
+    def conv(h, lp, layer, carry):
+        ck, cv, state = carry
+        out, state = _conv_prefill(h, lp, config, state, layer, valid)
+        return out, (ck, cv, state)
+
     def attn(h, lp, layer, carry):
         ck, cv, state = carry
         if not config.attn_diff:
@@ -1023,12 +1268,12 @@ def _forward(params: dict, config: ModelConfig, tokens: jax.Array,
                                   published["kv_layer"], offset), carry
 
     ops = {"M": mamba, "1": mamba1, "w": window, "*": attn, "x": cross,
-           "Y": functools.partial(mamba1, publish=True),
+           "c": conv, "Y": functools.partial(mamba1, publish=True),
            "g": lambda h, lp, _, carry: (_gmu(h, lp, config,
                                               published["m"]), carry)}
     h, (ck, cv, state), stats = _run_stack(
         params, config, h, ops, valid, None,
-        (cache.k, cache.v, cache.state))
+        (cache.k, cache.v, cache.state), chosen)
     cache = KVCache(ck, cv, cache.lengths, state)
     if hidden:
         return _norm(h, params, config, "final_norm"), cache, stats
@@ -1095,15 +1340,16 @@ def prefill_chunk_counted(params: dict, config: ModelConfig,
                           valid: Optional[jax.Array],
                           mesh: Optional[Mesh] = None,
                           rules: LogicalRules = DEFAULT_RULES,
-                          last_idx: Optional[jax.Array] = None, **_):
+                          last_idx: Optional[jax.Array] = None,
+                          chosen: bool = False, **_):
     """llama.prefill_chunk's contract (C tokens a row at positions
     offset..offset+C, resuming from ``cache``; lengths untouched): the
     recurrent layers resume from ``cache.state`` and hand the state at
     the chunk's end (at each row's last ``valid`` position) back in
-    it."""
+    it. ``chosen``: :func:`_run_stack`'s."""
     _refuse_mesh(mesh)
     return _forward(params, config, tokens, cache, int(offset), valid,
-                    last_idx)
+                    last_idx, chosen=chosen)
 
 
 def prefill_chunk(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -1139,19 +1385,20 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
                               mesh: Optional[Mesh] = None,
                               rules: LogicalRules = DEFAULT_RULES,
                               active: Optional[jax.Array] = None,
-                              *, pages: int):
+                              *, pages: int, chosen: bool = False):
     """One autoregressive step over both pools (llama.decode_step_paged's
     contract: tokens [B,1]; parked rows hold position, write their K and
     V to the garbage page and keep their state bit for bit). Returns
     (logits [B,1,V], cache with lengths advanced where active, counts
-    int32 [4] over the live rows)."""
+    int32 [4] over the live rows; with ``chosen``, :func:`_run_stack`'s
+    pair)."""
     from ..ops.paged_kv import write_decode_burst
     _refuse_mesh(mesh)
     B = tokens.shape[0]
     h = params["embed"][tokens]
     live = jnp.ones((B,), bool) if active is None else active
-    # The ``*`` layers inside the scan over periods (_rounds).
-    scanned = _rounds(config.hybrid_pattern)[1] > 1
+    # ``*`` layers inside a scan over periods (_segments).
+    scanned = any(n != 1 for _, n in _segments(config.hybrid_pattern))
 
     published: dict = {}
 
@@ -1171,6 +1418,11 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
         pool, kv = carry
         out, pool = _window_decode(h, lp, config, pool, layer, live,
                                    cache.lengths)
+        return out, (pool, kv)
+
+    def conv(h, lp, layer, carry):
+        pool, kv = carry
+        out, pool = _conv_decode(h, lp, config, pool, layer, live)
         return out, (pool, kv)
 
     def attn(h, lp, layer, carry):
@@ -1194,15 +1446,15 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
                          config), carry
 
     ops = {"M": mamba, "1": mamba1, "w": window, "*": attn, "x": cross,
-           "Y": functools.partial(mamba1, publish=True),
+           "c": conv, "Y": functools.partial(mamba1, publish=True),
            "g": lambda h, lp, _, carry: (_gmu(h, lp, config,
                                               published["m"]), carry)}
     kv = ()
     if scanned:
-        kv = (jnp.zeros((config.cache_layers, B, config.num_kv_heads,
-                         config.head_dim), h.dtype),) * 2
+        kv = (jnp.zeros((config.cache_layers, B, config.cache_kv_heads,
+                         config.cache_k_dim), h.dtype),) * 2
     h, (pool, kv), stats = _run_stack(params, config, h, ops, None, live,
-                                      (cache.state, kv))
+                                      (cache.state, kv), chosen)
     k_all, v_all = kv if scanned else (jnp.stack([k for k, _ in kv]),
                                        jnp.stack([v for _, v in kv]))
     cache = write_decode_burst(cache._replace(state=pool), k_all, v_all,

@@ -447,8 +447,8 @@ def route(xt: jax.Array, router: jax.Array, config: ModelConfig,
           bias: Optional[jax.Array] = None):
     """(top_w [T,k] float32, top_i [T,k]) over ALL ``router_width``
     experts, in float32: sigmoid (or softmax) scores, the k largest, the
-    kept weights divided by their sum (``moe_renormalize``) and
-    multiplied by ``routed_scaling_factor``. ``bias`` ([router_width],
+    kept weights divided by their sum + ``moe_renorm_eps``
+    (``moe_renormalize``) and multiplied by ``routed_scaling_factor``. ``bias`` ([router_width],
     ``moe_selection_bias``): added to the scores for the CHOICE alone;
     the kept weights are the unbiased scores of the chosen."""
     if bias is None:
@@ -469,7 +469,8 @@ def route(xt: jax.Array, router: jax.Array, config: ModelConfig,
                                  config.num_experts_per_tok)
         top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     if config.moe_renormalize:
-        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True)
+                         + config.moe_renorm_eps)
     return top_w * config.routed_scaling_factor, top_i
 
 
@@ -488,7 +489,7 @@ def _experts_ffn(lp: dict, config: ModelConfig):
 
 def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
                   counted: Optional[jax.Array], live: Optional[jax.Array],
-                  latent: Optional[jax.Array] = None):
+                  latent: Optional[jax.Array] = None, chosen: bool = False):
     """The held experts' part of the routed sum, and the counts. x
     [B,S,H]. ``live`` ([B] bool) given: a decode step, its active rows
     alone take slots in per-expert buckets that hold every row.
@@ -517,7 +518,9 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
     ``num_experts``) is sent nowhere and adds nothing. Dropless both
     ways.
 
-    Returns (out [B,S,H], stats int32 [STATS_WIDTH])."""
+    Returns (out [B,S,H], stats int32 [STATS_WIDTH]) and, where
+    ``chosen``, third the experts the router kept (int32 [B*S,k]), for a
+    caller that hands them on (models/nemotron_h.py's ``chosen``)."""
     B, S, H = x.shape
     NE, k = config.num_experts, config.num_experts_per_tok
     T = B * S
@@ -539,7 +542,7 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
                            jnp.sum(tiles) * tile_rows(
                                T * k, NE, config.router_width)])
         return (out.astype(x.dtype).reshape(B, S, H),
-                stats.astype(jnp.int32))
+                stats.astype(jnp.int32)) + ((top_i,) if chosen else ())
     takes = (top_i < NE) & jnp.broadcast_to(live[:, None, None],
                                             (B, S, k)).reshape(T, k)
     # one_hot of an id past NE is all zeros: an absent expert's queue
@@ -581,7 +584,8 @@ def _routed_local(x: jax.Array, lp: dict, config: ModelConfig,
                       * top_w[..., None], axis=1)
     stats = jnp.stack([jnp.sum(sent > 0), jnp.asarray(NE),
                        jnp.sum(live) * S * k, jnp.sum(takes)])
-    return (out.astype(x.dtype).reshape(B, S, H), stats.astype(jnp.int32))
+    return (out.astype(x.dtype).reshape(B, S, H),
+            stats.astype(jnp.int32)) + ((top_i,) if chosen else ())
 
 
 def no_stats() -> jax.Array:

@@ -14,6 +14,10 @@ layer's k/v with one batched scatter after its layer scan
 - :func:`gather_window` — one layer's window gathered once, for a layer
   whose pages other layers read too (models/nemotron_h.py's cross
   layers).
+- :func:`paged_attention_append_paired` — the decode tick at a head of
+  64 over a pool that keeps its KV heads in pairs (128 numbers a row):
+  the same two implementations under the same rule, the queries
+  zero-extended onto their own half of a pair's row.
 
 ``paged_attention_append`` has two implementations, the same f32 softmax
 over the same scores:
@@ -36,7 +40,10 @@ The rule that chooses (:func:`_flash_append_policy`, guarded by
 it and wherever the kernel cannot run (no TPU, a pool sharded over a
 mesh, a head_dim that does not fill 128 lanes, an int8 pool of fewer
 than 4 kv heads). A function of window and pool geometry alone, decided
-once per trace; its measurement is PERF.md section 6, PR 31, and
+once per trace (a paired pool's ``Hkv`` is its pairs and its
+``head_dim`` 128: four pairs are 512 numbers a token, the boundary
+1,024, and neither refusal meets them; PERF.md section 6, PR 45, has
+that geometry's table); its measurement is PERF.md section 6, PR 31, and
 ``python tools/check_append_kernel.py time`` measures it again by calling
 the two implementations by name. The block verify stays on the gather
 at every window (the kernel's state is seeded with ONE current token).
@@ -88,8 +95,17 @@ def _gqa_expander(Hq: int, Hkv: int, rep: int):
     return (hh == gg).astype(jnp.float32)
 
 
+def _scaled(scores, D: int, scale):
+    """Scores over the softmax's scale: ``1 / sqrt(D)``, or ``scale``
+    where the caller's head is not the pool's row (a pair of heads)."""
+    if scale is None:
+        return scores / jnp.sqrt(D).astype(jnp.float32)
+    return scores * jnp.float32(scale)
+
+
 def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
-                           *, pages: int, sharded: bool = False):
+                           *, pages: int, sharded: bool = False,
+                           scale=None):
     """Decode attention where this step's k/v is NOT yet in the pool:
     attend over the pool window (positions < ``lengths``) and merge the
     current token's own (k_cur, v_cur) contribution with one exact
@@ -110,7 +126,8 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     PagedKVCache (bf16 or int8 pools); lengths: positions already in
     the pool per row (NOT including the current token). Returns
     [B, Hq, D] in q.dtype. ``sharded``: the pool is sharded over a mesh
-    (TP serving), which the Pallas kernel cannot consume.
+    (TP serving), which the Pallas kernel cannot consume. ``scale``: the
+    softmax's, where it is not ``1 / sqrt(D)`` of the pool's row.
 
     Chooses between :func:`_append_gather` and
     :func:`_paged_attention_flash_append` from what it can observe — the
@@ -122,15 +139,57 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
     args = (q, k_cur, v_cur, cache.k, cache.v, cache.k_scale, cache.v_scale,
             cache.page_table, lengths, layer)
     blocked = flash_append_blocked(sharded, D, Hkv if quantized else 0)
+    scaled = {} if scale is None else {"scale": scale}
     if not blocked and _flash_append_policy(pages * cache.k.shape[2],
                                             Hkv * D):
         return _paged_attention_flash_append(*args, pages=pages,
-                                             quantized=quantized)
-    return _append_gather(*args, pages=pages)
+                                             quantized=quantized, **scaled)
+    return _append_gather(*args, pages=pages, **scaled)
+
+
+def pair_queries(q: jax.Array, rep: int) -> jax.Array:
+    """[B, Hq, D] -> [B, Hq, 2D]: each query zero-extended onto its KV
+    head's half of a PAIR's row (``ModelConfig.kv_paired``: KV heads 2g
+    and 2g + 1 side by side), so that its dot with the row is its dot
+    with its own head. ``rep`` query heads a KV head."""
+    B, Hq, D = q.shape
+    half = jnp.arange(Hq)[:, None] // rep % 2 == jnp.arange(2)[None, :]
+    return jnp.where(half[None, :, :, None], q[:, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(B, Hq, 2 * D)
+
+
+def unpair_outputs(o: jax.Array, rep: int) -> jax.Array:
+    """[B, Hq, 2D] -> [B, Hq, D]: of ``p . V`` over a pair's row, the
+    half that is the query's own KV head."""
+    B, Hq, D2 = o.shape
+    odd = (jnp.arange(Hq) // rep % 2 == 1)[None, :, None]
+    return jnp.where(odd, o[..., D2 // 2:], o[..., : D2 // 2])
+
+
+def paged_attention_append_paired(q, k_cur, v_cur, cache, lengths, layer,
+                                  *, pages: int):
+    """:func:`paged_attention_append` for a head of 64 over a pool that
+    keeps its KV heads in pairs (``[.., Hkv / 2, 128]``). q [B, Hq, 64];
+    k_cur, v_cur [B, Hkv, 64]. The pool's row is what both
+    implementations already take (whole 128-lane rows, and four of them
+    fill an int8 tile's sublanes): the queries go in zero-extended
+    (:func:`pair_queries`), the scores are each query's with its own
+    head, and of each output the own head's half is kept. Twice the MXU
+    work of a kernel written for the head, on a path bound by the pool's
+    bytes; measured against the gather path on a per-head pool in
+    PERF.md section 6, PR 45."""
+    B, Hq, D = q.shape
+    Hkv = k_cur.shape[1]
+    rep = Hq // Hkv
+    out = paged_attention_append(
+        pair_queries(q, rep), k_cur.reshape(B, Hkv // 2, 2 * D),
+        v_cur.reshape(B, Hkv // 2, 2 * D), cache, lengths, layer,
+        pages=pages, scale=D ** -0.5)
+    return unpair_outputs(out, rep)
 
 
 def _append_gather(q, k_cur, v_cur, k_pages, v_pages, k_scale, v_scale,
-                   page_table, lengths, layer, *, pages: int):
+                   page_table, lengths, layer, *, pages: int, scale=None):
     """:func:`paged_attention_append` in XLA: gather the window, score
     it, merge the current token's term. ``k_scale`` None = a bf16 pool."""
     B, Hq, D = q.shape
@@ -138,13 +197,12 @@ def _append_gather(q, k_cur, v_cur, k_pages, v_pages, k_scale, v_scale,
     rep = Hq // Hkv
     scores, v, sv = _gather_window_scores(
         q[:, None], k_pages, v_pages, k_scale, v_scale, page_table,
-        lengths, layer, pages=pages)
+        lengths, layer, pages=pages, scale=scale)
 
     # Current token's own score: q . k_cur per kv head.
     qg = q.reshape(B, 1, Hkv, rep, D)
-    s_cur = jnp.einsum("bgrd,bgd->bgr", qg[:, 0].astype(jnp.float32),
-                       k_cur.astype(jnp.float32)) / jnp.sqrt(D).astype(
-                           jnp.float32)                      # [B,G,rep]
+    s_cur = _scaled(jnp.einsum("bgrd,bgd->bgr", qg[:, 0].astype(jnp.float32),
+                               k_cur.astype(jnp.float32)), D, scale)
     s_cur = s_cur[..., None, None]                           # [B,G,rep,1,1]
 
     m_w = jnp.max(scores, axis=-1, keepdims=True)            # [B,G,rep,1,1]
@@ -165,7 +223,8 @@ def _append_gather(q, k_cur, v_cur, k_pages, v_pages, k_scale, v_scale,
 
 
 def _gather_window_scores(q4, k_pages, v_pages, k_scale, v_scale,
-                          page_table, lengths, layer, *, pages: int):
+                          page_table, lengths, layer, *, pages: int,
+                          scale=None):
     """Shared preamble of the gather append and the block verify: gather
     one layer's window, compute masked pre-softmax scores (per-position
     k scales folded in when the pool is int8), and return
@@ -188,7 +247,7 @@ def _gather_window_scores(q4, k_pages, v_pages, k_scale, v_scale,
     qg = q4.reshape(B, S, Hkv, rep, D)
     scores = jnp.einsum("bsgrd,btgd->bgrst", qg, k.astype(q4.dtype),
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(D).astype(jnp.float32)
+    scores = _scaled(scores, D, scale)
     sv = None
     if k_scale is not None:
         # Scales are stored head-major, lane-padded [L, N, Hkv, ps_pad]
@@ -628,12 +687,12 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
     return body
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("pages", "quantized", "interpret"))
+@functools.partial(jax.jit, static_argnames=("pages", "quantized",
+                                             "interpret", "scale"))
 def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
                                   k_scale, v_scale, page_table, lengths,
                                   layer, *, pages: int, quantized: bool,
-                                  interpret: bool = False):
+                                  interpret: bool = False, scale=None):
     """Multi-chunk flash-append dispatch: grid ``(B, num_chunks)``, one
     bounded chunk of manually-DMA'd pages (and scale rows) per program,
     online softmax carried in VMEM scratch across the chunk axis and
@@ -645,7 +704,8 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
     B, Hq, D = q.shape
     L, N, page_size, Hkv, _ = k_pages.shape
     rep = Hq // Hkv
-    scale = 1.0 / (D ** 0.5)
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
     pt = page_table[:, :pages].astype(jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     chunk_pages = flash_append_chunk_pages(

@@ -11,6 +11,12 @@ dtype:
     ssm:  [L_m, rows, heads, head_dim, state_size]   float32
     conv: [L_m, rows, conv_kernel - 1, conv_dim]
 
+A gated short convolution (``c`` of a hybrid pattern, LFM2) keeps the
+convolution's window and nothing else: ``conv`` [L_c, rows, conv_kernel -
+1, hidden_size] holds the last values of its gated input ``B * x``, and
+``ssm`` has no layer ([0, rows]). :func:`conv_update` is its decode step;
+its convolution has no bias and no activation behind it.
+
 The same :class:`StatePool` serves in two places. In the scheduler's
 pool (``PagedKVCache.state``) it is indexed by SLOT and has one row
 more than there are slots: the last is a garbage row, as page 0 is the
@@ -99,10 +105,10 @@ class StatePool(NamedTuple):
     @classmethod
     def create(cls, config: ModelConfig, rows: int, dtype,
                quantized: bool = False) -> "StatePool":
-        L = config.ssm_layers
         pool = cls(
-            ssm=jnp.zeros((L, rows) + config.ssm_state_shape, jnp.float32),
-            conv=jnp.zeros((L, rows, config.conv_kernel - 1,
+            ssm=jnp.zeros((config.ssm_layers, rows) + config.ssm_state_shape,
+                          jnp.float32),
+            conv=jnp.zeros((config.conv_layers, rows, config.conv_kernel - 1,
                             config.conv_dim), dtype))
         Lw, W = config.window_layers, config.sliding_window
         if not Lw:
@@ -168,29 +174,32 @@ def ssm_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
 
 
 def conv_step(window: jax.Array, xbc: jax.Array, w: jax.Array,
-              b: jax.Array) -> tuple:
+              b: Optional[jax.Array]) -> tuple:
     """One position of the causal depthwise convolution. window
     [B,K-1,C] (oldest first); xbc [B,C]; w [K,C] (w[K-1] weighs the
-    current input); b [C]. Returns (out [B,C] float32, new window)."""
+    current input); b [C], or None for a convolution without a bias.
+    Returns (out [B,C] float32, new window)."""
     f32 = jnp.float32
     full = jnp.concatenate([window, xbc[:, None].astype(window.dtype)],
                            axis=1)                            # [B,K,C]
-    out = jnp.sum(full.astype(f32) * w.astype(f32)[None], axis=1) \
-        + b.astype(f32)
+    out = jnp.sum(full.astype(f32) * w.astype(f32)[None], axis=1)
+    if b is not None:
+        out = out + b.astype(f32)
     return out, full[:, 1:]
 
 
 def conv_scan(xbc: jax.Array, window: jax.Array, lengths: jax.Array,
-              w: jax.Array, b: jax.Array) -> tuple:
+              w: jax.Array, b: Optional[jax.Array]) -> tuple:
     """The convolution over S positions behind ``window``. xbc [B,S,C];
     window [B,K-1,C]; lengths [B]: a row's first ``lengths`` positions
-    are real. Returns (out [B,S,C] float32, the window after each row's
-    last REAL position: the carried one where it has none)."""
+    are real; b as :func:`conv_step`'s. Returns (out [B,S,C] float32, the
+    window after each row's last REAL position: the carried one where it
+    has none)."""
     f32 = jnp.float32
     K = w.shape[0]
     S = xbc.shape[1]
     ext = jnp.concatenate([window, xbc.astype(window.dtype)], axis=1)
-    out = b.astype(f32)[None, None]
+    out = 0.0 if b is None else b.astype(f32)[None, None]
     for j in range(K):
         out = out + ext[:, j: j + S].astype(f32) * w[j].astype(f32)
     at = lengths.astype(jnp.int32)[:, None] + jnp.arange(K - 1)[None, :]
@@ -310,6 +319,24 @@ def decode_update(pool: StatePool, layer: jax.Array, live: jax.Array,
             pool.ssm, S_new[None], (layer, zero) + tail),
         conv=jax.lax.dynamic_update_slice(
             pool.conv, win_new[None], (layer, zero, zero, zero)))
+
+
+def conv_update(pool: StatePool, layer: jax.Array, live: jax.Array,
+                z: jax.Array, conv_w: jax.Array) -> tuple:
+    """One decode step of short-convolution layer ``layer`` for the
+    pool's first B rows, in place: :func:`decode_update` without a
+    recurrence behind the convolution. ``z`` [B,C]: the convolution's new
+    input. Rows not ``live`` keep their window bit for bit. Returns (out
+    [B,C] float32, pool)."""
+    B = z.shape[0]
+    zero = jnp.zeros((), jnp.int32)
+    at = (jnp.asarray(layer, jnp.int32), zero, zero, zero)
+    win = jax.lax.dynamic_slice(pool.conv, at,
+                                (1, B) + pool.conv.shape[2:])[0]
+    out, win_new = conv_step(win, z, conv_w, None)
+    win_new = jnp.where(live[:, None, None], win_new, win)
+    return out, pool._replace(conv=jax.lax.dynamic_update_slice(
+        pool.conv, win_new[None], at))
 
 
 # -- window rings -------------------------------------------------------------

@@ -644,8 +644,9 @@ class BatchScheduler:
                         f"served under {what}")
         if config.is_hybrid:
             # Paths that assume "a row's past is its pages" refuse a
-            # model with recurrent state or window rings here, by name:
-            # each would have to carry the state or roll it back.
+            # model with recurrent state, convolution windows or window
+            # rings here, by name (``state_kinds``): each would have to
+            # carry them or roll them back.
             for on, what in (
                     (mesh is not None, "a mesh (the state pool is not "
                      "laid out over one)"),
@@ -815,13 +816,14 @@ class BatchScheduler:
             config.hybrid_pattern.count("*")
             + config.hybrid_pattern.count("x")
             if "x" in config.hybrid_pattern else 0)
-        # Page layers beside rings, each read by its own layer alone (a
-        # model whose window layers keep no pages): live rows x context
+        # Page layers that are fewer than the model's layers, each read
+        # by its own layer alone (the others keep rings, convolution
+        # windows or recurrent state and no pages): live rows x context
         # x bytes a token x those layers.
         self._n_page_kv_bytes = 0        # owned-by: _loop
         self._page_kv_layers = (
             config.cache_layers
-            if config.window_layers and not self._shared_kv_readers else 0)
+            if config.is_hybrid and not self._shared_kv_readers else 0)
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._clean_s = 0.0
@@ -3672,10 +3674,11 @@ class BatchScheduler:
             # request.
             out["serve_moe_prefill_layers_total"] = \
                 self._n_moe_prefill_layers
-        if self.config.ssm_layers:
-            # Recurrent state beside the pages (ops/state_pool.py): the
-            # pool's bytes, the slots holding a live row's state, and
-            # what the decode dispatches moved of it.
+        if self.config.conv_layers:
+            # Recurrent state, or convolution windows alone, beside the
+            # pages (ops/state_pool.py): the pool's bytes, the slots
+            # holding a live row's state, and what the decode dispatches
+            # moved of it.
             out["serve_state_pool_bytes"] = self._state_pool_bytes
             out["serve_state_rows_in_use"] = sum(
                 s is not None for s in self._slots)
@@ -3801,8 +3804,7 @@ class BatchScheduler:
             off["flash-append"] = "a latent pool: mla-decode reads it"
         else:
             off["flash-append"] = flash_append_blocked(
-                sharded, self.config.head_dim,
-                self.config.num_kv_heads if self.kv_quant else 0)
+                sharded, *self._flash_pool_row(self.config, self.kv_quant))
         log.info("kernels on %s: %s; XLA instead of: %s; flash-append "
                  "min_w %d; pallas interpret %s", platform(),
                  ", ".join(k for k, why in off.items() if not why) or "none",
@@ -3841,6 +3843,14 @@ class BatchScheduler:
                      "x".join(map(str, st.conv.shape[2:])),
                      st.conv.dtype.name, st.row_bytes / 1e6,
                      (st.nbytes - st.ring_nbytes) / 1e9)
+        if st is not None and st.conv.shape[0] and not st.ssm.shape[0]:
+            log.info("convolution windows: %d layers x %d rows (%d slots "
+                     "and a garbage row) x %s %s, no recurrent state; "
+                     "%.3f MB a row, %.3f MB",
+                     st.conv.shape[0], st.rows, self.num_slots,
+                     "x".join(map(str, st.conv.shape[2:])),
+                     st.conv.dtype.name, st.row_bytes / 1e6,
+                     st.conv.nbytes / 1e6)
         if st is not None and st.win_k is not None:
             log.info("window rings: %d layers x %d rows x %d positions "
                      "x %dx%d %s%s; %d bytes a position a layer, %.3f GB",
@@ -3850,6 +3860,18 @@ class BatchScheduler:
                      ", a float32 scale a position a head for each"
                      if st.win_ks is not None else "",
                      st.ring_position_bytes, st.ring_nbytes / 1e9)
+
+    @staticmethod
+    def _flash_pool_row(config, kv_quant: bool) -> tuple:
+        """(lanes of the pool's row, rows of an int8 pool | 0): what
+        ops/paged_attention.flash_append_blocked asks of a pool. The row
+        is a head, or a PAIR of heads at a head of 64
+        (``ModelConfig.kv_paired``: four pairs x 128 take the kernel
+        where eight heads x 64 could not)."""
+        if config.kv_paired:
+            return (config.cache_k_dim,
+                    config.cache_kv_heads if kv_quant else 0)
+        return config.head_dim, config.num_kv_heads if kv_quant else 0
 
     @staticmethod
     def _flash_min_w(config, mesh, kv_quant: bool = False) -> int:
@@ -3868,8 +3890,8 @@ class BatchScheduler:
             # (ops/mla_attention.py); this policy is the per-head pools'.
             return 0
         return effective_flash_min_w(
-            config.kv_dim, mesh is not None, config.head_dim,
-            config.num_kv_heads if kv_quant else 0)
+            config.kv_dim, mesh is not None,
+            *BatchScheduler._flash_pool_row(config, kv_quant))
 
     def _try_reserve(self, slot: _Slot) -> bool:
         """Claim the slot's page budget (prompt + generation

@@ -13,7 +13,14 @@ PASS|FAIL`` line; the exit code is non-zero when any FAILs. The
 flash-append kernel also runs at ragged lengths with free rows (most of
 its grid skipped). ``time`` prints, by pool, width and window, gather
 against flash-append at a full and at a part-full batch: the
-measurement behind the dispatch boundary.
+measurement behind the dispatch boundary. ``time-hd64`` prints what a
+page layer of 8 KV heads x 64 (LFM2's) costs a decode step on each
+candidate: the gather path on a per-head ``[8, 64]`` pool, the gather
+path on the paired ``[4, 128]`` pool, and flash-append on the paired
+pool with zero-extended queries
+(ops/paged_attention.paged_attention_append_paired), at 32 rows of 1, 4,
+8 and 13 K and at 8 rows of 8 K, each beside the bytes it had to read
+over the chip's 819 GB/s.
 """
 
 from __future__ import annotations
@@ -240,8 +247,106 @@ def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
                             + "; ".join(lost))
 
 
+def time_hd64(rows: int, context: int, live: int, ps=64, repeat=8,
+              steps=5) -> None:
+    """Milliseconds a layer-step of decode attention at a head of 64 (32
+    query / 8 KV heads), ``live`` of ``rows`` rows at ``context`` cached
+    tokens each (the others free), int8 pools, the window the power of
+    two over the context: the three candidates of PERF.md section 6,
+    PR 45."""
+    W = 1 << (context + 1).bit_length()
+    if W // 2 > context + 1:
+        W //= 2
+    pages = W // ps
+    key = jax.random.PRNGKey(context)
+    Hq, Hkv, D = 32, 8, 64
+    base = get_config("llama3.1-8b").with_(num_layers=2, num_heads=Hq)
+    geoms = {"per-head": base.with_(num_kv_heads=Hkv, head_dim=D),
+             "paired": base.with_(num_kv_heads=Hkv // 2, head_dim=2 * D)}
+    lens = jnp.asarray([context] * live + [0] * (rows - live), jnp.int32)
+    k = jax.random.normal(key, (2, W, Hkv, D), jnp.bfloat16)
+    v = jax.random.normal(jax.random.fold_in(key, 1), k.shape, jnp.bfloat16)
+    pools = {}
+    for name, cfg in geoms.items():
+        cache = PagedKVCache.create(cfg, rows, live * pages + 1, ps,
+                                    max_pages_per_row=pages,
+                                    dtype=jnp.bfloat16, quantized=True)
+        shape = (2, W, cfg.num_kv_heads, cfg.head_dim)
+        for b in range(live):
+            table = jnp.asarray(1 + b * pages + np.arange(pages), jnp.int32)
+            cache = write_prefill_row(cache, k.reshape(shape),
+                                      v.reshape(shape), jnp.asarray(b),
+                                      jnp.asarray(context), table)
+        pools[name] = cache._replace(lengths=lens)
+    q = jax.random.normal(jax.random.fold_in(key, 2), (rows, Hq, D),
+                          jnp.bfloat16)
+    kc = jax.random.normal(jax.random.fold_in(key, 3), (rows, Hkv, D),
+                           jnp.bfloat16)
+    rep = Hq // Hkv
+
+    def paired_args(q, kc):
+        return (pa.pair_queries(q, rep), kc.reshape(rows, Hkv // 2, 2 * D),
+                kc.reshape(rows, Hkv // 2, 2 * D))
+
+    def gather_per_head(q, kc, cache, layer):
+        return _gather(q, kc, kc, cache, lens, layer, pages=pages)
+
+    def gather_paired(q, kc, cache, layer):
+        return pa.unpair_outputs(pa._append_gather(
+            *paired_args(q, kc), *_pool_args(cache, lens, layer),
+            pages=pages, scale=D ** -0.5), rep)
+
+    def flash_paired(q, kc, cache, layer):
+        return pa.unpair_outputs(pa._paged_attention_flash_append(
+            *paired_args(q, kc), *_pool_args(cache, lens, layer),
+            pages=pages, quantized=True, scale=D ** -0.5), rep)
+
+    def timed(one, cache):
+        @jax.jit
+        def run(q, kc, cache):
+            def body(i, acc):
+                return acc + one(q, kc, cache, i % 2)
+            return jax.lax.fori_loop(0, repeat, body, jnp.zeros_like(q))
+        out = run(q, kc, cache)
+        np.asarray(out).ravel()[:1]
+        t = time.monotonic()
+        for _ in range(steps):
+            out = run(q, kc, cache)
+        np.asarray(out).ravel()[:1]
+        return (time.monotonic() - t) / steps / repeat * 1e3, out
+
+    read = live * context * 2 * (Hkv * D + 4 * (Hkv // 2))
+    floor = read / 819e9 * 1e3
+    got = {}
+    for name, one, pool in (("gather per-head", gather_per_head, "per-head"),
+                            ("gather paired", gather_paired, "paired"),
+                            ("flash paired", flash_paired, "paired")):
+        try:
+            got[name] = timed(one, pools[pool])
+        except Exception as e:  # noqa: BLE001 — Mosaic's refusal is the news
+            print(f"hd64 rows={live}/{rows} ctx={context} W={W} {name}: "
+                  f"FAILED {type(e).__name__}: {str(e)[:400]}", flush=True)
+    ref = got.get("gather per-head")
+    for name, (ms, out) in got.items():
+        err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                    - ref[1].astype(jnp.float32)))) \
+            if ref else float("nan")
+        print(f"hd64 rows={live}/{rows} ctx={context} W={W} {name}: "
+              f"{ms:.4f} ms a layer-step, {read / 1e6:.1f} MB to read = "
+              f"{floor:.4f} ms at 819 GB/s ({100 * floor / ms:.1f}% of "
+              f"its roofline), max |diff| to gather per-head {err:.3f} "
+              f"over {repeat} summed steps", flush=True)
+
+
 def main() -> int:
     require_tpu()
+    if len(sys.argv) > 1 and sys.argv[1] == "time-hd64":
+        run_cases(tuple(
+            (f"time-hd64 rows={live} ctx={ctx}",
+             lambda c=ctx, n=live: time_hd64(32, c, n))
+            for live, ctx in ((32, 1024), (32, 4096), (32, 8192),
+                              (32, 13312), (8, 8192))))
+        return 0
     # ``python tools/check_append_kernel.py time``: the timing behind the
     # flash-append boundary, not the verdicts.
     if len(sys.argv) > 1 and sys.argv[1] == "time":

@@ -54,7 +54,8 @@ def pool_shapes(cfg: dict) -> dict:
     if c.ssm_layers:
         state = ",".join(map(str, c.ssm_state_shape))
         out[f"f32[{c.ssm_layers},{slots + 1},{state}]"] = "state"
-        out[f"bf16[{c.ssm_layers},{slots + 1},{c.conv_kernel - 1},"
+    if c.conv_layers:       # a Mamba layer's, or a short convolution's
+        out[f"bf16[{c.conv_layers},{slots + 1},{c.conv_kernel - 1},"
             f"{c.conv_dim}]"] = "window"
     if c.window_layers:
         lead = f"{c.window_layers},{slots + 1}"

@@ -5,8 +5,8 @@ paged int8 decode step with its pool write, a fused decode, the
 admission splice; for a routed Mixtral-family or latent-attention model
 also the ``_counted`` prefills, which are what an admission runs, and a
 verify step, which is also what a session wake runs; for the hybrid
-family's three test sizes a prefill chunk and a decode step, and for
-the two that route the ``_counted`` chunk). A PR that
+family's four test sizes a prefill chunk and a decode step, and for
+the three that route the ``_counted`` chunk). A PR that
 must not move another family's programs runs this on its parent and on itself
 (``PYTHONPATH=<checkout> python tools/hash_programs.py``) and pins the
 parent's digests in tests/test_program_hashes.py.
@@ -18,7 +18,7 @@ import hashlib
 import json
 
 CONFIGS = ("tiny", "tiny-moe", "tiny-olmoe", "tiny-pangu",
-           "tiny-nemotron-h", "tiny-phi4flash", "tiny-mellum2")
+           "tiny-nemotron-h", "tiny-phi4flash", "tiny-mellum2", "tiny-lfm2")
 # Of the hybrid family (models/nemotron_h.py) only these labels.
 HYBRID_LABELS = ("prefill_chunk", "decode_step_paged")
 
