@@ -84,29 +84,35 @@ _STRIPE_BUDGET_BYTES = int(_os.environ.get("QMM_STRIPE_BUDGET",
 _X_VMEM_BUDGET_BYTES = 6 * 1024 * 1024
 
 # Per-hidden-size output-tile autotune table for the 1D whole-stripe
-# grids, SHARED by w8a16 and w4a16 (both route block choice through
-# _pick_1d_bo, so identical logical shapes pick identical grids in both
-# precisions). Key = logical contraction dim, value = bo cap. Why it
-# exists: the stripe machinery was tuned at hidden=2048 (bench-1b),
-# where bo=1024 keeps >= 6 programs in flight per matmul; at hidden=1024
-# (draft-400m) the same bo leaves a 2048-col projection only TWO grid
-# programs — too shallow for Mosaic to overlap the next stripe's DMA
-# with the current dot, recorded as the stacked kernel losing ~5% to
-# forced XLA (ROADMAP round-8 MoE note). Capping bo at 256 restores
-# >= 8 programs and the double-buffer overlap; tests/test_quant.py pins
-# the dispatch decision, tools/check_quant_kernel.py measures it on
-# chip. Caps only apply when they divide O (else the next smaller
-# candidate divisor wins via the normal search).
+# grids of ONE matrix. What reads it: _pick_1d_bo, so the dense w8a16
+# kernels, the dense w4a16 kernels and the int4 expert kernel
+# (pick_int4_bo), identical logical shapes picking identical grids in
+# both precisions. What does not, since PR 47: the w8a16 expert-stripe
+# kernel (pick_expert_bo). Every entry is a DEPTH cap: a one-matrix grid
+# is O / bo programs deep and needs several in flight to overlap a
+# stripe's DMA with the dot before it, while the expert grid is
+# (NE, O / bo), 8 to 128 times deeper before a stripe is cut at all, and
+# measured fastest with the widest stripe that fits (0.306 ms at the 256
+# columns of the 1024 cap against 0.204 at the whole 2048 at OLMoE's
+# down projection, 32 rows; PERF.md section 6, PRs 26 and 47).
 #
-# MoE expert contractions (round-18): 2816 is bench-moe's w_down stripe
-# — uncapped it picks bo=1024 and leaves the O=1024 projection ONE grid
-# program (no DMA/compute overlap at all, the hidden=1024 failure mode
-# taken to its limit); 128 restores 8 programs. 11520 is mixtral-large's
-# w_down: the 4 MiB stripe budget already shrinks it to bo=256, pinned
-# here so the decision survives budget retunes (grid depth 16 at
-# O=4096). Both derive from the same grid-depth arithmetic the
-# hidden=1024 probe measured; tools/check_quant_kernel.py carries the
-# expert-shape matrix for the on-chip confirmation.
+# Key = logical contraction dim, value = bo cap. Why it exists: the
+# stripe machinery was tuned at hidden=2048 (bench-1b), where bo=1024
+# keeps >= 6 programs in flight per matmul; at hidden=1024 (draft-400m)
+# the same bo leaves a 2048-col projection only TWO grid programs — too
+# shallow for Mosaic to overlap the next stripe's DMA with the current
+# dot, recorded as the stacked kernel losing ~5% to forced XLA (ROADMAP
+# round-8 MoE note). Capping bo at 256 restores >= 8 programs and the
+# double-buffer overlap; tests/test_qmm_tile_table_dispatch.py pins the
+# dispatch decision, tools/check_quant_kernel.py measures it on chip.
+# Caps only apply when they divide O (else the next smaller candidate
+# divisor wins via the normal search).
+#
+# 2816 and 11520 (round 18) are bench-moe's and mixtral-large's w_down
+# contractions, entered for the expert kernels when those still searched
+# here: 128 restores 8 programs of an O=1024 projection, 256 pins what
+# the 4 MiB stripe budget gives at O=4096. The int4 expert kernel still
+# reads them.
 _TILE_TABLE = {1024: 256, 2816: 128, 11520: 256}
 
 
@@ -379,16 +385,95 @@ def int4_stripe_seg(K: int, ng: int) -> int | None:
     return G // 2 if G % 256 == 0 else None
 
 
+# The expert-stripe kernel's OWN block rule (pick_expert_bo). Its grid is
+# (NE, O / bo): the depth a pipeline needs comes from the experts, 8 to
+# 128 of them, so the widest stripe that fits is the best one (a program
+# costs about 0.25 us whatever it moves: tools/check_quant_kernel.py
+# sweep-cells, PERF.md section 6 PR 47).
+#
+# What "fits" means is ONE sum against Mosaic's scoped VMEM limit, of
+# what Mosaic itself allocates for a program of this kernel; every term
+# was read off its refusals ("scoped allocation 17.53M, limit 16.00M"
+# for 128 rows x 14336 -> 4096 at bo 256). Held against the compiler's
+# own figure at 25 shapes compiled for a described v5e under a lowered
+# limit and at the 14 widths the chip's sweep saw refused (PR 47): never
+# under it, 0.15 to 0.8 MiB over it at the widths the cells' shapes
+# get, up to 4 MiB over it where many rows meet a narrow stripe (the
+# body then keeps no copy of x):
+#   2 x [H, bo] int8           the stripe in its two pipeline buffers
+#   2 x [Cp, H] + 1 x [Cp, H]  x in its two buffers, and the copy the
+#                              body keeps while the MXU reads it
+#   2 x [Cp, bo]               the output block in its two buffers
+#   [Cp, 256] float32          the product, which Mosaic holds a column
+#                              tile at a time, never whole
+#   0.5 MiB                    scale blocks and the relayout of a bucket
+#                              of 16 or 32 bf16 rows
+# The stripe's bf16 conversion is NOT a term: Mosaic converts on the way
+# into the MXU and keeps none of it.
+_EXPERT_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+_EXPERT_VMEM_SLACK_BYTES = 512 * 1024
+# No stripe above 4 MiB: two of them are half the limit, and the sweep
+# found nothing past it (Mixtral's 4096 x 1024 stripes stand at 91-92%
+# of the roofline; PERF.md section 6, PRs 27 and 47).
+_EXPERT_STRIPE_BYTES = 4 * 1024 * 1024
+# An expert of more than 3 MiB is cut into at least two stripes. A decode
+# step leaves experts empty, and the stripe of a touched expert behind
+# an empty one is fetched in the open: the empty program that starts the
+# fetch has no dot to hide it behind, so every such turn costs one
+# stripe's DMA, a whole expert's where the stripe is the expert. OLMoE's
+# gate|up (4 MiB an expert, about six in ten touched by a decode step)
+# read 0.324 ms a layer-step at one stripe and 0.283 at two with 38 of
+# 64 touched, level with all 64; Nemotron's 2.6 MiB experts 0.458 at
+# one and 0.444 at three with a quarter of them empty, and one stripe
+# is 8% the faster at 128 rows (PERF.md section 6, PR 47).
+_EXPERT_WHOLE_BYTES = 3 * 1024 * 1024
+
+
+def expert_vmem_bytes(rows: int, H: int, bo: int, x_itemsize: int) -> int:
+    """What one program of the w8a16 expert-stripe kernel holds in VMEM
+    at a bucket of ``rows`` rows and a stripe ``[H, bo]``, by the
+    account above."""
+    cp = rows + ((-rows) % 8)
+    return (2 * H * bo + 3 * cp * H * x_itemsize + 2 * cp * bo * x_itemsize
+            + cp * min(bo, 256) * 4 + _EXPERT_VMEM_SLACK_BYTES)
+
+
+def expert_widths(O: int) -> list[int]:
+    """The stripe widths the expert grid may take for ``O`` columns:
+    every multiple of 128 that divides it, widest first."""
+    return [bo for bo in range(O - O % 128, 0, -128) if O % bo == 0]
+
+
+def expert_bo_fits(rows: int, H: int, O: int, bo: int,
+                   x_itemsize: int) -> bool:
+    """:func:`pick_expert_bo`'s rule for one width ``bo`` of ``O``
+    columns: the stripe limit, no whole expert above
+    ``_EXPERT_WHOLE_BYTES``, and the VMEM account."""
+    return (H * bo <= _EXPERT_STRIPE_BYTES
+            and (bo < O or H * O <= _EXPERT_WHOLE_BYTES)
+            and expert_vmem_bytes(rows, H, bo, x_itemsize)
+            <= _EXPERT_VMEM_LIMIT_BYTES)
+
+
 def pick_expert_bo(rows: int, H: int, O: int,
                    x_itemsize: int) -> int | None:
     """Output-block width for the w8a16 expert-stripe kernel, or None ->
-    models/quant.q_einsum keeps the XLA dequant path. The same budget /
-    tile-table search as the dense 1D grids, applied to ONE expert's
-    [C, H] bucket and [H, bo] stripe (there is no 2D fallback for the
-    expert grid — uncovered shapes are prefill-class and XLA's batched
-    einsum is the right tool there anyway)."""
-    rp = rows + ((-rows) % 8)
-    return _pick_1d_bo(rp, H, O, x_itemsize)
+    models/quant.q_einsum keeps the XLA dequant path (there is no 2D
+    fallback for the expert grid: uncovered shapes are prefill-class
+    and XLA's batched einsum is the right tool there anyway).
+
+    The widest multiple of 128 that divides ``O`` and fits
+    (:func:`expert_bo_fits`: a stripe of 4 MiB at the most, an expert
+    above 3 MiB in two stripes at the least, one VMEM account). A search
+    of its own, sharing nothing with :func:`_pick_1d_bo`: the dense grid is ``O / bo`` programs deep and
+    wants depth from narrow blocks (``_TILE_TABLE``'s caps), the expert
+    grid has its depth from ``NE`` and wants few, wide stripes. So
+    neither the four powers of two of ``_BLOCK_CANDIDATES`` nor
+    ``_TILE_TABLE`` nor the dense budgets are read here. The int4 expert
+    kernel stays on :func:`pick_int4_bo`, the dense search: no cell runs
+    int4 experts and nothing here was measured for them."""
+    return next((bo for bo in expert_widths(O)
+                 if expert_bo_fits(rows, H, O, bo, x_itemsize)), None)
 
 
 def pick_int4_bo(rows: int, H: int, O: int, ng: int,
@@ -576,11 +661,12 @@ def _expert_weight_map(NE: int, stripes: int):
     return index
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("bo", "interpret"))
 def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
                                  layer: jax.Array,
                                  count: jax.Array | None = None,
                                  source: jax.Array | None = None, *,
+                                 bo: int | None = None,
                                  interpret: bool = False) -> jax.Array:
     """Batched per-expert ``x[e] @ dequant(q[layer, e], s[layer, e])``
     reading the 4-D expert pool directly — the MoE twin of
@@ -606,10 +692,15 @@ def quant_matmul_experts_stacked(x: jax.Array, q: jax.Array, s: jax.Array,
     (models/moe_tiles.routed_tiles): ``x`` is then [tiles, rows a tile,
     H] and the grid walks tiles. A tile whose count is 0 must name the
     expert of the last filled tile before it.
+
+    ``bo`` (None = :func:`pick_expert_bo`'s): the stripe width, for the
+    sweep that measures the rule and the tests that hold every width to
+    the same bits; the caller answers for VMEM.
     """
     NE, C, H = x.shape
     O = q.shape[-1]
-    bo = pick_expert_bo(C, H, O, x.dtype.itemsize)
+    if bo is None:
+        bo = pick_expert_bo(C, H, O, x.dtype.itemsize)
     if bo is None:
         raise ValueError(
             f"expert w8a16 kernel does not cover C={C} H={H} O={O}; use "

@@ -61,6 +61,49 @@ def test_expert_stacked_int8_matches_dequant_einsum(L, NE, C, H, F):
                                    err_msg=f"layer {layer}")
 
 
+@pytest.mark.parametrize("tiles", [False, True], ids=["experts", "tiles"])
+@pytest.mark.parametrize("bo", [2688, 896, 384])
+def test_expert_stripe_width_leaves_every_column_as_it_was(bo, tiles):
+    """Nemotron's LatentMoE up-projection (1024 -> 2688 = 128 x 21) at
+    the stripes the widest-divisor rule can give, none a power of two:
+    a column's contraction over H, its float32 sum and its scale are the
+    same whatever stripe holds it, so the output equals the 128-column
+    walk's BIT FOR BIT, with empty experts skipped by ``count`` and with
+    ``source`` naming the expert of each tile of a sorted dispatch. x is
+    drawn in eighths, so every partial sum is exact in float32 and the
+    equality does not lean on how the CPU's dot blocks its contraction
+    under the interpreter."""
+    L, NE, C, H, O = 2, 4, 16, 1024, 2688
+    rng = np.random.default_rng(46)
+    q, s = _int8_pool(rng, L, NE, H, O)
+    if tiles:
+        # Seven tiles over four experts: a run of two, an expert with no
+        # tile (2), an empty tile naming the expert before it, as
+        # moe_tiles.routed_tiles lays them out.
+        source = jnp.asarray([0, 0, 1, 1, 1, 3, 3], jnp.int32)
+        count = jnp.asarray([16, 5, 16, 16, 0, 9, 0], jnp.int32)
+    else:
+        source = None
+        count = jnp.asarray([16, 0, 3, 0], jnp.int32)
+    n = count.shape[0]
+    x = np.round(rng.standard_normal((n, C, H)) * 8).clip(-32, 32) / 8
+    x = jnp.asarray(x.astype(np.float32))
+    x = x * (jnp.arange(C)[None, :, None] < count[:, None, None])
+    for layer in range(L):
+        got = qmm.quant_matmul_experts_stacked(
+            x, q, s, layer, count, source, bo=bo, interpret=True)
+        narrow = qmm.quant_matmul_experts_stacked(
+            x, q, s, layer, count, source, bo=128, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(narrow))
+        held = jnp.arange(NE) if source is None else source
+        ref = jnp.einsum("ech,ehf->ecf", x,
+                         q[layer][held].astype(x.dtype)) * s[layer][held]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-4 * H / 256, rtol=1e-4,
+                                   err_msg=f"layer {layer}")
+    assert qmm.pick_expert_bo(C, H, O, 2) == 2688
+
+
 @pytest.mark.parametrize("group,ng_parity", [
     (512, "odd"),     # ng=1: the round-18 half-group walk (G % 256 == 0)
     (256, "even"),    # ng=2: whole-group walk

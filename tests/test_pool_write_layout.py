@@ -147,3 +147,54 @@ def test_a_first_chunks_page_tiles_leave_the_values_where_they_lie(
               if re.search(rf"= {values}\S* copy\(", line)]
     assert not copies, "the pool is copied whole:\n" + "\n".join(copies)
     assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
+
+
+# The expert matmuls the benchmark's cells dispatch (H, O, experts; the
+# sweep tool's list), at the decode bucket and at the widest prefill tile: the stripe
+# `pick_expert_bo` gives must be one Mosaic accepts at its default
+# scoped limit, compiled ALONE with x read from HBM (inside a model's
+# program XLA may hand the kernel an x that already lies in VMEM, which
+# takes no pipeline buffers: that is how 128 rows x 14336 -> 4096 at 256
+# columns ran in the Mixtral cell while this compile refused it with
+# "scoped allocation 17.53M, limit 16.00M"; ROADMAP S5, PERF.md §6 PR 47).
+def _cell_expert_shapes():
+    from tools.check_quant_kernel import CELL_SHAPES
+    return [pytest.param(H, O, NE, id=label.replace(" ", "-"))
+            for label, H, O, NE, _ in CELL_SHAPES]
+
+
+@pytest.mark.parametrize("rows", [32, 128])
+@pytest.mark.parametrize("H,O,NE", _cell_expert_shapes())
+def test_the_expert_stripe_the_rule_picks_compiles_for_the_chip(
+        rows, H, O, NE, described, no_cache):
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops import quant_mm as qmm
+    assert qmm.pick_expert_bo(rows, H, O, 2) is not None
+    # The suite's "highest" is a float32 request the MXU's bf16 dot does
+    # not take; the server runs at the default.
+    with jax.default_matmul_precision("default"):
+        jax.jit(qmm.quant_matmul_experts_stacked).lower(
+            described((NE, rows, H), jnp.bfloat16),
+            described((2, NE, H, O), jnp.int8),
+            described((2, NE, 1, O), jnp.float32),
+            described((), jnp.int32), described((NE,), jnp.int32)).compile()
+
+
+def test_mosaic_refuses_what_the_expert_account_refuses(described, no_cache):
+    """The other side, at the shape the account was calibrated on: the
+    dense search's 256 columns at 128 rows x 14336 -> 4096 is refused by
+    the compiler with the figure the account reads (17.53M <= account)."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops import quant_mm as qmm
+    assert not qmm.expert_bo_fits(128, 14336, 4096, 256, 2)
+    with jax.default_matmul_precision("default"), \
+            pytest.raises(Exception, match=r"17\.53M and limit 16\.00M"):
+        jax.jit(functools.partial(qmm.quant_matmul_experts_stacked,
+                                  bo=256)).lower(
+            described((8, 128, 14336), jnp.bfloat16),
+            described((2, 8, 14336, 4096), jnp.int8),
+            described((2, 8, 1, 4096), jnp.float32),
+            described((), jnp.int32), described((8,), jnp.int32)).compile()

@@ -99,39 +99,143 @@ def test_int4_group_choices_are_kernel_servable():
 # -- block-width picks at the production shapes -------------------------------
 
 def test_tile_table_pinned_entries():
-    """The measured per-hidden-size caps (rounds 16-18). A removal or
-    retune shows up here first, with the bench row that justified it."""
+    """The measured per-hidden-size caps (rounds 16-18), depth caps for
+    the ONE-matrix grids: the dense and int4 pickers read them through
+    `_pick_1d_bo`; since PR 47 the w8a16 expert grid, whose depth comes
+    from its experts, does not. A removal or retune shows up here first,
+    with the bench row that justified it."""
     assert qmm._TILE_TABLE[1024] == 256     # round-16 dense decode trunk
     assert qmm._TILE_TABLE[2816] == 128     # bench-moe w_down: avoid 1-program grid
     assert qmm._TILE_TABLE[11520] == 256    # mixtral-large w_down, budget-derived
 
 
+# The expert matmuls the benchmark's cells dispatch, H -> O, with the
+# width the rule gives each (tools/check_quant_kernel.py lists them for
+# its sweep; PERF.md section 6, PR 47): at the decode bucket (32 rows)
+# and at the rows of their prefill tiles (moe_tiles.tile_rows: 16-128).
+from tools.check_quant_kernel import CELL_SHAPES  # noqa: E402
+
+_CELL_EXPERT_SHAPES = [(H, O) for _, H, O, _, _ in CELL_SHAPES]
+_CELL_WIDTHS = {
+    "nemotron up": 2688, "nemotron down": 1024,
+    "olmoe down": 2048, "olmoe gate|up": 1024,
+    "mellum gate|up": 896, "mellum down": 2304,
+    "lfm2 gate|up": 1792, "lfm2 down": 1024,
+    "openpangu gate|up": 512, "openpangu down": 1920,
+    "mixtral gate|up": 1024, "mixtral down": 256,
+}
+_CELL_ROWS = (16, 32, 64, 128)
+
+
 @pytest.mark.parametrize("rows,H,O,bo", [
-    (16, 4096, 23040, 512),    # mixtral-large wgu_e (O = 2F)
-    (16, 11520, 4096, 256),    # mixtral-large w_down (tile-table cap)
-    (8, 1024, 5632, 256),      # bench-moe wgu_e (cap via H=1024)
-    (8, 2816, 1024, 128),      # bench-moe w_down (cap avoids bo=O)
-    (2048, 11520, 4096, None),  # prefill-class rows blow the x budget
+    # The widest multiple of 128 that divides O under a 4 MiB stripe,
+    # where the dense search gave (PR 45): 512, 256 (`_TILE_TABLE`), 256
+    # (`_TILE_TABLE[1024]`), 128 (`_TILE_TABLE[2816]`).
+    (16, 4096, 23040, 768),    # mixtral-large wgu_e (O = 2F = 30 x 768)
+    (16, 11520, 4096, 256),    # mixtral-large w_down: 512 is 5.6 MiB
+    (8, 1024, 5632, 2816),     # bench-moe wgu_e (5632 itself is 5.5 MiB)
+    (8, 2816, 1024, 1024),     # bench-moe w_down: one 2.75 MiB stripe
+    (2048, 11520, 4096, None),  # prefill-class rows: x alone is 3 x 45 MiB
     # mixtral-8x7b (the benchmark's 6-layer cell): wgu_e [4096 -> 2 x
     # 14336] and w_down, at a part-full, a full and a prefill bucket.
+    # They keep the dense search's answer by the rule's own arithmetic:
+    # 28672 = 2^12 x 7 and 4096 x 1024 IS the 4 MiB stripe (1792 would
+    # be 7 MiB); 14336 x 256 is 3.5 MiB and 14336 x 512 is 7.
     (2, 4096, 28672, 1024),
     (32, 4096, 28672, 1024),
-    (512, 4096, 28672, 1024),
-    (32, 14336, 4096, 256),     # the 4 MiB stripe budget shrinks it
-    # olmoe-1b-7b's thin experts, NE 64 (PERF.md section 6, PR 26: the
-    # chip's sweep of the caps at C = 8..512): wgu_e [2048 -> 2 x 1024]
-    # and w_down [1024 -> 2048].
+    (256, 4096, 28672, 1024),   # the widest bucket a Mixtral cell fills
+    # 512 rows: the dense search said 1024 and Mosaic refuses that
+    # (scoped 22.36M of 16: x [512, 4096] bf16 three times over is 12);
+    # 512 it refuses too (17.36M); 256 compiles (14.73M). No admission
+    # makes this bucket (two rows of 256 tokens at the most, 256 rows).
+    (512, 4096, 28672, 256),
+    (32, 14336, 4096, 256),     # the 4 MiB stripe limit shrinks it
+    # 128 rows: the dense search said 256, which Mosaic refuses compiled
+    # alone (scoped 17.53M of 16, ROADMAP S5: the figure the account was
+    # calibrated on); 128 compiles (10.56M). 256 rows: XLA, as before.
+    (128, 14336, 4096, 128),
+    (256, 14336, 4096, None),
+    # olmoe-1b-7b's thin experts, NE 64 (PERF.md section 6, PR 26 and PR
+    # 46: the chip's sweeps at C = 8..512): wgu_e [2048 -> 2 x 1024]
+    # and w_down [1024 -> 2048]. The dense search gave 1024 (two
+    # programs an expert), which stays: 2048 x 2048 is a 4 MiB expert,
+    # over the 3 MiB a whole expert may be in one stripe (the cell's
+    # trace read it 7-11% slower at one stripe than at two); and 256
+    # (eight programs, under `_TILE_TABLE[1024]`, a cap measured for a
+    # dense trunk of two programs), which goes to the whole 2 MiB expert.
     (8, 2048, 2048, 1024),
     (32, 2048, 2048, 1024),
     (64, 2048, 2048, 1024),
     (512, 2048, 2048, 1024),
-    (8, 1024, 2048, 256),
-    (32, 1024, 2048, 256),
-    (64, 1024, 2048, 256),
-    (512, 1024, 2048, 256),
+    (8, 1024, 2048, 2048),
+    (32, 1024, 2048, 2048),
+    (64, 1024, 2048, 2048),
+    (512, 1024, 2048, 2048),
+] + [
+    # Every cell's shapes at its decode bucket and its tile rows.
+    (rows, H, O, _CELL_WIDTHS[label])
+    for label, H, O, _, _ in CELL_SHAPES
+    for rows in _CELL_ROWS
+    if (rows, H, O) != (128, 14336, 4096)
 ])
 def test_pick_expert_bo_matrix(rows, H, O, bo):
     assert qmm.pick_expert_bo(rows, H, O, 2) == bo
+
+
+@pytest.mark.parametrize("H,O", _CELL_EXPERT_SHAPES)
+@pytest.mark.parametrize("rows", _CELL_ROWS + (2, 256, 512))
+def test_pick_expert_bo_is_the_widest_divisor_that_fits(rows, H, O):
+    """The rule, whole: the result is a multiple of 128, divides O,
+    passes the stripe limit, the two-stripes clause and the VMEM
+    account, and no wider such divisor does; None only where not even
+    128 columns fit. There is no clause by rows beyond the account's own
+    terms: on the chip
+    (tools/check_quant_kernel.py sweep-cells, 16 to 128 rows, all
+    experts touched and the cells' shares of them; PERF.md section 6,
+    PR 47) the widest width under the stripe limit is the fastest or
+    within 1.5% of it at eleven of the twelve shapes at every row count,
+    128 rows included (Nemotron's up projection 1.200 ms at 2688 against
+    1.299 at 896; OLMoE's down 0.390 at 2048 against 0.432 at 1024).
+    The two-stripes clause gives some of that up at 128 rows, all
+    experts touched (Mellum's gate|up 0.486 ms at 896 against 0.446 at
+    1792) for what it gains where a decode step leaves experts empty
+    (OLMoE's gate|up, 38 of 64 touched: 0.283 ms at 1024 against 0.324
+    at 2048). The twelfth shape is openPangu's 7680 -> 4096, where 256
+    columns read 0.700 ms against 0.781 at the 512 this rule AND the
+    dense search give at 32 rows, and lose at 128 rows: left as it was
+    (PERF.md section 7(xix))."""
+    fits = [b for b in range(128, O + 1, 128)
+            if O % b == 0 and qmm.expert_bo_fits(rows, H, O, b, 2)]
+    bo = qmm.pick_expert_bo(rows, H, O, 2)
+    if not fits:
+        assert bo is None
+        return
+    assert bo == max(fits)
+    assert bo % 128 == 0 and O % bo == 0
+    assert H * bo <= qmm._EXPERT_STRIPE_BYTES
+    assert bo < O or H * O <= qmm._EXPERT_WHOLE_BYTES
+    assert (qmm.expert_vmem_bytes(rows, H, bo, 2)
+            <= qmm._EXPERT_VMEM_LIMIT_BYTES)
+
+
+def test_expert_vmem_account_against_mosaics_own_figures():
+    """The account never reads under what Mosaic printed for the same
+    program (compiled for a described v5e under a lowered limit, which
+    makes it print: PR 47 read these thirteen again that way, to the
+    digit; tests/test_pool_write_layout.py compiles the cells' shapes
+    at the default one), and stands within 1 MiB of it at the shapes
+    that decide a cell's width."""
+    M = 2 ** 20
+    for rows, H, bo, mosaic in [
+            (128, 14336, 256, 17.53),   # ROADMAP S5's refusal
+            (32, 14336, 256, 9.94), (32, 4096, 1024, 8.92),
+            (256, 4096, 1024, 15.12), (512, 4096, 1024, 22.36),
+            (512, 4096, 256, 14.73), (32, 1024, 2688, 6.14),
+            (128, 1024, 2688, 7.31), (32, 2048, 2048, 8.55),
+            (128, 2048, 2048, 10.53), (128, 7680, 512, 13.36),
+            (128, 2048, 1920, 9.96), (512, 2688, 1024, 15.49)]:
+        account = qmm.expert_vmem_bytes(rows, H, bo, 2) / M
+        assert mosaic <= account <= mosaic + 1.0, (rows, H, bo, account)
 
 
 @pytest.mark.parametrize("rows,H,O,bo", [

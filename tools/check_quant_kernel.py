@@ -5,7 +5,9 @@ unstacked) on the real chip over random weights and checks closeness
 to the explicit-dequant XLA path, then times both at decode rows. CPU
 tests cover the math in interpret mode; this is the Mosaic-lowering
 check, and the measurement behind the per-hidden-size tile autotune
-table (_TILE_TABLE).
+table (_TILE_TABLE) of the one-matrix grids and, as ``sweep-cells``,
+behind the expert grid's own rule (pick_expert_bo: the widest stripe
+that fits, at every expert matmul a benchmark cell dispatches).
 
 Every shape runs to the end and gets one verdict line: ``PASS``
 (compiled, matches XLA), ``FAIL`` (the compiler refused it, it
@@ -24,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 import sys
 import time
 
@@ -38,6 +41,7 @@ from p2p_llm_chat_tpu.models.quant import (QTensor, QTensor4,  # noqa: E402
                                            dequantize4, quantize, quantize4)
 from tools.kernel_verdicts import (SlowerThanXLA, require_tpu,  # noqa: E402
                                    run_cases)
+from p2p_llm_chat_tpu.ops import quant_mm as qmm  # noqa: E402
 from p2p_llm_chat_tpu.ops.quant_mm import (_pick_1d_bo,  # noqa: E402
                                            pick_expert_bo, pick_int4_bo,
                                            quant_matmul, quant_matmul4,
@@ -174,25 +178,34 @@ def run_experts8(H: int, O: int, NE: int = 8, L: int = 2,
 HBM_GBPS = 819.0   # TPU v5e
 
 
+def _shown_widths(H: int, O: int) -> list:
+    return [bo for bo in qmm.expert_widths(O)
+            if H * bo <= 2 * qmm._EXPERT_STRIPE_BYTES]
+
+
 def sweep_experts8(H: int, O: int, NE: int, rows: tuple,
-                   caps: tuple, touched: tuple = ()) -> None:
-    """The measurement behind a `_TILE_TABLE` entry for an expert
-    stripe: the kernel's time at every bucket size ``rows`` under every
-    output-tile cap ``caps`` (None = the budget's own choice), beside
-    the XLA dequant einsum and the time the bytes take at the chip's
-    bandwidth. One dispatch runs the matmul ``REPEAT`` times over
-    alternating layers, as a model's layer scan does: a lone call of
-    half a millisecond measures the host's dispatch, not the kernel.
-    The cap is set for the duration of one timing and the table is left
-    as it was.
+                   widths: tuple | None = None, touched: tuple = (),
+                   label: str = "") -> None:
+    """The measurement behind `pick_expert_bo`: the expert-stripe
+    kernel's time at every bucket size ``rows`` at every stripe width of
+    ``widths`` (None: every multiple of 128 that divides ``O``, as far
+    as twice the rule's stripe limit, to show what lies past it; the one
+    the rule picks is marked), beside the XLA dequant einsum and the time
+    the bytes take at the chip's bandwidth. One dispatch runs the matmul
+    ``REPEAT`` times over alternating layers, as a model's layer scan
+    does: a lone call of half a millisecond measures the host's
+    dispatch, not the kernel. A width Mosaic refuses prints what it said
+    (the scoped allocation against the limit) beside the rule's own
+    account of it, and the sweep goes on.
 
     ``touched``: for each ``t`` of it, the kernel again with only ``t``
     of the ``NE`` buckets holding rows (spread evenly over the experts,
     the rest zero, the count handed to the kernel as the dispatch hands
     it): ms, and the GB/s of the bytes that then had to be read, the
     ``t`` experts' weights and every bucket's rows in and out."""
-    from p2p_llm_chat_tpu.ops import quant_mm as qmm
     L, REPEAT = 2, 16
+    if widths is None:
+        widths = _shown_widths(H, O)
     qt = _quantized(H + O + 5, (L, NE, H, O), quantize)
 
     def repeated(one):
@@ -207,51 +220,71 @@ def sweep_experts8(H: int, O: int, NE: int, rows: tuple,
     xla = repeated(lambda x, q, s, layer: jnp.einsum(
         "ech,ehf->ecf", x, q[layer].astype(x.dtype)) * s[layer].astype(
             x.dtype))
-    saved = dict(qmm._TILE_TABLE)
-    try:
-        for C in rows:
-            x = jax.random.normal(jax.random.PRNGKey(C), (NE, C, H),
-                                  jnp.bfloat16)
-            nbytes = NE * (H * O + 4 * O + 2 * C * (H + O))
-            floor_ms = nbytes / (HBM_GBPS * 1e9) * 1e3
-            x_ms = _time_ms(lambda: xla(x, qt.q, qt.s)) / REPEAT
-            print(f"sweep H={H} O={O} NE={NE} C={C}: bytes {nbytes} "
-                  f"floor {floor_ms:.4f} ms, XLA {x_ms:.4f} ms")
-            for cap in caps:
-                qmm._TILE_TABLE.clear()
-                qmm._TILE_TABLE.update(saved)
-                qmm._TILE_TABLE.pop(H, None)
-                if cap is not None:
-                    qmm._TILE_TABLE[H] = cap
-                jax.clear_caches()
-                bo = pick_expert_bo(C, H, O, 2)
-                if bo is None:
-                    print(f"  cap={cap}: kernel does not cover C={C}")
-                    continue
-                kern = repeated(quant_matmul_experts_stacked)
+    for C in rows:
+        x = jax.random.normal(jax.random.PRNGKey(C), (NE, C, H),
+                              jnp.bfloat16)
+        nbytes = NE * (H * O + 4 * O + 2 * C * (H + O))
+        floor_ms = nbytes / (HBM_GBPS * 1e9) * 1e3
+        x_ms = _time_ms(lambda: xla(x, qt.q, qt.s)) / REPEAT
+        picked = pick_expert_bo(C, H, O, 2)
+        print(f"sweep {label}H={H} O={O} NE={NE} C={C}: bytes {nbytes} "
+              f"floor {floor_ms:.4f} ms, XLA {x_ms:.4f} ms, the rule "
+              f"picks bo={picked}")
+        for bo in widths:
+            account = qmm.expert_vmem_bytes(C, H, bo, 2) / 2**20
+            mark = " <- the rule" if bo == picked else ""
+            kern = repeated(functools.partial(quant_matmul_experts_stacked,
+                                              bo=bo))
+            try:
                 k_ms = _time_ms(lambda: kern(x, qt.q, qt.s)) / REPEAT
-                print(f"  cap={cap} bo={bo} grid={NE}x{O // bo}: "
-                      f"{k_ms:.4f} ms = {100 * floor_ms / k_ms:.1f}% of "
-                      f"roofline, {x_ms / k_ms:.2f}x XLA", flush=True)
-                for t in touched:
-                    held = np.zeros((NE,), np.int32)
-                    held[[j * NE // t for j in range(t)]] = C
-                    count = jnp.asarray(held)
-                    xt = x * (count > 0)[:, None, None].astype(x.dtype)
-                    part = repeated(
-                        lambda x, q, s, layer: quant_matmul_experts_stacked(
-                            x, q, s, layer, count))
-                    t_ms = _time_ms(lambda: part(xt, qt.q, qt.s)) / REPEAT
-                    read = t * (H * O + 4 * O) + NE * 2 * C * (H + O)
-                    print(f"    touched {t}/{NE}: {t_ms:.4f} ms = "
-                          f"{t_ms / k_ms:.3f} of all touched without a "
-                          f"count ({t / NE:.3f} of the experts), "
-                          f"{read / t_ms / 1e6:.1f} GB/s of {read} bytes",
-                          flush=True)
-    finally:
-        qmm._TILE_TABLE.clear()
-        qmm._TILE_TABLE.update(saved)
-        jax.clear_caches()
+            except Exception as e:  # noqa: BLE001 - Mosaic's refusal
+                said = re.search(r"[Ss]coped allocation[^.]*\.\d+M[^.]*"
+                                 r"\.\d+M", str(e))
+                print(f"  bo={bo} grid={NE}x{O // bo}: REFUSED "
+                      f"({said.group(0) if said else str(e)[-200:]}; the "
+                      f"account {account:.2f}M){mark}", flush=True)
+                continue
+            print(f"  bo={bo} grid={NE}x{O // bo}: {k_ms:.4f} ms = "
+                  f"{100 * floor_ms / k_ms:.1f}% of roofline, "
+                  f"{x_ms / k_ms:.2f}x XLA (the account {account:.2f}M)"
+                  f"{mark}", flush=True)
+            for t in touched:
+                held = np.zeros((NE,), np.int32)
+                held[[j * NE // t for j in range(t)]] = C
+                count = jnp.asarray(held)
+                xt = x * (count > 0)[:, None, None].astype(x.dtype)
+                part = repeated(
+                    lambda x, q, s, layer: quant_matmul_experts_stacked(
+                        x, q, s, layer, count, bo=bo))
+                t_ms = _time_ms(lambda: part(xt, qt.q, qt.s)) / REPEAT
+                read = t * (H * O + 4 * O) + NE * 2 * C * (H + O)
+                print(f"    touched {t}/{NE}: {t_ms:.4f} ms = "
+                      f"{t_ms / k_ms:.3f} of all touched without a "
+                      f"count ({t / NE:.3f} of the experts), "
+                      f"{read / t_ms / 1e6:.1f} GB/s of {read} bytes",
+                      flush=True)
+    del qt
+    jax.clear_caches()
+
+
+# The expert matmuls the benchmark's cells dispatch (PERF.md section 4):
+# label, H, O, experts a layer holds, experts a full decode step touches
+# (where not all: the cell's `moe_touched_share`, or 1 - (1 - k / E)^32;
+# OLMoE's six in ten by its cell's trace, PERF.md section 6 PR 47).
+CELL_SHAPES = (
+    ("nemotron up", 1024, 2688, 128, 96),
+    ("nemotron down", 2688, 1024, 128, 96),
+    ("olmoe down", 1024, 2048, 64, 38),
+    ("olmoe gate|up", 2048, 2048, 64, 38),
+    ("mellum gate|up", 2304, 1792, 64, 64),
+    ("mellum down", 896, 2304, 64, 64),
+    ("lfm2 gate|up", 2048, 3584, 32, 6),
+    ("lfm2 down", 1792, 2048, 32, 6),
+    ("openpangu gate|up", 7680, 4096, 16, 5),
+    ("openpangu down", 2048, 7680, 16, 5),
+    ("mixtral gate|up", 4096, 28672, 8, 8),
+    ("mixtral down", 14336, 4096, 8, 8),
+)
 
 
 def run_experts4(H: int, O: int, NE: int = 8, L: int = 2) -> None:
@@ -323,23 +356,43 @@ def main() -> int:
             cases.append((f"int8 experts ne64 H={H} O={O} C={C}",
                           functools.partial(run_experts8, H, O, 64, 2, C)))
     # ``python tools/check_quant_kernel.py ne64``: only the cases whose
-    # label holds the word; ``sweep-ne64``: the tile sweep behind the
-    # thin experts' _TILE_TABLE entries instead of the verdicts.
-    if len(sys.argv) > 1 and sys.argv[1] == "sweep-ne64":
-        rows = (8, 32, 64, 128, 256, 512)
-        sweep_experts8(2048, 2048, 64, rows, (None, 512, 256))
-        sweep_experts8(1024, 2048, 64, rows, (None, 512, 256, 128))
+    # label holds the word. ``sweep-cells [word]``: instead of the
+    # verdicts, the stripe sweep behind `pick_expert_bo` at every expert
+    # matmul a cell dispatches (those whose label holds the word), every
+    # candidate width x 16 / 32 / 64 / 128 rows x all experts touched and
+    # the cell's share of them; ``sweep-ne64`` is its OLMoE rows at the
+    # buckets a capacity dispatch would fill (8 to 512 rows).
+    mode = sys.argv[1] if len(sys.argv) > 1 else ""
+    if mode == "sweep-cells":
+        word = sys.argv[2] if len(sys.argv) > 2 else ""
+        for label, H, O, NE, t in CELL_SHAPES:
+            if word in label:
+                # The six widest, and what the dense search gave the
+                # expert grid until PR 47.
+                was = _pick_1d_bo(32, H, O, 2)
+                widths = _shown_widths(H, O)[:6]
+                if was not in widths:
+                    widths.append(was)
+                sweep_experts8(H, O, NE, (16, 32, 64, 128), tuple(widths),
+                               touched=(t,) if t < NE else (),
+                               label=f"[{label}, dense search {was}] ")
+        return 0
+    if mode == "sweep-ne64":
+        for label, H, O, NE, _ in CELL_SHAPES:
+            if label.startswith("olmoe"):
+                sweep_experts8(H, O, NE, (8, 32, 64, 128, 256, 512),
+                               label=f"[{label}] ")
         return 0
     # ``sweep-touched``: the decode bucket (C = 32) with part of the
     # experts empty, at Mixtral-8x7B's two expert shapes and OLMoE's,
-    # under the tiles the program picks (the table as it stands).
-    if len(sys.argv) > 1 and sys.argv[1] == "sweep-touched":
-        from p2p_llm_chat_tpu.ops.quant_mm import _TILE_TABLE
+    # at the stripe the program picks.
+    if mode == "sweep-touched":
         for H, O, NE, touched in ((4096, 28672, 8, (2, 4, 8)),
                                   (14336, 4096, 8, (2, 4, 8)),
                                   (2048, 2048, 64, (16, 48, 64)),
                                   (1024, 2048, 64, (16, 48, 64))):
-            sweep_experts8(H, O, NE, (32,), (_TILE_TABLE.get(H),), touched)
+            sweep_experts8(H, O, NE, (32,),
+                           (pick_expert_bo(32, H, O, 2),), touched)
         return 0
     if len(sys.argv) > 1:
         cases = [c for c in cases if sys.argv[1] in c[0]]
