@@ -30,7 +30,10 @@ Every operation on the scheduler's pool is in place on a donated buffer
 (tests/test_state_pool.py reads the optimised HLO for a copy):
 
 - :func:`decode_update`: one layer's step for the first ``B`` rows; the
-  rows not named live keep their state bit for bit.
+  rows not named live keep their state bit for bit. On the TPU Mamba-2's
+  is :func:`ssm_decode_kernel`, which reads and writes a live row's
+  state once and touches no other; everywhere else, and for Mamba-1, one
+  XLA program over the ``B`` rows with a select.
 - :func:`write_rows`: a prefill's or a chunk ladder's final state into
   named rows. It overwrites the whole row, so a slot that is freed and
   reused inherits nothing.
@@ -86,12 +89,17 @@ neither decays nor feeds the state: that is how padding is masked.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..models.configs import ModelConfig
+from ..utils.device import on_tpu
+from .quant_mm import _expert_route
 
 
 class StatePool(NamedTuple):
@@ -290,6 +298,197 @@ def ssm1_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     return jnp.moveaxis(y, 0, 1), S_out
 
 
+# -- the Mamba-2 decode step as one kernel ------------------------------------
+#
+# XLA compiles :func:`ssm_step` behind a dynamic_slice and in front of a
+# dynamic_update_slice as two programs a layer: one reads the state,
+# recomputes ``S_new`` and reduces it to ``y``; the other reads the state
+# again, recomputes and writes. Three passes over the state where the
+# recurrence needs two (PERF.md section 6, PR 48). The kernel below
+# reads a block of the pool once, writes it back in place and hands out
+# ``y`` of the same pass; a row that is not live moves nothing.
+
+# What a program may hold in VMEM. The chip's sweep (tools/
+# check_state_kernel.py sweep, PERF.md section 6, PR 48) reads 592 GB/s
+# of the moved bytes at 16 heads of Nemotron's [64, 128] a block, 606 at
+# 32 (4.2 MiB by the account below) and 606 at 64: the widest block under
+# 6 MiB is where the plateau starts, well inside Mosaic's 16 MiB.
+_SSM_VMEM_BYTES = 6 * 1024 * 1024
+
+
+def ssm_kernel_vmem_bytes(hb: int, P: int, N: int) -> int:
+    """What one program of the decode kernel holds in VMEM at a block of
+    ``hb`` heads: the state block it reads and the one it writes, each in
+    the pipeline's two buffers; two blocks each of the columns (``hb``
+    lanes in tiles of 128) and of ``y`` (one row in a tile of 8); ``B``
+    and ``C`` at 8 groups. The body keeps one head in registers. Mosaic
+    allocates 16.16 MiB where this says 16.58 (128 heads of [64, 128])."""
+    lanes = hb + (-hb) % 128
+    return (4 * hb * P * N + 2 * P * lanes + 2 * 8 * hb * P + 4 * 8 * N) * 4
+
+
+def head_blocks(H: int, groups: int) -> list[int]:
+    """The head blocks the decode kernel's grid may take: the divisors of
+    ``H`` that hold whole groups or divide one (so that one ``B`` and
+    ``C`` serves a run of the block's heads), widest first."""
+    rep = H // groups
+    return [hb for hb in range(H, 0, -1)
+            if H % hb == 0 and (hb % rep == 0 or rep % hb == 0)]
+
+
+def pick_head_block(H: int, P: int, N: int, groups: int) -> int | None:
+    """Heads a program of the decode kernel: the widest of
+    :func:`head_blocks` that :func:`ssm_kernel_vmem_bytes` keeps within
+    ``_SSM_VMEM_BYTES``. None where not even one head's state does, or
+    its minor dimensions do not tile."""
+    if P % 8 or N % 128:
+        return None
+    return next((hb for hb in head_blocks(H, groups)
+                 if ssm_kernel_vmem_bytes(hb, P, N) <= _SSM_VMEM_BYTES), None)
+
+
+def ssm_kernel_covers(state_shape: tuple) -> bool:
+    """Whether :func:`decode_update` runs Mamba-2's step as the kernel,
+    for a row's state ``[H, P, N]``: on the TPU (utils/device.py, the one
+    platform probe), where the state tiles and one head's fits (a block
+    of one head is within every grouping's reach, so the grouping is not
+    asked). Read once at a boot too: the scheduler counts the rows a step
+    moves by it."""
+    H, P, N = state_shape
+    return on_tpu() and pick_head_block(H, P, N, H) is not None
+
+
+def _ssm_decode_kernel(layer_ref, route_ref, decay_ref, s_ref, cols_ref,
+                       b_ref, c_ref, s_out_ref, y_ref, *, rep: int):
+    """One program = ``hb`` heads of one row of the pool's layer
+    ``layer_ref[0]``: ``S <- S * decay + dtx (outer) B`` written to the
+    block it was read from, and ``y = S C`` of what was written, a group
+    of heads a product: ``C [1, N] . S [heads x P, N]^T`` on the MXU at
+    float32 (the lane reduction of the same sum held the kernel to 513
+    GB/s of its bytes where this reads 606; PERF.md section 6, PR 48).
+    ``route_ref`` (quant_mm._expert_route over the live mask): a row that
+    is not live was handed the block the pipeline already holds, computes
+    nothing and leaves that block as it is; its ``y`` is zeros."""
+    r, j = pl.program_id(0), pl.program_id(1)
+    hb, P, N = s_ref.shape[2:]
+    H = hb * pl.num_programs(1)
+    per = min(hb, rep)                  # heads of the block that share a group
+    live = route_ref[r] > 0
+
+    @pl.when(live)
+    def _step():
+        for h0 in range(0, hb, per):
+            g = (j * hb + h0) // rep
+            Bv = b_ref[0, pl.ds(g, 1), :]
+            for h in range(h0, h0 + per):
+                s_out_ref[0, 0, h] = (
+                    s_ref[0, 0, h] * decay_ref[r * H + j * hb + h]
+                    + cols_ref[0, 0, :, h: h + 1] * Bv)
+            y = jax.lax.dot_general(
+                jnp.broadcast_to(c_ref[0, pl.ds(g, 1), :], (8, N)),
+                s_out_ref[0, 0, h0: h0 + per].reshape(per * P, N),
+                (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            y_ref[0, 0, :, h0 * P: (h0 + per) * P] = y[:1]
+
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    # No row before this one has written the output block the pipeline
+    # holds: it goes back as it came unless a live row fills it first.
+    @pl.when(jnp.logical_not(live) & (r == 0) & (j == 0))
+    def _keep():
+        s_out_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
+def ssm_decode_kernel(ssm: jax.Array, layer: jax.Array, live: jax.Array,
+                      x: jax.Array, dt: jax.Array, A: jax.Array,
+                      Bm: jax.Array, Cm: jax.Array, *,
+                      hb: int | None = None,
+                      interpret: bool = False) -> tuple:
+    """:func:`ssm_step` for the first B rows of layer ``layer`` of the
+    pool's ``ssm`` [L_m, rows, H, P, N], in place: one pass over the live
+    rows' state. x [B,H,P]; dt [B,H] float32; A [H]; Bm, Cm [B,G,N];
+    live [B] bool. Returns (y [B,H,P] float32, zeros for a row that is
+    not live; ssm, the other rows and layers untouched). ``hb`` (None =
+    :func:`pick_head_block`'s): heads a program, for the sweep that
+    measures the rule and the tests that hold every block to the same
+    answer. All arithmetic is float32, as :func:`ssm_step`'s."""
+    _, _, H, P, N = ssm.shape
+    B, G = x.shape[0], Bm.shape[-2]
+    f32 = jnp.float32
+    if hb is None:
+        hb = pick_head_block(H, P, N, G)
+    nblk = H // hb
+    decay = jnp.exp(dt * A).reshape(B * H)
+    # dtx as columns: a head's [P] lies along the state's sublanes.
+    cols = jnp.swapaxes(
+        (dt[..., None] * x.astype(f32)).reshape(B, nblk, hb, P), 2, 3)
+    route = _expert_route(live.astype(jnp.int32), B)
+
+    def row_map(r, j, ly, route):
+        src = route[B + r]
+        return src, jnp.where(route[r] > 0, j,
+                              jnp.where(src < r, nblk - 1, 0))
+
+    def state_map(r, j, ly, route):
+        src, blk = row_map(r, j, ly, route)
+        return ly[0], src, blk, 0, 0
+
+    def col_map(r, j, ly, route):
+        return (*row_map(r, j, ly, route), 0, 0)
+
+    def group_map(r, j, ly, route):
+        return route[B + r], 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nblk),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, hb, P, N), state_map),
+            pl.BlockSpec((1, 1, P, hb), col_map),
+            pl.BlockSpec((1, G, N), group_map),
+            pl.BlockSpec((1, G, N), group_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, hb, P, N), state_map),
+            pl.BlockSpec((1, 1, 1, hb * P),
+                         lambda r, j, ly, route: (r, j, 0, 0)),
+        ],
+    )
+    ssm, y = pl.pallas_call(
+        functools.partial(_ssm_decode_kernel, rep=H // G),
+        name="ssm_decode_step",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(ssm.shape, f32),
+                   jax.ShapeDtypeStruct((B, nblk, 1, hb * P), f32)],
+        input_output_aliases={3: 0},
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), route, decay, ssm, cols,
+      Bm.astype(f32), Cm.astype(f32))
+    return y.reshape(B, H, P), ssm
+
+
+def _window_update(pool: StatePool, layer: jax.Array, live: jax.Array,
+                   z: jax.Array, conv_w: jax.Array,
+                   conv_b: Optional[jax.Array]) -> tuple:
+    """One position of layer ``layer``'s convolution for the pool's first
+    B rows: (out [B,C] float32, ``pool.conv`` with the live rows' windows
+    moved on, in place)."""
+    B = z.shape[0]
+    zero = jnp.zeros((), jnp.int32)
+    at = (jnp.asarray(layer, jnp.int32), zero, zero, zero)
+    win = jax.lax.dynamic_slice(pool.conv, at,
+                                (1, B) + pool.conv.shape[2:])[0]
+    out, win_new = conv_step(win, z, conv_w, conv_b)
+    win_new = jnp.where(live[:, None, None], win_new, win)
+    return out, jax.lax.dynamic_update_slice(pool.conv, win_new[None], at)
+
+
 def decode_update(pool: StatePool, layer: jax.Array, live: jax.Array,
                   xbc: jax.Array, conv_w: jax.Array, conv_b: jax.Array,
                   split, step=ssm_step) -> tuple:
@@ -299,7 +498,15 @@ def decode_update(pool: StatePool, layer: jax.Array, live: jax.Array,
     reading of the convolved channels, in the shapes ``step`` takes
     (:func:`ssm_step`, or :func:`ssm1_step` for Mamba-1). ``live`` [B]
     bool: the other rows' state and window come back bit for bit.
-    Returns (y float32, x, pool)."""
+    Returns (y float32, x, pool). Where :func:`ssm_kernel_covers` says
+    so, Mamba-2's state goes through :func:`ssm_decode_kernel` and the
+    ``y`` of a row that is not live is zeros; the convolution's window,
+    a thousandth of the bytes, stays XLA's."""
+    if step is ssm_step and ssm_kernel_covers(pool.ssm.shape[2:]):
+        out, conv = _window_update(pool, layer, live, xbc, conv_w, conv_b)
+        x, dt, A, Bm, Cm = split(out)
+        y, ssm = ssm_decode_kernel(pool.ssm, layer, live, x, dt, A, Bm, Cm)
+        return y, x, pool._replace(ssm=ssm, conv=conv)
     B = xbc.shape[0]
     zero = jnp.zeros((), jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
@@ -328,15 +535,8 @@ def conv_update(pool: StatePool, layer: jax.Array, live: jax.Array,
     recurrence behind the convolution. ``z`` [B,C]: the convolution's new
     input. Rows not ``live`` keep their window bit for bit. Returns (out
     [B,C] float32, pool)."""
-    B = z.shape[0]
-    zero = jnp.zeros((), jnp.int32)
-    at = (jnp.asarray(layer, jnp.int32), zero, zero, zero)
-    win = jax.lax.dynamic_slice(pool.conv, at,
-                                (1, B) + pool.conv.shape[2:])[0]
-    out, win_new = conv_step(win, z, conv_w, None)
-    win_new = jnp.where(live[:, None, None], win_new, win)
-    return out, pool._replace(conv=jax.lax.dynamic_update_slice(
-        pool.conv, win_new[None], at))
+    out, conv = _window_update(pool, layer, live, z, conv_w, None)
+    return out, pool._replace(conv=conv)
 
 
 # -- window rings -------------------------------------------------------------

@@ -66,7 +66,7 @@ from ..ops.paged_kv import (PageAllocator, PagedKVCache, copy_slot,
                             gather_pages, scatter_pages, set_row_table,
                             write_prefill_batch, write_prefill_chunk)
 from ..ops.state_pool import (StatePool, from_snapshot, snapshot,
-                              write_rows)
+                              ssm_kernel_covers, write_rows)
 from ..tokenizer import Tokenizer
 from ..utils.env import env_float
 from ..utils.failpoints import failpoint
@@ -721,6 +721,13 @@ class BatchScheduler:
                          config.num_experts, config.router_width,
                          config.moe_scoring, config.routed_scaling_factor,
                          config.num_shared_experts, config.first_k_dense)
+        # Whether Mamba-2's decode step is the kernel that moves live
+        # rows' state only (ops/state_pool.decode_update's own predicate,
+        # read once: the traced programs bake it in): what the state
+        # counters below count a step's rows by.
+        self._state_kernel = (config.ssm_layers > 0
+                              and not config.mamba1_inner
+                              and ssm_kernel_covers(config.ssm_state_shape))
         self._log_kernels()
 
         self._slots: list[Optional[_Slot]] = [None] * num_slots  # owned-by: _loop
@@ -797,10 +804,11 @@ class BatchScheduler:
         self._n_attn_chunks = 0          # owned-by: _loop
         self._n_attn_chunks_walked = 0   # owned-by: _loop
         # Recurrent state (a hybrid model's ops/state_pool.py): rows whose
-        # state the decode dispatches moved (every slot's, each step:
-        # the update is one program over the pool's rows), those of them
-        # live, the bytes that is (read and written), and the prefix
-        # hits that started from an entry's snapshot.
+        # state the decode dispatches moved (the live ones where Mamba-2's
+        # kernel is the update; every slot's, each step, where XLA's one
+        # program over the pool's rows is), those of them live, the bytes
+        # that is (read and written), and the prefix hits that started
+        # from an entry's snapshot.
         self._n_state_row_steps = 0      # owned-by: _loop
         self._n_state_row_steps_live = 0  # owned-by: _loop
         self._n_state_bytes = 0          # owned-by: _loop
@@ -3805,6 +3813,13 @@ class BatchScheduler:
         else:
             off["flash-append"] = flash_append_blocked(
                 sharded, *self._flash_pool_row(self.config, self.kv_quant))
+        if self.config.ssm_layers:
+            shape = "x".join(map(str, self.config.ssm_state_shape))
+            off["ssm-decode"] = (
+                None if self._state_kernel else
+                "Mamba-1 hands in its own step" if self.config.mamba1_inner
+                else why_off or f"a state of {shape} does not tile (head_dim "
+                "% 8, state_size % 128)")
         log.info("kernels on %s: %s; XLA instead of: %s; flash-append "
                  "min_w %d; pallas interpret %s", platform(),
                  ", ".join(k for k, why in off.items() if not why) or "none",
@@ -4592,14 +4607,17 @@ class BatchScheduler:
         self._note_clean_interval(now, admitted)
         self._last_dispatch = (now, K)
         active = tuple(s is not None for s in self._slots)
-        self._n_decode_row_steps += sum(active) * K
+        live_steps = sum(active) * K
+        self._n_decode_row_steps += live_steps
         if self._cache.state is not None:
-            # The step's state update is one program over every slot's
-            # row; a row not live comes back as it was.
-            self._n_state_row_steps += self.num_slots * K
-            self._n_state_row_steps_live += sum(active) * K
-            self._n_state_bytes += (2 * self.num_slots * K
-                                    * self._state_row_bytes)
+            # The kernel reads and writes a live row's state and nothing
+            # of the others'; XLA's update is one program over every
+            # slot's row, and a row not live comes back as it was.
+            moved = (live_steps if self._state_kernel
+                     else self.num_slots * K)
+            self._n_state_row_steps += moved
+            self._n_state_row_steps_live += live_steps
+            self._n_state_bytes += 2 * moved * self._state_row_bytes
         # Step j of the K reads each live row's ctx_len + j cached rows.
         ctx_tokens = K * sum(
             s.ctx_len + inflight for s in self._slots

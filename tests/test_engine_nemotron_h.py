@@ -130,6 +130,19 @@ def test_admission_chunks_prefix_fused_decode_slot_reuse_and_counters(
         assert 0 < m["serve_moe_local_pairs_total"] < (
             m["serve_moe_routed_pairs_total"])
         assert m["serve_moe_dropped_total"] == 0
+        # Where the decode kernel is the update (on the chip; the boot
+        # reads ops/state_pool.ssm_kernel_covers once) only live rows'
+        # state moves, and the counters say so: the program is XLA's
+        # here, the flag is steered.
+        assert sched._state_kernel is False
+        sched._state_kernel = True
+        assert run(eng, lone, max_tokens=6)[0] == SOLO(qparams, lone, 6)
+        after = eng.metrics_snapshot()
+        moved = after["serve_state_row_steps_total"] - steps
+        assert 0 < moved == (after["serve_state_row_steps_live_total"]
+                             - m["serve_state_row_steps_live_total"])
+        assert after["serve_state_bytes_total"] \
+            - m["serve_state_bytes_total"] == 2 * moved * pool.row_bytes
     finally:
         eng.stop()
 

@@ -198,3 +198,61 @@ def test_mosaic_refuses_what_the_expert_account_refuses(described, no_cache):
             described((2, 8, 14336, 4096), jnp.int8),
             described((2, 8, 1, 4096), jnp.float32),
             described((), jnp.int32), described((8,), jnp.int32)).compile()
+
+
+# The state pool of benchmark/configs/nemotron-3-super-120b-a12b-l22e128:
+# ten Mamba-2 layers, 32 slots and the garbage row, 128 heads x 64 x 128
+# float32 in 8 groups; a decode step moves 32 rows.
+SSM_SHAPE = (10, 33, 128, 64, 128)
+SSM_GROUPS, SSM_ROWS, CONV_K = 8, 32, 4
+
+
+def test_the_decode_kernel_updates_the_state_pool_where_it_lies(
+        described, no_cache, monkeypatch):
+    """``decode_update`` on the chip's path (the platform probe steered
+    here: nothing runs), two layers' calls in one donated program, as a
+    decode program's walk makes them: Mosaic takes the head block
+    ``pick_head_block`` gives at its default scoped limit, the kernel is
+    in the program, and no instruction the shape of the pool is a copy
+    (PERF.md §6, PR 48)."""
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops import state_pool
+    from p2p_llm_chat_tpu.ops.state_pool import StatePool
+    _, _, H, P, N = SSM_SHAPE
+    G, B = SSM_GROUPS, SSM_ROWS
+    hb = state_pool.pick_head_block(H, P, N, G)
+    assert hb and H % hb == 0
+    assert state_pool.ssm_kernel_vmem_bytes(hb, P, N) < 16 * 1024 * 1024
+    monkeypatch.setattr(state_pool, "on_tpu", lambda: True)
+    C = H * P + 2 * G * N
+
+    def step(pool, live, xbc, dt_raw, w, b):
+        def split(out):
+            x = jax.nn.silu(out)
+            return (x[:, :H * P].reshape(B, H, P),
+                    jax.nn.softplus(dt_raw), -jnp.ones((H,), jnp.float32),
+                    x[:, H * P: H * P + G * N].reshape(B, G, N),
+                    x[:, H * P + G * N:].reshape(B, G, N))
+        ys = []
+        for layer in (3, 7):
+            y, _, pool = state_pool.decode_update(
+                pool, jnp.asarray(layer, jnp.int32), live, xbc, w, b, split)
+            ys.append(y)
+        return ys, pool
+
+    pool = StatePool(
+        ssm=described(SSM_SHAPE, jnp.float32),
+        conv=described(SSM_SHAPE[:2] + (CONV_K - 1, C), jnp.bfloat16))
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        pool, described((B,), jnp.bool_), described((B, C), jnp.bfloat16),
+        described((B, H), jnp.float32), described((CONV_K, C), jnp.bfloat16),
+        described((C,), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("ssm_decode_step") >= 2, "the kernel is not there"
+    shape = r"f32\[" + ",".join(map(str, SSM_SHAPE)) + r"\]"
+    copies = [line.strip()[:200] for line in text.splitlines()
+              if re.search(rf"= {shape}\S* copy(-start)?\(", line)]
+    assert not copies, "the state pool is copied whole:\n" + "\n".join(copies)
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
