@@ -180,6 +180,13 @@ def _admit_buffer(R: int, S: int, mppr: int, vocab_size: int) -> tuple:
     return buf, views
 
 
+def _unless_padding(real, run, *carried):
+    """``run(*carried)`` where the scalar ``real`` holds; where it does
+    not, ``carried`` as it came and nothing computed (one ``lax.cond``:
+    the ladder's chunk programs, ``_make_prefill_chunk_program._fwd``)."""
+    return jax.lax.cond(real, run, lambda *carried: carried, *carried)
+
+
 def _causal_pairs(lo: int, hi: int) -> int:
     """(query, key) pairs of prompt positions lo..hi-1 under a causal
     mask: position i attends i + 1 positions."""
@@ -958,6 +965,9 @@ class BatchScheduler:
                               if prefill_chunk else 0)
         self._prefill_carry: Optional[_PrefillCarry] = None  # owned-by: _loop
         self._n_prefill_chunks = 0    # owned-by: _loop — chunk dispatches
+        # ... of which those past every row's suffix: the chunk programs
+        # compute nothing there (_make_prefill_chunk_program._fwd).
+        self._n_prefill_chunks_padded = 0     # owned-by: _loop
         self._admit_since_tick = False  # owned-by: _loop — admission work since last decode dispatch
         self._last_decode_t: Optional[float] = None  # owned-by: _loop
         self._decode_stall_ms = 0.0   # owned-by: _loop — max decode gap attributable to admission
@@ -1359,7 +1369,9 @@ class BatchScheduler:
             (models/mixtral.py ``valid``): the real prompt positions of
             the ``width`` positions from suffix offset ``off`` — below
             the entry's (suffix) length, in an entry that carries a
-            request (a dummy entry's row is the sentinel ``num_slots``)."""
+            request (a dummy entry's row is the sentinel ``num_slots``).
+            Also what decides, for every family, whether a ladder's
+            chunk runs at all (``_make_prefill_chunk_program._fwd``)."""
             pos = off + jnp.arange(width)[None, :]
             real = (ints[1] < self.num_slots)[:, None]
             return (pos < ints[0][:, None]) & real
@@ -1609,26 +1621,54 @@ class BatchScheduler:
                 return (tokens[:, OFF: OFF + C], *rest)
 
             def _fwd(params, tokens, ints, carry, logits_c):
-                # A routed model's carried logits travel with its drop
-                # count so far: (logits [R,V], stats [2]).
-                suf_lens = ints[0]
-                local_last = suf_lens - 1 - OFF
-                last_idx = jnp.clip(local_last, 0, C - 1)
-                if routed:
-                    logits_c, moe_c = logits_c
-                    logits, carry, moe = model.prefill_chunk_counted(
-                        params, config, tokens, carry, base,
-                        _moe_valid(ints, OFF, C), mesh, last_idx=last_idx)
-                else:
-                    logits, carry = model.prefill_chunk(
-                        params, config, tokens, carry, base, mesh,
-                        last_idx=last_idx)
-                keep = (local_last >= 0) & (local_last < C)
-                logits_c = jnp.where(keep[:, None], logits[:, 0, :],
-                                     logits_c)
-                if routed:
-                    logits_c = (logits_c, moe_c + moe)
-                return carry, logits_c
+                """The chunk's forward, folded into the carried logits.
+
+                A ``mid`` or ``final`` chunk with no real position in
+                any row computes nothing (``lax.cond``) and hands the
+                carry and the carried logits back as it took them. A
+                ladder runs every chunk of its power-of-two bucket, so
+                a 9 K prompt brings six such chunks of sixteen, and they
+                lie at the ladder's highest offsets, where a page
+                layer's attention is longest. Its queries are padding,
+                what they would write behind the prompt is read by no
+                real query, a decode step overwrites a slot before it
+                trusts it, every recurrent or windowed layer moves its
+                state by the chunk's count of ``valid`` positions (none
+                here), and the logits fold in only for rows whose last
+                position lies in the chunk.
+
+                Padding is padding in every family, so the test is the
+                scheduler's own arithmetic over the admission buffer
+                (:func:`_moe_valid`) and no model's: a chunk with one
+                real position runs the program it always ran. The
+                first chunk always holds one and takes no ``cond``."""
+                valid = _moe_valid(ints, OFF, C)
+
+                def run(carry, logits_c):
+                    # A routed model's carried logits travel with its
+                    # drop count so far: (logits [R,V], stats [2]).
+                    suf_lens = ints[0]
+                    local_last = suf_lens - 1 - OFF
+                    last_idx = jnp.clip(local_last, 0, C - 1)
+                    if routed:
+                        logits_c, moe_c = logits_c
+                        logits, carry, moe = model.prefill_chunk_counted(
+                            params, config, tokens, carry, base, valid,
+                            mesh, last_idx=last_idx)
+                    else:
+                        logits, carry = model.prefill_chunk(
+                            params, config, tokens, carry, base, mesh,
+                            last_idx=last_idx)
+                    keep = (local_last >= 0) & (local_last < C)
+                    logits_c = jnp.where(keep[:, None], logits[:, 0, :],
+                                         logits_c)
+                    if routed:
+                        logits_c = (logits_c, moe_c + moe)
+                    return carry, logits_c
+
+                if first:
+                    return run(carry, logits_c)
+                return _unless_padding(jnp.any(valid), run, carry, logits_c)
 
             def _splice(cache, carry, ints, tables):
                 rows = ints[1]
@@ -3508,6 +3548,11 @@ class BatchScheduler:
             # on — the stall the tentpole bounds), and client-perceived
             # inter-token latency percentiles.
             "prefill_chunks_total": self._n_prefill_chunks,
+            # ... and those of them whose offset lay at or past every
+            # row's suffix length: dispatched (the ladder runs its whole
+            # bucket) and computing nothing.
+            "serve_prefill_chunks_padded_total":
+                self._n_prefill_chunks_padded,
             "decode_stall_ms": round(self._decode_stall_ms, 3),
             "inter_token_p50_ms": round(
                 self._tbt_hist.percentile(50) or 0.0, 4),
@@ -4524,13 +4569,17 @@ class BatchScheduler:
         P0 = pc.prefix.length if pc.prefix is not None else 0
         off = pc.off
         R = pc.packed.shape[0]
+        # Past every row's suffix: the program's own test, on the host.
+        padded = all(off >= len(s.prompt_ids) - P0 for s in pc.chunk)
         self._n_prefill_chunks += 1
+        self._n_prefill_chunks_padded += padded
         self._n_admit_pair_dispatches += len(pc.chunk) > 1
         self._n_prefill_padded += R * C
         self._admit_since_tick = True
         self._flight.note("prefill_chunk", self._loop_iter,
                           off=off, C=C, S=pc.S, n=len(pc.chunk))
-        with self._phase("prefill_chunk", R=R, S=pc.S, C=C, off=off):
+        with self._phase("prefill_chunk", R=R, S=pc.S, C=C, off=off,
+                         padded=int(padded)):
             kv, logits, toks_dev = self._dispatch_prefill_chunk(
                 P0, pc.S, off, C, pc.packed, pc.kv, pc.logits, pc.prefix)
             if toks_dev is None:

@@ -17,6 +17,8 @@ A family's engine test brings one :class:`Solo` (its arguments are what
 differs between families), not a copy of this loop.
 """
 
+import threading
+
 import numpy as np
 
 import jax
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import state_pool
 from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
+from p2p_llm_chat_tpu.serve import scheduler as sched_mod
 from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
                                             RequestStats)
 
@@ -37,6 +40,49 @@ def generate(engine, prompt, max_tokens=12, **opts):
     req = GenerateRequest(prompt=prompt, options=GenerateOptions(
         max_tokens=max_tokens, **opts))
     return "".join(engine.generate_stream(req, stats)), stats
+
+
+def on_loop(sched, fn):
+    """``fn()`` on ``sched``'s own thread, which owns the device buffers
+    (the road a warm-up job takes)."""
+    box = []
+    job = sched_mod._WarmupJob(lambda: box.append(fn()), threading.Event())
+    sched._admit_q.put(job)
+    assert job.done.wait(600)
+    if job.err is not None:
+        raise job.err
+    return box[0]
+
+
+def ladder(sched, prompts, S, R):
+    """The chunk ladder of ``prompts`` (lists of ids; entry ``i`` takes
+    row ``i + 1`` and a row's worth of pages, dummy entries fill up to
+    ``R``) through ``sched``'s own chunk programs, dispatched one by
+    one as the loop dispatches them. Call it on the loop
+    (:func:`on_loop`). Returns (the rows, the carry and carried logits
+    behind each chunk but the last as host arrays, the first tokens)."""
+    per_row = sched._cache.max_pages_per_row
+    slots = []
+    for i, ids in enumerate(prompts):
+        slot = sched_mod._Slot(
+            req=GenerateRequest(prompt="", options=GenerateOptions(
+                temperature=0.0)),
+            stats=None, out_q=None, seed=i)
+        slot.prompt_ids = list(ids)
+        slot.pages = list(range(1 + i * per_row, 1 + (i + 1) * per_row))
+        slots.append(slot)
+    rows = list(range(1, 1 + len(slots)))
+    packed = sched._admit_upload(
+        sched._admit_host_arrays(slots, rows, S, R, None), live=False)
+    kv = logits = toks = None
+    carries = []
+    for off in range(0, S, sched.prefill_chunk):
+        kv, logits, toks = sched._dispatch_prefill_chunk(
+            0, S, off, sched.prefill_chunk, packed, kv, logits, None)
+        if toks is None:
+            carries.append((jax.tree.map(np.asarray, kv),
+                            jax.tree.map(np.asarray, logits)))
+    return rows, carries, np.asarray(toks)[:len(rows)]
 
 
 def greedy(last, seen):

@@ -27,8 +27,10 @@ from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.ops import paged_attention as pa
 from p2p_llm_chat_tpu.ops import paged_kv
 from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_prefill_batch
+from p2p_llm_chat_tpu.serve.scheduler import BatchScheduler
+from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 
-from solo import jit_model
+from solo import jit_model, ladder, on_loop
 
 ROOT = os.path.join(manifest.REPO, "benchmark")
 NAME = "keye-vl-2.0-30b-a3b-l12"
@@ -456,35 +458,34 @@ def test_the_decode_scores_kernel_equals_the_gathered_windows_scores(
 def test_a_chunk_of_padding_alone_attends_nothing_and_changes_no_real_row(
         plain):
     """A ladder runs every chunk of its bucket: a chunk with no real
-    position in any row computes nothing (``lax.cond``) and hands the
+    position in any row computes nothing (the ``lax.cond`` of the
+    scheduler's chunk program, every family's since PR 50) and hands the
     carry back as it took it, and the real positions' logits are what a
-    ladder cut at the prompt's end gives."""
-    sched, _ = plain
-    long = jnp.asarray(ARCH.long_tokens(TOKENS, 512, CHUNK))[:, :64]
-
-    def chunk(offset, last=None):
-        return jax.jit(lambda p, t, c, v: nemotron_h.prefill_chunk_counted(
-            p, CFG, t, c, offset, v, last_idx=last))
-
-    carry = KVCache.create(CFG, 1, 64, jnp.float32)
-    _, carry, _ = chunk(0)(sched._params, long[:, :32], carry,
-                           jnp.ones((1, 32), bool))
-    nothing = jnp.zeros((1, 32), bool)
-    text = chunk(32).lower(sched._params, long[:, 32:], carry,
-                           nothing).as_text()
-    assert "stablehlo.case" in text or "stablehlo.if" in text
-    _, padded, _ = chunk(32)(sched._params, long[:, 32:], carry, nothing)
-    np.testing.assert_array_equal(np.asarray(padded.k), np.asarray(carry.k))
-    np.testing.assert_array_equal(np.asarray(padded.idx),
-                                  np.asarray(carry.idx))
+    one-piece prefill of the prompt gives."""
+    params = plain[0]._params
+    long = np.asarray(ARCH.long_tokens(TOKENS, 512, CHUNK))[0, :41]
+    sched = BatchScheduler(params, CFG, ByteTokenizer(vocab_size=512),
+                           num_slots=2, max_seq=128, page_size=16,
+                           prefill_chunk=32)
+    try:
+        # 41 positions in a bucket of four chunks: whole, nine real
+        # positions, padding alone, padding alone.
+        _, carries, first = on_loop(sched, lambda: ladder(sched, [long],
+                                                          128, 1))
+    finally:
+        sched.stop()
+    (whole, _), (half, lg_half), (padded, lg_padded) = carries
+    np.testing.assert_array_equal(padded.k, half.k)
+    np.testing.assert_array_equal(padded.idx, half.idx)
+    np.testing.assert_array_equal(lg_padded[0], lg_half[0])
+    assert not np.array_equal(half.k, whole.k)
     # A half-real chunk is computed whole.
-    half = jnp.arange(32)[None, :] < 9
-    lg, _, _ = chunk(32, jnp.asarray([8]))(sched._params, long[:, 32:],
-                                           carry, half)
     cold, _ = jit_model(nemotron_h.prefill, CFG, last_only=True)(
-        sched._params, long[:, :41], jnp.asarray([41]),
+        params, jnp.asarray(long)[None], jnp.asarray([41]),
         KVCache.create(CFG, 1, 41, jnp.float32))
-    np.testing.assert_allclose(np.asarray(lg), np.asarray(cold), atol=2e-4)
+    np.testing.assert_allclose(lg_half[0], np.asarray(cold)[:, 0],
+                               atol=2e-4)
+    assert int(first[0]) == int(np.argmax(np.asarray(cold)[0, 0]))
 
 
 def test_the_index_key_rides_every_write_op_of_the_pool():

@@ -21,6 +21,7 @@ All on the CPU: counts and control flow, never a device timing.
 import ast
 import json
 import os
+import re
 import threading
 import time
 import types
@@ -30,14 +31,16 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from p2p_llm_chat_tpu.models import llama
+from p2p_llm_chat_tpu.models import family_for, llama
 from p2p_llm_chat_tpu.models.configs import get_config
+from p2p_llm_chat_tpu.models.llama import KVCache
 from p2p_llm_chat_tpu.obs import phase as phase_mod
 from p2p_llm_chat_tpu.obs.phase import (CPU_EVERY, PARTS, PARTS_OF, PHASES,
                                         LoopPhases, compile_clock,
                                         process_age_s)
 from p2p_llm_chat_tpu.serve.backend import (GenerateOptions, GenerateRequest,
                                             RequestStats)
+from p2p_llm_chat_tpu.serve import scheduler as sched_mod
 from p2p_llm_chat_tpu.serve.scheduler import BatchScheduler
 from p2p_llm_chat_tpu.tokenizer import ByteTokenizer
 from p2p_llm_chat_tpu.utils import failpoints as fp
@@ -685,3 +688,91 @@ def test_a_lowered_program_carries_its_kind():
     finally:
         sched.stop()
     assert "module @jit_decode_step" in text
+
+
+# -- a ladder's padded chunks: one cond, on the admission buffer -------------------
+
+def _chunk_program_texts(family: str) -> dict:
+    """offset -> (the StableHLO of the chunk program there, the index of
+    the packed admission buffer among ``@main``'s arguments), for the
+    first, a mid and the final chunk of a four-chunk bucket of
+    ``family``'s test size."""
+    cfg = get_config(family)
+    params = family_for(cfg).init_params(cfg, jax.random.PRNGKey(0),
+                                         dtype=jnp.float32)
+    sched = BatchScheduler(params, cfg, ByteTokenizer(
+        vocab_size=cfg.vocab_size), num_slots=2, max_seq=128,
+        prefill_chunk=32)
+    try:
+        shapes = lambda tree: jax.tree.map(   # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+        packed = jax.ShapeDtypeStruct((1, sched_mod._admit_layout(
+            sched._cache.max_pages_per_row)[-1] + 128), jnp.int32)
+        carried = [jax.eval_shape(lambda: KVCache.create(
+            cfg, 1, 128, dtype=sched._dtype)),
+            jax.eval_shape(lambda: sched._chunk_logits0(1))]
+        installs = shapes([sched._keys, sched._next_dev, sched._temps_dev,
+                           sched._top_ks_dev, sched._top_ps_dev,
+                           sched._ring_dev, sched._rps_dev])
+        texts = {}
+        for off in (0, 64, 96):
+            args = [shapes(sched._params), packed, *(carried if off else ()),
+                    shapes(sched._cache), *(installs if off == 96 else ())]
+            text = sched._prefill_chunk_for(0, 128, off, 32).lower(
+                *args).as_text()
+            # Where the buffer sits among @main's arguments (jit drops
+            # those the program never reads): the one of its shape.
+            at, = re.findall(
+                r"%%arg(\d+): tensor<1x%dxi32>" % packed.shape[1], text)
+            texts[off] = (text, int(at))
+    finally:
+        sched.stop()
+    return texts
+
+
+def _case_predicate_arguments(text: str) -> list[set]:
+    """For every ``stablehlo.case`` of ``text``: the arguments of its
+    function that its index is computed from, by walking the SSA
+    definitions back (a call's result depends on all its operands)."""
+    found = []
+    for body in re.split(r"\n\s*func\.func ", text)[1:]:
+        defs = {}
+        for line in body.splitlines():
+            m = re.match(r"\s*(%[\w#]+)(?::\d+)? = (.*)", line)
+            if m:
+                defs[m.group(1)] = set(re.findall(r"%[\w]+", m.group(2)))
+        for m in re.finditer(r'"?stablehlo\.case"?\((%\w+)\)', body):
+            seen, todo = set(), [m.group(1)]
+            while todo:
+                name = todo.pop()
+                if name not in seen:
+                    seen.add(name)
+                    todo.extend(defs.get(name, ()))
+            found.append({int(n[4:]) for n in seen if n.startswith("%arg")})
+    return found
+
+
+@pytest.mark.parametrize("family", ["tiny", "tiny-mellum2"])
+def test_a_chunk_behind_the_first_runs_under_one_cond_on_the_buffer(family):
+    """``mid`` and ``final``: exactly one ``stablehlo.case``, and what
+    decides it is computed from the packed admission buffer and from
+    nothing else (no weight, no carry: the scheduler's arithmetic over
+    the entries' lengths and rows). ``first`` always holds a real
+    position and has none."""
+    texts = _chunk_program_texts(family)
+    assert "stablehlo.case" not in texts[0][0]
+    for off in (64, 96):
+        text, packed_at = texts[off]
+        assert _case_predicate_arguments(text) == [{packed_at}], off
+
+
+def test_the_hybrid_familys_chunk_has_no_cond_of_its_own():
+    """The test of padding is the scheduler's, for every family: the
+    family that had it first (PR 49, for indexed models) has none."""
+    with open(os.path.join(
+            ROOT, "p2p_llm_chat_tpu/models/nemotron_h.py")) as f:
+        tree = ast.parse(f.read())
+    fn = next(n for n in tree.body if isinstance(n, ast.FunctionDef)
+              and n.name == "prefill_chunk_counted")
+    names = {n.attr for n in ast.walk(fn) if isinstance(n, ast.Attribute)}
+    assert not names & {"is_indexed", "cond", "eval_shape"}, names

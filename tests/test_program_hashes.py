@@ -162,7 +162,18 @@ PR49 = {
     "tiny-keye.decode_step_paged": "1e35cb01f87bb551aa2762e043c8055de1b7d78d0619b1b3c24ec66b768c61c9",
     "tiny-keye.prefill_chunk_counted": "dbbd82669f6c94fca49ad9d2cb505ec12e4edb1f6b5a8ec35aee601ad5021f25"
 }
-PINNED = {**PARENT, **PR42, **PR43, **PR45, **PR49}
+# Taken on PR 50 itself (tools/hash_programs.py under this suite's
+# conftest): the one digest that PR moved. PR 49 had wrapped an indexed
+# model's ``_counted`` chunk in a ``lax.cond`` on whether any position
+# of it was real; PR 50 put that test into the scheduler's chunk program
+# for every family (serve/scheduler.py ``_unless_padding``) and took it
+# out of the model, whose chunk is the plain forward again (on PR 50's
+# parent it read dbbd82669f6c). The other 45 digests held on that tree:
+# no other model-level program changed its text.
+PR50 = {
+    "tiny-keye.prefill_chunk_counted": "0f536a992de348d8d043c152111cf2f6314dfb0e23418a5096045e5515ab2517"
+}
+PINNED = {**PARENT, **PR42, **PR43, **PR45, **PR49, **PR50}
 
 
 @pytest.fixture(scope="module")

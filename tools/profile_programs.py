@@ -6,7 +6,10 @@ does: the configuration file, its stack, random weights, ``SERVE_WARMUP=0``,
 no HTTP), calls the family's ``prefill_chunk_counted`` on a one-row carry
 of ``SERVE_MAX_SEQ`` positions at each ``--offsets`` and its
 ``decode_step_paged`` at each ``--windows`` over ``--rows`` live rows of
-``--context`` positions in the scheduler's own pool, each under
+``--context`` positions in the scheduler's own pool, and the
+scheduler's own ``mid`` chunk program on a ladder of dummy entries at each
+``--padded`` offset (what a chunk past every row's prompt costs: since
+PR 50 a launch, before it the whole forward), each under
 ``jax.profiler``, and prints the forty operations that took most self
 time (``benchmark/trace_reduce.reduce``). Two minutes a call for a
 routed hybrid model; a cell's traced run keeps ten operations of a 4 s
@@ -45,6 +48,9 @@ def main() -> None:
     ap.add_argument("config_file")
     ap.add_argument("--offsets", default="1024,8192")
     ap.add_argument("--windows", default="16384")
+    ap.add_argument("--padded", default="",
+                    help="offsets of the top bucket's ladder at which to "
+                    "run the scheduler's chunk program on padding alone")
     ap.add_argument("--rows", type=int, default=14)
     ap.add_argument("--context", type=int, default=9300)
     args = ap.parse_args()
@@ -122,6 +128,24 @@ def main() -> None:
                 last_idx=jnp.zeros((1,), jnp.int32))[0]))
         traced(f"chunk_at_{off}", lambda: chunk(params, toks, valid, carry),
                2, C, C * off + C * (C + 1) / 2, off + C)
+    S = sched.max_seq
+    for off in map(int, filter(None, args.padded.split(","))):
+        if not 0 < off < S - C or off % C:
+            raise SystemExit(f"--padded {off}: not a mid chunk of a ladder "
+                             f"of {C} to {S}")
+        # The loop is idle (no warm-up, no HTTP): its dispatch, called
+        # from here, on the buffer a warm-up job would build. The carry
+        # is donated, so each call takes the last one's.
+        packed = sched._admit_upload(
+            sched._admit_host_arrays([], [], S, 1, None), live=False)
+        held = [KVCache.create(config, 1, S, dtype=sched._dtype),
+                sched._chunk_logits0(1)]
+
+        def padded(off=off, packed=packed, held=held):
+            held[0], held[1], _ = sched._dispatch_prefill_chunk(
+                0, S, off, C, packed, held[0], held[1], None)
+            return sched._last_out
+        traced(f"padded_chunk_at_{off}", padded, 4, 0, 0, 0)
     cache = sched._cache
     ps = sched.page_size
     per_row = -(-(args.context + 1) // ps)
