@@ -55,6 +55,9 @@ _PHASE_PREFIXES = (
     ("sched.queue_wait", "queue_wait"),
     ("sched.prefill", "prefill"),
     ("sched.wake", "wake"),
+    # A summed share of sched.decode's own wall (the scheduler's
+    # interval ledger), not a phase beside it.
+    ("sched.decode.cut", None),
     ("sched.decode", "decode"),
     ("disagg.", "handoff"),
     ("router.route", "route"),

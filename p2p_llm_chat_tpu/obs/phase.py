@@ -249,17 +249,21 @@ class LoopPhases:
 class CompileClock:
     """Seconds this process has spent compiling (or fetching compiled
     programs from the persistent cache), summed from the durations JAX
-    reports through ``jax.monitoring``. JAX's listener registry is
-    process-wide, and so is this quantity: :func:`compile_clock` hands
-    out the one instance."""
+    reports through ``jax.monitoring``, and how many such durations it
+    has heard. It goes on counting after the server is ready: a compile
+    inside serving is what a run most needs to hear of. JAX's listener
+    registry is process-wide, and so are these quantities:
+    :func:`compile_clock` hands out the one instance."""
 
     def __init__(self) -> None:
         self.seconds = 0.0
+        self.events = 0
         jax.monitoring.register_event_duration_secs_listener(self._heard)
 
     def _heard(self, event: str, duration: float, **_kw) -> None:
         if event in _COMPILE_EVENTS:
             self.seconds += duration
+            self.events += 1
 
 
 _CLOCK: Optional[CompileClock] = None

@@ -60,6 +60,7 @@ from ..models.layers import causal_mask
 from ..models.llama import KVCache
 from ..models.sampling import sample_batched, sample_step_batched
 from ..obs.flight import FlightRecorder
+from ..obs.intervals import ADMIT, CHUNK, CLEAN, PADDED, IntervalLedger
 from ..obs.phase import LoopPhases, compile_clock, process_age_s
 from ..ops.paged_attention import flash_append_chunk_pages
 from ..ops.paged_kv import (IndexedPast, PageAllocator, PagedKVCache,
@@ -241,6 +242,10 @@ class _Slot:
     # and prefill (here -> install) for the sched.* spans. 0 = never
     # dispatched (the spans fall back to the install stamp).
     admit_t: float = 0.0
+    # The interval ledger's totals at this request's first token
+    # (IntervalLedger.totals), for the differences _release records on
+    # sched.decode and sched.decode.cut. None for an unsampled request.
+    cut0: Optional[tuple] = None
     # Admission-queue depth accounting (overload shedding): on_depart
     # fires exactly once, at the earlier of batch-row install or any
     # terminal outcome — the depth gauge must count submitted-but-not-
@@ -311,6 +316,7 @@ class _PrefillCarry:
     packed: object                 # the admission's packed buffer, on the
     # device since the admission (_admit_layout): every chunk program
     # takes it whole and slices its own tokens, so no chunk uploads
+    padded: int = 0                # chunks dispatched past every row's suffix
 
 
 class _SlotStream:
@@ -602,7 +608,9 @@ class BatchScheduler:
         # Boot gauges (serve_boot_*): set once each, at the end of this
         # constructor and when a warm-up finishes. The compile clock is
         # the process's own (started here if the entry point has not).
-        compile_clock()
+        clock = compile_clock()
+        # (events, seconds) of it as the loop last looked (_watchdog).
+        self._compiles_heard = (clock.events, clock.seconds)  # owned-by: _loop
         self._boot_load_s = 0.0
         self._boot_warmup_s = 0.0
         self._boot_compile_s = 0.0
@@ -785,8 +793,8 @@ class BatchScheduler:
         # started and the rows their programs were wide (R, dummy
         # entries included), prompt positions that had to be computed
         # against the positions the padded programs computed, live rows
-        # x steps per decode dispatch, and the decode dispatch intervals
-        # no admission work cut into (_note_clean_interval).
+        # x steps per decode dispatch. (The decode dispatch intervals
+        # are the ledger's, below.)
         self._n_admit_batches = 0
         self._n_admit_uploads = 0
         # Dispatches, single-shot or chunk, that carried more than one
@@ -872,10 +880,6 @@ class BatchScheduler:
             if config.is_hybrid and not self._shared_kv_readers else 0)
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
-        self._clean_s = 0.0
-        self._clean_steps = 0
-        self._clean_hold = 0          # dispatches still to skip after admission work
-        self._prev_k = 0              # K of the dispatch before _last_dispatch (0: none)
         # Shared-prefix KV cache (serve/prefix.py): prompt-head matches
         # skip recomputing the prefix at admission. Ladder grains that
         # could never pass the admission budget guard (P + smallest
@@ -945,9 +949,12 @@ class BatchScheduler:
         self._n_decode_steps = 0      # owned-by: _loop — decode steps across plain dispatches
         self._n_spec_ticks = 0        # owned-by: _loop — speculative dispatches (no K;
                                       # they must not dilute the realized mean)
-        self._last_dispatch: Optional[tuple[float, int]] = None  # owned-by: _loop
-        from ..utils.metrics import Histogram
-        self._wall_hist = Histogram("decode_wall_ms")
+        # Every decode dispatch-to-dispatch interval, booked to what cut
+        # into it (obs/intervals.py): the admission sites note their
+        # class (cut), the two token-emitting dispatches close the
+        # interval (note). decode_stall_ms, decode_wall_ms and the
+        # serve_decode_{clean,cut_*}_* counters are its outputs.
+        self._ledger = IntervalLedger()   # owned-by: _loop
         self._decode_device_ms = 0.0  # measured once at warmup (probe)
         # Chunked prefill (tentpole of the admission-stall work): prompts
         # whose bucket exceeds this budget admit in fixed chunks the loop
@@ -968,9 +975,6 @@ class BatchScheduler:
         # ... of which those past every row's suffix: the chunk programs
         # compute nothing there (_make_prefill_chunk_program._fwd).
         self._n_prefill_chunks_padded = 0     # owned-by: _loop
-        self._admit_since_tick = False  # owned-by: _loop — admission work since last decode dispatch
-        self._last_decode_t: Optional[float] = None  # owned-by: _loop
-        self._decode_stall_ms = 0.0   # owned-by: _loop — max decode gap attributable to admission
         # reset_decode_stall handshake: req set by the caller, serviced
         # (and ack'd) by _loop at the top of every iteration.
         self._stall_reset_req = threading.Event()
@@ -986,6 +990,7 @@ class BatchScheduler:
         self._park_all_req = threading.Event()
         self._park_all_ack = threading.Event()
         self._park_all_key: Optional[str] = None
+        from ..utils.metrics import Histogram
         self._tbt_hist = Histogram("inter_token_ms")
         # Multi-tier KV (serve/kv_tier.py): host-RAM session parking.
         # All tier state transitions run on the scheduler thread (they
@@ -2961,7 +2966,7 @@ class BatchScheduler:
                         # this gap — a cold admission after idle time would
                         # otherwise book the whole idle stretch as
                         # decode_stall_ms (it stalled nobody).
-                        self._last_decode_t = None
+                        self._ledger.rest()
                         if pending is not None:
                             self._process_tick(*pending)
                             pending = None
@@ -3035,7 +3040,22 @@ class BatchScheduler:
         loop's, and an iteration that is over budget only because of
         them enters no episode, dumps nothing and leaves the gauges at
         0 = never stalled. Once ready, a job's time counts like any
-        other (a background warm-up then stalls live streams)."""
+        other (a background warm-up then stalls live streams).
+
+        A compile heard during an iteration that began after a warm-up
+        had finished (the process's compile clock counted on: a program
+        the warm-up list missed, a promotion's build) leaves a
+        ``compile`` event in the flight ring first, so the dump of a
+        stalled iteration names it."""
+        clock = compile_clock()
+        heard, heard_s = self._compiles_heard
+        if clock.events != heard:
+            now_s = clock.seconds
+            if self._warmup_done_at and self._warmup_done_at < it_start:
+                self._flight.note("compile", self._loop_iter,
+                                  n=clock.events - heard,
+                                  seconds=round(now_s - heard_s, 3))
+            self._compiles_heard = (clock.events, now_s)
         budget = self.loop_budget_ms
         if not budget:
             return
@@ -3243,8 +3263,7 @@ class BatchScheduler:
         run)."""
         if self._stall_reset_req.is_set():
             self._stall_reset_req.clear()
-            self._decode_stall_ms = 0.0
-            self._last_decode_t = None
+            self._ledger.reset_stall()
             self._stall_reset_ack.set()
 
     def park_all(self, timeout_s: float = 30.0,
@@ -3485,7 +3504,7 @@ class BatchScheduler:
         """Serving-plane gauges/counters for the /metrics endpoint (read
         from any thread; values are monotonically-written ints and
         len()s, so torn reads are harmless)."""
-        ph = self._phase
+        ph, led, clock = self._phase, self._ledger, compile_clock()
         out = {
             "serve_batch_occupancy": sum(s is not None for s in self._slots),
             "serve_batch_slots": self.num_slots,
@@ -3539,8 +3558,8 @@ class BatchScheduler:
             # Wall vs device decode step: wall is the live p50 of
             # steady-state per-step dispatch intervals; device is the
             # warmup probe's two-point solve (_probe_device_step).
-            "decode_wall_ms": round(self._wall_hist.percentile(50) or 0.0,
-                                    4),
+            "decode_wall_ms": round(
+                led.wall_hist.percentile(50) or 0.0, 4),
             "decode_device_ms": self._decode_device_ms,
             # Chunked prefill (SERVE_PREFILL_CHUNK): continuation-chunk
             # dispatches, the max decode-tick gap attributable to
@@ -3553,7 +3572,7 @@ class BatchScheduler:
             # bucket) and computing nothing.
             "serve_prefill_chunks_padded_total":
                 self._n_prefill_chunks_padded,
-            "decode_stall_ms": round(self._decode_stall_ms, 3),
+            "decode_stall_ms": round(led.stall_ms, 3),
             "inter_token_p50_ms": round(
                 self._tbt_hist.percentile(50) or 0.0, 4),
             "inter_token_p95_ms": round(
@@ -3724,9 +3743,7 @@ class BatchScheduler:
             # of rows that were dummy entries), prompt positions that
             # had to be computed against the positions the padded
             # programs computed, live rows x steps over the decode
-            # dispatches, and the decode dispatch intervals that no
-            # admission work cut into with the steps they held
-            # (_note_clean_interval).
+            # dispatches.
             "serve_admit_batches_total": self._n_admit_batches,
             # Host-to-device transfers the admission path issued: one
             # an admission, none for a ladder's chunks (over batches +
@@ -3747,8 +3764,19 @@ class BatchScheduler:
             "serve_attn_context_tokens_total": self._n_attn_ctx_tokens,
             "serve_attn_chunks_total": self._n_attn_chunks,
             "serve_attn_chunks_walked_total": self._n_attn_chunks_walked,
-            "serve_decode_clean_seconds_total": self._clean_s,
-            "serve_decode_clean_steps_total": self._clean_steps,
+            # The interval ledger (obs/intervals.py): every decode
+            # dispatch interval under 0.25 s, with the steps of the
+            # dispatch it waited for, booked to clean or to the dearest
+            # admission work noted in its iteration or the two before.
+            "serve_decode_clean_seconds_total": led.seconds[CLEAN],
+            "serve_decode_clean_steps_total": led.steps[CLEAN],
+            "serve_decode_clean_intervals_total": led.intervals[CLEAN],
+            "serve_decode_cut_chunk_seconds_total": led.seconds[CHUNK],
+            "serve_decode_cut_chunk_steps_total": led.steps[CHUNK],
+            "serve_decode_cut_padded_seconds_total": led.seconds[PADDED],
+            "serve_decode_cut_padded_steps_total": led.steps[PADDED],
+            "serve_decode_cut_admit_seconds_total": led.seconds[ADMIT],
+            "serve_decode_cut_admit_steps_total": led.steps[ADMIT],
             # Boot, set once: process start (the OS's record) until
             # this scheduler was built; warmup() entry to its last job;
             # the seconds of compilation and cache retrieval JAX
@@ -3757,6 +3785,12 @@ class BatchScheduler:
             "serve_boot_warmup_seconds": round(self._boot_warmup_s, 3),
             "serve_boot_compile_seconds": round(self._boot_compile_s, 3),
             "serve_boot_programs_total": self._n_warmup_jobs,
+            # ... and the same clock's running totals, which go on
+            # after ready: a window's difference is the compilation
+            # that landed on serving (0 where warm-up covered every
+            # program), and the count says how many programs it was.
+            "serve_compile_seconds_total": round(clock.seconds, 3),
+            "serve_compiles_total": clock.events,
         }
         if self.config.is_moe:
             # Routed models only: of the layers x experts each decode
@@ -4363,7 +4397,7 @@ class BatchScheduler:
         P = prefix.length if prefix is not None else 0
         with self._phase("build"):
             packed = self._admit_host_arrays(chunk, rows, S, R, prefix)
-        self._admit_since_tick = True
+        self._ledger.cut(ADMIT)
         if chunk:       # warm-up's all-padding dispatches do not count
             self._n_admit_batches += 1
             self._n_admit_pair_dispatches += len(chunk) > 1
@@ -4457,11 +4491,12 @@ class BatchScheduler:
             return jax.device_put(packed, self._packed_sharding)
 
     def _install_admitted(self, chunk: list[_Slot], rows: list[int],
-                          toks_dev, dispatches: int = 1) -> None:
+                          toks_dev,
+                          ladder: Optional[_PrefillCarry] = None) -> None:
         """Admission epilogue shared by the single-shot program and the
-        final prefill chunk (of a ladder of ``dispatches`` chunks): read
-        the first tokens back, install the slots, stream/stop-check each
-        first token."""
+        final prefill chunk (of ``ladder``): read the first tokens back,
+        install the slots, stream/stop-check each first token."""
+        dispatches = ladder.S // ladder.C if ladder is not None else 1
         with self._phase("readback", rows=len(chunk)):
             # graftcheck: sync-ok intentional: R int32 first tokens, TTFT depends on it
             first_toks = np.asarray(toks_dev)
@@ -4504,6 +4539,14 @@ class BatchScheduler:
             if chunk:
                 self._flight.note("admit", self._loop_iter, n=len(chunk))
             tr = self._trace
+            # A ladder's side of a traced request, in the interval
+            # ledger's words: the chunks that ran their forward, those
+            # past every row's prompt, its bucket, the requests it
+            # carried.
+            laddered = ({} if ladder is None or tr is None else
+                        {"chunks": dispatches - ladder.padded,
+                         "padded": ladder.padded, "bucket": ladder.S,
+                         "shared": len(chunk)})
             for i, (slot, row) in enumerate(zip(chunk, rows)):
                 slot.depart()                # reached a batch row: not queued
                 if slot.stats is not None:
@@ -4518,7 +4561,8 @@ class BatchScheduler:
                            t_admit - slot.req.arrival_time)
                     tr.add(slot.req.trace_id, "sched.prefill", t_admit,
                            now - t_admit, tokens=len(slot.prompt_ids),
-                           row=row)
+                           row=row, **laddered)
+                    slot.cut0 = self._ledger.totals()
                 slot.ctx_len = len(slot.prompt_ids)
                 # last_emit_t stays 0 until _append_token below sets it: the
                 # first token's latency is TTFT, not an inter-token gap — a
@@ -4575,7 +4619,8 @@ class BatchScheduler:
         self._n_prefill_chunks_padded += padded
         self._n_admit_pair_dispatches += len(pc.chunk) > 1
         self._n_prefill_padded += R * C
-        self._admit_since_tick = True
+        pc.padded += padded
+        self._ledger.cut(PADDED if padded else CHUNK)
         self._flight.note("prefill_chunk", self._loop_iter,
                           off=off, C=C, S=pc.S, n=len(pc.chunk))
         with self._phase("prefill_chunk", R=R, S=pc.S, C=C, off=off,
@@ -4591,8 +4636,7 @@ class BatchScheduler:
                 self._n_prefix_tokens += P0 * len(pc.chunk)
                 if pc.prefix.state is not None:
                     self._n_state_snapshots += len(pc.chunk)
-            self._install_admitted(pc.chunk, pc.rows, toks_dev,
-                                   dispatches=pc.S // C)
+            self._install_admitted(pc.chunk, pc.rows, toks_dev, ladder=pc)
 
     def _dispatch_prefill_chunk(self, P0: int, S: int, off: int, C: int,
                                 packed, kv, logits, prefix) -> tuple:
@@ -4638,46 +4682,6 @@ class BatchScheduler:
         self._last_out = jax.tree.leaves(logits)[0]
         return kv, logits, None
 
-    # graftcheck: runs-on _loop
-    def _note_admission_gap(self, now: float) -> None:
-        """Advance the decode_stall_ms tracker at a token-emitting
-        dispatch (decode tick or spec tick): the dispatch-to-dispatch
-        interval across an iteration that did admission work
-        (single-shot prefill or a continuation chunk) is the stall
-        clients saw. With chunking on this is bounded by one chunk's
-        compute — the number the tentpole exists to shrink
-        (pre-chunking, a 512-token admission put its WHOLE prefill in
-        this gap)."""
-        if self._last_decode_t is not None and self._admit_since_tick:
-            gap = (now - self._last_decode_t) * 1e3
-            if gap > self._decode_stall_ms:
-                self._decode_stall_ms = gap
-        self._last_decode_t = now
-        self._admit_since_tick = False
-
-    # graftcheck: runs-on _loop
-    def _note_clean_interval(self, now: float, admitted: bool) -> None:
-        """_wall_hist's sample as a pair of window counters, without
-        the intervals admission work cut into. Under the one-tick-deep
-        pipeline the loop dispatches tick m as soon as tick m-2 has been
-        read back, so the interval that ends at dispatch m is the device
-        time of tick m-2 plus whatever else was queued before it, and
-        its steps are tick m-2's K. An admission dispatched in iteration
-        j therefore lands in the interval ending at dispatch j+2 (a
-        chunk, which nothing waits for), or shortens the one ending at
-        j+1 (a single-shot admission's first-token read drains the
-        pipeline): the two intervals after a flagged one are skipped
-        too."""
-        last = self._last_dispatch      # (time, K) of tick m-1, or None
-        if admitted:
-            self._clean_hold = 2
-        elif self._clean_hold:
-            self._clean_hold -= 1
-        elif last is not None and self._prev_k and now - last[0] < 0.25:
-            self._clean_s += now - last[0]
-            self._clean_steps += self._prev_k
-        self._prev_k = last[1] if last is not None else 0
-
     def _dispatch_tick(self, allow_fuse: bool = True,
                        inflight: int = 0) -> tuple:
         """Dispatch one batched decode tick (async — returns without a
@@ -4711,19 +4715,7 @@ class BatchScheduler:
         if K > 1:
             self._n_fused_ticks += 1
             self._n_fused_steps += K
-        now = time.monotonic()
-        admitted = self._admit_since_tick    # cleared by the next call
-        self._note_admission_gap(now)
-        if (self._last_dispatch is not None
-                and now - self._last_dispatch[0] < 0.25):
-            # Steady-state per-STEP wall: the interval between dispatches
-            # spans the previous tick's host drain + whatever device time
-            # the pipeline couldn't hide, over that tick's K steps. Idle
-            # gaps (> 250 ms) are load valleys, not decode wall.
-            self._wall_hist.observe(
-                (now - self._last_dispatch[0]) * 1e3 / self._last_dispatch[1])
-        self._note_clean_interval(now, admitted)
-        self._last_dispatch = (now, K)
+        self._ledger.note(time.monotonic(), K)
         active = tuple(s is not None for s in self._slots)
         live_steps = sum(active) * K
         self._n_decode_row_steps += live_steps
@@ -5050,11 +5042,11 @@ class BatchScheduler:
         # counts a speculative dispatch.
         self._n_decode_row_steps += sum(
             s is not None for s in self._slots)
-        self._last_dispatch = None    # spec wall is not decode-step wall
         # A spec tick emits tokens like a decode tick: book any pending
         # admission gap against it (the chunk's compute delayed THIS
-        # tick's emissions too), then restart the interval.
-        self._note_admission_gap(time.monotonic())
+        # tick's emissions too), then restart the interval. K = 0: spec
+        # wall is not decode-step wall.
+        self._ledger.note(time.monotonic(), 0)
         active = tuple(s is not None for s in self._slots)
         if active != self._active_host:
             self._active_host = active
@@ -5583,7 +5575,7 @@ class BatchScheduler:
             live.append((slot, row))
         if not live:
             return demoted, unused
-        self._admit_since_tick = True
+        self._ledger.cut(ADMIT)
         # A wake is an admission whose program runs every row at the
         # suffix bucket: B x S positions for the waking rows' suffixes.
         self._n_admit_batches += 1
@@ -5641,6 +5633,7 @@ class BatchScheduler:
                            slot.req.arrival_time, t0 - slot.req.arrival_time)
                     tr.add(slot.req.trace_id, "sched.wake", t0, now - t0,
                            tokens_saved=int(ints[1, row]), row=row)
+                    slot.cut0 = self._ledger.totals()
                 slot.ctx_len = len(slot.prompt_ids)
                 self._slots[row] = slot
                 if not self._append_token(slot, row, int(first_toks[row])):
@@ -5660,11 +5653,27 @@ class BatchScheduler:
                 and slot.stats.ttft_s is not None):
             # Decode phase: first token -> release (per-tick gaps are
             # the inter_token_ms histogram's job; the span carries the
-            # request's share of the decode wall).
+            # request's share of the decode wall), and beside it the
+            # request's own differences of the interval ledger: the
+            # decode steps booked while it decoded, those in episodes
+            # admission work opened, that work's dispatches by class,
+            # and as the sibling span's duration the SUM of those
+            # episodes' intervals (not one stretch of time: it shares
+            # sched.decode's start and never passes its length). The
+            # ledger books an interval two dispatches after its work
+            # was queued, so the differences are off by at most two
+            # intervals at each end (obs/intervals.py).
             t_first = slot.req.arrival_time + slot.stats.ttft_s
+            wall = time.monotonic() - t_first
+            end = self._ledger.totals()
+            cut_s, steps, steps_cut, chunks, padded, admits = (
+                b - a for a, b in zip(slot.cut0 or end, end))
             self._trace.add(slot.req.trace_id, "sched.decode", t_first,
-                            time.monotonic() - t_first,
-                            tokens=len(slot.ids), row=row)
+                            wall, tokens=len(slot.ids), row=row,
+                            steps=steps, steps_cut=steps_cut, chunks=chunks,
+                            padded=padded, admits=admits)
+            self._trace.add(slot.req.trace_id, "sched.decode.cut", t_first,
+                            min(cut_s, wall))
         for s in self._sources:
             s.release(row)
         if slot is not None and self._tier is not None:
