@@ -880,6 +880,7 @@ class BatchScheduler:
             if config.is_hybrid and not self._shared_kv_readers else 0)
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
+        self._n_decode_sort_dispatches = 0
         # Shared-prefix KV cache (serve/prefix.py): prompt-head matches
         # skip recomputing the prefix at admission. Ladder grains that
         # could never pass the admission budget guard (P + smallest
@@ -3761,6 +3762,8 @@ class BatchScheduler:
             "serve_moe_assignments_total": self._n_moe_assigned,
             "serve_moe_dropped_total": self._n_moe_dropped,
             "serve_decode_row_steps_total": self._n_decode_row_steps,
+            "serve_decode_sort_dispatches_total":
+                self._n_decode_sort_dispatches,
             "serve_attn_context_tokens_total": self._n_attn_ctx_tokens,
             "serve_attn_chunks_total": self._n_attn_chunks,
             "serve_attn_chunks_walked_total": self._n_attn_chunks_walked,
@@ -4719,6 +4722,13 @@ class BatchScheduler:
         active = tuple(s is not None for s in self._slots)
         live_steps = sum(active) * K
         self._n_decode_row_steps += live_steps
+        # The sampler's own test, on the host: a live row that is not
+        # greedy takes the step through the candidate sort
+        # (models/sampling.sample_batched). An upper bound for a fused
+        # dispatch, whose sampling row may stop before its last step.
+        self._n_decode_sort_dispatches += any(
+            s is not None and not s.req.options.temperature <= 0.0
+            for s in self._slots)
         if self._cache.state is not None:
             # The kernel reads and writes a live row's state and nothing
             # of the others'; XLA's update is one program over every

@@ -209,6 +209,28 @@ def test_sampling_with_seed_is_reproducible(engine):
     assert a == b
 
 
+def test_only_a_dispatch_that_carries_a_sampling_row_counts_as_a_sort(engine):
+    """``serve_decode_sort_dispatches_total`` is the sampler's own test
+    made on the host: it stands still under greedy requests, moves with
+    a request at temperature 0.8 (at most once a decode dispatch), and
+    stands still again once that request's slot is released, although
+    the slot keeps 0.8 on the device until an admission writes over it;
+    the greedy request behind it still reads the oracle's tokens."""
+    sort, ticks = "serve_decode_sort_dispatches_total", \
+        "serve_decode_ticks_total"
+    m0 = engine.metrics_snapshot()
+    run(engine, "greedy first", max_tokens=8)
+    m1 = engine.metrics_snapshot()
+    assert m1[sort] == m0[sort] and m1[ticks] > m0[ticks]
+    run(engine, "sampled", max_tokens=8, temperature=0.8, seed=7)
+    m2 = engine.metrics_snapshot()
+    assert 0 < m2[sort] - m1[sort] <= m2[ticks] - m1[ticks]
+    text, _ = run(engine, "greedy again", max_tokens=8)
+    m3 = engine.metrics_snapshot()
+    assert m3[sort] == m2[sort] and m3[ticks] > m2[ticks]
+    assert text == want(engine, "greedy again", 8)
+
+
 def test_paged_pool_exhaustion_backpressures_then_completes():
     """A pool too small for all concurrent requests must queue the
     overflow (FIFO page backpressure), admit it as pages free, and still

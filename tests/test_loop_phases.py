@@ -754,16 +754,18 @@ def _case_predicate_arguments(text: str) -> list[set]:
 
 @pytest.mark.parametrize("family", ["tiny", "tiny-mellum2"])
 def test_a_chunk_behind_the_first_runs_under_one_cond_on_the_buffer(family):
-    """``mid`` and ``final``: exactly one ``stablehlo.case``, and what
-    decides it is computed from the packed admission buffer and from
-    nothing else (no weight, no carry: the scheduler's arithmetic over
-    the entries' lengths and rows). ``first`` always holds a real
-    position and has none."""
+    """``mid`` and ``final``: exactly one ``stablehlo.case`` around the
+    forward, and what decides it is computed from the packed admission
+    buffer and from nothing else (no weight, no carry: the scheduler's
+    arithmetic over the entries' lengths and rows). ``first`` always
+    holds a real position and has none. ``final`` holds one more, the
+    sampler's around its candidate sort (models/sampling.sample_batched,
+    PR 52), decided by the entries' temperatures: the buffer again."""
     texts = _chunk_program_texts(family)
     assert "stablehlo.case" not in texts[0][0]
-    for off in (64, 96):
+    for off, conds in ((64, 1), (96, 2)):
         text, packed_at = texts[off]
-        assert _case_predicate_arguments(text) == [{packed_at}], off
+        assert _case_predicate_arguments(text) == [{packed_at}] * conds, off
 
 
 def test_the_hybrid_familys_chunk_has_no_cond_of_its_own():

@@ -187,3 +187,121 @@ def test_apply_repeat_penalty_matches_numpy_twin():
             want[t] = want[t] / rp[b] if want[t] > 0 else want[t] * rp[b]
         np.testing.assert_allclose(got[b], want.astype(np.float32),
                                    rtol=1e-6, atol=1e-6)
+
+
+# -- sample_batched: the candidate sort runs only where a live row samples --
+
+def _sample_batched_before_the_cond(logits, keys, temperature, top_k, top_p,
+                                    top_c=64, ring=None, rp=None):
+    """``sample_batched`` as it stood before the sort went under a
+    ``cond`` (PR 52), kept here as the oracle: every row sorted, warped
+    and drawn from, and the choice made per element after the work."""
+    from p2p_llm_chat_tpu.models.layers import NEG_INF
+    from p2p_llm_chat_tpu.models.sampling import _warp, apply_repeat_penalty
+    B, V = logits.shape
+    if ring is not None:
+        logits = apply_repeat_penalty(logits, ring, rp)
+    C = min(top_c, V)
+    sorted_logits, order = jax.lax.top_k(logits, C)
+    wprobs = _warp(sorted_logits, temperature, top_k, top_p)
+    split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+    new_keys, subs = split[:, 0], split[:, 1]
+    choice = jax.vmap(jax.random.categorical)(
+        subs, jnp.where(wprobs > 0, jnp.log(wprobs), NEG_INF))
+    sampled = jnp.take_along_axis(order, choice[:, None], axis=-1)[:, 0]
+    tok = jnp.where(temperature <= 0.0,
+                    jnp.argmax(logits, axis=-1), sampled).astype(jnp.int32)
+    return tok, new_keys
+
+
+_TEMPS = {"none-samples": [0.0, 0.0, 0.0, 0.0, 0.0, 0.0],
+          "some-sample": [0.0, 0.8, 0.0, 1.3, 0.0, 0.0],
+          "all-sample": [0.8, 0.8, 1.0, 1.3, 0.7, 0.5]}
+
+
+@pytest.mark.parametrize("dead_row", [False, True],
+                         ids=["all-live", "dead-row-at-0.8"])
+@pytest.mark.parametrize("penalty", [False, True],
+                         ids=["no-penalty", "penalty"])
+@pytest.mark.parametrize("case", sorted(_TEMPS))
+def test_batched_tokens_and_keys_are_the_unconditional_sampler_s(
+        case, penalty, dead_row):
+    """Tokens of the live rows and every row's advanced key, bit for
+    bit, over three steps of carried keys, whether the step's ``cond``
+    took the sort or the argmax alone. The dead row (a released slot
+    whose last request sampled at 0.8) is masked out by ``live``: it must
+    not force the sort, its key still splits, and nobody reads its
+    token."""
+    from p2p_llm_chat_tpu.models.sampling import sample_batched
+    B, V, R = 6, 300, 8
+    rng = np.random.default_rng(52)
+    temps = np.asarray(_TEMPS[case], np.float32)
+    live = np.ones(B, bool)
+    if dead_row:
+        temps[5], live[5] = 0.8, False
+    top_k = jnp.asarray([0, 40, 0, 5, 0, 40], jnp.int32)
+    top_p = jnp.asarray([1.0, 0.9, 1.0, 0.5, 1.0, 0.9], jnp.float32)
+    kw = {}
+    if penalty:
+        ring = np.full((B, R), V, np.int32)
+        ring[:, :4] = rng.integers(0, V, size=(B, 4))
+        kw = dict(ring=jnp.asarray(ring),
+                  rp=jnp.asarray([1.3, 1.1, 1.0, 2.0, 1.5, 1.2], jnp.float32))
+    new = jax.jit(lambda lg, k: sample_batched(
+        lg, k, jnp.asarray(temps), top_k, top_p,
+        live=jnp.asarray(live) if dead_row else None, **kw))
+    old = jax.jit(lambda lg, k: _sample_batched_before_the_cond(
+        lg, k, jnp.asarray(temps), top_k, top_p, **kw))
+    keys_new = keys_old = _keys(B, seed=11)
+    for _ in range(3):
+        lg = jnp.asarray(rng.normal(size=(B, V)).astype(np.float32) * 3.0)
+        toks_new, keys_new = new(lg, keys_new)
+        toks_old, keys_old = old(lg, keys_old)
+        assert toks_new.dtype == toks_old.dtype == jnp.int32
+        assert np.array_equal(np.asarray(toks_new)[live],
+                              np.asarray(toks_old)[live])
+        assert np.array_equal(np.asarray(keys_new), np.asarray(keys_old))
+
+
+def _sorts_by_case_branch(module) -> dict:
+    """Where a lowered module sorts: {None or (branch index of the
+    enclosing ``stablehlo.case``): count} over every ``chlo.top_k`` and
+    ``stablehlo.sort`` of every function (a ``cond`` lowers to a
+    ``case`` whose regions hold the branches inline)."""
+    found: dict = {}
+
+    def walk(op, branch):
+        name = op.operation.name
+        if name in ("chlo.top_k", "stablehlo.sort"):
+            found[branch] = found.get(branch, 0) + 1
+        for i, region in enumerate(op.operation.regions):
+            inner = i if name == "stablehlo.case" else branch
+            for block in region.blocks:
+                for child in block.operations:
+                    walk(child, inner)
+    walk(module, None)
+    return found
+
+
+def test_the_candidate_sort_sits_in_a_conditional_s_branch():
+    """In the program ``sample_batched`` lowers to, the ``top_k`` lies
+    in the taken branch of the one ``case`` and nowhere else: not in the
+    main computation, which keeps the penalty, the argmax and the key
+    split, and not in the branch a step of greedy rows runs. The same
+    walk finds the sort outside any ``case`` in the sampler as it stood,
+    so it can tell."""
+    from p2p_llm_chat_tpu.models.sampling import sample_batched
+    B, V, R = 4, 300, 8
+    args = (jnp.zeros((B, V), jnp.float32), _keys(B), jnp.zeros(B),
+            jnp.zeros(B, jnp.int32), jnp.ones(B))
+    kw = dict(ring=jnp.full((B, R), V, jnp.int32), rp=jnp.ones(B))
+    new = jax.jit(lambda live, *a: sample_batched(*a, live=live, **kw))
+    for live in (None, jnp.ones(B, bool)):
+        lowered = new.lower(live, *args)
+        assert lowered.as_text().count("stablehlo.case") == 1
+        assert _sorts_by_case_branch(
+            lowered.compiler_ir(dialect="stablehlo")) == {1: 1}
+    before = jax.jit(lambda *a: _sample_batched_before_the_cond(
+        *a, **kw)).lower(*args)
+    assert _sorts_by_case_branch(
+        before.compiler_ir(dialect="stablehlo")) == {None: 1}

@@ -9,7 +9,9 @@ of ``SERVE_MAX_SEQ`` positions at each ``--offsets`` and its
 ``--context`` positions in the scheduler's own pool, and the
 scheduler's own ``mid`` chunk program on a ladder of dummy entries at each
 ``--padded`` offset (what a chunk past every row's prompt costs: since
-PR 50 a launch, before it the whole forward), each under
+PR 50 a launch, before it the whole forward), and with ``--sampler`` the
+scheduler's own decode program (the model's step and the sampler behind
+it) over the same rows at each temperature given, each under
 ``jax.profiler``, and prints the forty operations that took most self
 time (``benchmark/trace_reduce.reduce``). Two minutes a call for a
 routed hybrid model; a cell's traced run keeps ten operations of a 4 s
@@ -51,6 +53,14 @@ def main() -> None:
     ap.add_argument("--padded", default="",
                     help="offsets of the top bucket's ladder at which to "
                     "run the scheduler's chunk program on padding alone")
+    ap.add_argument("--sampler", default="",
+                    help="temperatures of the live rows at which to run "
+                    "the scheduler's own decode program, sampler and all, "
+                    "at each of --windows (0: greedy rows; above 0 with "
+                    "top-k 40 and top-p 0.9)")
+    ap.add_argument("--fuse", type=int, default=1,
+                    help="steps of the scheduler's decode program that "
+                    "--sampler runs: 1 the plain step, more the fused scan")
     ap.add_argument("--rows", type=int, default=14)
     ap.add_argument("--context", type=int, default=9300)
     args = ap.parse_args()
@@ -170,6 +180,36 @@ def main() -> None:
         traced(f"decode_window_{W}",
                lambda: step(params, toks, held, jnp.asarray(lens > 0)), 4,
                args.rows, in_ctx, in_ctx)
+    # The scheduler's own decode program, sampler and all, over the same
+    # rows: what it takes beyond the model's step above is the sampler's
+    # (models/sampling.sample_step_batched). The program donates the
+    # pool, so these run last and hand the pool on; a step advances a
+    # live row's length, so the rows start 128 positions short.
+    live = jnp.asarray(lens > 0)
+    for W in map(int, filter(None, args.windows.split(","))):
+        for temp in map(float, filter(None, args.sampler.split(","))):
+            prog = (sched._decode_fused_for(W, args.fuse) if args.fuse > 1
+                    else sched._decode_for(W))
+            held = [jnp.full((slots, 1), 5, jnp.int32), cache._replace(
+                        page_table=jnp.asarray(table),
+                        lengths=jnp.asarray(np.minimum(lens, W - 128))),
+                    jax.vmap(jax.random.PRNGKey)(jnp.arange(slots)),
+                    sched._ring_dev]
+            temps = jnp.full((slots,), temp, jnp.float32)
+            top_ks = jnp.full((slots,), 40 if temp > 0 else 0, jnp.int32)
+            top_ps = jnp.full((slots,), 0.9 if temp > 0 else 1.0,
+                              jnp.float32)
+
+            def sched_step(prog=prog, held=held, temps=temps, top_ks=top_ks,
+                           top_ps=top_ps):
+                out_toks, *held[:] = prog(
+                    params, held[0], held[1], live, temps, top_ks, top_ps,
+                    held[2], held[3], sched._rps_dev)
+                return out_toks
+            in_ctx = args.rows * (min(args.context, W - 128) + 1)
+            traced(f"sched_decode{args.fuse}_window_{W}_temperature_{temp:g}",
+                   sched_step, 4, args.rows, in_ctx, in_ctx)
+            cache, sched._ring_dev = held[1], held[3]
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out",
                            f"profile.{cfg['name']}.json"), "w") as f:
