@@ -93,8 +93,17 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     # RMSNorms after the attention and after the MLP too, before each
-    # residual add (four norms a layer).
+    # residual add (four norms a layer): models/pangu.py's layers, and
+    # models/llama.py's (leaves ``attn_out_norm`` / ``mlp_out_norm``).
     sandwich_norm: bool = False
+    # A looped stack (Ouro's ``total_ut_steps``; models/llama.py's
+    # ``_walk``): the ``num_layers`` layers run this many times a token
+    # with the same weights. The final norm runs after EVERY pass and its
+    # output enters the next; pass ``t``, layer ``l`` keeps its own K and V
+    # (cache layer ``t * num_layers + l``: ``cache_layers``); an exit
+    # gate (leaves ``exit_gate_w`` / ``exit_gate_b``) reads each pass's
+    # output, and the logits are the last pass's whatever it says.
+    ut_steps: int = 1
     # Leading dense layers (SwiGLU of ``dense_intermediate_size``) before
     # the routed ones; ``intermediate_size`` is then one expert's width.
     first_k_dense: int = 0
@@ -273,12 +282,12 @@ class ModelConfig:
 
     @property
     def cache_layers(self) -> int:
-        """Layers that hold pages: all of them, or a hybrid's ``*`` and
-        ``s``."""
+        """Layers that hold pages: all of them, once a pass of a looped
+        stack (``ut_steps``), or a hybrid's ``*`` and ``s``."""
         if self.is_hybrid:
             return (self.hybrid_pattern.count("*")
                     + self.hybrid_pattern.count("s"))
-        return self.num_layers
+        return self.num_layers * self.ut_steps
 
     @property
     def is_indexed(self) -> bool:
@@ -665,6 +674,17 @@ _register(ModelConfig(
     index_head_dim=16, index_topk=16, num_experts=8,
     num_experts_per_tok=2, moe_renormalize=True,
     bos_token_id=1, eos_token_ids=(2,),
+))
+
+# Ouro's looped stack at test size (ByteDance/Ouro-2.6B config.json,
+# model_type ouro): three layers walked four times with one set of
+# weights, plain multi-head attention (4 / 4 heads), four norms a layer,
+# the final norm and the exit gate after every pass; 12 cache layers.
+_register(ModelConfig(
+    name="tiny-ouro", vocab_size=512, hidden_size=128,
+    intermediate_size=256, num_layers=3, num_heads=4, num_kv_heads=4,
+    head_dim=32, max_seq_len=256, rope_theta=10000.0, rms_norm_eps=1e-6,
+    sandwich_norm=True, ut_steps=4, bos_token_id=1, eos_token_ids=(2,),
 ))
 
 # Loadgen CPU profile: ``tiny`` dims with a real context window, so the

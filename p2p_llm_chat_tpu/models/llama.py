@@ -101,6 +101,36 @@ def qk_norm_leaves(config: ModelConfig, key: jax.Array, dtype) -> dict:
 QK_NORM_AXES = {"q_norm": (None, "heads"), "k_norm": (None, "kv_heads")}
 
 
+def loop_leaves(config: ModelConfig, key: jax.Array, dtype) -> tuple:
+    """(layer leaves, top-level leaves) of a sandwich-normed, looped stack
+    (Ouro): ``attn_out_norm`` / ``mlp_out_norm`` [L, H], the norms on each
+    branch's OUTPUT (``config.sandwich_norm``), and ``exit_gate_w`` [H, 1]
+    / ``exit_gate_b`` [1], the exit gate that reads every pass's normed
+    output (``config.ut_steps`` > 1). Both empty otherwise, so the
+    initialisers ``update`` their trees with them. The norms are drawn
+    from [0.5, 1.5), as :func:`qk_norm_leaves`'s are and for its reason;
+    the gate's logit has unit spread about 0.5 over unit-RMS inputs, so
+    that a gate of zeros (every pass 0.5) reads as another model."""
+    layer, top = {}, {}
+    L, H = config.num_layers, config.hidden_size
+    ka, km, kw = jax.random.split(key, 3)
+    if config.sandwich_norm:
+        def draw(k):
+            return (0.5 + jax.random.uniform(k, (L, H), jnp.float32)
+                    ).astype(dtype)
+        layer = {"attn_out_norm": draw(ka), "mlp_out_norm": draw(km)}
+    if config.ut_steps > 1:
+        top = {"exit_gate_w": (jax.random.normal(kw, (H, 1), jnp.float32)
+                               * H ** -0.5).astype(dtype),
+               "exit_gate_b": jnp.full((1,), 0.5, dtype)}
+    return layer, top
+
+
+LOOP_LAYER_AXES = {"attn_out_norm": (None, "embed"),
+                   "mlp_out_norm": (None, "embed")}
+LOOP_TOP_AXES = {"exit_gate_w": ("embed", None), "exit_gate_b": (None,)}
+
+
 def init_params(config: ModelConfig, key: jax.Array,
                 dtype=DEFAULT_COMPUTE_DTYPE) -> dict:
     """Random init (scaled normal). Real weights come from
@@ -128,6 +158,10 @@ def init_params(config: ModelConfig, key: jax.Array,
         "final_norm": jnp.ones((H,), dtype),
     }
     params["layers"].update(qk_norm_leaves(config, ks[9], dtype))
+    # A key of its own, so that no other leaf's draw moves.
+    layer, top = loop_leaves(config, jax.random.fold_in(ks[9], 2), dtype)
+    params["layers"].update(layer)
+    params.update(top)
     if not config.tie_embeddings:
         params["lm_head"] = normal(ks[8], (H, config.vocab_size))
     return params
@@ -181,6 +215,9 @@ def init_params_quantized(config: ModelConfig, key: jax.Array,
         # A key of its own, so that no other leaf's draw moves.
         **qk_norm_leaves(config, jax.random.fold_in(k_head, 1), dtype),
     }
+    loop_layer, loop_top = loop_leaves(config, jax.random.fold_in(k_head, 2),
+                                       dtype)
+    layers.update(loop_layer)
     for name, (din, dout) in dims.items():
         layers[name] = stream_bufs(L, (din, dout), quant)
 
@@ -206,6 +243,7 @@ def init_params_quantized(config: ModelConfig, key: jax.Array,
         "embed": normal(k_embed, (config.vocab_size, H), scale=1.0),
         "layers": layers,
         "final_norm": jnp.ones((H,), dtype),
+        **loop_top,
     }
     if not config.tie_embeddings:
         params["lm_head"] = _quantize_leaf(
@@ -352,6 +390,10 @@ def param_axes(config: ModelConfig) -> dict:
     }
     if config.qk_norm_whole:
         axes["layers"].update(QK_NORM_AXES)
+    if config.sandwich_norm:
+        axes["layers"].update(LOOP_LAYER_AXES)
+    if config.ut_steps > 1:
+        axes.update(LOOP_TOP_AXES)
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -469,14 +511,81 @@ def _attn_qkv(h: jax.Array, lp: dict, config: ModelConfig,
 
 def _post_attn(h: jax.Array, attn: jax.Array, lp: dict, config: ModelConfig,
                mesh: Optional[Mesh], rules: LogicalRules, mlp_fn) -> jax.Array:
-    """Output projection + residual + MLP + residual. attn: [B,S,Hq,D]."""
+    """Output projection + residual + MLP + residual. attn: [B,S,Hq,D].
+    Under ``config.sandwich_norm`` each branch's output is normed before
+    its residual add (``attn_out_norm`` / ``mlp_out_norm``)."""
     B, S = attn.shape[:2]
     attn = attn.reshape(B, S, config.q_dim)
-    h = h + constrain(mm(attn, lp["wo"]), mesh, ("batch", None, "act_embed"), rules)
+    out = mm(attn, lp["wo"])
+    if config.sandwich_norm:
+        out = rms_norm(out, lp["attn_out_norm"], config.rms_norm_eps)
+    h = h + constrain(out, mesh, ("batch", None, "act_embed"), rules)
     x = rms_norm(h, lp["mlp_norm"], config.rms_norm_eps)
     mlp = (mlp_fn(x, lp, mesh, rules) if mlp_fn is not None
            else _default_mlp(x, lp, mesh, rules, config))
+    if config.sandwich_norm:
+        mlp = rms_norm(mlp, lp["mlp_out_norm"], config.rms_norm_eps)
     return h + constrain(mlp, mesh, ("batch", None, "act_embed"), rules)
+
+
+def exit_pdf(gates: jax.Array) -> jax.Array:
+    """A looped stack's exit distribution from its passes' gates
+    ``g`` [T, ...] -> [..., T]: ``p_t = g_t prod_{s<t} (1 - g_s)`` for t
+    < T - 1 and ``p_{T-1} = prod_{s<T-1} (1 - g_s)`` (the last pass takes
+    what is left, whatever its own gate says). Sums to 1."""
+    stay = jnp.cumprod(1.0 - gates, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], axis=0)
+    pdf = jnp.concatenate([gates[:-1] * before[:-1], before[-1:]], axis=0)
+    return jnp.moveaxis(pdf, 0, -1)
+
+
+def _walk(params: dict, config: ModelConfig, carry: tuple, body):
+    """THE walk over the stack, which every entry point makes through
+    here: ``body(carry, layer, cache_layer) -> (carry, ys)`` once a layer,
+    ``carry[0]`` the hidden state ``h`` [B,S,H], ``layer`` the weights'
+    index (:func:`_layer_view`) and ``cache_layer`` the index of the K
+    and V it reads and writes. Returns (carry, ys stacked
+    [``config.cache_layers``, ...], exit pdf).
+
+    One pass (``config.ut_steps`` 1): one ``lax.scan`` over the layers,
+    the two indices equal, the hidden state handed back BEFORE the final
+    norm (the caller's, :func:`_final_norm`) and no pdf (None).
+
+    A looped stack: an outer scan over the passes around that scan; pass
+    ``t``, layer ``l`` reads weights ``l`` and cache layer ``t * L + l``;
+    after EVERY pass the final norm, whose output enters the next pass and
+    is what the exit gate reads (``sigmoid(h . w + b)``, float32). The
+    hidden state handed back is the last pass's normed output
+    (:func:`_final_norm` then adds nothing) and the pdf
+    (:func:`exit_pdf`) is [B,S,``ut_steps``] float32: computed and
+    counted, never acted on: every token runs every pass."""
+    L, T = config.num_layers, config.ut_steps
+    if T == 1:
+        carry, ys = jax.lax.scan(lambda c, layer: body(c, layer, layer),
+                                 carry, jnp.arange(L))
+        return carry, ys, None
+
+    def one_pass(carry, t):
+        carry, ys = jax.lax.scan(
+            lambda c, layer: body(c, layer, t * L + layer), carry,
+            jnp.arange(L))
+        h = rms_norm(carry[0], params["final_norm"], config.rms_norm_eps)
+        logit = (h.astype(jnp.float32)
+                 @ params["exit_gate_w"].astype(jnp.float32)
+                 + params["exit_gate_b"].astype(jnp.float32))
+        return (h, *carry[1:]), (ys, jax.nn.sigmoid(logit[..., 0]))
+
+    carry, (ys, gates) = jax.lax.scan(one_pass, carry, jnp.arange(T))
+    ys = jax.tree.map(lambda y: y.reshape((T * L,) + y.shape[2:]), ys)
+    return carry, ys, exit_pdf(gates)
+
+
+def _final_norm(params: dict, config: ModelConfig, h: jax.Array) -> jax.Array:
+    """The final norm over what :func:`_walk` handed back: a looped
+    stack's last pass has run it already."""
+    if config.ut_steps > 1:
+        return h
+    return rms_norm(h, params["final_norm"], config.rms_norm_eps)
 
 
 def _block(h: jax.Array, lp: dict, config: ModelConfig, inv_freq: jax.Array,
@@ -488,7 +597,8 @@ def _block(h: jax.Array, lp: dict, config: ModelConfig, inv_freq: jax.Array,
     """One decoder block against the full stacked cache.
 
     h: [B,S,H]; cache_k/v: [L,B,max_seq,Hkv,D] (the whole stacked cache —
-    this layer's slice is selected by ``layer``); write_pos: [B,S] absolute
+    this layer's slice is selected by ``layer``, the CACHE's layer index,
+    which in a looped stack is not the weights': :func:`_walk`); write_pos: [B,S] absolute
     slots to write this step's k/v into; mask: [B or 1, 1, S, max_seq].
     Returns (h, new_cache_k, new_cache_v).
 
@@ -554,20 +664,21 @@ def _mlp_without_aux(mlp_fn, config: ModelConfig):
     return fn
 
 
-def hidden_states_aux(params: dict, config: ModelConfig, tokens: jax.Array,
-                      positions: jax.Array, cache: KVCache, mask: jax.Array,
-                      mlp_fn, mlp_aux,
-                      mesh: Optional[Mesh] = None,
-                      rules: LogicalRules = DEFAULT_RULES,
-                      kv_window: Optional[int] = None,
-                      causal0: bool = False,
-                      write_pos: Optional[jax.Array] = None
-                      ) -> tuple[jax.Array, KVCache, Any]:
-    """embed -> scan(blocks) -> final norm, for an MLP that accumulates
-    something over the layers (models/mixtral.py counts what its
-    capacity buckets drop). ``mlp_fn(x, lp, mesh, rules, aux) ->
+def hidden_states_exit(params: dict, config: ModelConfig, tokens: jax.Array,
+                       positions: jax.Array, cache: KVCache, mask: jax.Array,
+                       mlp_fn, mlp_aux,
+                       mesh: Optional[Mesh] = None,
+                       rules: LogicalRules = DEFAULT_RULES,
+                       kv_window: Optional[int] = None,
+                       causal0: bool = False,
+                       write_pos: Optional[jax.Array] = None
+                       ) -> tuple[jax.Array, KVCache, Any, Any]:
+    """embed -> the walk (:func:`_walk`) -> final norm, for an MLP that
+    accumulates something over the layers (models/mixtral.py counts what
+    its capacity buckets drop). ``mlp_fn(x, lp, mesh, rules, aux) ->
     (out, aux)``; ``mlp_aux`` (a pytree) is the value the first layer is
-    handed, and the scan carries it. Returns (h [B,S,H], cache, aux).
+    handed, and the scan carries it. Returns (h [B,S,H], cache, aux, a
+    looped stack's exit pdf [B,S,ut_steps] or None).
 
     ``write_pos`` ([B,S], default = ``positions``): cache slots this
     step's k/v land in, decoupled from the RoPE positions — tree
@@ -580,19 +691,34 @@ def hidden_states_aux(params: dict, config: ModelConfig, tokens: jax.Array,
     inv_freq = rope_frequencies(config)
     wp = positions if write_pos is None else write_pos
 
-    def body(carry, layer):
+    def body(carry, layer, cache_layer):
         h, ck, cv, aux = carry
         lp = _layer_view(params["layers"], layer)
         fn, aux_after = _mlp_carrying(mlp_fn, aux)
         h, ck, cv = _block(h, lp, config, inv_freq, positions, ck, cv,
-                           layer, wp, mask, mesh, rules, kv_window,
+                           cache_layer, wp, mask, mesh, rules, kv_window,
                            fn, causal0)
         return (h, ck, cv, aux_after()), None
 
-    (h, new_k, new_v, aux), _ = jax.lax.scan(
-        body, (h, cache.k, cache.v, mlp_aux), jnp.arange(config.num_layers))
-    h = rms_norm(h, params["final_norm"], config.rms_norm_eps)
-    return h, KVCache(new_k, new_v, cache.lengths), aux
+    (h, new_k, new_v, aux), _, pdf = _walk(
+        params, config, (h, cache.k, cache.v, mlp_aux), body)
+    h = _final_norm(params, config, h)
+    return h, KVCache(new_k, new_v, cache.lengths), aux, pdf
+
+
+def hidden_states_aux(params: dict, config: ModelConfig, tokens: jax.Array,
+                      positions: jax.Array, cache: KVCache, mask: jax.Array,
+                      mlp_fn, mlp_aux,
+                      mesh: Optional[Mesh] = None,
+                      rules: LogicalRules = DEFAULT_RULES,
+                      kv_window: Optional[int] = None,
+                      causal0: bool = False,
+                      write_pos: Optional[jax.Array] = None
+                      ) -> tuple[jax.Array, KVCache, Any]:
+    """:func:`hidden_states_exit` without the pdf: (h, cache, aux)."""
+    return hidden_states_exit(params, config, tokens, positions, cache, mask,
+                              mlp_fn, mlp_aux, mesh, rules, kv_window,
+                              causal0, write_pos)[:3]
 
 
 def hidden_states(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -673,6 +799,23 @@ def forward_aux(params: dict, config: ModelConfig, tokens: jax.Array,
                                       cache, mask, mlp_fn, mlp_aux, mesh,
                                       rules, kv_window, causal0)
     return _logits(params, config, h, last_idx, mesh, rules), cache, aux
+
+
+def forward_exit(params: dict, config: ModelConfig, tokens: jax.Array,
+                 positions: jax.Array, cache: KVCache, mask: jax.Array,
+                 mesh: Optional[Mesh] = None,
+                 rules: LogicalRules = DEFAULT_RULES,
+                 causal0: bool = False,
+                 last_idx: Optional[jax.Array] = None,
+                 kv_window: Optional[int] = None
+                 ) -> tuple[jax.Array, KVCache, Any]:
+    """:func:`forward` of a looped stack with its exit pdf: (logits,
+    cache, pdf [B,S,ut_steps] float32, every position's whatever
+    ``last_idx`` says; None for a stack walked once)."""
+    h, cache, _, pdf = hidden_states_exit(
+        params, config, tokens, positions, cache, mask,
+        _mlp_without_aux(None, config), (), mesh, rules, kv_window, causal0)
+    return _logits(params, config, h, last_idx, mesh, rules), cache, pdf
 
 
 def embed_pooled(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -817,6 +960,43 @@ def decode_step(params: dict, config: ModelConfig, tokens: jax.Array,
     return logits, cache._replace(lengths=cache.lengths + inc)
 
 
+def no_exit_mass(config: ModelConfig) -> jax.Array:
+    """A decode dispatch's exit mass at its start: float32 [ut_steps],
+    the exit pdf summed over the rows live at each step and, in a fused
+    program, over the steps. Sums to the live row-steps."""
+    return jnp.zeros((config.ut_steps,), jnp.float32)
+
+
+def _add_exit_mass(mass, pdf: jax.Array, active: Optional[jax.Array]):
+    """``mass`` plus a step's pdf [B,1,T] over its live rows."""
+    pdf = pdf[:, 0]
+    if active is not None:
+        pdf = jnp.where(active[:, None], pdf, 0.0)
+    return mass + jnp.sum(pdf, axis=0)
+
+
+def decode_step_exit(params: dict, config: ModelConfig, tokens: jax.Array,
+                     cache: KVCache, mesh: Optional[Mesh] = None,
+                     rules: LogicalRules = DEFAULT_RULES,
+                     exit_mass: Optional[jax.Array] = None,
+                     active: Optional[jax.Array] = None,
+                     kv_window: Optional[int] = None
+                     ) -> tuple[jax.Array, KVCache, jax.Array]:
+    """:func:`decode_step` of a looped stack, and third ``exit_mass``
+    (None = :func:`no_exit_mass`) plus this step's exit pdf over its live
+    rows: the form :func:`decode_fused_aux` scans."""
+    positions = cache.lengths[:, None]
+    window = kv_window if kv_window is not None else cache.k.shape[2]
+    mask = length_mask(window, cache.lengths + 1)
+    logits, cache, pdf = forward_exit(params, config, tokens, positions,
+                                      cache, mask, mesh, rules,
+                                      kv_window=kv_window)
+    inc = jnp.ones_like(cache.lengths) if active is None else active.astype(jnp.int32)
+    mass = no_exit_mass(config) if exit_mass is None else exit_mass
+    return (logits, cache._replace(lengths=cache.lengths + inc),
+            _add_exit_mass(mass, pdf, active))
+
+
 def decode_fused_aux(params: dict, config: ModelConfig, tokens: jax.Array,
                      cache, step_fn, step_aux,
                      mesh: Optional[Mesh] = None,
@@ -907,6 +1087,24 @@ def decode_fused(params: dict, config: ModelConfig, tokens: jax.Array,
                             sample_fn=sample_fn, sample_state=sample_state,
                             stop_ids=stop_ids, kv_window=kv_window,
                             pages=pages)[:-1]
+
+
+def decode_fused_exit(params: dict, config: ModelConfig, tokens: jax.Array,
+                      cache, mesh: Optional[Mesh] = None,
+                      rules: LogicalRules = DEFAULT_RULES,
+                      active: Optional[jax.Array] = None, *,
+                      num_steps: int, sample_fn, sample_state, stop_ids,
+                      kv_window: Optional[int] = None,
+                      pages: Optional[int] = None):
+    """:func:`decode_fused` of a looped stack over the ``_exit`` steps,
+    and last the dispatch's exit mass (:func:`no_exit_mass`): each step
+    is handed the rows still live at it."""
+    step_fn = decode_step_exit if pages is None else decode_step_paged_exit
+    return decode_fused_aux(params, config, tokens, cache, step_fn,
+                            no_exit_mass(config), mesh, rules, active,
+                            num_steps=num_steps, sample_fn=sample_fn,
+                            sample_state=sample_state, stop_ids=stop_ids,
+                            kv_window=kv_window, pages=pages)
 
 
 def verify_step(params: dict, config: ModelConfig, tokens: jax.Array,
@@ -1068,7 +1266,7 @@ def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     inv_freq = rope_frequencies(config)
 
     def finish(h):
-        h = rms_norm(h, params["final_norm"], config.rms_norm_eps)
+        h = _final_norm(params, config, h)
         if last_idx is not None:
             # One position's logits per row ([B,1,vocab]) — the
             # session-wake admission shape, where S is a whole suffix
@@ -1081,15 +1279,16 @@ def verify_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,
         logits = mm(h, lm_head).astype(jnp.float32)
         return constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
 
-    def body(h, layer):
+    def body(carry, layer, cache_layer):
+        h, = carry
         lp = _layer_view(params["layers"], layer)
         q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh, rules)
         attn = paged_attention_verify_append(
-            q, k, v, cache, cache.lengths, layer, pages=pages)
+            q, k, v, cache, cache.lengths, cache_layer, pages=pages)
         h = _post_attn(h, attn, lp, config, mesh, rules, mlp_fn)
-        return h, (k, v)
+        return (h,), (k, v)
 
-    h, (k_all, v_all) = jax.lax.scan(body, h, jnp.arange(config.num_layers))
+    (h,), (k_all, v_all), _ = _walk(params, config, (h,), body)
     cache = write_decode_multi_all_layers(cache, k_all, v_all)
     return finish(h), cache
 
@@ -1121,19 +1320,20 @@ def verify_tree_paged(params: dict, config: ModelConfig, tokens: jax.Array,
     h = constrain(h, mesh, ("batch", None, "act_embed"), rules)
     inv_freq = rope_frequencies(config)
 
-    def body(h, layer):
+    def body(carry, layer, cache_layer):
+        h, = carry
         lp = _layer_view(params["layers"], layer)
         q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh,
                             rules)
         attn = paged_attention_verify_append(
-            q, k, v, cache, cache.lengths, layer, pages=pages,
+            q, k, v, cache, cache.lengths, cache_layer, pages=pages,
             block_mask=anc)
         h = _post_attn(h, attn, lp, config, mesh, rules, mlp_fn)
-        return h, (k, v)
+        return (h,), (k, v)
 
-    h, (k_all, v_all) = jax.lax.scan(body, h, jnp.arange(config.num_layers))
+    (h,), (k_all, v_all), _ = _walk(params, config, (h,), body)
     cache = write_decode_multi_all_layers(cache, k_all, v_all)
-    h = rms_norm(h, params["final_norm"], config.rms_norm_eps)
+    h = _final_norm(params, config, h)
     lm_head = (params["embed"].T if config.tie_embeddings
                else params["lm_head"])
     logits = mm(h, lm_head).astype(jnp.float32)
@@ -1141,17 +1341,16 @@ def verify_tree_paged(params: dict, config: ModelConfig, tokens: jax.Array,
                      rules), cache
 
 
-def decode_step_paged_aux(params: dict, config: ModelConfig,
-                          tokens: jax.Array, cache, mlp_fn, mlp_aux,
-                          mesh: Optional[Mesh] = None,
-                          rules: LogicalRules = DEFAULT_RULES,
-                          active: Optional[jax.Array] = None,
-                          *, pages: int):
+def _decode_step_paged(params: dict, config: ModelConfig,
+                       tokens: jax.Array, cache, mlp_fn, mlp_aux,
+                       mesh: Optional[Mesh], rules: LogicalRules,
+                       active: Optional[jax.Array], pages: int):
     """One autoregressive step over the paged KV pool (ops/paged_kv.py),
     for an MLP that accumulates something over the layers, in
     :func:`hidden_states_aux`'s form: ``mlp_fn(x, lp, mesh, rules, aux)
     -> (out, aux)``, ``mlp_aux`` what the first layer is handed.
-    :func:`decode_step_paged` is this with nothing to accumulate.
+    :func:`decode_step_paged_aux` is this without its fourth result, a
+    looped stack's exit pdf [B,1,ut_steps] (None for a stack walked once).
 
     Same contract as :func:`decode_step` — including the parked-row
     invariant, which paging strengthens: a released row's zeroed page
@@ -1161,15 +1360,16 @@ def decode_step_paged_aux(params: dict, config: ModelConfig,
     ``pages = ceil(window / page_size)``).
 
     cache: ops.paged_kv.PagedKVCache. Returns (logits [B,1,vocab], cache
-    with lengths advanced where active, aux).
+    with lengths advanced where active, aux, pdf).
 
     Structure note: a layer attends BEFORE the pool write — the current
     token's k/v folds into attention via one exact online-softmax merge
     (ops/paged_attention.paged_attention_append) — and the scan stacks
     each layer's k/v so ONE batched scatter lands the whole step
-    afterwards (write_decode_burst). Per-layer pool scatters inside the
-    scan carry a fixed cost that was measurable against the decode
-    bandwidth bound.
+    afterwards (write_decode_burst; a looped stack's K and V come out of
+    the walk stacked [cache_layers, ...], a pass after a pass). Per-layer
+    pool scatters inside the scan carry a fixed cost that was measurable
+    against the decode bandwidth bound.
 
     paged_attention_append chooses its implementation per layer call,
     from the window and the pool's geometry alone (the XLA gather below
@@ -1191,26 +1391,56 @@ def decode_step_paged_aux(params: dict, config: ModelConfig,
            else active.astype(jnp.int32))
 
     def finish(h):
-        h = rms_norm(h, params["final_norm"], config.rms_norm_eps)
+        h = _final_norm(params, config, h)
         lm_head = (params["embed"].T if config.tie_embeddings
                    else params["lm_head"])
         logits = mm(h, lm_head).astype(jnp.float32)
         return constrain(logits, mesh, ("batch", None, "act_vocab"), rules)
 
-    def body(carry, layer):
+    def body(carry, layer, cache_layer):
         h, aux = carry
         fn, aux_after = _mlp_carrying(mlp_fn, aux)
         lp = _layer_view(params["layers"], layer)
         q, k, v = _attn_qkv(h, lp, config, inv_freq, positions, mesh, rules)
         attn = paged_attention_append(q[:, 0], k[:, 0], v[:, 0], cache,
-                                      cache.lengths, layer, pages=pages,
-                                      sharded=mesh is not None)
+                                      cache.lengths, cache_layer,
+                                      pages=pages, sharded=mesh is not None)
         h = _post_attn(h, attn[:, None], lp, config, mesh, rules, fn)
         return (h, aux_after()), (k[:, 0], v[:, 0])
 
-    (h, aux), (k_all, v_all) = jax.lax.scan(
-        body, (h, mlp_aux), jnp.arange(config.num_layers))
-    return finish(h), write_decode_burst(cache, k_all, v_all, inc), aux
+    (h, aux), (k_all, v_all), pdf = _walk(params, config, (h, mlp_aux), body)
+    return (finish(h), write_decode_burst(cache, k_all, v_all, inc), aux,
+            pdf)
+
+
+def decode_step_paged_aux(params: dict, config: ModelConfig,
+                          tokens: jax.Array, cache, mlp_fn, mlp_aux,
+                          mesh: Optional[Mesh] = None,
+                          rules: LogicalRules = DEFAULT_RULES,
+                          active: Optional[jax.Array] = None,
+                          *, pages: int):
+    """:func:`_decode_step_paged` without the pdf: (logits, cache, aux).
+    :func:`decode_step_paged` is this with nothing to accumulate."""
+    return _decode_step_paged(params, config, tokens, cache, mlp_fn,
+                              mlp_aux, mesh, rules, active, pages)[:3]
+
+
+def decode_step_paged_exit(params: dict, config: ModelConfig,
+                           tokens: jax.Array, cache,
+                           mesh: Optional[Mesh] = None,
+                           rules: LogicalRules = DEFAULT_RULES,
+                           exit_mass: Optional[jax.Array] = None,
+                           active: Optional[jax.Array] = None,
+                           *, pages: int):
+    """:func:`decode_step_paged` of a looped stack, and third
+    ``exit_mass`` plus this step's, as :func:`decode_step_exit`: the
+    scheduler's decode programs run these ``_exit`` forms for a looped
+    model."""
+    logits, cache, _, pdf = _decode_step_paged(
+        params, config, tokens, cache, _mlp_without_aux(None, config), (),
+        mesh, rules, active, pages)
+    mass = no_exit_mass(config) if exit_mass is None else exit_mass
+    return logits, cache, _add_exit_mass(mass, pdf, active)
 
 
 def decode_step_paged(params: dict, config: ModelConfig, tokens: jax.Array,

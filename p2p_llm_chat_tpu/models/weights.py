@@ -90,12 +90,32 @@ def _olmoe_layer_map(i: int, num_experts: int) -> dict[str, Any]:
     return m
 
 
+def _sandwich_layer_map(i: int) -> dict[str, tuple[str, bool]]:
+    """Ouro's layout: the dense names, and the two norms on each branch's
+    OUTPUT (``input_layernorm_2`` behind the attention,
+    ``post_attention_layernorm_2`` behind the MLP)."""
+    p = f"model.layers.{i}"
+    return {**_dense_layer_map(i),
+            "attn_out_norm": (f"{p}.input_layernorm_2.weight", False),
+            "mlp_out_norm": (f"{p}.post_attention_layernorm_2.weight",
+                             False)}
+
+
+# A looped stack's exit gate (``model.early_exit_gate``, a Linear hidden
+# -> 1 with bias): our leaf -> (HF tensor name, transpose?).
+_EXIT_GATE_MAP = {
+    "exit_gate_w": ("model.early_exit_gate.weight", True),
+    "exit_gate_b": ("model.early_exit_gate.bias", False),
+}
+
+
 def _layer_map(config: ModelConfig, i: int) -> dict[str, Any]:
     """Layer i's name map for the configuration's family. The two routed
     layouts are told apart by QK-norm: Mixtral (``block_sparse_moe``,
     w1/w2/w3) has none, OLMoE (``mlp.experts``, *_proj) has it."""
     if not config.is_moe:
-        return _dense_layer_map(i)
+        return (_sandwich_layer_map(i) if config.sandwich_norm
+                else _dense_layer_map(i))
     if config.qk_norm_whole:
         return _olmoe_layer_map(i, config.num_experts)
     return _moe_layer_map(i, config.num_experts)
@@ -128,6 +148,9 @@ def convert_hf_state_dict(state: dict[str, np.ndarray], config: ModelConfig,
         "layers": layers,
         "final_norm": jnp.asarray(state["model.norm.weight"], dtype),
     }
+    if config.ut_steps > 1:
+        for key, spec in _EXIT_GATE_MAP.items():
+            params[key] = jnp.asarray(get(*spec), dtype)
     if not config.tie_embeddings:
         params["lm_head"] = jnp.asarray(
             np.ascontiguousarray(state["lm_head.weight"].T), dtype)
@@ -155,6 +178,15 @@ def config_from_hf_json(path: str) -> ModelConfig:
             original_max_position=int(rs.get("original_max_position_embeddings", 8192)),
         )
     num_heads = int(hf["num_attention_heads"])
+    if hf.get("early_exit_threshold", 1) != 1:
+        # A looped model (Ouro) whose rows leave the loop early: the
+        # program runs every pass for every token and has no path that
+        # exits (ROADMAP.md, Reach); serving it as if the threshold were
+        # 1 would be another model under this one's name.
+        raise ValueError(
+            f"{path}: early_exit_threshold {hf['early_exit_threshold']} "
+            "is below 1; only the last pass's logits (threshold 1) are "
+            "served")
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (int(eos),)
     return ModelConfig(
@@ -177,6 +209,13 @@ def config_from_hf_json(path: str) -> ModelConfig:
         num_experts_per_tok=int(hf.get("num_experts_per_tok", 0)),
         moe_renormalize=bool(hf.get("norm_topk_prob", True)),
         qk_norm_whole=hf.get("model_type") == "olmoe",
+        # A looped stack: walked ``total_ut_steps`` times. Four norms a
+        # layer where the config says so (``sandwich_norm``, openPangu's
+        # key); Ouro's config.json has no key for its four, so there the
+        # ``model_type`` says it, as it says OLMoE's QK-norm above.
+        ut_steps=int(hf.get("total_ut_steps") or 1),
+        sandwich_norm=bool(hf.get("sandwich_norm",
+                                  hf.get("model_type") == "ouro")),
         bos_token_id=int(hf.get("bos_token_id") or 1),
         eos_token_ids=eos_ids,
     )
@@ -193,6 +232,9 @@ def _reverse_name_map(config: ModelConfig) -> dict[str, tuple]:
     }
     if not config.tie_embeddings:
         out["lm_head.weight"] = (("lm_head",), None, None, True)
+    if config.ut_steps > 1:
+        for key, (name, tr) in _EXIT_GATE_MAP.items():
+            out[name] = ((key,), None, None, tr)
     for i in range(config.num_layers):
         for key, spec in _layer_map(config, i).items():
             if isinstance(spec, list):
@@ -381,6 +423,7 @@ _CONFIG_IDENTITY_FIELDS = (
     "vocab_size", "hidden_size", "intermediate_size", "num_layers",
     "num_heads", "num_kv_heads", "head_dim", "tie_embeddings",
     "num_experts", "num_experts_per_tok", "moe_renormalize", "qk_norm_whole",
+    "sandwich_norm", "ut_steps",
 )
 
 
@@ -477,7 +520,8 @@ def load_checkpoint_quantized(ckpt_dir: str,
     moe = config.is_moe
     # Norm vectors stay in the compute dtype, one row a layer.
     norm_keys = ("attn_norm", "mlp_norm") + (
-        ("q_norm", "k_norm") if config.qk_norm_whole else ())
+        ("q_norm", "k_norm") if config.qk_norm_whole else ()) + (
+        ("attn_out_norm", "mlp_out_norm") if config.sandwich_norm else ())
     layer_keys = norm_keys + ("wq", "wk", "wv", "wo", "w_gate", "w_up",
                               "w_down") + (("router",) if moe else ())
 
@@ -500,8 +544,9 @@ def load_checkpoint_quantized(ckpt_dir: str,
         def top_host() -> dict[str, np.ndarray]:
             out = {"embed": np.asarray(host_params["embed"]),
                    "final_norm": np.asarray(host_params["final_norm"])}
-            if "lm_head" in host_params:
-                out["lm_head"] = np.asarray(host_params["lm_head"])
+            for k in ("lm_head", *_EXIT_GATE_MAP):
+                if k in host_params:
+                    out[k] = np.asarray(host_params[k])
             return out
     else:
         host_params = None
@@ -655,6 +700,9 @@ def load_checkpoint_quantized(ckpt_dir: str,
         "layers": layers,
         "final_norm": jnp.asarray(top["final_norm"], dtype),
     }
+    if config.ut_steps > 1:
+        for k in _EXIT_GATE_MAP:
+            params[k] = jnp.asarray(top[k], dtype)
     if not config.tie_embeddings:
         # Host-side too: a device quantize of the 8B lm_head would spike
         # ~3 GB of bf16-upload + f32 temp on a chip already holding the

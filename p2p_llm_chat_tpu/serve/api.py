@@ -576,6 +576,12 @@ class OllamaServer:
                 info["nemotron_h.attention.block_count"] = cfg.cache_layers
                 info["nemotron_h.ssm.block_count"] = cfg.ssm_layers
                 info["nemotron_h.ssm.state_size"] = cfg.ssm_state_size
+            if cfg.ut_steps > 1:
+                # A looped stack: ``block_count`` layers of weights walked
+                # this many times a token, a cache layer a (pass, layer).
+                # Under the family's own key: a mechanism names no model.
+                info["llama.loop.pass_count"] = cfg.ut_steps
+                info["llama.attention.block_count"] = cfg.cache_layers
         return Response(200, {"modelfile": "", "parameters": "",
                               "template": "", "details": details,
                               "model_info": info})

@@ -98,6 +98,24 @@ _MIN_BUCKET = 16
 # requests never pay for a 4- or 8-row program; and nothing waits for a
 # partner: sharing a dispatch saves a tenth of two, at best.
 _ADMIT_PAIR_TOKENS = 2048
+# ... and inside this many bytes of the small cache both rows are
+# prefilled into ([cache_layers, R, P + S, Hkv, D], K and V, in the
+# compute dtype). Tokens stand for bytes while a token holds a few
+# dozen layers of grouped heads (Mistral 7B: 131 KB, 0.27 GB a pair at
+# 2,048); a looped stack's token holds one cache layer a PASS and a layer
+# (Ouro-2.6B: 192 of 16 heads, 1.57 MB), and its pair at 1,024 would be
+# 3.2 GB of a chip whose pool already takes what the weights left.
+_ADMIT_PAIR_BYTES = 1 << 30
+# A ladder's LAST chunk hands a carry of more than this a row back to the
+# host (which drops it), so that the carry is donated and the chunk's
+# forward writes it where it lies. A carry that dies in the program
+# cannot be donated (no output to alias), so XLA copies it before the
+# layer scan writes into it, and once more around the padding ``cond``:
+# twice the carry in temporaries. Nobody sees that at Mistral 7B's 146 MB
+# a row of 1,112 positions; a looped stack's 1,024-token row is 1.6 GB,
+# and its last chunk wanted 3.0 GB of copies beside a pool that had left
+# 2.4 (my chip run, PR 53: "Used 16.70G of 15.75G hbm").
+_CARRY_IN_PLACE_BYTES = 1 << 29
 # Cap on the R x S footprint of an operator-fixed width (admit_chunk):
 # the fused prefill materialises a [L, R, S(+P), Hkv, D] small cache, so
 # wide chunks at long prompt buckets would transiently eat gigabytes of
@@ -640,6 +658,12 @@ class BatchScheduler:
         self.num_pages = (num_pages if num_pages is not None else
                           num_slots * -(-self.max_seq // page_size) + 1)
         self._dtype = params["embed"].dtype
+        # One position of a prefill's dense carry (models/llama.KVCache:
+        # K and V of every cache layer, in the compute dtype).
+        self._carry_token_bytes = (
+            config.cache_layers * config.cache_kv_heads
+            * (config.cache_k_dim + config.cache_v_dim)
+            * jnp.dtype(self._dtype).itemsize)
         # llama or mixtral — same functional surface (models.family_for),
         # so dense and MoE configs serve through one scheduler.
         self._model = family_for(config)
@@ -698,6 +722,30 @@ class BatchScheduler:
                         f"{config.name} keeps an index key a token beside "
                         f"K and V (index_topk {config.index_topk}) and is "
                         f"not served under {what}")
+        if config.ut_steps > 1:
+            # A looped stack (models/llama._walk) is carried through the
+            # dense family's programs on one chip. Paths that walk a stack
+            # themselves, or were never run over one, refuse it here, by
+            # name, with what each would need (ROADMAP.md, Reach).
+            for on, what in (
+                    (config.is_moe or config.is_latent or config.is_hybrid,
+                     f"the {model.__name__.rsplit('.', 1)[-1]} family "
+                     "(only models/llama.py's walk knows passes: a routed, "
+                     "latent or hybrid layer would need its counts and "
+                     "its state carried a pass at a time)"),
+                    (mesh is not None, "a mesh (parallel/ring.py and "
+                     "parallel/pipeline.py scan the layers once, and the "
+                     "pool's head split was never run over "
+                     f"{config.cache_layers} cache layers)"),
+                    (bool(spec_k) or drafter is not None, "speculative "
+                     "decoding (the verify programs walk the passes, but "
+                     "acceptance, the tree's slot compaction and a "
+                     "drafter were never run over a looped stack)")):
+                if on:
+                    raise ValueError(
+                        f"{config.name} walks its {config.num_layers} "
+                        f"layers {config.ut_steps} times a token "
+                        f"(ut_steps) and is not served under {what}")
         # Where an admission's packed buffer goes (_admit_upload): every
         # device of a mesh, committed; else the default device.
         self._packed_sharding = (
@@ -739,6 +787,14 @@ class BatchScheduler:
         self._quant_mode = quant_mode(params)
         log.info("model weights: %.3f GB (%s)",
                  self._weight_bytes / 1e9, self._quant_mode or "bf16")
+        if config.ut_steps > 1:
+            log.info("looped model %s: %d layers walked %d times a token "
+                     "with one set of weights, %d cache layers, the final "
+                     "norm and the exit gate after every pass (the last "
+                     "pass's logits, always: serve_loop_exit_mass_total "
+                     "counts what the gate says)", config.name,
+                     config.num_layers, config.ut_steps,
+                     config.cache_layers)
         if config.is_moe:
             log.info("routed model %s: %d experts, top-%d %s; prefill "
                      "capacity factor %s; QK-norm %s; what the buckets "
@@ -877,7 +933,24 @@ class BatchScheduler:
         self._n_sparse_context = 0       # owned-by: _loop
         self._page_kv_layers = (
             config.cache_layers
-            if config.is_hybrid and not self._shared_kv_readers else 0)
+            if (config.is_hybrid and not self._shared_kv_readers)
+            or config.ut_steps > 1 else 0)
+        # A looped stack (ModelConfig.ut_steps > 1): passes over the
+        # stack the dispatches asked for (ut_steps a decode step, and a
+        # prefill dispatch that computes something), the weight bytes the
+        # decode steps' passes re-read (host arithmetic: passes x the
+        # stack's stored bytes), and the exit pdf summed over live rows a
+        # pass, which rides behind a decode dispatch's tokens
+        # (_with_moe).
+        self._looped = config.ut_steps > 1
+        self._stack_bytes = (param_bytes(params["layers"])
+                             if self._looped else 0)
+        self._n_loop_passes = 0          # owned-by: _loop
+        self._n_loop_weight_bytes = 0    # owned-by: _loop
+        self._loop_exit_mass = [0.0] * config.ut_steps   # owned-by: _loop
+        # Iterations in which a request waited for pages (_waiting) while
+        # a row stood free: the pool, not the rows, held the batch.
+        self._n_page_starved_iters = 0   # owned-by: _loop
         self._moe_unread: collections.deque = collections.deque()
         self._n_decode_row_steps = 0
         self._n_decode_sort_dispatches = 0
@@ -1102,6 +1175,10 @@ class BatchScheduler:
             behind its tokens ([B] or [K, B] -> [K * B + 2])."""
             if moe is None:
                 return toks
+            if moe.dtype != jnp.int32:
+                # A looped model's exit mass, float32 [ut_steps]: its
+                # bits, which _process_tick reads back as they are.
+                moe = jax.lax.bitcast_convert_type(moe, jnp.int32)
             return jnp.concatenate([toks.reshape(-1), moe])
 
         def _make_decode(kv_window: int):
@@ -1117,6 +1194,12 @@ class BatchScheduler:
                     # A routed model's step also counts the experts its
                     # live rows reached (models/mixtral.py).
                     logits, cache, moe = model.decode_step_paged_touched(
+                        params, config, tokens, cache, mesh, active=active,
+                        pages=pages)
+                elif self._looped:
+                    # A looped model's step also sums its exit pdf over
+                    # the live rows (models/llama.py).
+                    logits, cache, moe = model.decode_step_paged_exit(
                         params, config, tokens, cache, mesh, active=active,
                         pages=pages)
                 else:
@@ -1168,6 +1251,10 @@ class BatchScheduler:
                 if routed:
                     (toks_all, _, next_tokens, cache, _, (keys, ring),
                      moe) = model.decode_fused_touched(
+                        params, config, tokens, cache, mesh, **kwargs)
+                elif self._looped:
+                    (toks_all, _, next_tokens, cache, _, (keys, ring),
+                     moe) = model.decode_fused_exit(
                         params, config, tokens, cache, mesh, **kwargs)
                 else:
                     (toks_all, _, next_tokens, cache, _,
@@ -1754,11 +1841,19 @@ class BatchScheduler:
                  rps) = _install_rows(ints[1], row_keys, toks, ints,
                                       floats, rings, keys, next_tokens,
                                       temps, top_ks, top_ps, ring, rps)
-                return (_with_moe(toks, moe), cache, keys, next_tokens,
-                        temps, top_ks, top_ps, ring, rps)
+                out = (_with_moe(toks, moe), cache, keys, next_tokens,
+                       temps, top_ks, top_ps, ring, rps)
+                return out + (carry,) if in_place else out
             # The carry kv/logits die here but have no same-shaped output
             # to alias into — donating them only trips XLA's unusable-
-            # donation warning, so they are freed by refcount instead.
+            # donation warning, so they are freed by refcount instead;
+            # except a LARGE carry, which is handed back so that it can be
+            # donated (_CARRY_IN_PLACE_BYTES; _dispatch_prefill_chunk
+            # drops it).
+            in_place = W * self._carry_token_bytes > _CARRY_IN_PLACE_BYTES
+            if in_place:
+                return jax.jit(prefill_chunk_final,
+                               donate_argnums=(2, 4, 5, 6, 7, 8, 9, 10, 11))
             return jax.jit(prefill_chunk_final,
                            donate_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 
@@ -2063,14 +2158,17 @@ class BatchScheduler:
         admission never meets a width nobody compiled.
 
         Default: 1, and 2 where two rows of this footprint stay inside
-        _ADMIT_PAIR_TOKENS (the comment there says why no wider): a
+        _ADMIT_PAIR_TOKENS and _ADMIT_PAIR_BYTES (the comments there say
+        why no wider): a
         burst of n requests runs ceil(n/2) pair dispatches. A fixed
         ``admit_chunk`` is the ONLY width, narrowed where the bucket
         would pass the HBM budget."""
         if self.admit_chunk:
             return (min(self.admit_chunk,
                         _pow2_floor(_ADMIT_TOKEN_BUDGET // footprint)),)
-        if self.num_slots > 1 and 2 * footprint <= _ADMIT_PAIR_TOKENS:
+        if (self.num_slots > 1 and 2 * footprint <= _ADMIT_PAIR_TOKENS
+                and 2 * footprint * self._carry_token_bytes
+                <= _ADMIT_PAIR_BYTES):
             return (1, 2)
         return (1,)
 
@@ -2950,6 +3048,13 @@ class BatchScheduler:
                         self._admit_pending(block=not self._any_active()
                                             and pending is None
                                             and self._prefill_carry is None)
+                    if (self._waiting and self._prefill_carry is None
+                            and any(s is None for s in self._slots)):
+                        # Admission has run and a request still waits for
+                        # pages beside a free row (a ladder in progress
+                        # holds rows that are not in _slots yet, and
+                        # admits nothing: not counted).
+                        self._n_page_starved_iters += 1
                     if self._closed.is_set():
                         return
                     if self._prefix is not None:
@@ -3738,6 +3843,8 @@ class BatchScheduler:
             "serve_stream_deltas_total": self._n_deltas,
             "serve_loop_seconds_total": self._loop_s,
             "serve_loop_iterations_total": self._loop_iter,
+            "serve_page_starved_iterations_total":
+                self._n_page_starved_iters,
             # Counts at the dispatch sites: admissions started (with
             # serve_admitted_total: requests per admission) and the
             # rows of their programs (1 - admitted / rows: the share
@@ -3840,6 +3947,11 @@ class BatchScheduler:
             out["serve_shared_kv_bytes_total"] = self._n_shared_kv_bytes
         if self._page_kv_layers:
             out["serve_page_kv_bytes_total"] = self._n_page_kv_bytes
+        if self._looped:
+            out["serve_loop_passes_total"] = self._n_loop_passes
+            out["serve_loop_weight_bytes_total"] = self._n_loop_weight_bytes
+            for t, m in enumerate(self._loop_exit_mass):
+                out[f'serve_loop_exit_mass_total{{pass="{t}"}}'] = m
         if self.config.is_indexed:
             out["serve_index_kv_bytes_total"] = self._n_index_kv_bytes
             out["serve_sparse_selected_total"] = self._n_sparse_selected
@@ -4402,6 +4514,7 @@ class BatchScheduler:
             packed = self._admit_host_arrays(chunk, rows, S, R, prefix)
         self._ledger.cut(ADMIT)
         if chunk:       # warm-up's all-padding dispatches do not count
+            self._n_loop_passes += self.config.ut_steps
             self._n_admit_batches += 1
             self._n_admit_pair_dispatches += len(chunk) > 1
             self._n_admit_rows_padded += R
@@ -4620,6 +4733,8 @@ class BatchScheduler:
         padded = all(off >= len(s.prompt_ids) - P0 for s in pc.chunk)
         self._n_prefill_chunks += 1
         self._n_prefill_chunks_padded += padded
+        if not padded:      # a wholly padded chunk computes nothing
+            self._n_loop_passes += self.config.ut_steps
         self._n_admit_pair_dispatches += len(pc.chunk) > 1
         self._n_prefill_padded += R * C
         pc.padded += padded
@@ -4669,13 +4784,15 @@ class BatchScheduler:
                 kv, logits, self._cache = prog(self._params, packed, kv,
                                                logits, self._cache)
             else:
+                # (a large carry comes back behind them, donated so that
+                # the chunk wrote it in place, and is dropped here)
                 (toks_dev, self._cache, self._keys, self._next_dev,
                  self._temps_dev, self._top_ks_dev, self._top_ps_dev,
                  self._ring_dev, self._rps_dev) = prog(
                     self._params, packed, kv, logits,
                     self._cache, self._keys, self._next_dev,
                     self._temps_dev, self._top_ks_dev, self._top_ps_dev,
-                    self._ring_dev, self._rps_dev)
+                    self._ring_dev, self._rps_dev)[:9]
         self._chunk_shapes_run.add(shape_key)
         if toks_dev is not None:
             self._last_out = toks_dev
@@ -4767,6 +4884,10 @@ class BatchScheduler:
             self._n_sparse_context += n * in_ctx
         self._n_page_kv_bytes += (self._page_kv_layers * ctx_tokens
                                   * self._page_token_bytes)
+        if self._looped:
+            self._n_loop_passes += K * self.config.ut_steps
+            self._n_loop_weight_bytes += (K * self.config.ut_steps
+                                          * self._stack_bytes)
         if active != self._active_host:
             # Re-upload the mask only when the active set changed (it only
             # moves on admission/finish — not per tick).
@@ -4844,6 +4965,13 @@ class BatchScheduler:
                 self._n_moe_routed_pairs += int(toks[2 - w])
                 self._n_moe_local_pairs += int(toks[3 - w])
             toks = toks[:-w]
+        elif self._looped:
+            # The dispatch's exit mass rides behind its tokens, a pass an
+            # entry, as float32 bits (_with_moe).
+            T = self.config.ut_steps
+            for t, m in enumerate(toks[-T:].view(np.float32)):
+                self._loop_exit_mass[t] += float(m)
+            toks = toks[:-T]
         toks = toks.reshape(K, -1)
         with self._phase("stream"):
             for row, slot in enumerate(snapshot):
@@ -5588,6 +5716,7 @@ class BatchScheduler:
         self._ledger.cut(ADMIT)
         # A wake is an admission whose program runs every row at the
         # suffix bucket: B x S positions for the waking rows' suffixes.
+        self._n_loop_passes += self.config.ut_steps
         self._n_admit_batches += 1
         self._n_admit_rows_padded += B
         self._n_prefill_tokens += sum(int(ints[0, row]) for _, row in live)
@@ -5681,7 +5810,11 @@ class BatchScheduler:
             self._trace.add(slot.req.trace_id, "sched.decode", t_first,
                             wall, tokens=len(slot.ids), row=row,
                             steps=steps, steps_cut=steps_cut, chunks=chunks,
-                            padded=padded, admits=admits)
+                            padded=padded, admits=admits,
+                            # A looped model's span also says how many
+                            # passes over the stack its steps were.
+                            **({"passes": steps * self.config.ut_steps}
+                               if self._looped else {}))
             self._trace.add(slot.req.trace_id, "sched.decode.cut", t_first,
                             min(cut_s, wall))
         for s in self._sources:

@@ -360,3 +360,47 @@ def test_the_index_keys_decode_write_updates_them_in_place(described,
               if re.search(rf"= {shape}[^ ]* copy\(", line)]
     assert not copies, copies[:3]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+# The pool of benchmark/configs/ouro-2.6b.json: 192 cache layers (48
+# layers x 4 passes), 193 pages of 64, 16 kv heads x 128, 32 rows.
+OURO = dict(L=192, pages=193, hkv=16, rows=32)
+
+
+def test_a_looped_stacks_decode_write_lands_all_its_passes_in_place(
+        described, no_cache):
+    """``write_decode_burst`` with a looped stack's step, donated: K and V
+    of every (pass, layer) pair stacked [192, 32, 16, 128], as
+    models/llama._walk hands them out (an outer scan's ys reshaped: no
+    data moves). No instruction of the pool's or the scales' shape is a
+    copy (either would be gigabytes a step). The temporaries are the
+    rows' scale pages, read, updated and written back: [192, 32, 16, 128]
+    float32 is 50 MB, a few of them for K and for V (0.41 GB in all,
+    against Mixtral's 6 layers x 8 heads under 8 MB: they grow with the
+    cache layers x heads x rows, never with the pool)."""
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops.paged_kv import PagedKVCache, write_decode_burst
+    L, N, H, B = OURO["L"], OURO["pages"], OURO["hkv"], OURO["rows"]
+    pool = PagedKVCache(
+        k=described((L, N, PS, H, D), jnp.int8),
+        v=described((L, N, PS, H, D), jnp.int8),
+        page_table=described((B, 32), jnp.int32),
+        lengths=described((B,), jnp.int32),
+        k_scale=described((L, N, H, 128), jnp.float32),
+        v_scale=described((L, N, H, 128), jnp.float32))
+
+    def burst(cache, k, v, inc):
+        # [passes, layers, ...] -> [cache_layers, ...], as the walk does.
+        flat = lambda a: a.reshape((L,) + a.shape[2:])
+        return write_decode_burst(cache, flat(k), flat(v), inc)
+
+    kv = described((4, L // 4, B, H, D), jnp.bfloat16)
+    compiled = jax.jit(burst, donate_argnums=(0,)).lower(
+        pool, kv, kv, described((B,), jnp.int32)).compile()
+    shapes = (rf"f32\[{L},{N},{H},128\]", rf"s8\[{L},{N},{PS},{H},{D}\]")
+    copies = [line.strip()[:200] for line in compiled.as_text().splitlines()
+              if any(re.search(rf"= {s}\S* copy(-start)?\(", line)
+                     for s in shapes)]
+    assert not copies, "the pool is copied whole:\n" + "\n".join(copies)
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024

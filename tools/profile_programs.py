@@ -3,7 +3,7 @@ step of a benchmark configuration, on the chip, without a warm-up.
 
 Boots what a benchmark cell's child boots (as tools/check_reference_limit.py
 does: the configuration file, its stack, random weights, ``SERVE_WARMUP=0``,
-no HTTP), calls the family's ``prefill_chunk_counted`` on a one-row carry
+no HTTP), calls the family's ``prefill_chunk[_counted]`` on a one-row carry
 of ``SERVE_MAX_SEQ`` positions at each ``--offsets`` and its
 ``decode_step_paged`` at each ``--windows`` over ``--rows`` live rows of
 ``--context`` positions in the scheduler's own pool, and the
@@ -61,6 +61,10 @@ def main() -> None:
     ap.add_argument("--fuse", type=int, default=1,
                     help="steps of the scheduler's decode program that "
                     "--sampler runs: 1 the plain step, more the fused scan")
+    ap.add_argument("--carry", type=int, default=0,
+                    help="positions of the chunks' dense carry (0: "
+                    "SERVE_MAX_SEQ; a looped stack's carry of 2,048 is "
+                    "3.2 GB beside its pool)")
     ap.add_argument("--rows", type=int, default=14)
     ap.add_argument("--context", type=int, default=9300)
     args = ap.parse_args()
@@ -129,13 +133,20 @@ def main() -> None:
 
     C = sched.prefill_chunk
     for off in map(int, filter(None, args.offsets.split(","))):
-        carry = KVCache.create(config, 1, sched.max_seq, dtype=sched._dtype)
+        carry = KVCache.create(config, 1, args.carry or sched.max_seq,
+                               dtype=sched._dtype)
         toks = jnp.full((1, C), 7, jnp.int32)
         valid = jnp.ones((1, C), bool)
+        # The dense family's chunk takes no mask of real positions and
+        # hands no counts back.
         chunk = jax.jit(lambda p, t, v, c, off=off: (
             model.prefill_chunk_counted(
                 p, config, t, c, off, v, None,
-                last_idx=jnp.zeros((1,), jnp.int32))[0]))
+                last_idx=jnp.zeros((1,), jnp.int32))
+            if hasattr(model, "prefill_chunk_counted")
+            else model.prefill_chunk(
+                p, config, t, c, off, None,
+                last_idx=jnp.zeros((1,), jnp.int32)))[0])
         traced(f"chunk_at_{off}", lambda: chunk(params, toks, valid, carry),
                2, C, C * off + C * (C + 1) / 2, off + C)
     S = sched.max_seq
