@@ -41,10 +41,11 @@ over the same scores:
   ``B x W x hd``).
 - :func:`_paged_attention_flash_append` — a Pallas kernel, grid ``(row,
   chunk)``: each program DMAs one bounded chunk of pages (and scale
-  rows) and folds it into online-softmax state held in VMEM scratch
-  across the chunk axis; a chunk that starts past its row's length is
-  neither fetched nor folded, so its cost follows the rows' LENGTHS.
-  HBM sees each live page once. TPU only.
+  rows) and folds it, a tile of half a chunk at a time, into
+  online-softmax state held in VMEM scratch across the chunk axis; a
+  tile that starts past its row's length is neither fetched nor folded,
+  so its cost follows the rows' LENGTHS. HBM sees each live page once.
+  TPU only.
 
 The rule that chooses (:func:`_flash_append_policy`, guarded by
 :func:`flash_append_blocked`): the kernel from ``W >= max(256, 1024 *
@@ -78,33 +79,17 @@ from ..utils.device import on_tpu
 NEG_INF = -1e30
 
 
-def _gqa_selection_matrices(Hq: int, Hkv: int, D: int, rep: int):
-    """Constant 0/1 selection matrices built from in-register iotas
-    for the flash-append kernel, which turns every GQA shuffle into an
-    MXU dot: SEL tiles / collapses per-head D-blocks, BLOCKM masks q
-    columns to their own kv block (built both ways — Mosaic cannot
-    transpose i1), EXPT expands kv-head rows to query-head columns.
-    Returns
-    (sel bf16 [HD, D], blockm bool [HD, Hq], blockm_t bool [Hq, HD],
-    expt f32 [Hq, Hkv])."""
+def _gqa_block_mask(Hq: int, Hkv: int, D: int, rep: int):
+    """bool [Hq, Hkv * D], built from in-register iotas for the
+    flash-append kernel: a query head's row is true over its own kv
+    head's D columns of a token's flattened ``[Hkv * D]`` row. Queries
+    masked by it score all heads in ONE dot against a flattened K tile,
+    and of ``p . V`` over a flattened V tile it keeps each head's own
+    block."""
     HD = Hkv * D
-    cmod = jax.lax.broadcasted_iota(jnp.int32, (HD, D), 0) % D
-    drng = jax.lax.broadcasted_iota(jnp.int32, (HD, D), 1)
-    sel = (cmod == drng).astype(jnp.bfloat16)
-    cdiv = jax.lax.broadcasted_iota(jnp.int32, (HD, Hq), 0) // D
-    hdiv = jax.lax.broadcasted_iota(jnp.int32, (HD, Hq), 1) // rep
-    blockm = cdiv == hdiv
-    cdiv2 = jax.lax.broadcasted_iota(jnp.int32, (Hq, HD), 1) // D
-    hdiv2 = jax.lax.broadcasted_iota(jnp.int32, (Hq, HD), 0) // rep
-    blockm_t = cdiv2 == hdiv2
-    return sel, blockm, blockm_t, _gqa_expander(Hq, Hkv, rep)
-
-
-def _gqa_expander(Hq: int, Hkv: int, rep: int):
-    """EXPT alone (f32 [Hq, Hkv]): kv-head rows to query-head rows."""
-    hh = jax.lax.broadcasted_iota(jnp.int32, (Hq, Hkv), 0) // rep
-    gg = jax.lax.broadcasted_iota(jnp.int32, (Hq, Hkv), 1)
-    return (hh == gg).astype(jnp.float32)
+    cdiv = jax.lax.broadcasted_iota(jnp.int32, (Hq, HD), 1) // D
+    hdiv = jax.lax.broadcasted_iota(jnp.int32, (Hq, HD), 0) // rep
+    return cdiv == hdiv
 
 
 def _scaled(scores, D: int, scale):
@@ -812,8 +797,10 @@ def paged_attention_verify_append(q_blk, k_blk, v_blk, cache, lengths,
 # hd=1024, and proportionally MORE tokens per chunk at narrower KV
 # geometries (same VMEM bytes, fewer grid programs). The VMEM ceiling is
 # geometry-invariant by construction: double-buffered int8 k+v DMA
-# slots 4 MB + the chunk-local bf16 dequant view 4 MB + f32 softmax
-# state ~0.2 MB = 8.2 MB, under the 16 MB stack. Module-level so tests
+# slots 4 MB + a tile's bf16 view of K and of V (half a chunk: 2 MB)
+# + f32 softmax state ~0.2 MB = 6.2 MB, under the 16 MB stack (8.2 MB
+# until PR 54, which widens a tile where it widened a chunk).
+# Module-level so tests
 # can shrink it to exercise many-chunk grids in interpret mode at tiny
 # geometries.
 _FLASH_CHUNK_TOK_BYTES = 1024
@@ -925,10 +912,44 @@ def flash_append_chunk_pages(hd: int, itemsize: int, page_size: int,
     return max(1, min(pages, tok_budget // page_size))
 
 
+# Bytes of one (k or v) buffer side per token a fold step takes of a
+# fetched chunk AT THE CALIBRATION GEOMETRY, scaled as the chunk's are:
+# 512 int8 tokens at hd = 1024, 256 at hd = 2048, 1,024 at hd = 512 —
+# half a chunk. Module-level so tests can shrink it to put many tiles in
+# a chunk at tiny geometries.
+_FLASH_TILE_TOK_BYTES = 512
+
+# What the kernel's fold leaves out, for tools/check_append_kernel.py
+# ``time-fold`` alone (read at trace time; empty wherever anything is
+# served): "convert" folds zeros where it would widen the fetched K and
+# V, "dots" leaves both MXU dots out as well, "fold" waits for a tile's
+# pages and does nothing with them.
+_FOLD_WITHOUT: frozenset = frozenset()
+
+
+def flash_append_tile_pages(hd: int, itemsize: int, page_size: int,
+                            chunk_pages: int) -> int:
+    """Pages a fold step takes of a chunk — a tile — for a pool of ``hd
+    = Hkv * head_dim`` numbers a token, ``itemsize`` bytes each: the
+    whole pages of _FLASH_TILE_TOK_BYTES' budget, and a divisor of the
+    chunk. A tile is what a row's walk stops at and what a fold step
+    pays its fixed cost for (0.4 us on a v5e: the MXU's fill and drain
+    and the softmax's chain stand in line once a step), so its size is a
+    trade between the positions folded past a row's end (half a tile a
+    row) and the steps a row takes: measured at 16 MHA heads (hd 2,048)
+    256 tokens serve 450-token rows best, at hd 1,024 and 512 (GQA) 512
+    tokens serve 450-token and 13 K-token rows alike (PERF.md section 6,
+    PR 54)."""
+    most = max(1, _FLASH_TILE_TOK_BYTES * _FLASH_HD_REF
+               // (hd * itemsize * page_size))
+    return max(n for n in range(1, min(most, chunk_pages) + 1)
+               if chunk_pages % n == 0)
+
+
 def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
-                              chunk_pages: int, num_chunks: int, rep: int,
-                              scale: float, compute_dtype,
-                              masked: bool = False):
+                              chunk_pages: int, tile_pages: int,
+                              num_chunks: int, rep: int, scale: float,
+                              compute_dtype, masked: bool = False):
     """Build the multi-chunk flash-append kernel body: ONE program per
     (row, chunk) of a ``(B, num_chunks)`` grid — the split-K /
     flash-decoding shape (Dao et al.; the paged pool walk is vLLM
@@ -936,7 +957,7 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
     for a fixed row the chunk programs run back to back and the
     online-softmax state (m, l, acc) lives in VMEM **scratch
     accumulators** that persist across them — VMEM holds one bounded
-    chunk's tiles, never a whole window (a whole-window scratch
+    chunk's pages, never a whole window (a whole-window scratch
     overflowed the VMEM stack at 2,048-token chunks). Structure:
 
     - **append semantics**: chunk 0 INITIALISES the scratch state with
@@ -950,16 +971,20 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
       indexed by global step parity — the grid replaces a
       kernel-internal chunk loop, so launch overhead amortises across
       programs and no program serialises a whole window's DMA waits.
-    - **work follows the rows' lengths** (``holds_rows``): a chunk that
-      starts at or past its row's length is skipped WHOLE — the
-      program before it does not fetch it, its own program neither
-      waits nor folds, and only the seed (chunk 0) and the finalise
-      (last chunk) still run. The window is the power of two over the
-      LONGEST live row, so among rows of ragged lengths (and free rows,
-      whose length is 0) most of the grid is such chunks; an empty
-      program costs a fraction of a microsecond, which is what makes
-      the window's size stop mattering.
-    - **inside a chunk that is folded** nothing is skipped: a page past
+    - **work follows the rows' lengths, a tile at a time**
+      (``live_tiles``): a chunk is fetched and folded in tiles of
+      :func:`flash_append_tile_pages` pages (half a chunk), and a tile
+      that starts at or past its row's length is skipped WHOLE — the
+      program before it does not fetch it, its own program neither waits
+      for it nor folds it. A chunk with no live tile (a free row's every
+      chunk, a short row's tail under a window some other row set) costs
+      the seed (chunk 0) and the finalise (last chunk) alone, a fraction
+      of a microsecond, which is what makes the window's size stop
+      mattering; a row of 450 tokens under llama's 1,024-token chunk
+      folds 512 positions. The fold is a loop over the row's live tiles
+      that waits for a tile's pages where it folds them, so tile 0 is
+      folded while the last tile's pages land.
+    - **inside a tile that is folded** nothing is skipped: a page past
       the row's last is fetched through its table entry (0, the garbage
       page, by the pool contract) and masks to NEG_INF by position, and
       in a non-chunk-multiple window the page walk index clamps to
@@ -967,16 +992,27 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
       Skipping single page DMAs would leave uninitialised VMEM, which
       can be NaN, and a NaN row poisons the p.v dot even at zero
       probability.
+    - **the scores lie heads x tokens** (``[Hq, tile]``): one dot of the
+      block-masked queries ``[Hq, Hkv * D]`` (:func:`_gqa_block_mask`)
+      against the tile's flattened keys, contracted over the keys' own
+      minor axis (``q . K^T``, the keys being the MXU's stationary
+      operand), so the softmax chain runs on full 128-lane rows, its
+      state is a column a head, and ``p . V`` takes the probabilities
+      as they lie: ``[Hq, tile] x [tile, Hkv * D]`` accumulates every
+      head against every kv block in ``acc [Hq, Hkv * D]``, of which the
+      finalise keeps each head's own block. (Until PR 54 the scores lay
+      tokens x heads, 16 or 32 lanes of 128, and a live 512-token chunk
+      of 16 heads cost 5.3 us where waiting for its pages costs 3.3:
+      the table is in PERF.md section 6, PR 54.)
     - **int8 pools** (``quantized``): the per-page scale rows
       ([Hkv, ps_pad] f32, the head-major layout paged_kv.py stores for
-      kernel DMAs) ride the same DMA slots; k scales fold into the
+      kernel DMAs) ride the same DMA slots and are the scores' own
+      layout: a tile's pages side by side are ``[Hkv, tile]``, one row a
+      kv head (``rep`` query heads read a row by a broadcast and a
+      select; at ``rep`` 1 it is the row itself). k scales fold into the
       scores, v scales into the probabilities — the same
       fold-outside-the-dots contract as the gather path, so HBM sees
       int8 KV only.
-    - **selection-matmul GQA math** (_gqa_selection_matrices): scores
-      run as ONE [Ct, HD] x [HD, Hq] dot per chunk and the softmax chain
-      on full-width [Ct, Hq] arrays; the scale folds are one
-      [Ct, Hkv] x [Hkv, Hq] expander dot each.
     - ``compute_dtype``: bf16 on hardware (the MXU's preferred operand
       dtype; int8 -> bf16 is the cheap unpack), f32 in interpret mode so
       the CPU parity tests pin the kernel against the oracle at f32
@@ -990,6 +1026,11 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
       positions past the row's length; a position at NEG_INF weighs
       exactly 0, also where nothing has been kept yet.
     """
+    tiles = chunk_pages // tile_pages
+    Ct = chunk_pages * page_size
+    Tt = tile_pages * page_size
+    without = _FOLD_WITHOUT
+
     def body(*refs):
         # Prefetched scalars, inputs, the output, scratch: in that order,
         # each group with what its variant adds.
@@ -1013,7 +1054,7 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
         ly = layer_ref[0]
         length = len_ref[b]
 
-        def dma(slot, bb, cc, i: int):
+        def dma(slot, bb, cc, i):
             # Clamped page-walk index: see the docstring's partial-chunk
             # note. pt entries past a row's allocation are 0 (garbage
             # page) by the pool contract, so every fetch is in bounds.
@@ -1040,16 +1081,6 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
                     sems.at[len(copies), slot, i]))
             return copies
 
-        def start_chunk(slot, bb, cc) -> None:
-            for i in range(chunk_pages):
-                for d in dma(slot, bb, cc, i):
-                    d.start()
-
-        def wait_chunk(slot, bb, cc) -> None:
-            for i in range(chunk_pages):
-                for d in dma(slot, bb, cc, i):
-                    d.wait()
-
         # Global step index orders the whole grid's chunk walk; its
         # parity picks the DMA slot (num_chunks may be odd, so parity
         # must run THROUGH row boundaries, not reset per row — and
@@ -1058,19 +1089,27 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
         step = b * num_chunks + c
         slot = jax.lax.rem(step, 2)
         rows = pl.num_programs(0)
-        Ct = chunk_pages * page_size
 
-        def holds_rows(bb, cc):
-            # A chunk that starts at or past its row's length (a free
-            # row's every chunk, a short row's tail under a window some
-            # other row set) is not fetched by the program before it,
-            # not waited for and not folded: every position in it would
-            # mask to NEG_INF and weigh exactly zero. The issuer and the
-            # waiter read the same length — the FETCHED row's, which at
-            # a row boundary is the next row's.
-            return cc * Ct < len_ref[jnp.minimum(bb, rows - 1)]
+        def live_tiles(bb, cc):
+            # The tiles of chunk ``cc`` that start inside row ``bb``'s
+            # context: the others are not fetched by the program before
+            # them, not waited for and not folded (every position in
+            # them would mask to NEG_INF and weigh exactly zero). The
+            # issuer and the waiter read the same length — the FETCHED
+            # row's, which at a row boundary is the next row's.
+            left = len_ref[jnp.minimum(bb, rows - 1)] - cc * Ct
+            return jax.lax.div(jnp.clip(left, 0, Ct) + (Tt - 1), Tt)
 
-        @pl.when((step == 0) & holds_rows(b, c))
+        def start_chunk(slot, bb, cc) -> None:
+            live = live_tiles(bb, cc)
+            for t in range(tiles):
+                @pl.when(t < live)
+                def _start_tile():
+                    for i in range(t * tile_pages, (t + 1) * tile_pages):
+                        for d in dma(slot, bb, cc, i):
+                            d.start()
+
+        @pl.when(step == 0)
         def _warmup():
             start_chunk(0, b, c)
 
@@ -1079,111 +1118,134 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
         nb = jnp.where(c + 1 == num_chunks, b + 1, b)
         nc = jnp.where(c + 1 == num_chunks, 0, c + 1)
 
-        @pl.when((step + 1 < rows * num_chunks) & holds_rows(nb, nc))
+        @pl.when(step + 1 < rows * num_chunks)
         def _prefetch():
             start_chunk(jax.lax.rem(step + 1, 2), nb, nc)
 
-        q = q_ref[0].astype(jnp.float32)                 # [Hq, D]
+        q = q_ref[b].astype(jnp.float32)                 # [Hq, D]
         Hq, D = q.shape
         Hkv = Hq // rep
         HD = Hkv * D
+        group = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // rep
+
+        def heads(x):
+            # kv-head rows -> query-head rows ([Hkv, n] -> [Hq, n]): a
+            # row broadcast and a select a kv head, exact and off the
+            # MXU (as an expander dot, float32 x float32, it cost a
+            # GQA tile 0.2 us twice over; PERF.md section 6, PR 54).
+            if rep == 1:
+                return x
+            out = jnp.zeros((Hq, x.shape[1]), x.dtype)
+            for g in range(Hkv):
+                out = jnp.where(group == g, x[g:g + 1], out)
+            return out
 
         @pl.when(c == 0)
         def _seed():
             # Append init: state = the current token's softmax term at
             # FULL precision (p_cur = exp(s_cur - m) = 1 at m = s_cur).
-            # State layout matches the chunk math: m/l [1, Hq],
-            # acc [Hq, D]. Unconditional: a row of length 0 returns
+            # State layout matches the fold's: m/l [Hq, 1], acc
+            # [Hq, HD] with v_cur in every kv block (the finalise reads
+            # a head's own). Unconditional: a row of length 0 returns
             # this term alone.
-            expt = _gqa_expander(Hq, Hkv, rep)
-            kcur = jax.lax.dot(expt, kc_ref[0].astype(jnp.float32),
-                               preferred_element_type=jnp.float32)
-            vcur = jax.lax.dot(expt, vc_ref[0].astype(jnp.float32),
-                               preferred_element_type=jnp.float32)
-            s_cur = jnp.sum(q * kcur, axis=-1,
-                            keepdims=True).T * scale             # [1, Hq]
+            kcur = heads(kc_ref[b].astype(jnp.float32))
+            vcur = heads(vc_ref[b].astype(jnp.float32))
+            s_cur = jnp.sum(q * kcur, axis=-1, keepdims=True) * scale
+            ones = jnp.ones((Hq, 1), jnp.float32)
+            acc = jnp.concatenate([vcur] * Hkv, axis=1)          # [Hq, HD]
             if masked:      # a current token that is not kept: nothing
                 cur = ckeep_ref[b] > 0
                 m_ref[:] = jnp.where(cur, s_cur, NEG_INF)
-                l_ref[:] = jnp.where(cur, jnp.ones((1, Hq), jnp.float32),
-                                     0.0)
-                acc_ref[:] = jnp.where(cur, vcur, 0.0)
+                l_ref[:] = jnp.where(cur, ones, 0.0)
+                acc_ref[:] = jnp.where(cur, acc, 0.0)
             else:
                 m_ref[:] = s_cur
-                l_ref[:] = jnp.ones((1, Hq), jnp.float32)
-                acc_ref[:] = vcur                                # [Hq, D]
+                l_ref[:] = ones
+                acc_ref[:] = acc
 
-        @pl.when(holds_rows(b, c))
+        live = live_tiles(b, c)
+
+        @pl.when(live > 0)
         def _fold():
-            # Constant selection matrices (_gqa_selection_matrices).
-            sel, blockm, blockm_t, expt = _gqa_selection_matrices(
-                Hq, Hkv, D, rep)
-            sel_c = sel.astype(compute_dtype)
+            # The queries, each over its own kv block of a flattened
+            # row and zero elsewhere: [Hq, HD].
+            q_rows = jnp.where(_gqa_block_mask(Hq, Hkv, D, rep),
+                               jnp.concatenate([q] * Hkv, axis=1),
+                               0.0).astype(compute_dtype)
 
-            # Q stacked into its kv block: [HD, Hq]. Hkv copies of q's
-            # columns, the bits ``sel @ q.T`` gives (sel is 0/1), without
-            # the MXU round trip: inside this region that dot cost a
-            # live program 1.1 us of its 5 (v5e, PERF.md section 6,
-            # PR 31), and outside it every empty program 0.6 us.
-            q_cols = jnp.concatenate([q.T.astype(compute_dtype)] * Hkv,
-                                     axis=0)
-            q_blk = jnp.where(blockm, q_cols,
-                              jnp.zeros((), compute_dtype))      # [HD, Hq]
+            def flat(buf, p0):
+                # A tile's pages as [Tt, HD] MXU operands.
+                if "convert" in without:
+                    return jnp.zeros((Tt, HD), compute_dtype)
+                return buf[slot, pl.ds(p0, tile_pages)].reshape(
+                    Tt, HD).astype(compute_dtype)
 
-            wait_chunk(slot, b, c)
-            kflat = kbuf[slot].reshape(Ct, HD).astype(compute_dtype)
-            vflat = vbuf[slot].reshape(Ct, HD).astype(compute_dtype)
-            s = jax.lax.dot(kflat, q_blk,
-                            preferred_element_type=jnp.float32) * scale
-            if quantized:
-                # [Ct, Hkv] scale columns -> [Ct, Hq] via the expander
-                # dot (one MXU op; per-page segment concats measured
-                # overhead-bound on the VPU).
-                sk = jnp.concatenate(
-                    [ksbuf[slot][i, :, :page_size].T
-                     for i in range(chunk_pages)], axis=0)       # [Ct, Hkv]
-                s = s * jax.lax.dot(sk, expt.T,
-                                    preferred_element_type=jnp.float32)
-            pos = c * Ct + jax.lax.broadcasted_iota(
-                jnp.int32, (Ct, 1), dimension=0)
-            seen = pos < length
-            if masked:
-                mk = jnp.concatenate(
-                    [mbuf[slot][i, :, :page_size].T
-                     for i in range(chunk_pages)], axis=0)       # [Ct, Hkv]
-                seen = seen & (jax.lax.dot(
-                    mk, expt.T, preferred_element_type=jnp.float32) > 0.5)
-            s = jnp.where(seen, s, NEG_INF)                      # [Ct, Hq]
+            def lanes(buf, p0):
+                # A tile's scale (or mask) rows side by side, a row a
+                # query head: [Hq, Tt].
+                tile = buf[slot, pl.ds(p0, tile_pages)]
+                return heads(jnp.concatenate(
+                    [tile[i, :, :page_size] for i in range(tile_pages)],
+                    axis=1))
 
-            m_prev = m_ref[:]                                    # [1, Hq]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_cur)                      # [1, Hq]
-            probs = jnp.exp(s - m_cur)                           # [Ct, Hq]
-            if masked:      # exp(NEG_INF - NEG_INF) is 1, not 0
-                probs = jnp.where(seen, probs, 0.0)
-            # Denominator sums the UNSCALED probabilities (v scales fold
-            # into the p.v dot only — the gather path's contract).
-            l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=0,
-                                                  keepdims=True)
-            if quantized:
-                sv = jnp.concatenate(
-                    [vsbuf[slot][i, :, :page_size].T
-                     for i in range(chunk_pages)], axis=0)       # [Ct, Hkv]
-                probs = probs * jax.lax.dot(
-                    sv, expt.T, preferred_element_type=jnp.float32)
-            out_full = jax.lax.dot(probs.T.astype(compute_dtype), vflat,
-                                   preferred_element_type=jnp.float32)
-            out_full = jnp.where(blockm_t, out_full, 0.0)        # [Hq, HD]
-            acc_ref[:] = acc_ref[:] * alpha.T + jax.lax.dot(
-                out_full.astype(compute_dtype), sel_c,
-                preferred_element_type=jnp.float32)              # [Hq, D]
-            m_ref[:] = m_cur
+            def fold_tile(t, carry):
+                p0 = t * tile_pages
+                for i in range(tile_pages):
+                    for d in dma(slot, b, c, p0 + i):
+                        d.wait()
+                if "fold" in without:
+                    return carry
+                if "dots" in without:
+                    s = jnp.zeros((Hq, Tt), jnp.float32)
+                else:
+                    s = jax.lax.dot_general(
+                        q_rows, flat(kbuf, p0), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    s = s * lanes(ksbuf, p0)
+                pos = c * Ct + t * Tt + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, Tt), dimension=1)
+                seen = pos < length
+                if masked:
+                    seen = seen & (lanes(mbuf, p0) > 0.5)
+                s = jnp.where(seen, s, NEG_INF)                  # [Hq, Tt]
+
+                m_prev = m_ref[:]                                # [Hq, 1]
+                m_cur = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_cur)                  # [Hq, 1]
+                probs = jnp.exp(s - m_cur)                       # [Hq, Tt]
+                if masked:      # exp(NEG_INF - NEG_INF) is 1, not 0
+                    probs = jnp.where(seen, probs, 0.0)
+                # Denominator sums the UNSCALED probabilities (v scales
+                # fold into the p.v dot only — the gather path's
+                # contract).
+                l_ref[:] = l_ref[:] * alpha + jnp.sum(probs, axis=1,
+                                                      keepdims=True)
+                if quantized:
+                    probs = probs * lanes(vsbuf, p0)
+                if "dots" in without:
+                    pv = jnp.zeros((Hq, HD), jnp.float32)
+                else:
+                    pv = jax.lax.dot(probs.astype(compute_dtype),
+                                     flat(vbuf, p0),
+                                     preferred_element_type=jnp.float32)
+                acc_ref[:] = acc_ref[:] * alpha + pv             # [Hq, HD]
+                m_ref[:] = m_cur
+                return carry
+
+            jax.lax.fori_loop(0, live, fold_tile, 0)
 
         @pl.when(c == num_chunks - 1)
         def _finalise():
-            # l >= 1 always: the current token's own term seeds it (a
-            # selection keeps at least one position of a row).
-            o_ref[0] = (acc_ref[:] / l_ref[:].T).astype(o_ref.dtype)
+            # Of acc's [Hq, HD] each head's own kv block. l >= 1 always:
+            # the current token's own term seeds it (a selection keeps
+            # at least one position of a row).
+            out = jnp.zeros((Hq, D), jnp.float32)
+            for g in range(Hkv):
+                out = jnp.where(group == g, acc_ref[:, g * D:(g + 1) * D],
+                                out)
+            o_ref[b] = (out / l_ref[:]).astype(o_ref.dtype)
 
     return body
 
@@ -1200,11 +1262,12 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
     online softmax carried in VMEM scratch across the chunk axis and
     seeded with the current token (_flash_append_kernel_body). HBM reads
     each page exactly once per (layer, step) — no gathered-window
-    materialisation — and only the pages of chunks that start inside
-    their row's context (PR 31). ``interpret`` runs it on the CPU, with
-    f32 dot operands, for hardware-free parity tests. ``keep`` ([B, W]
-    bool) and ``keep_cur`` ([B] bool): an indexed layer's selection, the
-    kernel's ``masked`` variant."""
+    materialisation — and only the pages of tiles that start inside
+    their row's context (chunks since PR 31, tiles since PR 54).
+    ``interpret`` runs it on the CPU, with f32 dot operands, for
+    hardware-free parity tests. ``keep`` ([B, W] bool) and ``keep_cur``
+    ([B] bool): an indexed layer's selection, the kernel's ``masked``
+    variant."""
     B, Hq, D = q.shape
     L, N, page_size, Hkv, _ = k_pages.shape
     rep = Hq // Hkv
@@ -1222,13 +1285,16 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
 
     masked = keep is not None
 
-    def row(b, c, *prefetched):
-        return (b, 0, 0)
+    def whole(b, c, *prefetched):
+        return (0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, Hq, D), row),
-        pl.BlockSpec((1, Hkv, D), row),
-        pl.BlockSpec((1, Hkv, D), row),
+        # The step's queries and current tokens, every row's, fetched
+        # once and held: a block a row is a DMA a program and a wait
+        # on it, which was most of what an empty program cost.
+        pl.BlockSpec((B, Hq, D), whole),
+        pl.BlockSpec((B, Hkv, D), whole),
+        pl.BlockSpec((B, Hkv, D), whole),
         pl.BlockSpec(memory_space=pl.ANY),      # k pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),      # v pool stays in HBM
     ]
@@ -1267,9 +1333,9 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
     # Cross-chunk online-softmax state (persists across the grid's
     # chunk axis; re-seeded at every row's chunk 0).
     scratch += [
-        pltpu.VMEM((1, Hq), jnp.float32),       # running max m
-        pltpu.VMEM((1, Hq), jnp.float32),       # running sum l
-        pltpu.VMEM((Hq, D), jnp.float32),       # unnormalised acc
+        pltpu.VMEM((Hq, 1), jnp.float32),       # running max m
+        pltpu.VMEM((Hq, 1), jnp.float32),       # running sum l
+        pltpu.VMEM((Hq, Hkv * D), jnp.float32),  # unnormalised acc
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1277,13 +1343,15 @@ def _paged_attention_flash_append(q, k_cur, v_cur, k_pages, v_pages,
         num_scalar_prefetch=len(prefetched),
         grid=(B, num_chunks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), row),
+        out_specs=pl.BlockSpec((B, Hq, D), whole),
         scratch_shapes=scratch + [
             pltpu.SemaphoreType.DMA((n_sem, 2, chunk_pages))],
     )
-    kernel = _flash_append_kernel_body(quantized, page_size, pages,
-                                       chunk_pages, num_chunks, rep, scale,
-                                       compute_dtype, masked)
+    tile_pages = flash_append_tile_pages(
+        Hkv * D, k_pages.dtype.itemsize, page_size, chunk_pages)
+    kernel = _flash_append_kernel_body(
+        quantized, page_size, pages, chunk_pages, tile_pages, num_chunks,
+        rep, scale, compute_dtype, masked)
     common = dict(grid_spec=grid_spec, interpret=interpret,
                   out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype))
     # A kernel's name is a literal where it is called (the trace's

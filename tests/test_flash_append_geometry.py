@@ -146,8 +146,8 @@ def test_int8_scale_folding_at_chunk_boundaries(monkeypatch):
     exactly ON a chunk boundary (16 = 2 pages/chunk at ps=8), one off
     either side, on a page boundary inside a chunk (8, 24), and at the
     full window — the geometry where a boundary off-by-one in the
-    scale concat or position mask shows. rep=1 config (tiny-tp): the
-    expander dot degenerates to identity, the other boundary worth
+    scale concat or position mask shows. rep=1 config (tiny-tp): a
+    scale row is a query head's own, the other boundary worth
     covering (every other case runs rep=2)."""
     _check_case("tiny-tp", 4, PS, [16, 17, 15, 8, 24, 32], True,
                 monkeypatch)
@@ -264,15 +264,17 @@ def test_chunks_past_a_rows_length_are_not_read(case, quantized, rep,
 
 
 def _chunking_used(monkeypatch, hd, dtype, ps, pages):
-    """(chunk_pages, num_chunks) that _paged_attention_flash_append hands
-    its kernel body for a pool of this geometry, read while it traces."""
+    """(chunk_pages, tile_pages, num_chunks) that
+    _paged_attention_flash_append hands its kernel body for a pool of
+    this geometry, read while it traces."""
     seen = []
     real = pa._flash_append_kernel_body
 
-    def spy(quantized, page_size, n_pages, chunk_pages, num_chunks, *rest):
-        seen.append((chunk_pages, num_chunks))
-        return real(quantized, page_size, n_pages, chunk_pages, num_chunks,
-                    *rest)
+    def spy(quantized, page_size, n_pages, chunk_pages, tile_pages,
+            num_chunks, *rest):
+        seen.append((chunk_pages, tile_pages, num_chunks))
+        return real(quantized, page_size, n_pages, chunk_pages, tile_pages,
+                    num_chunks, *rest)
 
     monkeypatch.setattr(pa, "_flash_append_kernel_body", spy)
     Hkv, D, B = hd // 128, 128, 2
@@ -307,7 +309,8 @@ def test_chunk_size_function_is_the_kernels(hd, int8_tokens, dtype, itemsize,
         want = min(pages, int8_tokens // itemsize // ps)
         assert pa.flash_append_chunk_pages(hd, itemsize, ps, pages) == want
         assert _chunking_used(monkeypatch, hd, dtype, ps, pages) == (
-            want, -(-pages // want))
+            want, pa.flash_append_tile_pages(hd, itemsize, ps, want),
+            -(-pages // want))
 
 
 # The boundary by pool geometry (hd = Hkv * head_dim): the first window

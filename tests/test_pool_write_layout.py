@@ -308,6 +308,35 @@ def test_the_masked_flash_append_compiles_for_the_chip(masked, described,
     assert ("paged_attention_flash_append_masked" in text) == masked
 
 
+# The per-head pools the flash-append kernel walks at short contexts:
+# 16 MHA heads (OLMoE, Ouro: 256-token tiles of a 512-token chunk) and
+# llama's 8 GQA heads (512 of 1,024), 32 rows, int8 and bf16.
+@pytest.mark.parametrize("heads, hkv, window, quantized", [
+    (16, 16, 512, True), (16, 16, 1024, True), (32, 8, 1024, True),
+    (16, 16, 512, False), (32, 8, 2048, False)])
+def test_the_tiled_flash_append_compiles_for_the_chip(
+        heads, hkv, window, quantized, described, no_cache):
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops import paged_attention as pa
+    B, L, bf = ROWS, 2, jnp.bfloat16
+    pages, N = window // PS, ROWS * 8 + 1
+    kv = described((L, N, PS, hkv, D), jnp.int8 if quantized else bf)
+    scale = described((L, N, hkv, 128), jnp.float32) if quantized else None
+    chunk = pa.flash_append_chunk_pages(hkv * D, kv.dtype.itemsize, PS, pages)
+    assert pa.flash_append_tile_pages(hkv * D, kv.dtype.itemsize, PS,
+                                      chunk) * 2 == chunk
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(lambda *a: pa._paged_attention_flash_append(
+            *a, pages=pages, quantized=quantized)).lower(
+            described((B, heads, D), bf), described((B, hkv, D), bf),
+            described((B, hkv, D), bf), kv, kv, scale, scale,
+            described((B, pages), jnp.int32), described((B,), jnp.int32),
+            described((), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attention_flash_append" in text
+
+
 def test_the_selection_kernels_compile_for_the_chip(described, no_cache):
     """The threshold kernel at a decode step's and a chunk's rows, and
     the index-scores kernel at a chunk against the longest carry, for a

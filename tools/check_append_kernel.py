@@ -20,7 +20,12 @@ path on the paired ``[4, 128]`` pool, and flash-append on the paired
 pool with zero-extended queries
 (ops/paged_attention.paged_attention_append_paired), at 32 rows of 1, 4,
 8 and 13 K and at 8 rows of 8 K, each beside the bytes it had to read
-over the chip's 819 GB/s.
+over the chip's 819 GB/s. ``time-fold`` takes the kernel apart at a
+short context (32 rows x 450 tokens, OLMoE's and Ouro's 16 MHA heads
+and llama's GQA, W 512 / 1,024 / 2,048): the whole kernel, then
+without the int8 -> bf16 widening of K and V, then without its two MXU
+dots as well, then waiting for the pages and folding nothing, each
+beside the time its bytes ask (ops/paged_attention._FOLD_WITHOUT).
 """
 
 from __future__ import annotations
@@ -173,6 +178,25 @@ def run_prefill_flash(B=1, S=2048, heads=GQA) -> None:
            f"prefill flash B={B} S={S}")
 
 
+def _layer_step_ms(one, q, k_cur, cache, lens, layers: int, repeat: int,
+                   steps: int) -> float:
+    """Milliseconds a layer-step of ``one(q, k_cur, v_cur, cache, lens,
+    layer)``: one dispatch runs ``repeat`` of them (a lone call measures
+    the host), ``steps`` dispatches are timed behind a first that
+    compiles."""
+    @jax.jit
+    def run(q, cache, lens):
+        def body(i, acc):
+            return acc + one(q, k_cur, k_cur, cache, lens, i % layers)
+        return jax.lax.fori_loop(0, repeat, body, jnp.zeros_like(q))
+    np.asarray(run(q, cache, lens)).ravel()[:1]
+    t = time.monotonic()
+    for _ in range(steps):
+        out = run(q, cache, lens)
+    np.asarray(out).ravel()[:1]
+    return (time.monotonic() - t) / steps / repeat * 1e3
+
+
 def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
                 steps=10) -> None:
     """Milliseconds a layer-step of append attention, the XLA gather
@@ -210,18 +234,8 @@ def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
                               jnp.bfloat16)
 
     def timed(one, cache, lens) -> float:
-        @jax.jit
-        def run(q, cache, lens):
-            def body(i, acc):
-                return acc + one(q, k_cur, k_cur, cache, lens,
-                                 i % cfg.num_layers)
-            return jax.lax.fori_loop(0, repeat, body, jnp.zeros_like(q))
-        np.asarray(run(q, cache, lens)).ravel()[:1]
-        t = time.monotonic()
-        for _ in range(steps):
-            out = run(q, cache, lens)
-        np.asarray(out).ravel()[:1]
-        return (time.monotonic() - t) / steps / repeat * 1e3
+        return _layer_step_ms(functools.partial(one, pages=pages), q, k_cur,
+                              cache, lens, cfg.num_layers, repeat, steps)
 
     hd = cfg.num_kv_heads * cfg.head_dim
     rule = "flash" if pa._flash_append_policy(W, hd) else "gather"
@@ -231,8 +245,8 @@ def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
         lens = jnp.asarray(lengths[:live] + [0] * (B - live), jnp.int32)
         state = cache._replace(
             page_table=cache.page_table.at[live:].set(0), lengths=lens)
-        gather = timed(functools.partial(_gather, pages=pages), state, lens)
-        flash = timed(functools.partial(_flash, pages=pages), state, lens)
+        gather = timed(_gather, state, lens)
+        flash = timed(_flash, state, lens)
         tokens = sum(lengths[:live])
         print(f"append {pool} heads={heads} hd={hd} W={W} live={live}/"
               f"{B} ({tokens} cached tokens): gather {gather:.4f} ms, "
@@ -245,6 +259,63 @@ def time_append(heads, W: int, quantized=True, B=32, ps=64, repeat=16,
     if lost:
         raise SlowerThanXLA(f"the rule's {rule} is the slower path at "
                             + "; ".join(lost))
+
+
+# What ``time-fold`` leaves out of the kernel's fold, cumulatively.
+FOLD_PARTS = (("whole", ()), ("no widening", ("convert",)),
+              ("no widening, no dots", ("convert", "dots")),
+              ("no fold (DMAs alone)", ("fold",)))
+
+
+def time_fold(heads, W: int, context=450, rows=32, ps=64, repeat=16,
+              steps=10) -> None:
+    """Milliseconds a layer-step of the flash-append kernel over an int8
+    pool at ``rows`` live rows of ``context`` cached tokens, whole and
+    with parts of its fold left out (:data:`FOLD_PARTS`, through
+    ``pa._FOLD_WITHOUT``), beside the time its bytes ask of 819 GB/s:
+    where a live tile's time goes."""
+    cfg = _cfg(heads)
+    pages = W // ps
+    key = jax.random.PRNGKey(W)
+    live_pages = -(-context // ps)
+    cache = PagedKVCache.create(cfg, rows, rows * live_pages + 1, ps,
+                                max_pages_per_row=pages, dtype=jnp.bfloat16,
+                                quantized=True)
+    rk = jax.random.normal(
+        key, (cfg.num_layers, live_pages * ps, cfg.num_kv_heads,
+              cfg.head_dim), jnp.bfloat16)
+    rv = jax.random.normal(jax.random.fold_in(key, 1), rk.shape,
+                           jnp.bfloat16)
+    for b in range(rows):
+        table = jnp.zeros((pages,), jnp.int32).at[:live_pages].set(
+            1 + b * live_pages + jnp.arange(live_pages, dtype=jnp.int32))
+        cache = write_prefill_row(cache, rk, rv, jnp.asarray(b),
+                                  jnp.asarray(context), table)
+    lens = jnp.full((rows,), context, jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 2),
+                          (rows, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+    kc = jax.random.normal(jax.random.fold_in(key, 3),
+                           (rows, cfg.num_kv_heads, cfg.head_dim),
+                           jnp.bfloat16)
+
+    hd = cfg.num_kv_heads * cfg.head_dim
+    read = rows * context * 2 * (hd + 4 * cfg.num_kv_heads)
+    floor = read / 819e9 * 1e3
+    for label, without in FOLD_PARTS:
+        pa._FOLD_WITHOUT = frozenset(without)
+        jax.clear_caches()      # the knob is read where the kernel traces
+        try:
+            ms = _layer_step_ms(functools.partial(_flash, pages=pages), q,
+                                kc, cache, lens, cfg.num_layers, repeat,
+                                steps)
+        finally:
+            pa._FOLD_WITHOUT = frozenset()
+        print(f"fold heads={heads} hd={hd} W={W} {rows} rows x {context}: "
+              f"{label}: {ms:.4f} ms a layer-step "
+              f"({1e3 * ms / rows:.2f} us a row); {read / 1e6:.1f} MB = "
+              f"{floor:.4f} ms at 819 GB/s ({100 * floor / ms:.1f}%)",
+              flush=True)
+    jax.clear_caches()
 
 
 def time_hd64(rows: int, context: int, live: int, ps=64, repeat=8,
@@ -346,6 +417,13 @@ def main() -> int:
              lambda c=ctx, n=live: time_hd64(32, c, n))
             for live, ctx in ((32, 1024), (32, 4096), (32, 8192),
                               (32, 13312), (8, 8192))))
+        return 0
+    if len(sys.argv) > 1 and sys.argv[1] == "time-fold":
+        run_cases(tuple(
+            (f"time-fold heads={heads} W={W}",
+             lambda h=heads, w=W: time_fold(h, w))
+            for heads, windows in ((MHA16, (512, 1024)), (GQA, (1024, 2048)))
+            for W in windows))
         return 0
     # ``python tools/check_append_kernel.py time``: the timing behind the
     # flash-append boundary, not the verdicts.
