@@ -196,6 +196,19 @@ class ModelConfig:
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # Four scalars of a hybrid pattern (the Granite 4.0-H family's muP
+    # parameterisation; models/nemotron_h.py applies them, each default
+    # leaves a program the text it was): the embedding's rows times
+    # ``embedding_multiplier``; every residual add ``h + residual_multiplier
+    # x out``, a mixer's and an MLP's alike; the softmax scale
+    # ``attention_multiplier`` in place of ``1 / sqrt(head_dim)`` (0: that
+    # default), folded into the queries so that the prefill attention and
+    # both decode paths read it alike; the head's logits divided by
+    # ``logits_scaling``, before anything samples them.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # token ids (llama3 defaults; byte tokenizer overrides)
     bos_token_id: int = 128000
     eos_token_ids: tuple[int, ...] = (128001, 128008, 128009)
@@ -673,6 +686,47 @@ _register(ModelConfig(
     hybrid_pattern="sE" * 4, qk_norm_head=True, index_heads=4,
     index_head_dim=16, index_topk=16, num_experts=8,
     num_experts_per_tok=2, moe_renormalize=True,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# Granite-4.0-H-Micro (ibm-granite/granite-4.0-h-micro config.json,
+# model_type granitemoehybrid, no routed part), whole: 40 published
+# layers, each a mixer and a dense SwiGLU MLP of 8,192 behind RMSNorms.
+# Thirty-six mixers are Mamba-2 (64 heads x 64, ONE group, state 128, a
+# convolution with a bias over 4,352 channels, SSD block 256), four are
+# GQA 32 / 8 x 64 without positional encoding at layers 5, 15, 25, 35
+# (KV heads in pairs, ``kv_paired``). Four scalars: embedding x 12, both
+# halves' outputs x 0.22, softmax scale 1/64, logits / 8. Tied head.
+# 3.19 G parameters. A row's past is 75.5 MB of float32 state whatever
+# its length and 4.1 KB of pages a token.
+GRANITE_H_PERIOD = "M-M-M-M-M-*-M-M-M-M-"
+
+_register(ModelConfig(
+    name="granite-4.0-h-micro", vocab_size=100352, hidden_size=2048,
+    intermediate_size=8192, num_layers=40, num_heads=32, num_kv_heads=8,
+    head_dim=64, max_seq_len=131072, rope_theta=10000.0, rms_norm_eps=1e-5,
+    tie_embeddings=True, hybrid_pattern=GRANITE_H_PERIOD * 4,
+    mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128,
+    ssm_groups=1, conv_kernel=4, ssm_chunk=256, attn_rope=False,
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    attention_multiplier=0.015625, logits_scaling=8.0,
+    bos_token_id=1, eos_token_ids=(2,),
+))
+
+# The same two kinds of layer at test size: two periods of three Mamba-2
+# layers (4 heads x 16, one group, state 16) to one attention layer at a
+# head of 64 = 256 / 4 (4 / 2 heads: one pair of KV heads), an MLP behind
+# each, and the four scalars away from their neutral values (a softmax
+# scale of 1/32 where 1 / sqrt(64) is 1/8).
+_register(ModelConfig(
+    name="tiny-granite-h", vocab_size=512, hidden_size=256,
+    intermediate_size=192, num_layers=8, num_heads=4, num_kv_heads=2,
+    head_dim=64, max_seq_len=256, rope_theta=10000.0, rms_norm_eps=1e-5,
+    tie_embeddings=True, hybrid_pattern="M-M-*-M-" * 2,
+    mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16, ssm_groups=1,
+    conv_kernel=4, ssm_chunk=16, attn_rope=False,
+    embedding_multiplier=6.0, residual_multiplier=0.3,
+    attention_multiplier=0.03125, logits_scaling=4.0,
     bos_token_id=1, eos_token_ids=(2,),
 ))
 

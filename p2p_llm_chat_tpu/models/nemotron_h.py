@@ -122,6 +122,17 @@ positional encoding):
   ``intermediate_size``. A published layer of this family is its mixer
   and ``-``.
 
+The Granite 4.0-H stack is ``M``, ``*`` and ``-`` alone, a ``-`` behind
+every mixer, with four scalars (``ModelConfig``; each default leaves a
+program the text it was): ``h0 = embedding_multiplier x E[token]``;
+every residual add is ``h + residual_multiplier x out``, a mixer's and
+an MLP's alike (:func:`_run_stack`); the softmax scale is
+``attention_multiplier`` where the kernels take ``1 / sqrt(head_dim)``,
+folded into the queries (:func:`_qkv`: the prefill attention, the paired
+flash-append kernel and the gather path read the same q); the logits
+are divided by ``logits_scaling`` (:func:`_logits`), so everything that
+samples or counts sees scaled logits.
+
 **Kinds of per-row past.** The ``*`` layers' K and V are pages
 (ops/paged_kv.py; ``ModelConfig.cache_layers`` of them, each read by
 its own layer, and by the ``x`` layers above it where there are any;
@@ -549,6 +560,10 @@ def _build(config: ModelConfig, key: jax.Array, dtype, stack, head,
     if config.attn_diff and config.attn_rope:
         raise ValueError(f"{config.name}: differential attention is "
                          "served without rotary embedding")
+    if config.attn_diff and config.attention_multiplier:
+        raise ValueError(f"{config.name}: attention_multiplier scales the "
+                         "queries of plain GQA; differential attention "
+                         "takes 1 / sqrt(head_dim)")
     if config.is_indexed and (
             config.kv_paired or config.attn_diff or not config.attn_rope
             or not (config.index_heads and config.index_head_dim
@@ -722,7 +737,8 @@ def _qkv(h, lp, config: ModelConfig, positions=None, window: bool = False):
     """q [B,S,Hq,D], k, v [B,S,Hkv,D] of a GQA layer. ``positions``
     ([B|1,S]): where ``attn_rope``, q and k come back rotated by the
     layer kind's table (``window``: the plain one), behind the per-head
-    RMSNorm where the model has one (``qk_norm_head``)."""
+    RMSNorm where the model has one (``qk_norm_head``); q carries the
+    softmax scale where the model states one (``attention_multiplier``)."""
     B, S, _ = h.shape
     qkv = mm(rms_norm(h, lp["norm"], config.rms_norm_eps), lp["wqkv"])
     Q, KV = config.q_dim, config.kv_dim
@@ -738,6 +754,12 @@ def _qkv(h, lp, config: ModelConfig, positions=None, window: bool = False):
         inv_freq, factor = rope_table(config, window)
         q = apply_rope(q, positions, inv_freq, factor)
         k = apply_rope(k, positions, inv_freq, factor)
+    if config.attention_multiplier:
+        # Every reader of q divides q.k by sqrt(head_dim): the factor
+        # that makes that product ``attention_multiplier`` (Granite's
+        # 1/64 at a head of 64: an eighth, exact in bf16).
+        q = q * jnp.asarray(
+            config.attention_multiplier * config.head_dim ** 0.5, q.dtype)
     return q, k, v
 
 
@@ -1198,22 +1220,29 @@ def _run_stack(params: dict, config: ModelConfig, h: jax.Array, ops: dict,
     experts every routed layer's router kept (int32 [``E`` layers, B, S,
     k]: what a reference replays to tell a wrong layer from a near tie
     decided the other way, benchmark/architectures/lfm2.py)."""
+    def add(h, out):
+        """The residual add, ``residual_multiplier`` on what a layer's
+        half put out (1: the add alone)."""
+        if config.residual_multiplier != 1.0:
+            out = out * jnp.asarray(config.residual_multiplier, out.dtype)
+        return h + out
+
     def moe_step(h, lp, k, carry, stats):
         out, st, *top_i = _moe(h, lp, config, counted, live, chosen)
         if not chosen:
-            return h + out, carry, stats + st
+            return add(h, out), carry, stats + st
         (top_i,), (counts, kept) = top_i, stats
-        return h + out, carry, (
+        return add(h, out), carry, (
             counts + st, jax.lax.dynamic_update_index_in_dim(
                 kept, top_i.reshape(kept.shape[1:]), k, 0))
 
     def mlp_step(h, lp, k, carry, stats):
-        return h + _mlp(h, lp, config), carry, stats
+        return add(h, _mlp(h, lp, config)), carry, stats
 
     def kept(op):
         def step(h, lp, k, carry, stats):
             out, carry = op(h, lp, k, carry)
-            return h + out, carry, stats
+            return add(h, out), carry, stats
         return step
 
     steps = {"E": moe_step, "-": mlp_step,
@@ -1326,7 +1355,19 @@ def _logits(params, config, h, last_idx):
         h = jnp.take_along_axis(h, last_idx[:, None, None].astype(jnp.int32),
                                 axis=1)
     h = _norm(h, params, config, "final_norm")
-    return mm(h, params["lm_head"]).astype(jnp.float32)
+    logits = mm(h, params["lm_head"]).astype(jnp.float32)
+    if config.logits_scaling != 1.0:
+        logits = logits / config.logits_scaling
+    return logits
+
+
+def _embed(params, config, tokens):
+    """``E[token]``, times ``embedding_multiplier`` where the model
+    states one."""
+    h = params["embed"][tokens]
+    if config.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(config.embedding_multiplier, h.dtype)
+    return h
 
 
 def _refuse_mesh(mesh) -> None:
@@ -1349,7 +1390,7 @@ def _forward(params: dict, config: ModelConfig, tokens: jax.Array,
     B, S = tokens.shape
     if valid is None:
         valid = jnp.ones((B, S), bool)
-    h = params["embed"][tokens]
+    h = _embed(params, config, tokens)
     # What ``Y`` and ``*`` hand to the layers above them in this step.
     published: dict = {}
 
@@ -1556,7 +1597,7 @@ def decode_step_paged_touched(params: dict, config: ModelConfig,
     from ..ops.paged_kv import write_decode_burst
     _refuse_mesh(mesh)
     B = tokens.shape[0]
-    h = params["embed"][tokens]
+    h = _embed(params, config, tokens)
     live = jnp.ones((B,), bool) if active is None else active
     # ``*`` layers inside a scan over periods (_segments); an ``s``
     # layer is scanned with its neighbour (_plan).

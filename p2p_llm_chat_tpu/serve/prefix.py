@@ -151,6 +151,17 @@ class PrefixStore:
         with self._lock:
             return sum(e.nbytes for e in self._entries.values())
 
+    @property
+    def state_nbytes(self) -> int:
+        """Of :attr:`nbytes`, the entries' state snapshots: one row's
+        recurrent state a layer whatever the entry's length, so for a
+        model that keeps one the store is sized in snapshots, not
+        tokens (76 MB each for granite-4.0-h-micro; SERVE_PREFIX_MB
+        counts them)."""
+        with self._lock:
+            return sum(int(getattr(e.state, "nbytes", 0) or 0)
+                       for e in self._entries.values())
+
     def match(self, ids: list[int]) -> Optional[PrefixEntry]:
         """Longest entry that is a proper prefix of ``ids`` (at least one
         suffix token must remain to prefill — its logits seed sampling)."""

@@ -3941,6 +3941,11 @@ class BatchScheduler:
             out["serve_state_row_steps_live_total"] = \
                 self._n_state_row_steps_live
             out["serve_state_snapshots_total"] = self._n_state_snapshots
+            # What the prefix store holds as state snapshots (an entry's
+            # is one row's state and window whatever its length).
+            out["serve_prefix_state_bytes"] = (
+                self._prefix.state_nbytes if self._prefix is not None
+                else 0)
         if self.config.window_layers:
             out["serve_window_bytes_total"] = self._n_window_bytes
         if self._shared_kv_readers:
@@ -4541,7 +4546,9 @@ class BatchScheduler:
             # dropped via the row sentinel).
             prog, pre = self._admit_j, ()
         packed = self._admit_upload(packed, live=bool(chunk))
-        with self._phase("launch"):
+        # Every entry, a dummy too, writes a whole row of the state pool
+        # (0 bytes for a model without recurrent state).
+        with self._phase("launch", state_bytes=R * self._state_row_bytes):
             self._note_launch("admit")
             (toks_dev, self._cache, self._keys, self._next_dev,
              self._temps_dev, self._top_ks_dev, self._top_ps_dev,
@@ -4677,7 +4684,10 @@ class BatchScheduler:
                            t_admit - slot.req.arrival_time)
                     tr.add(slot.req.trace_id, "sched.prefill", t_admit,
                            now - t_admit, tokens=len(slot.prompt_ids),
-                           row=row, **laddered)
+                           row=row, **laddered,
+                           # only a model with recurrent state says so
+                           **({"state_bytes": self._state_row_bytes}
+                              if self._state_row_bytes else {}))
                     slot.cut0 = self._ledger.totals()
                 slot.ctx_len = len(slot.prompt_ids)
                 # last_emit_t stays 0 until _append_token below sets it: the
@@ -4741,8 +4751,10 @@ class BatchScheduler:
         self._ledger.cut(PADDED if padded else CHUNK)
         self._flight.note("prefill_chunk", self._loop_iter,
                           off=off, C=C, S=pc.S, n=len(pc.chunk))
+        # The ladder's last chunk installs its rows in the state pool.
+        installs = R * self._state_row_bytes if off + C == pc.S else 0
         with self._phase("prefill_chunk", R=R, S=pc.S, C=C, off=off,
-                         padded=int(padded)):
+                         padded=int(padded), state_bytes=installs):
             kv, logits, toks_dev = self._dispatch_prefill_chunk(
                 P0, pc.S, off, C, pc.packed, pc.kv, pc.logits, pc.prefix)
             if toks_dev is None:

@@ -202,13 +202,17 @@ def test_mosaic_refuses_what_the_expert_account_refuses(described, no_cache):
 
 # The state pool of benchmark/configs/nemotron-3-super-120b-a12b-l22e128:
 # ten Mamba-2 layers, 32 slots and the garbage row, 128 heads x 64 x 128
-# float32 in 8 groups; a decode step moves 32 rows.
-SSM_SHAPE = (10, 33, 128, 64, 128)
-SSM_GROUPS, SSM_ROWS, CONV_K = 8, 32, 4
+# float32 in 8 groups; a decode step moves 32 rows. And that of
+# benchmark/configs/granite-4.0-h-micro.json: 36 layers, 64 slots and the
+# garbage row, 64 heads x 64 x 128 in ONE group; a step moves 64 rows.
+SSM_POOLS = {"nemotron": ((10, 33, 128, 64, 128), 8, 32),
+             "granite": ((36, 65, 64, 64, 128), 1, 64)}
+CONV_K = 4
 
 
+@pytest.mark.parametrize("which", sorted(SSM_POOLS))
 def test_the_decode_kernel_updates_the_state_pool_where_it_lies(
-        described, no_cache, monkeypatch):
+        described, no_cache, monkeypatch, which):
     """``decode_update`` on the chip's path (the platform probe steered
     here: nothing runs), two layers' calls in one donated program, as a
     decode program's walk makes them: Mosaic takes the head block
@@ -219,8 +223,8 @@ def test_the_decode_kernel_updates_the_state_pool_where_it_lies(
     import jax.numpy as jnp
     from p2p_llm_chat_tpu.ops import state_pool
     from p2p_llm_chat_tpu.ops.state_pool import StatePool
+    SSM_SHAPE, G, B = SSM_POOLS[which]
     _, _, H, P, N = SSM_SHAPE
-    G, B = SSM_GROUPS, SSM_ROWS
     hb = state_pool.pick_head_block(H, P, N, G)
     assert hb and H % hb == 0
     assert state_pool.ssm_kernel_vmem_bytes(hb, P, N) < 16 * 1024 * 1024

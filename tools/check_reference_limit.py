@@ -20,7 +20,7 @@ does and compares it with
   ``compare``, which also holds the recurrent state to a limit).
 
     python tools/check_reference_limit.py benchmark/configs/<name>.json \
-        --seeds 53,1,2 --wrong-seeds 53
+        --seeds 53,1,2 --wrong-seeds 53,1,2 --rest-wrong bf16_state
 
 Prints one JSON line a reading and writes them all to
 ``chiprun_out/reference_limit.<name>.json``.
@@ -82,6 +82,10 @@ def main() -> None:
                     help="sample seeds of the sound reading (53 is the "
                          "one a run checks, benchmark/run.py)")
     ap.add_argument("--wrong-seeds", default="53")
+    ap.add_argument("--rest-wrong", default="",
+                    help="the wrong models read on the wrong seeds behind "
+                         "the first (comma-separated; default: all): more "
+                         "seeds for the reading nearest a limit")
     args = ap.parse_args()
     with open(args.config_file) as f:
         cfg = json.load(f)
@@ -103,6 +107,7 @@ def main() -> None:
     drive = getattr(arch, "system_logits", serve_cell.system_logits)
     sound = [int(s) for s in args.seeds.split(",")]
     wrong = [int(s) for s in args.wrong_seeds.split(",")]
+    rest = set(filter(None, args.rest_wrong.split(",")))
     P, D = serve_cell.REF_PREFILL, serve_cell.REF_DECODE
     readings = []
 
@@ -122,6 +127,8 @@ def main() -> None:
         if seed in wrong:
             for name, (wcfg, w) in wrong_models(cfg, weights,
                                                 arch).items():
+                if seed != wrong[0] and rest and name not in rest:
+                    continue
                 ref, wfacts = arch.forward(wcfg, tokens, w)
                 if isinstance(system, tuple):
                     # A family whose check reads more than logits (the
