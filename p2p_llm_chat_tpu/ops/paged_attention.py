@@ -48,18 +48,19 @@ over the same scores:
   TPU only.
 
 The rule that chooses (:func:`_flash_append_policy`, guarded by
-:func:`flash_append_blocked`): the kernel from ``W >= max(256, 1024 *
-1024 / max(hd, 1024))`` with ``hd = Hkv * head_dim``, the gather below
-it and wherever the kernel cannot run (no TPU, a pool sharded over a
-mesh, a head_dim that does not fill 128 lanes, an int8 pool of fewer
-than 4 kv heads). A function of window and pool geometry alone, decided
-once per trace (a paired pool's ``Hkv`` is its pairs and its
-``head_dim`` 128: four pairs are 512 numbers a token, the boundary
-1,024, and neither refusal meets them; PERF.md section 6, PR 45, has
-that geometry's table); its measurement is PERF.md section 6, PR 31, and
-``python tools/check_append_kernel.py time`` measures it again by calling
-the two implementations by name. The block verify stays on the gather
-at every window (the kernel's state is seeded with ONE current token).
+:func:`flash_append_blocked`): the kernel from a window of 256 tokens
+(``_FLASH_MIN_W``) at every pool geometry, the gather below it and
+wherever the kernel cannot run (no TPU, a pool sharded over a mesh, a
+head_dim that does not fill 128 lanes, an int8 pool of fewer than 4 kv
+heads). A function of the window alone, decided once per trace; a paired
+pool's ``Hkv`` is its pairs and its ``head_dim`` 128, so neither refusal
+meets it. Its measurement is PERF.md section 6, PR 56 (the table by
+window, width, pool and occupancy; PR 31's rule, ``W >= max(256, 1024 *
+1024 / max(hd, 1024))``, was set with a kernel that folded tokens x
+heads), and ``python tools/check_append_kernel.py time`` measures it
+again by calling the two implementations by name. The block verify
+stays on the gather at every window (the kernel's state is seeded with
+ONE current token).
 
 :func:`paged_attention_reference` is the index-naive jnp oracle the tests
 hold both implementations to (tests/test_ops_paged.py).
@@ -137,8 +138,7 @@ def paged_attention_append(q, k_cur, v_cur, cache, lengths, layer,
             cache.page_table, lengths, layer)
     blocked = flash_append_blocked(sharded, D, Hkv if quantized else 0)
     scaled = {} if scale is None else {"scale": scale}
-    if not blocked and _flash_append_policy(pages * cache.k.shape[2],
-                                            Hkv * D):
+    if not blocked and _flash_append_policy(pages * cache.k.shape[2]):
         return _paged_attention_flash_append(*args, pages=pages,
                                              quantized=quantized, **scaled)
     return _append_gather(*args, pages=pages, **scaled)
@@ -712,7 +712,7 @@ def paged_attention_select_append(q, k_cur, v_cur, qi, wi, ki_cur, cache,
     args = (q, k_cur, v_cur, cache.k, cache.v, cache.k_scale, cache.v_scale,
             cache.page_table, lengths, layer)
     blocked = flash_append_blocked(sharded, D, Hkv if quantized else 0)
-    if not blocked and _flash_append_policy(W, Hkv * D):
+    if not blocked and _flash_append_policy(W):
         out = _paged_attention_flash_append(
             *args, pages=pages, quantized=quantized, keep=in_pool,
             keep_cur=keep_cur)
@@ -805,43 +805,39 @@ def paged_attention_verify_append(q_blk, k_blk, v_blk, cache, lengths,
 # geometries.
 _FLASH_CHUNK_TOK_BYTES = 1024
 
-# The Hkv * head_dim the chunk budget and the boundary were calibrated
-# at (llama-8B class: 8 kv heads x 128), and the boundary there.
+# The Hkv * head_dim the chunk and tile budgets were calibrated at
+# (llama-8B class: 8 kv heads x 128).
 _FLASH_HD_REF = 1024
-_FLASH_MIN_W = 1024
 
-# Floor for the engagement boundary: no geometry measured engages below
-# it (one chunk a row, nothing to skip at a full batch).
-_FLASH_MIN_W_FLOOR = 256
+# The smallest window the kernel serves, at every pool geometry.
+_FLASH_MIN_W = 256
 
 
-def _flash_boundary(hd: int) -> int:
-    """The window from which the flash kernel serves a pool of ``hd =
-    Hkv * head_dim`` numbers a token: 1,024 up to the calibration
-    geometry, scaled down by ``1024 / hd`` for wider ones (OLMoE's MHA,
-    hd = 2048: 512), never below 256."""
-    return max(_FLASH_MIN_W_FLOOR,
-               _FLASH_MIN_W * _FLASH_HD_REF // max(hd, _FLASH_HD_REF))
-
-
-def _flash_append_policy(window: int, hd: int = _FLASH_HD_REF) -> bool:
+def _flash_append_policy(window: int) -> bool:
     """The dispatch rule for the append path where the kernel can run
     (:func:`flash_append_blocked` is the guard), pure so CPU tests pin
     its table (tests/test_flash_append_geometry.py).
 
-    Why a function of window and width alone (PR 31; PERF.md section 6
-    has the table): the kernel's work follows the rows' lengths, the
-    gather path's the window, so what decides is the FULL batch, where
-    the kernel has least to skip. There a grid program costs 3-5 us
-    whatever the width while the gather path's materialised, dequantised
-    window grows with ``W * hd`` (and past hd = 1024 stops fitting what
-    XLA fuses): measured on a v5e at 32 live rows of chat-mix lengths,
-    int8 pool, the kernel is level with gather or ahead from W = 1024 at
-    hd 512 (2% behind) and 1024 (10% ahead) and from W = 256 at hd 2048,
-    and at 2 live rows of 32 it is 1.2x-56x faster at every window
-    measured (so the boundary is where the full batch stops losing,
-    never a function of live rows, which a trace cannot see)."""
-    return window >= _flash_boundary(hd)
+    Why a function of the window alone: the kernel's work follows the
+    rows' lengths, the gather path's the window and every slot, so what
+    decides is the FULL batch, where the kernel has least to skip; live
+    rows are never read (a trace cannot see them). Measured on a v5e
+    (PR 56, ``tools/check_append_kernel.py time``; PERF.md section 6 and
+    docs/serving.md have the 80 points: W 128-2,048, hd 512 / 1,024 /
+    2,048, int8 and bf16 pools, 32 slots and 64 at hd 512, full and 2
+    live rows): from W 256 the kernel is level with the gather or ahead
+    at a full batch on the int8 pool at every width (1.00-1.27x at W 256
+    below hd 2,048, 3.1x there), 3% and 7% behind at W 256 on a bf16
+    pool at hd 512 (32 and 64 slots; no configuration serves one) and
+    ahead of it from W 512, and 1.5-8x ahead at 2 live rows; at W 128 a
+    full batch LOSES at hd 512 (0.029 ms a layer-step against 0.037,
+    0.047 against 0.064 at 64 slots: a launch a row of one short tile
+    costs more than that gather) and is level at hd 1,024, so one window
+    serves every geometry. Until PR 56 the rule was PR 31's, ``W >=
+    max(256, 1024 * 1024 / max(hd, 1024))``, set with a kernel that
+    folded tokens x heads and lost a full batch to the gather at W <=
+    512 below hd 2,048; PR 54 rewrote the fold."""
+    return window >= _FLASH_MIN_W
 
 
 def flash_append_blocked(sharded: bool = False, head_dim: int = 128,
@@ -877,16 +873,16 @@ def flash_append_blocked(sharded: bool = False, head_dim: int = 128,
     return None
 
 
-def effective_flash_min_w(hd: int = _FLASH_HD_REF, sharded: bool = False,
-                          head_dim: int = 128, int8_kv_heads: int = 0) -> int:
-    """The flash-append engagement boundary as ONE number, for gauges
-    and logs (serve/scheduler.py's ``paged_flash_min_w``): 0 = the
-    kernel cannot engage in this process (:func:`flash_append_blocked`),
-    else the boundary for ``hd = Hkv * head_dim`` (the scheduler passes
-    its model's)."""
+def effective_flash_min_w(sharded: bool = False, head_dim: int = 128,
+                          int8_kv_heads: int = 0) -> int:
+    """The smallest window the flash-append kernel serves as ONE number,
+    for gauges and logs (serve/scheduler.py's ``paged_flash_min_w``):
+    0 = the kernel cannot engage in this process for this pool
+    (:func:`flash_append_blocked`), else :func:`_flash_append_policy`'s
+    window."""
     if flash_append_blocked(sharded, head_dim, int8_kv_heads):
         return 0
-    return _flash_boundary(hd)
+    return _FLASH_MIN_W
 
 
 def flash_append_chunk_pages(hd: int, itemsize: int, page_size: int,
@@ -1026,10 +1022,17 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
       positions past the row's length; a position at NEG_INF weighs
       exactly 0, also where nothing has been kept yet.
     """
-    tiles = chunk_pages // tile_pages
     Ct = chunk_pages * page_size
     Tt = tile_pages * page_size
     without = _FOLD_WITHOUT
+    # A window of ONE chunk (every window under the chunk budget: where a
+    # part-full batch decodes) starts and waits for its page DMAs in
+    # loops the trace holds once; a longer window unrolls them a page.
+    # Unrolled, every program that holds the kernel costs a boot 0.2 s a
+    # page of its chunk to trace and lower; rolled, a FULL batch of a
+    # narrow pool folds 6-13% slower (PERF.md section 6, PR 56, has both
+    # tables), which is what the long windows' cells would pay.
+    rolled = num_chunks == 1
 
     def body(*refs):
         # Prefetched scalars, inputs, the output, scratch: in that order,
@@ -1102,7 +1105,15 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
 
         def start_chunk(slot, bb, cc) -> None:
             live = live_tiles(bb, cc)
-            for t in range(tiles):
+            if rolled:      # the live tiles are the chunk's first
+                def start_page(i, carry):
+                    for d in dma(slot, bb, cc, i):
+                        d.start()
+                    return carry
+
+                jax.lax.fori_loop(0, live * tile_pages, start_page, 0)
+                return
+            for t in range(chunk_pages // tile_pages):
                 @pl.when(t < live)
                 def _start_tile():
                     for i in range(t * tile_pages, (t + 1) * tile_pages):
@@ -1190,9 +1201,17 @@ def _flash_append_kernel_body(quantized: bool, page_size: int, pages: int,
 
             def fold_tile(t, carry):
                 p0 = t * tile_pages
-                for i in range(tile_pages):
+
+                def wait_page(i, carry=0):
                     for d in dma(slot, b, c, p0 + i):
                         d.wait()
+                    return carry
+
+                if rolled:
+                    jax.lax.fori_loop(0, tile_pages, wait_page, 0)
+                else:
+                    for i in range(tile_pages):
+                        wait_page(i)
                 if "fold" in without:
                     return carry
                 if "dots" in without:

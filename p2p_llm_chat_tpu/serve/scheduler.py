@@ -4040,13 +4040,11 @@ class BatchScheduler:
                 self._wake_hist.percentile(95) or 0.0, 3)
         out["serve_kv_free_pages"] = self._alloc.free_pages
         out["serve_kv_total_pages"] = self.num_pages - 1
-        # The gather->flash-append promotion boundary (0 = kernel
-        # cannot engage: CPU / disabled / block-kernel override;
-        # 1 = the flash override, every window): operators
-        # correlating a step-time knee at a window boundary read the
-        # value the compiled ladder baked in — snapshotted at
-        # construction and at warmup, NOT the live env (the toggle
-        # is runtime-flippable; traced programs are not).
+        # The gather->flash-append promotion boundary (0 = the kernel
+        # cannot engage: the CPU, a mesh, a pool Mosaic refuses, a
+        # latent pool): operators correlating a step-time knee at a
+        # window boundary read the value the compiled ladder baked in,
+        # fixed at construction.
         out["paged_flash_min_w"] = self._paged_flash_min_w
         return out
 
@@ -4161,10 +4159,8 @@ class BatchScheduler:
         """Window threshold at which this process's paged decode
         programs dispatch the multi-chunk flash-append kernel instead of
         the gather path: 0 = cannot engage (CPU, a mesh-sharded pool, a
-        head_dim or an int8 pool Mosaic refuses). The threshold is a
-        function of the model's per-token KV row width (kv_dim =
-        num_kv_heads * head_dim): 1,024 up to kv_dim 1,024, lower for
-        wider rows. One source of truth:
+        head_dim or an int8 pool Mosaic refuses), else one window for
+        every pool geometry. One source of truth:
         ops/paged_attention.effective_flash_min_w, next to the dispatch
         policy itself."""
         from ..ops.paged_attention import effective_flash_min_w
@@ -4173,7 +4169,7 @@ class BatchScheduler:
             # (ops/mla_attention.py); this policy is the per-head pools'.
             return 0
         return effective_flash_min_w(
-            config.kv_dim, mesh is not None,
+            mesh is not None,
             *BatchScheduler._flash_pool_row(config, kv_quant))
 
     def _try_reserve(self, slot: _Slot) -> bool:

@@ -54,19 +54,20 @@ PS = 8
 CHUNK_BYTES = 64
 
 
-def _filled_cache(cfg, pages, ps, lengths, quantized, rng):
+def _filled_cache(cfg, pages, ps, lengths, quantized, rng,
+                  dtype=jnp.float32):
     """Pool with each row's first ``lengths[b]`` slots holding random kv
     through the real splice op; rows own disjoint page ranges."""
     B = len(lengths)
     cache = paged_kv.PagedKVCache.create(
         cfg, B, B * pages + 1, ps, max_pages_per_row=pages,
-        dtype=jnp.float32, quantized=quantized)
+        dtype=dtype, quantized=quantized)
     for b, n in enumerate(lengths):
         table = jnp.asarray(1 + b * pages + np.arange(pages), jnp.int32)
         rk = jnp.asarray(rng.normal(size=(cfg.num_layers, pages * ps,
                                           cfg.num_kv_heads, cfg.head_dim)),
-                         jnp.float32)
-        rv = jnp.asarray(rng.normal(size=rk.shape), jnp.float32)
+                         dtype)
+        rv = jnp.asarray(rng.normal(size=rk.shape), dtype)
         cache = paged_kv.write_prefill_row(cache, rk, rv, jnp.asarray(b),
                                            jnp.asarray(n), table)
     return cache
@@ -313,46 +314,6 @@ def test_chunk_size_function_is_the_kernels(hd, int8_tokens, dtype, itemsize,
             -(-pages // want))
 
 
-# The boundary by pool geometry (hd = Hkv * head_dim): the first window
-# the kernel serves, and the last it does not.
-_BOUNDARY = {
-    # Narrower than any served geometry: not scaled down (the full batch
-    # loses there; measured at hd 512, W = 512: 0.075 ms gather against
-    # 0.111).
-    32: 1024, 256: 1024,
-    # bench-moe's narrow KV (4 kv heads x 128) and the calibration width
-    # (Mistral, Mixtral, the 70B class): 1,024 (PR 31; 2,048 while the
-    # kernel walked every chunk).
-    512: 1024, 1024: 1024,
-    # Wider than the calibration (OLMoE's MHA): 1024 / hd of it, as
-    # PR 26 measured (3.2-11.5x at 32 live rows on a v5e, PERF.md
-    # section 6, PR 31).
-    2048: 512,
-    # The floor: no geometry engages below 256 tokens, however wide.
-    8192: 256,
-}
-
-
-@pytest.mark.parametrize("hd", sorted(_BOUNDARY))
-def test_dispatch_policy_table(hd, monkeypatch):
-    """The dispatch rule is a function of (window, hd) and nothing else:
-    its table by geometry, with no other argument to give it and no
-    environment variable that moves it."""
-    w = _BOUNDARY[hd]
-    for env in ({}, {"PAGED_APPEND_FLASH_MIN_W": "4096",
-                     "PAGED_APPEND_IMPL": "flash",
-                     "PAGED_ATTN_IMPL": "kernel"}):
-        for name, value in env.items():
-            monkeypatch.setenv(name, value)
-        assert pa._flash_boundary(hd) == w
-        assert pa._flash_append_policy(w, hd)
-        assert pa._flash_append_policy(4 * w, hd)
-        assert not pa._flash_append_policy(w - 1, hd)
-        assert not pa._flash_append_policy(192, hd)
-    if hd == 1024:
-        assert pa._flash_append_policy(w) and not pa._flash_append_policy(w - 1)
-
-
 def _pool(Hkv=8, D=128, quantized=True, ps=64, pages=16, B=2):
     """What the chooser reads of a cache: shapes, and whether it has
     scales."""
@@ -366,9 +327,58 @@ def _pool(Hkv=8, D=128, quantized=True, ps=64, pages=16, B=2):
         page_table=jax.ShapeDtypeStruct((B, pages), jnp.int32))
 
 
+def _stub_both_sides(monkeypatch, on_tpu=True):
+    """The chooser's two implementations answer with their names."""
+    monkeypatch.setattr(pa, "on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(pa, "_append_gather",
+                        lambda *a, pages: ("gather", pages))
+    monkeypatch.setattr(
+        pa, "_paged_attention_flash_append",
+        lambda *a, pages, quantized: ("flash", pages, quantized))
+
+
+# The kernel's first window (PR 56: one for every geometry; PERF.md
+# section 6 has the table it came from).
+MIN_W = 256
+
+# Pool widths hd = Hkv * head_dim as (kv heads, int8 pool): one and two
+# heads (float pools: an int8 pool of fewer than four is refused), the
+# paired pools' and the 4-KV-head models' 512, Mistral's and Mixtral's
+# 1,024, OLMoE's and Ouro's 2,048, and wider than anything served.
+# Until PR 56 the window scaled with the width (1,024 up to hd 1,024,
+# 512 at 2,048, 256 from 4,096).
+_WIDTHS = {128: (1, False), 256: (2, False), 512: (4, True),
+           1024: (8, True), 2048: (16, True), 8192: (64, True)}
+
+
+@pytest.mark.parametrize("hd", sorted(_WIDTHS))
+def test_dispatch_policy_table(hd, monkeypatch):
+    """The dispatch rule is a function of the window and nothing else:
+    the same table at every pool width through the chooser itself, and
+    no environment variable that moves it."""
+    Hkv, quantized = _WIDTHS[hd]
+    cache = _pool(Hkv=Hkv, quantized=quantized, ps=64, pages=16)
+    _stub_both_sides(monkeypatch)
+    for env in ({}, {"PAGED_APPEND_FLASH_MIN_W": "4096",
+                     "PAGED_APPEND_IMPL": "gather",
+                     "PAGED_ATTN_IMPL": "kernel"}):
+        for name, value in env.items():
+            monkeypatch.setenv(name, value)
+        for W in (128, 192, 256, 512, 1024):
+            assert pa._flash_append_policy(W) == (W >= MIN_W)
+            got = pa.paged_attention_append(None, None, None, cache, None,
+                                            0, pages=W // 64)
+            assert got == (("flash", W // 64, quantized) if W >= MIN_W
+                           else ("gather", W // 64))
+        assert not pa._flash_append_policy(MIN_W - 1)
+        assert pa._flash_append_policy(4096)
+        assert pa.effective_flash_min_w(False, 128,
+                                        Hkv if quantized else 0) == MIN_W
+
+
 # Each reason flash_append_blocked can give, as (what the reason says, the
 # platform probe's answer, the pool, ``sharded``), then a pool nothing
-# blocks. Every pool's window (1,024) is at or past its boundary.
+# blocks. Every pool's window (1,024) is past the kernel's first.
 _GUARD = {
     "not-a-tpu": ("not on a TPU", False, _pool(), False),
     "sharded": ("sharded over a mesh", True, _pool(), True),
@@ -386,18 +396,13 @@ def test_chooser_takes_gather_wherever_the_kernel_is_blocked(case,
     for each reason the guard names, the kernel where it names none, and
     the gauge (``effective_flash_min_w``) says the same."""
     reason, on_tpu, cache, sharded = _GUARD[case]
-    monkeypatch.setattr(pa, "on_tpu", lambda: on_tpu)
-    monkeypatch.setattr(pa, "_append_gather",
-                        lambda *a, pages: ("gather", pages))
-    monkeypatch.setattr(
-        pa, "_paged_attention_flash_append",
-        lambda *a, pages, quantized: ("flash", pages, quantized))
+    _stub_both_sides(monkeypatch, on_tpu)
     Hkv, D = cache.k.shape[3:]
     quantized = cache.k_scale is not None
     guard = (sharded, D, Hkv if quantized else 0)
     pages = cache.page_table.shape[1]
     for short in (False, True):
-        n = pages // 2 if short else pages       # below / at the boundary
+        n = 2 if short else pages       # 128 tokens / the whole 1,024
         got = pa.paged_attention_append(None, None, None, cache, None, 0,
                                         pages=n, sharded=sharded)
         if reason or short:
@@ -406,11 +411,10 @@ def test_chooser_takes_gather_wherever_the_kernel_is_blocked(case,
             assert got == ("flash", n, quantized)
     if reason:
         assert reason in pa.flash_append_blocked(*guard)
-        assert pa.effective_flash_min_w(Hkv * D, *guard) == 0
+        assert pa.effective_flash_min_w(*guard) == 0
     else:
         assert pa.flash_append_blocked(*guard) is None
-        assert pa.effective_flash_min_w(Hkv * D, *guard) == max(
-            256, 1024 * 1024 // max(Hkv * D, 1024))
+        assert pa.effective_flash_min_w(*guard) == MIN_W
 
 
 # -- long-window matrix (ci.sh full mode) -------------------------------------

@@ -29,6 +29,7 @@ from p2p_llm_chat_tpu.ops import paged_attention as pa
 from p2p_llm_chat_tpu.ops import state_pool
 from p2p_llm_chat_tpu.ops.paged_kv import (PagedKVCache, write_prefill_batch,
                                            write_prefill_row)
+from p2p_llm_chat_tpu.serve.scheduler import BatchScheduler
 
 from solo import jit_model
 
@@ -502,14 +503,20 @@ def test_zero_extended_flash_append_equals_the_gather_path(quantized,
     np.testing.assert_array_equal(np.asarray(back), np.asarray(q))
 
 
-def test_the_rule_that_picks_the_decode_attention_sees_the_pairs_row():
-    """The flash-append policy reads the pool's row: four pairs x 128 =
-    512 numbers a token, the boundary of the narrow-KV geometry (1,024);
+def test_the_rule_that_picks_the_decode_attention_sees_the_pairs_row(
+        monkeypatch):
+    """The flash-append guard reads the pool's row, four pairs x 128:
     neither refusal of the kernel (a head under 128 lanes, an int8 pool
-    of fewer than 4 rows) meets the published pool."""
+    of fewer than 4 rows) meets the published pool, so on a chip its
+    decode takes the kernel from the window every geometry does (256
+    since PR 56; 1,024 at this width before)."""
     big = get_config(NAME)
-    assert pa._flash_boundary(big.cache_kv_heads * big.cache_k_dim) == 1024
     assert big.cache_k_dim % 128 == 0 and big.cache_kv_heads % 4 == 0
+    row = BatchScheduler._flash_pool_row(big, True)
+    assert row == (128, 4)
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    assert pa.effective_flash_min_w(False, *row) == 256
+    assert BatchScheduler._flash_min_w(big, None, True) == 256
 
 
 # -- the served precision, and the wrong models --------------------------------

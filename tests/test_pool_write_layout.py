@@ -313,6 +313,29 @@ def test_the_masked_flash_append_compiles_for_the_chip(masked, described,
 
 
 # The per-head pools the flash-append kernel walks at short contexts:
+def _flash_append_compiled(described, heads, hkv, rows, window, quantized):
+    """(the kernel's chunk in pages, the compiled text) of the
+    flash-append kernel alone at one pool geometry and window."""
+    import jax
+    import jax.numpy as jnp
+    from p2p_llm_chat_tpu.ops import paged_attention as pa
+    L, bf = 2, jnp.bfloat16
+    pages, N = window // PS, rows * 8 + 1
+    kv = described((L, N, PS, hkv, D), jnp.int8 if quantized else bf)
+    scale = described((L, N, hkv, 128), jnp.float32) if quantized else None
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(lambda *a: pa._paged_attention_flash_append(
+            *a, pages=pages, quantized=quantized)).lower(
+            described((rows, heads, D), bf), described((rows, hkv, D), bf),
+            described((rows, hkv, D), bf), kv, kv, scale, scale,
+            described((rows, pages), jnp.int32),
+            described((rows,), jnp.int32),
+            described((), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_attention_flash_append" in text
+    return pa.flash_append_chunk_pages(hkv * D, kv.dtype.itemsize, PS, pages)
+
+
 # 16 MHA heads (OLMoE, Ouro: 256-token tiles of a 512-token chunk) and
 # llama's 8 GQA heads (512 of 1,024), 32 rows, int8 and bf16.
 @pytest.mark.parametrize("heads, hkv, window, quantized", [
@@ -320,25 +343,27 @@ def test_the_masked_flash_append_compiles_for_the_chip(masked, described,
     (16, 16, 512, False), (32, 8, 2048, False)])
 def test_the_tiled_flash_append_compiles_for_the_chip(
         heads, hkv, window, quantized, described, no_cache):
-    import jax
-    import jax.numpy as jnp
     from p2p_llm_chat_tpu.ops import paged_attention as pa
-    B, L, bf = ROWS, 2, jnp.bfloat16
-    pages, N = window // PS, ROWS * 8 + 1
-    kv = described((L, N, PS, hkv, D), jnp.int8 if quantized else bf)
-    scale = described((L, N, hkv, 128), jnp.float32) if quantized else None
-    chunk = pa.flash_append_chunk_pages(hkv * D, kv.dtype.itemsize, PS, pages)
-    assert pa.flash_append_tile_pages(hkv * D, kv.dtype.itemsize, PS,
+    chunk = _flash_append_compiled(described, heads, hkv, ROWS, window,
+                                   quantized)
+    assert pa.flash_append_tile_pages(hkv * D, 1 if quantized else 2, PS,
                                       chunk) * 2 == chunk
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(lambda *a: pa._paged_attention_flash_append(
-            *a, pages=pages, quantized=quantized)).lower(
-            described((B, heads, D), bf), described((B, hkv, D), bf),
-            described((B, hkv, D), bf), kv, kv, scale, scale,
-            described((B, pages), jnp.int32), described((B,), jnp.int32),
-            described((), jnp.int32)).compile().as_text()
-    assert text.count("tpu_custom_call") == 1
-    assert "paged_attention_flash_append" in text
+
+
+# What PR 56 put on the served path: a window of 256 or 512 tokens is ONE
+# chunk a row, shorter than the chunk budget. Mistral's and Mixtral's 8
+# GQA heads, the 4 rows x 128 of the paired pools and of the 4-KV-head
+# models (at Granite's 64 slots too), 16 MHA heads, int8 and bf16.
+@pytest.mark.parametrize("heads, hkv, rows, window, quantized", [
+    (32, 8, 32, 256, True), (32, 8, 32, 512, True), (32, 4, 32, 256, True),
+    (32, 4, 64, 256, True), (16, 16, 32, 256, True), (32, 8, 32, 256, False),
+    (32, 4, 32, 512, False)])
+def test_the_flash_append_compiles_for_the_chip_at_a_short_window(
+        heads, hkv, rows, window, quantized, described, no_cache):
+    from p2p_llm_chat_tpu.ops import paged_attention as pa
+    assert pa._flash_append_policy(window)
+    assert _flash_append_compiled(described, heads, hkv, rows, window,
+                                  quantized) == window // PS
 
 
 def test_the_selection_kernels_compile_for_the_chip(described, no_cache):
